@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.jax_compat import axis_size as _axis_size
 from . import comm as dist
 
 AxisName = Union[str, Tuple[str, ...]]
@@ -52,7 +51,7 @@ def _axes_tuple(axes: AxisName) -> Tuple[str, ...]:
 def _world(axes: AxisName) -> int:
     w = 1
     for a in _axes_tuple(axes):
-        w *= int(_axis_size(a))
+        w *= int(jax.lax.axis_size(a))
     return w
 
 
@@ -61,7 +60,7 @@ def _linear_index(axes: AxisName):
     matches how ``PartitionSpec((a, b))`` linearizes shards."""
     idx = jnp.int32(0)
     for a in _axes_tuple(axes):
-        idx = idx * int(_axis_size(a)) + dist.axis_index(a)
+        idx = idx * int(jax.lax.axis_size(a)) + dist.axis_index(a)
     return idx
 
 

@@ -23,11 +23,18 @@ from ..utils.logging import logger
 
 def rank_env(rank: int, world: int, master_addr: str, master_port: int
              ) -> Dict[str, str]:
+    from ..utils import compile_cache
+
     return {
         "RANK": str(rank), "WORLD_SIZE": str(world), "LOCAL_RANK": "0",
         "MASTER_ADDR": master_addr, "MASTER_PORT": str(master_port),
         "COORDINATOR_ADDRESS": f"{master_addr}:{master_port}",
         "NUM_PROCESSES": str(world), "PROCESS_ID": str(rank),
+        # every child compiles into one persistent cache: the launcher's
+        # own if it was given one, else the checkout's (the parent stays
+        # off JAX, so it exports the variable rather than configuring)
+        compile_cache.ENV_VAR: (os.environ.get(compile_cache.ENV_VAR)
+                                or compile_cache.DEFAULT_DIR),
     }
 
 

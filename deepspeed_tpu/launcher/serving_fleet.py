@@ -7,6 +7,13 @@ same parse-one-line contract the standalone rendezvous store uses.
 The front door, ``serving bench --network``, and the chaos shard all
 launch fleets through here; chaos tests then ``kill -9`` members by
 ``pid`` and watch the router drain them.
+
+A chip belongs to one process: the first worker to touch JAX on a TPU
+host holds the chip, and a second one fails or hangs.  So a fleet of
+real engines takes ONE worker per chip, each told which chip is its own
+through ``env``; only ``synthetic`` (host-only) workers are pinned to the
+CPU, so that they never claim a chip.  The launching process stays off
+JAX.
 """
 
 from __future__ import annotations
@@ -50,6 +57,18 @@ def _worker_cmd(worker_id: str, role: str, engine: str,
     return cmd
 
 
+def _worker_env(engine: str, env: Optional[Dict[str, str]]
+                ) -> Dict[str, str]:
+    """The child's environment: the caller's, with ``env`` on top.  A
+    worker with a real engine inherits the platform as it is — defaulting
+    it to the CPU would quietly serve a chip host's traffic from the CPU."""
+    full_env = dict(os.environ)
+    if engine == "synthetic":
+        full_env.setdefault("JAX_PLATFORMS", "cpu")
+    full_env.update(env or {})
+    return full_env
+
+
 def spawn_serving_worker(worker_id: str, role: str = "mixed",
                          engine: str = "synthetic",
                          store: Optional[str] = None, port: int = 0,
@@ -57,9 +76,7 @@ def spawn_serving_worker(worker_id: str, role: str = "mixed",
                          extra_args: Optional[List[str]] = None,
                          ready_timeout_s: float = 120.0) -> WorkerProc:
     """Start one worker process and block until its readiness line."""
-    full_env = dict(os.environ)
-    full_env.setdefault("JAX_PLATFORMS", "cpu")
-    full_env.update(env or {})
+    full_env = _worker_env(engine, env)
     proc = subprocess.Popen(
         _worker_cmd(worker_id, role, engine, store, port, extra_args),
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
@@ -129,9 +146,7 @@ def launch_worker_fleet(n: int, prefill: int = 0,
               f"serving-r{i - prefill}",
               "prefill" if i < prefill else "mixed")
              for i in range(int(n))]
-    full_env = dict(os.environ)
-    full_env.setdefault("JAX_PLATFORMS", "cpu")
-    full_env.update(env or {})
+    full_env = _worker_env(engine, env)
     procs = [subprocess.Popen(
         _worker_cmd(wid, role, engine, store, 0, extra_args),
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,

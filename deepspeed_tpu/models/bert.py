@@ -208,10 +208,11 @@ class BertModel:
             # (A pad QUERY then attends only pads where the dense path
             # lets it see real keys — those rows are -100-masked in the
             # loss, and the parity test compares real rows only.)
-            from ..ops.pallas.flash_attention import flash_attention
+            from ..ops.pallas.flash_attention import flash_attention_spmd
 
-            attn = flash_attention(q, kk, vv, causal=False,
-                                   segment_ids=pad_mask.astype(jnp.int32))
+            attn = flash_attention_spmd(
+                q, kk, vv, self.mesh, causal=False,
+                segment_ids=pad_mask.astype(jnp.int32))
         else:
             scale = 1.0 / np.sqrt(c.hd)
             s = jnp.einsum("bqhd,bkhd->bhqk", q,

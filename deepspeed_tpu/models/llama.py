@@ -344,10 +344,7 @@ class LlamaModel:
         from ..parallel.mesh import strip_manual_axes
 
         stripped = strip_manual_axes(*spec)
-        from ..utils.jax_compat import abstract_mesh_or_none
-
-        am = abstract_mesh_or_none()
-        if am is not None and not am.empty:
+        if not jax.sharding.get_abstract_mesh().empty:
             # inside a (partial-manual) shard_map / set_mesh scope: a bare
             # PartitionSpec binds to the CONTEXT mesh — a concrete-mesh
             # NamedSharding would fail the context-consistency check
@@ -402,13 +399,14 @@ class LlamaModel:
             S = q.shape[1]
             W = c.sliding_window
             if c.attn_impl == "flash":
-                from ..ops.pallas.flash_attention import flash_attention
+                from ..ops.pallas.flash_attention import flash_attention_spmd
 
                 # window rides into the kernel: k-blocks wholly outside the
                 # window are skipped, so windowed work is O(S·W), not O(S²)
-                return flash_attention(q, kk, vv, True,
-                                       block_q=c.flash_block_q,
-                                       block_k=c.flash_block_k, window=W)
+                return flash_attention_spmd(q, kk, vv, self.mesh, True,
+                                            block_q=c.flash_block_q,
+                                            block_k=c.flash_block_k,
+                                            window=W)
             from ..ops.masks import local_attention_mask
 
             pos = jnp.arange(S)
@@ -488,11 +486,11 @@ class LlamaModel:
         kk = _rope(kk, positions, c.rope_theta)
         W = c.sliding_window
         if c.attn_impl == "flash":
-            from ..ops.pallas.flash_attention import flash_attention
+            from ..ops.pallas.flash_attention import flash_attention_spmd
 
-            attn = flash_attention(q, kk, vv, True,
-                                   block_q=c.flash_block_q,
-                                   block_k=c.flash_block_k, window=W)
+            attn = flash_attention_spmd(q, kk, vv, self.mesh, True,
+                                        block_q=c.flash_block_q,
+                                        block_k=c.flash_block_k, window=W)
         else:
             from ..ops.masks import local_attention_mask
 

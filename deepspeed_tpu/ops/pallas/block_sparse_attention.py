@@ -56,6 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import lattice
+from .select import (reference_off_tpu, resident_compiler_params,
+                     shape_refused)
 
 
 # ---------------------------------------------------------------------------
@@ -565,6 +567,7 @@ def _bs_fwd(q, k, v, layout_key, causal, block_q, block_k, cb, interpret):
         out_shape=[jax.ShapeDtypeStruct((B * h, S, d), q.dtype),
                    jax.ShapeDtypeStruct((B * h, S, 1), jnp.float32)],
         interpret=bool(interpret),
+        **resident_compiler_params(bool(interpret)),
     )(jnp.asarray(idx), jnp.asarray(counts), qr, kr, vr, jnp.asarray(cells))
     out = out.reshape(B, h, S, d).transpose(0, 2, 1, 3)
     return out, (q, k, v, out, lse)
@@ -1144,10 +1147,9 @@ def block_sparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     B, S, h, d = q.shape
     cb = sparsity_config.block
     layout = _norm_layout(sparsity_config.make_layout(S), h)
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return _dense_reference(q, k, v, layout, cb, causal)
-        interpret = False
+    if reference_off_tpu(interpret):
+        return _dense_reference(q, k, v, layout, cb, causal)
+    interpret = bool(interpret)
     auto = _bs_auto_block(S, cb)
     block_q = min(block_q, auto) if block_q else auto
     block_k = min(block_k, auto) if block_k else auto
@@ -1160,6 +1162,9 @@ def block_sparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     while block_k > cb and not fits(block_k):
         block_k //= 2
     if not (fits(block_q) and fits(block_k)):
+        shape_refused("block_sparse_attention", tuple(q.shape),
+                      f"no kernel block that is a multiple of the {cb}-"
+                      f"token cell divides S={S}")
         return _dense_reference(q, k, v, layout, cb, causal)
 
     # fine-celled layouts can coarsen to near-dense at kernel-block
@@ -1172,7 +1177,10 @@ def block_sparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # coarsen dense), and past _DENSE_DISPATCH_MAX_S dense cannot run.
     _, counts, _ = _plan(layout, S, block_q, block_k, cb, causal)
     live = _live_fraction(counts, S, block_q, block_k, causal)
-    if choose_impl(S, d, live, bool(interpret)) == "dense":
+    if choose_impl(S, d, live, interpret) == "dense":
+        shape_refused("block_sparse_attention", tuple(q.shape),
+                      f"live fraction {live:.2f} is above the dense "
+                      f"crossover {dense_live_threshold(S):.2f} at S={S}")
         return _dense_reference(q, k, v, layout, cb, causal)
     key = (layout.tobytes(), layout.shape, layout.dtype.str)
     _LAYOUTS[key] = layout

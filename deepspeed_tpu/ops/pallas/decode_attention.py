@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .select import reference_off_tpu, shape_refused
+
 
 def _reference_decode(q, k_cache, v_cache, lengths, window=None):
     # q: [B, h, d]; caches: [B, Smax, kv_h, d] with kv_h | h (GQA); lengths: [B]
@@ -99,24 +101,30 @@ def decode_attention(q, k_cache, v_cache, lengths, block_k: int = 128,
                      interpret: bool | None = None, window=None):
     """q ``[B, h, d]`` one-token queries over padded caches
     ``[B, Smax, kv_h, d]`` (``kv_h`` divides ``h`` — GQA groups expanded
-    inside the kernel) with per-sequence ``lengths [B]``.  ``window``
-    (Mistral sliding window) routes to the masked reference path — the
-    blocked kernel's window support (skipping pre-window blocks' DMA) is a
-    serving optimization for a later round."""
+    inside the kernel) with per-sequence ``lengths [B]``.  The kernel has
+    no ``window`` (Mistral sliding window) support, so a windowed call
+    runs the masked reference; the paged kernel, which the serving engine
+    uses, does take a window."""
     from jax.experimental import pallas as pl
 
-    if window is not None:
+    if reference_off_tpu(interpret):
         return _reference_decode(q, k_cache, v_cache, lengths, window)
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return _reference_decode(q, k_cache, v_cache, lengths)
-        interpret = False
     B, Smax, kv_h, d = k_cache.shape
     h = q.shape[1]
     n_rep = h // kv_h
     block_k = min(block_k, Smax)
-    if Smax % block_k or h % kv_h:
-        return _reference_decode(q, k_cache, v_cache, lengths)
+    refusal = None
+    if window is not None:
+        refusal = "the kernel has no sliding-window support"
+    elif Smax % block_k:
+        refusal = f"block_k={block_k} does not divide Smax={Smax}"
+    elif h % kv_h:
+        refusal = f"kv heads {kv_h} do not divide query heads {h}"
+    if refusal is not None:
+        shape_refused("decode_attention",
+                      (tuple(q.shape), tuple(k_cache.shape)), refusal)
+        return _reference_decode(q, k_cache, v_cache, lengths, window)
+    interpret = bool(interpret)
     num_blocks = Smax // block_k
 
     kernel = functools.partial(_decode_kernel, block_k=block_k,

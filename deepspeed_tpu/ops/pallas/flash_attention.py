@@ -40,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import lattice
+from .select import (reference_off_tpu, resident_compiler_params,
+                     shape_refused)
 
 
 def _mask(S, T, causal, window=None):
@@ -82,15 +84,6 @@ def _reference_fwd_with_lse(q, k, v, causal: bool, window=None,
     return jnp.einsum("bhqk,bkhd->bqhd", p, v), lse
 
 
-# kept as the module-local name older callers/tests import; the logic
-# lives in lattice.fit_block so forward/backward eligibility share it
-_flash_fit_probe = lattice.fit_block
-
-
-def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _resolve_blocks(block_q, block_k, S, d, backward=False):
     """0/None → the seq-length table; explicit values are honored (then
     shrunk to legal divisors).  The backward CAPS explicit sizes at the
@@ -102,6 +95,40 @@ def _resolve_blocks(block_q, block_k, S, d, backward=False):
     block_k = min(block_k, abk) if (block_k and backward) else (block_k
                                                                or abk)
     return lattice.fit_block(block_q, S), lattice.fit_block(block_k, S)
+
+
+def _kernel_refusal(S: int, d: int, block_q: int, block_k: int,
+                    has_seg: bool):
+    """Why the kernels cannot take this shape (None when they can) — the
+    ONE eligibility test, shared by the forward, the backward and the
+    public entry, so the three cannot disagree about which path ran."""
+    for backward in (False, True):
+        bq, bk = _resolve_blocks(block_q, block_k, S, d, backward=backward)
+        if min(bq, bk) < 64:
+            return (f"no block >= 64 divides S={S} on the 8-sublane grid "
+                    f"({'backward' if backward else 'forward'} blocks "
+                    f"{bq}x{bk})")
+    if has_seg and not lattice.resident_fits(S, d):
+        # the streamed plan is a pure position lattice; packed
+        # long-sequence streaming is not written yet
+        return (f"segment_ids ride the resident kernels only and S*d="
+                f"{S * d} exceeds lattice.RESIDENT_VMEM_ELEMS")
+    return None
+
+
+def _seg_operand(segment_ids, S: int, heads: int):
+    """(array, BlockSpec) for the resident kernels' segment-id input:
+    ``[B, 1, S]`` blocked one batch row at a time — the middle singleton
+    keeps the block's last two dims equal to the array's, which Mosaic
+    demands of any block that is not a multiple of (8, 128) — or a
+    ``[1, 1, 1]`` placeholder when the caller packs nothing."""
+    from jax.experimental import pallas as pl
+
+    if segment_ids is None:
+        return (jnp.zeros((1, 1, 1), jnp.int32),
+                pl.BlockSpec((1, 1, 1), lambda bh, i: (0, 0, 0)))
+    return (segment_ids.astype(jnp.int32)[:, None, :],
+            pl.BlockSpec((1, 1, S), lambda bh, i: (bh // heads, 0, 0)))
 
 
 # ---------------------------------------------------------------------------
@@ -121,7 +148,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, seg_ref, o_ref, lse_ref, *,
     m0 = jnp.full((block_q,), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
     acc0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    q_seg = (seg_ref[0, pl.ds(qi * block_q, block_q)] if has_seg else None)
+    q_seg = (seg_ref[0, 0, pl.ds(qi * block_q, block_q)] if has_seg else None)
 
     def body(ki, carry):
         m, l, acc = carry
@@ -129,7 +156,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, seg_ref, o_ref, lse_ref, *,
         vblk = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        k_seg = (seg_ref[0, pl.ds(ki * block_k, block_k)] if has_seg
+        k_seg = (seg_ref[0, 0, pl.ds(ki * block_k, block_k)] if has_seg
                  else None)
         keep = lattice.tile_keep(qi, ki, block_q, block_k, causal, window,
                                  q_seg, k_seg)
@@ -263,40 +290,29 @@ def _flash_call(q, k, v, causal, block_q, block_k, interpret,
     from jax.experimental import pallas as pl
 
     B, S, h, d = q.shape
-    block_q, block_k = _resolve_blocks(block_q, block_k, S, d)
-    if block_q < 64 or block_k < 64:  # degenerate shapes → dense reference
+    has_seg = segment_ids is not None
+    if _kernel_refusal(S, d, block_q, block_k, has_seg) is not None:
         out, lse = _reference_fwd_with_lse(q, k, v, causal, window,
                                            segment_ids)
         return (out, lse) if with_lse else out
+    block_q, block_k = _resolve_blocks(block_q, block_k, S, d)
     # [B, S, h, d] -> [B*h, S, d]
     qr = q.transpose(0, 2, 1, 3).reshape(B * h, S, d)
     kr = k.transpose(0, 2, 1, 3).reshape(B * h, S, d)
     vr = v.transpose(0, 2, 1, 3).reshape(B * h, S, d)
 
-    stream = force_stream or not lattice.resident_fits(S, d)
-    if stream and segment_ids is None:
+    if (force_stream or not lattice.resident_fits(S, d)) and not has_seg:
         out, lse = _flash_fwd_stream(qr, kr, vr, causal, block_q, block_k,
                                      window, interpret)
         out = out.reshape(B, h, S, d).transpose(0, 2, 1, 3)
         lse = lse.reshape(B, h, S)
         return (out, lse) if with_lse else out
-    # segments ride the resident kernel only (the streamed plan is a
-    # pure position lattice); beyond residency they fall back dense —
-    # packed long-sequence streaming is a later round
-    has_seg = segment_ids is not None
-    if stream and has_seg:
-        out, lse = _reference_fwd_with_lse(q, k, v, causal, window,
-                                           segment_ids)
-        return (out, lse) if with_lse else out
-    seg = (segment_ids.astype(jnp.int32) if has_seg
-           else jnp.zeros((B, 1), jnp.int32))
-    heads = h
+    seg, seg_spec = _seg_operand(segment_ids, S, h)
 
     kernel = functools.partial(
         _fa_kernel, block_q=block_q, block_k=block_k, seq_len=S,
         causal=causal, scale=1.0 / np.sqrt(d), window=window,
         has_seg=has_seg)
-    seg_block = (1, S) if has_seg else (1, 1)
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * h, S // block_q),
@@ -304,8 +320,7 @@ def _flash_call(q, k, v, causal, block_q, block_k, interpret,
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, S, d), lambda bh, qi: (bh, 0, 0)),
             pl.BlockSpec((1, S, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec(seg_block,
-                         lambda bh, qi: (bh // heads, 0)),
+            seg_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
@@ -318,6 +333,7 @@ def _flash_call(q, k, v, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((B * h, S, 1), jnp.float32),
         ],
         interpret=interpret,
+        **resident_compiler_params(interpret),
     )(qr, kr, vr, seg)
     out = out.reshape(B, h, S, d).transpose(0, 2, 1, 3)
     lse = lse.reshape(B, h, S)  # drops the singleton
@@ -344,14 +360,14 @@ def _fa_bwd_dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
     do = do_ref[0].astype(jnp.float32)
     lse = lse_ref[0, :, 0]                             # [bq]
     delta = delta_ref[0, :, 0]
-    q_seg = (seg_ref[0, pl.ds(qi * block_q, block_q)] if has_seg else None)
+    q_seg = (seg_ref[0, 0, pl.ds(qi * block_q, block_q)] if has_seg else None)
 
     def body(ki, acc):
         kblk = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         vblk = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        k_seg = (seg_ref[0, pl.ds(ki * block_k, block_k)] if has_seg
+        k_seg = (seg_ref[0, 0, pl.ds(ki * block_k, block_k)] if has_seg
                  else None)
         keep = lattice.tile_keep(qi, ki, block_q, block_k, causal, window,
                                  q_seg, k_seg)
@@ -386,7 +402,7 @@ def _fa_bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
     nq = seq_len // block_q
     kblk = k_ref[0].astype(jnp.float32)                # [bk, d]
     vblk = v_ref[0].astype(jnp.float32)
-    k_seg = (seg_ref[0, pl.ds(ki * block_k, block_k)] if has_seg else None)
+    k_seg = (seg_ref[0, 0, pl.ds(ki * block_k, block_k)] if has_seg else None)
 
     def body(qi, carry):
         dk_acc, dv_acc = carry
@@ -396,7 +412,7 @@ def _fa_bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
         delta = delta_ref[0, pl.ds(qi * block_q, block_q), 0]
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        q_seg = (seg_ref[0, pl.ds(qi * block_q, block_q)] if has_seg
+        q_seg = (seg_ref[0, 0, pl.ds(qi * block_q, block_q)] if has_seg
                  else None)
         keep = lattice.tile_keep(qi, ki, block_q, block_k, causal, window,
                                  q_seg, k_seg)
@@ -445,10 +461,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, block_q, block_k,
     delta_r = delta.transpose(0, 2, 1).reshape(B * h, S, 1)
     scale = 1.0 / np.sqrt(d)
     has_seg = segment_ids is not None
-    seg = (segment_ids.astype(jnp.int32) if has_seg
-           else jnp.zeros((B, 1), jnp.int32))
-    seg_block = (1, S) if has_seg else (1, 1)
-    heads = h
+    seg, seg_spec = _seg_operand(segment_ids, S, h)
 
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, block_q=block_q,
@@ -462,11 +475,12 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, block_q, block_k,
             pl.BlockSpec((1, S, d), lambda bh, qi: (bh, 0, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec(seg_block, lambda bh, qi: (bh // heads, 0)),
+            seg_spec,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * h, S, d), q.dtype),
         interpret=interpret,
+        **resident_compiler_params(interpret),
     )(qr, dor, kr, vr, lse_r, delta_r, seg)
 
     dk, dv = pl.pallas_call(
@@ -481,7 +495,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, S, 1), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, S, 1), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec(seg_block, lambda bh, ki: (bh // heads, 0)),
+            seg_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
@@ -490,6 +504,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, block_q, block_k,
         out_shape=[jax.ShapeDtypeStruct((B * h, S, d), k.dtype),
                    jax.ShapeDtypeStruct((B * h, S, d), v.dtype)],
         interpret=interpret,
+        **resident_compiler_params(interpret),
     )(qr, dor, kr, vr, lse_r, delta_r, seg)
 
     back = lambda a: a.reshape(B, h, S, d).transpose(0, 2, 1, 3)
@@ -695,52 +710,55 @@ def _flash_bwd_stream(q, k, v, out, lse, do, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, seg, causal, block_q, block_k, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, seg, causal, block_q, block_k, window, impl):
     return _flash_inner_fwd(q, k, v, seg, causal, block_q, block_k,
-                            window)[0]
+                            window, impl)[0]
 
 
-def _flash_inner_fwd(q, k, v, seg, causal, block_q, block_k, window):
-    segment_ids = seg if seg is not None and seg.ndim == 2 \
-        and seg.shape[1] == q.shape[1] else None
-    if _use_pallas():
-        out, lse = _flash_call(q, k, v, causal, block_q, block_k,
-                               interpret=False, with_lse=True,
-                               window=window, segment_ids=segment_ids)
-    else:
+def _segments(seg, q):
+    """The ``[B, S]`` segment ids, or None for the ``[B, 1]`` placeholder
+    the custom_vjp carries when the caller passed none."""
+    return seg if seg.ndim == 2 and seg.shape[1] == q.shape[1] else None
+
+
+def _flash_inner_fwd(q, k, v, seg, causal, block_q, block_k, window, impl):
+    segment_ids = _segments(seg, q)
+    if impl == "reference":
         out, lse = _reference_fwd_with_lse(q, k, v, causal, window,
                                            segment_ids)
+    else:
+        out, lse = _flash_call(q, k, v, causal, block_q, block_k,
+                               interpret=impl == "interpret", with_lse=True,
+                               window=window, segment_ids=segment_ids)
     return out, (q, k, v, seg, out, lse)
 
 
-def _flash_inner_bwd(causal, block_q, block_k, window, res, do):
-    """Backward dispatch: resident Pallas kernels while the planes fit
-    VMEM, streamed kernels beyond, jnp chunked scan off-TPU.
+def _flash_inner_bwd(causal, block_q, block_k, window, impl, res, do):
+    """Backward of whatever the forward ran (``impl`` was fixed by
+    :func:`flash_attention`): resident Pallas kernels while the planes
+    fit VMEM, streamed kernels beyond, a jnp chunked scan for the
+    reference.
 
     Uses the saved per-row log-sum-exp (no softmax re-normalization pass)
     and ``delta_i = Σ_d do_i·o_i`` so the softmax jacobian term needs no
     cross-block reduction."""
     q, k, v, seg, out, lse = res
-    segment_ids = seg if seg is not None and seg.ndim == 2 \
-        and seg.shape[1] == q.shape[1] else None
+    segment_ids = _segments(seg, q)
     B, S, h, d = q.shape
-    bq, bk = _resolve_blocks(block_q, block_k, S, d, backward=True)
     dseg = np.zeros(seg.shape, dtype=jax.dtypes.float0)
-    kernel_ok = _use_pallas() and S % 64 == 0 and min(bq, bk) >= 64
-    # segments ride the resident kernels only (mirrors the forward)
-    if kernel_ok and segment_ids is not None \
-            and not lattice.resident_fits(S, d):
-        kernel_ok = False
-    if kernel_ok:
+    if impl != "reference":
+        interpret = impl == "interpret"
         if lattice.resident_fits(S, d):
             dq, dk, dv = _flash_bwd_pallas(
                 q, k, v, out, lse, do, causal, block_q, block_k, window,
-                segment_ids=segment_ids)
+                interpret=interpret, segment_ids=segment_ids)
         else:
             dq, dk, dv = _flash_bwd_stream(
-                q, k, v, out, lse, do, causal, block_q, block_k, window)
+                q, k, v, out, lse, do, causal, block_q, block_k, window,
+                interpret=interpret)
         return dq, dk, dv, dseg
+    _, bk = _resolve_blocks(block_q, block_k, S, d, backward=True)
     scale = 1.0 / np.sqrt(d)
     blk = min(bk if bk >= 1 else S, S)
     while blk > 1 and S % blk:  # shrink to a divisor (matches _flash_call)
@@ -797,28 +815,82 @@ _flash.defvjp(_flash_inner_fwd, _flash_inner_bwd)
 
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = 0, block_k: int = 0,
-                    window=None, segment_ids=None):
-    """[B, S, h, d] attention; Pallas on TPU, jnp reference elsewhere.
+                    window=None, segment_ids=None,
+                    interpret: bool | None = None):
+    """[B, S, h, d] attention, forward and backward.
 
     ``block_q``/``block_k`` 0 → the seq-length-aware table
     (:func:`lattice.auto_flash_blocks`; forward and backward resolve
     independently).  ``window`` = sliding-window reach (ops/masks
     semantics); k-blocks wholly outside the lattice are skipped.
     ``segment_ids [B, S]`` masks cross-segment pairs (packed sequences,
-    padding) on the resident kernels and all reference paths."""
-    B, S = q.shape[0], q.shape[1]
+    padding) on the resident kernels and all reference paths.
+
+    ``interpret`` follows :mod:`.select`: None → the compiled kernels on
+    a TPU and the jnp reference elsewhere.  The choice is made HERE, once,
+    for both passes; a shape the kernels refuse runs the reference and
+    says so on a TPU."""
+    B, S, h, d = q.shape
     seg = (segment_ids.astype(jnp.int32) if segment_ids is not None
            else jnp.zeros((B, 1), jnp.int32))
-    return _flash(q, k, v, seg, causal, int(block_q or 0),
-                  int(block_k or 0), window)
+    block_q, block_k = int(block_q or 0), int(block_k or 0)
+    if reference_off_tpu(interpret):
+        impl = "reference"
+    else:
+        refusal = _kernel_refusal(S, d, block_q, block_k,
+                                  segment_ids is not None)
+        if refusal is not None:
+            shape_refused("flash_attention", tuple(q.shape), refusal)
+            impl = "reference"
+        else:
+            impl = "interpret" if interpret else "kernel"
+    return _flash(q, k, v, seg, causal, block_q, block_k, window, impl)
 
 
 def flash_attention_interpret(q, k, v, causal: bool = True,
                               block_q: int = 64, block_k: int = 64,
                               window=None, segment_ids=None,
                               stream: bool = False):
-    """Interpreter-mode kernel run (CPU numerics testing); ``stream=True``
-    forces the long-S gather kernels regardless of residency."""
+    """Interpreter-mode FORWARD kernel run (CPU numerics testing);
+    ``stream=True`` forces the long-S gather kernels regardless of
+    residency."""
     return _flash_call(q, k, v, causal, block_q, block_k, interpret=True,
                        window=window, segment_ids=segment_ids,
                        force_stream=stream)
+
+
+def flash_attention_spmd(q, k, v, mesh, causal: bool = True,
+                         block_q: int = 0, block_k: int = 0, window=None,
+                         segment_ids=None):
+    """:func:`flash_attention` on a mesh.  GSPMD cannot partition a Mosaic
+    call, and jax lowers one only where EVERY mesh axis is manual
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap the
+    call in a shard_map"), so the partitioning is explicit: a
+    ``shard_map`` over all axes that are not manual already (an enclosing
+    Ulysses, pipeline or ZeRO-3 region keeps its own), with batch rows
+    split over the data-parallel axes and heads over ``tensor`` — both
+    independent in attention, so no communication."""
+    from ...parallel.mesh import AXIS_TENSOR, DP_AXES
+    from ...utils.jax_compat import current_manual_axes, shard_map
+
+    def local(ql, kl, vl, seg):
+        return flash_attention(ql, kl, vl, causal, block_q=block_q,
+                               block_k=block_k, window=window,
+                               segment_ids=seg)
+
+    if mesh is None:
+        return local(q, k, v, segment_ids)
+    manual = current_manual_axes()
+    axes = set(mesh.axis_names) - manual
+    if not axes:
+        return local(q, k, v, segment_ids)
+    P = jax.sharding.PartitionSpec
+    batch = tuple(a for a in DP_AXES if a in axes) or None
+    qkv = P(batch, None, AXIS_TENSOR if AXIS_TENSOR in axes else None, None)
+    ctx = jax.sharding.get_abstract_mesh()
+    return shard_map(
+        local, mesh=mesh if ctx.empty else ctx,
+        in_specs=(qkv, qkv, qkv,
+                  None if segment_ids is None else P(batch, None)),
+        out_specs=qkv, axis_names=axes, check_vma=False)(q, k, v,
+                                                         segment_ids)

@@ -28,8 +28,10 @@ contracts the same way).  The parity tests in
 ``tests/unit/ops/test_fused_optimizer.py`` lock exactly this contract,
 so an engine can flip ``kernels.fused_adam`` on without perturbing a
 loss curve.
-``interpret`` mode (CPU) lowers the same kernels through the Pallas
-interpreter, keeping parity testable without a chip.
+``interpret=True`` lowers the same kernels through the Pallas
+interpreter, keeping parity testable without a chip; with ``interpret``
+left open the entry points follow :mod:`.select` — compiled kernel on a
+TPU, :func:`reference_adam_tree` / a plain ``jnp.sum`` elsewhere.
 """
 
 from __future__ import annotations
@@ -41,12 +43,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .select import reference_off_tpu
+
 #: kernel tile: (rows, 128) fp32 — rows per grid step.  64 rows × 128
 #: lanes × 4 B = 32 KiB per plane per step; 7 resident planes ≈ 224 KiB,
 #: comfortably double-buffered in VMEM.
 _LANES = 128
 _ROWS = 64
-_CHUNK = _ROWS * _LANES
+#: the grad-norm read moves one plane, so its tile is larger: each grid
+#: step leaves an (8, 128) partial (the smallest fp32 block Mosaic
+#: stores), 1/128 of what it read
+_SQ_ROWS = 1024
 
 
 class FusedAdamConfig(NamedTuple):
@@ -63,15 +70,13 @@ class FusedAdamConfig(NamedTuple):
     decoupled_wd: bool = True
 
 
-def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _pad_flat(x: jnp.ndarray) -> Tuple[jnp.ndarray, int]:
-    """Flatten to [rows, 128] fp32-tileable form, zero-padded."""
+def _pad_flat(x: jnp.ndarray, rows: int = _ROWS
+              ) -> Tuple[jnp.ndarray, int]:
+    """Flatten to [k·rows, 128] tileable form, zero-padded."""
     flat = x.reshape(-1)
     n = flat.shape[0]
-    padded = -(-n // _CHUNK) * _CHUNK
+    chunk = rows * _LANES
+    padded = -(-n // chunk) * chunk
     if padded != n:
         flat = jnp.concatenate(
             [flat, jnp.zeros((padded - n,), flat.dtype)])
@@ -85,25 +90,28 @@ def _pad_flat(x: jnp.ndarray) -> Tuple[jnp.ndarray, int]:
 
 def _sqsum_kernel(g_ref, out_ref):
     g = g_ref[...].astype(jnp.float32)
-    out_ref[0, 0] = jnp.sum(g * g)
+    # fold the tile's sublane groups onto one (8, 128) vreg-shaped partial
+    # (VPU adds only; the cross-lane reduce happens once, outside)
+    out_ref[0] = jnp.sum((g * g).reshape(-1, 8, _LANES), axis=0)
 
 
 def leaf_sqsum(g: jnp.ndarray, interpret: Optional[bool] = None
                ) -> jnp.ndarray:
     """Σ g² of one leaf via the Pallas reduction kernel — one HBM read,
-    per-tile partials summed on the host graph."""
+    per-tile partials summed in the surrounding graph."""
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = not _use_pallas()
-    rows2d, _ = _pad_flat(g)
-    steps = rows2d.shape[0] // _ROWS
+    if reference_off_tpu(interpret):
+        g32 = g.astype(jnp.float32)
+        return jnp.sum(g32 * g32)
+    rows2d, _ = _pad_flat(g, _SQ_ROWS)
+    steps = rows2d.shape[0] // _SQ_ROWS
     partials = pl.pallas_call(
         _sqsum_kernel,
         grid=(steps,),
-        in_specs=[pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((steps, 1), jnp.float32),
+        in_specs=[pl.BlockSpec((_SQ_ROWS, _LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((1, 8, _LANES), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps, 8, _LANES), jnp.float32),
         interpret=bool(interpret),
     )(rows2d)
     return jnp.sum(partials)
@@ -158,11 +166,11 @@ def _adam_kernel(sc_ref, p_ref, g_ref, m_ref, v_ref, po_ref, mo_ref,
 def fused_adam_leaf(p, g, m, v, lr, mult, bc1, bc2,
                     cfg: FusedAdamConfig,
                     interpret: Optional[bool] = None):
-    """One leaf through the fused kernel → (p_new, m_new, v_new)."""
+    """One leaf through the fused kernel → (p_new, m_new, v_new); the
+    kernel always (compiled unless ``interpret``) — the reference choice
+    is :func:`fused_adam_tree`'s."""
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = not _use_pallas()
     shape, dtype = p.shape, p.dtype
     p2, n = _pad_flat(p)
     g2, _ = _pad_flat(g)
@@ -219,6 +227,9 @@ def fused_adam_tree(params: Any, grads: Any, mu: Any, nu: Any,
     per-element gradient multiplier (loss-scale unscale × clip factor ×
     overflow zero) the engine folds in so no separate unscale/clip
     sweeps exist."""
+    if reference_off_tpu(interpret):
+        return reference_adam_tree(params, grads, mu, nu, count_inc, lr,
+                                   mult, cfg)
     # bias corrections once per step (optax: 1 - decay**count_inc)
     cf = count_inc
     bc1 = 1.0 - jnp.asarray(cfg.b1, jnp.float32) ** cf
@@ -333,15 +344,17 @@ def reference_adam_tree(params, grads, mu, nu, count_inc, lr, mult=1.0,
     bc2 = 1.0 - jnp.asarray(b2, jnp.float32) ** count_inc
 
     def leaf(p, g, m, v):
+        p32 = p.astype(jnp.float32)
         g = g.astype(jnp.float32) * mult
         if wd and not cfg.decoupled_wd:
-            g = g + wd * p
-        m_new = (1.0 - b1) * g + b1 * m
-        v_new = (1.0 - b2) * (g * g) + b2 * v
+            g = g + wd * p32
+        m_new = (1.0 - b1) * g + b1 * m.astype(jnp.float32)
+        v_new = (1.0 - b2) * (g * g) + b2 * v.astype(jnp.float32)
         direction = (m_new / bc1) / (jnp.sqrt(v_new / bc2) + eps)
         if wd and cfg.decoupled_wd:
-            direction = direction + wd * p
-        return p + (-lr) * direction, m_new, v_new
+            direction = direction + wd * p32
+        return ((p32 + (-lr) * direction).astype(p.dtype),
+                m_new.astype(m.dtype), v_new.astype(v.dtype))
 
     trees = [jax.tree.map(lambda *xs, i=i: leaf(*xs)[i], params, grads,
                           mu, nu) for i in range(3)]

@@ -21,10 +21,11 @@ Three rungs share these index semantics:
 * ``*_reference`` — jnp ``take``-based, fully differentiable (``take``'s
   transpose is the scatter-add), GSPMD-friendly: this is what runs under an
   expert-sharded mesh, where the gather IS the all-to-all boundary.
-* ``pallas_dispatch`` / ``pallas_combine`` — Pallas kernels riding
-  ``PrefetchScalarGridSpec``: the index array is scalar-prefetched to SMEM
-  and drives per-row dynamic-slice loads from a VMEM-resident token /
-  expert-output block.  Forward-only kernels with a ``custom_vjp`` whose
+* ``pallas_dispatch`` / ``pallas_combine`` — one Pallas row-gather kernel
+  riding ``PrefetchScalarGridSpec``: the index array is scalar-prefetched
+  to SMEM and drives one DMA per row from the HBM-resident token /
+  expert-output matrix into the output window (combine leaves its
+  K-way weighted sum to XLA).  Forward-only, with a ``custom_vjp`` whose
   backward is the jnp reference (indices are routing decisions — integer,
   non-differentiable — so both paths share one backward).
 * ``choose_dispatch_impl`` — the auto crossover: tiny T·E·C keeps the dense
@@ -44,6 +45,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from .select import reference_off_tpu, shape_refused
 
 #: src_idx value marking an unfilled expert slot
 EMPTY_SLOT = -1
@@ -70,9 +73,6 @@ def set_crossover_scale(scale: float) -> None:
 def dense_crossover_tec() -> int:
     """The calibrated T·E·C crossover the auto impl compares against."""
     return max(int(DENSE_CROSSOVER_TEC * _CROSSOVER_SCALE), 1)
-
-#: pallas combine tiles tokens in blocks of this many rows
-_COMBINE_BLOCK_T = 128
 
 
 # ---------------------------------------------------------------------------
@@ -145,114 +145,147 @@ def combine_reference(expert_out: jnp.ndarray, flat_idx: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# pallas kernels (forward) — index-driven row gathers
+# pallas kernel (forward) — one index-driven row gather serves both verbs
 # ---------------------------------------------------------------------------
 
-def _dispatch_kernel(src_ref, tokens_ref, out_ref):
-    """grid=(E,): fill one expert's ``[1, C, H]`` buffer by gathering rows
-    of the VMEM-resident token block at scalar-prefetched indices."""
-    from jax.experimental import pallas as pl
-
-    e = pl.program_id(0)
-    C = out_ref.shape[1]
-
-    def body(c, _):
-        idx = src_ref[e, c]
-        safe = jnp.maximum(idx, 0)
-        row = pl.load(tokens_ref, (pl.dslice(safe, 1), slice(None)))
-        row = jnp.where(idx >= 0, row, jnp.zeros_like(row))
-        pl.store(out_ref, (pl.dslice(0, 1), pl.dslice(c, 1), slice(None)),
-                 row[None])
-        return _
-
-    jax.lax.fori_loop(0, C, body, 0)
+#: rows gathered per grid step (the output window is [_GATHER_ROWS, H])
+_GATHER_ROWS = 128
+_LANES = 128
 
 
-def _combine_kernel(idx_ref, out_flat_ref, gates_ref, y_ref):
-    """grid=(T/BT,): one token block's ``y[t] = Σ_k g·out[idx]`` with the
-    flattened expert output resident in VMEM (pad row at E·C)."""
-    from jax.experimental import pallas as pl
+def _gather_kernel(idx_ref, src_ref, out_ref, sem):
+    """grid=(G, M/BM): fill one ``[1, BM, W/128, 128]`` output window with
+    rows of the HBM-resident source, one DMA per row at the
+    scalar-prefetched index; a negative index leaves a zero row.  Nothing
+    but the window lives in VMEM, so T and E·C are unbounded.
 
-    t0 = pl.program_id(0) * y_ref.shape[0]
-    BT = y_ref.shape[0]
-    K = gates_ref.shape[1]
-
-    def body(r, _):
-        acc = jnp.zeros((1, y_ref.shape[1]), jnp.float32)
-        for k in range(K):
-            idx = idx_ref[t0 + r, k]
-            row = pl.load(out_flat_ref, (pl.dslice(idx, 1), slice(None)))
-            gk = pl.load(gates_ref, (pl.dslice(r, 1), pl.dslice(k, 1)))
-            acc = acc + gk.astype(jnp.float32) * row.astype(jnp.float32)
-        pl.store(y_ref, (pl.dslice(r, 1), slice(None)),
-                 acc.astype(y_ref.dtype))
-        return _
-
-    jax.lax.fori_loop(0, BT, body, 0)
-
-
-def _pallas_dispatch_fwd(tokens: jnp.ndarray, src_idx: jnp.ndarray,
-                         interpret: bool) -> jnp.ndarray:
+    Two refusals of Mosaic (jax 0.9.0) shape the layout.  Rows are 32-bit
+    words (:func:`_to_words`): a single-row dynamic slice of a packed
+    dtype fails with "cannot statically prove that index in dimension 0
+    is a multiple of 8".  And a row is a ``[W/128, 128]`` slab indexed on
+    a LEADING dim: one row of a 2-D ``[N, W]`` array cuts the (8, 128)
+    tiling ("Slice shape along dimension 0 must be aligned to tiling
+    (8), but is 1")."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    T, H = tokens.shape
-    E, C = src_idx.shape
+    g = pl.program_id(0)
+    BM = out_ref.shape[1]
+    base = pl.program_id(1) * BM
+
+    def row_copy(r, idx):
+        return pltpu.make_async_copy(src_ref.at[idx], out_ref.at[0, r], sem)
+
+    def start(r, carry):
+        idx = idx_ref[g, base + r]
+
+        @pl.when(idx >= 0)
+        def _():
+            row_copy(r, idx).start()
+
+        @pl.when(idx < 0)
+        def _():
+            out_ref[0, r] = jnp.zeros(out_ref.shape[2:], out_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, BM, start, 0)
+
+    def wait(r, carry):
+        idx = idx_ref[g, base + r]
+
+        @pl.when(idx >= 0)
+        def _():
+            # every row copy moves the same bytes on the one semaphore,
+            # so waiting once per started copy drains them all
+            row_copy(r, idx).wait()
+
+        return carry
+
+    jax.lax.fori_loop(0, BM, wait, 0)
+
+
+def _to_words(x: jnp.ndarray) -> jnp.ndarray:
+    """``[N, H]`` of a 1/2/4-byte dtype → ``[N, H·itemsize/4]`` uint32."""
+    pack = 4 // x.dtype.itemsize
+    if pack == 1:
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+    N, H = x.shape
+    return jax.lax.bitcast_convert_type(x.reshape(N, H // pack, pack),
+                                        jnp.uint32)
+
+
+def _from_words(w: jnp.ndarray, dtype) -> jnp.ndarray:
+    out = jax.lax.bitcast_convert_type(w, dtype)
+    return out.reshape(*w.shape[:-1], -1) if out.ndim > w.ndim else out
+
+
+def gather_refusal(H: int, dtype) -> Optional[str]:
+    """Why the gather kernel cannot move rows of this width (None when it
+    can): a row must be a whole number of 128-lane 32-bit vectors."""
+    bits = H * jnp.dtype(dtype).itemsize * 8
+    if jnp.dtype(dtype).itemsize > 4 or bits % (32 * _LANES):
+        return (f"rows of {H} x {jnp.dtype(dtype).name} are not a multiple "
+                f"of 128 32-bit lanes")
+    return None
+
+
+def _gather_rows(src: jnp.ndarray, idx: jnp.ndarray, interpret: bool
+                 ) -> jnp.ndarray:
+    """``src [N, H]``, ``idx [G, M]`` → ``[G, M, H]`` with
+    ``out[g, m] = src[idx[g, m]]`` (zeros where ``idx < 0``)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, M = idx.shape
+    words = _to_words(src)
+    N, W = words.shape
+    # interpret-mode tests use rows narrower than a lane vector
+    lanes = _LANES if W % _LANES == 0 else W
+    slab = (W // lanes, lanes)
+    BM = min(_GATHER_ROWS, M)
+    Mp = -(-M // BM) * BM
+    idx_p = jnp.pad(idx.astype(jnp.int32), ((0, 0), (0, Mp - M)),
+                    constant_values=EMPTY_SLOT)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(E,),
-        in_specs=[pl.BlockSpec((T, H), lambda e, src: (0, 0))],
-        out_specs=pl.BlockSpec((1, C, H), lambda e, src: (e, 0, 0)),
+        grid=(G, Mp // BM),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, BM) + slab,
+                               lambda g, m, idx: (g, m, 0, 0)),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
-        _dispatch_kernel,
+    out = pl.pallas_call(
+        _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((E, C, H), tokens.dtype),
+        out_shape=jax.ShapeDtypeStruct((G, Mp) + slab, jnp.uint32),
         interpret=interpret,
-    )(src_idx, tokens)
+    )(idx_p, words.reshape((N,) + slab))
+    return _from_words(out[:, :M].reshape(G, M, W), src.dtype)
 
 
 def _pallas_combine_fwd(expert_out: jnp.ndarray, flat_idx: jnp.ndarray,
                         gates: jnp.ndarray, interpret: bool) -> jnp.ndarray:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    """The gather kernel picks each token's K expert rows; the weighted
+    sum over K is left to XLA, which fuses it into one pass."""
     E, C, H = expert_out.shape
-    T, K = flat_idx.shape
-    BT = min(_COMBINE_BLOCK_T, T)
-    pad_T = (-T) % BT
-    flat = jnp.concatenate(
-        [expert_out.reshape(E * C, H),
-         jnp.zeros((1, H), expert_out.dtype)], axis=0)
-    gates_p = jnp.pad(gates, ((0, pad_T), (0, 0)))
-    idx_p = jnp.pad(flat_idx, ((0, pad_T), (0, 0)),
-                    constant_values=E * C)
-    Tp = T + pad_T
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Tp // BT,),
-        in_specs=[pl.BlockSpec((E * C + 1, H), lambda i, idx: (0, 0)),
-                  pl.BlockSpec((BT, K), lambda i, idx: (i, 0))],
-        out_specs=pl.BlockSpec((BT, H), lambda i, idx: (i, 0)),
-    )
-    y = pl.pallas_call(
-        _combine_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tp, H), expert_out.dtype),
-        interpret=interpret,
-    )(idx_p, flat, gates_p)
-    return y[:T]
+    valid = flat_idx < E * C
+    picked = _gather_rows(expert_out.reshape(E * C, H),
+                          jnp.where(valid, flat_idx, EMPTY_SLOT).T,
+                          interpret)                       # [K, T, H]
+    w = jnp.where(valid, gates, 0.0).T[..., None].astype(expert_out.dtype)
+    return jnp.sum(w * picked, axis=0)
 
 
 # -- custom_vjp wrappers: pallas forward, jnp-reference backward -----------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _pallas_dispatch(tokens, src_idx, interpret):
-    return _pallas_dispatch_fwd(tokens, src_idx, interpret)
+    return _gather_rows(tokens, src_idx, interpret)
 
 
 def _pallas_dispatch_vjp_fwd(tokens, src_idx, interpret):
-    return _pallas_dispatch_fwd(tokens, src_idx, interpret), \
+    return _gather_rows(tokens, src_idx, interpret), \
         (tokens.shape, src_idx)
 
 
@@ -303,30 +336,38 @@ _pallas_combine.defvjp(_pallas_combine_vjp_fwd, _pallas_combine_vjp_bwd)
 # public entry points
 # ---------------------------------------------------------------------------
 
+def _runs_reference(kernel: str, rows: jnp.ndarray,
+                    interpret: Optional[bool]) -> bool:
+    """:mod:`.select`'s choice for a gather over ``rows [..., H]``: the
+    reference off the TPU, and (said once) for a row width the compiled
+    kernel refuses — the interpreter takes any width."""
+    if reference_off_tpu(interpret):
+        return True
+    refusal = (None if interpret
+               else gather_refusal(rows.shape[-1], rows.dtype))
+    if refusal is not None:
+        shape_refused(kernel, tuple(rows.shape), refusal)
+    return refusal is not None
+
+
 def pallas_dispatch(tokens: jnp.ndarray, src_idx: jnp.ndarray,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """Pallas token dispatch: ``tokens [T, H]`` + ``src_idx [E, C]`` →
-    ``[E, C, H]``.  Off-TPU (``interpret=None``) falls back to the jnp
-    reference; ``interpret=True`` forces the kernel in interpret mode
-    (the parity harness)."""
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return dispatch_reference(tokens, src_idx)
-        interpret = False
-    return _pallas_dispatch(tokens, src_idx, interpret)
+    ``[E, C, H]``.  ``interpret`` follows :mod:`.select`; a row width the
+    gather kernel refuses runs :func:`dispatch_reference`."""
+    if _runs_reference("moe_dispatch", tokens, interpret):
+        return dispatch_reference(tokens, src_idx)
+    return _pallas_dispatch(tokens, src_idx, bool(interpret))
 
 
 def pallas_combine(expert_out: jnp.ndarray, flat_idx: jnp.ndarray,
                    gates: jnp.ndarray,
                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """Pallas token combine: ``expert_out [E, C, H]`` + ``flat_idx/gates
-    [T, K]`` → ``y [T, H]``.  Fallback semantics mirror
-    :func:`pallas_dispatch`."""
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return combine_reference(expert_out, flat_idx, gates)
-        interpret = False
-    return _pallas_combine(expert_out, flat_idx, gates, interpret)
+    [T, K]`` → ``y [T, H]``.  Selection mirrors :func:`pallas_dispatch`."""
+    if _runs_reference("moe_combine", expert_out, interpret):
+        return combine_reference(expert_out, flat_idx, gates)
+    return _pallas_combine(expert_out, flat_idx, gates, bool(interpret))
 
 
 def dispatch_scratch_bytes(num_experts: int, capacity: int, hidden: int,

@@ -23,7 +23,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
 from ...utils.jax_compat import shard_map as _shard_map
+from .select import reference_off_tpu, shape_refused
 
 
 def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
@@ -113,6 +115,17 @@ def _paged_kernel(len_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
                     / jnp.maximum(l_ref[0], 1e-9)[:, None]).astype(o_ref.dtype)
 
 
+def paged_decode_impl(num_heads: int, kv_heads: int,
+                      interpret: bool | None = None) -> str:
+    """Which path :func:`paged_decode_attention` takes for these head
+    counts: ``"pallas"``, ``"pallas_interpret"`` or ``"reference"`` — the
+    serving engine records it (``last_attn_path``) from the same test the
+    entry point decides by."""
+    if reference_off_tpu(interpret) or num_heads % kv_heads:
+        return "reference"
+    return "pallas_interpret" if interpret else "pallas"
+
+
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            interpret: bool | None = None, window=None):
     """One-token queries ``q [B, h, d]`` over a shared paged KV pool
@@ -123,18 +136,18 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     dead-page work is done."""
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return paged_decode_reference(q, k_pool, v_pool, block_tables,
-                                          lengths, window)
-        interpret = False
     B, h, d = q.shape
     _, block_size, kv_h, _ = k_pool.shape
     max_blocks = block_tables.shape[1]
     n_rep = h // kv_h
-    if h % kv_h:
+    if paged_decode_impl(h, kv_h, interpret) == "reference":
+        if h % kv_h:
+            shape_refused("paged_decode_attention",
+                          (tuple(q.shape), tuple(k_pool.shape)),
+                          f"kv heads {kv_h} do not divide query heads {h}")
         return paged_decode_reference(q, k_pool, v_pool, block_tables,
                                       lengths, window)
+    interpret = bool(interpret)
 
     kernel = functools.partial(_paged_kernel, block_size=block_size,
                                num_blocks=max_blocks,
@@ -183,8 +196,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, lengths,
                               mesh, window=None):
     """TENSOR-PARALLEL paged decode: the Pallas kernel itself is not
-    GSPMD-partitionable (custom call), so the partitioning is explicit —
-    a ``shard_map`` over the ``tensor`` mesh axis on the HEAD dims.
+    GSPMD-partitionable (custom call, and jax lowers it only where every
+    mesh axis is manual), so the partitioning is explicit — a
+    ``shard_map`` over the whole mesh that splits the HEAD dims over the
+    ``tensor`` axis and replicates over the rest.
     Attention heads are independent, so each TP rank runs the kernel on
     its local ``h/tp`` query heads against its local ``kv_h/tp`` pool
     slice with NO cross-rank communication; block tables and lengths are
@@ -208,5 +223,5 @@ def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, lengths,
                   P(None, None, AXIS_TENSOR, None), P(), P()),
         out_specs=P(None, AXIS_TENSOR, None),
         check_vma=False,
-        axis_names={AXIS_TENSOR})(q, k_pool, v_pool,
-                                  block_tables, lengths)
+        axis_names=set(mesh.axis_names))(q, k_pool, v_pool,
+                                         block_tables, lengths)

@@ -18,6 +18,15 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from .select import reference_off_tpu, shape_refused
+
+#: fp32 bytes one grid step may hold per plane: the kernel works on the
+#: block upcast to fp32 and Pallas double-buffers it, under Mosaic's
+#: default 16 MiB of scoped VMEM
+_BLOCK_BYTES = 2 * 1024 * 1024
+#: int8 packs 32 rows to a sublane tile — the row-block granule
+_ROW_GRANULE = 32
+
 
 def _ref_quantize(x2d):
     amax = jnp.max(jnp.abs(x2d), axis=-1, keepdims=True)
@@ -40,21 +49,27 @@ def quantize_int8(x: jnp.ndarray, block_rows: int = 256,
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Symmetric per-row int8 quantization of a 2D ``[R, C]`` array →
     ``(int8 [R, C], scales f32 [R])``.  Higher-rank inputs are flattened to
-    rows of the last dim."""
+    rows of the last dim.  ``block_rows`` is an upper bound: wide rows
+    shrink it so a block stays inside ``_BLOCK_BYTES``."""
     from jax.experimental import pallas as pl
 
     shape = x.shape
     x2d = x.reshape(-1, shape[-1])
     R, C = x2d.shape
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            q, s = _ref_quantize(x2d)
-            return q.reshape(shape), s.reshape(shape[:-1])
-        interpret = False
-    block_rows = min(block_rows, R)
-    if R % block_rows:
+
+    def reference():
         q, s = _ref_quantize(x2d)
         return q.reshape(shape), s.reshape(shape[:-1])
+
+    if reference_off_tpu(interpret):
+        return reference()
+    fit = max(_BLOCK_BYTES // (4 * C) // _ROW_GRANULE * _ROW_GRANULE,
+              _ROW_GRANULE)
+    block_rows = min(block_rows, fit, R)
+    if R % block_rows:
+        shape_refused("quantize_int8", (R, C),
+                      f"{block_rows}-row blocks do not divide R={R}")
+        return reference()
     q, s = pl.pallas_call(
         _quant_kernel,
         grid=(R // block_rows,),

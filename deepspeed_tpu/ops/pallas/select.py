@@ -1,0 +1,51 @@
+"""Kernel-or-reference selection shared by every Pallas entry point.
+
+Each entry point takes ``interpret``: ``None`` leaves the choice to the
+platform (compiled kernel on a TPU, the ``jax.numpy`` reference anywhere
+else), ``False`` demands the compiled kernel, ``True`` runs the kernel in
+the Pallas interpreter (what the CPU tests ask for).  A shape the kernel
+cannot take still runs the reference, but on a TPU that is said once
+where the decision is made, so a run on the chip cannot quietly measure
+the reference.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional
+
+import jax
+
+from ...utils.logging import warn_once
+
+#: scoped-VMEM ceiling handed to Mosaic by the kernels that keep whole
+#: per-head planes resident (flash/block-sparse resident passes, the MoE
+#: row gather's output window).  Mosaic's default is 16 MiB; a v5e core
+#: has 128 MiB, and the resident dk/dv pass at S·d = 1M elements (bf16)
+#: needs 20.5 MiB (the compiler's own figure, jax 0.9.0 / libtpu 0.0.34).
+RESIDENT_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def reference_off_tpu(interpret: Optional[bool]) -> bool:
+    """The caller left the choice open and there is no TPU to compile for."""
+    return interpret is None and jax.default_backend() != "tpu"
+
+
+def shape_refused(kernel: str, shape: Any, reason: str) -> None:
+    """Record that ``kernel`` runs its reference for ``shape``.  Silent off
+    the TPU, where the reference is the expected path anyway."""
+    if jax.default_backend() == "tpu":
+        warn_once(
+            f"pallas/{kernel}/{shape}",
+            f"{kernel}: shape {shape} runs the jax.numpy reference on the "
+            f"TPU, not the Pallas kernel — {reason}")
+
+
+def resident_compiler_params(interpret: bool):
+    """``compiler_params`` for a kernel holding resident planes (empty in
+    the interpreter, which has no VMEM to limit)."""
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=RESIDENT_VMEM_LIMIT_BYTES)}

@@ -133,8 +133,10 @@ def build_mesh(layout: Optional[MeshLayout] = None,
     """Build the global Mesh with the canonical axis order.
 
     Uses ``mesh_utils.create_device_mesh`` so axis adjacency maps onto physical
-    ICI topology on real TPU slices; falls back to a plain reshape for host
-    (CPU) device sets where there is no topology to exploit.
+    ICI topology on real TPU slices; host (CPU) device sets have no topology
+    to exploit and are reshaped in enumeration order.  On a TPU a failure
+    of the topology-aware assignment is raised, not papered over: a
+    reshape there would train on a mesh whose "adjacent" chips are not.
     """
     layout = layout or MeshLayout.infer()
     if devices is None:
@@ -148,11 +150,11 @@ def build_mesh(layout: Optional[MeshLayout] = None,
     if len(devices) != layout.world_size:
         raise ValueError(f"{len(devices)} devices != layout world {layout.world_size}")
     shape = tuple(layout.axis_sizes[a] for a in MESH_AXIS_ORDER)
-    try:
+    if devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
 
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
+    else:
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, MESH_AXIS_ORDER)
 

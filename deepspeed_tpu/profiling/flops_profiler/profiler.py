@@ -42,26 +42,18 @@ PEAK_TABLE = (
 #: of PEAK_TABLE; ``peak_for_device`` is the lookup new code uses)
 PEAK_BF16_BY_KIND = tuple((tag, flops) for tag, flops, _, _ in PEAK_TABLE)
 
-#: fallback peak per backend when the device kind is unrecognized
-DEFAULT_PEAK_FLOPS = {
-    "tpu": 197e12,
-    "cpu": 1e12,
-    "gpu": 312e12,
-}
-
-#: (flops, hbm B/s, ici B/s) backend fallbacks for the full peak lookup
-DEFAULT_PEAKS = {
-    "tpu": (197e12, 819e9, 200e9),
-    "gpu": (312e12, 2039e9, 300e9),
-    "cpu": (1e12, 50e9, 10e9),
-}
+#: (flops, hbm B/s, ici B/s) handed to a CPU run so the roofline
+#: arithmetic has a denominator in the tests.  Not a device peak: the
+#: result carries ``source="backend_default"``.  Accelerators get no such
+#: default — a kind missing from PEAK_TABLE is an error.
+CPU_PLACEHOLDER_PEAKS = (1e12, 50e9, 10e9)
 
 
 @dataclasses.dataclass(frozen=True)
 class DevicePeak:
     """One chip's roofline ceilings.  ``source`` is ``"spec"`` when the
-    device kind matched the spec-sheet table, ``"backend_default"`` when
-    only the backend fallback applied (CPU, unknown kinds)."""
+    device kind matched the spec-sheet table, ``"backend_default"`` for
+    the CPU placeholder."""
 
     kind: str
     flops_per_s: float
@@ -83,7 +75,8 @@ class DevicePeak:
 def peak_for_device(device: Any = None) -> DevicePeak:
     """THE peak lookup — the single source the MFU math, the anatomy
     plane's roofline model, and any future bandwidth accounting share.
-    Kind-matched against the spec table, backend fallback otherwise."""
+    Kind-matched against the spec table; an accelerator whose kind is not
+    in it raises rather than borrowing another chip's numbers."""
     dev = device if device is not None else jax.devices()[0]
     kind = getattr(dev, "device_kind", "") or ""
     low = kind.lower()
@@ -91,9 +84,15 @@ def peak_for_device(device: Any = None) -> DevicePeak:
         if tag in low:
             return DevicePeak(kind=kind, flops_per_s=flops,
                               hbm_bytes_per_s=hbm, ici_bytes_per_s=ici)
-    backend = (getattr(dev, "platform", None) or jax.default_backend())
-    flops, hbm, ici = DEFAULT_PEAKS.get(str(backend), DEFAULT_PEAKS["cpu"])
-    return DevicePeak(kind=kind or str(backend), flops_per_s=flops,
+    backend = str(getattr(dev, "platform", None) or jax.default_backend())
+    if backend != "cpu":
+        raise ValueError(
+            f"no entry in PEAK_TABLE for device_kind {kind!r} on platform "
+            f"{backend!r}: add its published peaks (with their source) — "
+            f"utilization against another chip's peak would be wrong "
+            f"without saying so")
+    flops, hbm, ici = CPU_PLACEHOLDER_PEAKS
+    return DevicePeak(kind=kind or backend, flops_per_s=flops,
                       hbm_bytes_per_s=hbm, ici_bytes_per_s=ici,
                       source="backend_default")
 
@@ -101,18 +100,12 @@ def peak_for_device(device: Any = None) -> DevicePeak:
 def peak_flops_per_chip() -> float:
     """bf16 peak for THIS chip — ``peak_for_device().flops_per_s``, kept
     as the narrow helper the MFU call sites read."""
-    peak = peak_for_device()
-    if peak.source == "spec":
-        return peak.flops_per_s
-    return DEFAULT_PEAK_FLOPS.get(jax.default_backend(), 1e12)
+    return peak_for_device().flops_per_s
 
 
 def _compiled_cost(fn: Callable, *args, **kwargs) -> Dict[str, float]:
     compiled = jax.jit(fn).lower(*args, **kwargs).compile()
-    costs = compiled.cost_analysis()
-    if isinstance(costs, list):  # older jax returns [dict]
-        costs = costs[0] if costs else {}
-    return dict(costs or {})
+    return dict(compiled.cost_analysis() or {})
 
 
 class FlopsProfiler:

@@ -21,7 +21,6 @@ from typing import Any, Callable, Optional
 
 import jax
 
-from ...utils.logging import logger
 
 
 @dataclasses.dataclass
@@ -69,15 +68,9 @@ def configure(mpu_: Any = None, deepspeed_config: Any = None,
 def _policy():
     cp = jax.checkpoint_policies
     if _CONFIG.cpu_checkpointing:
-        try:
-            return cp.save_and_offload_only_these_names(
-                names_which_can_be_saved=[],
-                names_which_can_be_offloaded=[],
-                offload_src="device", offload_dst="pinned_host")
-        except Exception:  # older jax — fall back to recompute-everything
-            logger.warning("cpu_checkpointing policy unavailable; "
-                           "using nothing_saveable")
-            return cp.nothing_saveable
+        return cp.save_and_offload_only_these_names(
+            names_which_can_be_saved=[], names_which_can_be_offloaded=[],
+            offload_src="device", offload_dst="pinned_host")
     return cp.dots_with_no_batch_dims_saveable
 
 

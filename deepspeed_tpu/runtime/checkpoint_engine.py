@@ -27,7 +27,6 @@ import numpy as np
 import orbax.checkpoint as ocp
 
 from ..utils.logging import log_dist, logger
-from ..utils.jax_compat import ckpt_metadata_tree
 
 #: sidecar integrity manifest written next to every saved checkpoint tree
 SIDECAR_MANIFEST = "ds_manifest.json"
@@ -217,7 +216,7 @@ class TorchCheckpointEngine(CheckpointEngine):
         verify_sidecar_manifest(path)
         with ocp.StandardCheckpointer() as loader:
             if target is None:
-                meta = ckpt_metadata_tree(loader, path)
+                meta = loader.metadata(path).item_metadata.tree
                 target = jax.tree.map(
                     lambda am: jax.ShapeDtypeStruct(tuple(am.shape),
                                                     am.dtype), meta)

@@ -26,7 +26,6 @@ import numpy as np
 import orbax.checkpoint as ocp
 
 from ..utils.logging import log_dist, logger
-from ..utils.jax_compat import ckpt_metadata_tree
 
 LATEST_FILE = "latest"
 
@@ -236,7 +235,7 @@ def _load_checkpoint_impl(engine, load_dir: str, tag: Optional[str],
             # module-only load works against a DIFFERENT optimizer than the
             # one that saved (reference: load_module_only skips optimizer
             # state [K]); only the params subtree binds to engine shardings.
-            meta = ckpt_metadata_tree(loader, state_path)
+            meta = loader.metadata(state_path).item_metadata.tree
             target = jax.tree.map(
                 lambda am: jax.ShapeDtypeStruct(tuple(am.shape), am.dtype),
                 meta)
@@ -255,7 +254,7 @@ def _load_checkpoint_impl(engine, load_dir: str, tag: Optional[str],
             with ocp.StandardCheckpointer() as loader:
                 for i in range(sw.L):  # layer-at-a-time, like the save
                     lp = os.path.join(trunk_path, f"layer_{i:05d}")
-                    meta_tree = ckpt_metadata_tree(loader, lp)
+                    meta_tree = loader.metadata(lp).item_metadata.tree
                     target = jax.tree.map(
                         lambda am: jax.ShapeDtypeStruct(tuple(am.shape),
                                                         am.dtype),
@@ -287,7 +286,8 @@ def _load_checkpoint_impl(engine, load_dir: str, tag: Optional[str],
                           if k != "step"}
                 # legacy checkpoints (pre-round-3) carry no 'master' entry;
                 # probe the saved tree instead of masking restore errors
-                saved_keys = set(ckpt_metadata_tree(loader, offload_path))
+                saved_keys = set(
+                    loader.metadata(offload_path).item_metadata.tree)
                 if "master" not in saved_keys:
                     target.pop("master", None)
                     log_dist("offload restore: legacy checkpoint without "

@@ -116,10 +116,8 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # manualize ONLY the seq axis (batch/dp stays GSPMD-auto) — same
     # partial-manual convention as ulysses_attention so the two compose
     # with the surrounding engine shardings identically
-    from ...utils.jax_compat import abstract_mesh_or_none
-
-    ctx = abstract_mesh_or_none()
-    sm_mesh = ctx if ctx is not None and ctx.shape else mesh
+    ctx = jax.sharding.get_abstract_mesh()
+    sm_mesh = mesh if ctx.empty else ctx
     body = partial(_ring_attention_local, axis_name=AXIS_SEQ, sp=sp,
                    causal=causal, window=window)
     spec = P(None, AXIS_SEQ, None, None)

@@ -55,10 +55,8 @@ def ulysses_attention(attn_fn: Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray],
     # Manualize ONLY the seq axis: batch/head sharding stays with GSPMD, and
     # the partial-manual form composes under an enclosing pipeline shard_map
     # (whose context mesh must be reused — a concrete Mesh would mismatch).
-    from ...utils.jax_compat import abstract_mesh_or_none
-
-    ctx = abstract_mesh_or_none()
-    sm_mesh = ctx if ctx is not None and ctx.shape else mesh
+    ctx = jax.sharding.get_abstract_mesh()
+    sm_mesh = mesh if ctx.empty else ctx
     spec = P(None, AXIS_SEQ, None, None)
 
     def inner(ql, kl, vl):

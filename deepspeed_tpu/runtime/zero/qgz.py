@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from ...comm.comm import all_gather_in_graph, all_to_all_in_graph
-from ...utils.jax_compat import axis_size as _axis_size
 
 GROUP = 256  # quantization group size (scale granularity)
 
@@ -57,7 +56,7 @@ def quantized_allreduce(g: jnp.ndarray, axis_names: Sequence[str]
     names = tuple(axis_names)
     world = 1
     for ax in names:
-        world *= _axis_size(ax)
+        world *= jax.lax.axis_size(ax)
     if world == 1:
         return g
 
@@ -100,7 +99,7 @@ def quantized_reduce_scatter(g: jnp.ndarray, axis_names: Sequence[str],
     names = tuple(axis_names)
     world = 1
     for ax in names:
-        world *= _axis_size(ax)
+        world *= jax.lax.axis_size(ax)
     if world == 1:
         return g
 

@@ -66,12 +66,7 @@ def comm_bytes_from_hlo(hlo_text: str) -> int:
 
 
 def _cost_dict(compiled: Any) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jax versions (older
-    releases return ``[dict]`` per module, newer a flat dict)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    return dict(compiled.cost_analysis() or {})
 
 
 class CostLedger:

@@ -330,6 +330,11 @@ class TrackedJit:
         donate = jit_kwargs.get("donate_argnums", ())
         self._donate = (tuple(donate) if isinstance(donate, (tuple, list))
                         else (donate,))
+        static = jit_kwargs.get("static_argnames", ())
+        #: an AOT executable has its static arguments baked in and refuses
+        #: them at call time; they are part of the signature key instead
+        self._static_names = {static} if isinstance(static, str) \
+            else set(static)
         self._jitted = jax.jit(fn, **jit_kwargs)
         self._programs: Dict[Tuple, Any] = {}  # sig key -> (idx, compiled)
         self._fell_back = False
@@ -338,11 +343,19 @@ class TrackedJit:
     def lower(self, *args, **kwargs):
         return self._jitted.lower(*args, **kwargs)
 
+    def executables(self) -> List[Any]:
+        """The AOT executables compiled at this site so far (their
+        ``as_text()`` is the partitioned HLO, collectives included)."""
+        with self._lock:
+            return [c for _, c in self._programs.values() if c is not None]
+
     def __call__(self, *args, **kwargs):
         if not self.tracker.enabled:
             return self._jitted(*args, **kwargs)
         sig = signature_of(args, kwargs, self.static_context, self._donate)
         key = signature_key(sig)
+        dynamic = kwargs if not self._static_names else {
+            k: v for k, v in kwargs.items() if k not in self._static_names}
         with self._lock:
             entry = self._programs.get(key)
         if entry is not None:
@@ -350,7 +363,7 @@ class TrackedJit:
             self.tracker.note_call(self.site, idx)
             if compiled is None:  # this signature runs on the fallback path
                 return self._jitted(*args, **kwargs)
-            return compiled(*args, **kwargs)
+            return compiled(*args, **dynamic)
         # cache miss: the AOT path, so lower and compile are timed apart
         compiled = None
         try:
@@ -385,7 +398,7 @@ class TrackedJit:
         if fallback:
             return out
         try:
-            return compiled(*args, **kwargs)
+            return compiled(*args, **dynamic)
         except Exception as e:
             # an executable the AOT path built but cannot dispatch (layout
             # or weak-type mismatch): route THIS signature through the

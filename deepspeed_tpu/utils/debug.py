@@ -8,9 +8,9 @@ TPU story per SURVEY §5.2's plan: XLA programs are race-free; the risk
 surface is host↔device async (offload streams, async checkpointing) and
 silent NaN propagation.  Debug mode therefore:
 
-* forces a REAL device fence after every ``train_step`` (a scalar fetch —
-  on tunneled platforms ``block_until_ready`` can be a no-op, a metrics
-  fetch is not), so failures surface at the step that caused them;
+* forces a device fence after every ``train_step`` (the loss scalar is
+  fetched, which waits for the step), so failures surface at the step
+  that caused them;
 * enables ``jax_debug_nans`` (XLA re-runs the failing op un-jitted and
   points at it) and raises on non-finite loss.
 

@@ -1,12 +1,6 @@
-"""Version-portable wrappers over jax APIs that moved between releases.
-
-The codebase is written against the jax >= 0.9 surface (``jax.shard_map``
-with ``axis_names=``/``check_vma=``); older installs (0.4.x) carry the
-same capability as ``jax.experimental.shard_map.shard_map`` with the
-inverse knobs (``auto=`` lists the axes that STAY automatic instead of
-``axis_names=`` listing the manual ones, and replication checking is
-``check_rep=``).  Import ``shard_map`` from here everywhere so one
-translation covers both.
+"""Two thin adapters over the installed jax (0.9.0, pinned in
+``pyproject.toml``).  Nothing here branches on a version: code that needs
+another jax changes the pin.
 """
 
 from __future__ import annotations
@@ -15,107 +9,22 @@ from typing import Any, Optional, Set
 
 import jax
 
-if hasattr(jax, "shard_map"):
 
-    def shard_map(f, *, mesh, in_specs, out_specs,
-                  axis_names: Optional[Set[Any]] = None,
-                  check_vma: bool = False):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs,
-                  axis_names: Optional[Set[Any]] = None,
-                  check_vma: bool = False):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - set(axis_names)
-            if auto:
-                kw["auto"] = auto
-        return _shard_map(f, **kw)
-
-
-def partial_manual_shard_map_ok() -> bool:
-    """Whether this jax/jaxlib can compile PARTIAL-manual ``shard_map``
-    (manual over a subset of axes) when some AUTO axis has size > 1.
-    jaxlib 0.4.x CHECK-fails in the SPMD partitioner on that combination
-    (``spmd_partitioner.cc: target.IsManualSubgroup() ==
-    sharding().IsManualSubgroup()``) — an uncatchable process abort, so
-    tests exercising those paths (Ulysses/ring SP, 1F1B pipeline + dp)
-    must skip rather than crash the suite.  Size-1 auto axes are fine
-    everywhere."""
-    return hasattr(jax, "shard_map")
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` (size of a named mesh axis at the current
-    trace point) for releases that predate it: a psum of 1 over the axis
-    is statically evaluated to the same number."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    # a psum of the literal 1 is an axis-SIZE query the partitioner folds
-    # to a constant, not data movement — and this shim sits BELOW comm/
-    # in the import graph, so it cannot route through the comm verbs
-    return jax.lax.psum(1, axis_name)  # dslint: disable=raw-collective
-
-
-def abstract_mesh_or_none():
-    """The context AbstractMesh (inside ``jax.set_mesh``/``shard_map``
-    scopes) on jax >= 0.7; None on releases without the concept — callers
-    fall back to their concrete mesh."""
-    try:
-        return jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        return None
+def shard_map(f, *, mesh, in_specs, out_specs,
+              axis_names: Optional[Set[Any]] = None,
+              check_vma: bool = False):
+    """``jax.shard_map`` with this codebase's defaults: replication
+    checking off (the manual regions here use collectives whose
+    replication jax cannot infer), and ``axis_names=None`` meaning every
+    mesh axis is manual."""
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check_vma)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 def current_manual_axes() -> Set[Any]:
     """Mesh axes that are MANUAL at the current trace point (we are inside
-    a ``shard_map`` over them).  jax >= 0.7 exposes this on the abstract
-    mesh; 0.4.x carries the same information in the axis environment."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        am = None
-    if am is not None:
-        return set(getattr(am, "manual_axes", ()) or ())
-    try:
-        from jax._src.core import get_axis_env
-
-        return set(get_axis_env().axis_sizes)
-    except Exception:
-        return set()
-
-
-def live_arrays():
-    """``jax.live_arrays()`` — every live array the client tracks —
-    across releases; ``[]`` when the introspection API is absent (the
-    memory plane then reports device/host stats only)."""
-    try:
-        return list(jax.live_arrays())
-    except Exception as e:  # API drift across jax releases
-        from .logging import debug_once
-
-        debug_once("jax_compat/live_arrays",
-                   f"jax.live_arrays unavailable ({e!r})")
-        return []
-
-
-def ckpt_metadata_tree(loader, path):
-    """Orbax moved checkpoint metadata between releases: newer
-    StandardCheckpointer returns an object with ``.item_metadata.tree``,
-    older ones hand back the tree (dict) directly."""
-    meta = loader.metadata(path)
-    im = getattr(meta, "item_metadata", None)
-    if im is not None:
-        return im.tree
-    tree = getattr(meta, "tree", None)
-    if tree is not None:
-        return tree
-    return meta
+    a ``shard_map`` over them); empty outside any mesh context."""
+    return set(jax.sharding.get_abstract_mesh().manual_axes)

@@ -16,7 +16,6 @@ from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
-from .jax_compat import ckpt_metadata_tree
 
 
 def path_key(path) -> str:
@@ -61,7 +60,7 @@ def restore_saved_state(checkpoint_dir: str, tag: Optional[str] = None):
     tag = resolve_tag(checkpoint_dir, tag)
     state_path = os.path.join(checkpoint_dir, tag, "state")
     with ocp.StandardCheckpointer() as loader:
-        meta = ckpt_metadata_tree(loader, state_path)
+        meta = loader.metadata(state_path).item_metadata.tree
         target = jax.tree.map(
             lambda am: jax.ShapeDtypeStruct(tuple(am.shape), am.dtype), meta)
         return loader.restore(state_path, target), tag

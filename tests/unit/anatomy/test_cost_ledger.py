@@ -52,12 +52,20 @@ def test_harvest_cost_model_is_measured():
     assert e["arithmetic_intensity"] == 1000.0
 
 
-def test_harvest_list_shaped_cost_analysis():
-    # older jax returns [dict] per module
+def test_harvest_reads_a_real_executable():
+    # the installed jax hands cost_analysis() back as one flat dict; the
+    # ledger reads it as such (no per-version shapes)
+    import jax
+    import jax.numpy as jnp
+
+    compiled = jax.jit(lambda a, b: a @ b).lower(
+        jnp.ones((64, 64)), jnp.ones((64, 64))).compile()
+    assert isinstance(compiled.cost_analysis(), dict)
     led = CostLedger(peak=V4)
-    led.harvest("s", 1, FakeCompiled(cost=[{"flops": 2e12,
-                                            "bytes accessed": 4e9}]))
-    assert led.entry_for("s")["flops"] == 2e12
+    led.harvest("s", 1, compiled)
+    e = led.entry_for("s")
+    assert e["provenance"] == "measured"
+    assert e["flops"] >= 2 * 64 ** 3
 
 
 def test_degraded_backend_is_estimated_not_measured():

@@ -1,13 +1,13 @@
 """Device peak table (ISSUE 17 satellite): v5p/v6e entries, the single
 ``peak_for_device`` lookup, and its consistency with the MFU helper."""
 
-import jax
+import pytest
 
 from deepspeed_tpu.profiling.flops_profiler import (DevicePeak,
                                                     peak_flops_per_chip,
                                                     peak_for_device)
 from deepspeed_tpu.profiling.flops_profiler.profiler import (
-    DEFAULT_PEAK_FLOPS, DEFAULT_PEAKS, PEAK_BF16_BY_KIND, PEAK_TABLE)
+    CPU_PLACEHOLDER_PEAKS, PEAK_BF16_BY_KIND, PEAK_TABLE)
 
 
 class FakeDev:
@@ -38,11 +38,26 @@ def test_peak_for_device_spec_match():
     assert p4.flops_per_s == 275e12
 
 
-def test_peak_for_device_backend_fallback():
-    p = peak_for_device(FakeDev("mystery accelerator", platform="cpu"))
+def test_peak_for_device_cpu_placeholder():
+    p = peak_for_device(FakeDev("mystery host", platform="cpu"))
     assert p.source == "backend_default"
     assert (p.flops_per_s, p.hbm_bytes_per_s,
-            p.ici_bytes_per_s) == DEFAULT_PEAKS["cpu"]
+            p.ici_bytes_per_s) == CPU_PLACEHOLDER_PEAKS
+
+
+@pytest.mark.parametrize("platform", ["tpu", "gpu"])
+def test_unknown_accelerator_kind_raises(platform):
+    """An accelerator missing from the table is an error, not a default:
+    a v5e peak under an unknown chip's utilization would be wrong without
+    saying so."""
+    with pytest.raises(ValueError, match="PEAK_TABLE"):
+        peak_for_device(FakeDev("TPU v9 mystery", platform=platform))
+
+
+def test_both_names_of_the_v5e_resolve():
+    # the chip reports itself as "TPU v5 lite"; documents call it v5e
+    assert (peak_for_device(FakeDev("TPU v5 lite")).flops_per_s
+            == peak_for_device(FakeDev("TPU v5e")).flops_per_s == 197e12)
 
 
 def test_peak_for_current_backend_never_raises():
@@ -56,15 +71,7 @@ def test_peak_for_current_backend_never_raises():
 
 
 def test_mfu_helper_consistent_with_peak_table():
-    # on a spec-matched chip peak_flops_per_chip IS the table entry; on
-    # the test backend (CPU) it stays the legacy backend default the
-    # existing MFU tests pin
-    peak = peak_for_device()
-    if peak.source == "spec":
-        assert peak_flops_per_chip() == peak.flops_per_s
-    else:
-        assert peak_flops_per_chip() == DEFAULT_PEAK_FLOPS.get(
-            jax.default_backend(), 1e12)
+    assert peak_flops_per_chip() == peak_for_device().flops_per_s
 
 
 def test_back_compat_bf16_view_matches_table():

@@ -175,8 +175,11 @@ class Gang:
         return t
 
     def join_all(self, timeout=300):
+        # one deadline for the gang, not one per thread: a hung gang
+        # (ROADMAP D0) must not eat the whole tier-1 time limit
+        deadline = time.monotonic() + timeout
         for t in self.threads.values():
-            t.join(timeout=timeout)
+            t.join(timeout=max(deadline - time.monotonic(), 0.0))
         assert not any(t.is_alive() for t in self.threads.values()), \
             "gang never finished"
 

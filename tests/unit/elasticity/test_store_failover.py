@@ -17,6 +17,19 @@ from deepspeed_tpu.elasticity.rendezvous import (ElasticRendezvous,
 from deepspeed_tpu.telemetry import get_telemetry, parse_prometheus_text
 
 
+@pytest.fixture(autouse=True)
+def _forget_clients_of_earlier_tests():
+    """``control_plane_status`` is process-wide.  A gang test that failed
+    earlier in the run (ROADMAP D0) leaves agent threads with degraded
+    clients behind; these tests count only the clients they make."""
+    from deepspeed_tpu.elasticity import rendezvous
+
+    with rendezvous._registry_lock:
+        rendezvous._all_clients.clear()
+        rendezvous._degraded_clients.clear()
+    yield
+
+
 def _client(endpoint):
     # a tight retry budget so outage tests take milliseconds
     return RendezvousClient(endpoint, retries=1, backoff_s=0.001)

@@ -8,7 +8,6 @@ import pytest
 from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import partial_manual_shard_map_ok
 
 pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
 
@@ -61,9 +60,6 @@ def test_labels_with_ignore_index():
 ])
 def test_sharded_training_matches_single_device(layout_kw, stage):
     """Hybrid-sharded training (mesh) must track the unsharded trace."""
-    if layout_kw.get("sp", 1) > 1 and not partial_manual_shard_map_ok():
-        pytest.skip("sp>1 runs partial-manual shard_map; jaxlib<0.5 SPMD "
-                    "partitioner aborts on it")
     import deepspeed_tpu
 
     cfg = tiny()
@@ -95,8 +91,6 @@ def test_sharded_training_matches_single_device(layout_kw, stage):
     assert sharded[-1] < sharded[0]  # it actually learns
 
 
-@pytest.mark.skipif(not partial_manual_shard_map_ok(),
-                    reason="pp>1 runs partial-manual shard_map over the pipe axis; jaxlib<0.5 cannot lower it")
 def test_pipeline_parallel_training_matches_single_device():
     """pp=2 × tp=2 × dp=2 dense Llama must track the unsharded trace (dense
     model: pipeline microbatching is numerically neutral)."""

@@ -8,7 +8,6 @@ import pytest
 from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import partial_manual_shard_map_ok
 
 pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
 
@@ -65,8 +64,6 @@ def test_windowed_generate_matches_full_forward():
     np.testing.assert_array_equal(got, np.asarray(seq))
 
 
-@pytest.mark.skipif(not partial_manual_shard_map_ok(),
-                    reason="sp>1 with dp>1 runs partial-manual shard_map; jaxlib<0.5 SPMD partitioner rejects it")
 def test_ring_window_matches_dense_window():
     from deepspeed_tpu.runtime.sequence_parallel.ring import (
         _plain_attention, ring_attention)

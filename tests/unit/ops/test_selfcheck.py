@@ -1,40 +1,40 @@
-"""The bench kernel self-check gate (VERDICT round-2 item 7).
+"""The kernel self-check (``ops/pallas/selfcheck.py``) has teeth.
 
-On CPU the kernels route to their jnp references, so a clean run passing
-here only proves the gate's plumbing; the real numerics check happens on
-the chip (bench.py runs it before the headline).  What IS provable
-anywhere: a wrong kernel fails the gate — the gate has teeth.
+``chip_smoke.py`` runs it compiled at Mistral-7B shapes on the chip; here
+the same checks run through the Pallas interpreter at a tiny size (the
+whole set runs in ``tests/unit/test_chip_smoke.py``).  What is provable
+anywhere: a kernel that is wrong, or returns a NaN, fails the gate.
 """
 
-import pathlib
-import sys
+import importlib
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
-_REPO_ROOT = str(pathlib.Path(__file__).resolve().parents[3])
-
-
-def _bench():
-    if _REPO_ROOT not in sys.path:
-        sys.path.insert(0, _REPO_ROOT)
-    import bench
-
-    return bench
+from deepspeed_tpu.models import LlamaConfig
+from deepspeed_tpu.ops.pallas import lattice, selfcheck
 
 
-def test_selfcheck_passes_clean():
-    _bench().selfcheck()
+@pytest.fixture
+def shapes(monkeypatch):
+    cfg = LlamaConfig.tiny(dtype=jnp.float32, sliding_window=96)
+    # residency bound at the tiny context, so that twice the context streams
+    monkeypatch.setattr(lattice, "RESIDENT_VMEM_ELEMS",
+                        cfg.max_seq_len * cfg.hd)
+    return selfcheck.KernelShapes.for_model(cfg)
 
 
-def test_selfcheck_detects_broken_kernel(monkeypatch):
-    """A kernel producing wrong values (the round-1 VMEM-overflow class)
-    must fail the gate."""
-    bench = _bench()
-    import importlib
+def test_shapes_follow_the_model(shapes):
+    assert (shapes.heads, shapes.kv_heads, shapes.head_dim) == (8, 4, 16)
+    assert shapes.window == 96
+    assert lattice.resident_fits(shapes.seq, shapes.head_dim)
+    assert not lattice.resident_fits(shapes.stream_seq, shapes.head_dim)
 
+
+def test_selfcheck_detects_broken_kernel(monkeypatch, shapes):
+    """A kernel producing wrong values (the VMEM-overflow class) must
+    fail the gate."""
     fa_mod = importlib.import_module(
         "deepspeed_tpu.ops.pallas.flash_attention")
     real = fa_mod.flash_attention
@@ -43,14 +43,12 @@ def test_selfcheck_detects_broken_kernel(monkeypatch):
         return real(q, k, v, *a, **kw) * 1.5  # silently wrong scale
 
     monkeypatch.setattr(fa_mod, "flash_attention", broken)
-    with pytest.raises(AssertionError, match="selfcheck FAILED"):
-        bench.selfcheck()
+    monkeypatch.setattr(selfcheck, "CHECKS", (selfcheck.check_flash,))
+    with pytest.raises(AssertionError, match="selfcheck FAILED.*flash"):
+        selfcheck.run_checks(shapes, interpret=True)
 
 
-def test_selfcheck_detects_nan(monkeypatch):
-    bench = _bench()
-    import importlib
-
+def test_selfcheck_detects_nan(monkeypatch, shapes):
     da_mod = importlib.import_module(
         "deepspeed_tpu.ops.pallas.decode_attention")
     real = da_mod.decode_attention
@@ -60,5 +58,15 @@ def test_selfcheck_detects_nan(monkeypatch):
         return out.at[0].set(np.nan)
 
     monkeypatch.setattr(da_mod, "decode_attention", nan_kernel)
-    with pytest.raises(AssertionError, match="selfcheck FAILED"):
-        bench.selfcheck()
+    monkeypatch.setattr(selfcheck, "CHECKS", (selfcheck.check_decode,))
+    with pytest.raises(AssertionError, match="selfcheck FAILED.*decode"):
+        selfcheck.run_checks(shapes, interpret=True)
+
+
+def test_streamed_check_refuses_a_resident_shape(shapes):
+    """The streamed check must not quietly re-run the resident kernels."""
+    import dataclasses
+
+    resident = dataclasses.replace(shapes, stream_seq=shapes.seq)
+    with pytest.raises(ValueError, match="RESIDENT_VMEM_ELEMS"):
+        selfcheck.check_flash_streamed(resident, interpret=True)

@@ -9,14 +9,8 @@ from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.parallel.pipeline import (pipeline_apply,
                                              pipeline_train_1f1b)
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import partial_manual_shard_map_ok
 
 pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
-needs_partial_manual = pytest.mark.skipif(
-    not partial_manual_shard_map_ok(),
-    reason="pipeline schedules run partial-manual shard_map over the pipe axis; jaxlib<0.5 cannot lower it (PartitionId unsupported)")
-
 
 def layer_fn(lp, x):
     return jnp.tanh(x @ lp["w"] + lp["b"])
@@ -38,7 +32,6 @@ def ref_apply(params, micro):
 
 
 @pytest.mark.parametrize("pp,M", [(2, 4), (4, 4), (4, 2), (2, 8)])
-@needs_partial_manual
 def test_pipeline_forward_matches_sequential(pp, M):
     mesh = groups.initialize_mesh(MeshLayout.infer(8, pp=pp))
     params = make_params()
@@ -49,7 +42,6 @@ def test_pipeline_forward_matches_sequential(pp, M):
                                rtol=1e-5, atol=1e-5)
 
 
-@needs_partial_manual
 def test_pipeline_gradients_match_sequential():
     pp, M = 4, 4
     mesh = groups.initialize_mesh(MeshLayout.infer(8, pp=pp))
@@ -88,7 +80,6 @@ def _1f1b_ref_loss(p, ep, hp, micros):
 
 
 @pytest.mark.parametrize("pp,M", [(1, 4), (2, 8), (4, 8), (2, 4)])
-@needs_partial_manual
 def test_1f1b_loss_and_grads_match_sequential(pp, M):
     """VERDICT r2 item 5: 1F1B schedule — pp>1 grads == sequential for
     trunk, embed AND head params; stash bound < GPipe's M."""
@@ -123,7 +114,6 @@ def test_1f1b_loss_and_grads_match_sequential(pp, M):
 
 @pytest.mark.parametrize("pp,M,v", [(2, 4, 2), (2, 3, 2), (4, 4, 2),
                                     (2, 8, 4)])
-@needs_partial_manual
 def test_interleaved_forward_matches_sequential(pp, M, v):
     """Virtual-stage (interleaved) schedule is numerics-identical; only the
     bubble shrinks."""
@@ -137,7 +127,6 @@ def test_interleaved_forward_matches_sequential(pp, M, v):
                                rtol=1e-5, atol=1e-5)
 
 
-@needs_partial_manual
 def test_interleaved_gradients_match_sequential():
     pp, M, v = 2, 4, 2
     mesh = groups.initialize_mesh(MeshLayout.infer(8, pp=pp))
@@ -176,7 +165,6 @@ def test_bubble_fraction_shrinks_with_interleave():
     assert abs(inter - 3 / 35) < 1e-9
 
 
-@needs_partial_manual
 def test_pipeline_composes_with_dp():
     """pipe × data hybrid: batch sharded over data, layers over pipe."""
     mesh = groups.initialize_mesh(MeshLayout.infer(8, pp=2, dp=4))

@@ -7,15 +7,14 @@ import jax.numpy as jnp
 import pytest
 
 from deepspeed_tpu.profiling.flops_profiler.profiler import (
-    DEFAULT_PEAK_FLOPS, PEAK_BF16_BY_KIND, FlopsProfiler,
+    CPU_PLACEHOLDER_PEAKS, PEAK_BF16_BY_KIND, FlopsProfiler,
     get_model_profile, peak_flops_per_chip)
 
 
-def test_peak_flops_unknown_device_falls_back_to_backend():
-    # the CPU test backend's device_kind matches no TPU entry, so the
-    # helper must fall back to the backend table, never 0 or a crash
-    peak = peak_flops_per_chip()
-    assert peak == DEFAULT_PEAK_FLOPS[jax.default_backend()]
+def test_peak_flops_on_the_cpu_backend_is_the_placeholder():
+    # the CPU test backend's device_kind matches no TPU entry: the helper
+    # hands out the marked CPU placeholder (an accelerator would raise)
+    assert peak_flops_per_chip() == CPU_PLACEHOLDER_PEAKS[0]
 
 
 def test_peak_flops_kind_table_is_ordered_most_specific_first():

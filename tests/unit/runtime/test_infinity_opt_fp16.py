@@ -14,7 +14,6 @@ from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.ops.op_builder import CPUAdamBuilder
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import partial_manual_shard_map_ok
 
 pytestmark = [
     pytest.mark.slow,
@@ -38,8 +37,6 @@ def _trajectory(eng, b, steps=3):
     return [float(eng.train_step(b)["loss"]) for _ in range(steps)]
 
 
-@pytest.mark.skipif(not partial_manual_shard_map_ok(),
-                    reason="sp=2 streaming needs partial-manual shard_map; jaxlib<0.5 SPMD partitioner aborts on it")
 def test_pipelined_optimizer_matches_serial(tmp_path, monkeypatch):
     """The pipelined optimizer swapper (worker-thread C++ Adam behind
     device compute — reference pipelined_optimizer_swapper.py) must be

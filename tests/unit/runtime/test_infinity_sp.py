@@ -24,15 +24,11 @@ from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.ops.op_builder import CPUAdamBuilder
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import partial_manual_shard_map_ok
 
 pytestmark = [
     pytest.mark.slow,
     pytest.mark.skipif(not CPUAdamBuilder.is_compatible(),
                        reason="no g++ toolchain"),
-    pytest.mark.skipif(not partial_manual_shard_map_ok(),
-                       reason="sp>1 needs partial-manual shard_map; "
-                              "jaxlib<0.5 SPMD partitioner aborts on it"),
 ]
 
 DS = {"train_micro_batch_size_per_gpu": 8,

@@ -16,14 +16,8 @@ from deepspeed_tpu.runtime.pipe.module import (LayerSpec, PipelineModule,
                                                TiedLayerSpec)
 from deepspeed_tpu.utils import groups
 import pytest
-from deepspeed_tpu.utils.jax_compat import partial_manual_shard_map_ok
 
 pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
-needs_partial_manual = pytest.mark.skipif(
-    not partial_manual_shard_map_ok(),
-    reason="1F1B runs a partial-manual shard_map over the pipe axis; jaxlib<0.5 cannot lower it (PartitionId unsupported)")
-
 
 def _tied_module(H=8, V=16):
     """embed → tanh mid-layer → unembed with the SAME weight (tied)."""
@@ -159,7 +153,6 @@ def _llama_pp(schedule, zero_stage=0, pp=2, steps=3, tp=1):
     return engine, losses
 
 
-@needs_partial_manual
 def test_engine_routes_1f1b_schedule():
     """pipeline.schedule=1f1b (the default) drives the real 1F1B tick scan
     — engine.last_pipe_stats proves the schedule built the program, and
@@ -178,7 +171,6 @@ def test_engine_routes_1f1b_schedule():
     assert losses_1f1b[-1] < losses_1f1b[0]
 
 
-@needs_partial_manual
 def test_1f1b_under_tensor_axes_manual_tp():
     """1F1B x tp2 (VERDICT r4 item 6): the tensor axis joins the manual
     shard_map set and the model's Megatron column/row layer
@@ -198,7 +190,6 @@ def test_1f1b_under_tensor_axes_manual_tp():
     assert losses[-1] < losses[0]
 
 
-@needs_partial_manual
 def test_1f1b_fp16_loss_scaling():
     """fp16 through 1F1B (VERDICT r4 item 10): the per-micro loss scales
     INSIDE the schedule, grads unscale outside, and the overflow vote is
@@ -255,7 +246,6 @@ def test_1f1b_fp16_loss_scaling():
 
 
 @pytest.mark.parametrize("stage", [2, 3])
-@needs_partial_manual
 def test_1f1b_composes_with_zero(stage):
     """pipeline × ZeRO stage 2/3: the 1F1B schedule's grads feed the
     sharded optimizer states and the trajectory matches stage 0."""
@@ -265,7 +255,6 @@ def test_1f1b_composes_with_zero(stage):
     np.testing.assert_allclose(losses, losses0, rtol=2e-4, atol=2e-4)
 
 
-@needs_partial_manual
 def test_compat_pipeline_engine_runs_schedule_at_pp2():
     """The compat PipelineEngine executes the REAL ppermute fill/drain
     schedule when the mesh has pipe=2 — trajectory matches the pp=1
@@ -318,7 +307,6 @@ def _relayout_batch():
         np.random.RandomState(3).randint(0, 512, size=(16, 32)))}
 
 
-@needs_partial_manual
 def test_universal_checkpoint_3d_relayout_to_pp_tp(tmp_path):
     """Universal-checkpoint 3D relayout (VERDICT r3 item 8, reference
     ``ds_to_universal`` role, SURVEY §5.4): save under dp8 ZeRO-3, resume
@@ -338,7 +326,6 @@ def test_universal_checkpoint_3d_relayout_to_pp_tp(tmp_path):
     np.testing.assert_allclose(got, ref_next, rtol=3e-4)
 
 
-@needs_partial_manual
 def test_universal_checkpoint_3d_relayout_to_dp(tmp_path):
     """Reverse 3D relayout: save under pp2 x tp2 x dp2 ZeRO-1, resume
     under dp8 ZeRO-3 — trace continues."""

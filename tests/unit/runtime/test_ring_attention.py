@@ -9,14 +9,8 @@ from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.runtime.sequence_parallel.ring import (_plain_attention,
                                                           ring_attention)
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import partial_manual_shard_map_ok
 
 pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
-needs_partial_manual = pytest.mark.skipif(
-    not partial_manual_shard_map_ok(),
-    reason="jaxlib<0.5 SPMD partitioner CHECK-fails on partial-manual shard_map with size>1 auto axes (process abort, not catchable)")
-
 
 def _qkv(B=2, S=64, h=2, d=16, seed=0):
     rng = np.random.RandomState(seed)
@@ -29,9 +23,6 @@ def _qkv(B=2, S=64, h=2, d=16, seed=0):
 def test_ring_matches_dense(sp, causal):
     """sp devices, only h=2 heads — BEYOND the Ulysses sp<=h limit for
     sp>2 — still bit-close to dense attention."""
-    if sp < 8 and not partial_manual_shard_map_ok():
-        pytest.skip("partial-manual shard_map with dp>1 auto axis "
-                    "aborts on this jaxlib")
     groups.reset_mesh()
     mesh = groups.initialize_mesh(MeshLayout.infer(8, sp=sp,
                                                    dp=8 // sp))
@@ -79,7 +70,6 @@ def test_ring_sp1_is_plain():
                                rtol=1e-6)
 
 
-@needs_partial_manual
 def test_llama_ring_sp_beyond_head_count_matches_single_device():
     """End-to-end: Llama with attn_impl='ring' trains under sp=4 with only
     2 heads (Ulysses would need sp<=2) and tracks the unsharded trace."""
@@ -114,7 +104,6 @@ def test_llama_ring_sp_beyond_head_count_matches_single_device():
     assert ring_losses[-1] < ring_losses[0]
 
 
-@needs_partial_manual
 def test_ring_gqa_rotates_kv_width():
     """GQA: K/V circulate at kv-head width; output matches dense with
     expanded heads."""

@@ -11,14 +11,8 @@ from deepspeed_tpu.runtime.sequence_parallel import (
     UlyssesSPDataLoaderAdapter, sequence_tiled_loss, ulysses_attention)
 from deepspeed_tpu.sequence import DistributedAttention
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import partial_manual_shard_map_ok
 
 pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
-needs_partial_manual = pytest.mark.skipif(
-    not partial_manual_shard_map_ok(),
-    reason="jaxlib<0.5 SPMD partitioner CHECK-fails on partial-manual shard_map with size>1 auto axes (process abort, not catchable)")
-
 
 def softmax_attn(q, k, v):
     scale = 1.0 / np.sqrt(q.shape[-1])
@@ -33,7 +27,6 @@ def make_qkv(B=4, S=32, h=8, d=16, seed=0):
     return mk(), mk(), mk()
 
 
-@needs_partial_manual
 def test_ulysses_attention_matches_direct():
     mesh = groups.initialize_mesh(MeshLayout.infer(8, dp=2, sp=2, tp=2))
     q, k, v = make_qkv()
@@ -52,7 +45,6 @@ def test_ulysses_sp1_passthrough():
                                np.asarray(softmax_attn(q, k, v)), rtol=1e-5)
 
 
-@needs_partial_manual
 def test_distributed_attention_legacy_api():
     mesh = groups.initialize_mesh(MeshLayout.infer(8, dp=4, sp=2))
     q, k, v = make_qkv()

@@ -146,7 +146,7 @@ def test_device_unresponsive_latch_kills_all_replicas():
 
     fe, reps, _ = make_cluster(n=2)
     h = fe.submit([9] * 8, max_new_tokens=4)
-    mark_device_unresponsive("dead tunnel (test)")
+    mark_device_unresponsive("runtime stopped answering (test)")
     try:
         import pytest as _pytest
 

@@ -51,3 +51,22 @@ def test_topology_comm_lists():
     assert len(dp_groups) == 2
     assert dp_groups[0] == [0, 1, 2, 3]
     assert dp_groups[1] == [4, 5, 6, 7]
+
+
+def test_tpu_topology_failure_is_raised_not_reshaped(monkeypatch):
+    """On a TPU a failed topology-aware assignment must surface: a plain
+    reshape would build a mesh whose adjacent chips are not adjacent."""
+    import pytest
+    from jax.experimental import mesh_utils
+
+    from deepspeed_tpu.parallel.mesh import MeshLayout, build_mesh
+
+    class FakeTpu:
+        platform = "tpu"
+
+    def refuse(shape, devices):
+        raise ValueError("no assignment for this topology")
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", refuse)
+    with pytest.raises(ValueError, match="topology"):
+        build_mesh(MeshLayout.infer(4, tp=2), [FakeTpu() for _ in range(4)])

@@ -92,7 +92,7 @@ def test_environment_failure_artifact_cannot_justify_promotion(
         store, baseline, tmp_path):
     p = tmp_path / "nodata.json"
     p.write_text(json.dumps({"metric": "llama_110m_train_tokens_per_sec",
-                             "value": 0.0, "error": "tunnel down",
+                             "value": 0.0, "error": "no device answered",
                              "environment_failure": True}))
     code, report = promote_entry(store, KEY, str(p), baseline)
     assert code == PROMOTE_ERROR
