@@ -80,8 +80,9 @@ FIRST_LOSS_BAND = 1.0
 #: per-device bytes_in_use after a multi-chip run, largest over smallest
 MEMORY_BALANCE_RATIO = 1.25
 
-FLASH_KERNELS = {"_fa_kernel", "_fa_bwd_dq_kernel", "_fa_bwd_dkv_kernel"}
-PAGED_KERNEL = "_paged_kernel"
+#: the names the kernels are given at their ``pl.pallas_call(name=...)``
+FLASH_KERNELS = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+PAGED_KERNEL = "paged_decode_attention"
 
 
 # ---------------------------------------------------------------------------

@@ -441,10 +441,22 @@ class RaggedInferenceEngineV2:
         from ...telemetry import get_telemetry
 
         tel = get_telemetry()
-        chunks, decode = self.scheduler.plan_step()
-        temp = jnp.float32(temperature)
-        n_tokens = 0
-        if chunks:
+        with tel.span("inference/step") as sp:
+            with tel.span("inference/plan"):
+                chunks, decode = self.scheduler.plan_step()
+            sp.set(chunks=len(chunks), decoding=len(decode))
+            temp = jnp.float32(temperature)
+            n_tokens = 0
+            if chunks:
+                n_tokens += self._step_prefill(tel, chunks, temp,
+                                               eos_token_id)
+            if decode:
+                n_tokens += self._step_decode(tel, chunks, decode, temp,
+                                              eos_token_id)
+        return n_tokens
+
+    def _step_prefill(self, tel: Any, chunks, temp, eos_token_id) -> int:
+        with tel.span("inference/pack", args={"kind": "prefill"}):
             Bp, C = self.prefill_batch, self.chunk
             tokens = np.zeros((Bp, C), np.int32)
             tables = np.zeros((Bp, self.cache_config.max_blocks_per_seq),
@@ -456,21 +468,30 @@ class RaggedInferenceEngineV2:
                 tables[i] = self.scheduler.table_row(ch.request)
                 start[i] = ch.start_pos
                 last[i] = max(ch.n_valid - 1, 0)
-            with tel.span("inference/prefill",
-                          args={"chunks": len(chunks)}):
+        with tel.span("inference/prefill", args={"chunks": len(chunks)}):
+            with tel.span("inference/prefill/dispatch"):
                 sampled, self.pool = self._prefill(
                     self.params, self.pool, jnp.asarray(tokens),
                     jnp.asarray(tables), jnp.asarray(start),
                     jnp.asarray(last), temp, self._next_key(),
                     kb=self._prefill_bucket(chunks))
+            with tel.span("inference/prefill/fetch"):
                 sampled = np.asarray(sampled)
+        n_tokens = 0
+        with tel.span("inference/commit"):
             for i, ch in enumerate(chunks):
                 first = int(sampled[i]) if ch.is_last else None
                 self.scheduler.chunk_done(ch, first, eos_token_id)
                 n_tokens += ch.n_valid
-            tel.inc_counter("inference/prefill_tokens", v=n_tokens,
-                            help="prompt tokens written through prefill")
-        if decode:
+        tel.inc_counter("inference/prefill_tokens", v=n_tokens,
+                        help="prompt tokens written through prefill")
+        return n_tokens
+
+    def _step_decode(self, tel: Any, chunks, decode, temp,
+                     eos_token_id) -> int:
+        from ...telemetry import numerics
+
+        with tel.span("inference/pack", args={"kind": "decode"}):
             # exactly TWO decode program shapes ever compile (1 and
             # decode_burst): over-running a request's budget inside a
             # burst is safe (max_pos clamps writes, the host discards
@@ -489,10 +510,9 @@ class RaggedInferenceEngineV2:
                 kv_lens[s] = req.prefilled + len(req.generated) - 1
                 max_pos[s] = len(req.prompt) + req.max_new_tokens - 1
                 tables[s] = self.scheduler.table_row(req)
-            from ...telemetry import numerics
-
-            with tel.span("inference/decode_burst",
-                          args={"burst": burst, "batch": len(decode)}):
+        with tel.span("inference/decode_burst",
+                      args={"burst": burst, "batch": len(decode)}):
+            with tel.span("inference/decode_burst/dispatch"):
                 # the collector only matters at trace time (first call per
                 # burst length) — cached calls just return the stats the
                 # traced program already threads out
@@ -502,15 +522,16 @@ class RaggedInferenceEngineV2:
                         self.params, self.pool, jnp.asarray(tokens),
                         jnp.asarray(kv_lens), jnp.asarray(tables),
                         jnp.asarray(max_pos), temp, self._next_key())
+            with tel.span("inference/decode_burst/fetch"):
                 toks = np.asarray(toks)  # [burst, B]
+        with tel.span("inference/commit"):
             if moe_aux:
                 self._ingest_moe_stats(moe_aux, tel)
             accepted = self.scheduler.decode_burst_done(decode, toks,
                                                         eos_token_id)
-            n_tokens += accepted
-            tel.inc_counter("inference/decode_tokens", v=accepted,
-                            help="decode tokens accepted by the scheduler")
-        return n_tokens
+        tel.inc_counter("inference/decode_tokens", v=accepted,
+                        help="decode tokens accepted by the scheduler")
+        return accepted
 
     def generate(self, prompts: List[List[int]], max_new_tokens: int = 32,
                  temperature: float = 0.0, seed: int = 0,

@@ -281,6 +281,7 @@ def _flash_fwd_stream(qr, kr, vr, causal, block_q, block_k, window,
         out_shape=[jax.ShapeDtypeStruct((BH, S, d), qr.dtype),
                    jax.ShapeDtypeStruct((BH, S, 1), jnp.float32)],
         interpret=bool(interpret),
+        name="flash_fwd_stream",
     )(jnp.asarray(idx), jnp.asarray(counts), qr, kr, vr)
 
 
@@ -333,6 +334,7 @@ def _flash_call(q, k, v, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((B * h, S, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
         **resident_compiler_params(interpret),
     )(qr, kr, vr, seg)
     out = out.reshape(B, h, S, d).transpose(0, 2, 1, 3)
@@ -480,6 +482,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * h, S, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
         **resident_compiler_params(interpret),
     )(qr, dor, kr, vr, lse_r, delta_r, seg)
 
@@ -504,6 +507,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, block_q, block_k,
         out_shape=[jax.ShapeDtypeStruct((B * h, S, d), k.dtype),
                    jax.ShapeDtypeStruct((B * h, S, d), v.dtype)],
         interpret=interpret,
+        name="flash_bwd_dkv",
         **resident_compiler_params(interpret),
     )(qr, dor, kr, vr, lse_r, delta_r, seg)
 
@@ -658,6 +662,7 @@ def _flash_bwd_stream(q, k, v, out, lse, do, causal, block_q, block_k,
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((B * h, S, d), q.dtype),
         interpret=bool(interpret),
+        name="flash_bwd_dq_stream",
     )(jnp.asarray(idx), jnp.asarray(counts), qr, dor, kr, vr, lse_r,
       delta_r)
 
@@ -698,6 +703,7 @@ def _flash_bwd_stream(q, k, v, out, lse, do, causal, block_q, block_k,
         out_shape=[jax.ShapeDtypeStruct((B * h, S, d), k.dtype),
                    jax.ShapeDtypeStruct((B * h, S, d), v.dtype)],
         interpret=bool(interpret),
+        name="flash_bwd_dkv_stream",
     )(jnp.asarray(idx_k), jnp.asarray(counts_k), qr, dor, kr, vr, lse_r,
       delta_r)
 

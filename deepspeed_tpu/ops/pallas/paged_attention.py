@@ -188,6 +188,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         ),
         out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, k_pool, v_pool)
     return out

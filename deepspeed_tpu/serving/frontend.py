@@ -242,6 +242,12 @@ class ServingFrontend:
         self._watchdogs: List[Any] = []  # for detach on close()
         self._round = 0  # pump round counter: probe-memo invalidation
         self._attach_recorder()
+        # the percentile and queue gauges are worked out when the
+        # registry is read, not at the end of every round; the hub holds
+        # the hook weakly and carries it over its own reset()
+        from ..telemetry import get_telemetry
+
+        get_telemetry().add_collect_hook(self._publish_gauges)
 
     def _attach_recorder(self) -> None:
         """Every debug bundle gets a ``serving`` section."""
@@ -269,6 +275,9 @@ class ServingFrontend:
         every replica's engine, model params, and KV pool — alive for
         the life of the process."""
         self.stop()
+        from ..telemetry import get_telemetry
+
+        get_telemetry().remove_collect_hook(self._publish_gauges)
         for wd in self._watchdogs:
             try:
                 wd.remove_trip_listener(self._on_watchdog_trip)
@@ -493,40 +502,65 @@ class ServingFrontend:
         """One serving round: drain dead replicas, admit (with
         preemption), step every replica with work, deliver tokens.
         Returns tokens processed — 0 means idle."""
+        from ..telemetry import get_telemetry
+
+        tel = get_telemetry()
         with self._lock:
-            # one health-probe evaluation per replica per round: every
-            # healthy() call below this reuses the memoized verdict
             self._round += 1
-            for r in self.router.replicas:
-                r.new_round(self._round)
-            self._drain_dead()
-            if not self.router.healthy():
-                # pump/start() mode has no caller to raise to (that is
-                # run_until_idle's job): fail pending handles so
-                # consumers parked in stream()/result() unblock instead
-                # of hanging forever
-                if any(self._queues.values()):
-                    self._fail_pending_no_replica()
-                return 0
-            self._admit_all()
-            if self.params.preemption and self._queues["interactive"]:
-                if self._preempt_for_interactive():
-                    self._admit_all()
-            n = 0
-            for rep in self.router.healthy():
-                if rep.scheduler.has_work:
-                    n += rep.engine.step(
-                        temperature=self.params.temperature,
-                        eos_token_id=self.params.eos_token_id)
-                self._deliver(rep)
-                rep.update_ledger()
-            self.metrics.publish(
-                {c: len(q) for c, q in self._queues.items()},
-                self._aggregate_hit_rate(),
-                moe_imbalance={r.id: imb for r in self.router.replicas
-                               for imb in [r.moe_load_imbalance()]
-                               if imb > 0.0} or None)
-            return n
+            with tel.span("serving/pump", args={"round": self._round}):
+                # one health-probe evaluation per replica per round: every
+                # healthy() call below this reuses the memoized verdict
+                for r in self.router.replicas:
+                    r.new_round(self._round)
+                with tel.span("serving/admit") as sp:
+                    queued = sum(len(q) for q in self._queues.values())
+                    seated = 0
+                    self._drain_dead()
+                    healthy = bool(self.router.healthy())
+                    if healthy:
+                        seated = self._admit_all()
+                        if self.params.preemption \
+                                and self._queues["interactive"] \
+                                and self._preempt_for_interactive():
+                            seated += self._admit_all()
+                    sp.set(queued=queued, admitted=seated)
+                if not healthy:
+                    # pump/start() mode has no caller to raise to (that is
+                    # run_until_idle's job): fail pending handles so
+                    # consumers parked in stream()/result() unblock instead
+                    # of hanging forever
+                    if any(self._queues.values()):
+                        self._fail_pending_no_replica()
+                    return 0
+                n = 0
+                for rep in self.router.healthy():
+                    if rep.scheduler.has_work:
+                        n += rep.engine.step(
+                            temperature=self.params.temperature,
+                            eos_token_id=self.params.eos_token_id)
+                    with tel.span("serving/deliver") as sp:
+                        sp.set(tokens=self._deliver(rep, tel))
+                    with tel.span("serving/ledger"):
+                        rep.update_ledger()
+                return n
+
+    def _publish_gauges(self) -> None:
+        """The registry's collect hook.  It runs on the reader's thread
+        and takes no lock: a scrape must not wait for a round (the pump
+        holds the front-end's lock through its device calls), and what
+        it reads is safe to read beside the pump: queue lengths, the
+        prefix counters, and sample windows that copy themselves
+        (``LatencyTracker.percentile``)."""
+        from ..telemetry import get_telemetry
+
+        if not get_telemetry().enabled:
+            return
+        self.metrics.publish(
+            {c: len(q) for c, q in self._queues.items()},
+            self._aggregate_hit_rate(),
+            moe_imbalance={r.id: imb for r in self.router.replicas
+                           for imb in [r.moe_load_imbalance()]
+                           if imb > 0.0} or None)
 
     def run_until_idle(self, max_rounds: int = 100_000) -> None:
         """Pump until no queued or in-flight work remains.  Raises
@@ -662,7 +696,10 @@ class ServingFrontend:
         hb = led.heartbeat_summary().get("hbm_headroom")
         return hb is not None and hb < floor
 
-    def _admit_all(self) -> None:
+    def _admit_all(self) -> int:
+        """Seat what can be seated, in class order; returns how many
+        requests were (fresh admissions and resumed ones)."""
+        seated = 0
         degraded = self._headroom_degraded()
         for klass in CLASSES:
             if degraded and klass != "interactive":
@@ -677,6 +714,7 @@ class ServingFrontend:
                 if not self._try_admit(q[0]):
                     break  # FIFO within a class: no overtaking
                 q.pop(0)
+                seated += 1
             if q:
                 # strict priority: a class that could not fully drain
                 # blocks lower classes this round (no SLO inversion) —
@@ -686,6 +724,7 @@ class ServingFrontend:
                 # them would deadlock the whole service
                 if any(r.scheduler.has_work for r in self.router.healthy()):
                     break
+        return seated
 
     def _reserve_pages(self, rep: Replica, klass: str) -> int:
         if klass == "interactive":
@@ -738,6 +777,7 @@ class ServingFrontend:
             if h.record is not None:
                 h.record.event("admitted", replica=rep.id)
             rep.active.append(h)
+            self._queued_span(h)
             return True
         if h.record is not None:
             h.record.note_blocked_admission()
@@ -805,7 +845,24 @@ class ServingFrontend:
             break
         return preempted
 
-    def _deliver(self, rep: Replica) -> None:
+    def _queued_span(self, h: ServingHandle) -> None:
+        """A request's wait for a seat as a span of its trace id, between
+        its record's own two stamps (``queue_wait_ms_p50.batch`` reads
+        it).  A replayed request has waited twice and one without a
+        record has no stamps: neither gives a span."""
+        from ..telemetry import get_telemetry
+
+        tel = get_telemetry()
+        rec = h.record
+        if tel.enabled and rec is not None and not h.replays:
+            tel.tracer.add("serving/request/queued", rec.start_ts,
+                           rec.admitted_ts,
+                           {"trace_id": h.trace_id, "klass": h.klass})
+
+    def _deliver(self, rep: Replica, tel: Any) -> int:
+        """Push what the replica's requests generated since the last
+        round onto their streams; returns the tokens pushed."""
+        pushed = 0
         for h in list(rep.active):
             req = h.request
             new = req.generated[h.consumed:]
@@ -820,6 +877,7 @@ class ServingFrontend:
                             h.record.event("first_token",
                                            replica=rep.id)
                     h.delivered += 1
+                    pushed += 1
                     if h.record is not None:
                         h.record.token()
                     h._push(int(tok))
@@ -829,12 +887,11 @@ class ServingFrontend:
                 gen_s = (h.finished_at - (h.first_token_at
                                           or h.finished_at))
                 self.metrics.record_completion(h.klass, h.delivered, gen_s)
-                from ..telemetry import get_telemetry
-
-                get_telemetry().inc_counter(
+                tel.inc_counter(
                     f"serving/{h.klass}_tokens", v=h.delivered,
                     help="generated tokens delivered per latency class")
                 h._finish("done")
+        return pushed
 
     # -- introspection -----------------------------------------------------
 

@@ -25,9 +25,11 @@ touches a request makes the SAME decision) with always-on sampling for
 anomalous requests (replayed, preempted, failed, expired, or TTFT over
 the threshold), shipped cross-process over the PR-13 rollup transport.
 
-All ServingMetrics methods are called with the front-end's lock held
-(single writer); RequestRecord/RequestLog carry their own lock (door
-handler threads, worker protocol threads, and the pump all touch them).
+ServingMetrics is written with the front-end's lock held (single
+writer); ``publish`` alone also runs without it, on a registry reader's
+thread, and reads only what is safe beside the writer.
+RequestRecord/RequestLog carry their own lock (door handler threads,
+worker protocol threads, and the pump all touch them).
 Reads used by tests/CLI take point-in-time copies.
 """
 
@@ -63,10 +65,18 @@ class LatencyTracker:
         return len(self._samples)
 
     def percentile(self, p: float) -> float:
-        """Exact percentile over the window (nearest-rank); 0.0 empty."""
-        if not self._samples:
+        """Exact percentile over the window (nearest-rank); 0.0 empty.
+        Safe beside the writer: the registry's collect hook reads while
+        the pump appends, so a copy the deque reports as torn ("mutated
+        during iteration") is taken again."""
+        while True:
+            try:
+                ordered = sorted(self._samples)
+                break
+            except RuntimeError:
+                continue
+        if not ordered:
             return 0.0
-        ordered = sorted(self._samples)
         rank = min(len(ordered) - 1,
                    max(0, int(round(p / 100.0 * (len(ordered) - 1)))))
         return ordered[rank]
@@ -183,8 +193,11 @@ class ServingMetrics:
     def publish(self, queue_depths: Dict[str, int],
                 prefix_hit_rate: float,
                 moe_imbalance: Optional[Dict[int, float]] = None) -> None:
-        """Push the current numbers as gauges/counters through the
-        telemetry hub (no-op when telemetry is off).  ``moe_imbalance``
+        """Push the current numbers as gauges through the telemetry hub
+        (no-op when telemetry is off).  The front-end calls this from the
+        registry's collect hook, when the registry is read, and not once
+        a round: nine percentile sorts are a reader's cost, not the
+        pump's.  ``moe_imbalance``
         maps replica id → hot-expert imbalance (max/mean expert load) so
         the autoscaler and dashboards see which replica is routing
         skewed."""

@@ -3,8 +3,8 @@
 ONE pipeline correlating what used to be fragments (ISSUE 1):
 
 * :mod:`.tracer` — nested host-side spans (``telemetry.span("zero/...")``)
-  with optional device-fence close, exported as Chrome-trace JSON that
-  merges with ``profiling/collective_trace.py``'s XLA device lanes.
+  kept in a ring and entered as ``jax.profiler.TraceAnnotation``s, so a
+  profiler session shows them on the device's clock.
 * :mod:`.metrics` — counters / gauges / fixed-bucket histograms with a
   JSONL event log and Prometheus text exposition.
 * :mod:`.step_record` — the per-optimizer-step record the engine emits
@@ -12,7 +12,7 @@ ONE pipeline correlating what used to be fragments (ISSUE 1):
   single source every consumer (bench, autotuner, monitors) reads.
 
 The module-level hub is a process-global singleton, DISABLED by default:
-``span()`` returns a shared no-op context manager and the counter/gauge
+``span()`` returns one shared no-op object and the counter/gauge
 helpers early-return, so instrumented hot paths cost one attribute read
 when telemetry is off.  Enable via the ``telemetry`` config group
 (``{"telemetry": {"enabled": true, ...}}``) — wired through
@@ -22,9 +22,11 @@ when telemetry is off.  Enable via the ``telemetry`` config group
 
 from __future__ import annotations
 
+import inspect
 import os
 import threading
-from typing import Any, Dict, Optional
+import weakref
+from typing import Any, Callable, Dict, List, Optional
 
 from .collective_ledger import (CollectiveLedger, attach_collective_ledger,
                                 configure_collective_ledger,
@@ -52,7 +54,7 @@ from .rollup import (MetricsRollup, StepStream, collect_rollup,
                      push_node_telemetry, render_top, rollup_tick)
 from .step_record import (StepRecord, collect_memory_stats,
                           publish_step_record)
-from .tracer import NOOP_SPAN, SpanTracer, device_fence
+from .tracer import NOOP_SPAN, SpanTracer
 from .watchdog import (HEARTBEAT_SCHEMA_V, HangWatchdog, WatchdogTimeout,
                        cap_heartbeat_payload, get_watchdog, set_watchdog)
 
@@ -61,7 +63,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "JSONLExporter",
     "configure", "configure_from_config", "get_telemetry", "span",
     "publish_step_record", "collect_memory_stats", "parse_prometheus_text",
-    "prom_name", "device_fence", "DEFAULT_BUCKETS",
+    "prom_name", "DEFAULT_BUCKETS",
     "FlightRecorder", "configure_flight_recorder", "get_flight_recorder",
     "load_bundle", "HealthEvent", "HealthMonitor",
     "HangWatchdog", "WatchdogTimeout", "get_watchdog", "set_watchdog",
@@ -90,7 +92,10 @@ class Telemetry:
     def __init__(self) -> None:
         self.enabled = False
         self.tracer = SpanTracer()
-        self.registry = MetricsRegistry()
+        #: run before the registry is read as a whole; kept here and not
+        #: on the registry so that ``reset()`` carries them over
+        self._collect_hooks: List[Callable[[], Any]] = []
+        self.registry = MetricsRegistry(before_read=self._collect)
         self.output_path: Optional[str] = None
         self.chrome_trace = False
         self.prometheus = True
@@ -133,15 +138,47 @@ class Telemetry:
             self.enabled = False
             self.output_path = None
             self.tracer = SpanTracer(self.tracer.max_events)
-            self.registry = MetricsRegistry()
+            self.registry = MetricsRegistry(before_read=self._collect)
+
+    # -- collect hooks -----------------------------------------------------
+
+    def add_collect_hook(self, fn: Callable[[], None]) -> None:
+        """``fn()`` runs, on the reader's thread, each time the registry
+        is read as a whole (``snapshot()``, ``prometheus_text()``, so
+        ``flush()`` and the rollup beat too): the place to set gauges
+        that are derived from state kept elsewhere.  A bound method is
+        held weakly, so a hook never keeps its object alive; a reader
+        must not be made to wait, so a hook takes no lock that a hot path
+        holds for long."""
+        ref = weakref.WeakMethod(fn) if inspect.ismethod(fn) else (lambda: fn)
+        with self._lock:
+            self._collect_hooks.append(ref)
+
+    def remove_collect_hook(self, fn: Callable[[], None]) -> None:
+        with self._lock:
+            self._collect_hooks = [r for r in self._collect_hooks
+                                   if r() not in (None, fn)]
+
+    def _collect(self) -> None:
+        with self._lock:
+            live = [(r, fn) for r in self._collect_hooks
+                    for fn in [r()] if fn is not None]
+            self._collect_hooks = [r for r, _ in live]  # owners collected
+        for _, fn in live:
+            try:
+                fn()
+            except Exception as e:  # an export must survive its sources
+                from ..utils.logging import logger
+
+                logger.warning(f"telemetry: collect hook {fn!r} failed "
+                               f"({e!r})", exc_info=True)
 
     # -- hot-path surface (cheap no-ops when disabled) ---------------------
 
-    def span(self, name: str, fence: bool = False,
-             args: Optional[Dict[str, Any]] = None):
+    def span(self, name: str, args: Optional[Dict[str, Any]] = None):
         if not self.enabled:
-            return NOOP_SPAN()
-        return self.tracer.span(name, fence=fence, args=args)
+            return NOOP_SPAN
+        return self.tracer.span(name, args)
 
     def inc_counter(self, name: str, v: float = 1.0, help: str = "") -> None:
         if not self.enabled:
@@ -219,7 +256,6 @@ def configure_from_config(tcfg: Any) -> Telemetry:
         max_span_events=int(getattr(tcfg, "max_span_events", 100_000)))
 
 
-def span(name: str, fence: bool = False,
-         args: Optional[Dict[str, Any]] = None):
+def span(name: str, args: Optional[Dict[str, Any]] = None):
     """Module-level convenience: ``with telemetry.span("zero/gather"): ...``"""
-    return _default.span(name, fence=fence, args=args)
+    return _default.span(name, args)

@@ -26,7 +26,7 @@ import os
 import re
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_:]")
@@ -217,10 +217,17 @@ class JSONLExporter:
 class MetricsRegistry:
     """Get-or-create registry of named metrics + the two exporters."""
 
-    def __init__(self) -> None:
+    def __init__(self, before_read: Optional[Callable[[], None]] = None
+                 ) -> None:
         self._metrics: Dict[str, Any] = {}
         self._lock = threading.Lock()
         self.event_log: Optional[JSONLExporter] = None
+        #: called before the registry is read as a whole (``snapshot()``,
+        #: ``prometheus_text()``).  The hub runs its collect hooks here:
+        #: gauges derived from state kept elsewhere (a percentile over a
+        #: sample window) are computed when somebody looks, not on the
+        #: hot path that feeds them
+        self._before_read = before_read
 
     # -- get-or-create -----------------------------------------------------
 
@@ -261,6 +268,8 @@ class MetricsRegistry:
         cumulative) plus sum/count, so N snapshots merge by plain
         elementwise addition.  Help text rides along so the merged
         Prometheus export can render it without sharing a registry."""
+        if self._before_read is not None:
+            self._before_read()
         out: Dict[str, Any] = {"counters": {}, "gauges": {},
                                "histograms": {}}
         for name, m in self.metrics().items():
@@ -293,6 +302,8 @@ class MetricsRegistry:
 
     def prometheus_text(self) -> str:
         """The registry in Prometheus text exposition format 0.0.4."""
+        if self._before_read is not None:
+            self._before_read()
         lines: List[str] = []
         snapshot = self.metrics()  # index the snapshot: a concurrent
         for name in sorted(snapshot):  # reset() must not KeyError a flush

@@ -33,6 +33,8 @@ just isn't separately lower/compile-split.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import re
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -312,6 +314,35 @@ def format_cause(c: Dict[str, Any]) -> str:
     return f"{k}: {c.get('leaf', c.get('key', ''))}"
 
 
+def program_name(site: str, static_context: Optional[Dict[str, Any]] = None
+                 ) -> str:
+    """The name a site's program carries on the device: ``site`` with its
+    separators as ``_``, then each static that has a value as its key and
+    the value (``inference_v2/decode_burst`` with ``n_steps=8`` gives
+    ``inference_v2_decode_burst_n_steps8``, which the profiler shows as
+    module ``jit_inference_v2_decode_burst_n_steps8``)."""
+    parts = [re.sub(r"\W+", "_", site).strip("_")]
+    for key, value in sorted((static_context or {}).items()):
+        if value is None:
+            continue
+        if isinstance(value, bool):
+            value = int(value)
+        parts.append(key + re.sub(r"\W+", "_", str(value)))
+    return "_".join(parts)
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: ``jax.jit`` names a module after its
+    function's ``__name__``, which a ``functools.partial`` or a bound
+    method cannot be given (the first shows as ``jit__unknown``)."""
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
 class TrackedJit:
     """``jax.jit`` with a signature-keyed AOT cache + compile telemetry.
 
@@ -417,10 +448,14 @@ def tracked_jit(fn: Callable, site: str,
                 **jit_kwargs: Any):
     """``jax.jit`` that records compile/recompile events at ``site``.
 
-    With ``tracker=None`` (tracking off) this IS ``jax.jit(fn, **kw)`` —
+    The program is named from ``site`` and ``static_context``
+    (:func:`program_name`) whatever the tracker is, so a traced and an
+    untraced run execute the same programs under the same names.  With
+    ``tracker=None`` (tracking off) this IS ``jax.jit`` of that function:
     zero overhead, zero behavior change."""
     import jax
 
+    fn = _named(fn, program_name(site, static_context))
     if tracker is None:
         return jax.jit(fn, **jit_kwargs)
     return TrackedJit(fn, site, tracker, static_context=static_context,

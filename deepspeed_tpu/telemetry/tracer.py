@@ -1,18 +1,21 @@
-"""Host-side span tracer — nested timed regions, Chrome-trace export.
+"""Span tracer: nested timed regions of host code, written to two sinks.
 
-Role: the correlation layer the reproduction lacked (ISSUE 1).  The
-device side of every hot path is already observable through
-``profiling/collective_trace.py`` (XLA lanes under ``jax.profiler``);
-this module adds the HOST side — ``telemetry.span("zero/all_gather")``
-around dispatch/placement/IO work — and exports the same Chrome-trace
-JSON event shape (``ph: "X"`` duration events, microsecond timestamps)
-so both can be loaded into one Perfetto/chrome://tracing view and read
-against each other.
+``telemetry.span("inference/plan")`` around a piece of host work records
+one event in this tracer's ring (name, start, duration, depth, parent:
+what the benchmark's per-layer readers and the flight recorder read) and
+enters a ``jax.profiler.TraceAnnotation`` of the same name.  The second
+sink costs nothing while no profiler session runs; while one does, the
+span lands on its host thread in the profiler's own trace, on the
+device's clock, so an idle gap of the device can be named by the program
+span the host was in.  There is one file to open: the profiler's.
 
-Spans nest per thread (a thread-local stack carries depth and parent),
-are bounded in memory (``max_events`` ring), and can optionally close
-with a device fence so a span around dispatched device work measures
-execution, not enqueue.
+Spans nest per thread (a thread-local stack carries depth and parent)
+and are bounded in memory (``max_events`` ring).  A span times the host:
+around dispatched device work it measures the enqueue unless the work's
+result is fetched (or blocked on) inside it.  ``add()`` records a span
+whose two ends were stamped elsewhere (a request's phases).  The ring
+can still be exported as Chrome-trace JSON for the cluster timeline
+(``telemetry collect``), shifted by the clock-sync offset.
 """
 
 from __future__ import annotations
@@ -22,28 +25,74 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
+_TraceAnnotation = None
 
-def device_fence(value=None) -> None:
-    """Best-effort device drain.  ``jax.effects_barrier()`` only flushes
-    EFFECTS (debug callbacks, io) — it does NOT wait for dispatched pure
-    computations, so pass the ``value`` a span's work produced to get a
-    real execution fence (``block_until_ready`` on it, which waits for
-    the device on the TPU — checked on the v5e, PR 21)."""
-    try:
-        import jax
 
-        if value is not None:
-            jax.block_until_ready(value)
-        jax.effects_barrier()
-    except Exception as e:  # fence failure ⇒ host-time spans, say so once
-        from ..utils.logging import debug_once
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first live span:
+    a process with the hub off never imports the profiler for it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
 
-        debug_once("tracer/device_fence",
-                   f"device fence failed ({e!r}); span timings reflect "
-                   f"dispatch, not device completion")
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
+
+
+class _NoopSpan:
+    """What ``span()`` returns with the hub off: one shared object."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args: Any) -> None:
+        pass
+
+
+NOOP_SPAN = _NoopSpan()
+
+
+class _Span:
+    """One live span: the tracer's ring and the profiler's trace."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_annotation", "_start")
+
+    def __init__(self, tracer: "SpanTracer", name: str,
+                 args: Optional[Dict[str, Any]]):
+        self._tracer = tracer
+        self._name = name
+        self._args = dict(args) if args else {}
+        self._annotation = _trace_annotation()(name, **self._args)
+
+    def set(self, **args: Any) -> None:
+        """Arguments known only once the work is done (how many were
+        admitted): they reach the ring, not the profiler, whose
+        annotation took its arguments when the span opened."""
+        self._args.update(args)
+
+    def __enter__(self):
+        self._tracer._stack().append(self._name)
+        self._annotation.__enter__()
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        end = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        stack = self._tracer._stack()
+        stack.pop()
+        self._args["depth"] = len(stack)
+        if stack:
+            self._args["parent"] = stack[-1]
+        self._tracer._record(self._name, self._start, end, self._args)
+        return False
 
 
 class SpanTracer:
@@ -93,36 +142,28 @@ class SpanTracer:
                 self._dropped += 1  # ring full: oldest event falls off
             self._events.append(ev)
 
-    @contextmanager
-    def span(self, name: str, fence: bool = False,
-             args: Optional[Dict[str, Any]] = None):
-        """Time a nested region.  ``fence=True`` flushes jax EFFECTS
-        before the end stamp; dispatched pure computations are only
-        fenced by blocking on their results — do that INSIDE the span
-        (``jax.block_until_ready(out)`` / a dependent scalar fetch) when
-        the span must measure execution rather than enqueue."""
-        stack = self._stack()
-        stack.append(name)
-        start = time.perf_counter()
-        try:
-            yield self
-        finally:
-            if fence:
-                device_fence()
-            end = time.perf_counter()
-            stack.pop()
-            ev = {
-                "ph": "X", "cat": "host", "name": name,
-                "pid": os.getpid(), "tid": threading.get_ident(),
-                "ts": round((start - self._t0) * 1e6, 1),
-                "dur": round((end - start) * 1e6, 1),
-            }
-            span_args = dict(args or {})
-            span_args["depth"] = len(stack)
-            if stack:
-                span_args["parent"] = stack[-1]
-            ev["args"] = span_args
-            self._append(ev)
+    def _record(self, name: str, start: float, end: float,
+                args: Dict[str, Any]) -> None:
+        self._append({
+            "ph": "X", "cat": "host", "name": name,
+            "pid": os.getpid(), "tid": threading.get_ident(),
+            "ts": round((start - self._t0) * 1e6, 1),
+            "dur": round((end - start) * 1e6, 1), "args": args})
+
+    def span(self, name: str, args: Optional[Dict[str, Any]] = None
+             ) -> _Span:
+        """Time a nested region: ``with tracer.span("zero/gather"): ...``.
+        ``args`` given here reach both sinks; ``set()`` on the span adds
+        what is known only at its end."""
+        return _Span(self, name, args)
+
+    def add(self, name: str, start: float, end: float,
+            args: Optional[Dict[str, Any]] = None) -> None:
+        """A span whose ends were stamped elsewhere, in
+        ``time.perf_counter()`` seconds (a request's queue wait, from its
+        record's own stamps).  It belongs to no thread's stack, so it
+        carries neither depth nor parent, and it goes to the ring only."""
+        self._record(name, start, end, dict(args) if args else {})
 
     # ------------------------------------------------------------------
 
@@ -186,10 +227,3 @@ class SpanTracer:
         os.replace(tmp, path)  # atomic: a crashed flush never tears the file
         return path
 
-
-@contextmanager
-def _noop_cm():
-    yield None
-
-
-NOOP_SPAN = _noop_cm
