@@ -7,13 +7,39 @@ v2 kernels").  Sequences share one physical KV pool; a per-sequence block
 table maps logical KV positions onto pool blocks, so memory is allocated in
 ``block_size`` pages instead of a padded ``[B, Smax]`` rectangle.
 
-TPU-first formulation: the pool has a static shape ``[num_blocks,
-block_size, kv_h, d]`` and the block table rides the kernel's scalar
-prefetch, so the table lookup happens in the BlockSpec ``index_map`` —
-the DMA engine fetches exactly the pages a sequence owns, one page per
-sequential grid step, with the online-softmax state carried in VMEM
-scratch (same discipline as ``decode_attention.py``; a page is the unit
-of both allocation AND kernel tiling).
+The walk.  The pool ``[num_blocks, block_size, kv_h, d]`` stays in HBM and
+the kernel fetches from it itself: the grid is one step per sequence, and
+inside a step a ``fori_loop`` with a dynamic trip count walks that row's
+LIVE pages only — ``[k0, nk)`` with ``nk = ceil(length / block_size)`` and
+``k0 = max(length − window, 0) // block_size`` — ``P`` pages a compute
+step.  Page ids come from the scalar-prefetched block table; each live
+page of a step is one ``make_async_copy`` of K and one of V into slot
+``s`` of a ``[2, P, block_size·kv_h, d]`` VMEM buffer, and the next
+step's pages (the next row's first step at a row's end) are in flight in
+slot ``1 − s`` while the current step is scored.  So grid steps plus loop
+iterations are ``Σ_rows max(ceil(live_pages / P), 1)``, whatever the
+table's width; a row's last step fetches only its live pages, and what is
+left in the buffer from earlier steps is masked by position.
+
+``P`` follows from the shapes (:func:`pages_per_step`): as many pages as
+hold ``_STEP_TOKENS`` keys, no more than the table has, and no more than
+fit ``_VMEM_BUDGET_BYTES`` (both buffers of K and V, the head mask and
+the float32 score temporaries).
+
+The arithmetic.  A page is read as the matrix ``[block_size·kv_h, d]`` it
+already is in memory (row ``t·kv_h + g`` is key ``t`` of kv head ``g``),
+so a step holds ``C = P·block_size·kv_h`` key rows.  QKᵀ is ONE MXU dot
+of all ``h`` query heads against all ``C`` rows in the cache dtype with
+float32 accumulation; a constant additive mask keeps, for query head
+``r``, the columns of its own kv head (``column % kv_h == r // n_rep``).
+That spends ``kv_h``× the exponentials the result needs, and in exchange
+K and V are never upcast, repeated per group or re-laid-out by head: the
+kernel is bound by the cache it reads, not by the MXU or the VPU.  The
+``1/sqrt(d)`` scale is applied to the float32 scores.  Running max, sum
+and the ``[h, d]`` accumulator are float32 loop carries.  PV is a second
+MXU dot with float32 accumulation whose left operand, the unnormalised
+probabilities, is cast to the cache dtype first — the choice
+:func:`paged_decode_reference` and the engine's prefill path make too.
 """
 
 from __future__ import annotations
@@ -54,65 +80,130 @@ def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
     return jnp.einsum("bhk,bkhd->bhd", p, v)
 
 
-def _num_valid_blocks(length, block_size):
-    return jax.lax.div(length + block_size - 1, block_size)
+#: keys scored per compute step (``P·block_size``)
+_STEP_TOKENS = 256
+#: what one step may hold in VMEM: two slots each of K and V pages, the
+#: head mask (double-buffered by the pipeline) and three float32 ``[h, C]``
+#: temporaries of the softmax (Mosaic's default scoped limit is 16 MiB)
+_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
-def _paged_kernel(len_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, block_size: int, num_blocks: int,
-                  scale: float, n_rep: int, window=None):
+def pages_per_step(block_size: int, kv_h: int, h: int, d: int, itemsize: int,
+                   max_blocks: int) -> int:
+    """``P``: pages fetched and scored per compute step, from the shapes
+    alone."""
+    rows = block_size * kv_h
+    per_page = 4 * rows * d * itemsize + 5 * h * rows * 4
+    return max(1, min(_STEP_TOKENS // block_size, max_blocks,
+                      _VMEM_BUDGET_BYTES // per_page))
+
+
+def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, k_hbm, v_hbm,
+                  o_ref, k_buf, v_buf, sems, slot_ref, *, block_size: int,
+                  kv_h: int, scale: float, window=None):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
-    ki = pl.program_id(1)
+    num_rows = pl.num_programs(0)
+    P = k_buf.shape[1]
+    h = q_ref.shape[1]
+    C = P * block_size * kv_h
+
+    def live_pages(row):
+        """(first live page, number of live pages) of ``row``."""
+        length = len_ref[row]
+        nk = jax.lax.div(length + block_size - 1, block_size)
+        if window is None:
+            return 0, nk
+        k0 = jax.lax.div(jnp.maximum(length - window, 0), block_size)
+        return k0, nk - k0
+
+    def page_copies(page, slot, j):
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, j],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
+                                      sems.at[1, slot]))
+
+    def step_pages(n_live, i):
+        return jnp.clip(n_live - i * P, 0, P)
+
+    def start_step(row, k0, n_live, i, slot):
+        def start(j, carry):
+            for copy in page_copies(table_ref[row, k0 + i * P + j], slot, j):
+                copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, step_pages(n_live, i), start, 0)
+
+    def wait_step(n_live, i, slot):
+        def wait(j, carry):
+            # every page copy moves the same bytes, so any page's
+            # descriptor waits for one of them
+            for copy in page_copies(0, slot, j):
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, step_pages(n_live, i), wait, 0)
+
+    k0, n_live = live_pages(b)
     length = len_ref[b]
-    nk_valid = _num_valid_blocks(length, block_size)
+    # a row of length 0 still takes one (empty) step, so that every row's
+    # last step can start the next row's first
+    steps = jnp.maximum(jax.lax.div(n_live + P - 1, P), 1)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    @pl.when(b == 0)
+    def _first():
+        # masked columns carry probability 0 into the PV dot, and 0 x what
+        # an unwritten VMEM buffer holds may be NaN: after this, a slot only
+        # ever holds zeros or pages some row owns
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        start_step(0, k0, n_live, 0, 0)
 
-    if window is not None:
-        # skip blocks wholly BEFORE the window: a fully-masked block would
-        # otherwise poison the online softmax (exp(-1e30 - m) with m also
-        # -1e30 is exp(0)); the boundary block always has >=1 live entry
-        k0 = jnp.maximum(length - window, 0) // block_size
-        in_range = (ki < nk_valid) & (ki >= k0)
-    else:
-        in_range = ki < nk_valid
+    slot0 = slot_ref[0]
+    q = q_ref[0]                                   # [h, d], cache dtype
+    column = jax.lax.broadcasted_iota(jnp.int32, (h, C), 1)
 
-    @pl.when(in_range)
-    def _update():
-        q = q_ref[0].astype(jnp.float32) * scale  # [h, d]
-        h = q.shape[0]
-        kblk = k_ref[0].astype(jnp.float32)  # [block_size, kv_h, d]
-        vblk = v_ref[0].astype(jnp.float32)
-        if n_rep > 1:  # GQA groups expand in VMEM, never in the pool
-            kblk = jnp.repeat(kblk, n_rep, axis=1)
-            vblk = jnp.repeat(vblk, n_rep, axis=1)
-        s = jnp.sum(kblk * q[None, :, :], axis=-1)  # [block_size, h]
-        pos = ki * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (block_size, h), 0)
-        keep = pos < length
+    def step(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = jax.lax.rem(slot0 + i, 2)
+        last = i == steps - 1
+        nxt_row = jnp.where(last, b + 1, b)
+
+        @pl.when(nxt_row < num_rows)
+        def _prefetch():
+            nk0, nn = live_pages(nxt_row)
+            start_step(nxt_row, nk0, nn, jnp.where(last, 0, i + 1), 1 - slot)
+
+        wait_step(n_live, i, slot)
+        k = k_buf[slot].reshape(C, k_buf.shape[-1])
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * scale + head_mask_ref[...]
+        # column c of this step is position first + c // kv_h
+        first = (k0 + i * P) * block_size
+        keep = column < (length - first) * kv_h
         if window is not None:  # sliding window: only the cache tail
-            keep = keep & (pos >= length - window)
+            keep = keep & (column >= (length - window - first) * kv_h)
         s = jnp.where(keep, s, -1e30)
-        m_prev = m_ref[0]
-        l_prev = l_ref[0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-        p = jnp.exp(s - m_new[None, :])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        m_ref[0] = m_new
-        l_ref[0] = l_prev * alpha + jnp.sum(p, axis=0)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jnp.sum(p[:, :, None] * vblk, axis=0))
+        v = v_buf[slot].reshape(C, v_buf.shape[-1])
+        pv = jnp.dot(p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+        return (m_new, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+                acc * alpha + pv)
 
-    @pl.when(ki == num_blocks - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[0], 1e-9)[:, None]).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(
+        0, steps, step,
+        (jnp.full((h, 1), -jnp.inf, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32),
+         jnp.zeros((h, q_ref.shape[2]), jnp.float32)))
+    slot_ref[0] = jax.lax.rem(slot0 + steps, 2)
+    # a row of length 0 ran one step with every column masked
+    o_ref[0] = jnp.where(length > 0, acc / l, 0.0).astype(o_ref.dtype)
 
 
 def paged_decode_impl(num_heads: int, kv_heads: int,
@@ -120,7 +211,9 @@ def paged_decode_impl(num_heads: int, kv_heads: int,
     """Which path :func:`paged_decode_attention` takes for these head
     counts: ``"pallas"``, ``"pallas_interpret"`` or ``"reference"`` — the
     serving engine records it (``last_attn_path``) from the same test the
-    entry point decides by."""
+    entry point decides by.  Head counts are all it is given: a head size
+    the compiled kernel cannot take is refused at the entry point, which
+    says so once (``select.shape_refused``)."""
     if reference_off_tpu(interpret) or num_heads % kv_heads:
         return "reference"
     return "pallas_interpret" if interpret else "pallas"
@@ -131,67 +224,69 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     """One-token queries ``q [B, h, d]`` over a shared paged KV pool
     ``[N, block_size, kv_h, d]`` addressed by ``block_tables [B, max_blocks]``
     with true ``lengths [B]``.  ``window`` (sliding-window attention) is
-    handled natively by the kernel: out-of-window pages are skipped via the
-    k0 grid start in ``_paged_kernel`` and the clamped ``_kv_index``, so no
-    dead-page work is done."""
+    handled by the kernel's walk: it starts at the window's first page, so
+    pages before the window are neither fetched nor scored.  A row of
+    length 0 gives zeros.  The pool is passed as it lies in memory: the
+    ``[N, block_size·kv_h, d]`` view the kernel reads merges two adjacent
+    dims and moves nothing."""
     from jax.experimental import pallas as pl
-
-    B, h, d = q.shape
-    _, block_size, kv_h, _ = k_pool.shape
-    max_blocks = block_tables.shape[1]
-    n_rep = h // kv_h
-    if paged_decode_impl(h, kv_h, interpret) == "reference":
-        if h % kv_h:
-            shape_refused("paged_decode_attention",
-                          (tuple(q.shape), tuple(k_pool.shape)),
-                          f"kv heads {kv_h} do not divide query heads {h}")
-        return paged_decode_reference(q, k_pool, v_pool, block_tables,
-                                      lengths, window)
-    interpret = bool(interpret)
-
-    kernel = functools.partial(_paged_kernel, block_size=block_size,
-                               num_blocks=max_blocks,
-                               scale=1.0 / np.sqrt(d), n_rep=n_rep,
-                               window=window)
     from jax.experimental.pallas import tpu as pltpu
 
-    def _kv_index(b, ki, lens, table):
-        # in-range pages resolve through the block table; out-of-range grid
-        # steps clamp onto a valid page (the repeated DMA is a no-op and
-        # compute is masked); with a window, pages wholly BEFORE the
-        # window clamp forward onto the window's first page — their
-        # compute is fully masked, and their DMA collapses to a revisit
-        nk_valid = _num_valid_blocks(lens[b], jnp.int32(block_size))
-        ki_c = jnp.minimum(ki, jnp.maximum(nk_valid - 1, 0))
-        if window is not None:
-            k0 = jnp.maximum(lens[b] - window, 0) // block_size
-            ki_c = jnp.maximum(ki_c, k0)
-        return (table[b, ki_c], 0, 0, 0)
+    B, h, d = q.shape
+    N, block_size, kv_h, _ = k_pool.shape
+    max_blocks = block_tables.shape[1]
+    impl = paged_decode_impl(h, kv_h, interpret)
+    refusal = None
+    if h % kv_h:
+        refusal = f"kv heads {kv_h} do not divide query heads {h}"
+    elif impl == "pallas" and d % 128:
+        # Mosaic (jax 0.9.0) pads an HBM operand's lanes to 128 and then
+        # refuses the page-sized slice of it a DMA needs
+        refusal = f"head size {d} is not a multiple of 128 lanes"
+    if refusal or impl == "reference":
+        if refusal:
+            shape_refused("paged_decode_attention",
+                          (tuple(q.shape), tuple(k_pool.shape)), refusal)
+        return paged_decode_reference(q, k_pool, v_pool, block_tables,
+                                      lengths, window)
 
-    out = pl.pallas_call(
+    P = pages_per_step(block_size, kv_h, h, d, k_pool.dtype.itemsize,
+                       max_blocks)
+    rows = block_size * kv_h
+    # query head r reads the columns of kv head r // n_rep
+    head_mask = np.where(
+        np.arange(P * rows)[None, :] % kv_h
+        == np.arange(h)[:, None] // (h // kv_h), 0.0, -1e30
+    ).astype(np.float32)
+    kernel = functools.partial(_paged_kernel, block_size=block_size,
+                               kv_h=kv_h, scale=1.0 / np.sqrt(d),
+                               window=window)
+    q_spec = pl.BlockSpec((1, h, d), lambda b, lens, table: (b, 0, 0))
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, max_blocks),
+            grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, h, d), lambda b, ki, lens, table: (b, 0, 0)),
-                pl.BlockSpec((1, block_size, kv_h, d), _kv_index),
-                pl.BlockSpec((1, block_size, kv_h, d), _kv_index),
+                q_spec,
+                pl.BlockSpec((h, P * rows), lambda b, lens, table: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, h, d),
-                                   lambda b, ki, lens, table: (b, 0, 0)),
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((1, h), jnp.float32),
-                pltpu.VMEM((1, h), jnp.float32),
-                pltpu.VMEM((h, d), jnp.float32),
+                pltpu.VMEM((2, P, rows, d), k_pool.dtype),
+                pltpu.VMEM((2, P, rows, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
-        interpret=interpret,
+        interpret=bool(interpret),
         name="paged_decode_attention",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, k_pool, v_pool)
-    return out
+      q, jnp.asarray(head_mask), k_pool.reshape(N, rows, d),
+      v_pool.reshape(N, rows, d))
 
 
 def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, lengths,

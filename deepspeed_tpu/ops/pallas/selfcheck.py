@@ -46,9 +46,11 @@ class Check(NamedTuple):
 #: (PR 21): forward 2.1e-3..2.6e-3, gradients 4.2e-3..7.1e-3, MoE
 #: combine 5.0e-3 — the tolerance leaves about 3x over the worst of them.
 ATTENTION_TOL = 2e-2
-#: the decode kernels do float32 VPU arithmetic on one query row, so only
-#: the output rounding is left: measured 6.5e-4 (padded cache) and 9.5e-4
-#: (paged) — 5x margin, and well under a wrong page or length (O(1))
+#: one query row a sequence.  The padded-cache kernel does float32 VPU
+#: arithmetic, so only the output rounding is left: measured 6.5e-4.  The
+#: paged kernel's products run on the MXU with float32 accumulation, its
+#: probabilities rounded to the cache dtype for PV: measured 1.44e-3
+#: (PR 25) — 3.5x margin, and well under a wrong page or length (O(1))
 DECODE_TOL = 5e-3
 
 
@@ -280,19 +282,33 @@ def check_decode(s: KernelShapes, interpret: bool) -> List[Check]:
                   DECODE_TOL)]
 
 
+def _paged_lengths(s: KernelShapes, step: int) -> jnp.ndarray:
+    """The decode lengths, then as many rows in the serving regime: far
+    shorter than the table, ending inside one of the kernel's compute
+    steps of ``step`` keys, on its edge and one key past it."""
+    picks = [step // 2 + 3, step - 1, step, step + 1, 2 * step + s.page // 2,
+             3 * step + s.page + 5, 5 * step - 3, s.page]
+    short = np.resize(np.clip(picks, 1, s.cache_len), s.slots)
+    return jnp.concatenate([_decode_lengths(s),
+                            jnp.asarray(short.astype(np.int32))])
+
+
 def check_paged(s: KernelShapes, interpret: bool) -> List[Check]:
     """Paged decode through a shuffled block table, with the model's
     window and without."""
     pa = _mod("paged_attention")
     rng = np.random.RandomState(3)
     max_blocks = s.cache_len // s.page
-    num_pages = s.slots * max_blocks + 1      # page 0 stays scratch
-    q = _normal(rng, (s.slots, s.heads, s.head_dim), s.dtype)
+    rows = 2 * s.slots
+    num_pages = rows * max_blocks + 1         # page 0 stays scratch
+    q = _normal(rng, (rows, s.heads, s.head_dim), s.dtype)
     pool = (num_pages, s.page, s.kv_heads, s.head_dim)
     k_pool, v_pool = _normal(rng, pool, s.dtype), _normal(rng, pool, s.dtype)
     tables = jnp.asarray(rng.permutation(np.arange(1, num_pages)).reshape(
-        s.slots, max_blocks).astype(np.int32))
-    lengths = _decode_lengths(s)
+        rows, max_blocks).astype(np.int32))
+    lengths = _paged_lengths(s, s.page * pa.pages_per_step(
+        s.page, s.kv_heads, s.heads, s.head_dim,
+        jnp.dtype(s.dtype).itemsize, max_blocks))
     out = []
     for window in dict.fromkeys((None, s.window)):
         got = pa.paged_decode_attention(q, k_pool, v_pool, tables, lengths,

@@ -62,7 +62,6 @@ def test_paged_decode_matches_dense(n_rep):
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.slow
 def test_paged_kernel_interpret_matches_reference():
     """The Pallas kernel (interpret mode) == the jnp reference."""
     rng = np.random.RandomState(1)
@@ -328,7 +327,6 @@ def test_v2_temperature_sampling_in_graph(tiny_model):
     assert a != c  # astronomically unlikely to collide for 8 tokens
 
 
-@pytest.mark.slow
 def test_paged_kernel_window_matches_reference():
     """Windowed paged kernel (interpret) == windowed reference — including
     sequences long enough that whole pages fall before the window (the

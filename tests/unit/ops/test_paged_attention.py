@@ -1,0 +1,176 @@
+"""The paged decode kernel's walk (Pallas interpreter) against
+``paged_decode_reference``: live pages only, several pages a compute step,
+whatever the block table's width."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops.pallas import paged_attention as pa
+from deepspeed_tpu.ops.pallas.selfcheck import DECODE_TOL, _rel_err
+
+H, D, PAGE, MAX_BLOCKS = 4, 8, 8, 6
+#: pages per compute step in these tests: a step is 16 keys, the table 48
+P = 2
+STEP = P * PAGE
+
+
+@pytest.fixture
+def two_pages_a_step(monkeypatch):
+    monkeypatch.setattr(pa, "_STEP_TOKENS", STEP)
+    assert pa.pages_per_step(PAGE, 1, H, D, 4, MAX_BLOCKS) == P
+
+
+def _case(n_rep, dtype, lengths, seed=0, dead_slot=True):
+    """Rows of ``lengths`` over a shuffled, non-contiguous table, and (last)
+    a dead slot as the engine leaves one: length 1, every entry page 0."""
+    rng = np.random.RandomState(seed)
+    kv_h = H // n_rep
+    B = len(lengths) + int(dead_slot)
+    num_pages = B * MAX_BLOCKS + 1
+    q = jnp.asarray(rng.standard_normal((B, H, D)), dtype)
+    k_pool = jnp.asarray(rng.standard_normal((num_pages, PAGE, kv_h, D)),
+                         dtype)
+    v_pool = jnp.asarray(rng.standard_normal((num_pages, PAGE, kv_h, D)),
+                         dtype)
+    tables = rng.permutation(np.arange(1, num_pages)).reshape(
+        B, MAX_BLOCKS).astype(np.int32)
+    lengths = list(lengths)
+    if dead_slot:
+        tables[-1] = 0
+        lengths.append(1)
+    return (q, k_pool, v_pool, jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+
+
+#: nothing, one key, one page exactly, one compute step exactly, a step
+#: and one key, the whole table
+LENGTHS = (0, 1, PAGE, STEP, STEP + 1, MAX_BLOCKS * PAGE)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "window", [None, 5, STEP + PAGE + 3, 10 * STEP],
+    ids=["no_window", "window_in_one_step", "window_spans_steps",
+         "window_past_row"])
+@pytest.mark.parametrize("n_rep", [1, 4])
+def test_walk_matches_reference(two_pages_a_step, n_rep, window, dtype):
+    q, k_pool, v_pool, tables, lengths = _case(n_rep, dtype, LENGTHS)
+    got = pa.paged_decode_attention(q, k_pool, v_pool, tables, lengths,
+                                    interpret=True, window=window)
+    assert got.dtype == q.dtype
+    with jax.default_matmul_precision("highest"):
+        want = pa.paged_decode_reference(
+            q.astype(jnp.float32), k_pool.astype(jnp.float32),
+            v_pool.astype(jnp.float32), tables, lengths, window)
+    # the reference's softmax over no live key is uniform, not zero
+    want = jnp.where((lengths > 0)[:, None, None], want, 0.0)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+    else:
+        assert float(_rel_err(got, want)) <= DECODE_TOL
+    assert not np.any(np.asarray(got[0], np.float32))   # length 0: zeros
+
+
+@pytest.mark.parametrize("pages_a_step", [P, None],
+                         ids=["two_pages_a_step", "derived_pages_a_step"])
+def test_ragged_rows_at_the_derived_step(monkeypatch, pages_a_step):
+    """Many rows of every length up to the table, one after another (each
+    row's last step fetches the next row's first), with two pages a step
+    and with the step the shapes give (the whole table here)."""
+    if pages_a_step:
+        monkeypatch.setattr(pa, "_STEP_TOKENS", pages_a_step * PAGE)
+    lengths = list(range(0, MAX_BLOCKS * PAGE + 1, 5))
+    q, k_pool, v_pool, tables, lengths = _case(2, jnp.float32, lengths,
+                                               seed=1)
+    for window in (None, 11):
+        got = pa.paged_decode_attention(q, k_pool, v_pool, tables, lengths,
+                                        interpret=True, window=window)
+        want = pa.paged_decode_reference(q, k_pool, v_pool, tables, lengths,
+                                         window)
+        want = jnp.where((lengths > 0)[:, None, None], want, 0.0)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5,
+                                   err_msg=f"window={window}")
+
+
+@pytest.mark.parametrize("window", [None, 5, STEP + PAGE + 3],
+                         ids=["no_window", "window_in_one_step",
+                              "window_spans_steps"])
+def test_dead_pages_never_reach_the_math(two_pages_a_step, window):
+    """NaN in every page a row does not own, every page past its length
+    and every page wholly before its window: the output is finite and
+    equals the reference over a clean pool."""
+    lengths = (1, PAGE, STEP + 1, 3 * PAGE + 2, MAX_BLOCKS * PAGE)
+    q, k_pool, v_pool, tables, lens = _case(4, jnp.float32, lengths, seed=2,
+                                            dead_slot=False)
+    want = pa.paged_decode_reference(q, k_pool, v_pool, tables, lens, window)
+    tables = np.array(tables)
+    live = np.zeros(k_pool.shape[0], bool)
+    for b, n in enumerate(lengths):
+        first = max(n - window, 0) // PAGE if window else 0
+        last = -(-n // PAGE)
+        live[tables[b, first:last]] = True
+        tables[b, last:] = 0                    # page 0 is poisoned too
+    assert 0 < live.sum() < live.size - 1
+    poison = jnp.where(jnp.asarray(live)[:, None, None, None], 0.0, jnp.nan)
+    got = pa.paged_decode_attention(q, k_pool + poison, v_pool + poison,
+                                    jnp.asarray(tables), lens,
+                                    interpret=True, window=window)
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def _pallas_grids(fn, *args):
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+def test_work_does_not_scale_with_the_table():
+    """The lowered call's grid is the rows, for a table 8 pages wide and
+    for one 512 wide: no axis of the table's width."""
+    B, h, kv_h, d, page = 4, 8, 2, 128, 16
+    grids = {}
+    for max_blocks in (8, 512):
+        args = (jnp.zeros((B, h, d), jnp.bfloat16),
+                jnp.zeros((64, page, kv_h, d), jnp.bfloat16),
+                jnp.zeros((64, page, kv_h, d), jnp.bfloat16),
+                jnp.zeros((B, max_blocks), jnp.int32),
+                jnp.ones((B,), jnp.int32))
+        grids[max_blocks], = _pallas_grids(
+            lambda *a: pa.paged_decode_attention(*a, interpret=True,
+                                                 window=4096), *args)
+        assert max_blocks not in grids[max_blocks]
+    assert grids[8] == grids[512] == (B,)
+
+
+@pytest.mark.parametrize("shape, want", [
+    # Mistral-7B serving: 256 keys a step
+    ((16, 8, 32, 128, 2, 512), 16),
+    # a table narrower than a step
+    ((16, 8, 32, 128, 2, 4), 4),
+    # a TP shard's two kv heads; pages of 64 keys
+    ((16, 2, 8, 128, 2, 512), 16),
+    ((64, 8, 32, 128, 2, 128), 4),
+    # 32 kv heads of float32: the VMEM budget, not the step, bounds it
+    ((16, 32, 32, 128, 4, 512), 6),
+    ((512, 8, 32, 128, 2, 16), 1),
+])
+def test_pages_per_step_follows_the_shapes(shape, want):
+    assert pa.pages_per_step(*shape) == want
+    page, kv_h, h, d, itemsize, _ = shape
+    held = want * (4 * page * kv_h * d * itemsize + 5 * h * page * kv_h * 4)
+    assert want == 1 or held <= pa._VMEM_BUDGET_BYTES
