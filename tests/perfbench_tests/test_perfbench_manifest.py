@@ -56,14 +56,13 @@ def test_cells_are_unique_and_four_chip_cells_are_within_quota():
     assert four <= max(1, len(CELLS) // 4)
 
 
-@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
-def test_configuration(entry):
+def check_configuration(bench, entry, cfg, family):
+    """Everything one configuration is held to: its entry, its file, its
+    family's module and the operations it counts for a trained token."""
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
-    assert entry["name"] in {w["config"] for w in BENCH["workloads"]}
+    assert entry["name"] in {w["config"] for w in bench["workloads"]}
     assert entry["source"].startswith("https://") and len(entry["reduced"]) <= 16
-    assert any(entry["file"].startswith(p + "/") for p in BENCH["paths"])
-    with open(manifest.ROOT / entry["file"]) as f:
-        cfg = json.load(f)
+    assert any(entry["file"].startswith(p + "/") for p in bench["paths"])
     assert cfg["source"] == entry["source"]
     # the file says why each reduced key was changed, and no width is one
     assert set(cfg["reduced"]) == set(entry["reduced"])
@@ -72,19 +71,108 @@ def test_configuration(entry):
                              r"experts_per_tok", key)
     # its family's module is found by the published ``model_type``: the
     # program's model, a trained token's operations, the plain reference
-    family = manifest.load_module("models", cfg["model_type"])
     for name in ("build", "train_flops_per_token", "forward", "loss"):
         assert callable(getattr(family, name)), name
     text = pathlib.Path(family.__file__).read_text()
     reference = text.split("# -- the plain reference")[1]
     assert 'default_matmul_precision("highest")' in reference
     assert "deepspeed_tpu" not in reference
-    # 6 operations per weight and trained token, and attention on top
+    # 6 operations per weight a trained token passes through, and attention
+    # on top.  The bracket is worked out here from the published keys, not
+    # by the family's module.  A sparse-expert family's keys: the width of
+    # one expert is ``moe_intermediate_size`` where the config has it
+    # (DeepSeek, Qwen-MoE) and ``intermediate_size`` where it has not
+    # (OLMoE, Mixtral); a token runs ``num_experts_per_tok`` routed experts
+    # and ``n_shared_experts`` shared ones of that width; the router is one
+    # [H, E] matrix a layer, E being ``num_experts`` (OLMoE, Qwen-MoE),
+    # ``num_local_experts`` (Mixtral) or ``n_routed_experts`` (DeepSeek).
+    # A dense family has none of these keys: one FFN, no router.  Leading
+    # dense layers of another width (``first_k_dense_replace``) are not
+    # read: left to the PR that brings such a family.
     n = family.train_flops_per_token(cfg, cfg["run"].get("seq", 512)) / 6
-    H, L, I = (cfg[k] for k in ("hidden_size", "num_hidden_layers",
-                                "intermediate_size"))
-    assert L * (2 * H * H + 2 * H * I) < n < L * (5 * H * H + 4 * H * I) \
-        + 2 * H * cfg["vocab_size"]
+    H, L, V = (cfg[k] for k in ("hidden_size", "num_hidden_layers",
+                                "vocab_size"))
+    I = cfg.get("moe_intermediate_size", cfg["intermediate_size"])
+    m = cfg.get("num_experts_per_tok", 1) + cfg.get("n_shared_experts", 0)
+    R = H * next((cfg[k] for k in ("num_experts", "num_local_experts",
+                                   "n_routed_experts") if k in cfg), 0)
+    assert L * (2 * H * H + 2 * m * H * I) < n \
+        < L * (5 * H * H + 4 * m * H * I + R) + 2 * H * V
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_configuration(entry):
+    with open(manifest.ROOT / entry["file"]) as f:
+        cfg = json.load(f)
+    check_configuration(BENCH, entry, cfg,
+                        manifest.load_module("models", cfg["model_type"]))
+
+
+#: OLMoE-1B-7B-0125-Instruct's published keys (its ``config.json``), cut to
+#: the eight layers that fit one chip, with a stub family whose operation
+#: count runs ``_experts_counted`` experts a token (keys with ``_`` are the
+#: test's own)
+SPARSE = {
+    "hidden_size": 2048, "intermediate_size": 1024, "num_hidden_layers": 8,
+    "num_attention_heads": 16, "num_key_value_heads": 16, "num_experts": 64,
+    "num_experts_per_tok": 8, "norm_topk_prob": False, "vocab_size": 50304,
+    "max_position_embeddings": 4096, "model_type": "olmoe",
+    "source": "https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/"
+              "blob/main/config.json",
+    "reduced": {"num_hidden_layers": "16 -> 8"}, "run": {}}
+SPARSE_FAMILY = '''
+def build(cfg, mesh=None):
+    raise NotImplementedError("a stub: only its operation count is read")
+
+
+def train_flops_per_token(cfg, seq):
+    H, L, V = cfg["hidden_size"], cfg["num_hidden_layers"], cfg["vocab_size"]
+    experts = cfg["_experts_counted"] * 3 * H * cfg["intermediate_size"]
+    weights = L * (4 * H * H + experts + H * cfg["_experts_stored"]) + H * V
+    return 3.0 * (2 * weights + L * 2 * 2 * (seq + 1) / 2 * H)
+
+
+# -- the plain reference
+def forward(weights, cfg, ids):
+    with jax.default_matmul_precision("highest"):
+        raise NotImplementedError
+
+
+def loss(weights, cfg, batch):
+    raise NotImplementedError
+'''
+
+
+@pytest.mark.parametrize("counted, held", [(8, True), (64, False), (1, False)])
+def test_a_sparse_expert_configuration_is_held_by_its_own_keys(
+        counted, held, tmp_path):
+    """The bracket follows the configuration's sparsity: the operations of
+    the eight experts a token runs pass; those of all 64 (what the chip
+    stores) and of one (``intermediate_size`` read as a dense FFN) fail."""
+    import importlib.util
+
+    (tmp_path / "olmoe.py").write_text(SPARSE_FAMILY)
+    spec = importlib.util.spec_from_file_location("olmoe_stub",
+                                                  tmp_path / "olmoe.py")
+    family = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(family)
+    entry = {"name": "olmoe-1b-7b-serve-l8", "source": SPARSE["source"],
+             "file": "perfbench/configs/olmoe-1b-7b-serve-l8.json",
+             "reduced": ["num_hidden_layers"], "why": "64 experts, 8 a token"}
+    bench = dict(BENCH, workloads=BENCH["workloads"] + [
+        {"name": "serve-batch-olmoe-l8", "config": entry["name"]}])
+    cfg = dict(SPARSE, _experts_counted=counted, _experts_stored=64)
+    if not held:
+        with pytest.raises(AssertionError):
+            check_configuration(bench, entry, cfg, family)
+        return
+    check_configuration(bench, entry, cfg, family)
+    # the dense reading of the same keys (the bracket before PR 26) refuses
+    # the honest count: that is what kept every sparse family out
+    dense = {k: v for k, v in cfg.items()
+             if k not in ("num_experts", "num_experts_per_tok")}
+    with pytest.raises(AssertionError):
+        check_configuration(bench, entry, dense, family)
 
 
 def test_configuration_files_are_not_shared():

@@ -102,7 +102,7 @@ def test_the_check_crosses_the_sliding_window(lines):
                        max(check["prompt_tokens"]) + check["new_tokens"])
             assert longest > window + 16, cell["name"]
             assert longest <= cfg["max_position_embeddings"]
-    assert seen >= 4
+    assert seen >= 6          # three Mistral cells, real and tiny
     # the prompt beyond the traffic's longest is served and checked, and
     # the programs compiled for it alone are not counted in the peak
     serving = next(w["name"] for w in BENCH["workloads"]

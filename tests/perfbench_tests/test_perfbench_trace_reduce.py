@@ -2,10 +2,12 @@
 trace and on a hand-made one whose answers can be worked out on paper."""
 
 import pathlib
+import re
 
 import jax
 import pytest
 
+from perfbench import manifest
 from perfbench import trace_reduce as tr
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
@@ -64,6 +66,135 @@ def test_recorded_while_bodies_are_not_counted_twice(recorded):
     assert len(leaves) == len(ops) - len(containers)
     # every leaf summed is at most the busy union plus overlaps: no more
     assert sum(e.dur_ns for e in leaves) * 1e-9 <= tr.busy_s(recorded) * 1.05
+
+
+# -- kernels by name ---------------------------------------------------------
+
+#: recordings with the names PR 24 gave the kernels (my chip runs, PR 26, one
+#: "TPU v5 lite"): 21 instructions around one ``paged_decode_attention`` call
+#: of a one-step decode program of the serving cell, with ``%fusion.133``
+#: that takes the kernel's result as an operand; and 21 around one
+#: ``flash_fwd`` call of the two-layer training step
+NAMED = {"paged": ("serve_l16_v5e_paged_named.xspace.txt",
+                   "paged_decode_attention.7", 153542.5),
+         "flash": ("train_l2_v5e_flash_named.xspace.txt",
+                   "flash_fwd.21", 4061065.0)}
+KERNEL_METRICS = {"paged_attn_share.batch": "paged",
+                  "paged_attn_roofline.batch": "paged",
+                  "attention_share.train": "flash"}
+
+
+@pytest.fixture(scope="module")
+def named():
+    return {key: tr.from_profile_data(jax.profiler.ProfileData.from_text_proto(
+        (DATA / name).read_text())) for key, (name, _, _) in NAMED.items()}
+
+
+@pytest.mark.parametrize("metric", KERNEL_METRICS)
+def test_a_kernel_metric_counts_its_kernel_and_nothing_else(named, metric):
+    pattern = manifest.load_json("metrics", metric)["args"]["pattern"]
+    own = KERNEL_METRICS[metric]
+    _, kernel, kernel_ns = NAMED[own]
+    ops = named[own].devices[0].ops
+    hit = [e for e in ops if re.search(pattern, e.name)]
+    assert [tr.label(e.name).split(":")[0] for e in hit] == [kernel]
+    assert tr.opcode(hit[0].name) == "tpu_custom_call"
+    assert tr.matching_s(named[own], pattern) == pytest.approx(
+        hit[0].dur_ns * 1e-9) == pytest.approx(kernel_ns * 1e-9, rel=1e-4)
+    # the other family's kernel in the same program would not be counted
+    other = next(k for k in NAMED if k != own)
+    assert tr.matching_s(named[other], pattern) == 0.0
+    assert tr.matching_s(named[other], "tpu_custom_call") > 0.0
+
+
+def test_a_kernels_consumer_names_it_and_is_not_counted(named):
+    """An instruction's text names its operands too: the fusion after the
+    paged kernel holds ``%paged_decode_attention.7``.  The name alone
+    would count it; the pattern, anchored at the start, does not."""
+    pattern = manifest.load_json(
+        "metrics", "paged_attn_share.batch")["args"]["pattern"]
+    ops = named["paged"].devices[0].ops
+    consumers = [e for e in ops if "%paged_decode_attention.7" in e.name
+                 and not e.name.startswith("%paged_decode_attention.7 = ")]
+    assert [tr.label(e.name) for e in consumers] == ["fusion.133:f32[32]:fusion"]
+    assert not re.search(pattern, consumers[0].name)
+    kernel_s = tr.matching_s(named["paged"], pattern)
+    assert kernel_s == pytest.approx(NAMED["paged"][2] * 1e-9, rel=1e-4)
+    assert tr.matching_s(named["paged"], "paged_decode_attention") == \
+        pytest.approx(kernel_s + consumers[0].dur_ns * 1e-9)
+
+
+def _kernel_text(name: str) -> str:
+    return (f"%{name} = bf16[32,32,128]{{2,1,0:T(8,128)(2,1)}} custom-call("
+            f"bf16[32,32,128]{{2,1,0}} %fusion.1), "
+            f'custom_call_target="tpu_custom_call", operand_layout_constraints={{}}')
+
+
+def _families_matching(text: str):
+    return {own for metric, own in KERNEL_METRICS.items() if re.search(
+        manifest.load_json("metrics", metric)["args"]["pattern"], text)}
+
+
+@pytest.mark.parametrize("name, family", [
+    ("paged_decode_attention.7", "paged"), ("paged_decode_attention", "paged"),
+    ("flash_fwd.2", "flash"), ("flash_bwd_dq.1", "flash"),
+    ("flash_bwd_dkv.13", "flash"), ("flash_bwd.4", "flash"),
+    ("flash_fwd.clone.1", "flash"), ("moe_gather.3", None),
+    ("closed_call.12", None), ("my_flash_fwd.1", None)])
+def test_kernel_patterns_against_instruction_names(name, family):
+    """The names the ledger's ``breakdown`` shows, the forms a later PR
+    could give them (backward kernels merged into one, XLA's ``.clone``),
+    one that a second kernel family would bring, and the unnamed kernel
+    of PR 23's trace."""
+    text = _kernel_text(name)
+    for metric, own in KERNEL_METRICS.items():
+        pattern = manifest.load_json("metrics", metric)["args"]["pattern"]
+        assert bool(re.search(pattern, text)) == (own == family), metric
+        assert bool(re.search(pattern, text[1:])) == (own == family)  # no "%"
+        # the same name on another kind of instruction is not the kernel
+        assert not re.search(pattern, text.replace("tpu_custom_call", "Sharding"))
+
+
+#: the program's files whose kernels the kernel metrics count, and each
+#: one's family
+PALLAS = pathlib.Path(__file__).resolve().parents[2] / "deepspeed_tpu/ops/pallas"
+KERNEL_FILES = {"flash_attention.py": "flash", "paged_attention.py": "paged"}
+
+
+def _kernel_names(file: str):
+    """(number of ``pl.pallas_call`` sites, their ``name=`` arguments)."""
+    source = (PALLAS / file).read_text()
+    return (len(re.findall(r"\bpl\.pallas_call\(", source)),
+            re.findall(r'^\s+name="(\w+)",?\s*$', source, re.M))
+
+
+@pytest.mark.parametrize("file, name", [
+    (file, name) for file in KERNEL_FILES for name in _kernel_names(file)[1]])
+def test_every_kernel_the_program_names_is_counted_once(file, name):
+    """The patterns against the ``name=`` of every ``pl.pallas_call`` in
+    the program's own files, read from the source (the streaming variants
+    too, which no cell runs yet): one family's metrics count it, as XLA
+    writes it with and without a number.  A kernel renamed out of its
+    family fails here, before a traced run reads a share that fell."""
+    for text in (_kernel_text(name), _kernel_text(f"{name}.17")):
+        assert _families_matching(text) == {KERNEL_FILES[file]}, text
+
+
+@pytest.mark.parametrize("file", KERNEL_FILES)
+def test_every_kernel_of_a_counted_file_has_a_name(file):
+    calls, names = _kernel_names(file)
+    assert calls == len(names) == len(set(names)) > 0
+
+
+def test_a_share_of_nothing_is_not_read_as_zero(named):
+    """``ops_share_pct`` with no instruction to count returns nothing, so
+    the metric is left out of the line and not printed as 0."""
+    read = manifest.load_module("readers", "ops_share_pct").read
+    spec = manifest.load_json("metrics", "attention_share.train")
+    args = spec["args"]
+    assert read({"trace": named["flash"]}, args) > 0
+    assert read({"trace": named["paged"]}, args) is None
+    assert read({"trace": None}, args) is None
 
 
 # -- a trace made by hand ----------------------------------------------------
