@@ -100,16 +100,17 @@ class ModelAdapterV2:
 
 
 class LlamaV2Adapter(ModelAdapterV2):
-    """Llama/Mistral/Mixtral family: RoPE, RMSNorm, biasless projections.
-    Mixtral routes through the same hooks because ``post_attn`` delegates the
-    FFN to ``model._ffn`` (the MoE override)."""
+    """Llama/Mistral/Mixtral/OLMoE family: RoPE, RMSNorm, biasless
+    projections, the config's q/k norm.  The sparse-expert models route
+    through the same hooks because ``post_attn`` delegates the FFN to
+    ``model._ffn`` (the MoE override)."""
 
     def embed(self, params, tokens, positions):
         del positions  # rotary — positions enter at qkv time
         return jnp.take(params["embed"].astype(self.dtype), tokens, axis=0)
 
     def qkv(self, lp, x, positions):
-        from ...models.llama import _rms_norm, _rope
+        from ...models.llama import _rms_norm, _rope, apply_qk_norm
 
         c = self.config
         dt = self.dtype
@@ -117,6 +118,7 @@ class LlamaV2Adapter(ModelAdapterV2):
         q = jnp.einsum("nH,Hhd->nhd", h, lp["attn"]["wq"].astype(dt))
         k = jnp.einsum("nH,Hhd->nhd", h, lp["attn"]["wk"].astype(dt))
         v = jnp.einsum("nH,Hhd->nhd", h, lp["attn"]["wv"].astype(dt))
+        q, k = apply_qk_norm(c, lp["attn"], q, k)
         q = _rope(q, positions, c.rope_theta)
         k = _rope(k, positions, c.rope_theta)
         return q, k, v
@@ -208,5 +210,6 @@ class OPTV2Adapter(ModelAdapterV2):
 _REGISTRY = {
     "LlamaModel": LlamaV2Adapter,
     "MixtralModel": LlamaV2Adapter,
+    "OlmoeModel": LlamaV2Adapter,
     "OPTModel": OPTV2Adapter,
 }

@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -178,6 +178,9 @@ class RaggedInferenceEngineV2:
         #: host-side rolling per-expert load (fractions, sum≈1) and the
         #: derived max/mean imbalance — the router's placement signal
         self.last_moe_stats: Optional[Dict[str, Any]] = None
+        #: per program ("prefill", "decode"): (entry name, width) of each
+        #: column block of the stats it packs, written as it is traced
+        self._moe_columns: Dict[str, List[Tuple[str, int]]] = {}
         log_dist(f"inference v2: pool={self.cache_config.num_blocks}"
                  f"x{self.cache_config.block_size} tokens, "
                  f"slots={max_batch_slots}, chunk={prefill_chunk}"
@@ -208,7 +211,9 @@ class RaggedInferenceEngineV2:
         is the page bucket this program attends over — the first ``kb``
         pages of each row's table cover every key written so far, so the
         gather/mask is O(allocated), not O(max_seq_len).  Returns
-        (sampled token ids ``[Bp]``, pool)."""
+        (sampled token ids ``[Bp]``, pool, the gate's stats packed or None)."""
+        from ...telemetry import numerics
+
         ad = self.adapter
         Bp, C = tokens.shape
         bs = self.cache_config.block_size
@@ -262,17 +267,20 @@ class RaggedInferenceEngineV2:
         def layer(carry, xs):
             x, = carry
             lp, k_pool_l, v_pool_l = xs
+            mark = numerics.scan_mark()
             x, k_pool_l, v_pool_l = self._layer_step(
                 lp, k_pool_l, v_pool_l, x, pos_flat, write_fn, attend_fn)
-            return (x,), (k_pool_l, v_pool_l)
+            return (x,), (k_pool_l, v_pool_l, numerics.scan_drain(mark))
 
-        (x,), (ks, vs) = jax.lax.scan(
+        (x,), (ks, vs, stats) = jax.lax.scan(
             layer, (x,), (ad.layers(params), pool["k"], pool["v"]))
+        numerics.scan_collect(stats)  # keep the per-layer axis
         x = ad.finalize(params, x).reshape(Bp, C, -1)
         last_h = jnp.take_along_axis(
             x, last_idx[:, None, None], axis=1)[:, 0]  # [Bp, H]
         logits = ad.logits(params, last_h)  # [Bp, V]
-        return _sample(logits, temperature, key), {"k": ks, "v": vs}
+        return (_sample(logits, temperature, key), {"k": ks, "v": vs},
+                self._pack_moe_stats("prefill"))
 
     def _decode_burst_fn(self, params, pool, tokens, kv_lens, tables,
                          max_pos, temperature, key, *, n_steps: int):
@@ -282,7 +290,7 @@ class RaggedInferenceEngineV2:
         positions clamp at ``max_pos`` (a slot that hit EOS/budget inside
         the burst only scribbles its own reserved pages; the host discards
         its surplus tokens).  Returns (token ids ``[n_steps, B]``, pool,
-        moe gate stats dict or None)."""
+        the gate's stats packed or None)."""
         from ...telemetry import numerics
 
         ad = self.adapter
@@ -345,9 +353,7 @@ class RaggedInferenceEngineV2:
         (_, _, pool), (toks, stats) = jax.lax.scan(
             one_step, (tokens, kv_lens, pool), keys)
         numerics.scan_collect(stats, combine=True)  # mean over the burst
-        coll = numerics.active()
-        moe_aux = coll.harvest() if coll is not None else None
-        return toks, pool, moe_aux
+        return toks, pool, self._pack_moe_stats("decode")
 
     def _decode(self, n_steps: int) -> Callable:
         fn = self._decode_jits.get(n_steps)
@@ -371,26 +377,68 @@ class RaggedInferenceEngineV2:
 
     # -- MoE serving telemetry -----------------------------------------
 
-    def _ingest_moe_stats(self, moe_aux: Dict[str, Any], tel: Any) -> None:
-        """Host-side decode of the burst's gate stats: per-expert load
-        gauges + the imbalance/drop scalars the router and autoscaler
-        read.  Telemetry must never kill a decode step."""
+    def _pack_moe_stats(self, program: str) -> Optional[jnp.ndarray]:
+        """Inside a program, after its layer scan: the active collector's
+        entries (each with the per-layer axis the scan gave it: ``[L]`` or
+        ``[L, E]``) as ONE float32 array ``[L, columns]``, so that the host
+        fetches the router's stats in one transfer beside the tokens
+        (fetched entry by entry they cost the serving round 7.5 ms of
+        host, PERF.md PR 27).  Which columns hold what is a fact of the
+        trace, kept for ``program`` in ``_moe_columns``."""
         from ...telemetry import numerics
 
-        try:
-            decoded = numerics.decode(moe_aux)
-            summary = numerics.summarize(decoded)
-        except Exception:  # pragma: no cover - defensive
+        coll = numerics.active()
+        named = coll.harvest() if coll is not None else None
+        if not named:
+            return None
+        layers = self.adapter.num_layers
+        blocks = [(key.partition(":")[2],
+                   named[key].astype(jnp.float32).reshape(layers, -1))
+                  for key in sorted(named)]
+        self._moe_columns[program] = [(name, int(b.shape[1]))
+                                      for name, b in blocks]
+        return jnp.concatenate([b for _, b in blocks], axis=1)
+
+    def _ingest_moe_stats(self, packed: np.ndarray, tel: Any, program: str,
+                          steps: int = 1) -> None:
+        """Host side of one call's gate stats.  Every call feeds the
+        dropless layer's counters; a decode burst (``steps`` steps, the
+        stats their mean) also sets what the router and autoscaler read:
+        per-expert load gauges and the imbalance/drop scalars.  Telemetry
+        must never kill a serving round: a layout that does not fit the
+        array, or an entry the gate did not report, is skipped."""
+        layout = self._moe_columns.get(program, ())
+        if packed.ndim != 2 or packed.shape[1] != sum(w for _, w in layout):
             return
-        load = np.asarray(decoded.get("moe", {}).get("load", []),
-                          dtype=np.float64)
-        if load.ndim > 1:  # [L, E] → mean over the layer axis
-            load = load.reshape(-1, load.shape[-1]).mean(axis=0)
-        stats = {
-            "load": load.tolist(),
-            "imbalance": float(summary.get("moe_load_imbalance", 0.0)),
-            "drop_rate": float(summary.get("moe_drop_rate", 0.0)),
-        }
+        cols, at = {}, 0
+        for name, width in layout:
+            cols[name] = packed[:, at:at + width]
+            at += width
+        if tel.enabled and "moe/experts_active" in cols \
+                and "moe/assignments" in cols:
+            # rows x k is the same in every layer; non-empty groups are not
+            tel.inc_counter(
+                "inference/moe/assignments",
+                v=float(cols["moe/assignments"].mean()) * steps,
+                help="token-to-expert assignments computed: rows x k, a "
+                     "step of a call")
+            tel.inc_counter(
+                "inference/moe/experts_active",
+                v=float(cols["moe/experts_active"].sum()) * steps,
+                help="experts with at least one row (whose weights the "
+                     "grouped matmul reads), summed over layers and steps")
+        load = cols.get("moe/load")
+        if program != "decode" or load is None:
+            return
+        load = load.astype(np.float64)                        # [L, E]
+        mean = load.mean(axis=1)
+        # max/mean of the hottest layer: 1.0 = a balanced router
+        hottest = np.where(mean > 0, load.max(axis=1)
+                           / np.maximum(mean, 1e-12), 0.0).max()
+        drop = cols.get("moe/drop_rate")
+        stats = {"load": load.mean(axis=0).tolist(),
+                 "imbalance": float(hottest),
+                 "drop_rate": float(drop.mean()) if drop is not None else 0.0}
         self.last_moe_stats = stats
         if not tel.enabled:
             return
@@ -404,6 +452,15 @@ class RaggedInferenceEngineV2:
         tel.set_gauge("inference/moe/drop_rate", stats["drop_rate"],
                       help="capacity-dropped token fraction of the last "
                            "decode burst")
+
+    def _collecting_moe(self):
+        """Around a program call: the collector only matters at trace time
+        (the first call of a shape); cached calls just return the stats
+        the traced program already threads out."""
+        from ...telemetry import numerics
+
+        return (numerics.collecting(self._moe_coll)
+                if self._moe_coll is not None else _null_ctx())
 
     def moe_load_imbalance(self) -> float:
         """Router-facing hot-expert signal: max/mean expert load of the
@@ -469,16 +526,19 @@ class RaggedInferenceEngineV2:
                 start[i] = ch.start_pos
                 last[i] = max(ch.n_valid - 1, 0)
         with tel.span("inference/prefill", args={"chunks": len(chunks)}):
-            with tel.span("inference/prefill/dispatch"):
-                sampled, self.pool = self._prefill(
+            with tel.span("inference/prefill/dispatch"), \
+                    self._collecting_moe():
+                sampled, self.pool, moe_aux = self._prefill(
                     self.params, self.pool, jnp.asarray(tokens),
                     jnp.asarray(tables), jnp.asarray(start),
                     jnp.asarray(last), temp, self._next_key(),
                     kb=self._prefill_bucket(chunks))
             with tel.span("inference/prefill/fetch"):
-                sampled = np.asarray(sampled)
+                sampled, moe_aux = jax.device_get((sampled, moe_aux))
         n_tokens = 0
         with tel.span("inference/commit"):
+            if moe_aux is not None:
+                self._ingest_moe_stats(moe_aux, tel, "prefill")
             for i, ch in enumerate(chunks):
                 first = int(sampled[i]) if ch.is_last else None
                 self.scheduler.chunk_done(ch, first, eos_token_id)
@@ -489,8 +549,6 @@ class RaggedInferenceEngineV2:
 
     def _step_decode(self, tel: Any, chunks, decode, temp,
                      eos_token_id) -> int:
-        from ...telemetry import numerics
-
         with tel.span("inference/pack", args={"kind": "decode"}):
             # exactly TWO decode program shapes ever compile (1 and
             # decode_burst): over-running a request's budget inside a
@@ -513,20 +571,16 @@ class RaggedInferenceEngineV2:
         with tel.span("inference/decode_burst",
                       args={"burst": burst, "batch": len(decode)}):
             with tel.span("inference/decode_burst/dispatch"):
-                # the collector only matters at trace time (first call per
-                # burst length) — cached calls just return the stats the
-                # traced program already threads out
-                with numerics.collecting(self._moe_coll) \
-                        if self._moe_coll is not None else _null_ctx():
+                with self._collecting_moe():
                     toks, self.pool, moe_aux = self._decode(burst)(
                         self.params, self.pool, jnp.asarray(tokens),
                         jnp.asarray(kv_lens), jnp.asarray(tables),
                         jnp.asarray(max_pos), temp, self._next_key())
             with tel.span("inference/decode_burst/fetch"):
-                toks = np.asarray(toks)  # [burst, B]
+                toks, moe_aux = jax.device_get((toks, moe_aux))  # [burst, B]
         with tel.span("inference/commit"):
-            if moe_aux:
-                self._ingest_moe_stats(moe_aux, tel)
+            if moe_aux is not None:
+                self._ingest_moe_stats(moe_aux, tel, "decode", steps=burst)
             accepted = self.scheduler.decode_burst_done(decode, toks,
                                                         eos_token_id)
         tel.inc_counter("inference/decode_tokens", v=accepted,

@@ -11,9 +11,11 @@ that compose with the ZeRO sharding policy.
 from .bert import BertConfig, BertModel
 from .llama import LlamaConfig, LlamaModel
 from .mixtral import MixtralConfig, MixtralModel
+from .olmoe import OlmoeConfig, OlmoeModel
 from .opt import OPTConfig, OPTModel
 from .resnet import ResNetConfig, ResNetModel
 
 __all__ = ["BertConfig", "BertModel", "LlamaConfig", "LlamaModel",
-           "MixtralConfig", "MixtralModel", "OPTConfig", "OPTModel",
+           "MixtralConfig", "MixtralModel", "OlmoeConfig", "OlmoeModel",
+           "OPTConfig", "OPTModel",
            "ResNetConfig", "ResNetModel"]

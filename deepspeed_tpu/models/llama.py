@@ -58,6 +58,12 @@ class LlamaConfig:
     #: prefill mask by window; decode masks the cache tail (a rolling
     #: window KV cache is a serving optimization for a later round).
     sliding_window: Optional[int] = None
+    #: OLMoE's q/k norm: an RMSNorm with its own weight
+    #: (``layers.attn.q_norm`` / ``k_norm [L, heads·d]``) over the WHOLE q
+    #: and k projection, before the split into heads and before rotary.
+    #: Read by :func:`apply_qk_norm`, which every attention path calls: the
+    #: model's own forward, its dense-cache decode and the v2 adapter.
+    qk_norm: bool = False
     dtype: Any = jnp.bfloat16
     remat: bool = True
     #: >1 → chunk final projection+loss over the sequence so the [B,S,V]
@@ -210,6 +216,22 @@ def _rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
     return (x32 * jax.lax.rsqrt(var + eps)).astype(dt) * w
 
 
+def apply_qk_norm(c: LlamaConfig, attn: Any, q: jnp.ndarray, k: jnp.ndarray
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``c.qk_norm`` applied to projections ``[..., heads, d]`` (identity
+    where the config has none): each is flattened to ``[..., heads·d]``,
+    normalised as one vector and split again; rotary comes after."""
+    if not c.qk_norm:
+        return q, k
+
+    def normed(x, w):
+        flat = x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
+        return _rms_norm(flat, w.astype(x.dtype), c.rms_norm_eps
+                         ).reshape(x.shape)
+
+    return normed(q, attn["q_norm"]), normed(k, attn["k_norm"])
+
+
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """Rotary embedding on [..., S, h, D] with positions [..., S]."""
     d = x.shape[-1]
@@ -295,6 +317,11 @@ class LlamaModel:
             },
             "final_norm": jnp.ones((H,), jnp.float32),
         }
+        if c.qk_norm:
+            params["layers"]["attn"]["q_norm"] = jnp.ones((L, nh * hd),
+                                                          jnp.float32)
+            params["layers"]["attn"]["k_norm"] = jnp.ones((L, nkv * hd),
+                                                          jnp.float32)
         if not c.tie_embeddings:
             params["lm_head"] = normal(next(k), (H, V), H)
         return params
@@ -330,6 +357,9 @@ class LlamaModel:
             },
             "final_norm": P(None),
         }
+        if self.config.qk_norm:
+            specs["layers"]["attn"]["q_norm"] = P(pipe, None)
+            specs["layers"]["attn"]["k_norm"] = P(pipe, None)
         if not self.config.tie_embeddings:
             specs["lm_head"] = P(None, t)  # vocab-sharded output projection
         return specs
@@ -417,6 +447,7 @@ class LlamaModel:
         q = jnp.einsum("bsH,Hhd->bshd", h, lp["attn"]["wq"].astype(c.dtype))
         kk = jnp.einsum("bsH,Hhd->bshd", h, lp["attn"]["wk"].astype(c.dtype))
         vv = jnp.einsum("bsH,Hhd->bshd", h, lp["attn"]["wv"].astype(c.dtype))
+        q, kk = apply_qk_norm(c, lp["attn"], q, kk)
         if n_rep > 1 and not ring_active:
             # GQA: repeat KV heads so every Ulysses rank holds a slice;
             # the ring path rotates kv-width blocks and expands per-visit
@@ -471,6 +502,10 @@ class LlamaModel:
         the full ``[B, S, H]`` activation (replicated over tensor)."""
         c = self.config
         n_rep = c.num_heads // c.num_kv_heads
+        if c.qk_norm:
+            raise NotImplementedError(
+                "qk_norm spans the whole projection; the manual-TP layer "
+                "holds a slice of the heads")
 
         h = _rms_norm(x, lp["attn_norm"].astype(c.dtype), c.rms_norm_eps)
         h = _tp_copy(h)
@@ -640,6 +675,7 @@ class LlamaModel:
             q = jnp.einsum("bsH,Hhd->bshd", h, lp["attn"]["wq"].astype(c.dtype))
             kk = jnp.einsum("bsH,Hhd->bshd", h, lp["attn"]["wk"].astype(c.dtype))
             vv = jnp.einsum("bsH,Hhd->bshd", h, lp["attn"]["wv"].astype(c.dtype))
+            q, kk = apply_qk_norm(c, lp["attn"], q, kk)
             q = _rope(q, positions, c.rope_theta)
             kk = _rope(kk, positions, c.rope_theta)
             # cache keeps the GQA (kv-head) layout; expand only for compute
@@ -685,6 +721,7 @@ class LlamaModel:
             q = jnp.einsum("bH,Hhd->bhd", h, lp["attn"]["wq"].astype(c.dtype))
             kk = jnp.einsum("bH,Hhd->bhd", h, lp["attn"]["wk"].astype(c.dtype))
             vv = jnp.einsum("bH,Hhd->bhd", h, lp["attn"]["wv"].astype(c.dtype))
+            q, kk = apply_qk_norm(c, lp["attn"], q, kk)
             q = _rope(q[:, None], pos, c.rope_theta)[:, 0]
             kk = _rope(kk[:, None], pos, c.rope_theta)[:, 0]
             # cache stays in kv-head layout; the kernel expands GQA groups

@@ -60,9 +60,16 @@ class MixtralModel(LlamaModel):
     def __init__(self, config: MixtralConfig, mesh: Any = None):
         super().__init__(config, mesh=mesh)
         self.aux_loss_coef = config.aux_loss_coef
+        self._moe_layer = self._build_moe_layer()
+
+    def _build_moe_layer(self) -> Any:
+        """The routed FFN, called as ``(wg, experts, h) → (y, l_aux, meta)``:
+        GShard's capacity gate here; a family with another routing rule
+        (``OlmoeModel``) overrides this alone."""
         from ..moe.layer import swiglu_expert_fn
         from ..moe.sharded_moe import MOELayer, TopKGate
 
+        config = self.config
         gate = TopKGate(num_experts=config.num_experts, k=config.top_k,
                         capacity_factor=config.capacity_factor,
                         eval_capacity_factor=config.capacity_factor,
@@ -71,8 +78,8 @@ class MixtralModel(LlamaModel):
             swiglu_expert_fn,
             constrain_act=lambda a: self._constrain(
                 a, AXIS_EXPERT, None, AXIS_TENSOR))
-        self._moe_layer = MOELayer(gate, expert_fn, mesh=mesh,
-                                   dispatch_impl=config.moe_dispatch_impl)
+        return MOELayer(gate, expert_fn, mesh=self.mesh,
+                        dispatch_impl=config.moe_dispatch_impl)
 
     # ------------------------------------------------------------------
 

@@ -38,6 +38,46 @@ def swiglu_expert_fn(params: Any, x: jnp.ndarray,
     return jnp.einsum("eci,eih->ech", act, params["w_down"].astype(dt))
 
 
+class DroplessMoE:
+    """Top-k routed SwiGLU experts with no capacity: every assignment is
+    computed (``sharded_moe.top_k_routing``), on the sorted layout of
+    ``ops/pallas/moe_grouped_matmul`` (tokens gathered by expert, one
+    grouped matmul for gate/up and one for down, the weighted sum back).
+    Same call as :class:`~.sharded_moe.MOELayer`:
+    ``(wg [H, E], {w_gate, w_up [E, H, I], w_down [E, I, H]}, x [B, S, H])
+    → (y, l_aux, meta)``.  The kernels run on an unsharded TPU; anywhere else
+    (and under a mesh of several devices, where a Mosaic call does not
+    partition itself) the same layout runs ``jax.lax.ragged_dot``."""
+
+    def __init__(self, num_experts: int, k: int, renormalize: bool = False,
+                 mesh: Any = None):
+        self.num_experts = num_experts
+        self.k = k
+        self.renormalize = renormalize
+        self.mesh = mesh
+
+    def __call__(self, wg: jnp.ndarray, expert_params: Any, x: jnp.ndarray
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
+        from ..ops.pallas import moe_grouped_matmul as gm
+        from .sharded_moe import top_k_routing
+
+        B, S, H = x.shape
+        tokens = x.reshape(B * S, H)
+        expert_idx, weights, meta = top_k_routing(
+            wg, tokens, self.k, self.renormalize)
+        plan = gm.plan_groups(
+            expert_idx, self.num_experts,
+            gm.tile_rows_for(B * S * self.k, self.num_experts, x.dtype))
+        sharded = self.mesh is not None and self.mesh.size > 1
+        rows = gm.gather_rows(tokens, plan)
+        act = gm.grouped_swiglu(rows, expert_params["w_gate"],
+                                expert_params["w_up"], plan, sharded=sharded)
+        out = gm.grouped_matmul(act, expert_params["w_down"], plan,
+                                sharded=sharded)
+        y = gm.combine_rows(out, plan, weights).astype(x.dtype)
+        return y.reshape(B, S, H), meta["l_aux"], meta
+
+
 class MoE:
     """Reference-shaped MoE block."""
 

@@ -236,6 +236,40 @@ def top_k_gating_indices(logits: jnp.ndarray, k: int, capacity: int,
     return gi, core["l_aux"], meta
 
 
+def top_k_routing(wg: jnp.ndarray, x: jnp.ndarray, k: int,
+                  renormalize: bool = False
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, Any]]:
+    """Dropless top-k routing for any ``k``: ``x [T, H]``, ``wg [H, E]`` →
+    ``(expert_idx [T, k] int32, weights [T, k] float32, meta)``.
+
+    The router product is accumulated in float32 and the softmax over all
+    ``E`` experts is float32; the ``k`` weights are the softmax values as
+    they are, or divided by their sum where ``renormalize`` (the published
+    ``norm_topk_prob``).  No capacity: every assignment is computed,
+    whatever else the batch holds, so ``drop_rate`` is 0 by construction.
+    ``l_aux`` is the load-balancing loss of the sparse-expert decoders
+    (``E · Σ_e f_e · P_e`` with ``f_e`` the assignments an expert gets per
+    token and ``P_e`` its mean probability)."""
+    T, E = x.shape[0], wg.shape[1]
+    logits = jnp.einsum("th,he->te", x, wg.astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, expert_idx = jax.lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    counts = jnp.sum(_one_hot(expert_idx.reshape(-1), E), axis=0)
+    me = jnp.mean(probs, axis=0)
+    zero = jnp.float32(0.0)
+    meta = GateMeta({
+        "l_aux": jnp.sum(counts / max(T, 1) * me) * E,
+        "exp_counts": counts, "load": counts / max(T * k, 1),
+        "entropy": -jnp.sum(me * jnp.log(jnp.maximum(me, 1e-9))),
+        "overflow_frac": zero, "drop_rate": zero,
+        "assignments": jnp.float32(T * k),
+        "experts_active": jnp.sum(counts > 0).astype(jnp.float32)})
+    return expert_idx.astype(jnp.int32), weights, meta
+
+
 @dataclasses.dataclass
 class TopKGate:
     """Router config + params-free apply (reference ``TopKGate`` ctor keys).

@@ -385,6 +385,43 @@ def check_moe(s: KernelShapes, interpret: bool) -> List[Check]:
                   ATTENTION_TOL)]
 
 
+def check_moe_grouped(s: KernelShapes, interpret: bool) -> List[Check]:
+    """The dropless expert layer (rows sorted by expert, the two grouped
+    matmuls, the weighted sum back) against each expert run over all
+    tokens in float32.  Experts of OLMoE's width, or the model's FFN where
+    that is narrower; routing skewed so that groups differ in size."""
+    gm = _mod("moe_grouped_matmul")
+    rng = np.random.RandomState(8)
+    T, E, K, H = s.moe_tokens, s.moe_experts, s.moe_top_k, s.hidden
+    inner = min(s.ffn, 1024)
+    logits = _normal(rng, (T, E), jnp.float32) + jnp.linspace(1.0, 0.0, E)
+    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+    x = _normal(rng, (T, H), s.dtype)
+    w_gate, w_up = (_normal(rng, (E, H, inner), s.dtype, H ** -0.5)
+                    for _ in range(2))
+    w_down = _normal(rng, (E, inner, H), s.dtype, inner ** -0.5)
+    plan = gm.plan_groups(idx, E, gm.tile_rows_for(T * K, E, s.dtype))
+    act = gm.grouped_swiglu(gm.gather_rows(x, plan), w_gate, w_up, plan,
+                            interpret=interpret)
+    got = gm.combine_rows(gm.grouped_matmul(act, w_down, plan,
+                                            interpret=interpret), plan, gates)
+
+    xe = x.astype(jnp.float32)
+
+    def one_expert(y, e):
+        out = (jax.nn.silu(xe @ w_gate[e].astype(jnp.float32))
+               * (xe @ w_up[e].astype(jnp.float32))
+               ) @ w_down[e].astype(jnp.float32)
+        weight = jnp.sum(jnp.where(idx == e, gates, 0.0), axis=1)
+        return y + weight[:, None] * out, None
+
+    with jax.default_matmul_precision("highest"):
+        want, _ = jax.lax.scan(one_expert, jnp.zeros((T, H), jnp.float32),
+                               jnp.arange(E))
+    return [Check("moe_grouped_matmul", float(_rel_err(got, want)),
+                  ATTENTION_TOL)]
+
+
 def check_quantizer(s: KernelShapes, interpret: bool) -> List[Check]:
     qz = _mod("quantizer")
     rng = np.random.RandomState(6)
@@ -430,7 +467,8 @@ def check_block_sparse(s: KernelShapes, interpret: bool) -> List[Check]:
 
 
 CHECKS = (check_flash, check_flash_streamed, check_decode, check_paged,
-          check_fused_adam, check_moe, check_quantizer, check_block_sparse)
+          check_fused_adam, check_moe, check_moe_grouped, check_quantizer,
+          check_block_sparse)
 
 
 def run_checks(shapes: KernelShapes, interpret: bool = False

@@ -163,7 +163,8 @@ def moe_stats(meta: Dict[str, Any]) -> None:
     c = _active
     if c is None or not c.want_moe:
         return
-    for key in ("load", "entropy", "drop_rate", "overflow_frac"):
+    for key in ("load", "entropy", "drop_rate", "overflow_frac",
+                "assignments", "experts_active"):
         if key in meta:
             c.add(MOE_PREFIX + key, meta[key])
 

@@ -1,0 +1,136 @@
+"""The dropless expert layout and its grouped matmul: the plan's
+invariants for any routing, the Pallas kernels (interpreter) and the
+``ragged_dot`` reference against one expert at a time over all tokens, and
+the kernels' gradients, which run through the reference."""
+
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops.pallas import moe_grouped_matmul as gm
+
+T, K, E, H, I = 24, 4, 8, 128, 256
+
+
+def _routings():
+    spread = jax.lax.top_k(jax.random.normal(jax.random.PRNGKey(0), (T, E)),
+                           K)[1]
+    same = jnp.tile(jnp.arange(2, 2 + K)[None], (T, 1))   # four experts only
+    one_heavy = spread.at[:, 0].set(5)
+    return {"spread": spread, "all_rows_the_same_experts": same,
+            "one_heavy_expert": one_heavy}
+
+
+ROUTINGS = _routings()
+
+
+def _weights(seed=1):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(ks[0], (T, H)),
+            jax.random.normal(ks[1], (E, H, I)) / np.sqrt(H),
+            jax.random.normal(ks[2], (E, H, I)) / np.sqrt(H),
+            jax.random.normal(ks[3], (E, I, H)) / np.sqrt(I),
+            jax.random.uniform(ks[4], (T, K)))
+
+
+def _layer(plan, x, w_gate, w_up, w_down, gates, interpret):
+    rows = gm.gather_rows(x, plan)
+    act = gm.grouped_swiglu(rows, w_gate, w_up, plan, interpret=interpret)
+    out = gm.grouped_matmul(act, w_down, plan, interpret=interpret)
+    return gm.combine_rows(out, plan, gates)
+
+
+def _one_expert_at_a_time(idx, x, w_gate, w_up, w_down, gates):
+    y = jnp.zeros((T, H))
+    for e in range(E):
+        out = (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]
+        y += jnp.sum(jnp.where(idx == e, gates, 0.0), axis=1)[:, None] * out
+    return y
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("tile_rows", [8, 16, 32])
+def test_every_assignment_has_a_row_of_its_own_experts_tile(routing, tile_rows):
+    idx = ROUTINGS[routing]
+    plan = jax.tree.map(np.asarray, gm.plan_groups(idx, E, tile_rows))
+    tiles = T * K // tile_rows + min(E, T * K)
+    assert plan.tile_group.shape == (tiles,)
+    assert plan.row_valid.shape == (tiles * tile_rows,)
+    n = int(plan.num_tiles[0])
+    assert n == sum(-(-c // tile_rows) for c in plan.group_sizes) <= tiles
+    assert plan.group_sizes.sum() == T * K and plan.row_valid.sum() == T * K
+    # nothing dropped: the T*k destinations are distinct, valid rows that
+    # hold the assignment's own token, in a tile of the assignment's expert
+    dest = plan.dest.reshape(-1)
+    assert len(set(dest.tolist())) == T * K
+    assert plan.row_valid[dest].all()
+    assert (plan.row_token[dest] == np.repeat(np.arange(T), K)).all()
+    assert (plan.tile_group[dest // tile_rows]
+            == np.asarray(idx).reshape(-1)).all()
+    # tiles in use are sorted by expert; the unused tail repeats the last
+    assert (np.diff(plan.tile_group) >= 0).all()
+    assert (plan.tile_group[n:] == plan.tile_group[n - 1]).all()
+    assert not plan.row_valid[n * tile_rows:].any()
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["pallas_interpret", "reference"])
+def test_layer_equals_one_expert_at_a_time(routing, interpret):
+    """float32 at ``highest``: sums of the same products in another order."""
+    idx = ROUTINGS[routing]
+    plan = gm.plan_groups(idx, E, gm.tile_rows_for(T * K, E, jnp.float32))
+    args = _weights()
+    with jax.default_matmul_precision("highest"):
+        got = _layer(plan, *args, interpret=interpret)
+        want = _one_expert_at_a_time(idx, *args)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+
+
+def test_gradients_of_the_kernel_path_are_the_references():
+    """The kernels carry no backward kernel: their ``custom_vjp`` runs the
+    reference's.  Every operand's gradient against the plain layer's."""
+    idx = ROUTINGS["spread"]
+    plan = gm.plan_groups(idx, E, 16)
+    args = _weights(2)
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(lambda *a: jnp.sum(_layer(plan, *a, interpret=True) ** 2),
+                       argnums=(0, 1, 2, 3, 4))(*args)
+        want = jax.grad(lambda *a: jnp.sum(_one_expert_at_a_time(idx, *a) ** 2),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+    for g, w in zip(got, want):
+        assert float(jnp.max(jnp.abs(g - w))) < 1e-4 * float(jnp.max(jnp.abs(w)))
+
+
+def test_bfloat16_rows_accumulate_in_float32():
+    idx = ROUTINGS["spread"]
+    plan = gm.plan_groups(idx, E, 16)
+    x, w_gate, w_up, w_down, gates = _weights(3)
+    bf = lambda a: a.astype(jnp.bfloat16)
+    got = _layer(plan, bf(x), bf(w_gate), bf(w_up), bf(w_down), gates, True)
+    with jax.default_matmul_precision("highest"):
+        want = _one_expert_at_a_time(idx, *(bf(a).astype(jnp.float32) for a in
+                                            (x, w_gate, w_up, w_down)), gates)
+    # two bf16 roundings (the activation, the output) of ~2^-8 each
+    assert float(jnp.max(jnp.abs(got - want))) < 0.03 * float(jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("assignments, experts, dtype, rows", [
+    (32 * 8, 64, jnp.bfloat16, 16),      # a decode step of the 8-of-64 model
+    (256 * 8, 64, jnp.bfloat16, 64),     # a prefill call
+    (8192 * 2, 8, jnp.bfloat16, 128),    # a training row of an 8x7B
+    (4, 8, jnp.float32, 8)])
+def test_tile_rows_follow_the_mean_group(assignments, experts, dtype, rows):
+    assert gm.tile_rows_for(assignments, experts, dtype) == rows
+
+
+def test_every_kernel_is_named_for_the_device_trace():
+    source = pathlib.Path(gm.__file__).read_text()
+    assert len(re.findall(r"\bpl\.pallas_call\(", source)) == 1
+    assert "interpret=interpret, name=name," in source
+    names = re.findall(r'_differentiable\(\w+, "(\w+)"', source)
+    assert sorted(names) == ["moe_grouped_matmul", "moe_grouped_matmul_swiglu"]

@@ -34,7 +34,9 @@ def one_chip():
     (8, 8, 2, jnp.bfloat16, 4096),       # a tensor-parallel shard of it
     (8, 32, 32, jnp.bfloat16, None),     # no grouping (Llama-7B)
     (8, 32, 8, jnp.float32, 4096),
-], ids=["mistral7b", "mistral7b_no_window", "tp4_shard", "mha", "float32"])
+    (32, 16, 16, jnp.bfloat16, None),    # OLMoE-1B-7B: pages [16, 16, 128]
+], ids=["mistral7b", "mistral7b_no_window", "tp4_shard", "mha", "float32",
+        "olmoe_16_heads"])
 def test_paged_decode_compiles_for_v5e(one_chip, rows, h, kv_h, dtype,
                                        window):
     """The compiled program is the Mosaic call alone: the pool reaches it
@@ -58,3 +60,38 @@ def test_paged_decode_compiles_for_v5e(one_chip, rows, h, kv_h, dtype,
     # "%name = <shape and layout> <opcode>(...": what holds the pool's shape
     made = re.findall(rf"= \w+\[{pages},\S* ([\w-]+)\(", text)
     assert made and set(made) <= {"parameter", "bitcast"}, made
+
+
+@pytest.mark.parametrize("rows", [32, 256], ids=["decode_step", "prefill_call"])
+def test_grouped_expert_matmul_compiles_for_v5e_inside_a_layer_scan(
+        one_chip, rows, monkeypatch):
+    """The dropless expert layer at OLMoE-1B-7B's widths (64 experts of
+    [2048, 1024], 8 a token), scanned over the stacked layers as the
+    serving programs scan them: both Mosaic calls are there, once each."""
+    from deepspeed_tpu.moe import DroplessMoE
+    from deepspeed_tpu.ops.pallas import moe_grouped_matmul as gm
+
+    # the layer asks the platform, which is the CPU here: steer it onto
+    # the path it takes on the chip
+    monkeypatch.setattr(gm, "reference_off_tpu", lambda interpret: False)
+    L, E, H, I, k = 8, 64, 2048, 1024, 8
+    layer_fn = DroplessMoE(E, k)
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(x, wg, w_gate, w_up, w_down):
+        def one(x, lp):
+            wg_l, experts = lp
+            y, _, _ = layer_fn(wg_l, experts, x[None])
+            return x + y[0], None
+
+        return jax.lax.scan(one, x, (wg, {"w_gate": w_gate, "w_up": w_up,
+                                          "w_down": w_down}))[0]
+
+    text = jax.jit(fn).lower(
+        arg((rows, H)), arg((L, H, E)), arg((L, E, H, I)), arg((L, E, H, I)),
+        arg((L, E, I, H))).compile().as_text()
+    assert len(re.findall(r"moe_grouped_matmul_swiglu[\w.]* = ", text)) == 1
+    assert len(re.findall(r"moe_grouped_matmul\.[\w.]* = |"
+                          r"moe_grouped_matmul = ", text)) == 1
