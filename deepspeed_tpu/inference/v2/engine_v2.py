@@ -24,7 +24,12 @@ Architecture deltas (norms, positions, FFN, head) live in
 same engine (reference keeps per-arch model implementations under
 ``inference/v2/model_implementations`` [K]).
 
-Both programs donate the pool, so KV updates are in-place in HBM.
+Both programs donate the pool and carry it WHOLE through their layer
+scan, addressed by ``(layer, page)``: a step's rows (decode) or pages
+(prefill) are scattered into it in place, and attention reads pages
+``l·N + page`` of its flat view.  No program forms a layer's ``pool[l]``
+(see ``_layer_step`` for why), so KV updates are in-place in HBM and no
+call moves more of the cache than it reads or writes.
 
 Prefill cost is O(pages allocated so far), not O(max_seq_len): each
 chunk call gathers/masks only ``kb`` pages per row, where ``kb`` is the
@@ -191,17 +196,58 @@ class RaggedInferenceEngineV2:
     # compiled programs
     # ------------------------------------------------------------------
 
-    def _layer_step(self, lp, k_pool_l, v_pool_l, x_flat, positions_flat,
-                    write_fn, attend_fn):
+    def _layer_step(self, lp, l, pool, x_flat, positions_flat, write_fn,
+                    attend_fn):
         """Shared per-layer skeleton: qkv → KV write → attention →
         post-attn block.  ``write_fn``/``attend_fn`` differ between the
-        prefill and decode programs."""
+        prefill and decode programs.
+
+        ``pool`` is the WHOLE pool ``{"k", "v"}: [L, N, bs, kv_h, d]``, a
+        carry of the layer scan, and ``l`` this layer's index: the write
+        scatters rows or pages at ``(l, page)`` and attention reads pages
+        ``l·N + page`` of the flat view (:meth:`_flat_pool`).  No layer's
+        ``pool[l]`` is ever formed: a slice of a scanned stack handed to
+        a custom call (the paged kernel) is copied out and the updated
+        layer copied back, which was 62% of the serving cell's device
+        time (PERF.md §6, PRs 25, 27 and 28).  Write, then attend: only
+        the written pool lives on, so the write stays in place."""
         ad = self.adapter
         q, kk, vv = ad.qkv(lp, x_flat, positions_flat)
-        k_pool_l, v_pool_l = write_fn(k_pool_l, v_pool_l, kk, vv)
-        attn = attend_fn(q, k_pool_l, v_pool_l)
+        pool = write_fn(pool, l, kk, vv)
+        attn = attend_fn(q, pool, l)
         x_flat = ad.post_attn(lp, x_flat, attn)
-        return x_flat, k_pool_l, v_pool_l
+        return x_flat, pool
+
+    @staticmethod
+    def _flat_pool(pool):
+        """The pool as ``[L·N, bs, kv_h, d]``: layer ``l``'s page ``p`` is
+        page ``l·N + p``.  Two adjacent major dims merged: a bitcast."""
+        return {name: a.reshape((-1,) + a.shape[2:])
+                for name, a in pool.items()}
+
+    def _scan_layers(self, params, pool, x, positions_flat, write_fn,
+                     attend_fn):
+        """The layer scan of both programs.  Carry: the activations and
+        the pool; ``xs``: each layer's parameters and its index; ``ys``:
+        the MoE gate's stats (``moe_stats`` inside ``model._ffn``), which
+        must leave the scan as ``ys`` — names ride the dict keys."""
+        from ...telemetry import numerics
+
+        ad = self.adapter
+
+        def layer(carry, xs):
+            x, pool = carry
+            lp, l = xs
+            mark = numerics.scan_mark()
+            x, pool = self._layer_step(lp, l, pool, x, positions_flat,
+                                       write_fn, attend_fn)
+            return (x, pool), numerics.scan_drain(mark)
+
+        (x, pool), stats = jax.lax.scan(
+            layer, (x, pool),
+            (ad.layers(params), jnp.arange(ad.num_layers, dtype=jnp.int32)))
+        numerics.scan_collect(stats)  # keep the per-layer axis
+        return x, pool
 
     def _prefill_batch_fn(self, params, pool, tokens, tables, start_pos,
                           last_idx, temperature, key, *, kb):
@@ -212,8 +258,6 @@ class RaggedInferenceEngineV2:
         pages of each row's table cover every key written so far, so the
         gather/mask is O(allocated), not O(max_seq_len).  Returns
         (sampled token ids ``[Bp]``, pool, the gate's stats packed or None)."""
-        from ...telemetry import numerics
-
         ad = self.adapter
         Bp, C = tokens.shape
         bs = self.cache_config.block_size
@@ -237,21 +281,28 @@ class RaggedInferenceEngineV2:
             p, karange, causal=True, window=self.window))(positions)
         mask = mask[:, None]  # [Bp, 1(head), C, mb*bs]
 
-        def write_fn(k_pool_l, v_pool_l, kk, vv):
-            k_pool_l = k_pool_l.at[pages_flat].set(
-                kk.reshape(Bp * (C // bs), bs, ad.kv_heads, ad.head_dim))
-            v_pool_l = v_pool_l.at[pages_flat].set(
-                vv.reshape(Bp * (C // bs), bs, ad.kv_heads, ad.head_dim))
-            return k_pool_l, v_pool_l
+        n_pages = self.cache_config.num_blocks
+        page_shape = (Bp * (C // bs), bs, ad.kv_heads, ad.head_dim)
 
-        def attend_fn(q, k_pool_l, v_pool_l):
+        def write_fn(pool, l, kk, vv):
+            # whole pages, scattered at (l, page) into the carried pool
+            return {"k": pool["k"].at[l, pages_flat].set(
+                        kk.reshape(page_shape)),
+                    "v": pool["v"].at[l, pages_flat].set(
+                        vv.reshape(page_shape))}
+
+        def attend_fn(q, pool, l):
             # gather only the bucket's pages (every key written so far
             # lives in the first kb pages of each row's table) and attend
-            # chunk-queries over them — O(allocated), not O(max_seq_len)
-            kf = k_pool_l[tables[:, :mb]].reshape(Bp, mb * bs, ad.kv_heads,
-                                                  ad.head_dim)
-            vf = v_pool_l[tables[:, :mb]].reshape(Bp, mb * bs, ad.kv_heads,
-                                                  ad.head_dim)
+            # chunk-queries over them — O(allocated), not O(max_seq_len).
+            # One gather out of the carried buffer's flat view, never a
+            # layer sliced out first
+            flat = self._flat_pool(pool)
+            idx = tables[:, :mb] + l * n_pages
+            kf = flat["k"][idx].reshape(Bp, mb * bs, ad.kv_heads,
+                                        ad.head_dim)
+            vf = flat["v"][idx].reshape(Bp, mb * bs, ad.kv_heads,
+                                        ad.head_dim)
             if n_rep > 1:
                 kf = jnp.repeat(kf, n_rep, axis=2)
                 vf = jnp.repeat(vf, n_rep, axis=2)
@@ -264,22 +315,13 @@ class RaggedInferenceEngineV2:
             attn = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
             return attn.reshape(Bp * C, ad.num_heads, ad.head_dim)
 
-        def layer(carry, xs):
-            x, = carry
-            lp, k_pool_l, v_pool_l = xs
-            mark = numerics.scan_mark()
-            x, k_pool_l, v_pool_l = self._layer_step(
-                lp, k_pool_l, v_pool_l, x, pos_flat, write_fn, attend_fn)
-            return (x,), (k_pool_l, v_pool_l, numerics.scan_drain(mark))
-
-        (x,), (ks, vs, stats) = jax.lax.scan(
-            layer, (x,), (ad.layers(params), pool["k"], pool["v"]))
-        numerics.scan_collect(stats)  # keep the per-layer axis
+        x, pool = self._scan_layers(params, pool, x, pos_flat, write_fn,
+                                    attend_fn)
         x = ad.finalize(params, x).reshape(Bp, C, -1)
         last_h = jnp.take_along_axis(
             x, last_idx[:, None, None], axis=1)[:, 0]  # [Bp, H]
         logits = ad.logits(params, last_h)  # [Bp, V]
-        return (_sample(logits, temperature, key), {"k": ks, "v": vs},
+        return (_sample(logits, temperature, key), pool,
                 self._pack_moe_stats("prefill"))
 
     def _decode_burst_fn(self, params, pool, tokens, kv_lens, tables,
@@ -296,6 +338,7 @@ class RaggedInferenceEngineV2:
         ad = self.adapter
         B = tokens.shape[0]
         bs = self.cache_config.block_size
+        n_pages = self.cache_config.num_blocks
 
         def one_step(carry, key):
             tokens, kv_lens, pool = carry
@@ -305,11 +348,17 @@ class RaggedInferenceEngineV2:
             offsets = wp % bs
             x = ad.embed(params, tokens, wp)
 
-            def write_fn(k_pool_l, v_pool_l, kk, vv):
-                return (k_pool_l.at[page_ids, offsets].set(kk),
-                        v_pool_l.at[page_ids, offsets].set(vv))
+            def write_fn(pool, l, kk, vv):
+                # one scatter of [B, kv_h, d] rows at (l, page, offset)
+                return {"k": pool["k"].at[l, page_ids, offsets].set(kk),
+                        "v": pool["v"].at[l, page_ids, offsets].set(vv)}
 
-            def attend_fn(q, k_pool_l, v_pool_l):
+            def attend_fn(q, pool, l):
+                # the kernel fetches pages from HBM by page id: it gets
+                # the whole pool's flat view, and the layer's offset is
+                # folded into the tables it prefetches anyway
+                flat = self._flat_pool(pool)
+                layer_tables = tables + l * n_pages
                 # what paged_decode_attention will run for these head
                 # counts on this platform, by its own test
                 impl = paged_decode_impl(ad.num_heads // self._tp,
@@ -323,31 +372,20 @@ class RaggedInferenceEngineV2:
 
                     self.last_attn_path = f"{impl}_tp_shard_map"
                     return paged_decode_attention_tp(
-                        q, k_pool_l, v_pool_l, tables, wp + 1,
+                        q, flat["k"], flat["v"], layer_tables, wp + 1,
                         mesh=self.mesh, window=self.window)
                 self.last_attn_path = impl
-                return paged_decode_attention(q, k_pool_l, v_pool_l, tables,
-                                              wp + 1, window=self.window)
+                return paged_decode_attention(
+                    q, flat["k"], flat["v"], layer_tables, wp + 1,
+                    window=self.window)
 
-            def layer(carry, xs):
-                x, = carry
-                lp, k_pool_l, v_pool_l = xs
-                mark = numerics.scan_mark()
-                x, k_pool_l, v_pool_l = self._layer_step(
-                    lp, k_pool_l, v_pool_l, x, wp, write_fn, attend_fn)
-                # MoE gate stats (moe_stats inside model._ffn) must exit
-                # the layer scan as ys — names ride the dict keys
-                stats = numerics.scan_drain(mark)
-                return (x,), (k_pool_l, v_pool_l, stats)
-
-            (x,), (ks, vs, stats) = jax.lax.scan(
-                layer, (x,), (ad.layers(params), pool["k"], pool["v"]))
-            numerics.scan_collect(stats)  # keep the per-layer axis
+            x, pool = self._scan_layers(params, pool, x, wp, write_fn,
+                                        attend_fn)
             x = ad.finalize(params, x)
             logits = ad.logits(params, x)  # [B, V]
             nxt = _sample(logits, temperature, key)
             step_stats = numerics.scan_drain(step_mark)
-            return (nxt, kv_lens + 1, {"k": ks, "v": vs}), (nxt, step_stats)
+            return (nxt, kv_lens + 1, pool), (nxt, step_stats)
 
         keys = jax.random.split(key, n_steps)
         (_, _, pool), (toks, stats) = jax.lax.scan(

@@ -7,10 +7,21 @@ is committed in page units as sequences grow instead of a padded
 ``[B, max_len]`` rectangle up front.
 
 TPU-first: the pool is ONE device array per K/V with the layer dim stacked
-(``[L, num_blocks, block_size, kv_h, d]``) so the per-layer ``lax.scan``
-in the decode program slices it like every other stacked-layer tensor;
-page bookkeeping (free list, tables) is plain host Python — it never
-enters the compiled program, which only ever sees int32 table arrays.
+(``[L, num_blocks, block_size, kv_h, d]``).  Inside the engine's programs
+it is a CARRIED BUFFER addressed by ``(layer, page)``: it rides the
+per-layer ``lax.scan`` as a carry beside the activations (the scan's
+``xs`` are a layer's parameters and its index), a layer's rows or pages
+are scattered into it in place at ``(l, page)``, and attention reads it
+through the flat view ``[L·num_blocks, ...]`` with ``l·num_blocks`` added
+to the block tables.  It is NOT scanned over like the stacked weights: a
+layer sliced out of a scanned stack and handed to a custom call (the paged
+kernel) is copied out, and the updated layer copied back into a fresh
+stack: 62% of a serving cell's device time before PR 28 (PERF.md §6; PR
+27 met the same copy on the expert stack).  Outside the programs the
+shape is what callers index: ``pool["k"][:, block]`` is one page's planes
+over all layers (``serving/kv_transfer.py``).  Page bookkeeping (free
+list, tables) is plain host Python — it never enters the compiled
+program, which only ever sees int32 table arrays.
 """
 
 from __future__ import annotations

@@ -428,3 +428,153 @@ def test_v2_llama_has_no_moe_collector(tiny_model):
     eng2.generate([[5, 6, 7]], max_new_tokens=4)
     assert eng2.last_moe_stats is None
     assert eng2.moe_load_imbalance() == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the pool as a carried buffer addressed by (layer, page)  (PR 28)
+# ---------------------------------------------------------------------------
+# Three or more layers everywhere: with two, a layer index that is off by
+# one, or a layer's offset l*N missing from the tables, still lands on a
+# layer that exists.
+
+
+def _three_layer_model(kind):
+    if kind == "opt":
+        from deepspeed_tpu.models.opt import OPTConfig, OPTModel
+
+        model = OPTModel(OPTConfig.tiny(num_layers=3, max_seq_len=64,
+                                        dtype=jnp.float32))
+    else:
+        kw = {"gqa_window": dict(num_kv_heads=4, sliding_window=8),
+              "mha": dict(num_kv_heads=8)}[kind]
+        model = LlamaModel(LlamaConfig.tiny(num_layers=3, max_seq_len=64,
+                                            dtype=jnp.float32, **kw))
+    return model, model.init_params(jax.random.PRNGKey(7))
+
+
+@pytest.mark.parametrize("kind, engine_kw, prompt_lens", [
+    # contexts of up to 27 tokens under an 8-token window
+    ("gqa_window", dict(prefill_chunk=8, decode_burst=4), (3, 13, 21)),
+    ("mha", dict(prefill_chunk=8, decode_burst=4), (3, 13, 21)),
+    ("opt", dict(prefill_chunk=8, decode_burst=4), (3, 10, 17)),
+    # two sequences' chunks in one prefill call, at different positions
+    # of their prompts and in different rows of the call; the last call
+    # holds one row and an all-zero table beside it
+    ("gqa_window", dict(prefill_chunk=8, prefill_batch=2, decode_burst=8),
+     (5, 22)),
+], ids=["gqa_window", "mha", "opt", "two_chunks_a_call"])
+def test_v2_serves_the_dense_forward_tokens(kind, engine_kw, prompt_lens):
+    """Served through the carried pool, token for token what the dense-cache
+    forward pass generates."""
+    model, params = _three_layer_model(kind)
+    rng = np.random.RandomState(21)
+    prompts = [rng.randint(1, 512, size=n).tolist() for n in prompt_lens]
+    eng = build_engine_v2(
+        model, params,
+        cache_config=KVCacheConfig(num_blocks=48, block_size=4,
+                                   max_seq_len=64),
+        max_batch_slots=4, **engine_kw)
+    got = eng.generate(prompts, max_new_tokens=6)
+    for prompt, g in zip(prompts, got):
+        assert g == _v1_greedy(model, params, prompt, 6), len(prompt)
+    assert eng.scheduler.allocator.num_free == 47
+
+
+def _rows_written(before, after):
+    """{(layer, page, offset)} of the pool rows a program changed."""
+    changed = np.any(before != after, axis=(3, 4))          # [L, N, bs]
+    return {tuple(int(i) for i in idx) for idx in np.argwhere(changed)}
+
+
+def test_v2_burst_clamped_at_max_pos_writes_only_its_own_rows():
+    """An eight-step burst in which one slot reaches ``max_pos`` after
+    three steps: its clamped writes stay on its own last position, the
+    other slot writes its eight, the idle slots scribble on the scratch
+    page, and every other row of every layer is bit for bit what it was."""
+    model, params = _three_layer_model("gqa_window")
+    bs = 4
+    eng = build_engine_v2(
+        model, params,
+        cache_config=KVCacheConfig(num_blocks=32, block_size=bs,
+                                   max_seq_len=64),
+        max_batch_slots=4, prefill_chunk=8, prefill_batch=2, decode_burst=8)
+    rng = np.random.RandomState(5)
+    short, long_ = (rng.randint(1, 512, size=n).tolist() for n in (6, 7))
+    reqs = [eng.put(short, 4), eng.put(long_, 14)]
+    while eng.scheduler.waiting or eng.scheduler.prefilling:
+        eng.step()
+    assert [len(r.generated) for r in reqs] == [1, 1]
+    # what was never written reads as noise: a row that moves shows
+    noise = jax.random.normal(jax.random.PRNGKey(1), eng.pool["k"].shape)
+    keep = np.zeros(eng.pool["k"].shape[1:3], bool)          # [N, bs]
+    for r in reqs:
+        for pos in range(len(r.prompt)):
+            keep[r.blocks[pos // bs], pos % bs] = True
+    keep = jnp.asarray(keep)[None, :, :, None, None]
+    eng.pool = {name: jnp.where(keep, a, noise)
+                for name, a in eng.pool.items()}
+    before = {name: np.asarray(a) for name, a in eng.pool.items()}
+    expect = {(0, 0)}                                # idle slots: scratch
+    for r, steps in zip(reqs, (4, 8)):     # 4 = up to max_pos, then clamped
+        first = r.prefilled + len(r.generated) - 1
+        expect |= {(r.blocks[pos // bs], pos % bs)
+                   for pos in range(first, first + steps)}
+    eng.step()                                       # the eight-step burst
+    assert [len(r.generated) for r in reqs] == [4, 9]
+    for name in ("k", "v"):
+        rows = _rows_written(before[name], np.asarray(eng.pool[name]))
+        assert rows == {(l, page, off) for l in range(3)
+                        for page, off in expect}, name
+    while eng.scheduler.has_work:
+        eng.step()
+    assert reqs[0].generated == _v1_greedy(model, params, short, 4)
+    assert reqs[1].generated == _v1_greedy(model, params, long_, 14)
+
+
+def test_v2_kv_pages_exported_and_imported_decode_the_same_token():
+    """``kv_transfer`` sees the pool's external shape only: a prefilled
+    request's pages leave one engine as ``pool[:, block]`` planes, land in
+    another engine's pool at other block ids, and the decode program reads
+    them there through (layer, page) to the token the first engine makes."""
+    from deepspeed_tpu.serving.kv_transfer import inject_pages, page_payload
+
+    model, params = _three_layer_model("gqa_window")
+    bs = 4
+
+    def build():
+        return build_engine_v2(
+            model, params,
+            cache_config=KVCacheConfig(num_blocks=24, block_size=bs,
+                                       max_seq_len=64),
+            max_batch_slots=2, prefill_chunk=8, decode_burst=1)
+
+    prompt = np.random.RandomState(9).randint(1, 512, size=13).tolist()
+    src = build()
+    req = src.put(prompt, 3)
+    while not req.generated:
+        src.step()
+    n_pages = -(-len(prompt) // bs)
+    payloads = {i: page_payload(src, prompt, req.blocks, i)
+                for i in range(n_pages)}
+    assert payloads[0]["shape"] == [3, bs, 4, 16]
+
+    dst = build()
+    blocks = [17, 5, 11, 2]
+    inject_pages(dst, blocks, payloads)
+    for i, block in enumerate(blocks):
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(dst.pool[name][:, block]),
+                np.asarray(src.pool[name][:, req.blocks[i]]))
+    untouched = np.ones(24, bool)
+    untouched[blocks] = False
+    assert not np.asarray(dst.pool["k"])[:, untouched].any()
+    src.step()                                  # the source's own next token
+    tables = np.zeros((2, dst.cache_config.max_blocks_per_seq), np.int32)
+    tables[0, :len(blocks)] = blocks
+    toks, dst.pool, _ = dst._decode(1)(
+        dst.params, dst.pool, jnp.asarray([req.generated[0], 0], jnp.int32),
+        jnp.asarray([len(prompt), 0], jnp.int32), jnp.asarray(tables),
+        jnp.asarray([len(prompt) + 2, 0], jnp.int32), jnp.float32(0.0),
+        jax.random.PRNGKey(0))
+    assert int(toks[0, 0]) == req.generated[1]

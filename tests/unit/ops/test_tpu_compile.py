@@ -4,6 +4,7 @@ caught here and not on the chip.  The topology is described inside a
 fixture (one process may hold libtpu; see the on-chip-measurement guide),
 and every such compile lives in this one file."""
 
+import functools
 import os
 import re
 
@@ -16,15 +17,19 @@ from deepspeed_tpu.ops.pallas import paged_attention as pa
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no libtpu here, or another process holds it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -60,6 +65,177 @@ def test_paged_decode_compiles_for_v5e(one_chip, rows, h, kv_h, dtype,
     # "%name = <shape and layout> <opcode>(...": what holds the pool's shape
     made = re.findall(rf"= \w+\[{pages},\S* ([\w-]+)\(", text)
     assert made and set(made) <= {"parameter", "bitcast"}, made
+
+
+#: the serving cells' models at their published widths, cut to two layers
+#: (the pool's page shape is what is held here: ``[16, 8, 128]`` under a
+#: 4,096-token window and ``[16, 16, 128]``), and each cell's context limit
+_SERVING_CELLS = {
+    "mistral7b": dict(vocab_size=32000, hidden_size=4096,
+                      intermediate_size=14336, num_heads=32, num_kv_heads=8,
+                      max_seq_len=8192, sliding_window=4096),
+    "olmoe": dict(vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+                  num_heads=16, num_kv_heads=16, max_seq_len=4096,
+                  num_experts=64, top_k=8, norm_topk_prob=False,
+                  aux_loss_coef=0.0),
+}
+_POOL_LAYERS, _POOL_PAGES, _PAGE, _SLOTS = 2, 3200, 16, 32
+
+
+def pool_value_faults(text, layers, pages, page, kv_h, d):
+    """What in a compiled program's text makes a value of the KV pool's
+    shape, or of one layer of it, other than by passing the buffer on
+    (``parameter``, ``bitcast``, ``get-tuple-element``; ``while`` and
+    ``tuple`` have tuple shapes) or writing rows or pages into it in place
+    (a ``scatter`` or ``dynamic-update-slice``, or a fusion whose root is
+    one).  A ``copy``, a ``dynamic-slice`` of a layer and the update-slice
+    that puts a layer back are all among them.  Empty for a program in
+    which the pool is a carried buffer addressed by (layer, page)."""
+    def dims(*shape):
+        return ",".join(str(n) for n in shape)
+
+    whole = {dims(layers, pages, page, kv_h, d),           # as it is held
+             dims(layers * pages, page, kv_h, d),          # flat, 4-D
+             dims(layers * pages, page * kv_h, d)}         # as the kernel reads
+    layer = {dims(pages, page, kv_h, d), dims(1, pages, page, kv_h, d),
+             dims(pages, page * kv_h, d)}
+    passes_on = {"parameter", "bitcast", "get-tuple-element"}
+    writes = {"scatter", "dynamic-update-slice"}
+    made = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = \w+\[([\d,]+)\]\S* "
+                      r"([\w-]+)\((.*)$", text, re.M)
+    roots, computation = {}, None         # computation -> its root's opcode
+    for line in text.splitlines():
+        head = re.match(r"%?([\w.-]+) \(.*\{$", line)
+        root = re.match(r"\s*ROOT %?[\w.-]+ = \S+ ([\w-]+)\(", line)
+        if head:
+            computation = head.group(1)
+        elif root:
+            roots[computation] = root.group(1)
+    faults = []
+    for name, shape, opcode, rest in made:
+        if shape in layer:
+            faults.append(f"{opcode} {name} makes one layer [{shape}]")
+        elif shape in whole and opcode not in passes_on | writes:
+            called = re.search(r"calls=%?([\w.-]+)", rest)
+            if not (opcode == "fusion" and called
+                    and roots.get(called.group(1)) in writes):
+                faults.append(f"{opcode} {name} makes the pool [{shape}]")
+    return faults
+
+
+@pytest.fixture(scope="module",
+                params=sorted(_SERVING_CELLS) + ["mistral7b_tp4"])
+def serving_programs(request, topo, one_chip):
+    """A cell's engine over shapes alone (no weight and no pool exists), and
+    a function that compiles one of its programs for the described chip;
+    ``_tp4``: for the four described chips, heads split over ``tensor``."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from deepspeed_tpu import models
+    from deepspeed_tpu.inference.v2 import engine_v2 as ev2
+    from deepspeed_tpu.inference.v2.kv_cache import KVCacheConfig
+    from deepspeed_tpu.ops.pallas import moe_grouped_matmul as gm
+    from deepspeed_tpu.parallel.mesh import (MeshLayout, build_mesh,
+                                             strip_manual_axes)
+
+    cell, _, tp = request.param.partition("_tp")
+    tp = int(tp or 1)
+    widths = _SERVING_CELLS[cell]
+    mesh = build_mesh(MeshLayout(tp=tp), devices=topo.devices) \
+        if tp > 1 else None
+    family = (models.OlmoeConfig, models.OlmoeModel) \
+        if "num_experts" in widths else (models.LlamaConfig, models.LlamaModel)
+    model = family[1](family[0](num_layers=_POOL_LAYERS, remat=False,
+                                **widths), mesh=mesh)
+    cache = KVCacheConfig(num_blocks=_POOL_PAGES, block_size=_PAGE,
+                          max_seq_len=widths["max_seq_len"])
+
+    def placed(tree, spec=PartitionSpec()):
+        """Shapes with their place: the one chip, or the mesh under
+        ``spec`` (one for every leaf, or a tree of them)."""
+        def at(a, where):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where)
+
+        if mesh is None:
+            return jax.tree.map(lambda a: at(a, one_chip), tree)
+        if isinstance(spec, PartitionSpec):
+            spec = jax.tree.map(lambda _: spec, tree)
+        return jax.tree.map(lambda a, s: at(a, NamedSharding(mesh, s)),
+                            tree, spec)
+
+    def arg(shape, dt=jnp.int32):
+        return placed(jax.ShapeDtypeStruct(shape, dt))
+
+    mp = pytest.MonkeyPatch()
+    # the engine and both kernels ask the platform, which is the CPU here:
+    # steer them onto the path they take on the chip, and build no pool
+    mp.setattr(pa, "reference_off_tpu", lambda interpret: False)
+    mp.setattr(gm, "reference_off_tpu", lambda interpret: False)
+    real_pool = ev2.init_kv_pool
+    mp.setattr(ev2, "init_kv_pool",
+               lambda ad, cc: jax.eval_shape(lambda: real_pool(ad, cc)))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    engine = ev2.RaggedInferenceEngineV2(model, shapes, cache,
+                                         max_batch_slots=_SLOTS)
+    # given the mesh at birth the engine would place real weights on it
+    engine.mesh, engine._tp = mesh, tp
+    params = placed(shapes, jax.tree.map(
+        lambda spec: strip_manual_axes(*spec), model.param_specs(shapes),
+        is_leaf=lambda spec: isinstance(spec, PartitionSpec)))
+    pool = placed(engine.pool,
+                  PartitionSpec(None, None, None, "tensor", None))
+    blocks = cache.max_blocks_per_seq
+    common = (arg((), jnp.float32),
+              placed(jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+
+    def compiled_text(program):
+        kind, _, n = program.rpartition("_")
+        if kind == "decode_burst":
+            fn = functools.partial(engine._decode_burst_fn, n_steps=int(n))
+            rows = (arg((_SLOTS,)), arg((_SLOTS,)), arg((_SLOTS, blocks)),
+                    arg((_SLOTS,)))
+        else:
+            bucket = blocks if n == "deepest" else int(n)
+            fn = functools.partial(engine._prefill_batch_fn, kb=bucket)
+            rows = (arg((engine.prefill_batch, engine.chunk)),
+                    arg((engine.prefill_batch, blocks)),
+                    arg((engine.prefill_batch,)),
+                    arg((engine.prefill_batch,)))
+        return jax.jit(fn, donate_argnums=(1,)).lower(
+            params, pool, *rows, *common).compile().as_text()
+
+    yield engine, compiled_text
+    mp.undo()
+
+
+@pytest.mark.parametrize("program", ["decode_burst_1", "decode_burst_8",
+                                     "prefill_8", "prefill_deepest"])
+def test_engine_programs_keep_the_pool_in_place_on_v5e(serving_programs,
+                                                       program):
+    """Both serving cells' programs at the cells' pool shapes: the pool is
+    a carried buffer that is passed on, written by a scatter that aliases
+    it, and read by the paged kernel through a bitcast.  No instruction
+    copies it and none makes a value of one layer's shape (the parent's
+    programs sliced a layer out for the kernel and put it back: 62% of the
+    dense cell's device time, PERF.md PR 28)."""
+    engine, compiled_text = serving_programs
+    ad = engine.adapter
+    kv_h = ad.kv_heads // engine._tp          # what one chip holds
+    text = compiled_text(program)
+    if engine._tp > 1 and program.startswith("prefill"):
+        # it compiles, and that is all that holds: with two kv heads a
+        # chip and no custom call to fix the pool's layout, XLA gives the
+        # carried pool a layout of its own and converts the whole pool on
+        # the way in and out (PERF.md §7, PR 28; no cell serves under TP)
+        return
+    assert pool_value_faults(text, _POOL_LAYERS, _POOL_PAGES, _PAGE, kv_h,
+                             ad.head_dim) == []
+    whole = f"bf16[{_POOL_LAYERS},{_POOL_PAGES},{_PAGE},{kv_h},"
+    writes = re.findall(rf"= {re.escape(whole)}\S* scatter\(", text)
+    assert len(writes) == 2, writes                # K and V, once a layer
+    if program.startswith("decode"):
+        assert 'custom_call_target="tpu_custom_call"' in text
+        assert "paged_decode_attention" in text
 
 
 @pytest.mark.parametrize("rows", [32, 256], ids=["decode_step", "prefill_call"])
