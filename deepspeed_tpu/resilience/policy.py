@@ -327,7 +327,12 @@ class RecoveryPolicy:
         if not self.cfg.emergency_save_on_trip:
             return
         try:
-            path = self.snapshots.emergency_flush()
+            # another thread holds the save entry only for its
+            # device→host copy: one that outlasts the watchdog's own
+            # bound on a device answer is part of the hang
+            wd = getattr(self.engine, "watchdog", None)
+            path = self.snapshots.emergency_flush(
+                entry_timeout_s=getattr(wd, "device_probe_timeout_s", None))
             if path:
                 log_dist(f"resilience: emergency snapshot at watchdog "
                          f"trip -> {path}")

@@ -49,6 +49,9 @@ import numpy as np
 
 from ..runtime.checkpoint_engine import (CheckpointCorruptionError,
                                          TorchCheckpointEngine,
+                                         join_inflight_save,
+                                         register_inflight_save,
+                                         release_inflight_save,
                                          verify_sidecar_manifest)
 from ..utils.logging import log_dist, logger
 
@@ -243,10 +246,16 @@ class SnapshotManager:
         self._async = str(cfg.flush_engine) == "async"
         self._flush_pool = None
         self._pending_flush = None
+        self._pending_path = None
         #: tier-2 plumbing, attached when an elastic rendezvous exists
         self._rdzv = None
         self.snapshots_taken = 0
         self.flushes = 0
+        # restart fence: an in-process restart (elastic agent) builds
+        # this manager while the attempt it replaces may still be
+        # flushing into the same dir on its background thread; that
+        # write finishes before this one lists or writes snapshots
+        join_inflight_save(self.snapshot_dir)
 
     # -- registration ------------------------------------------------------
 
@@ -456,6 +465,8 @@ class SnapshotManager:
 
             self._flush_pool = concurrent.futures.ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="ds-snapshot-flush")
+        register_inflight_save(path, self)
+        self._pending_path = path
         self._pending_flush = self._flush_pool.submit(
             self._flush_sync, snap, emergency)
         from ..telemetry import get_telemetry
@@ -467,16 +478,26 @@ class SnapshotManager:
                  "(async: excludes the background write)")
         return path
 
-    def _flush_sync(self, snap: Snapshot, emergency: bool) -> str:
+    def _flush_sync(self, snap: Snapshot, emergency: bool,
+                    entry_timeout_s: Optional[float] = None) -> str:
         """The full tier-1 job, on whatever thread calls it.  Uses a
         throwaway sync engine per call: concurrent emergency + regular
-        flushes target different dirs and share no writer state."""
+        flushes target different dirs and share no writer state (the
+        engine serializes their entry into orbax)."""
         tag = _tag(snap.global_steps, emergency=emergency)
         path = os.path.join(self.snapshot_dir, tag)
+        if os.path.isdir(path) and not os.path.exists(
+                os.path.join(path, SNAPSHOT_MANIFEST)):
+            # a flush of this step that never committed (the attempt
+            # that started it was killed, or failed mid-write): nothing
+            # restores from it, and its half-written tree must not be
+            # what this write trips over
+            shutil.rmtree(path, ignore_errors=True)
         os.makedirs(path, exist_ok=True)
         t0 = self._clock()
         state_path = os.path.join(path, "state")
-        TorchCheckpointEngine().save(snap.state, state_path)
+        TorchCheckpointEngine().save(snap.state, state_path,
+                                     entry_timeout_s=entry_timeout_s)
         # sha256 sidecar on EVERY host: the engine only stamps it on
         # process 0 (user checkpoints share one tree), but snapshots are
         # per-host local trees — each host gates its own restores.
@@ -511,26 +532,37 @@ class SnapshotManager:
     def wait(self) -> None:
         """Join any in-flight async flush (tests / teardown / before a
         deliberate corruption or a restore decision)."""
-        pending, self._pending_flush = self._pending_flush, None
-        if pending is not None:
-            try:
-                pending.result()
-            except Exception as e:
-                # a failed background flush must surface (loudly) but
-                # not kill the training step that joined it — the next
-                # interval retries with a fresh snapshot
-                logger.error(f"resilience: background snapshot flush "
-                             f"failed: {e!r}")
+        pending = self._pending_flush
+        if pending is None:
+            return
+        try:
+            pending.result()
+        except Exception as e:
+            # a failed background flush must surface (loudly) but
+            # not kill the training step that joined it — the next
+            # interval retries with a fresh snapshot
+            logger.error(f"resilience: background snapshot flush "
+                         f"failed: {e!r}")
+        # cleared only once joined: a second joiner (the next attempt's
+        # manager, on another thread) waits on the same future
+        if self._pending_flush is pending:
+            self._pending_flush = None
+            release_inflight_save(self._pending_path, self)
 
-    def emergency_flush(self) -> Optional[str]:
+    def emergency_flush(self, entry_timeout_s: Optional[float] = None
+                        ) -> Optional[str]:
         """Watchdog-trip path: the device may be hung, but the newest
         tier-0 HOST copy is already taken — make it durable NOW, on the
         calling (watchdog) thread with its own sync writer (the
-        background flusher may be the thing that is stuck)."""
+        background flusher may be the thing that is stuck).
+        ``entry_timeout_s`` bounds the wait behind another thread's
+        entry into a save (its device→host copy; the background write
+        holds nothing): past it this one writes unserialized."""
         snap = self.latest()
         if snap is None:
             return None
-        path = self._flush_sync(snap, emergency=True)
+        path = self._flush_sync(snap, emergency=True,
+                                entry_timeout_s=entry_timeout_s)
         from ..telemetry import get_telemetry
 
         get_telemetry().inc_counter(

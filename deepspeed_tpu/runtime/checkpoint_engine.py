@@ -15,11 +15,13 @@ engine classes here supply the reference's lifecycle surface
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
 import threading
 import time
+import weakref
 from typing import Any, Dict, Optional
 
 import jax
@@ -30,6 +32,49 @@ from ..utils.logging import log_dist, logger
 
 #: sidecar integrity manifest written next to every saved checkpoint tree
 SIDECAR_MANIFEST = "ds_manifest.json"
+
+#: ONE thread at a time inside the synchronous part of an orbax
+#: ``save()``.  orbax keys a save's "directory created" signals on
+#: ``OperationIdGenerator._operation_id``, a process-global that every
+#: ``save()`` advances and that the futures it builds read back while
+#: the call is still on the caller's thread: two threads in there at
+#: once read each other's id, so one save's array writer is released by
+#: the OTHER save's signal and writes into a tmp dir its own creator
+#: then finds (``FileExistsError``), removes under the writer
+#: (``OSError 39`` / tensorstore ``NOT_FOUND``) or never signals (the
+#: writer waits out orbax's coordination timeout).  Once ``save()``
+#: returns every future holds its own id, so the background write needs
+#: no lock.  The training thread's checkpoint, the snapshot flusher, an
+#: emergency flush on the watchdog thread and every in-process host of
+#: an elastic gang all save from their own threads (ROADMAP D0).
+_save_entry_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def save_entry(path: str, timeout_s: Optional[float] = None):
+    """Hold :data:`_save_entry_lock` around one orbax ``save()`` call.
+    ``timeout_s`` is for a caller that must not wait forever behind a
+    save that may itself be what hangs (the emergency flush): after
+    that long it goes in unserialized, and says so."""
+    locked = _save_entry_lock.acquire(
+        timeout=-1 if timeout_s is None else max(timeout_s, 0.0))
+    if not locked:
+        logger.warning(
+            f"checkpoint save of {path}: another save held orbax's "
+            f"entry for {timeout_s:.1f}s; writing unserialized (the "
+            f"write may fail or tear: the manifest gate refuses it on "
+            f"load if so)")
+        from ..telemetry import get_telemetry
+
+        get_telemetry().inc_counter(
+            "checkpoint/unserialized_saves_total",
+            help="orbax saves started without the entry lock after "
+                 "waiting out their bound behind another save")
+    try:
+        yield
+    finally:
+        if locked:
+            _save_entry_lock.release()
 
 
 class CheckpointCorruptionError(RuntimeError):
@@ -192,10 +237,12 @@ class TorchCheckpointEngine(CheckpointEngine):
     serialization is orbax, not torch)."""
 
     def save(self, state_tree: Any, path: str,
-             commit_fn: Optional[Any] = None) -> None:
+             commit_fn: Optional[Any] = None,
+             entry_timeout_s: Optional[float] = None) -> None:
         t0 = time.perf_counter()
         with ocp.StandardCheckpointer() as saver:
-            saver.save(path, state_tree, force=True)
+            with save_entry(path, entry_timeout_s):
+                saver.save(path, state_tree, force=True)
         # integrity sidecar BEFORE the durability marker: a manifest's
         # existence implies the payload it hashes was fully written.
         # Process 0 only — the tree is shared, the stamp must not race
@@ -247,9 +294,27 @@ class TorchCheckpointEngine(CheckpointEngine):
 #: still flushing).  Relying on GC to __del__-join the writer is a race.
 #: Values are WEAK references: an engine abandoned mid-save still joins
 #: through its __del__ (pre-existing behavior); the registry must not
-#: pin it — and its checkpointer — for the process lifetime.
+#: pin it — and its checkpointer — for the process lifetime.  The owner
+#: is whatever has the ``wait()`` that joins the write: an async engine
+#: here, a resilience ``SnapshotManager`` with a background flush.
 _inflight_lock = threading.Lock()
-_inflight: Dict[str, Any] = {}  # path -> weakref to the engine
+_inflight: Dict[str, Any] = {}  # path -> weakref to the owner
+
+
+def register_inflight_save(path: str, owner: Any) -> None:
+    """``owner.wait()`` joins the background write of ``path``."""
+    with _inflight_lock:
+        _inflight[os.path.abspath(path)] = weakref.ref(owner)
+
+
+def release_inflight_save(path: str, owner: Any) -> None:
+    """``owner`` has joined its write of ``path`` (a newer owner's entry
+    for the same path stays)."""
+    path = os.path.abspath(path)
+    with _inflight_lock:
+        ref = _inflight.get(path)
+        if ref is not None and ref() in (owner, None):
+            _inflight.pop(path, None)
 
 
 def join_inflight_save(path: str) -> None:
@@ -287,17 +352,15 @@ class DecoupledCheckpointEngine(CheckpointEngine):
              commit_fn: Optional[Any] = None) -> None:
         t0 = time.perf_counter()
         self.wait()
-        self._ckptr.save(path, args=ocp.args.StandardSave(state_tree),
-                         force=True)
+        with save_entry(path):
+            self._ckptr.save(path, args=ocp.args.StandardSave(state_tree),
+                             force=True)
         # only the BLOCKING part (join previous + device→host snapshot)
         # counts as checkpoint time; the storage write overlaps training
         _charge_checkpoint_goodput(time.perf_counter() - t0)
         self._pending = path
         self._pending_commit = commit_fn
-        import weakref
-
-        with _inflight_lock:
-            _inflight[os.path.abspath(path)] = weakref.ref(self)
+        register_inflight_save(path, self)
         log_dist(f"async checkpoint save started: {path}")
 
     def load(self, path: str, target: Any = None,
@@ -314,10 +377,7 @@ class DecoupledCheckpointEngine(CheckpointEngine):
         if self._pending is not None:
             self._ckptr.wait_until_finished()
             pending, self._pending = self._pending, None
-            with _inflight_lock:
-                ref = _inflight.get(os.path.abspath(pending))
-                if ref is not None and ref() in (self, None):
-                    _inflight.pop(os.path.abspath(pending), None)
+            release_inflight_save(pending, self)
             try:
                 # the background writer just finished: hash what it wrote
                 # before the commit marker can name it (process 0 only)

@@ -26,12 +26,21 @@ import numpy as np
 import orbax.checkpoint as ocp
 
 from ..utils.logging import log_dist, logger
+from .checkpoint_engine import save_entry
 
 LATEST_FILE = "latest"
 
 
 def _tag_for(engine, tag: Optional[str]) -> str:
     return tag if tag is not None else f"global_step{engine.global_steps}"
+
+
+def _side_save(saver, path: str, tree: Any) -> None:
+    """A tree saved beside the main state (Infinity trunk, offload
+    moments): entered into orbax one thread at a time, like the
+    checkpoint engines' own saves."""
+    with save_entry(path):
+        saver.save(path, tree, force=True)
 
 
 def _ckpt_engine_for(engine):
@@ -115,13 +124,14 @@ def _save_checkpoint_impl(engine, save_dir: str, tag: Optional[str],
             # nvme tier's O(buffer_count) host-memory bound survives the save
             sw = infinity.swapper
             for i in range(sw.L):
-                saver.save(
-                    os.path.join(ckpt_dir, "infinity_trunk",
-                                 f"layer_{i:05d}"),
+                _side_save(
+                    saver, os.path.join(ckpt_dir, "infinity_trunk",
+                                        f"layer_{i:05d}"),
                     {"master": sw.layer_master_tree(i),
-                     "moments": sw.layer_moments(i)}, force=True)
-            saver.save(os.path.join(ckpt_dir, "infinity_resident_opt"),
-                       infinity.res_opt_state, force=True)
+                     "moments": sw.layer_moments(i)})
+            _side_save(saver,
+                       os.path.join(ckpt_dir, "infinity_resident_opt"),
+                       infinity.res_opt_state)
         if getattr(engine, "offload_opt", None) is not None:
             # ZeRO-Offload: moments live host-side in the C++ optimizer;
             # the attribute set varies per optimizer (Adam: both moments,
@@ -129,8 +139,8 @@ def _save_checkpoint_impl(engine, save_dir: str, tag: Optional[str],
             moments = {k: list(v) for k, v in
                        engine.offload_opt.state_dict_arrays().items()
                        if k != "step"}
-            saver.save(os.path.join(ckpt_dir, "offload_state"), moments,
-                       force=True)
+            _side_save(saver, os.path.join(ckpt_dir, "offload_state"),
+                       moments)
 
     # sync the scheduler to the APPLIED step (excludes fp16 overflow skips;
     # the per-step fast path tracks global_steps to avoid a device sync)

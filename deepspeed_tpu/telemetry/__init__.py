@@ -115,11 +115,10 @@ class Telemetry:
             self.chrome_trace = bool(chrome_trace)
             self.device_fence_steps = bool(device_fence)
             self.tracer.max_events = int(max_span_events)
-            if not jsonl and self.registry.event_log is not None:
+            if not jsonl:
                 # a reconfigure to in-memory-only must stop appending to
                 # the PREVIOUS job's events.jsonl
-                self.registry.event_log.close()
-                self.registry.event_log = None
+                self.registry.detach_event_log()
             if enabled and (jsonl or prometheus or chrome_trace):
                 base = os.path.join(output_path or "telemetry_logs", job_name)
                 self.output_path = base
@@ -133,8 +132,7 @@ class Telemetry:
     def reset(self) -> None:
         """Test isolation: drop all metrics/spans and disable."""
         with self._lock:
-            if self.registry.event_log is not None:
-                self.registry.event_log.close()
+            self.registry.detach_event_log()
             self.enabled = False
             self.output_path = None
             self.tracer = SpanTracer(self.tracer.max_events)

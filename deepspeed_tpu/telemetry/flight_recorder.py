@@ -20,8 +20,8 @@ signal, unhandled exception, or watchdog trip — writes a self-contained
   at dump time — for a hang, this is usually the answer.
 
 The recorder is a process-global singleton (like the telemetry hub) so
-the engine, the watchdog, the elastic agent, and ``bench.py``'s crash
-path all feed one black box.  Recording is cheap (deque appends under a
+the engine, the watchdog and the elastic agent all feed one black
+box.  Recording is cheap (deque appends under a
 lock); all the expensive work happens at dump time.
 """
 

@@ -221,6 +221,7 @@ class MetricsRegistry:
                  ) -> None:
         self._metrics: Dict[str, Any] = {}
         self._lock = threading.Lock()
+        self._event_lock = threading.Lock()
         self.event_log: Optional[JSONLExporter] = None
         #: called before the registry is read as a whole (``snapshot()``,
         #: ``prometheus_text()``).  The hub runs its collect hooks here:
@@ -288,15 +289,31 @@ class MetricsRegistry:
 
     # -- JSONL -------------------------------------------------------------
 
+    # Lookup + write and every swap hold ``_event_lock``: a reconfigure
+    # on another thread (in-process hosts share one hub) must not close
+    # the log between an emitter's lookup and its write; the event goes
+    # to whichever log is attached when the emitter gets the lock.
+
     def attach_event_log(self, path: str) -> None:
-        if self.event_log is not None:
-            self.event_log.close()
-        self.event_log = JSONLExporter(path)
+        new = JSONLExporter(path)
+        with self._event_lock:
+            old, self.event_log = self.event_log, new
+            if old is not None:
+                old.close()
+
+    def detach_event_log(self) -> None:
+        with self._event_lock:
+            old, self.event_log = self.event_log, None
+            if old is not None:
+                old.close()
 
     def emit_event(self, kind: str, payload: Dict[str, Any]) -> None:
         if self.event_log is None:
             return
-        self.event_log.write({"ts": time.time(), "kind": kind, **payload})
+        with self._event_lock:
+            if self.event_log is not None:
+                self.event_log.write(
+                    {"ts": time.time(), "kind": kind, **payload})
 
     # -- Prometheus --------------------------------------------------------
 

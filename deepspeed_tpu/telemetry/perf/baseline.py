@@ -2,14 +2,14 @@
 
 ``BENCH_r*.json`` has been a *log*: every round appends a number, nobody
 is forced to look when it drifts down.  This module makes it a *gated
-trajectory*: ``bench.py`` persists a perf baseline (step-time p50, MFU,
+trajectory*: a bench run persists a perf baseline (step-time p50, MFU,
 compile seconds, goodput, tokens/sec) and
 ``python -m deepspeed_tpu.telemetry perf {show,baseline,check}``
 compares any later run against it, exiting **3** on regression beyond
 configurable tolerances — the same scriptable-exit-code contract as the
 ``desync`` command.
 
-A *run file* is a bench JSON line (the object ``bench.py`` prints), a
+A *run file* is a bench JSON line (one flat object of metric keys), a
 driver ``BENCH_r*.json`` artifact (the same object under ``"parsed"``),
 or a previously saved baseline file — all three carry the same metric
 keys at top level or under ``metrics``.
@@ -177,9 +177,9 @@ def extract_perf(run: Dict[str, Any]) -> Dict[str, float]:
 def environment_failure_reason(run: Dict[str, Any]) -> Optional[str]:
     """A *no-data* artifact's named reason, or ``None`` for a real run.
 
-    Matches two shapes of recorded artifact (``bench.py`` no longer
-    writes either — without a chip it now exits non-zero and prints
-    nothing; the reader stays until ROADMAP D1 retires the gate): an
+    Matches two shapes of recorded artifact (nothing in the repo writes
+    either any more; the reader stays until ROADMAP D1 retires the
+    gate): an
     explicit ``environment_failure`` marker, and the older
     probe-failure line — ``value`` 0 with an ``error`` field and NO
     ``debug_bundle`` key.  The key matters: a bench that

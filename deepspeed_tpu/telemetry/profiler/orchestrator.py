@@ -38,7 +38,7 @@ cache.  Capture wall time is booked to the goodput ledger's
 ``profiler`` bucket.  Duty-cycle continuous mode self-arms a window of
 ``duty_cycle_pct`` percent of every ``duty_period_steps`` steps into the
 same bounded ring of trace dirs — always-on capture with a bounded
-overhead budget (``bench.py`` gates it as ``profiler_overhead_pct``).
+overhead budget.
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ The engine assembles ONE of these each ``train_step`` (device-fenced
 step wall time, throughput, loss/grad-norm/loss-scale, cumulative comm
 bytes from ``comm.comms_logger``, JAX live-buffer/host memory stats) and
 publishes it through the metrics registry + JSONL event log — so
-``bench.py``, the autotuner, and any monitor backend all read the SAME
+the benchmark, the autotuner, and any monitor backend all read the SAME
 numbers the runtime measured, instead of re-deriving their own
 (ISSUE 1: the round-5 headline numbers were unwitnessed precisely
 because the measuring code lived outside the engine).

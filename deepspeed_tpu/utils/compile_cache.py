@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives — the one place that says.
 
-Called by the executables (``chip_smoke.py``, ``bench.py``,
+Called by the executables (``chip_smoke.py``,
 ``python -m deepspeed_tpu.serving``), never from ``initialize()``: the CPU
 test suite runs without a persistent cache on purpose
 (``tests/conftest.py``).  The launcher exports the variable to its

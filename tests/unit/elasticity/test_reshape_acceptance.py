@@ -11,8 +11,10 @@ departed/about-to-join peer never completes, the membership change
 tears the step down)."""
 
 import os
+import sys
 import threading
 import time
+import traceback
 
 import pytest
 
@@ -25,6 +27,9 @@ from deepspeed_tpu.elasticity.rendezvous import (ElasticRendezvous,
 from deepspeed_tpu.telemetry import get_telemetry, parse_prometheus_text
 
 TOTAL, CHAOS_AT = 6, 3
+#: ONE deadline for a whole gang test: a passing gang is ~10-20 s, so a
+#: gang that cannot finish fails here and says which host is stuck where
+GANG_DEADLINE_S = 60.0
 
 
 @pytest.fixture(autouse=True)
@@ -66,6 +71,11 @@ class Gang:
         self.agents, self.results = {}, {}
         self.losses, self.worlds = {}, {}
         self.threads = {}
+        #: node -> the exception that ended its agent thread
+        self.errors = {}
+        #: every wait in the gang (worker gates, rendezvous, join_all)
+        #: runs against this one clock
+        self.deadline = time.monotonic() + GANG_DEADLINE_S
         self.snap_dirs = {}
         #: grow tests set this to the joiner's node id: restarted
         #: incumbents then hold their first post-reseal step until the
@@ -109,8 +119,7 @@ class Gang:
                     f"{node} restart found no snapshot in any tier"
             if (restart_count > 0 and self.join_barrier
                     and node != self.join_barrier):
-                deadline = time.monotonic() + 120.0
-                while (time.monotonic() < deadline
+                while (time.monotonic() < self.deadline
                        and not self.losses.get(self.join_barrier)):
                     time.sleep(0.02)
             while engine.global_steps < TOTAL:
@@ -121,9 +130,8 @@ class Gang:
                         and engine.global_steps == CHAOS_AT):
                     # the chaos gate: block like the real collective
                     # would until the membership round moves
-                    deadline = time.monotonic() + 120.0
                     while (agent.rdzv.current_round() == agent._round
-                           and time.monotonic() < deadline):
+                           and time.monotonic() < self.deadline):
                         time.sleep(0.02)
                     raise _RestartSignal("peer set changed at the gate")
                 if (restart_count == 0 and self.faults_for.get(node)
@@ -140,8 +148,7 @@ class Gang:
                     # node is already gone).  The flush is ASYNC, so
                     # passing the step is not enough — wait for each
                     # peer's COMMITTED snap-2 marker on disk
-                    deadline = time.monotonic() + 120.0
-                    while time.monotonic() < deadline and not all(
+                    while time.monotonic() < self.deadline and not all(
                             any(s >= CHAOS_AT - 1 for _rc, s, _l
                                 in self.losses.get(p, []))
                             and self._snap_committed(p, CHAOS_AT - 1)
@@ -158,14 +165,17 @@ class Gang:
         rdzv = ElasticRendezvous(
             RendezvousClient(self.srv.endpoint), node,
             min_nodes=self.min_nodes, max_nodes=self.max_nodes,
-            settle_s=0.3, timeout_s=120.0)
+            settle_s=0.3, timeout_s=GANG_DEADLINE_S)
         agent = DSElasticAgent(
             WorkerSpec(fn=self._worker(node), max_restarts=3,
                        monitor_interval=0.05, heartbeat_ttl=30.0,
                        restart_backoff_s=0.05, restart_backoff_max_s=0.1),
             rdzv=rdzv, node_id=node)
         self.agents[node] = agent
-        self.results[node] = agent.run()
+        try:
+            self.results[node] = agent.run()
+        except Exception as e:  # reported by join_all, not lost
+            self.errors[node] = e
 
     def start(self, node):
         t = threading.Thread(target=self._run_agent, args=(node,),
@@ -174,14 +184,23 @@ class Gang:
         t.start()
         return t
 
-    def join_all(self, timeout=300):
-        # one deadline for the gang, not one per thread: a hung gang
-        # (ROADMAP D0) must not eat the whole tier-1 time limit
-        deadline = time.monotonic() + timeout
-        for t in self.threads.values():
-            t.join(timeout=max(deadline - time.monotonic(), 0.0))
-        assert not any(t.is_alive() for t in self.threads.values()), \
-            "gang never finished"
+    def join_all(self):
+        # one deadline for the gang, not one per thread; a host that
+        # died or is still running when it passes is named, with the
+        # exception that ended it or the frame it is stuck in
+        for t in list(self.threads.values()):
+            t.join(timeout=max(self.deadline - time.monotonic(), 0.0))
+        frames = sys._current_frames()
+        stuck = {n: "".join(traceback.format_stack(frames[t.ident])[-6:])
+                 for n, t in self.threads.items()
+                 if t.is_alive() and t.ident in frames}
+        died = {n: "".join(traceback.format_exception(e)[-8:])
+                for n, e in self.errors.items()}
+        assert not stuck and not died, (
+            f"gang did not finish inside {GANG_DEADLINE_S:.0f} s"
+            + "".join(f"\n-- {n} died: {tb}" for n, tb in died.items())
+            + "".join(f"\n-- {n} stuck at:\n{tb}"
+                      for n, tb in stuck.items()))
 
 
 def test_gang_shrinks_4_to_3_and_resumes(tiny_engine_factory):
@@ -259,9 +278,8 @@ def test_gang_grows_4_to_5_with_bootstrap_joiner(tiny_engine_factory):
         for n in incumbents:
             gang.start(n)
         # host-e's thread is started by the fault callback
-        deadline = time.monotonic() + 200.0
         while "host-e" not in gang.threads \
-                and time.monotonic() < deadline:
+                and time.monotonic() < gang.deadline:
             time.sleep(0.05)
         assert "host-e" in gang.threads, "node_join never launched host-e"
         gang.join_all()

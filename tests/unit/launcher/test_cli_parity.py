@@ -12,13 +12,10 @@ import textwrap
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 import deepspeed_tpu as dst
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
-
-pytestmark = pytest.mark.slow
 
 _REPO = str(pathlib.Path(__file__).resolve().parents[3])
 

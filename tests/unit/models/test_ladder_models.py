@@ -12,8 +12,6 @@ from deepspeed_tpu.models import (BertConfig, BertModel, LlamaModel,
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
 
 # ---------------------------------------------------------------------------
 # ResNet (ladder rung 1 — ZeRO-0)

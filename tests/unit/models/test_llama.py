@@ -9,8 +9,6 @@ from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
 
 def tiny(**kw):
     return LlamaConfig.tiny(num_layers=2, dtype=jnp.float32, **kw)

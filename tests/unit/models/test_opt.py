@@ -10,8 +10,6 @@ from deepspeed_tpu.models import OPTConfig, OPTModel
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
 
 def _cfg(**kw):
     d = dict(num_layers=2, dtype=jnp.float32)

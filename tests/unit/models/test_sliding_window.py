@@ -3,13 +3,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
-
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
 
 
 def _cfg(**kw):

@@ -9,8 +9,6 @@ from deepspeed_tpu.moe import MoE, MOELayer, TopKGate, top_k_gating
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
 
 def test_top1_gating_invariants():
     rng = np.random.RandomState(0)
@@ -107,6 +105,61 @@ def test_mixtral_ep_training_matches_single_device():
     single = run(groups.initialize_mesh(MeshLayout.infer(1, dp=1)))
     np.testing.assert_allclose(sharded, single, rtol=3e-4, atol=3e-4)
     assert sharded[-1] < sharded[0]
+
+
+def test_config_driven_expert_parallel_trains_like_ep1():
+    """``moe.expert_parallel_size`` in the config alone (no mesh passed)
+    sizes the expert axis, shards every expert-stacked parameter to
+    exactly 1/ep of its bytes a device, publishes the gate's drop rate,
+    and trains to the losses of ep=1."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models import MixtralConfig, MixtralModel
+    from deepspeed_tpu.telemetry import get_telemetry
+
+    cfg = MixtralConfig.tiny(num_layers=2, max_seq_len=128)
+    hub = get_telemetry()
+    hub_was_enabled = hub.enabled
+
+    def run(ep):
+        groups.reset_mesh()
+        ds = {"train_micro_batch_size_per_gpu": 1,
+              "gradient_accumulation_steps": 1,
+              "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+              "zero_optimization": {"stage": 1},
+              "moe": {"expert_parallel_size": ep,
+                      "dispatch_impl": "sparse"},
+              "steps_per_print": 0,
+              "telemetry": {"enabled": True, "jsonl": False,
+                            "numerics": {"every": 1}}}
+        engine, *_ = deepspeed_tpu.initialize(
+            model=MixtralModel(cfg), config=ds)
+        rng = np.random.default_rng(11)
+        losses = [float(engine.train_step({"input_ids": rng.integers(
+            1, cfg.vocab_size, size=(engine.train_batch_size,
+                                     cfg.max_seq_len),
+            dtype=np.int32)})["loss"]) for _ in range(6)]
+        fracs = {name: np.prod(w.sharding.shard_shape(w.shape))
+                 / np.prod(w.shape)
+                 for name, w in engine.state.params["layers"]["moe"].items()
+                 if name in ("w_gate", "w_up", "w_down")}
+        gauges = hub.registry.snapshot().get("gauges", {})
+        return dict(engine.mesh.shape), losses, fracs, gauges
+
+    try:
+        mesh, losses, fracs, gauges = run(4)
+        ref_mesh, ref_losses, ref_fracs, _ = run(1)
+    finally:
+        groups.reset_mesh()
+        if not hub_was_enabled:
+            hub.configure(enabled=False)
+    assert mesh["expert"] == 4 and mesh["data"] == 2, mesh
+    assert ref_mesh["expert"] == 1 and ref_mesh["data"] == 8, ref_mesh
+    # the expert axis carries the expert dim; ZeRO-1 leaves params
+    # unsharded over data, so a device holds exactly 1/ep of each
+    assert fracs and all(f == 1 / 4 for f in fracs.values()), fracs
+    assert all(f == 1.0 for f in ref_fracs.values()), ref_fracs
+    assert 0.0 <= float(gauges["moe/drop_rate"]["value"]) < 1.0
+    np.testing.assert_allclose(losses, ref_losses, rtol=3e-3)
 
 
 def test_moe_residual_path():

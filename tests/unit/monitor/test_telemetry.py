@@ -92,6 +92,45 @@ def test_jsonl_event_log(tmp_path):
     assert all("ts" in e for e in lines)
 
 
+def test_events_survive_a_reconfigure_on_another_thread(tmp_path):
+    """Emitters that race another thread's re-attach (five in-process
+    hosts share one hub) neither fail their training step on a closed
+    file nor lose an event: each lands in the log attached when its
+    emitter got there."""
+    import threading
+
+    reg = MetricsRegistry()
+    paths = [str(tmp_path / "a.jsonl"), str(tmp_path / "b.jsonl")]
+    for path in paths:  # both exist, however early the emitters finish
+        reg.attach_event_log(path)
+    n_threads, n_events, errors = 4, 1000, []
+
+    def emit(t):
+        try:
+            for i in range(n_events):
+                reg.emit_event("step", {"t": t, "i": i})
+        except Exception as e:
+            errors.append(e)
+
+    threads = [threading.Thread(target=emit, args=(t,))
+               for t in range(n_threads)]
+    for th in threads:
+        th.start()
+    swaps = 0
+    while any(th.is_alive() for th in threads):
+        swaps += 1
+        reg.attach_event_log(paths[swaps % 2])
+    for th in threads:
+        th.join()
+    reg.detach_event_log()
+    assert not errors, errors
+    reg.emit_event("step", {"t": -1, "i": 0})  # detached: a no-op
+    seen = [(e["t"], e["i"]) for p in paths
+            for e in map(json.loads, open(p).read().splitlines())]
+    assert sorted(seen) == [(t, i) for t in range(n_threads)
+                            for i in range(n_events)]
+
+
 def test_step_record_publish_roundtrip(tmp_path):
     reg = MetricsRegistry()
     reg.attach_event_log(str(tmp_path / "e.jsonl"))

@@ -18,8 +18,6 @@ from deepspeed_tpu.ops.sparse_attention import (BigBirdSparsityConfig,
                                                 FixedSparsityConfig,
                                                 sparse_attention)
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
 
 def _qkv(B=2, S=256, h=4, d=64, seed=0):
     rng = np.random.RandomState(seed)

@@ -17,8 +17,6 @@ import pytest
 fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
 lattice = importlib.import_module("deepspeed_tpu.ops.pallas.lattice")
 
-pytestmark = pytest.mark.slow  # jit-heavy; smoke tier runs -m "not slow"
-
 #: (rtol, atol) per input dtype — bf16 inputs accumulate in fp32 inside
 #: every kernel, so the budget covers the input rounding, not the math
 TOL = {jnp.float32: (2e-5, 2e-5), jnp.bfloat16: (2e-2, 2e-2)}

@@ -18,8 +18,6 @@ import pytest
 
 fo = importlib.import_module("deepspeed_tpu.ops.pallas.fused_optimizer")
 
-pytestmark = pytest.mark.slow  # jit-heavy; smoke tier runs -m "not slow"
-
 
 def tree(seed=0):
     rng = np.random.RandomState(seed)

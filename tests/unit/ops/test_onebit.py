@@ -12,8 +12,6 @@ from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.jax_compat import shard_map as _shard_map
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
 
 def test_pack_unpack_roundtrip():
     rng = np.random.RandomState(0)

@@ -12,8 +12,6 @@ from deepspeed_tpu.ops.pallas.flash_attention import (
 from deepspeed_tpu.ops.pallas.quantizer import (dequantize_int8,
                                                 quantize_int8)
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
 
 def qkv(B=2, S=128, h=4, d=64, seed=0):
     rng = np.random.RandomState(seed)

@@ -10,7 +10,6 @@ from deepspeed_tpu.parallel.pipeline import (pipeline_apply,
                                              pipeline_train_1f1b)
 from deepspeed_tpu.utils import groups
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
 
 def layer_fn(lp, x):
     return jnp.tanh(x @ lp["w"] + lp["b"])

@@ -130,6 +130,168 @@ def test_disk_resume_restores_meta(tiny_engine_factory):
     np.testing.assert_array_equal(w1, w2)
 
 
+def test_flush_clears_an_uncommitted_dir_of_its_step_only(
+        tiny_engine_factory):
+    """What a killed attempt leaves of step N (a tree with no commit
+    marker) goes before step N is written again; a COMMITTED snapshot
+    of another step is not touched."""
+    engine, batches = tiny_engine_factory("u")
+    for b in batches[:2]:
+        engine.train_step(b)
+    snap_dir = engine.snapshots.snapshot_dir
+    assert [s["step"] for s in list_snapshots(snap_dir)] == [2, 0]
+    torn = os.path.join(snap_dir, "snap-00000004")
+    os.makedirs(os.path.join(torn, "state.orbax-checkpoint-tmp", "d"))
+    with open(os.path.join(torn, "leftover"), "w") as fh:
+        fh.write("half a write")
+    kept = os.path.join(snap_dir, "snap-00000002", "snapshot.json")
+    before = os.stat(kept).st_mtime_ns
+    for b in batches[2:4]:
+        engine.train_step(b)  # flushes step 4 over the torn dir
+    assert not os.path.exists(os.path.join(torn, "leftover"))
+    ok, detail = verify_snapshot(torn)
+    assert ok, detail
+    assert os.stat(kept).st_mtime_ns == before
+
+
+def test_new_manager_joins_the_previous_attempts_flush(
+        tiny_engine_factory, monkeypatch):
+    """The restart fence: an attempt abandoned mid-flush (async) still
+    owns its snapshot dir; the next attempt's manager on that dir is
+    not built until that flush has committed, so it resumes from it."""
+    import threading
+
+    from deepspeed_tpu.resilience import snapshot as snapmod
+
+    engine, batches = tiny_engine_factory(
+        "f1", resilience={"flush_engine": "async"})
+    release = threading.Event()
+    real = snapmod.SnapshotManager._flush_sync
+
+    def slow(self, *a, **kw):
+        assert release.wait(timeout=30)
+        return real(self, *a, **kw)
+
+    monkeypatch.setattr(snapmod.SnapshotManager, "_flush_sync", slow)
+    engine.snapshots.take()  # dispatched; held before it writes
+    assert list_snapshots(engine.snapshots.snapshot_dir) == []
+    threading.Timer(0.3, release.set).start()
+    engine2, _ = tiny_engine_factory(
+        "f1", resilience={"flush_engine": "async"})  # the same dir
+    assert engine2.snapshots.snapshot_dir == engine.snapshots.snapshot_dir
+    assert release.is_set(), "the new manager did not wait"
+    assert engine2.resilience.resume_if_restarted(force=True) is not None
+
+
+@pytest.mark.parametrize("checkpoint", [None, "sync", "async"],
+                         ids=["flushes-only", "sync-checkpoint",
+                              "async-checkpoint"])
+def test_concurrent_saves_in_one_process_all_commit(tiny_engine_factory,
+                                                    tmp_path, checkpoint):
+    """ROADMAP D0: orbax keys a save's signals on a process-global
+    operation id, so two threads saving at once corrupt or hang each
+    other.  Five managers (the background flusher, an emergency flush
+    and every in-process host of a gang are such threads) flush the
+    same steps at once, with or without ``save_checkpoint`` on the
+    training thread beside them: every flush commits a snapshot that
+    verifies and every checkpoint loads back."""
+    import sys
+    import threading
+
+    from deepspeed_tpu.runtime.checkpoint_engine import (
+        DecoupledCheckpointEngine, TorchCheckpointEngine)
+
+    engines = [tiny_engine_factory(f"c{i}")[0] for i in range(5)]
+    snaps = [e.snapshots.take() for e in engines]  # step-0, flushed
+    trainer = engines[0]
+    trainer._ckpt_engine = (DecoupledCheckpointEngine()
+                            if checkpoint == "async"
+                            else TorchCheckpointEngine())
+    tags = [f"t{i}" for i in range(4)] if checkpoint else []
+    errors = []
+
+    def flush(mgr, snap):
+        try:
+            for emergency in (False, True, False):
+                mgr._flush_sync(snap, emergency)
+        except Exception as e:
+            errors.append(e)
+
+    threads = [threading.Thread(target=flush, args=(e.snapshots, s),
+                                daemon=True)
+               for e, s in zip(engines, snaps)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for tag in tags:
+            trainer.save_checkpoint(str(tmp_path / "ckpt"), tag=tag)
+        trainer._ckpt_engine.wait()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads), "a flush hung"
+    assert not errors, errors
+    for e in engines:
+        listed = list_snapshots(e.snapshots.snapshot_dir)
+        assert [(s["step"], s["emergency"]) for s in listed] == \
+            [(0, False), (0, True)]
+        for entry in listed:
+            ok, detail = verify_snapshot(entry["path"])
+            assert ok, detail
+    w = np.asarray(trainer.state.params["w"])
+    for tag in tags:
+        path, _ = trainer.load_checkpoint(str(tmp_path / "ckpt"), tag=tag)
+        assert path is not None
+        np.testing.assert_array_equal(
+            np.asarray(trainer.state.params["w"]), w)
+
+
+@pytest.mark.parametrize("released", [True, False],
+                         ids=["entry-released-in-time", "entry-never-freed"])
+def test_emergency_flush_behind_a_held_save_entry(tiny_engine_factory,
+                                                  released):
+    """The watchdog's emergency flush waits for another thread's entry
+    into a save, but only as long as the watchdog waits for a device to
+    answer: a holder that never lets go (a device→host copy on a hung
+    device) costs it that bound, the flush still commits a snapshot that
+    verifies, and the unserialized write is counted."""
+    import threading
+
+    from deepspeed_tpu.runtime import checkpoint_engine as ce
+    from deepspeed_tpu.telemetry import get_telemetry
+
+    engine, batches = tiny_engine_factory(
+        "e", telemetry={"watchdog": {
+            "enabled": True, "hang_timeout_s": 600.0,
+            "device_probe_timeout_s": 30.0 if released else 0.3}})
+    for b in batches[:2]:
+        engine.train_step(b)
+    assert ce._save_entry_lock.acquire(timeout=5)
+    timer = threading.Timer(0.2, ce._save_entry_lock.release)
+    try:
+        if released:
+            timer.start()
+        engine.watchdog._last_progress -= 100_000.0  # the trip edge
+        assert engine.watchdog.check() is True
+    finally:
+        engine.watchdog.stop()
+        if not released:
+            ce._save_entry_lock.release()
+        timer.cancel()
+    assert not ce._save_entry_lock.locked()
+    path = os.path.join(engine.snapshots.snapshot_dir,
+                        "snap-00000002-emergency")
+    ok, detail = verify_snapshot(path)
+    assert ok, detail
+    counters = get_telemetry().registry.snapshot()["counters"]
+    unserialized = counters.get("checkpoint/unserialized_saves_total",
+                                {"value": 0})["value"]
+    assert unserialized == (0 if released else 1)
+
+
 # ---------------------------------------------------------------------------
 # checkpoint-engine sidecar (ISSUE 4 satellite)
 # ---------------------------------------------------------------------------

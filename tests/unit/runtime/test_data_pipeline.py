@@ -3,14 +3,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from deepspeed_tpu.runtime.data_pipeline import (
     CurriculumScheduler, CurriculumSampler, DeepSpeedDataSampler,
     RandomLTDScheduler, random_ltd_apply)
 from deepspeed_tpu.runtime.data_pipeline.data_sampler import truncate_batch
-
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
 
 
 # ---------------------------------------------------------------------------

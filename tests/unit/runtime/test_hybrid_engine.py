@@ -9,9 +9,6 @@ from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.runtime.hybrid_engine import DeepSpeedHybridEngine
 from deepspeed_tpu.utils import groups
-import pytest
-
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
 
 
 def _build(stage=3, enabled=True):

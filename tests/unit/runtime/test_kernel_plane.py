@@ -10,14 +10,11 @@ for kernel scratch and the config-gating fallbacks.
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
-
-pytestmark = pytest.mark.slow
 
 
 def make_engine(extra=None, zero=2, clip=1.0, opt="Adam", dp=8,

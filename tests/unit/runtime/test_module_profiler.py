@@ -7,14 +7,11 @@ MACs/params/latency table honoring ``module_depth``/``top_modules``
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.profiling.flops_profiler.profiler import (
     format_module_table, profile_model_modules)
-
-pytestmark = pytest.mark.slow
 
 
 def _model_and_batch():

@@ -10,7 +10,6 @@ from deepspeed_tpu.runtime.sequence_parallel.ring import (_plain_attention,
                                                           ring_attention)
 from deepspeed_tpu.utils import groups
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
 
 def _qkv(B=2, S=64, h=2, d=16, seed=0):
     rng = np.random.RandomState(seed)

@@ -12,7 +12,6 @@ from deepspeed_tpu.runtime.sequence_parallel import (
 from deepspeed_tpu.sequence import DistributedAttention
 from deepspeed_tpu.utils import groups
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
 
 def softmax_attn(q, k, v):
     scale = 1.0 / np.sqrt(q.shape[-1])

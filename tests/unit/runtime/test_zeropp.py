@@ -13,8 +13,6 @@ from deepspeed_tpu.runtime.zero import qgz
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.jax_compat import shard_map as _shard_map
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
 
 def test_quantized_allreduce_close_to_exact(mesh8):
     rng = np.random.RandomState(0)

@@ -33,7 +33,6 @@ def _unshared_generate(model, params, prompts, n_new):
     return eng.generate(prompts, max_new_tokens=n_new)
 
 
-@pytest.mark.slow
 def test_prefix_sharing_bitwise_identical_and_reclaimed(tiny_model):
     """ISSUE 8 acceptance: two prompts with a shared header allocate the
     header pages once (refcount 2), produce exactly the unshared
@@ -84,7 +83,6 @@ def test_prefix_sharing_bitwise_identical_and_reclaimed(tiny_model):
     assert sched.allocator.num_free == 63
 
 
-@pytest.mark.slow
 def test_prefix_revival_across_sequential_requests(tiny_model):
     """The second request arrives AFTER the first completed: the header
     KV is revived from the cached tier (never recomputed) and the
@@ -113,7 +111,6 @@ def test_prefix_revival_across_sequential_requests(tiny_model):
         is RequestState.DONE
 
 
-@pytest.mark.slow
 def test_replica_kv_pools_attributed_in_memory_ledger(tiny_model):
     """ISSUE 8 satellite: per-replica KV pools and the prefix cache get
     DISTINCT kv_cache sub-keys in the PR-7 memory ledger."""

@@ -1,8 +1,6 @@
 """Flight recorder (ISSUE 2 tentpole): bounded rings, bundle dump/reload
-round trip, crash hooks, and bench.py's exception path recording its
-bundle in the BENCH artifact."""
+round trip and crash hooks."""
 
-import json
 import os
 import signal
 import sys
@@ -10,9 +8,7 @@ import sys
 import pytest
 
 from deepspeed_tpu.telemetry import (FlightRecorder, StepRecord,
-                                     configure_flight_recorder,
-                                     get_flight_recorder, get_telemetry,
-                                     load_bundle)
+                                     get_telemetry, load_bundle)
 
 
 def _rec(step, **over):
@@ -103,33 +99,6 @@ def test_signal_handlers_install_and_restore():
     finally:
         fr.uninstall()
     assert signal.getsignal(signal.SIGTERM) == prev_term
-
-
-def test_bench_exception_path_writes_bundle(tmp_path, capsys, monkeypatch):
-    """Acceptance (ISSUE 2): bench.py's exception path writes a debug
-    bundle and records its path in the one-line BENCH artifact."""
-    import bench
-
-    configure_flight_recorder(output_path=str(tmp_path))
-
-    def boom():
-        raise RuntimeError("induced bench crash")
-
-    monkeypatch.setattr(bench, "_main", boom)
-    with pytest.raises(SystemExit) as ei:
-        bench.main()
-    assert ei.value.code == 4
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    doc = json.loads(line)
-    assert doc["metric"] == "llama_110m_train_tokens_per_sec"
-    assert doc["value"] == 0.0
-    assert doc["error"].startswith("RuntimeError: induced bench crash")
-    assert doc["debug_bundle"] and os.path.isdir(doc["debug_bundle"])
-    m = load_bundle(doc["debug_bundle"])["manifest"]
-    assert "bench unhandled exception" in m["reason"]
-    assert "induced bench crash" in m["extra"]["traceback"]
-    # the crash bundle came from the process-global recorder
-    assert get_flight_recorder().last_bundle_path == doc["debug_bundle"]
 
 
 def test_bundle_retention_prunes_to_newest_k(tmp_path):

@@ -15,8 +15,6 @@ import pytest
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
 
-pytestmark = pytest.mark.slow  # jit/engine-heavy; smoke tier runs -m "not slow"
-
 
 # ---------------------------------------------------------------- elasticity
 

@@ -17,8 +17,6 @@ from deepspeed_tpu.comm import overlap as ov
 from deepspeed_tpu.comm.comm import comms_logger
 from deepspeed_tpu.utils.jax_compat import shard_map
 
-pytestmark = pytest.mark.slow
-
 
 @pytest.fixture()
 def mesh():
