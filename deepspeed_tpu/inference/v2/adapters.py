@@ -11,6 +11,18 @@ QKV projection (biasless vs biased), and the FFN/residual block.
 All hooks operate on FLAT token batches ``[N, ...]`` so the same adapter
 serves both compiled programs (prefill rows are flattened ``[Bp*C]``,
 decode is ``[B]``).  Positions come in as an ``[N]`` int32 vector.
+
+What ``layers(params)`` returns is the ``xs`` of the engine's layer scan,
+and **a scan slices whatever its ``xs`` hold**: each step gets layer
+``l``'s leaves as values of their own.  XLA fuses such a slice into a
+matmul that reads it, but not into a custom call's operand: there it is
+a copy of the layer.  So ``post_attn`` also gets the whole ``params`` and
+the layer's index ``l``, and a family whose weights feed a kernel that can
+address a layer itself keeps those leaves out of ``layers()`` and hands
+them over whole.  One family does: :class:`OlmoeV2Adapter` (the expert
+stacks of the grouped matmul, 805 MB a layer at OLMoE-1B-7B's widths).
+The registry picks a family's hooks; no hook tests a model's type or a
+flag, and the engine knows nothing of what rides whole.
 """
 
 from __future__ import annotations
@@ -71,7 +83,8 @@ class ModelAdapterV2:
     # -- jit-side hooks -----------------------------------------------------
 
     def layers(self, params: Any) -> Any:
-        """Stacked-layer pytree with leading ``L`` dim (for ``lax.scan``)."""
+        """Stacked-layer pytree with leading ``L`` dim: the ``xs`` of the
+        engine's ``lax.scan``, sliced a layer a step (``lp`` below)."""
         return params["layers"]
 
     def embed(self, params: Any, tokens: jnp.ndarray,
@@ -84,10 +97,12 @@ class ModelAdapterV2:
         rotary encoding already applied."""
         raise NotImplementedError
 
-    def post_attn(self, lp: Any, x: jnp.ndarray,
-                  attn: jnp.ndarray) -> jnp.ndarray:
+    def post_attn(self, lp: Any, x: jnp.ndarray, attn: jnp.ndarray,
+                  params: Any, l: jnp.ndarray) -> jnp.ndarray:
         """Output projection + residual + FFN block: ``x [N, H]``,
-        ``attn [N, h, d]`` → ``[N, H]``."""
+        ``attn [N, h, d]`` → ``[N, H]``.  ``params`` is the whole tree and
+        ``l`` this layer's index (traced), for what ``layers()`` left out
+        of ``lp``."""
         raise NotImplementedError
 
     def finalize(self, params: Any, x: jnp.ndarray) -> jnp.ndarray:
@@ -100,10 +115,11 @@ class ModelAdapterV2:
 
 
 class LlamaV2Adapter(ModelAdapterV2):
-    """Llama/Mistral/Mixtral/OLMoE family: RoPE, RMSNorm, biasless
-    projections, the config's q/k norm.  The sparse-expert models route
-    through the same hooks because ``post_attn`` delegates the FFN to
-    ``model._ffn`` (the MoE override)."""
+    """Llama/Mistral/Mixtral family: RoPE, RMSNorm, biasless projections,
+    the config's q/k norm.  Mixtral routes through the same hooks because
+    ``post_attn`` delegates the FFN to ``model._ffn`` (the MoE override:
+    GShard's ``MOELayer``, whose einsums XLA fuses the layer's slice
+    into)."""
 
     def embed(self, params, tokens, positions):
         del positions  # rotary — positions enter at qkv time
@@ -123,7 +139,7 @@ class LlamaV2Adapter(ModelAdapterV2):
         k = _rope(k, positions, c.rope_theta)
         return q, k, v
 
-    def post_attn(self, lp, x, attn):
+    def post_attn(self, lp, x, attn, params, l):
         from ...models.llama import _rms_norm
 
         c = self.config
@@ -131,8 +147,12 @@ class LlamaV2Adapter(ModelAdapterV2):
         out = jnp.einsum("nhd,hdH->nH", attn, lp["attn"]["wo"].astype(dt))
         x = x + out
         h = _rms_norm(x, lp["mlp_norm"].astype(dt), c.rms_norm_eps)
-        ffn_out, _ = self.model._ffn(h[None], lp)
-        return x + ffn_out[0]
+        return x + self.ffn(lp, h, params, l)
+
+    def ffn(self, lp, h, params, l):
+        """The FFN half of ``post_attn``: ``h [N, H]`` (normed) → ``[N, H]``."""
+        del params, l  # everything the FFN reads is in the layer's slice
+        return self.model._ffn(h[None], lp)[0][0]
 
     def finalize(self, params, x):
         from ...models.llama import _rms_norm
@@ -144,6 +164,29 @@ class LlamaV2Adapter(ModelAdapterV2):
     def logits(self, params, x):
         head = self.model._head(params).astype(self.dtype)
         return jnp.einsum("nH,HV->nV", x, head).astype(jnp.float32)
+
+
+class OlmoeV2Adapter(LlamaV2Adapter):
+    """OLMoE (and any family on ``moe.layer.DroplessMoE``): the Llama hooks,
+    with the three expert stacks ``layers.moe.w_gate/w_up/w_down
+    [L, E, …]`` kept OUT of the scan's ``xs`` and handed whole, with the
+    layer's index, to the grouped matmul, which reads layer ``l``'s
+    experts where they lie.  Sliced by the scan they were copied for the
+    Mosaic call: 19.6 ms of a 32.5 ms decode step of the serving cell
+    (PERF.md §6, PR 30).  The router's ``wg`` is sliced as before."""
+
+    WHOLE = ("w_gate", "w_up", "w_down")
+
+    def layers(self, params):
+        layers = dict(params["layers"])
+        layers["moe"] = {name: w for name, w in layers["moe"].items()
+                         if name not in self.WHOLE}
+        return layers
+
+    def ffn(self, lp, h, params, l):
+        stacks = params["layers"]["moe"]
+        moe = dict(lp["moe"], **{name: stacks[name] for name in self.WHOLE})
+        return self.model._ffn(h[None], {"moe": moe}, layer=l)[0][0]
 
 
 class OPTV2Adapter(ModelAdapterV2):
@@ -177,9 +220,10 @@ class OPTV2Adapter(ModelAdapterV2):
             + a["bv"].astype(dt)
         return q, k, v
 
-    def post_attn(self, lp, x, attn):
+    def post_attn(self, lp, x, attn, params, l):
         from ...models.bert import _layer_norm
 
+        del params, l  # everything is in the layer's slice
         c = self.config
         dt = self.dtype
         out = jnp.einsum("nhd,hdH->nH", attn, lp["attn"]["wo"].astype(dt)) \
@@ -210,6 +254,6 @@ class OPTV2Adapter(ModelAdapterV2):
 _REGISTRY = {
     "LlamaModel": LlamaV2Adapter,
     "MixtralModel": LlamaV2Adapter,
-    "OlmoeModel": LlamaV2Adapter,
+    "OlmoeModel": OlmoeV2Adapter,
     "OPTModel": OPTV2Adapter,
 }

@@ -196,8 +196,8 @@ class RaggedInferenceEngineV2:
     # compiled programs
     # ------------------------------------------------------------------
 
-    def _layer_step(self, lp, l, pool, x_flat, positions_flat, write_fn,
-                    attend_fn):
+    def _layer_step(self, params, lp, l, pool, x_flat, positions_flat,
+                    write_fn, attend_fn):
         """Shared per-layer skeleton: qkv → KV write → attention →
         post-attn block.  ``write_fn``/``attend_fn`` differ between the
         prefill and decode programs.
@@ -215,7 +215,7 @@ class RaggedInferenceEngineV2:
         q, kk, vv = ad.qkv(lp, x_flat, positions_flat)
         pool = write_fn(pool, l, kk, vv)
         attn = attend_fn(q, pool, l)
-        x_flat = ad.post_attn(lp, x_flat, attn)
+        x_flat = ad.post_attn(lp, x_flat, attn, params, l)
         return x_flat, pool
 
     @staticmethod
@@ -228,9 +228,14 @@ class RaggedInferenceEngineV2:
     def _scan_layers(self, params, pool, x, positions_flat, write_fn,
                      attend_fn):
         """The layer scan of both programs.  Carry: the activations and
-        the pool; ``xs``: each layer's parameters and its index; ``ys``:
-        the MoE gate's stats (``moe_stats`` inside ``model._ffn``), which
-        must leave the scan as ``ys`` — names ride the dict keys."""
+        the pool; ``xs``: what the adapter's ``layers(params)`` holds, a
+        layer's slice a step, and the layer's index; ``ys``: the MoE
+        gate's stats (``moe_stats`` inside ``model._ffn``), which must
+        leave the scan as ``ys`` — names ride the dict keys.  A leaf in
+        ``xs`` is sliced whatever is done with it later, so what an
+        adapter wants whole it leaves out of ``layers()`` and reads from
+        ``params`` at ``l``, both of which its hooks are given (as the
+        pool is read at ``l`` here)."""
         from ...telemetry import numerics
 
         ad = self.adapter
@@ -239,8 +244,8 @@ class RaggedInferenceEngineV2:
             x, pool = carry
             lp, l = xs
             mark = numerics.scan_mark()
-            x, pool = self._layer_step(lp, l, pool, x, positions_flat,
-                                       write_fn, attend_fn)
+            x, pool = self._layer_step(params, lp, l, pool, x,
+                                       positions_flat, write_fn, attend_fn)
             return (x, pool), numerics.scan_drain(mark)
 
         (x, pool), stats = jax.lax.scan(

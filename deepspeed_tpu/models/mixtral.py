@@ -122,13 +122,18 @@ class MixtralModel(LlamaModel):
 
     # ------------------------------------------------------------------
 
-    def _ffn(self, h: jnp.ndarray, lp: Any) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def _ffn(self, h: jnp.ndarray, lp: Any, layer: Any = None
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Routed-FFN via the shared MOELayer (one dispatch implementation
-        for the whole framework) with an expert-TP-constrained SwiGLU expert."""
+        for the whole framework) with an expert-TP-constrained SwiGLU expert.
+        ``layer`` is passed through to a layer object that takes the expert
+        stacks whole (``DroplessMoE``): ``lp["moe"]``'s ``w_*`` are then the
+        ``[L, E, …]`` stacks, ``wg`` still the one layer's."""
         from ..telemetry import numerics
 
         moe = lp["moe"]
         y, l_aux, meta = self._moe_layer(
-            moe["wg"], {k: moe[k] for k in ("w_gate", "w_up", "w_down")}, h)
+            moe["wg"], {k: moe[k] for k in ("w_gate", "w_up", "w_down")}, h,
+            **({} if layer is None else {"layer": layer}))
         numerics.moe_stats(meta)
         return numerics.probe("mlp_out", y), l_aux

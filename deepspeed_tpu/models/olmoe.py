@@ -12,6 +12,15 @@ Weights sit in Mixtral's stacked layout (``layers.moe.{wg [L,H,E], w_gate,
 w_up [L,E,H,I], w_down [L,E,I,H]}``) plus ``layers.attn.q_norm`` / ``k_norm
 [L, heads·d]``; init, partition specs and the ``_ffn`` hook are
 ``MixtralModel``'s, the routing rule is this family's own.
+
+The grouped matmul takes the expert stacks ``[L, E, …]`` plus a layer's
+index and reads the layer where it lies.  The training scan of
+``models/llama.py`` slices every leaf a layer a step (XLA's own matmuls
+fuse that slice), so there ``_ffn`` hands ``DroplessMoE`` one layer's
+leaves and it makes the stack of one.  The serving engine's
+``OlmoeV2Adapter`` (``inference/v2/adapters.py``) keeps the three stacks
+out of its scan and passes them whole with ``layer=l``: sliced, each
+layer's 805 MB of experts was copied for the Mosaic call.
 """
 
 from __future__ import annotations

@@ -47,7 +47,18 @@ class DroplessMoE:
     ``(wg [H, E], {w_gate, w_up [E, H, I], w_down [E, I, H]}, x [B, S, H])
     → (y, l_aux, meta)``.  The kernels run on an unsharded TPU; anywhere else
     (and under a mesh of several devices, where a Mosaic call does not
-    partition itself) the same layout runs ``jax.lax.ragged_dot``."""
+    partition itself) the same layout runs ``jax.lax.ragged_dot``.
+
+    The grouped matmul has one form, the stacked layers' weights plus a
+    layer's index, and this is where a caller chooses.  ``layer=None``: the
+    three leaves are one layer's, as a scan over the layers hands them
+    (``models/llama.py``'s training scan), and become a stack of one here,
+    ``w[None]`` at layer 0: free for a value the scan has sliced already.
+    ``layer=l``: the leaves are the whole ``[L, E, …]`` stacks and the
+    kernels read layer ``l``'s experts where they lie.  A serving program
+    that let its layer scan slice them would copy every layer's experts
+    for the custom call (the v2 engine's ``OlmoeV2Adapter`` keeps them out
+    of the scan for that reason); ``wg`` is one layer's either way."""
 
     def __init__(self, num_experts: int, k: int, renormalize: bool = False,
                  mesh: Any = None):
@@ -56,11 +67,15 @@ class DroplessMoE:
         self.renormalize = renormalize
         self.mesh = mesh
 
-    def __call__(self, wg: jnp.ndarray, expert_params: Any, x: jnp.ndarray
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
+    def __call__(self, wg: jnp.ndarray, expert_params: Any, x: jnp.ndarray,
+                 layer: Any = None) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
         from ..ops.pallas import moe_grouped_matmul as gm
         from .sharded_moe import top_k_routing
 
+        if layer is None:
+            expert_params = {name: w[None]
+                             for name, w in expert_params.items()}
+            layer = 0
         B, S, H = x.shape
         tokens = x.reshape(B * S, H)
         expert_idx, weights, meta = top_k_routing(
@@ -71,8 +86,9 @@ class DroplessMoE:
         sharded = self.mesh is not None and self.mesh.size > 1
         rows = gm.gather_rows(tokens, plan)
         act = gm.grouped_swiglu(rows, expert_params["w_gate"],
-                                expert_params["w_up"], plan, sharded=sharded)
-        out = gm.grouped_matmul(act, expert_params["w_down"], plan,
+                                expert_params["w_up"], layer, plan,
+                                sharded=sharded)
+        out = gm.grouped_matmul(act, expert_params["w_down"], layer, plan,
                                 sharded=sharded)
         y = gm.combine_rows(out, plan, weights).astype(x.dtype)
         return y.reshape(B, S, H), meta["l_aux"], meta

@@ -25,10 +25,25 @@ stream of weights, and that is what its roofline counts.
 :func:`grouped_swiglu` fuses the gate and up projections with
 ``silu(g)·u``; :func:`grouped_matmul` is the down projection.
 
+**The weights have a layer**: both take the whole stack ``w [L, E, K, N]``
+as the model holds it and ``layer``, an int32 scalar that may be traced
+(the index of a layer scan).  The kernels read the stack's flat view
+``[L·E, K, N]`` (two adjacent major dimensions merged: a bitcast) with
+``layer·E`` added to ``tile_group``, so expert ``e`` of layer ``l`` is
+block ``l·E + e`` and the layer's experts are read where they lie.  Handed
+one layer's ``w[l]`` out of a scanned stack instead, XLA copies the slice
+to make it a custom call's operand: 805 MB a layer at OLMoE-1B-7B's
+widths, 60% of the serving cell's device time (PERF.md §6, PR 30).  There
+is no entry for unstacked weights: a single layer is a stack of one
+(``DroplessMoE`` builds it).
+
 Off the TPU, and under a sharded mesh (a Mosaic call does not partition
 itself), the same layout runs :func:`grouped_matmul_reference`
-(``jax.lax.ragged_dot`` over the padded group sizes).  The kernels are
-differentiable through that reference (``custom_vjp``): no backward kernel.
+(``jax.lax.ragged_dot`` of ``w[layer]`` over the padded group sizes: XLA
+may fuse or copy that slice as it likes, and a stack sharded over its
+``E`` keeps its sharding, which a flat ``[L·E]`` view would not).  The
+kernels are differentiable through that reference (``custom_vjp``): no
+backward kernel.
 """
 
 from __future__ import annotations
@@ -123,22 +138,23 @@ def _padded_group_sizes(tile_group, num_tiles, num_experts, tile_rows):
     return (per_group * tile_rows).astype(jnp.int32)
 
 
-def _ragged(x, weights, tile_group, num_tiles):
-    """``x`` times each of ``weights [E, K, N]``, in float32: tile i's rows
-    times ``w[tile_group[i]]``, zeros past the tiles in use."""
-    sizes = _padded_group_sizes(tile_group, num_tiles, weights[0].shape[0],
+def _ragged(x, weights, layer, tile_group, num_tiles):
+    """``x`` times layer ``layer`` of each of ``weights [L, E, K, N]``, in
+    float32: tile i's rows times ``w[layer, tile_group[i]]``, zeros past the
+    tiles in use."""
+    sizes = _padded_group_sizes(tile_group, num_tiles, weights[0].shape[1],
                                 x.shape[0] // tile_group.shape[0])
-    return [jax.lax.ragged_dot(x, w.astype(x.dtype), sizes,
+    return [jax.lax.ragged_dot(x, w[layer], sizes,
                                preferred_element_type=jnp.float32)
             for w in weights]
 
 
-def grouped_matmul_reference(x, w, tile_group, num_tiles):
-    return _ragged(x, (w,), tile_group, num_tiles)[0].astype(x.dtype)
+def grouped_matmul_reference(x, w, layer, tile_group, num_tiles):
+    return _ragged(x, (w,), layer, tile_group, num_tiles)[0].astype(x.dtype)
 
 
-def grouped_swiglu_reference(x, w_gate, w_up, tile_group, num_tiles):
-    gate, up = _ragged(x, (w_gate, w_up), tile_group, num_tiles)
+def grouped_swiglu_reference(x, w_gate, w_up, layer, tile_group, num_tiles):
+    gate, up = _ragged(x, (w_gate, w_up), layer, tile_group, num_tiles)
     return (jax.nn.silu(gate) * up).astype(x.dtype)
 
 
@@ -180,6 +196,8 @@ def _tile_n(K: int, N: int, itemsize: int) -> int:
 
 def _grouped_call(kernel, name, x, weights, tile_group, num_tiles,
                   interpret: bool):
+    """``weights``: each ``[G, K, N]``; tile m multiplies block
+    ``tile_group[m]`` of the ``G``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -213,20 +231,25 @@ def _differentiable(kernel, name, reference):
     """The kernel forward, the reference's gradient backward."""
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-    def run(interpret, tile_group, num_tiles, x, *weights):
-        return _grouped_call(kernel, name, x, weights, tile_group,
-                             num_tiles, interpret)
+    def run(interpret, tile_group, num_tiles, layer, x, *weights):
+        # the stacks' flat view [L·E, K, N]; the layer's offset rides the
+        # tile -> block map the kernel prefetches anyway
+        experts = weights[0].shape[1]
+        flat = [w.reshape((-1,) + w.shape[2:]) for w in weights]
+        return _grouped_call(kernel, name, x, flat,
+                             tile_group + layer * experts, num_tiles,
+                             interpret)
 
-    def fwd(interpret, tile_group, num_tiles, x, *weights):
-        return (run(interpret, tile_group, num_tiles, x, *weights),
-                (tile_group, num_tiles, x, weights))
+    def fwd(interpret, tile_group, num_tiles, layer, x, *weights):
+        return (run(interpret, tile_group, num_tiles, layer, x, *weights),
+                (tile_group, num_tiles, layer, x, weights))
 
     def bwd(interpret, saved, g):
-        tile_group, num_tiles, x, weights = saved
+        tile_group, num_tiles, layer, x, weights = saved
         _, vjp = jax.vjp(
-            lambda x_, *w_: reference(x_, *w_, tile_group, num_tiles),
+            lambda x_, *w_: reference(x_, *w_, layer, tile_group, num_tiles),
             x, *weights)
-        return (None, None) + vjp(g)
+        return (None, None, None) + vjp(g)
 
     run.defvjp(fwd, bwd)
     return run
@@ -250,27 +273,43 @@ def _runs_reference(kernel: str, x, w, interpret: Optional[bool]) -> bool:
     return False
 
 
-def grouped_matmul(x, w, plan: GroupPlan, interpret: Optional[bool] = None,
-                   sharded: bool = False):
-    """``x [R, K]`` in the plan's layout times each tile's expert of ``w
-    [E, K, N]`` → ``[R, N]``.  Rows of unused tiles are undefined (the
-    plan's ``dest`` never points at them).  ``sharded``: the operands live
-    on a mesh of several devices, so the reference runs everywhere."""
-    w = w.astype(x.dtype)
-    if sharded or _runs_reference("moe_grouped_matmul", x, w, interpret):
-        return grouped_matmul_reference(x, w, plan.tile_group,
-                                        plan.num_tiles)
-    return _matmul(bool(interpret), plan.tile_group, plan.num_tiles, x, w)
+def _in_rows_dtype(weights, layer, dtype):
+    """The stacks in the rows' dtype, and the layer's index in them.
+    Where they are in it already (the serving cells: bf16 both) nothing
+    is done and nothing copied.  Otherwise (float32 master weights under
+    bf16 rows) the cast has to write what it casts, so it casts the one
+    layer, ``w[layer]``, and hands it on as a stack of one: a caller that
+    scans many layers of such weights through the kernels casts the stack
+    once, outside its scan, if it wants the layer read in place."""
+    if all(w.dtype == dtype for w in weights):
+        return weights, jnp.asarray(layer, jnp.int32)
+    return ([w[layer].astype(dtype)[None] for w in weights],
+            jnp.zeros((), jnp.int32))
 
 
-def grouped_swiglu(x, w_gate, w_up, plan: GroupPlan,
+def grouped_matmul(x, w, layer, plan: GroupPlan,
                    interpret: Optional[bool] = None, sharded: bool = False):
-    """``silu(x·w_gate[e]) ⊙ (x·w_up[e])`` per tile: ``[R, H]`` → ``[R, I]``;
-    weights as in :func:`grouped_matmul`."""
-    w_gate, w_up = w_gate.astype(x.dtype), w_up.astype(x.dtype)
+    """``x [R, K]`` in the plan's layout times each tile's expert of layer
+    ``layer`` of the stack ``w [L, E, K, N]`` → ``[R, N]``.  Rows of unused
+    tiles are undefined (the plan's ``dest`` never points at them).
+    ``sharded``: the operands live on a mesh of several devices, so the
+    reference runs everywhere."""
+    (w,), layer = _in_rows_dtype((w,), layer, x.dtype)
+    if sharded or _runs_reference("moe_grouped_matmul", x, w, interpret):
+        return grouped_matmul_reference(x, w, layer, plan.tile_group,
+                                        plan.num_tiles)
+    return _matmul(bool(interpret), plan.tile_group, plan.num_tiles, layer,
+                   x, w)
+
+
+def grouped_swiglu(x, w_gate, w_up, layer, plan: GroupPlan,
+                   interpret: Optional[bool] = None, sharded: bool = False):
+    """``silu(x·w_gate[layer, e]) ⊙ (x·w_up[layer, e])`` per tile: ``[R, H]``
+    → ``[R, I]``; weights as in :func:`grouped_matmul`."""
+    (w_gate, w_up), layer = _in_rows_dtype((w_gate, w_up), layer, x.dtype)
     if sharded or _runs_reference("moe_grouped_matmul_swiglu", x, w_gate,
                                   interpret):
-        return grouped_swiglu_reference(x, w_gate, w_up, plan.tile_group,
-                                        plan.num_tiles)
-    return _swiglu(bool(interpret), plan.tile_group, plan.num_tiles, x,
-                   w_gate, w_up)
+        return grouped_swiglu_reference(x, w_gate, w_up, layer,
+                                        plan.tile_group, plan.num_tiles)
+    return _swiglu(bool(interpret), plan.tile_group, plan.num_tiles, layer,
+                   x, w_gate, w_up)

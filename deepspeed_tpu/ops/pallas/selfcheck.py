@@ -389,7 +389,9 @@ def check_moe_grouped(s: KernelShapes, interpret: bool) -> List[Check]:
     """The dropless expert layer (rows sorted by expert, the two grouped
     matmuls, the weighted sum back) against each expert run over all
     tokens in float32.  Experts of OLMoE's width, or the model's FFN where
-    that is narrower; routing skewed so that groups differ in size."""
+    that is narrower; routing skewed so that groups differ in size.  The
+    weights are a stack of two layers read at layer 1, as a serving
+    program reads them: the layer's offset is part of what is checked."""
     gm = _mod("moe_grouped_matmul")
     rng = np.random.RandomState(8)
     T, E, K, H = s.moe_tokens, s.moe_experts, s.moe_top_k, s.hidden
@@ -397,14 +399,17 @@ def check_moe_grouped(s: KernelShapes, interpret: bool) -> List[Check]:
     logits = _normal(rng, (T, E), jnp.float32) + jnp.linspace(1.0, 0.0, E)
     gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
     x = _normal(rng, (T, H), s.dtype)
-    w_gate, w_up = (_normal(rng, (E, H, inner), s.dtype, H ** -0.5)
-                    for _ in range(2))
-    w_down = _normal(rng, (E, inner, H), s.dtype, inner ** -0.5)
+    layer = 1
+    stack_gate, stack_up = (_normal(rng, (2, E, H, inner), s.dtype, H ** -0.5)
+                            for _ in range(2))
+    stack_down = _normal(rng, (2, E, inner, H), s.dtype, inner ** -0.5)
     plan = gm.plan_groups(idx, E, gm.tile_rows_for(T * K, E, s.dtype))
-    act = gm.grouped_swiglu(gm.gather_rows(x, plan), w_gate, w_up, plan,
-                            interpret=interpret)
-    got = gm.combine_rows(gm.grouped_matmul(act, w_down, plan,
+    act = gm.grouped_swiglu(gm.gather_rows(x, plan), stack_gate, stack_up,
+                            layer, plan, interpret=interpret)
+    got = gm.combine_rows(gm.grouped_matmul(act, stack_down, layer, plan,
                                             interpret=interpret), plan, gates)
+    w_gate, w_up, w_down = (w[layer] for w in
+                            (stack_gate, stack_up, stack_down))
 
     xe = x.astype(jnp.float32)
 

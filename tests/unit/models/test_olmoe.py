@@ -16,7 +16,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[3]))
 
 from deepspeed_tpu.inference.v2 import KVCacheConfig
 from deepspeed_tpu.inference.v2 import engine_v2
-from deepspeed_tpu.inference.v2.adapters import LlamaV2Adapter, make_adapter
+from deepspeed_tpu.inference.v2.adapters import (LlamaV2Adapter,
+                                                 OlmoeV2Adapter, make_adapter)
 from deepspeed_tpu.models import (LlamaConfig, LlamaModel, MixtralConfig,
                                   MixtralModel, OlmoeConfig, OlmoeModel)
 from deepspeed_tpu.moe import DroplessMoE, MOELayer
@@ -74,8 +75,16 @@ def test_the_family_is_the_llama_backbone_with_its_own_routing(model, params):
     assert isinstance(MixtralModel(MixtralConfig.tiny())._moe_layer, MOELayer)
     dense = LlamaModel(LlamaConfig.tiny()).init_params(jax.random.PRNGKey(0))
     assert "q_norm" not in dense["layers"]["attn"]
+    # the family's own adapter keeps the three expert stacks out of what
+    # the engine's layer scan slices, and nothing else; the tree itself
+    # keeps Mixtral's layout
     adapter = make_adapter(model)
-    assert type(adapter) is LlamaV2Adapter
+    assert type(adapter) is OlmoeV2Adapter
+    scanned = adapter.layers(params)
+    assert set(scanned["moe"]) == {"wg"} and scanned["moe"]["wg"] is moe["wg"]
+    assert {k: v for k, v in scanned.items() if k != "moe"} == {
+        k: v for k, v in params["layers"].items() if k != "moe"}
+    assert set(moe) == {"wg", "w_gate", "w_up", "w_down"}
 
 
 def test_one_layer(model, params):
@@ -170,6 +179,49 @@ def test_prefill_then_decode_through_the_paged_cache(model, params,
                         if np.max(np.abs(r - want[i])) < LOGIT_TOL]
                 assert rows, (i, token)
                 assert int(np.argmax(want[i])) == token
+
+
+def _tiny(family):
+    if family == "olmoe":
+        cfg = OlmoeConfig.tiny(dtype=jnp.float32, remat=False)
+        return OlmoeModel(cfg), OlmoeV2Adapter
+    if family == "mixtral":
+        # capacity 2·T·2.0/4 = T slots an expert: nothing is dropped, so a
+        # token does not depend on who shares its batch
+        cfg = MixtralConfig.tiny(num_layers=2, dtype=jnp.float32,
+                                 remat=False)
+        return MixtralModel(cfg), LlamaV2Adapter
+    cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32, remat=False)
+    return LlamaModel(cfg), LlamaV2Adapter
+
+
+@pytest.mark.parametrize("family", ["olmoe", "mixtral", "dense_llama"])
+def test_the_engine_serves_the_tokens_of_the_models_own_forward(family):
+    """Through each family's adapter (OLMoE's hands the expert stacks over
+    whole with the layer's index; Mixtral and a dense Llama keep
+    ``LlamaV2Adapter``, whose scan slices the whole layers' tree as
+    before): every served token is the argmax of the model's own forward
+    pass over the prompt and what was served before it."""
+    tiny, adapter_type = _tiny(family)
+    weights = tiny.init_params(jax.random.PRNGKey(3))
+    engine = engine_v2.build_engine_v2(
+        tiny, weights, KVCacheConfig(block_size=16, num_blocks=32,
+                                     max_seq_len=128),
+        max_batch_slots=4, prefill_chunk=32, prefill_batch=2, decode_burst=4)
+    assert type(engine.adapter) is adapter_type
+    if adapter_type is LlamaV2Adapter:
+        assert engine.adapter.layers(weights) is weights["layers"]
+    prompts = [np.asarray(IDS[0, :37]).tolist(),
+               np.asarray(IDS[1, :12]).tolist()]
+    new = 6
+    with jax.default_matmul_precision("highest"):
+        served = engine.generate(prompts, max_new_tokens=new)
+        for prompt, tokens in zip(prompts, served):
+            assert len(tokens) == new
+            logits = tiny.forward(weights,
+                                  jnp.asarray([prompt + tokens[:-1]]))[0]
+            want = np.argmax(np.asarray(logits[len(prompt) - 1:]), axis=-1)
+            assert want.tolist() == tokens
 
 
 def test_the_engine_counts_assignments_and_active_experts(model, params):

@@ -88,6 +88,33 @@ def test_layer_equals_the_plain_reference(k, renormalize):
     assert float(meta["drop_rate"]) == 0.0 and float(l_aux) > 0.0
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("several_devices", [False, True],
+                         ids=["one_device", "mesh_of_two"])
+def test_the_stacked_layers_weights_and_an_index_are_that_layers_call(
+        layer, several_devices):
+    """``layer=l`` with the ``[3, E, …]`` stacks (what a serving program
+    hands over, ``l`` traced) against ``layer=None`` with layer ``l``'s own
+    leaves (what a scan over the layers hands over): bit for bit, and the
+    router's stats too.  Under a mesh the reference indexes the stack."""
+    from jax.sharding import Mesh
+
+    wg, _, x = _params(4)
+    stacks = jax.tree.map(lambda *ws: jnp.stack(ws),
+                          *(_params(seed)[1] for seed in (5, 6, 7)))
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("data",)) \
+        if several_devices else None
+    moe = DroplessMoE(E, 8, mesh=mesh)
+    y, l_aux, meta = jax.jit(
+        lambda l: moe(wg, stacks, x[None], layer=l))(jnp.int32(layer))
+    one = jax.tree.map(lambda w: w[layer], stacks)
+    want, want_aux, want_meta = jax.jit(lambda: moe(wg, one, x[None]))()
+    assert bool(jnp.all(y == want)) and float(l_aux) == float(want_aux)
+    assert bool(jnp.all(meta["exp_counts"] == want_meta["exp_counts"]))
+    other = jax.tree.map(lambda w: w[(layer + 1) % 3], stacks)
+    assert not bool(jnp.all(y == moe(wg, other, x[None])[0]))
+
+
 def test_under_a_skewed_router_the_capacity_gate_drops_and_this_does_not():
     """The control: every row picks the same experts.  At k = 2, which the
     capacity gate can run, its capacity ``ceil(2·48·2.0/16) = 12`` slots an

@@ -1,8 +1,11 @@
 """The dropless expert layout and its grouped matmul: the plan's
 invariants for any routing, the Pallas kernels (interpreter) and the
 ``ragged_dot`` reference against one expert at a time over all tokens, and
-the kernels' gradients, which run through the reference."""
+the kernels' gradients, which run through the reference.  The weights are
+a stack of layers plus a layer's index: every layer of a stack of three
+gives the bits of that layer as a stack of one."""
 
+import functools
 import pathlib
 import re
 
@@ -37,10 +40,22 @@ def _weights(seed=1):
             jax.random.uniform(ks[4], (T, K)))
 
 
-def _layer(plan, x, w_gate, w_up, w_down, gates, interpret):
+def _stacks_of_three():
+    """``w_gate, w_up [3, E, H, I]``, ``w_down [3, E, I, H]``: three layers
+    of different weights."""
+    return [jnp.stack(ws) for ws in zip(*(_weights(seed)[1:4]
+                                          for seed in (5, 6, 7)))]
+
+
+def _layer(plan, x, w_gate, w_up, w_down, gates, interpret, layer=None):
+    """``layer=None``: one layer's ``[E, K, N]`` weights, made the stack of
+    one that is the kernels' only form; else ``[L, E, K, N]`` stacks."""
+    if layer is None:
+        w_gate, w_up, w_down, layer = w_gate[None], w_up[None], w_down[None], 0
     rows = gm.gather_rows(x, plan)
-    act = gm.grouped_swiglu(rows, w_gate, w_up, plan, interpret=interpret)
-    out = gm.grouped_matmul(act, w_down, plan, interpret=interpret)
+    act = gm.grouped_swiglu(rows, w_gate, w_up, layer, plan,
+                            interpret=interpret)
+    out = gm.grouped_matmul(act, w_down, layer, plan, interpret=interpret)
     return gm.combine_rows(out, plan, gates)
 
 
@@ -89,6 +104,50 @@ def test_layer_equals_one_expert_at_a_time(routing, interpret):
         got = _layer(plan, *args, interpret=interpret)
         want = _one_expert_at_a_time(idx, *args)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["pallas_interpret", "reference"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16_rows_float32_stack"])
+def test_a_layer_of_a_stack_of_three_is_that_layer_bit_for_bit(layer, interpret,
+                                                               dtype):
+    """The same blocks in the same order, so the same sums: layer ``l`` of
+    ``[3, E, K, N]`` read in place, with ``l`` traced as a scan's index is,
+    against that layer alone as a stack of one.  With bf16 rows over the
+    float32 stack the layer is cast, not the stack."""
+    idx = ROUTINGS["one_heavy_expert"]
+    plan = gm.plan_groups(idx, E, 16)
+    x, *_, gates = _weights(4)
+    x = x.astype(dtype)
+    stacks = _stacks_of_three()
+    got = jax.jit(lambda l: _layer(plan, x, *stacks, gates, interpret,
+                                   layer=l))(jnp.int32(layer))
+    want = _layer(plan, x, *(w[layer] for w in stacks), gates, interpret)
+    assert got.dtype == want.dtype
+    assert bool(jnp.all(got == want))
+    others = [_layer(plan, x, *(w[l] for w in stacks), gates, interpret)
+              for l in range(3) if l != layer]
+    assert not any(bool(jnp.all(got == other)) for other in others)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_gradients_reach_the_layer_of_the_stack_and_no_other(layer):
+    idx = ROUTINGS["spread"]
+    plan = gm.plan_groups(idx, E, 16)
+    x, *_, gates = _weights(4)
+    stacks = _stacks_of_three()
+    loss = lambda *w, l=None: jnp.sum(
+        _layer(plan, x, *w, gates, True, layer=l) ** 2)
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(functools.partial(loss, l=jnp.int32(layer)),
+                       argnums=(0, 1, 2))(*stacks)
+        want = jax.grad(loss, argnums=(0, 1, 2))(*(w[layer] for w in stacks))
+    for g, w in zip(got, want):
+        assert g.shape == (3,) + w.shape
+        assert bool(jnp.all(g[layer] == w))
+        assert float(jnp.abs(g).sum()) == float(jnp.abs(g[layer]).sum())
 
 
 def test_gradients_of_the_kernel_path_are_the_references():

@@ -82,6 +82,14 @@ _SERVING_CELLS = {
 _POOL_LAYERS, _POOL_PAGES, _PAGE, _SLOTS = 2, 3200, 16, 32
 
 
+def _values_made(text):
+    """``(name, dims, opcode, operands…)`` of every instruction of a
+    compiled program's text whose value is one array (a tuple's shape
+    starts with a parenthesis and is not matched)."""
+    return re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = \w+\[([\d,]+)\]\S* "
+                      r"([\w-]+)\((.*)$", text, re.M)
+
+
 def pool_value_faults(text, layers, pages, page, kv_h, d):
     """What in a compiled program's text makes a value of the KV pool's
     shape, or of one layer of it, other than by passing the buffer on
@@ -101,8 +109,7 @@ def pool_value_faults(text, layers, pages, page, kv_h, d):
              dims(pages, page * kv_h, d)}
     passes_on = {"parameter", "bitcast", "get-tuple-element"}
     writes = {"scatter", "dynamic-update-slice"}
-    made = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = \w+\[([\d,]+)\]\S* "
-                      r"([\w-]+)\((.*)$", text, re.M)
+    made = _values_made(text)
     roots, computation = {}, None         # computation -> its root's opcode
     for line in text.splitlines():
         head = re.match(r"%?([\w.-]+) \(.*\{$", line)
@@ -174,7 +181,11 @@ def serving_programs(request, topo, one_chip):
     real_pool = ev2.init_kv_pool
     mp.setattr(ev2, "init_kv_pool",
                lambda ad, cc: jax.eval_shape(lambda: real_pool(ad, cc)))
-    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    # weights in the model's dtype, as the cells hold them (bf16)
+    shapes = jax.eval_shape(
+        lambda key: jax.tree.map(lambda w: w.astype(model.config.dtype),
+                                 model.init_params(key)),
+        jax.random.PRNGKey(0))
     engine = ev2.RaggedInferenceEngineV2(model, shapes, cache,
                                          max_batch_slots=_SLOTS)
     # given the mesh at birth the engine would place real weights on it
@@ -188,6 +199,7 @@ def serving_programs(request, topo, one_chip):
     common = (arg((), jnp.float32),
               placed(jax.eval_shape(lambda: jax.random.PRNGKey(0))))
 
+    @functools.cache                   # two tests read each program's text
     def compiled_text(program):
         kind, _, n = program.rpartition("_")
         if kind == "decode_burst":
@@ -238,12 +250,67 @@ def test_engine_programs_keep_the_pool_in_place_on_v5e(serving_programs,
         assert "paged_decode_attention" in text
 
 
+def expert_value_faults(text, layers, experts, hidden, inner):
+    """What in a compiled program's text makes a value of one layer's
+    expert weights (``[E, H, I]`` or ``[E, I, H]``, with or without a
+    leading 1: a ``dynamic-slice`` of the stack, the ``copy`` that makes it
+    a custom call's operand), or makes the ``[L, E, …]`` stack or its flat
+    ``[L·E, …]`` view other than by passing the buffer on.  Empty for a
+    program whose grouped matmul reads a layer's experts where they lie."""
+    def dims(*shape):
+        return ",".join(str(n) for n in shape)
+
+    tails = [(hidden, inner), (inner, hidden)]
+    layer = {dims(*lead, experts, *tail)
+             for tail in tails for lead in ((), (1,))}
+    whole = {dims(*lead, *tail) for tail in tails
+             for lead in ((layers, experts), (layers * experts,))}
+    passes_on = {"parameter", "bitcast", "get-tuple-element"}
+    return [f"{opcode} {name} makes "
+            f"{'one layer' if shape in layer else 'the stack'} [{shape}]"
+            for name, shape, opcode, _ in _values_made(text)
+            if shape in layer or (shape in whole and opcode not in passes_on)]
+
+
+def _mosaic_calls(text):
+    """How often each of the two grouped kernels stands in the text."""
+    return (len(re.findall(r"moe_grouped_matmul_swiglu[\w.]* = ", text)),
+            len(re.findall(r"moe_grouped_matmul\.[\w.]* = |"
+                           r"moe_grouped_matmul = ", text)))
+
+
+@pytest.mark.parametrize("program", ["decode_burst_1", "decode_burst_8",
+                                     "prefill_8", "prefill_deepest"])
+def test_engine_programs_read_the_experts_where_they_lie_on_v5e(
+        serving_programs, program):
+    """The sparse cell's programs at its widths (64 experts of
+    ``[2048, 1024]``): the three expert stacks reach the two Mosaic calls
+    through bitcasts alone, the layer's offset folded into the tile ->
+    block map.  The parent's programs let the layer scan slice them, and
+    XLA copied each slice for the custom call: nine faults in each of the
+    four programs, a ``dynamic-slice`` and two more values of a layer's
+    shape for each of the three leaves (on the chip
+    ``dynamic-slice_bitcast_fusion`` x 3, 60% of the cell's device time,
+    PERF.md PR 30).  A dense model's programs hold no expert kernel."""
+    engine, compiled_text = serving_programs
+    c = engine.config
+    text = compiled_text(program)
+    if not hasattr(c, "num_experts"):
+        assert _mosaic_calls(text) == (0, 0)       # a dense FFN: no expert
+        return
+    assert expert_value_faults(text, _POOL_LAYERS, c.num_experts,
+                               c.hidden_size, c.intermediate_size) == []
+    assert _mosaic_calls(text) == (1, 1)
+
+
 @pytest.mark.parametrize("rows", [32, 256], ids=["decode_step", "prefill_call"])
 def test_grouped_expert_matmul_compiles_for_v5e_inside_a_layer_scan(
         one_chip, rows, monkeypatch):
     """The dropless expert layer at OLMoE-1B-7B's widths (64 experts of
     [2048, 1024], 8 a token), scanned over the stacked layers as the
-    serving programs scan them: both Mosaic calls are there, once each."""
+    serving programs scan them (the router and the layer's index are the
+    scan's ``xs``; the expert stacks stay whole): both Mosaic calls are
+    there, once each, and nothing copies a layer's experts."""
     from deepspeed_tpu.moe import DroplessMoE
     from deepspeed_tpu.ops.pallas import moe_grouped_matmul as gm
 
@@ -257,17 +324,17 @@ def test_grouped_expert_matmul_compiles_for_v5e_inside_a_layer_scan(
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     def fn(x, wg, w_gate, w_up, w_down):
-        def one(x, lp):
-            wg_l, experts = lp
-            y, _, _ = layer_fn(wg_l, experts, x[None])
+        experts = {"w_gate": w_gate, "w_up": w_up, "w_down": w_down}
+
+        def one(x, xs):
+            wg_l, l = xs
+            y, _, _ = layer_fn(wg_l, experts, x[None], layer=l)
             return x + y[0], None
 
-        return jax.lax.scan(one, x, (wg, {"w_gate": w_gate, "w_up": w_up,
-                                          "w_down": w_down}))[0]
+        return jax.lax.scan(one, x, (wg, jnp.arange(L, dtype=jnp.int32)))[0]
 
     text = jax.jit(fn).lower(
         arg((rows, H)), arg((L, H, E)), arg((L, E, H, I)), arg((L, E, H, I)),
         arg((L, E, I, H))).compile().as_text()
-    assert len(re.findall(r"moe_grouped_matmul_swiglu[\w.]* = ", text)) == 1
-    assert len(re.findall(r"moe_grouped_matmul\.[\w.]* = |"
-                          r"moe_grouped_matmul = ", text)) == 1
+    assert _mosaic_calls(text) == (1, 1)
+    assert expert_value_faults(text, L, E, H, I) == []
