@@ -23,13 +23,54 @@ them over whole.  One family does: :class:`OlmoeV2Adapter` (the expert
 stacks of the grouped matmul, 805 MB a layer at OLMoE-1B-7B's widths).
 The registry picks a family's hooks; no hook tests a model's type or a
 flag, and the engine knows nothing of what rides whole.
+
+**Kinds of layer.**  An adapter states the KINDS of attention layer its
+model has (:class:`AttentionKind`: KV heads, K and V row widths, window,
+sink, rotary base; each kind has a KV pool of its own shape) and the
+PATTERN its layers follow (:class:`LayerPattern`: leading layers, then
+whole periods of one repeated sequence of kinds).  The engine runs the
+leading layers one by one and scans over the periods, a period's layers
+unrolled inside the step.  Most families have one kind and a period of one
+layer, which is what the base class states from ``kv_heads`` /
+``head_dim`` / ``window``: the dense and OLMoE adapters are that case of
+the same interface, not a second path.  :class:`MimoV2Adapter` is the
+family that mixes full and windowed layers.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+import dataclasses
+from typing import Any, List, Optional, Tuple
 
 import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """One kind of attention layer, as the cache, the masks and the paged
+    kernel see it."""
+    name: str
+    layers: int                     # how many of the model's layers
+    kv_heads: int
+    k_dim: int                      # a K (and Q) row
+    v_dim: int                      # a V row
+    window: Optional[int] = None    # keys ``i − j < window``; None: all
+    sink: bool = False              # a learned logit a head beside the keys
+    theta: Optional[float] = None   # rotary base; None: no rotary
+    #: pages that fell out of the window are recycled: the kind's pool is
+    #: rings of ``KVCacheConfig.ring_blocks`` pages, one a sequence, and not
+    #: pages of token capacity.  False keeps every key (and the prefix
+    #: cache: PERF.md §7)
+    ring: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerPattern:
+    """The model's layers by attention kind: ``leading``, run one by one,
+    then ``periods`` repeats of ``period``, scanned."""
+    leading: Tuple[str, ...]
+    period: Tuple[str, ...]
+    periods: int
 
 
 def make_adapter(model: Any) -> "ModelAdapterV2":
@@ -80,29 +121,59 @@ class ModelAdapterV2:
     def window(self) -> Optional[int]:
         return getattr(self.config, "sliding_window", None)
 
+    @property
+    def kinds(self) -> Tuple[AttentionKind, ...]:
+        """One kind: every layer alike, its window (if any) masked and
+        walked but not recycled."""
+        return (AttentionKind("kv", self.num_layers, self.kv_heads,
+                              self.head_dim, self.head_dim, self.window,
+                              theta=getattr(self.config, "rope_theta", None)),)
+
+    @property
+    def pattern(self) -> LayerPattern:
+        return LayerPattern((), (self.kinds[0].name,), self.num_layers)
+
     # -- jit-side hooks -----------------------------------------------------
 
     def layers(self, params: Any) -> Any:
-        """Stacked-layer pytree with leading ``L`` dim: the ``xs`` of the
-        engine's ``lax.scan``, sliced a layer a step (``lp`` below)."""
+        """Stacked pytree with a leading ``periods`` dim: the ``xs`` of the
+        engine's ``lax.scan``, sliced a period a step (``pp`` below; where
+        a period is one layer, that layer's ``lp``)."""
         return params["layers"]
+
+    def leading_layers(self, params: Any) -> List[Any]:
+        """``lp`` of each of the pattern's leading layers, in order."""
+        return []
+
+    def period_layers(self, pp: Any, p: jnp.ndarray) -> List[Any]:
+        """The ``lp`` of each layer of period ``p`` (traced), in the
+        pattern's order, out of the period's slice ``pp``."""
+        del p
+        return [pp]
+
+    def sink(self, lp: Any) -> Optional[jnp.ndarray]:
+        """The layer's sink logits ``[h]``, where its kind has a sink."""
+        return None
 
     def embed(self, params: Any, tokens: jnp.ndarray,
               positions: jnp.ndarray) -> jnp.ndarray:
         raise NotImplementedError
 
-    def qkv(self, lp: Any, x: jnp.ndarray, positions: jnp.ndarray
+    def qkv(self, lp: Any, x: jnp.ndarray, positions: jnp.ndarray,
+            kind: AttentionKind
             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """``x [N, H]`` → (q ``[N, h, d]``, k, v ``[N, kv_h, d]``) with any
-        rotary encoding already applied."""
+        """``x [N, H]`` → (q ``[N, h, k_dim]``, k ``[N, kv_h, k_dim]``, v
+        ``[N, kv_h, v_dim]``) of a layer of ``kind``, with any rotary
+        encoding already applied."""
         raise NotImplementedError
 
     def post_attn(self, lp: Any, x: jnp.ndarray, attn: jnp.ndarray,
                   params: Any, l: jnp.ndarray) -> jnp.ndarray:
         """Output projection + residual + FFN block: ``x [N, H]``,
-        ``attn [N, h, d]`` → ``[N, H]``.  ``params`` is the whole tree and
-        ``l`` this layer's index (traced), for what ``layers()`` left out
-        of ``lp``."""
+        ``attn [N, h, v_dim]`` → ``[N, H]``.  ``params`` is the whole tree
+        and ``l`` this layer's index among the scanned layers (traced;
+        None in a leading layer), for what ``layers()`` left out of
+        ``lp``."""
         raise NotImplementedError
 
     def finalize(self, params: Any, x: jnp.ndarray) -> jnp.ndarray:
@@ -125,9 +196,10 @@ class LlamaV2Adapter(ModelAdapterV2):
         del positions  # rotary — positions enter at qkv time
         return jnp.take(params["embed"].astype(self.dtype), tokens, axis=0)
 
-    def qkv(self, lp, x, positions):
+    def qkv(self, lp, x, positions, kind):
         from ...models.llama import _rms_norm, _rope, apply_qk_norm
 
+        del kind  # one kind: the config says it all
         c = self.config
         dt = self.dtype
         h = _rms_norm(x, lp["attn_norm"].astype(dt), c.rms_norm_eps)
@@ -203,10 +275,10 @@ class OPTV2Adapter(ModelAdapterV2):
         return (jnp.take(params["embed"].astype(dt), tokens, axis=0)
                 + jnp.take(params["pos_embed"].astype(dt), pos_idx, axis=0))
 
-    def qkv(self, lp, x, positions):
+    def qkv(self, lp, x, positions, kind):
         from ...models.bert import _layer_norm
 
-        del positions  # learned positions were added at embed time
+        del positions, kind  # learned positions were added at embed time
         c = self.config
         dt = self.dtype
         h = _layer_norm(x, lp["attn_ln_w"].astype(dt),
@@ -251,8 +323,86 @@ class OPTV2Adapter(ModelAdapterV2):
                           ).astype(jnp.float32)
 
 
+class MimoV2Adapter(ModelAdapterV2):
+    """MiMo-V2 (``models/mimo_v2.py``): full-attention and window layers in
+    one stack, each kind with its own KV heads and rotary base, K rows of
+    192 and V rows of 128, a sink in the window layers' softmax; a leading
+    dense layer, then periods of sparse layers whose expert stacks stay
+    whole (as :class:`OlmoeV2Adapter`'s do) and hold this chip's share of
+    the experts.  The window kind recycles its pages (``ring``)."""
+
+    def __init__(self, model: Any):
+        super().__init__(model)
+        self.plan = model.plan
+
+    @property
+    def num_layers(self) -> int:
+        return self.config.num_layers
+
+    @property
+    def head_dim(self) -> int:
+        return self.config.head_dim
+
+    @property
+    def kinds(self) -> Tuple[AttentionKind, ...]:
+        from ...models.mimo_v2 import FULL, WINDOW
+
+        c, m = self.config, self.model
+        kinds = (
+            AttentionKind(FULL, self.plan.count(FULL), m.kv_heads(FULL),
+                          c.head_dim, c.v_head_dim, theta=m.theta(FULL)),
+            AttentionKind(WINDOW, self.plan.count(WINDOW),
+                          m.kv_heads(WINDOW), c.head_dim, c.v_head_dim,
+                          window=c.sliding_window, sink=True,
+                          theta=m.theta(WINDOW), ring=True))
+        return tuple(k for k in kinds if k.layers)
+
+    @property
+    def pattern(self) -> LayerPattern:
+        plan = self.plan
+        return LayerPattern(tuple(a for a, _ in plan.leading),
+                            tuple(a for a, _ in plan.period), plan.periods)
+
+    def layers(self, params):
+        return self.model.stacks_by_period(params)
+
+    def leading_layers(self, params):
+        return params["leading"]
+
+    def period_layers(self, pp, p):
+        return self.model.period_layers(pp, p)
+
+    def sink(self, lp):
+        return lp["attn"].get("sink")
+
+    def embed(self, params, tokens, positions):
+        del positions  # rotary: positions enter at qkv time
+        return jnp.take(params["embed"].astype(self.dtype), tokens, axis=0)
+
+    def qkv(self, lp, x, positions, kind):
+        return self.model.qkv(lp, x, positions, kind.name)
+
+    def post_attn(self, lp, x, attn, params, l):
+        del l  # a sparse layer's index among the expert stacks rides lp
+        return self.model.post_attn(lp, x, attn, params["layers"])
+
+    def finalize(self, params, x):
+        from ...models.llama import _rms_norm
+
+        return _rms_norm(x, params["final_norm"].astype(self.dtype),
+                         self.config.rms_norm_eps)
+
+    def logits(self, params, x):
+        # the product's float32 sum as it is: rounded to bfloat16 first, a
+        # logit near 4 moves by up to 0.008 and near-ties flip for nothing
+        head = self.model._head(params).astype(self.dtype)
+        return jnp.einsum("nH,HV->nV", x, head,
+                          preferred_element_type=jnp.float32)
+
+
 _REGISTRY = {
     "LlamaModel": LlamaV2Adapter,
+    "MimoV2Model": MimoV2Adapter,
     "MixtralModel": LlamaV2Adapter,
     "OlmoeModel": OlmoeV2Adapter,
     "OPTModel": OPTV2Adapter,

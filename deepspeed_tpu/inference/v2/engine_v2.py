@@ -24,12 +24,33 @@ Architecture deltas (norms, positions, FFN, head) live in
 same engine (reference keeps per-arch model implementations under
 ``inference/v2/model_implementations`` [K]).
 
-Both programs donate the pool and carry it WHOLE through their layer
-scan, addressed by ``(layer, page)``: a step's rows (decode) or pages
-(prefill) are scattered into it in place, and attention reads pages
-``l·N + page`` of its flat view.  No program forms a layer's ``pool[l]``
-(see ``_layer_step`` for why), so KV updates are in-place in HBM and no
-call moves more of the cache than it reads or writes.
+Both programs donate the pools (one of K and V for each kind of attention
+layer the adapter states: ``adapters.AttentionKind``) and carry them WHOLE
+through their layer scan, addressed by ``(layer, page)``: a step's rows
+(decode) or pages (prefill) are scattered into a pool in place, and
+attention reads pages ``l·N + page`` of its flat view.  No program forms a
+layer's ``pool[l]`` (see ``_layer_step`` for why), so KV updates are
+in-place in HBM and no call moves more of the cache than it reads or
+writes.
+
+The scan runs over the PERIODS of the adapter's layer pattern, a period's
+layers unrolled in the step, after the pattern's leading layers; a model
+whose layers are all alike has one kind, no leading layer and a period of
+one.  A kind that recycles its pages (``ring``) has a pool of rings, one a
+live sequence (``KVCacheConfig.ring_blocks``; the scheduler hands them
+out): logical page ``j`` of a sequence is page ``j % ring_blocks`` of its
+ring, so the paged kernel's window walk and the prefill's gather find the
+window's keys where the block table of a growing sequence would have put
+them, and the pages behind the window are overwritten.
+
+A round that has both a prefill call and a decode step dispatches BOTH
+before it waits for either, and ``step_ahead`` (the serving front-end's
+entry) returns with the decode call still running: the next call fetches
+and commits it before it plans.  The device goes from one program to the
+next, and runs while the front-end delivers and admits.  The programs take
+their small arguments as NumPy arrays (one transfer inside the call, not
+an upload each) and their sampling keys from a chain split 256 links at a
+time (``_next_key``).
 
 Prefill cost is O(pages allocated so far), not O(max_seq_len): each
 chunk call gathers/masks only ``kb`` pages per row, where ``kb`` is the
@@ -41,6 +62,7 @@ per chunk" cost note is gone).  Buckets are static shapes, so at most
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -53,7 +75,7 @@ from ...ops.pallas.paged_attention import (paged_decode_attention,
                                            paged_decode_impl)
 from ...telemetry.perf import get_compile_tracker, tracked_jit
 from ...utils.logging import log_dist
-from .adapters import ModelAdapterV2, make_adapter
+from .adapters import AttentionKind, ModelAdapterV2, make_adapter
 from .kv_cache import KVCacheConfig, init_kv_pool
 from .scheduler import RaggedScheduler, Request
 
@@ -64,6 +86,15 @@ class _null_ctx:
 
     def __exit__(self, *exc):
         return None
+
+
+@jax.jit
+def _split_chain(key: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """256 links of ``key, sub = split(key)``: (the last key, the subs)."""
+    def link(key, _):
+        key, sub = jax.random.split(key)
+        return key, sub
+    return jax.lax.scan(link, key, None, length=256)
 
 
 def _sample(logits: jnp.ndarray, temperature: jnp.ndarray,
@@ -101,21 +132,30 @@ class RaggedInferenceEngineV2:
         self.mesh = mesh
         self.last_attn_path = None  # set at trace time by attend_fn
         self._tp = int(mesh.shape.get("tensor", 1)) if mesh is not None else 1
-        if self._tp > 1 and self.adapter.kv_heads % self._tp:
-            raise ValueError(
-                f"tensor axis {self._tp} must divide kv heads "
-                f"{self.adapter.kv_heads} for TP serving")
+        self.kinds: Dict[str, AttentionKind] = {
+            k.name: k for k in self.adapter.kinds}
+        for kind in self.kinds.values():
+            if self._tp > 1 and kind.kv_heads % self._tp:
+                raise ValueError(
+                    f"tensor axis {self._tp} must divide kv heads "
+                    f"{kind.kv_heads} for TP serving")
         if prefill_chunk % self.cache_config.block_size:
             raise ValueError("prefill_chunk must be a multiple of block_size")
-        #: Mistral-style window, threaded into both compiled programs'
-        #: masks (pages before the window still occupy pool slots — a
-        #: window-aware page-release policy is a later optimization)
-        self.window = self.adapter.window
         if self.cache_config.max_seq_len % prefill_chunk:
             # keeps every chunk's page-table slice in range: dynamic_slice
             # clamps out-of-bounds starts, which would silently retarget a
             # chunk's KV writes onto the sequence's EARLIER pages
             raise ValueError("max_seq_len must be a multiple of prefill_chunk")
+        rings = [k for k in self.kinds.values() if k.ring]
+        if rings:
+            # a ring holds the window's pages and those a prefill chunk
+            # writes before it attends; a decode step needs one page more
+            # than the window's, which a chunk's pages cover
+            bs = self.cache_config.block_size
+            self.cache_config = dataclasses.replace(
+                self.cache_config, num_rings=max_batch_slots,
+                ring_blocks=max(-(-k.window // bs) for k in rings)
+                + max(prefill_chunk // bs, 1))
         #: the serving plane swaps in its prefix-sharing scheduler here —
         #: same planner surface, refcounted page reservations
         make_sched = scheduler_factory or RaggedScheduler
@@ -140,7 +180,9 @@ class RaggedInferenceEngineV2:
             self.pool = tracked_jit(
                 lambda: init_kv_pool(ad, cc), "inference_v2/pool_init",
                 tracker=get_compile_tracker(),
-                out_shardings={"k": pool_sharding, "v": pool_sharding})()
+                out_shardings=jax.tree.map(
+                    lambda _: pool_sharding,
+                    jax.eval_shape(lambda: init_kv_pool(ad, cc))))()
         else:
             self.pool = init_kv_pool(self.adapter, self.cache_config)
         from ...telemetry.memory import get_memory_ledger
@@ -166,7 +208,10 @@ class RaggedInferenceEngineV2:
                                     donate_argnums=(1,),
                                     static_argnames=("kb",))
         self._decode_jits: Dict[int, Callable] = {}
-        self._key = jax.random.PRNGKey(0)
+        self._reseed(0)
+        #: the decode call ``step_ahead`` left running: (its requests, its
+        #: steps, its outputs on the device, the EOS id to accept under)
+        self._inflight: Optional[Tuple] = None
         #: MoE serving telemetry (ISSUE 19): when the model routes through
         #: a MOELayer, the decode program additionally returns the gate's
         #: per-expert load so the router/autoscaler can see hot experts.
@@ -196,129 +241,261 @@ class RaggedInferenceEngineV2:
     # compiled programs
     # ------------------------------------------------------------------
 
-    def _layer_step(self, params, lp, l, pool, x_flat, positions_flat,
-                    write_fn, attend_fn):
+    def _layer_step(self, params, lp, l, kind, lk, pools, x_flat,
+                    positions_flat, write_fn, attend_fn):
         """Shared per-layer skeleton: qkv → KV write → attention →
         post-attn block.  ``write_fn``/``attend_fn`` differ between the
         prefill and decode programs.
 
-        ``pool`` is the WHOLE pool ``{"k", "v"}: [L, N, bs, kv_h, d]``, a
-        carry of the layer scan, and ``l`` this layer's index: the write
-        scatters rows or pages at ``(l, page)`` and attention reads pages
-        ``l·N + page`` of the flat view (:meth:`_flat_pool`).  No layer's
+        ``pools`` holds, for each attention kind, the WHOLE pool ``{"k",
+        "v"}: [layers of the kind, N, bs, kv_h, d]``, a carry of the layer
+        scan; this layer is of ``kind`` and the ``lk``-th of it: the write
+        scatters rows or pages at ``(lk, page)`` and attention reads pages
+        ``lk·N + page`` of the flat view (:meth:`_flat_pool`).  No layer's
         ``pool[l]`` is ever formed: a slice of a scanned stack handed to
         a custom call (the paged kernel) is copied out and the updated
         layer copied back, which was 62% of the serving cell's device
         time (PERF.md §6, PRs 25, 27 and 28).  Write, then attend: only
         the written pool lives on, so the write stays in place."""
         ad = self.adapter
-        q, kk, vv = ad.qkv(lp, x_flat, positions_flat)
-        pool = write_fn(pool, l, kk, vv)
-        attn = attend_fn(q, pool, l)
+        q, kk, vv = ad.qkv(lp, x_flat, positions_flat, kind)
+        pools = dict(pools, **{kind.name: write_fn(
+            pools[kind.name], kind, lk, kk, vv)})
+        attn = attend_fn(q, pools[kind.name], kind, lk, ad.sink(lp))
         x_flat = ad.post_attn(lp, x_flat, attn, params, l)
-        return x_flat, pool
+        return x_flat, pools
 
     @staticmethod
     def _flat_pool(pool):
-        """The pool as ``[L·N, bs, kv_h, d]``: layer ``l``'s page ``p`` is
+        """A pool as ``[L·N, bs, kv_h, d]``: layer ``l``'s page ``p`` is
         page ``l·N + p``.  Two adjacent major dims merged: a bitcast."""
         return {name: a.reshape((-1,) + a.shape[2:])
                 for name, a in pool.items()}
 
-    def _scan_layers(self, params, pool, x, positions_flat, write_fn,
+    @staticmethod
+    def _planes(rows, plane):
+        """``rows [..., d]`` as a pool holds them: the planes of ``plane``'s
+        width (``kv_cache.lane_planes``), zeros beyond ``d`` in the last;
+        the rows themselves where one plane holds them."""
+        d, w = rows.shape[-1], plane.shape[-1]
+        if d <= w:
+            return [rows]
+        n = -(-d // w)
+        rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, n * w - d)])
+        return [rows[..., p * w:(p + 1) * w] for p in range(n)]
+
+    @classmethod
+    def _scatter(cls, plane, l, where, rows):
+        """``rows`` written into a pool's ``plane`` array at layer ``l``,
+        a scatter a plane (plane ``p`` of layer ``l`` is block
+        ``p·layers + l``), at ``where`` (index arrays after the layer)."""
+        parts = cls._planes(rows, plane)
+        layers = plane.shape[0] // len(parts)
+        for p, part in enumerate(parts):
+            plane = plane.at[(p * layers + l if p else l,) + where].set(part)
+        return plane
+
+    @property
+    def _page_matrices(self) -> bool:
+        """How the prefill program addresses a pool: as the paged kernel
+        does, page matrices ``[blocks·N, bs·kv_h, w]`` (a bitcast of the
+        carried buffer), unless the kv-head dim is sharded over ``tensor``,
+        which that view would merge with the tokens: then ``(block,
+        page)`` of the pool as it is held.  With no custom call in the
+        prefill program to hold the layout, XLA:TPU re-lays a carried pool
+        of fewer KV heads than a vreg has sublanes out tokens-minor on the
+        way in and back on the way out (2.5 GB each way of every call at
+        the hybrid cell's size; the TP prefill still does, PERF.md §7);
+        addressed as page matrices there is no such choice to make."""
+        return self._tp == 1
+
+    def _ring_pages(self, ring_base, logical):
+        """Pages of a recycled pool: ``ring_base [R]`` (a row's ring's
+        first page; 0: the row holds none) and logical page numbers
+        ``[R, n]`` → ``[R, n]``: page ``j % ring_blocks`` of the ring, and
+        the scratch page 0 for a row without one or a page before the
+        sequence's first."""
+        base = ring_base[:, None]
+        return jnp.where((base > 0) & (logical >= 0),
+                         base + logical % self.cache_config.ring_blocks, 0)
+
+    def _scan_layers(self, params, pools, x, positions_flat, write_fn,
                      attend_fn):
-        """The layer scan of both programs.  Carry: the activations and
-        the pool; ``xs``: what the adapter's ``layers(params)`` holds, a
-        layer's slice a step, and the layer's index; ``ys``: the MoE
-        gate's stats (``moe_stats`` inside ``model._ffn``), which must
-        leave the scan as ``ys`` — names ride the dict keys.  A leaf in
-        ``xs`` is sliced whatever is done with it later, so what an
-        adapter wants whole it leaves out of ``layers()`` and reads from
-        ``params`` at ``l``, both of which its hooks are given (as the
-        pool is read at ``l`` here)."""
+        """The layers of both programs: the pattern's leading layers, then
+        a scan over its periods.  Carry: the activations and the pools;
+        ``xs``: what the adapter's ``layers(params)`` holds, a period's
+        slice a step, and the period's index; ``ys``: the MoE gate's
+        stats (``moe_stats`` inside the FFN), which must leave the scan
+        as ``ys`` — names ride the dict keys (a leading layer's are not
+        collected: they would lack the scan's axis).  A leaf in ``xs`` is
+        sliced whatever is done with it later, so what an adapter wants
+        whole it leaves out of ``layers()`` and reads from ``params``,
+        which its hooks are given (as the pools are read at ``lk``
+        here)."""
         from ...telemetry import numerics
 
         ad = self.adapter
+        pattern = ad.pattern
+        # a layer's index within its kind's pool: the leading layers of
+        # the kind first, then period by period
+        first = {name: pattern.leading.count(name) for name in self.kinds}
+        a_period = {name: pattern.period.count(name) for name in self.kinds}
+        with numerics.suppressed():
+            seen = dict.fromkeys(self.kinds, 0)
+            for name, lp in zip(pattern.leading, ad.leading_layers(params)):
+                x, pools = self._layer_step(
+                    params, lp, None, self.kinds[name], seen[name], pools, x,
+                    positions_flat, write_fn, attend_fn)
+                seen[name] += 1
 
-        def layer(carry, xs):
-            x, pool = carry
-            lp, l = xs
+        def period(carry, xs):
+            x, pools = carry
+            pp, p = xs
             mark = numerics.scan_mark()
-            x, pool = self._layer_step(params, lp, l, pool, x,
-                                       positions_flat, write_fn, attend_fn)
-            return (x, pool), numerics.scan_drain(mark)
+            seen = dict.fromkeys(self.kinds, 0)
+            for j, (name, lp) in enumerate(zip(pattern.period,
+                                               ad.period_layers(pp, p))):
+                x, pools = self._layer_step(
+                    params, lp, p * len(pattern.period) + j,
+                    self.kinds[name],
+                    first[name] + p * a_period[name] + seen[name], pools, x,
+                    positions_flat, write_fn, attend_fn)
+                seen[name] += 1
+            return (x, pools), numerics.scan_drain(mark)
 
-        (x, pool), stats = jax.lax.scan(
-            layer, (x, pool),
-            (ad.layers(params), jnp.arange(ad.num_layers, dtype=jnp.int32)))
-        numerics.scan_collect(stats)  # keep the per-layer axis
-        return x, pool
+        (x, pools), stats = jax.lax.scan(
+            period, (x, pools),
+            (ad.layers(params), jnp.arange(pattern.periods, dtype=jnp.int32)))
+        numerics.scan_collect(stats)  # keep the per-period axis
+        return x, pools
 
     def _prefill_batch_fn(self, params, pool, tokens, tables, start_pos,
-                          last_idx, temperature, key, *, kb):
+                          last_idx, temperature, key, rings=None, *, kb):
         """Up to ``Bp`` sequences' chunks at once: ``tokens [Bp, C]`` at
         positions ``start_pos[r] + [0..C)``; rows beyond the live chunk
         count carry all-zero tables (page 0 = scratch).  ``kb`` (static)
         is the page bucket this program attends over — the first ``kb``
         pages of each row's table cover every key written so far, so the
-        gather/mask is O(allocated), not O(max_seq_len).  Returns
-        (sampled token ids ``[Bp]``, pool, the gate's stats packed or None)."""
+        gather/mask is O(allocated), not O(max_seq_len).  ``rings [Bp]``:
+        each row's ring's first page, where a kind recycles (else None);
+        such a kind gathers the window's pages and the chunk's and no
+        bucket.  Returns (sampled token ids ``[Bp]``, pools, the gate's
+        stats packed or None)."""
         ad = self.adapter
         Bp, C = tokens.shape
         bs = self.cache_config.block_size
         mb = int(kb)  # attend over the bucket, not the full table width
-        n_rep = ad.num_heads // ad.kv_heads
         positions = start_pos[:, None] + jnp.arange(C)[None, :]  # [Bp, C]
         pos_flat = positions.reshape(-1)
         x = ad.embed(params, tokens.reshape(-1), pos_flat)  # [Bp*C, H]
         page_cursor = start_pos // bs  # chunks & starts are page-aligned
 
-        # per-row page slice for this chunk's writes: [Bp, C//bs]
-        pages = jax.vmap(
-            lambda row, cur: jax.lax.dynamic_slice(row, (cur,), (C // bs,))
-        )(tables, page_cursor)
-        pages_flat = pages.reshape(-1)
-
         from ...ops.masks import local_attention_mask
 
-        karange = jnp.arange(mb * bs)
-        mask = jax.vmap(lambda p: local_attention_mask(
-            p, karange, causal=True, window=self.window))(positions)
-        mask = mask[:, None]  # [Bp, 1(head), C, mb*bs]
+        def pages_and_mask(kind):
+            """For a kind: the pages this chunk's rows are written to
+            ``[Bp·C/bs]``, the pages attended over ``[Bp, n]`` and the mask
+            ``[Bp, 1(head), C, n·bs]`` over their keys."""
+            if kind.ring:
+                # the window's pages before the chunk, then the chunk's:
+                # logical numbers, negative before the sequence's start
+                reach = -(-kind.window // bs)
+                logical = (page_cursor[:, None] - reach
+                           + jnp.arange(reach + C // bs)[None, :])
+                kpos = (logical[:, :, None] * bs
+                        + jnp.arange(bs)[None, None, :]).reshape(Bp, -1)
+                mask = jax.vmap(
+                    lambda p, k: local_attention_mask(
+                        p, k, causal=True, window=kind.window) & (k >= 0)[None]
+                )(positions, kpos)
+                return (self._ring_pages(rings, logical[:, reach:]
+                                         ).reshape(-1),
+                        self._ring_pages(rings, logical), mask[:, None])
+            # per-row page slice for this chunk's writes: [Bp, C//bs]
+            pages = jax.vmap(
+                lambda row, cur: jax.lax.dynamic_slice(
+                    row, (cur,), (C // bs,)))(tables, page_cursor)
+            karange = jnp.arange(mb * bs)
+            mask = jax.vmap(lambda p: local_attention_mask(
+                p, karange, causal=True, window=kind.window))(positions)
+            return pages.reshape(-1), tables[:, :mb], mask[:, None]
 
-        n_pages = self.cache_config.num_blocks
-        page_shape = (Bp * (C // bs), bs, ad.kv_heads, ad.head_dim)
+        plans = {name: pages_and_mask(kind)
+                 for name, kind in self.kinds.items()}
+        written = {name: plan[0] for name, plan in plans.items()}
+        attended = {name: plan[1] for name, plan in plans.items()}
+        masks = {name: plan[2] for name, plan in plans.items()}
 
-        def write_fn(pool, l, kk, vv):
+        def as_pages(plane):
+            """A pool's ``plane`` array ``[blocks, N, bs, kv_h, w]`` as page
+            matrices ``[blocks·N, bs·kv_h, w]``: the paged kernel's own
+            view, a bitcast, which every pool is scattered into and
+            gathered from (:attr:`_page_matrices`)."""
+            blocks, pages, _, _, w = plane.shape
+            return plane.reshape(blocks * pages, -1, w)
+
+        def write_fn(pool, kind, l, kk, vv):
             # whole pages, scattered at (l, page) into the carried pool
-            return {"k": pool["k"].at[l, pages_flat].set(
-                        kk.reshape(page_shape)),
-                    "v": pool["v"].at[l, pages_flat].set(
-                        vv.reshape(page_shape))}
+            matrices = self._page_matrices
 
-        def attend_fn(q, pool, l):
-            # gather only the bucket's pages (every key written so far
-            # lives in the first kb pages of each row's table) and attend
-            # chunk-queries over them — O(allocated), not O(max_seq_len).
-            # One gather out of the carried buffer's flat view, never a
-            # layer sliced out first
-            flat = self._flat_pool(pool)
-            idx = tables[:, :mb] + l * n_pages
-            kf = flat["k"][idx].reshape(Bp, mb * bs, ad.kv_heads,
-                                        ad.head_dim)
-            vf = flat["v"][idx].reshape(Bp, mb * bs, ad.kv_heads,
-                                        ad.head_dim)
+            def written_into(plane, rows):
+                rows = rows.reshape((Bp * (C // bs), bs) + rows.shape[1:])
+                view = as_pages(plane) if matrices else plane
+                for p, part in enumerate(self._planes(rows, plane)):
+                    block = p * kind.layers + l if p else l
+                    if matrices:
+                        view = view.at[written[kind.name]
+                                       + block * plane.shape[1]].set(
+                            part.reshape((-1,) + view.shape[1:]))
+                    else:
+                        view = view.at[block, written[kind.name]].set(part)
+                return view.reshape(plane.shape)
+
+            return {"k": written_into(pool["k"], kk),
+                    "v": written_into(pool["v"], vv)}
+
+        def attend_fn(q, pool, kind, l, sink):
+            # gather only the attended pages (a bucket: every key written
+            # so far lives in the first kb pages of each row's table) and
+            # attend chunk-queries over them — O(allocated), not
+            # O(max_seq_len).  One gather out of the carried buffer's flat
+            # view, never a layer sliced out first
+            keys = attended[kind.name].shape[1] * bs
+
+            def gathered(name, d):
+                plane = pool[name]
+                view = as_pages(plane) if self._page_matrices \
+                    else plane.reshape((-1,) + plane.shape[2:])
+                parts = [view[attended[kind.name]
+                              + (p * kind.layers + l if p else l)
+                              * plane.shape[1]]
+                         for p in range(plane.shape[0] // kind.layers)]
+                rows = parts[0] if len(parts) == 1 else jnp.concatenate(
+                    parts, axis=-1)[..., :d]
+                return rows.reshape(Bp, keys, kind.kv_heads, d)
+
+            kf = gathered("k", kind.k_dim)
+            vf = gathered("v", kind.v_dim)
+            n_rep = ad.num_heads // kind.kv_heads
             if n_rep > 1:
                 kf = jnp.repeat(kf, n_rep, axis=2)
                 vf = jnp.repeat(vf, n_rep, axis=2)
-            qb = q.reshape(Bp, C, ad.num_heads, ad.head_dim)
-            scale = 1.0 / np.sqrt(ad.head_dim)
+            qb = q.reshape(Bp, C, ad.num_heads, kind.k_dim)
+            scale = 1.0 / np.sqrt(kind.k_dim)
             s = jnp.einsum("bqhd,bkhd->bhqk", qb, kf
                            ).astype(jnp.float32) * scale
-            s = jnp.where(mask, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(ad.dtype)
+            s = jnp.where(masks[kind.name], s, -1e30)
+            if sink is None:
+                p = jax.nn.softmax(s, axis=-1).astype(ad.dtype)
+            else:
+                # the sink: one more column, which carries no value
+                beside = jnp.broadcast_to(
+                    sink.astype(jnp.float32)[None, :, None, None],
+                    s.shape[:3] + (1,))
+                p = jax.nn.softmax(jnp.concatenate([s, beside], axis=-1),
+                                   axis=-1)[..., :-1].astype(ad.dtype)
             attn = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
-            return attn.reshape(Bp * C, ad.num_heads, ad.head_dim)
+            return attn.reshape(Bp * C, ad.num_heads, kind.v_dim)
 
         x, pool = self._scan_layers(params, pool, x, pos_flat, write_fn,
                                     attend_fn)
@@ -330,44 +507,67 @@ class RaggedInferenceEngineV2:
                 self._pack_moe_stats("prefill"))
 
     def _decode_burst_fn(self, params, pool, tokens, kv_lens, tables,
-                         max_pos, temperature, key, *, n_steps: int):
+                         max_pos, temperature, key, rings=None, *,
+                         n_steps: int):
         """``n_steps`` decode iterations entirely on device: each step
         writes KV at ``kv_lens`` through ``tables``, attends via the paged
         kernel, samples the next token in-graph and feeds it back.  Write
         positions clamp at ``max_pos`` (a slot that hit EOS/budget inside
         the burst only scribbles its own reserved pages; the host discards
-        its surplus tokens).  Returns (token ids ``[n_steps, B]``, pool,
+        its surplus tokens).  ``rings [B]``: each row's ring's first page,
+        where a kind recycles (else None): such a kind's block table is
+        the ring, repeated.  Returns (token ids ``[n_steps, B]``, pools,
         the gate's stats packed or None)."""
         from ...telemetry import numerics
 
         ad = self.adapter
         B = tokens.shape[0]
         bs = self.cache_config.block_size
-        n_pages = self.cache_config.num_blocks
+        tables_of = {
+            kind.name: self._ring_pages(
+                rings, jnp.broadcast_to(jnp.arange(tables.shape[1])[None, :],
+                                        tables.shape))
+            if kind.ring else tables for kind in self.kinds.values()}
 
         def one_step(carry, key):
             tokens, kv_lens, pool = carry
             step_mark = numerics.scan_mark()
             wp = jnp.minimum(kv_lens, max_pos)  # [B] write positions
-            page_ids = tables[jnp.arange(B), wp // bs]
             offsets = wp % bs
+            page_ids = {name: table[jnp.arange(B), wp // bs]
+                        for name, table in tables_of.items()}
             x = ad.embed(params, tokens, wp)
 
-            def write_fn(pool, l, kk, vv):
+            def write_fn(pool, kind, l, kk, vv):
                 # one scatter of [B, kv_h, d] rows at (l, page, offset)
-                return {"k": pool["k"].at[l, page_ids, offsets].set(kk),
-                        "v": pool["v"].at[l, page_ids, offsets].set(vv)}
+                where = (page_ids[kind.name], offsets)
+                return {"k": self._scatter(pool["k"], l, where, kk),
+                        "v": self._scatter(pool["v"], l, where, vv)}
 
-            def attend_fn(q, pool, l):
+            def attend_fn(q, pool, kind, l, sink):
                 # the kernel fetches pages from HBM by page id: it gets
                 # the whole pool's flat view, and the layer's offset is
                 # folded into the tables it prefetches anyway
                 flat = self._flat_pool(pool)
-                layer_tables = tables + l * n_pages
-                # what paged_decode_attention will run for these head
-                # counts on this platform, by its own test
-                impl = paged_decode_impl(ad.num_heads // self._tp,
-                                         ad.kv_heads // self._tp)
+                pages = pool["k"].shape[1]
+                k_planes = pool["k"].shape[0] // kind.layers
+                if pool["v"].shape[0] != kind.layers:
+                    raise NotImplementedError(
+                        f"V rows of {kind.v_dim}: wider than one plane")
+                layer_tables = tables_of[kind.name] + l * pages
+                # what paged_decode_attention will run for these shapes
+                # on this platform, by its own test
+                impl = paged_decode_impl(
+                    ad.num_heads // self._tp, kind.kv_heads // self._tp,
+                    None, flat["k"].shape[-1], flat["v"].shape[-1])
+                if impl == "reference" and jax.default_backend() == "tpu":
+                    from ...telemetry import get_telemetry
+
+                    get_telemetry().inc_counter(
+                        "inference/attn/reference_fallbacks",
+                        help="layers traced on a TPU whose paged decode "
+                             "attention runs the jax.numpy reference: "
+                             "the kernel refused their shapes")
                 if self._tp > 1:
                     # the Pallas kernel runs PER TP SHARD via an explicit
                     # shard_map over the kv-head axis (heads independent,
@@ -375,14 +575,20 @@ class RaggedInferenceEngineV2:
                     from ...ops.pallas.paged_attention import (
                         paged_decode_attention_tp)
 
+                    if sink is not None:
+                        raise NotImplementedError(
+                            "a sink under tensor-parallel serving")
                     self.last_attn_path = f"{impl}_tp_shard_map"
                     return paged_decode_attention_tp(
                         q, flat["k"], flat["v"], layer_tables, wp + 1,
-                        mesh=self.mesh, window=self.window)
+                        mesh=self.mesh, window=kind.window)
                 self.last_attn_path = impl
+                # plane p of a layer's K lies a whole plane (every layer's
+                # pages) further on than plane p - 1
                 return paged_decode_attention(
                     q, flat["k"], flat["v"], layer_tables, wp + 1,
-                    window=self.window)
+                    window=kind.window, sink=sink, k_planes=k_planes,
+                    plane_stride=kind.layers * pages)
 
             x, pool = self._scan_layers(params, pool, x, wp, write_fn,
                                         attend_fn)
@@ -422,8 +628,9 @@ class RaggedInferenceEngineV2:
 
     def _pack_moe_stats(self, program: str) -> Optional[jnp.ndarray]:
         """Inside a program, after its layer scan: the active collector's
-        entries (each with the per-layer axis the scan gave it: ``[L]`` or
-        ``[L, E]``) as ONE float32 array ``[L, columns]``, so that the host
+        entries (each with the axis the scan gave it: ``[periods]`` or
+        ``[periods, E]``, one entry for each sparse layer of a period) as
+        ONE float32 array ``[sparse layers, columns]``, so that the host
         fetches the router's stats in one transfer beside the tokens
         (fetched entry by entry they cost the serving round 7.5 ms of
         host, PERF.md PR 27).  Which columns hold what is a fact of the
@@ -434,10 +641,19 @@ class RaggedInferenceEngineV2:
         named = coll.harvest() if coll is not None else None
         if not named:
             return None
-        layers = self.adapter.num_layers
-        blocks = [(key.partition(":")[2],
-                   named[key].astype(jnp.float32).reshape(layers, -1))
-                  for key in sorted(named)]
+        # an entry a sparse layer of the period, in program order, each
+        # with the periods' axis in front: [periods, …] → rows (period,
+        # layer of the period), so a row is a sparse layer in model order
+        by_name: Dict[str, List[Any]] = {}
+        for key in sorted(named):
+            by_name.setdefault(key.partition(":")[2], []).append(
+                named[key].astype(jnp.float32))
+        blocks = []
+        for name, entries in sorted(by_name.items()):
+            periods = entries[0].shape[0]
+            block = entries[0] if len(entries) == 1 else jnp.stack(
+                [e.reshape(periods, -1) for e in entries], axis=1)
+            blocks.append((name, block.reshape(periods * len(entries), -1)))
         self._moe_columns[program] = [(name, int(b.shape[1]))
                                       for name, b in blocks]
         return jnp.concatenate([b for _, b in blocks], axis=1)
@@ -459,17 +675,28 @@ class RaggedInferenceEngineV2:
             at += width
         if tel.enabled and "moe/experts_active" in cols \
                 and "moe/assignments" in cols:
-            # rows x k is the same in every layer; non-empty groups are not
+            # rows x k is the same in every layer; non-empty groups are
+            # not, nor is what lands on a share of the experts
+            computed = float(cols["moe/assignments"].mean()) * steps
             tel.inc_counter(
-                "inference/moe/assignments",
-                v=float(cols["moe/assignments"].mean()) * steps,
-                help="token-to-expert assignments computed: rows x k, a "
-                     "step of a call")
+                "inference/moe/assignments", v=computed,
+                help="token-to-expert assignments computed HERE, a layer "
+                     "(the mean over layers) a step of a call: rows x k "
+                     "where every expert is held, the held experts' part "
+                     "of it under expert parallelism")
+            routed = cols.get("moe/assignments_routed")
+            tel.inc_counter(
+                "inference/moe/assignments_routed",
+                v=computed if routed is None
+                else float(routed.mean()) * steps,
+                help="token-to-expert assignments the router made: rows "
+                     "x k, a step of a call, wherever the experts live")
             tel.inc_counter(
                 "inference/moe/experts_active",
                 v=float(cols["moe/experts_active"].sum()) * steps,
-                help="experts with at least one row (whose weights the "
-                     "grouped matmul reads), summed over layers and steps")
+                help="held experts with at least one row (whose weights "
+                     "the grouped matmul reads), summed over layers and "
+                     "steps")
         load = cols.get("moe/load")
         if program != "decode" or load is None:
             return
@@ -512,9 +739,21 @@ class RaggedInferenceEngineV2:
             return 0.0
         return float(self.last_moe_stats.get("imbalance", 0.0))
 
-    def _next_key(self) -> jax.Array:
-        self._key, sub = jax.random.split(self._key)
-        return sub
+    def _next_key(self) -> np.ndarray:
+        """The next call's sampling key: ``key, sub = split(key)`` as ever,
+        256 links of the chain in one program and one fetch (an eager
+        split a call is two dispatches a round on the host's critical
+        path)."""
+        if not self._subkeys:
+            self._refill_keys()
+        return self._subkeys.pop()
+
+    def _refill_keys(self) -> None:
+        self._key, subs = _split_chain(self._key)
+        self._subkeys = list(np.asarray(subs)[::-1]) + self._subkeys
+
+    def _reseed(self, seed: int) -> None:
+        self._key, self._subkeys = jax.random.PRNGKey(seed), []
 
     def _prefill_bucket(self, chunks) -> int:
         """Static page-bucket for this prefill call: smallest power-of-two
@@ -532,30 +771,131 @@ class RaggedInferenceEngineV2:
     def step(self, temperature: float = 0.0,
              eos_token_id: Optional[int] = None,
              rng: Optional[np.random.Generator] = None) -> int:
-        """One scheduler step: a batched prefill call and/or a decode
-        burst.  While prefill work exists the burst length is 1 so
-        SplitFuse keeps interleaving chunks with decodes; once all prompts
-        are in, decodes run ``decode_burst`` steps per dispatch.  Returns
-        the number of tokens processed."""
+        """One scheduler step, complete when it returns: a batched prefill
+        call and/or a decode burst.  While prefill work exists the burst
+        length is 1 so SplitFuse keeps interleaving chunks with decodes;
+        once all prompts are in, decodes run ``decode_burst`` steps per
+        dispatch.  Returns the number of tokens processed."""
         del rng  # sampling is in-graph now; kept for API compat
+        return self.step_ahead(temperature, eos_token_id) + self.settle()
+
+    def step_ahead(self, temperature: float = 0.0,
+                   eos_token_id: Optional[int] = None) -> int:
+        """:meth:`step` for a caller that comes back: the step's decode
+        call is left running on the device, and the next ``step_ahead``
+        (or :meth:`settle`) fetches and commits it first.  So whatever the
+        caller does between two calls (delivering tokens, admitting) costs
+        the device nothing.  Returns the tokens committed in THIS call: the
+        previous call's decode and this one's prefill.
+
+        In a round with both, the prefill call and the decode step are
+        dispatched before either is waited for: the decode rows were
+        planned before the prefill and read nothing of its result, so the
+        host packs and dispatches them while the device runs the prefill
+        (that work lies inside the ``inference/prefill`` span), and the
+        device goes from one program to the next."""
         from ...telemetry import get_telemetry
 
         tel = get_telemetry()
         with tel.span("inference/step") as sp:
+            n_tokens = self._settle(tel)
             with tel.span("inference/plan"):
                 chunks, decode = self.scheduler.plan_step()
             sp.set(chunks=len(chunks), decoding=len(decode))
-            temp = jnp.float32(temperature)
-            n_tokens = 0
+            if tel.enabled:
+                self._publish_pages_in_use(tel)
+            temp = np.float32(temperature)
+            if len(self._subkeys) < 2:
+                # a round's two keys, fetched while the device is idle
+                self._refill_keys()
             if chunks:
-                n_tokens += self._step_prefill(tel, chunks, temp,
-                                               eos_token_id)
-            if decode:
-                n_tokens += self._step_decode(tel, chunks, decode, temp,
+                call = self._pack_prefill(tel, chunks, temp)
+                with tel.span("inference/prefill",
+                              args={"chunks": len(chunks)}):
+                    with tel.span("inference/prefill/dispatch"), \
+                            self._collecting_moe():
+                        sampled, self.pool, moe_aux = self._prefill(
+                            *call, kb=self._prefill_bucket(chunks))
+                    if decode:
+                        self._dispatch_decode(tel, chunks, decode, temp,
                                               eos_token_id)
+                    with tel.span("inference/prefill/fetch"):
+                        sampled, moe_aux = jax.device_get((sampled, moe_aux))
+                n_tokens += self._commit_prefill(tel, chunks, sampled,
+                                                 moe_aux, eos_token_id)
+            elif decode:
+                self._dispatch_decode(tel, chunks, decode, temp,
+                                      eos_token_id)
         return n_tokens
 
-    def _step_prefill(self, tel: Any, chunks, temp, eos_token_id) -> int:
+    def settle(self) -> int:
+        """Fetch and commit the decode call :meth:`step_ahead` left
+        running, if any; returns the tokens it yielded.  A request that
+        stopped running meanwhile (cancelled, preempted) is passed over:
+        it decodes that position again if it resumes."""
+        from ...telemetry import get_telemetry
+
+        return self._settle(get_telemetry())
+
+    def _settle(self, tel: Any) -> int:
+        if self._inflight is None:
+            return 0
+        decode, burst, toks, moe_aux, eos_token_id = self._inflight
+        self._inflight = None
+        with tel.span("inference/decode_burst",
+                      args={"burst": burst, "batch": len(decode)}):
+            with tel.span("inference/decode_burst/fetch"):
+                toks, moe_aux = jax.device_get((toks, moe_aux))  # [burst, B]
+        with tel.span("inference/commit"):
+            if moe_aux is not None:
+                self._ingest_moe_stats(moe_aux, tel, "decode", steps=burst)
+            accepted = self.scheduler.decode_burst_done(decode, toks,
+                                                        eos_token_id)
+        tel.inc_counter("inference/decode_tokens", v=accepted,
+                        help="decode tokens accepted by the scheduler")
+        return accepted
+
+    def _publish_pages_in_use(self, tel: Any) -> None:
+        sched = self.scheduler
+        tokens = (self.cache_config.num_blocks - 1
+                  - sched.allocator.num_free)
+        for kind in self.kinds.values():
+            tel.set_gauge(
+                f"inference/kv/pages_in_use/{kind.name}",
+                float(sched.ring_pages_in_use() if kind.ring else tokens),
+                help="pages of the kind's pool that live sequences hold "
+                     "(a recycled kind: at most a ring a sequence), each "
+                     "over all the kind's layers")
+
+    def _ring_bases(self, rows: int, requests) -> Optional[np.ndarray]:
+        """``[rows]``: the first page of each request's ring at its row
+        (``(row, request)`` pairs), 0 elsewhere; None where no kind
+        recycles."""
+        if not self.cache_config.ring_blocks:
+            return None
+        base = np.zeros((rows,), np.int32)
+        for row, req in requests:
+            base[row] = self.cache_config.ring_base(req.ring)
+        return base
+
+    def _count_recycled(self, tel: Any, first_page, pages) -> None:
+        """``pages`` logical pages a sequence from ``first_page`` on (arrays
+        over sequences) were begun by a call: those past a ring's length
+        overwrote a page that fell out of the window."""
+        ring = self.cache_config.ring_blocks
+        if not (ring and tel.enabled):
+            return
+        first_page, pages = np.asarray(first_page), np.asarray(pages)
+        tel.inc_counter(
+            "inference/kv/window_pages_recycled",
+            v=float(np.clip(first_page + pages - np.maximum(first_page, ring),
+                            0, None).sum()),
+            help="pages of the window layers' rings overwritten with a "
+                 "later page of the same sequence (logical pages: each is "
+                 "one page in every window layer)")
+
+    def _pack_prefill(self, tel: Any, chunks, temp) -> Tuple:
+        """The prefill program's arguments for ``chunks``."""
         with tel.span("inference/pack", args={"kind": "prefill"}):
             Bp, C = self.prefill_batch, self.chunk
             tokens = np.zeros((Bp, C), np.int32)
@@ -568,20 +908,21 @@ class RaggedInferenceEngineV2:
                 tables[i] = self.scheduler.table_row(ch.request)
                 start[i] = ch.start_pos
                 last[i] = max(ch.n_valid - 1, 0)
-        with tel.span("inference/prefill", args={"chunks": len(chunks)}):
-            with tel.span("inference/prefill/dispatch"), \
-                    self._collecting_moe():
-                sampled, self.pool, moe_aux = self._prefill(
-                    self.params, self.pool, jnp.asarray(tokens),
-                    jnp.asarray(tables), jnp.asarray(start),
-                    jnp.asarray(last), temp, self._next_key(),
-                    kb=self._prefill_bucket(chunks))
-            with tel.span("inference/prefill/fetch"):
-                sampled, moe_aux = jax.device_get((sampled, moe_aux))
+            rings = self._ring_bases(
+                Bp, ((i, ch.request) for i, ch in enumerate(chunks)))
+        return (self.params, self.pool, tokens, tables, start, last, temp,
+                self._next_key(), rings)
+
+    def _commit_prefill(self, tel: Any, chunks, sampled, moe_aux,
+                        eos_token_id) -> int:
         n_tokens = 0
         with tel.span("inference/commit"):
             if moe_aux is not None:
                 self._ingest_moe_stats(moe_aux, tel, "prefill")
+            bs = self.cache_config.block_size
+            self._count_recycled(
+                tel, [ch.start_pos // bs for ch in chunks],
+                [-(-ch.n_valid // bs) for ch in chunks])
             for i, ch in enumerate(chunks):
                 first = int(sampled[i]) if ch.is_last else None
                 self.scheduler.chunk_done(ch, first, eos_token_id)
@@ -590,8 +931,30 @@ class RaggedInferenceEngineV2:
                         help="prompt tokens written through prefill")
         return n_tokens
 
-    def _step_decode(self, tel: Any, chunks, decode, temp,
-                     eos_token_id) -> int:
+    def _count_cache_traffic(self, tel: Any, kv_lens, max_pos, burst) -> None:
+        """What the decode steps of a call read of each kind's cache, and
+        the ring pages they begin, from the lengths the call packed: a
+        step reads a row's keys so far, the one it writes among them, at
+        most the kind's window."""
+        # [burst, rows]: the length a row attends over at each step
+        lengths = np.minimum(kv_lens[None, :] + np.arange(burst)[:, None],
+                             max_pos[None, :]) + 1
+        for kind in self.kinds.values():
+            tel.inc_counter(
+                f"inference/attn/keys_read_{kind.name}",
+                v=float(np.minimum(lengths, kind.window or lengths).sum()),
+                help="keys a layer of the kind attends over, summed over "
+                     "decoding rows and decode steps (a KV head's; times "
+                     "layers, KV heads and row bytes: what the paged "
+                     "kernel must read)")
+        bs = self.cache_config.block_size
+        self._count_recycled(tel, -(-kv_lens // bs),
+                             (lengths[-1] - 1) // bs + 1 - -(-kv_lens // bs))
+
+    def _dispatch_decode(self, tel: Any, chunks, decode, temp,
+                         eos_token_id) -> None:
+        """Pack and dispatch the decode call for ``decode``; its outputs
+        stay on the device until :meth:`_settle`."""
         with tel.span("inference/pack", args={"kind": "decode"}):
             # exactly TWO decode program shapes ever compile (1 and
             # decode_burst): over-running a request's budget inside a
@@ -611,24 +974,17 @@ class RaggedInferenceEngineV2:
                 kv_lens[s] = req.prefilled + len(req.generated) - 1
                 max_pos[s] = len(req.prompt) + req.max_new_tokens - 1
                 tables[s] = self.scheduler.table_row(req)
-        with tel.span("inference/decode_burst",
-                      args={"burst": burst, "batch": len(decode)}):
-            with tel.span("inference/decode_burst/dispatch"):
-                with self._collecting_moe():
-                    toks, self.pool, moe_aux = self._decode(burst)(
-                        self.params, self.pool, jnp.asarray(tokens),
-                        jnp.asarray(kv_lens), jnp.asarray(tables),
-                        jnp.asarray(max_pos), temp, self._next_key())
-            with tel.span("inference/decode_burst/fetch"):
-                toks, moe_aux = jax.device_get((toks, moe_aux))  # [burst, B]
-        with tel.span("inference/commit"):
-            if moe_aux is not None:
-                self._ingest_moe_stats(moe_aux, tel, "decode", steps=burst)
-            accepted = self.scheduler.decode_burst_done(decode, toks,
-                                                        eos_token_id)
-        tel.inc_counter("inference/decode_tokens", v=accepted,
-                        help="decode tokens accepted by the scheduler")
-        return accepted
+            rings = self._ring_bases(B, ((r.slot, r) for r in decode))
+            if tel.enabled:
+                live = [r.slot for r in decode]
+                self._count_cache_traffic(tel, kv_lens[live], max_pos[live],
+                                          burst)
+        with tel.span("inference/decode_burst/dispatch"), \
+                self._collecting_moe():
+            toks, self.pool, moe_aux = self._decode(burst)(
+                self.params, self.pool, tokens, kv_lens, tables, max_pos,
+                temp, self._next_key(), rings)
+        self._inflight = (decode, burst, toks, moe_aux, eos_token_id)
 
     def generate(self, prompts: List[List[int]], max_new_tokens: int = 32,
                  temperature: float = 0.0, seed: int = 0,
@@ -636,7 +992,7 @@ class RaggedInferenceEngineV2:
                  ) -> List[List[int]]:
         """Drive the scheduler to completion over a ragged prompt batch.
         Returns the generated-token lists in prompt order."""
-        self._key = jax.random.PRNGKey(seed)
+        self._reseed(seed)
         reqs = [self.put(p, max_new_tokens) for p in prompts]
         t0 = time.perf_counter()
         total = 0

@@ -7,7 +7,11 @@ is committed in page units as sequences grow instead of a padded
 ``[B, max_len]`` rectangle up front.
 
 TPU-first: the pool is ONE device array per K/V with the layer dim stacked
-(``[L, num_blocks, block_size, kv_h, d]``).  Inside the engine's programs
+(``[L, num_blocks, block_size, kv_h, d]``), for each KIND of attention
+layer the model has (``adapters.AttentionKind``: most models have one; a
+model that mixes full and windowed layers has a pool for each, with their
+own head counts and row widths, and the windowed one's pages are recycled:
+``KVCacheConfig.ring_blocks``).  Inside the engine's programs
 it is a CARRIED BUFFER addressed by ``(layer, page)``: it rides the
 per-layer ``lax.scan`` as a carry beside the activations (the scan's
 ``xs`` are a layer's parameters and its index), a layer's rows or pages
@@ -18,10 +22,10 @@ layer sliced out of a scanned stack and handed to a custom call (the paged
 kernel) is copied out, and the updated layer copied back into a fresh
 stack: 62% of a serving cell's device time before PR 28 (PERF.md §6; PR
 27 met the same copy on the expert stack).  Outside the programs the
-shape is what callers index: ``pool["k"][:, block]`` is one page's planes
-over all layers (``serving/kv_transfer.py``).  Page bookkeeping (free
-list, tables) is plain host Python — it never enters the compiled
-program, which only ever sees int32 table arrays.
+shape is what callers index: ``pool[kind]["k"][:, block]`` is one page's
+planes over all that kind's layers (``serving/kv_transfer.py``).  Page
+bookkeeping (free list, tables) is plain host Python — it never enters the
+compiled program, which only ever sees int32 table arrays.
 """
 
 from __future__ import annotations
@@ -37,25 +41,70 @@ class KVCacheConfig:
     num_blocks: int = 256          # pool pages (page 0 reserved as scratch)
     block_size: int = 16           # tokens per page
     max_seq_len: int = 2048        # per-sequence logical capacity
+    #: Set by the ENGINE for a model that has attention kinds whose pages
+    #: are recycled behind their window (``AttentionKind.ring``); a caller
+    #: leaves them 0.  ``num_blocks`` stays the pages of TOKEN capacity:
+    #: the pool of the kinds that keep every key.  A recycling kind has a
+    #: pool of its own of ``num_rings`` rings of ``ring_blocks`` pages (and
+    #: page 0): a sequence is given one ring at admission, its logical page
+    #: ``j`` is page ``j % ring_blocks`` of it, and what falls out of the
+    #: window is overwritten there.
+    ring_blocks: int = 0
+    num_rings: int = 0
 
     @property
     def max_blocks_per_seq(self) -> int:
         return -(-self.max_seq_len // self.block_size)
 
+    @property
+    def ring_pool_blocks(self) -> int:
+        """Pages of a recycling kind's pool: page 0, then the rings."""
+        return 1 + self.num_rings * self.ring_blocks
 
-def init_kv_pool(model_or_adapter: Any, cache_config: KVCacheConfig
-                 ) -> Dict[str, jnp.ndarray]:
-    """Zeroed pool sized from the model's (layers, kv-heads, head-dim).
-    Accepts either a ``ModelAdapterV2`` (preferred — normalizes families
-    without ``num_kv_heads``, e.g. OPT) or a raw model config."""
-    c = model_or_adapter
-    if hasattr(c, "kv_heads"):  # adapter protocol
-        shape = (c.num_layers, cache_config.num_blocks,
-                 cache_config.block_size, c.kv_heads, c.head_dim)
-        return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
-    shape = (c.num_layers, cache_config.num_blocks, cache_config.block_size,
-             c.num_kv_heads, c.hd)
-    return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
+    def ring_base(self, ring: int) -> int:
+        """First page of ring ``ring``; 0 (the scratch page, which is no
+        ring's) for a row that holds none."""
+        return 1 + ring * self.ring_blocks if ring >= 0 else 0
+
+
+def lane_planes(d: int) -> tuple:
+    """``(planes, width)``: how a cached row of ``d`` numbers lies in the
+    pool.  A row of at most 128 is one plane as wide as itself.  A wider
+    one (192) is cut into PLANES of 128 lanes, the last padded with zeros
+    (192 → 2 x 128), each plane a stretch of all the layers' blocks in the
+    pool's leading dim: the compiled paged kernel fetches whole 128-lane
+    rows only, and a ``[…, kv_h, 256]`` array of few KV heads has a tiled
+    layout on the chip whose ``[pages, block·kv_h, 256]`` view (the
+    kernel's) is a copy of the pool and not a bitcast
+    (``ops/pallas/paged_attention.py``).  A row under 128 is left as it is
+    (padding 64 → 128 would double such a pool, and it runs the reference
+    on the chip today: PERF.md §7)."""
+    return (1, d) if d <= 128 else (-(-d // 128), 128)
+
+
+def init_kv_pool(adapter: Any, cache_config: KVCacheConfig
+                 ) -> Dict[str, Dict[str, jnp.ndarray]]:
+    """Zeroed pools, one of K and V for each of the adapter's attention
+    kinds: ``{kind: {"k": [layers·planes, pages, block_size, kv_heads,
+    width], "v": […]}}`` with ``(planes, width) = lane_planes(k_dim)`` (and
+    of ``v_dim``): plane ``p`` of layer ``l`` is block ``p·layers + l``, so
+    that a layer's page ``n`` is page ``l·pages + n`` of every plane's
+    stretch and one block table serves K's planes and V alike.
+    ``pages`` is ``num_blocks`` for a kind that keeps every key and
+    ``ring_pool_blocks`` for one that recycles."""
+    pools = {}
+    for kind in adapter.kinds:
+        pages = (cache_config.ring_pool_blocks if kind.ring
+                 else cache_config.num_blocks)
+
+        def plane(d):
+            planes, width = lane_planes(d)
+            return jnp.zeros((kind.layers * planes, pages,
+                              cache_config.block_size, kind.kv_heads, width),
+                             adapter.dtype)
+
+        pools[kind.name] = {"k": plane(kind.k_dim), "v": plane(kind.v_dim)}
+    return pools
 
 
 class BlockAllocator:

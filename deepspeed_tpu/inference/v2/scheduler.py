@@ -40,11 +40,18 @@ class Request:
     blocks: List[int] = dataclasses.field(default_factory=list)
     prefilled: int = 0          # prompt tokens already written to the pool
     slot: int = -1              # decode batch slot while RUNNING
+    #: its ring in the recycled pools (``KVCacheConfig.ring_blocks``), held
+    #: from admission to release, through preemption too; -1: none
+    ring: int = -1
     #: prefill-lattice priority (lower = sooner) — the serving plane maps
     #: latency classes here so an interactive prompt's chunks are not
     #: stuck behind a batch of background prefills; plain engine use
     #: leaves everything at 0 (pure FIFO)
     priority: int = 0
+    #: (``blocks`` as they were, their padded table row):
+    #: ``RaggedScheduler.table_row``'s
+    table: Optional[tuple] = dataclasses.field(default=None, repr=False,
+                                               compare=False)
 
     @property
     def length(self) -> int:
@@ -90,6 +97,11 @@ class RaggedScheduler:
         self.waiting: Deque[Request] = deque()
         self.prefilling: Deque[Request] = deque()
         self._uid = 0
+        #: rings of the recycled pools not held by a sequence (empty where
+        #: the cache has none)
+        self._free_rings: List[int] = list(
+            range(cache_config.num_rings - 1, -1, -1)
+            if cache_config.ring_blocks else ())
 
     def _make_allocator(self, num_blocks: int) -> BlockAllocator:
         """Subclass hook: the serving scheduler swaps in its refcounted
@@ -165,6 +177,26 @@ class RaggedScheduler:
         prefix pages survive until their last holder lets go."""
         self.allocator.free(req.blocks)
 
+    def _claim(self, req: Request) -> bool:
+        """A request's whole reservation: its pages (``_reserve``) and,
+        where the cache recycles window pages, a ring to recycle them in.
+        ``False`` defers admission and takes nothing."""
+        rings = bool(self.cache.ring_blocks)
+        if (rings and not self._free_rings) or not self._reserve(req):
+            return False
+        if rings:
+            req.ring = self._free_rings.pop()
+        return True
+
+    def _give_back(self, req: Request) -> None:
+        """Undo :meth:`_claim`: pages through ``_release``, the ring to
+        the free rings."""
+        self._release(req)
+        req.blocks = []
+        if req.ring >= 0:
+            self._free_rings.append(req.ring)
+            req.ring = -1
+
     def _admit(self) -> None:
         """Move waiting → prefilling while a slot + enough pages exist.
         Pages for the FULL request (prompt + generation budget) are reserved
@@ -175,7 +207,7 @@ class RaggedScheduler:
             slot = self._free_slot()
             if slot < 0:
                 return
-            if not self._reserve(req):
+            if not self._claim(req):
                 return
             self.waiting.popleft()
             req.state = RequestState.PREFILL
@@ -255,14 +287,16 @@ class RaggedScheduler:
         surplus tokens a done slot generated inside the burst are
         discarded.  Returns the number of accepted tokens."""
         accepted = 0
+        columns = np.asarray(tokens).T.tolist()     # [B][n_steps] ints
         for req in requests:
-            col = tokens[:, req.slot]
-            for tok in col:
-                if req.state is not RequestState.RUNNING:
-                    break
-                req.generated.append(int(tok))
-                accepted += 1
-                self._maybe_finish(req, int(tok), eos_token_id)
+            if req.state is not RequestState.RUNNING:
+                continue
+            col = columns[req.slot][:max(req.remaining_budget, 1)]
+            if eos_token_id is not None and eos_token_id in col:
+                col = col[:col.index(eos_token_id) + 1]
+            req.generated.extend(col)
+            accepted += len(col)
+            self._maybe_finish(req, col[-1], eos_token_id)
         return accepted
 
     def _maybe_finish(self, req: Request, tok: int,
@@ -270,8 +304,7 @@ class RaggedScheduler:
         if (len(req.generated) >= req.max_new_tokens
                 or (eos is not None and tok == eos)):
             req.state = RequestState.DONE
-            self._release(req)
-            req.blocks = []
+            self._give_back(req)
             if req.slot >= 0:
                 self.slots[req.slot] = None
                 req.slot = -1
@@ -292,8 +325,7 @@ class RaggedScheduler:
         if req in self.prefilling:
             self.prefilling.remove(req)
         if req.blocks:
-            self._release(req)
-            req.blocks = []
+            self._give_back(req)
         if req.slot >= 0:
             self.slots[req.slot] = None
             req.slot = -1
@@ -305,6 +337,20 @@ class RaggedScheduler:
             help="requests aborted before completion")
 
     def table_row(self, req: Request) -> np.ndarray:
-        row = np.zeros((self.cache.max_blocks_per_seq,), np.int32)
-        row[:len(req.blocks)] = req.blocks
-        return row
+        """The request's block table, padded to the table's width.  Built
+        once for a reservation: ``blocks`` is assigned whole wherever it
+        changes (admission, release, a resumed request), never edited, so
+        the list's identity names the reservation."""
+        made = req.table
+        if made is None or made[0] is not req.blocks:
+            row = np.zeros((self.cache.max_blocks_per_seq,), np.int32)
+            row[:len(req.blocks)] = req.blocks
+            made = req.table = (req.blocks, row)
+        return made[1]
+
+    def ring_pages_in_use(self) -> int:
+        """Pages of a recycled pool that hold keys some window still
+        reaches: a sequence's pages so far, at most its ring."""
+        bs, ring = self.cache.block_size, self.cache.ring_blocks
+        return sum(min(-(-r.length // bs), ring)
+                   for r in self.slots if r is not None and r.ring >= 0)

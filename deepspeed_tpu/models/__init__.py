@@ -10,12 +10,13 @@ that compose with the ZeRO sharding policy.
 
 from .bert import BertConfig, BertModel
 from .llama import LlamaConfig, LlamaModel
+from .mimo_v2 import MimoV2Config, MimoV2Model
 from .mixtral import MixtralConfig, MixtralModel
 from .olmoe import OlmoeConfig, OlmoeModel
 from .opt import OPTConfig, OPTModel
 from .resnet import ResNetConfig, ResNetModel
 
 __all__ = ["BertConfig", "BertModel", "LlamaConfig", "LlamaModel",
-           "MixtralConfig", "MixtralModel", "OlmoeConfig", "OlmoeModel",
+           "MimoV2Config", "MimoV2Model", "MixtralConfig", "MixtralModel", "OlmoeConfig", "OlmoeModel",
            "OPTConfig", "OPTModel",
            "ResNetConfig", "ResNetModel"]

@@ -58,17 +58,37 @@ class DroplessMoE:
     kernels read layer ``l``'s experts where they lie.  A serving program
     that let its layer scan slice them would copy every layer's experts
     for the custom call (the v2 engine's ``OlmoeV2Adapter`` keeps them out
-    of the scan for that reason); ``wg`` is one layer's either way."""
+    of the scan for that reason); ``wg`` is one layer's either way.
+
+    ``scoring`` and the call's ``choice_bias`` are the router's
+    (``top_k_routing``).  ``held=(first, count)``: this chip's share under
+    expert parallelism.  The router keeps its width ``num_experts`` and its
+    ``k``; the expert leaves hold ``count`` experts, numbers ``first`` ..
+    ``first + count − 1`` of the router's; only the assignments to those
+    are planned and computed, and ``y`` is THEIR part of the layer's
+    result (the shares' parts add up to the whole layer's).  Nothing
+    stands in for the other chips or the exchange with them.  ``meta``
+    then counts ``assignments`` and ``experts_active`` of the share, and
+    ``assignments_routed`` is the router's rows x k."""
 
     def __init__(self, num_experts: int, k: int, renormalize: bool = False,
-                 mesh: Any = None):
+                 mesh: Any = None, scoring: str = "softmax",
+                 held: Optional[Tuple[int, int]] = None):
         self.num_experts = num_experts
         self.k = k
         self.renormalize = renormalize
         self.mesh = mesh
+        self.scoring = scoring
+        if held is not None and not (
+                0 <= held[0] and held[1] >= 1
+                and held[0] + held[1] <= num_experts):
+            raise ValueError(f"held=(first, count)={held} is no share of "
+                             f"{num_experts} experts")
+        self.held = held
 
     def __call__(self, wg: jnp.ndarray, expert_params: Any, x: jnp.ndarray,
-                 layer: Any = None) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
+                 layer: Any = None, choice_bias: Optional[jnp.ndarray] = None
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
         from ..ops.pallas import moe_grouped_matmul as gm
         from .sharded_moe import top_k_routing
 
@@ -79,10 +99,28 @@ class DroplessMoE:
         B, S, H = x.shape
         tokens = x.reshape(B * S, H)
         expert_idx, weights, meta = top_k_routing(
-            wg, tokens, self.k, self.renormalize)
-        plan = gm.plan_groups(
-            expert_idx, self.num_experts,
-            gm.tile_rows_for(B * S * self.k, self.num_experts, x.dtype))
+            wg, tokens, self.k, self.renormalize, self.scoring, choice_bias)
+        if self.held is None:
+            plan = gm.plan_groups(
+                expert_idx, self.num_experts,
+                gm.tile_rows_for(B * S * self.k, self.num_experts, x.dtype))
+        else:
+            # this chip's share: experts first .. first + count - 1 are
+            # groups 0 .. count - 1, the others' assignments get no row
+            # and no weight; the tiles are sized for what can land here
+            first, count = self.held
+            local = expert_idx - first
+            here = (local >= 0) & (local < count)
+            weights = jnp.where(here, weights, 0.0)
+            plan = gm.plan_groups(
+                local, count,
+                gm.tile_rows_for(B * S * min(self.k, count), count, x.dtype),
+                share=True)
+            meta = type(meta)(
+                meta, assignments_routed=meta["assignments"],
+                assignments=jnp.sum(plan.group_sizes).astype(jnp.float32),
+                experts_active=jnp.sum(plan.group_sizes > 0
+                                       ).astype(jnp.float32))
         sharded = self.mesh is not None and self.mesh.size > 1
         rows = gm.gather_rows(tokens, plan)
         act = gm.grouped_swiglu(rows, expert_params["w_gate"],

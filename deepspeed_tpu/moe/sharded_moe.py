@@ -237,24 +237,39 @@ def top_k_gating_indices(logits: jnp.ndarray, k: int, capacity: int,
 
 
 def top_k_routing(wg: jnp.ndarray, x: jnp.ndarray, k: int,
-                  renormalize: bool = False
+                  renormalize: bool = False, scoring: str = "softmax",
+                  choice_bias: Optional[jnp.ndarray] = None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, Any]]:
     """Dropless top-k routing for any ``k``: ``x [T, H]``, ``wg [H, E]`` →
     ``(expert_idx [T, k] int32, weights [T, k] float32, meta)``.
 
-    The router product is accumulated in float32 and the softmax over all
-    ``E`` experts is float32; the ``k`` weights are the softmax values as
-    they are, or divided by their sum where ``renormalize`` (the published
-    ``norm_topk_prob``).  No capacity: every assignment is computed,
-    whatever else the batch holds, so ``drop_rate`` is 0 by construction.
-    ``l_aux`` is the load-balancing loss of the sparse-expert decoders
-    (``E · Σ_e f_e · P_e`` with ``f_e`` the assignments an expert gets per
-    token and ``P_e`` its mean probability)."""
+    The router product is accumulated in float32 and the scores over all
+    ``E`` experts are float32: their ``softmax`` (OLMoE, Mixtral) or, each
+    expert for itself, their ``sigmoid`` (the ``scoring_func`` of the
+    DeepSeek-V3 line of routers).  The ``k`` experts are the largest of
+    ``scores + choice_bias`` (``noaux_tc``: one learned bias an expert that
+    moves the CHOICE only; None: the scores alone); the ``k`` weights are
+    the chosen experts' scores as they are, or divided by their sum where
+    ``renormalize`` (the published ``norm_topk_prob``).  No capacity: every
+    assignment is computed, whatever else the batch holds, so
+    ``drop_rate`` is 0 by construction.  ``l_aux`` is the load-balancing
+    loss of the sparse-expert decoders (``E · Σ_e f_e · P_e`` with ``f_e``
+    the assignments an expert gets per token and ``P_e`` its mean score)."""
     T, E = x.shape[0], wg.shape[1]
     logits = jnp.einsum("th,he->te", x, wg.astype(x.dtype),
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, expert_idx = jax.lax.top_k(probs, k)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring: 'softmax' or 'sigmoid', not {scoring!r}")
+    if choice_bias is None:
+        weights, expert_idx = jax.lax.top_k(probs, k)
+    else:
+        _, expert_idx = jax.lax.top_k(
+            probs + choice_bias.astype(jnp.float32)[None, :], k)
+        weights = jnp.take_along_axis(probs, expert_idx, axis=-1)
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     counts = jnp.sum(_one_hot(expert_idx.reshape(-1), E), axis=0)

@@ -14,7 +14,9 @@ rows, so that every tile belongs to exactly one expert:
 :func:`plan_groups` builds that layout from the router's ``[T, k]`` expert
 choices with a one-hot running count and one stable sort: gathers only, no
 scatter.  ``tiles`` is static, ``T·k // tile_rows + min(E, T·k)``: the most
-any routing can need.  Nothing is dropped, whatever the routing.
+any routing can need.  Nothing is dropped, whatever the routing.  Where
+the ``E`` groups are a share of the router's experts (``share``),
+the assignments to the others get no row and the rest is as before.
 
 The kernels (``name="moe_grouped_matmul…"`` on the device trace) walk the
 tiles with the expert's weight block chosen by a scalar-prefetched
@@ -81,12 +83,26 @@ def tile_rows_for(assignments: int, num_experts: int, dtype) -> int:
 
 
 def plan_groups(expert_idx: jnp.ndarray, num_experts: int,
-                tile_rows: int) -> GroupPlan:
-    """``expert_idx [T, k]`` → the layout.  Assignment ``a = t·k + j``."""
+                tile_rows: int, share: bool = False) -> GroupPlan:
+    """``expert_idx [T, k]`` → the layout.  Assignment ``a = t·k + j``.
+
+    ``share``: the ``num_experts`` groups are a SHARE of the experts
+    the router chose among (this chip's, under expert parallelism), and an
+    index outside ``[0, num_experts)`` is an assignment to an expert that
+    lives elsewhere.  It gets no row: it sorts behind every group, is in
+    no group's size, and its ``dest`` is row 0, which is always computed
+    (at least one tile is in use, of zeros where nothing landed here), so
+    that the caller's zero weight for it meets a finite value.  The static
+    ``tiles`` are sized for what CAN land here: a token's ``k`` choices are
+    distinct experts, so at most ``min(k, num_experts)`` of them."""
     T, k = expert_idx.shape
     M, E, tm = T * k, num_experts, tile_rows
-    tiles = M // tm + min(E, M)
+    can_land = T * min(k, E)
+    tiles = can_land // tm + min(E, can_land)
     flat = expert_idx.reshape(M).astype(jnp.int32)
+    if share:
+        here = (flat >= 0) & (flat < E)
+        flat = jnp.where(here, flat, E)            # behind every group
     onehot = (flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :]
               ).astype(jnp.int32)                                  # [M, E]
     sizes = jnp.sum(onehot, axis=0)
@@ -95,13 +111,21 @@ def plan_groups(expert_idx: jnp.ndarray, num_experts: int,
     tile_end = jnp.cumsum(group_tiles)
     tile_start = tile_end - group_tiles
     num_tiles = tile_end[-1]
-    dest = tile_start[flat] * tm + rank
+    if not share:
+        dest = tile_start[flat] * tm + rank
+    else:
+        num_tiles = jnp.maximum(num_tiles, 1)
+        dest = jnp.where(here, tile_start[jnp.minimum(flat, E - 1)] * tm
+                         + rank, 0)
     tile_ids = jnp.arange(tiles, dtype=jnp.int32)
     # tile i belongs to the first expert whose tiles end after i; the
     # unused tail repeats the last used tile's expert (no new weight block)
     in_use = jnp.minimum(tile_ids, num_tiles - 1)
     tile_group = jnp.sum(in_use[:, None] >= tile_end[None, :], axis=1
                          ).astype(jnp.int32)
+    if share:
+        # nothing landed here: the one tile in use is group 0's, of zeros
+        tile_group = jnp.minimum(tile_group, E - 1)
     # sorted position → assignment; a row's position within its group
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     sorted_start = jnp.cumsum(sizes) - sizes

@@ -40,6 +40,29 @@ and the ``[h, d]`` accumulator are float32 loop carries.  PV is a second
 MXU dot with float32 accumulation whose left operand, the unnormalised
 probabilities, is cast to the cache dtype first — the choice
 :func:`paged_decode_reference` and the engine's prefill path make too.
+
+K and V rows need not be equally wide (a model whose value head is
+narrower than its key head): the V pool's last dim is the accumulator's
+and the output's.  A K row wider than 128 lanes (192) lies in ``k_planes``
+PLANES of 128 lanes, the last padded with zeros
+(``kv_cache.lane_planes``): plane ``p`` of page ``n`` is page ``n +
+p·plane_stride`` of the K pool, fetched by a copy of its own into its
+own stretch of the K buffer, and QKᵀ is the sum over planes of a dot of
+the query's 128 lanes of that plane (the query is padded with zeros to
+match; the scale stays that of the true width).  Why planes and not one
+256-lane row: Mosaic pads an HBM operand's lanes to 128 and refuses the
+page-sized slice a DMA needs of anything else, and the ``[pages,
+block·kv_h, 256]`` view of a ``[…, kv_h = 4, 256]`` pool is, in the chip's
+tiled layout, a COPY of the pool (2.7 GB a call at the serving cell's
+size), where every 128-lane view is a bitcast.  The padding is read from
+HBM like the keys (a quarter of a 192-wide K: what the kernel's roofline
+share loses).
+
+``sink`` (``[h]`` float32, one learned logit a query head): a column of
+the softmax that takes mass and carries no value,
+``p_j = exp(s_j) / (Σ exp(s_j') + exp(sink))``.  In the kernel it is where
+the running max and sum START (``m = sink, l = 1``) in place of
+``(−inf, 0)``; the walk, the mask and the dots are the same.
 """
 
 from __future__ import annotations
@@ -55,16 +78,23 @@ from .select import reference_off_tpu, shape_refused
 
 
 def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
-                           window=None):
-    """Pure-jnp reference.  ``q [B, h, d]``; pools ``[N, bs, kv_h, d]``;
-    ``block_tables [B, max_blocks]``; ``lengths [B]``; ``window`` =
-    sliding-window reach (only the last ``window`` cache entries)."""
-    B = q.shape[0]
-    _, bs, kv_h, d = k_pool.shape
+                           window=None, sink=None, k_planes=1,
+                           plane_stride=0):
+    """Pure-jnp reference.  ``q [B, h, d]``; K pool ``[M, bs, kv_h, w]`` in
+    ``k_planes`` planes (plane ``p`` of page ``n`` at ``n +
+    p·plane_stride``; ``k_planes·w >= d``: what lies beyond ``d`` is lane
+    padding); V pool ``[N, bs, kv_h, dv]``; ``block_tables [B,
+    max_blocks]``; ``lengths [B]``; ``window`` = sliding-window reach (only
+    the last ``window`` cache entries); ``sink [h]`` = a logit a head
+    beside the keys'."""
+    B, _, d = q.shape
+    _, bs, kv_h, _ = k_pool.shape
     max_blocks = block_tables.shape[1]
     # gather each sequence's pages into a padded [B, max_blocks*bs, kv_h, d]
-    k = k_pool[block_tables].reshape(B, max_blocks * bs, kv_h, d)
-    v = v_pool[block_tables].reshape(B, max_blocks * bs, kv_h, d)
+    k = jnp.concatenate([k_pool[block_tables + p * plane_stride]
+                         for p in range(k_planes)], axis=-1)
+    k = k[..., :d].reshape(B, max_blocks * bs, kv_h, d)
+    v = v_pool[block_tables].reshape(B, max_blocks * bs, kv_h, -1)
     n_rep = q.shape[1] // kv_h
     if n_rep > 1:
         k = jnp.repeat(k, n_rep, axis=2)
@@ -76,7 +106,13 @@ def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
     if window is not None:
         mask = mask & (pos >= lengths[:, None, None] - window)
     s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    else:
+        beside = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, None],
+                                  s.shape[:2] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, beside], axis=-1),
+                           axis=-1)[..., :-1].astype(q.dtype)
     return jnp.einsum("bhk,bkhd->bhd", p, v)
 
 
@@ -89,24 +125,30 @@ _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
 def pages_per_step(block_size: int, kv_h: int, h: int, d: int, itemsize: int,
-                   max_blocks: int) -> int:
+                   max_blocks: int, v_dim: int | None = None) -> int:
     """``P``: pages fetched and scored per compute step, from the shapes
-    alone."""
+    alone (``d``: a K row as the pool holds it, all its planes; ``v_dim``:
+    a V row, where it is another width)."""
     rows = block_size * kv_h
-    per_page = 4 * rows * d * itemsize + 5 * h * rows * 4
+    per_page = (2 * rows * (d + (v_dim or d)) * itemsize
+                + 5 * h * rows * 4)
     return max(1, min(_STEP_TOKENS // block_size, max_blocks,
                       _VMEM_BUDGET_BYTES // per_page))
 
 
-def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, k_hbm, v_hbm,
-                  o_ref, k_buf, v_buf, sems, slot_ref, *, block_size: int,
-                  kv_h: int, scale: float, window=None):
+def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
+                  block_size: int, kv_h: int, scale: float, window=None,
+                  sink: bool = False, k_planes: int = 1,
+                  plane_stride: int = 0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    # with a sink, its [h, 1] logits come before the pools
+    sink_ref = rest[0] if sink else None
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest[int(sink):]
     b = pl.program_id(0)
     num_rows = pl.num_programs(0)
-    P = k_buf.shape[1]
+    P = v_buf.shape[1]           # the K buffer holds P pages a plane
     h = q_ref.shape[1]
     C = P * block_size * kv_h
 
@@ -120,10 +162,13 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, k_hbm, v_hbm,
         return k0, nk - k0
 
     def page_copies(page, slot, j):
-        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, j],
-                                      sems.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
-                                      sems.at[1, slot]))
+        return tuple(
+            pltpu.make_async_copy(k_hbm.at[page + p * plane_stride],
+                                  k_buf.at[slot, p * P + j],
+                                  sems.at[0, slot])
+            for p in range(k_planes)) + (
+            pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
+                                  sems.at[1, slot]),)
 
     def step_pages(n_live, i):
         return jnp.clip(n_live - i * P, 0, P)
@@ -177,9 +222,20 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, k_hbm, v_hbm,
             start_step(nxt_row, nk0, nn, jnp.where(last, 0, i + 1), 1 - slot)
 
         wait_step(n_live, i, slot)
-        k = k_buf[slot].reshape(C, k_buf.shape[-1])
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        w = k_buf.shape[-1]
+
+        def plane_scores(p):
+            """q's lanes of plane ``p`` against that plane's pages."""
+            qp = q if k_planes == 1 else q[:, p * w:(p + 1) * w]
+            kp = k_buf[slot] if k_planes == 1 \
+                else k_buf[slot, p * P:(p + 1) * P]
+            return jax.lax.dot_general(
+                qp, kp.reshape(C, w), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        s = plane_scores(0)
+        for p in range(1, k_planes):
+            s = s + plane_scores(p)
         s = s * scale + head_mask_ref[...]
         # column c of this step is position first + c // kv_h
         first = (k0 + i * P) * block_size
@@ -196,62 +252,87 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, k_hbm, v_hbm,
         return (m_new, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
                 acc * alpha + pv)
 
+    if sink:    # the sink's column is where the running max and sum start
+        start = (sink_ref[...], jnp.ones((h, 1), jnp.float32))
+    else:
+        start = (jnp.full((h, 1), -jnp.inf, jnp.float32),
+                 jnp.zeros((h, 1), jnp.float32))
     _, l, acc = jax.lax.fori_loop(
         0, steps, step,
-        (jnp.full((h, 1), -jnp.inf, jnp.float32),
-         jnp.zeros((h, 1), jnp.float32),
-         jnp.zeros((h, q_ref.shape[2]), jnp.float32)))
+        start + (jnp.zeros((h, o_ref.shape[2]), jnp.float32),))
     slot_ref[0] = jax.lax.rem(slot0 + steps, 2)
     # a row of length 0 ran one step with every column masked
     o_ref[0] = jnp.where(length > 0, acc / l, 0.0).astype(o_ref.dtype)
 
 
+def _refusal(h: int, kv_h: int, k_dim: int, v_dim: int,
+             compiled: bool) -> str | None:
+    """Why the kernel cannot take these shapes, or None.  ``k_dim`` and
+    ``v_dim`` are the POOLS' last dims (a plane's width)."""
+    if h % kv_h:
+        return f"kv heads {kv_h} do not divide query heads {h}"
+    if compiled and (k_dim % 128 or v_dim % 128):
+        # Mosaic (jax 0.9.0) pads an HBM operand's lanes to 128 and then
+        # refuses the page-sized slice of it a DMA needs
+        return (f"head size {k_dim}/{v_dim} is not a multiple of 128 "
+                f"lanes")
+    return None
+
+
 def paged_decode_impl(num_heads: int, kv_heads: int,
-                      interpret: bool | None = None) -> str:
-    """Which path :func:`paged_decode_attention` takes for these head
-    counts: ``"pallas"``, ``"pallas_interpret"`` or ``"reference"`` — the
-    serving engine records it (``last_attn_path``) from the same test the
-    entry point decides by.  Head counts are all it is given: a head size
-    the compiled kernel cannot take is refused at the entry point, which
-    says so once (``select.shape_refused``)."""
-    if reference_off_tpu(interpret) or num_heads % kv_heads:
+                      interpret: bool | None = None, k_dim: int = 128,
+                      v_dim: int = 128) -> str:
+    """Which path :func:`paged_decode_attention` takes for these shapes
+    (``k_dim``, ``v_dim``: the pools' last dims): ``"pallas"``,
+    ``"pallas_interpret"`` or ``"reference"`` — the serving engine records
+    it (``last_attn_path``) from the same test the entry point decides by,
+    so a head size the compiled kernel cannot take reads ``"reference"``
+    there too, on a TPU as anywhere."""
+    if reference_off_tpu(interpret) or _refusal(
+            num_heads, kv_heads, k_dim, v_dim, compiled=not interpret):
         return "reference"
     return "pallas_interpret" if interpret else "pallas"
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                           interpret: bool | None = None, window=None):
+                           interpret: bool | None = None, window=None,
+                           sink=None, k_planes: int = 1,
+                           plane_stride: int = 0):
     """One-token queries ``q [B, h, d]`` over a shared paged KV pool
-    ``[N, block_size, kv_h, d]`` addressed by ``block_tables [B, max_blocks]``
-    with true ``lengths [B]``.  ``window`` (sliding-window attention) is
-    handled by the kernel's walk: it starts at the window's first page, so
-    pages before the window are neither fetched nor scored.  A row of
-    length 0 gives zeros.  The pool is passed as it lies in memory: the
-    ``[N, block_size·kv_h, d]`` view the kernel reads merges two adjacent
-    dims and moves nothing."""
+    ``k [M, block_size, kv_h, w]``, ``v [N, block_size, kv_h, dv]``
+    addressed by ``block_tables [B, max_blocks]`` with true ``lengths
+    [B]`` → ``[B, h, dv]``.  A K row of ``d > 128`` lies in ``k_planes``
+    planes of ``w = 128`` lanes, plane ``p`` of page ``n`` at page ``n +
+    p·plane_stride`` of ``k`` (zeros beyond ``d``).  ``window``
+    (sliding-window attention) is handled by the kernel's walk: it starts
+    at the window's first page, so pages before the window are neither
+    fetched nor scored.  ``sink [h]``: a logit a query head in the
+    softmax's denominator.  A row of length 0 gives zeros.  The pool is
+    passed as it lies in memory: the ``[M, block_size·kv_h, w]`` view the
+    kernel reads merges two adjacent dims and moves nothing."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, h, d = q.shape
-    N, block_size, kv_h, _ = k_pool.shape
+    M, block_size, kv_h, w = k_pool.shape
+    N, dv = v_pool.shape[0], v_pool.shape[-1]
     max_blocks = block_tables.shape[1]
-    impl = paged_decode_impl(h, kv_h, interpret)
-    refusal = None
-    if h % kv_h:
-        refusal = f"kv heads {kv_h} do not divide query heads {h}"
-    elif impl == "pallas" and d % 128:
-        # Mosaic (jax 0.9.0) pads an HBM operand's lanes to 128 and then
-        # refuses the page-sized slice of it a DMA needs
-        refusal = f"head size {d} is not a multiple of 128 lanes"
-    if refusal or impl == "reference":
+    impl = paged_decode_impl(h, kv_h, interpret, w, dv)
+    if impl == "reference":
+        refusal = _refusal(h, kv_h, w, dv, compiled=not interpret)
         if refusal:
             shape_refused("paged_decode_attention",
-                          (tuple(q.shape), tuple(k_pool.shape)), refusal)
+                          (tuple(q.shape), tuple(k_pool.shape),
+                           tuple(v_pool.shape)), refusal)
         return paged_decode_reference(q, k_pool, v_pool, block_tables,
-                                      lengths, window)
+                                      lengths, window, sink, k_planes,
+                                      plane_stride)
 
-    P = pages_per_step(block_size, kv_h, h, d, k_pool.dtype.itemsize,
-                       max_blocks)
+    dk = k_planes * w
+    if dk > d:      # the last plane's lane padding: zeros times zeros
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, dk - d)))
+    P = pages_per_step(block_size, kv_h, h, dk, k_pool.dtype.itemsize,
+                       max_blocks, dv)
     rows = block_size * kv_h
     # query head r reads the columns of kv head r // n_rep
     head_mask = np.where(
@@ -260,33 +341,40 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     ).astype(np.float32)
     kernel = functools.partial(_paged_kernel, block_size=block_size,
                                kv_h=kv_h, scale=1.0 / np.sqrt(d),
-                               window=window)
-    q_spec = pl.BlockSpec((1, h, d), lambda b, lens, table: (b, 0, 0))
+                               window=window, sink=sink is not None,
+                               k_planes=k_planes, plane_stride=plane_stride)
+    row = lambda b, lens, table: (b, 0, 0)
+    whole = lambda b, lens, table: (0, 0)
+    sink_spec, sink_arg = [], []
+    if sink is not None:
+        sink_spec = [pl.BlockSpec((h, 1), whole)]
+        sink_arg = [sink.astype(jnp.float32).reshape(h, 1)]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
             in_specs=[
-                q_spec,
-                pl.BlockSpec((h, P * rows), lambda b, lens, table: (0, 0)),
+                pl.BlockSpec((1, h, dk), row),
+                pl.BlockSpec((h, P * rows), whole),
+                *sink_spec,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=q_spec,
+            out_specs=pl.BlockSpec((1, h, dv), row),
             scratch_shapes=[
-                pltpu.VMEM((2, P, rows, d), k_pool.dtype),
-                pltpu.VMEM((2, P, rows, d), v_pool.dtype),
+                pltpu.VMEM((2, k_planes * P, rows, w), k_pool.dtype),
+                pltpu.VMEM((2, P, rows, dv), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, h, dv), q.dtype),
         interpret=bool(interpret),
         name="paged_decode_attention",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, jnp.asarray(head_mask), k_pool.reshape(N, rows, d),
-      v_pool.reshape(N, rows, d))
+      q, jnp.asarray(head_mask), *sink_arg, k_pool.reshape(M, rows, w),
+      v_pool.reshape(N, rows, dv))
 
 
 def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, lengths,

@@ -322,6 +322,47 @@ def check_paged(s: KernelShapes, interpret: bool) -> List[Check]:
     return out
 
 
+def check_paged_hybrid(s: KernelShapes, interpret: bool) -> List[Check]:
+    """Paged decode where K and V rows differ in width and many query
+    heads share a KV head, at the widths of the model that has them (64
+    query heads, K rows of 192 in two 128-lane planes, V rows of 128): a
+    full layer's 4 KV heads, and a window layer's 8 with its window of 128
+    and a sink."""
+    pa = _mod("paged_attention")
+    rng = np.random.RandomState(11)
+    heads, k_dim, v_dim, window = 64, 192, 128, 128
+    max_blocks = max(s.cache_len // s.page, 2 * window // s.page)
+    rows = s.slots
+    pages = rows * max_blocks + 1
+    q = _normal(rng, (rows, heads, k_dim), s.dtype)
+    sink = _normal(rng, (heads,), jnp.float32)
+    tables = jnp.asarray(rng.permutation(np.arange(1, pages)).reshape(
+        rows, max_blocks).astype(np.int32))
+    top = max_blocks * s.page
+    lengths = jnp.asarray(np.resize(np.clip(
+        [window - 1, window, window + 1, top, 3, 2 * window + 5, top // 2,
+         s.page], 1, top), rows).astype(np.int32))
+    out = []
+    for kv_heads, reach, logits in ((4, None, None), (8, window, sink)):
+        k = _normal(rng, (pages, s.page, kv_heads, 256), s.dtype)
+        k = k.at[..., k_dim:].set(0)          # the last plane's padding
+        k_pool = jnp.concatenate([k[..., :128], k[..., 128:]], axis=0)
+        v_pool = _normal(rng, (pages, s.page, kv_heads, v_dim), s.dtype)
+        got = pa.paged_decode_attention(
+            q, k_pool, v_pool, tables, lengths, interpret=interpret,
+            window=reach, sink=logits, k_planes=2, plane_stride=pages)
+        with jax.default_matmul_precision("highest"):
+            want = pa.paged_decode_reference(
+                q.astype(jnp.float32), k_pool.astype(jnp.float32),
+                v_pool.astype(jnp.float32), tables, lengths, reach, logits,
+                2, pages)
+        out.append(Check(
+            f"paged_decode(k192_v128, kv_heads={kv_heads}, window={reach}, "
+            f"sink={logits is not None})", float(_rel_err(got, want)),
+            DECODE_TOL))
+    return out
+
+
 def check_fused_adam(s: KernelShapes, interpret: bool) -> List[Check]:
     """One-pass Adam and the grad-norm read on one large fp32 leaf.  The
     kernel and the reference run the same fp32 formula; they may differ by
@@ -427,6 +468,52 @@ def check_moe_grouped(s: KernelShapes, interpret: bool) -> List[Check]:
                   ATTENTION_TOL)]
 
 
+def check_moe_share(s: KernelShapes, interpret: bool) -> List[Check]:
+    """The grouped expert matmul over a SHARE of the router's experts (16
+    held of 256, 8 a token: a decode step's 256 rows put ~8 on each held
+    expert and 15 assignments of 16 land elsewhere), so that most of the
+    plan's static tiles are empty; experts of width 2048 over the model's
+    hidden size, or its FFN where that is narrower.  Against each held
+    expert run over all tokens in float32."""
+    gm = _mod("moe_grouped_matmul")
+    rng = np.random.RandomState(12)
+    T, routed, held, K, H = 256, 256, 16, 8, s.hidden
+    inner = min(s.ffn, 2048)
+    score = jax.nn.sigmoid(_normal(rng, (T, routed), jnp.float32))
+    gates, idx = jax.lax.top_k(score, K)
+    first = 32
+    local = idx - first
+    here = (local >= 0) & (local < held)
+    gates = jnp.where(here, gates, 0.0)
+    x = _normal(rng, (T, H), s.dtype)
+    layer = 1
+    stack_gate, stack_up = (_normal(rng, (2, held, H, inner), s.dtype,
+                                    H ** -0.5) for _ in range(2))
+    stack_down = _normal(rng, (2, held, inner, H), s.dtype, inner ** -0.5)
+    plan = gm.plan_groups(local, held, gm.tile_rows_for(
+        T * min(K, held), held, s.dtype), share=True)
+    act = gm.grouped_swiglu(gm.gather_rows(x, plan), stack_gate, stack_up,
+                            layer, plan, interpret=interpret)
+    got = gm.combine_rows(gm.grouped_matmul(act, stack_down, layer, plan,
+                                            interpret=interpret), plan, gates)
+    xe = x.astype(jnp.float32)
+
+    def one_expert(y, e):
+        out = (jax.nn.silu(xe @ stack_gate[layer, e].astype(jnp.float32))
+               * (xe @ stack_up[layer, e].astype(jnp.float32))
+               ) @ stack_down[layer, e].astype(jnp.float32)
+        weight = jnp.sum(jnp.where(local == e, gates, 0.0), axis=1)
+        return y + weight[:, None] * out, None
+
+    with jax.default_matmul_precision("highest"):
+        want, _ = jax.lax.scan(one_expert, jnp.zeros((T, H), jnp.float32),
+                               jnp.arange(held))
+    tiles = int(plan.tile_group.shape[0])
+    return [Check(f"moe_grouped_matmul(share 16 of 256, "
+                  f"{int(plan.num_tiles[0])} of {tiles} tiles in use)",
+                  float(_rel_err(got, want)), ATTENTION_TOL)]
+
+
 def check_quantizer(s: KernelShapes, interpret: bool) -> List[Check]:
     qz = _mod("quantizer")
     rng = np.random.RandomState(6)
@@ -472,8 +559,8 @@ def check_block_sparse(s: KernelShapes, interpret: bool) -> List[Check]:
 
 
 CHECKS = (check_flash, check_flash_streamed, check_decode, check_paged,
-          check_fused_adam, check_moe, check_moe_grouped, check_quantizer,
-          check_block_sparse)
+          check_paged_hybrid, check_fused_adam, check_moe, check_moe_grouped,
+          check_moe_share, check_quantizer, check_block_sparse)
 
 
 def run_checks(shapes: KernelShapes, interpret: bool = False

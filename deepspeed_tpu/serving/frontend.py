@@ -157,15 +157,17 @@ class ServingHandle:
         completion flag.  The replica-worker protocol's ``poll`` op
         reads the stream this way (a socket peer cannot park in
         :meth:`stream`)."""
-        toks: List[int] = []
-        while True:
-            try:
-                item = self._queue.get_nowait()
-            except queue.Empty:
-                return toks, False
-            if item is _DONE:
-                return toks, True
-            toks.append(int(item))
+        q = self._queue
+        if not q.queue:     # nothing arrived: no lock taken
+            return [], False
+        with q.mutex:       # the whole buffer in one go, not a lock a token
+            items = list(q.queue)
+            q.queue.clear()
+            q.not_full.notify_all()
+        done = _DONE in items   # the completion mark: nothing follows it
+        if done:
+            items = items[:items.index(_DONE)]
+        return [int(t) for t in items], done
 
     def next_event(self, timeout: Optional[float] = None) -> "tuple":
         """One stream event for push-style consumers (the SSE writer):
@@ -206,6 +208,23 @@ class ServingHandle:
 
     def _push(self, tok: int) -> None:
         self._put_drop_oldest(tok)
+
+    def _push_many(self, toks: List[int]) -> None:
+        """:meth:`_push` for a round's tokens of one stream under one
+        lock (a decode burst hands a stream 8 at once, 256 streams a
+        round): the same bound, the oldest unread dropped."""
+        q = self._queue
+        with q.mutex:
+            held, room = len(q.queue), q.maxsize
+            unread = max(0, min(held + len(toks) - room, held))
+            for _ in range(unread):
+                q.queue.popleft()
+            # a round longer than the whole buffer loses its own first
+            unsent = max(0, len(toks) - room)
+            self.dropped += unread + unsent
+            q.queue.extend(toks[unsent:])
+            q.unfinished_tasks += len(toks) - unsent
+            q.not_empty.notify_all()
 
     def _finish(self, status: str,
                 error: Optional[BaseException] = None) -> None:
@@ -501,7 +520,11 @@ class ServingFrontend:
     def pump(self) -> int:
         """One serving round: drain dead replicas, admit (with
         preemption), step every replica with work, deliver tokens.
-        Returns tokens processed — 0 means idle."""
+        Returns tokens processed — 0 means idle.  The paged engine is
+        stepped through ``step_ahead``: the round's decode call is still
+        on the device when this returns, and what it yields is committed
+        and delivered by the next round (so is the slot of a request it
+        finishes: admission sees it a round later)."""
         from ..telemetry import get_telemetry
 
         tel = get_telemetry()
@@ -535,9 +558,14 @@ class ServingFrontend:
                 n = 0
                 for rep in self.router.healthy():
                     if rep.scheduler.has_work:
-                        n += rep.engine.step(
-                            temperature=self.params.temperature,
-                            eos_token_id=self.params.eos_token_id)
+                        # an engine that can leave its decode call running
+                        # does: delivery, the clients' reads and the next
+                        # round's admissions then cost the device nothing
+                        # (what it yields is delivered next round)
+                        step = getattr(rep.engine, "step_ahead",
+                                       rep.engine.step)
+                        n += step(temperature=self.params.temperature,
+                                  eos_token_id=self.params.eos_token_id)
                     with tel.span("serving/deliver") as sp:
                         sp.set(tokens=self._deliver(rep, tel))
                     with tel.span("serving/ledger"):
@@ -865,22 +893,24 @@ class ServingFrontend:
         pushed = 0
         for h in list(rep.active):
             req = h.request
-            new = req.generated[h.consumed:]
-            for tok in new:
-                h.consumed += 1
-                if h.consumed > h.delivered:
-                    if h.first_token_at is None:
-                        h.first_token_at = self.clock()
-                        self.metrics.record_ttft(h.klass, h.ttft_ms,
-                                                 ref=h.trace_id)
-                        if h.record is not None:
-                            h.record.event("first_token",
-                                           replica=rep.id)
-                    h.delivered += 1
-                    pushed += 1
+            # what the request generated since the last round, less what
+            # a replayed request's stream was already given
+            seen = len(req.generated)
+            new = [int(t) for t in
+                   req.generated[max(h.consumed, h.delivered):seen]]
+            h.consumed = seen
+            if new:
+                if h.first_token_at is None:
+                    h.first_token_at = self.clock()
+                    self.metrics.record_ttft(h.klass, h.ttft_ms,
+                                             ref=h.trace_id)
                     if h.record is not None:
-                        h.record.token()
-                    h._push(int(tok))
+                        h.record.event("first_token", replica=rep.id)
+                h.delivered += len(new)
+                pushed += len(new)
+                if h.record is not None:
+                    h.record.token(len(new))
+                h._push_many(new)
             if req.state.value == "done" and h.status == "running":
                 rep.active.remove(h)
                 h.finished_at = self.clock()

@@ -64,11 +64,23 @@ def page_payload(engine: Any, prompt: List[int], blocks: List[int],
         return {"raw": raw + raw, "sha256": _sha256(raw + raw),
                 "dtype": "int32", "shape": [bs], "synthetic": True}
     block = blocks[page_index]
+    pool = _token_pool(pool)
     k = np.asarray(pool["k"][:, block])
     v = np.asarray(pool["v"][:, block])
     raw = k.tobytes() + v.tobytes()
     return {"raw": raw, "sha256": _sha256(raw), "dtype": str(k.dtype),
             "shape": list(k.shape), "synthetic": False}
+
+
+def _token_pool(pools: Dict[str, Any]) -> Dict[str, Any]:
+    """The one pool of a model with one kind of attention layer.  A model
+    that has several (full and window layers) keeps part of a sequence's
+    cache in a ring that no block table names, and is not transferred."""
+    if len(pools) != 1:
+        raise NotImplementedError(
+            f"KV page transfer of a model with {len(pools)} KV pools "
+            f"({sorted(pools)})")
+    return next(iter(pools.values()))
 
 
 def inject_pages(engine: Any, blocks: List[int],
@@ -100,6 +112,7 @@ def inject_pages(engine: Any, blocks: List[int],
     if not ids:
         return
     idx = jnp.asarray(ids)
+    pool = _token_pool(pool)
     # page planes are [L, bs, kh, hd]; stacked on a new axis 1 they
     # line up with pool[:, idx] -> [L, n, bs, kh, hd]
     pool["k"] = pool["k"].at[:, idx].set(

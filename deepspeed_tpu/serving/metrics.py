@@ -355,12 +355,15 @@ class RequestRecord:
         with self._lock:
             self.admission_attempts += 1
 
-    def token(self) -> None:
+    def token(self, n: int = 1) -> None:
+        """``n`` tokens delivered now (a round hands a stream its burst
+        at once: one stamp for them)."""
         now = time.perf_counter()
         with self._lock:
-            self.tokens += 1
-            if len(self.token_ts) < self._token_cap:
-                self.token_ts.append(now)
+            self.tokens += n
+            room = self._token_cap - len(self.token_ts)
+            if room > 0:
+                self.token_ts.extend([now] * min(n, room))
 
     def finish(self, status: str, ttft_ms: Optional[float] = None,
                error: Optional[BaseException] = None,

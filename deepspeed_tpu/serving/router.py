@@ -59,8 +59,12 @@ class Replica:
         #: ledger attribution; 0 when the engine has no device pool
         pool = getattr(engine, "pool", None)
         if pool is not None:
-            total = int(pool["k"].nbytes) + int(pool["v"].nbytes)
-            self.block_nbytes = total // engine.cache_config.num_blocks
+            # the pools whose pages a block table names (a recycled
+            # kind's rings are no prefix's)
+            blocks = engine.cache_config.num_blocks
+            self.block_nbytes = sum(
+                int(a.nbytes) // blocks for kind in pool.values()
+                for a in kind.values() if a.shape[1] == blocks)
         else:
             self.block_nbytes = 0
 
@@ -212,7 +216,10 @@ class ReplicaRouter:
                     1.0 + self.moe_imbalance_weight * (imb - 1.0))
             return (-affinity, load, r.id)
 
-        return sorted(self.healthy(), key=score)
+        healthy = self.healthy()
+        # one replica: nothing to order (its load is a walk over every
+        # active request, in every round that has a request queued)
+        return healthy if len(healthy) < 2 else sorted(healthy, key=score)
 
     def route(self, prompt: List[int]) -> Optional[Replica]:
         """Pick the replica for a fresh request; ``None`` when no

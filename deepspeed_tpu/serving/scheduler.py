@@ -46,6 +46,18 @@ class ServingScheduler(RaggedScheduler):
         super().__init__(cache_config, max_batch_slots, prefill_chunk,
                          prefill_batch)
         self.allocator: RefcountedBlockAllocator
+        if prefix_sharing and cache_config.ring_blocks:
+            # a shared prefix's pages hold the layers that keep every key;
+            # the window layers' last keys lie in the ring of the sequence
+            # that wrote them and are recycled there, so a prefix hit would
+            # leave the window layers without their window
+            from ..utils.logging import warn_once
+
+            warn_once("serving/prefix_cache/window",
+                      "prefix sharing is off for this model: its window "
+                      "layers recycle their KV pages, and a shared prefix "
+                      "could not give them their last keys back")
+            prefix_sharing = False
         self.prefix = PrefixCache(self.allocator, cache_config.block_size,
                                   enabled=prefix_sharing)
         self.preemptions = 0
@@ -122,6 +134,8 @@ class ServingScheduler(RaggedScheduler):
         page-blocked (it cannot: preempted KV stays resident)."""
         if not ignore_slots and self._free_slot() < 0:
             return False
+        if self.cache.ring_blocks and not self._free_rings:
+            return False
         _, fresh, _, avail = self._shared_plan(prompt, max_new_tokens)
         return fresh + max(reserve_pages, 0) <= avail
 
@@ -146,7 +160,7 @@ class ServingScheduler(RaggedScheduler):
         if req not in self.waiting:
             raise ValueError(f"admit_now: uid {req.uid} is not waiting")
         slot = self._free_slot()
-        if slot < 0 or not self._reserve(req):
+        if slot < 0 or not self._claim(req):
             return False  # stays in waiting; _admit will retry in order
         self.waiting.remove(req)
         req.state = RequestState.PREFILL
@@ -229,8 +243,7 @@ class ServingScheduler(RaggedScheduler):
                 f"can only preempt RUNNING/PREFILL requests, uid "
                 f"{req.uid} is {req.state.value}")
         released = len(req.blocks)
-        self._release(req)
-        req.blocks = []
+        self._give_back(req)
         if req.slot >= 0:
             self.slots[req.slot] = None
             req.slot = -1
@@ -291,6 +304,10 @@ class ServingScheduler(RaggedScheduler):
         parks WAITING in its slot (inert to the planner) until
         :meth:`adopt_commit` seats it RUNNING."""
         self.validate(prompt, max_new_tokens)
+        if self.cache.ring_blocks:
+            raise NotImplementedError(
+                "KV adoption of a model whose window layers recycle their "
+                "pages: a ring's pages are not transferred")
         slot = self._free_slot()
         if slot < 0:
             return None
@@ -330,8 +347,7 @@ class ServingScheduler(RaggedScheduler):
         """Transfer failed: give the reservation back (pages through
         refcounts, slot freed) — the caller re-routes the request."""
         if req.blocks:
-            self._release(req)
-            req.blocks = []
+            self._give_back(req)
         if req.slot >= 0:
             self.slots[req.slot] = None
             req.slot = -1

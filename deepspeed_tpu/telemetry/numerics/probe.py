@@ -164,7 +164,7 @@ def moe_stats(meta: Dict[str, Any]) -> None:
     if c is None or not c.want_moe:
         return
     for key in ("load", "entropy", "drop_rate", "overflow_frac",
-                "assignments", "experts_active"):
+                "assignments", "experts_active", "assignments_routed"):
         if key in meta:
             c.add(MOE_PREFIX + key, meta[key])
 

@@ -309,6 +309,37 @@ def test_v2_burst_eos_truncation(tiny_model):
     assert eng2.scheduler.allocator.num_free == 31
 
 
+@pytest.mark.parametrize("budget, eos, want, state", [
+    (8, None, [1, 2, 3, 4], "running"),      # a whole burst
+    (3, None, [1, 2, 3], "done"),            # the budget ends inside it
+    (8, 2, [1, 2], "done"),                  # EOS inside it, EOS included
+    (1, 9, [1], "done"),                     # both at its first token
+])
+def test_burst_acceptance_takes_a_column_up_to_budget_or_eos(budget, eos,
+                                                             want, state):
+    """``decode_burst_done`` gives each request its slot's column of the
+    ``[n_steps, B]`` tokens up to its budget or the first EOS, and a
+    reservation's table row is built once."""
+    from deepspeed_tpu.inference.v2.scheduler import RaggedScheduler
+
+    sched = RaggedScheduler(KVCacheConfig(num_blocks=32, block_size=4,
+                                          max_seq_len=32), max_batch_slots=2)
+    req = sched.add_request([5, 6, 7], budget)
+    (chunk,), _ = sched.plan_step()
+    sched.chunk_done(chunk, None)
+    row = sched.table_row(req)
+    assert sched.table_row(req) is row and list(row[:len(req.blocks)]) \
+        == req.blocks and not row[len(req.blocks):].any()
+    tokens = np.array([[0, 1], [0, 2], [0, 3], [0, 4]])[:, ::-1] \
+        if req.slot == 0 else np.array([[0, 1], [0, 2], [0, 3], [0, 4]])
+    assert sched.decode_burst_done([req], tokens, eos) == len(want)
+    assert req.generated == want and req.state.value == state
+    if state == "done":       # the pages came back, and a new request's
+        again = sched.add_request([1], 1)       # row is its own
+        sched.plan_step()
+        assert sched.table_row(again) is not row
+
+
 @pytest.mark.slow
 def test_v2_temperature_sampling_in_graph(tiny_model):
     """temperature>0 samples in-graph: output differs across seeds but
@@ -379,7 +410,7 @@ def test_v2_tp_sharded_serving_matches_meshless():
         cache_config=KVCacheConfig(num_blocks=64, block_size=4,
                                    max_seq_len=64),
         max_batch_slots=2, prefill_chunk=8, decode_burst=4, mesh=mesh)
-    assert not eng.pool["k"].sharding.is_fully_replicated
+    assert not eng.pool["kv"]["k"].sharding.is_fully_replicated
     got = eng.generate(prompts, max_new_tokens=5)
     assert got == want
     # decode attention ran per TP shard (shard_map over kv heads), and
@@ -480,6 +511,60 @@ def test_v2_serves_the_dense_forward_tokens(kind, engine_kw, prompt_lens):
     assert eng.scheduler.allocator.num_free == 47
 
 
+@pytest.mark.parametrize("engine_kw", [
+    dict(prefill_chunk=8, prefill_batch=2, decode_burst=4),
+    dict(prefill_chunk=8, prefill_batch=1, decode_burst=1),
+], ids=["burst4", "burst1"])
+def test_v2_step_ahead_leaves_the_decode_call_running(engine_kw):
+    """``step_ahead`` (the front-end's entry) returns with its decode call
+    on the device: its tokens are committed by the next call or by
+    ``settle``, a prefill's first token at once; driven that way the
+    engine serves the tokens ``step`` serves, and counts the same."""
+    model, params = _three_layer_model("gqa_window")
+    rng = np.random.RandomState(33)
+    prompts = [rng.randint(1, 512, size=n).tolist() for n in (5, 14, 22)]
+    eng = build_engine_v2(
+        model, params,
+        cache_config=KVCacheConfig(num_blocks=48, block_size=4,
+                                   max_seq_len=64),
+        max_batch_slots=4, **engine_kw)
+    assert eng.settle() == 0                    # nothing running: no-op
+    reqs = [eng.put(p, 7) for p in prompts]
+    total, lagged = 0, 0
+    while eng.scheduler.has_work:
+        before = sum(len(r.generated) for r in reqs)
+        n = eng.step_ahead()
+        total += n
+        # what this call's decode yields is not on the requests yet
+        lagged += eng._inflight is not None
+        assert sum(len(r.generated) for r in reqs) - before <= n
+    assert eng._inflight is None and eng.settle() == 0
+    assert lagged >= 3
+    for prompt, r in zip(prompts, reqs):
+        assert r.generated == _v1_greedy(model, params, prompt, 7)
+    # prompt tokens through prefill + every token but each request's first
+    assert total == sum(map(len, prompts)) + 3 * 6
+    assert eng.scheduler.allocator.num_free == 47
+
+
+def test_v2_settle_commits_what_step_ahead_left():
+    model, params = _three_layer_model("mha")
+    prompt = np.random.RandomState(2).randint(1, 512, size=6).tolist()
+    eng = build_engine_v2(
+        model, params,
+        cache_config=KVCacheConfig(num_blocks=24, block_size=4,
+                                   max_seq_len=64),
+        max_batch_slots=2, prefill_chunk=8, decode_burst=4)
+    req = eng.put(prompt, 9)
+    eng.step_ahead()                            # prefill: the first token
+    assert len(req.generated) == 1 and eng._inflight is None
+    assert eng.step_ahead() == 0                # a burst of four, running
+    assert len(req.generated) == 1 and eng._inflight is not None
+    assert eng.settle() == 4 and len(req.generated) == 5
+    assert eng.step() == 4                      # ``step`` is both halves
+    assert req.generated == _v1_greedy(model, params, prompt, 9)
+
+
 def _rows_written(before, after):
     """{(layer, page, offset)} of the pool rows a program changed."""
     changed = np.any(before != after, axis=(3, 4))          # [L, N, bs]
@@ -505,15 +590,15 @@ def test_v2_burst_clamped_at_max_pos_writes_only_its_own_rows():
         eng.step()
     assert [len(r.generated) for r in reqs] == [1, 1]
     # what was never written reads as noise: a row that moves shows
-    noise = jax.random.normal(jax.random.PRNGKey(1), eng.pool["k"].shape)
-    keep = np.zeros(eng.pool["k"].shape[1:3], bool)          # [N, bs]
+    noise = jax.random.normal(jax.random.PRNGKey(1), eng.pool["kv"]["k"].shape)
+    keep = np.zeros(eng.pool["kv"]["k"].shape[1:3], bool)          # [N, bs]
     for r in reqs:
         for pos in range(len(r.prompt)):
             keep[r.blocks[pos // bs], pos % bs] = True
     keep = jnp.asarray(keep)[None, :, :, None, None]
-    eng.pool = {name: jnp.where(keep, a, noise)
-                for name, a in eng.pool.items()}
-    before = {name: np.asarray(a) for name, a in eng.pool.items()}
+    eng.pool = {"kv": {name: jnp.where(keep, a, noise)
+                       for name, a in eng.pool["kv"].items()}}
+    before = {name: np.asarray(a) for name, a in eng.pool["kv"].items()}
     expect = {(0, 0)}                                # idle slots: scratch
     for r, steps in zip(reqs, (4, 8)):     # 4 = up to max_pos, then clamped
         first = r.prefilled + len(r.generated) - 1
@@ -522,7 +607,7 @@ def test_v2_burst_clamped_at_max_pos_writes_only_its_own_rows():
     eng.step()                                       # the eight-step burst
     assert [len(r.generated) for r in reqs] == [4, 9]
     for name in ("k", "v"):
-        rows = _rows_written(before[name], np.asarray(eng.pool[name]))
+        rows = _rows_written(before[name], np.asarray(eng.pool["kv"][name]))
         assert rows == {(l, page, off) for l in range(3)
                         for page, off in expect}, name
     while eng.scheduler.has_work:
@@ -564,11 +649,11 @@ def test_v2_kv_pages_exported_and_imported_decode_the_same_token():
     for i, block in enumerate(blocks):
         for name in ("k", "v"):
             np.testing.assert_array_equal(
-                np.asarray(dst.pool[name][:, block]),
-                np.asarray(src.pool[name][:, req.blocks[i]]))
+                np.asarray(dst.pool["kv"][name][:, block]),
+                np.asarray(src.pool["kv"][name][:, req.blocks[i]]))
     untouched = np.ones(24, bool)
     untouched[blocks] = False
-    assert not np.asarray(dst.pool["k"])[:, untouched].any()
+    assert not np.asarray(dst.pool["kv"]["k"])[:, untouched].any()
     src.step()                                  # the source's own next token
     tables = np.zeros((2, dst.cache_config.max_blocks_per_seq), np.int32)
     tables[0, :len(blocks)] = blocks

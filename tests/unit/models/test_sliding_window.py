@@ -95,7 +95,7 @@ def test_v2_windowed_ragged_matches_v1():
         cache_config=KVCacheConfig(num_blocks=64, block_size=4,
                                    max_seq_len=64),
         max_batch_slots=2, prefill_chunk=16)
-    assert eng2.window == cfg.sliding_window
+    assert eng2.kinds["kv"].window == cfg.sliding_window
     got = eng2.generate(prompts, max_new_tokens=6)
     v1 = init_inference(model=model, model_params=params)
     for prompt, g in zip(prompts, got):

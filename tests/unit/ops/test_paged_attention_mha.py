@@ -60,7 +60,7 @@ def test_the_engine_says_which_path_ran():
         max_batch_slots=2, prefill_chunk=16)
     assert engine.last_attn_path is None                 # nothing traced yet
     engine.generate([[1, 2, 3, 4, 5]], max_new_tokens=3)
-    assert engine.pool["k"].shape == (2, 16, 16, 16, 16)
+    assert engine.pool["kv"]["k"].shape == (2, 16, 16, 16, 16)
     # off the TPU the entry point runs its reference, and the engine's
     # record is the entry point's own test
     assert engine.last_attn_path == pa.paged_decode_impl(16, 16) == "reference"

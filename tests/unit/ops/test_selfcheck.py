@@ -70,3 +70,27 @@ def test_streamed_check_refuses_a_resident_shape(shapes):
     resident = dataclasses.replace(shapes, stream_seq=shapes.seq)
     with pytest.raises(ValueError, match="RESIDENT_VMEM_ELEMS"):
         selfcheck.check_flash_streamed(resident, interpret=True)
+
+
+def test_the_hybrid_paged_check_has_teeth(monkeypatch, shapes):
+    """The paged case at K 192 / V 128 runs both kinds of layer, and a
+    kernel that loses the sink fails it."""
+    pa = importlib.import_module("deepspeed_tpu.ops.pallas.paged_attention")
+    monkeypatch.setattr(selfcheck, "CHECKS", (selfcheck.check_paged_hybrid,))
+    names = [c.name for c in selfcheck.run_checks(shapes, interpret=True)]
+    assert len(names) == 2 and "kv_heads=4" in names[0] \
+        and "sink=True" in names[1]
+    real = pa.paged_decode_attention
+    monkeypatch.setattr(
+        pa, "paged_decode_attention",
+        lambda *a, sink=None, **kw: real(*a, sink=None, **kw))
+    with pytest.raises(AssertionError, match="selfcheck FAILED.*sink=True"):
+        selfcheck.run_checks(shapes, interpret=True)
+
+
+def test_the_share_check_leaves_most_tiles_empty(monkeypatch, shapes):
+    monkeypatch.setattr(selfcheck, "CHECKS", (selfcheck.check_moe_share,))
+    (check,) = selfcheck.run_checks(shapes, interpret=True)
+    used, tiles = map(int, __import__("re").search(
+        r"(\d+) of (\d+) tiles", check.name).groups())
+    assert 1 <= used <= tiles // 2

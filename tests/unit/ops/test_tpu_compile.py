@@ -10,6 +10,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -242,7 +243,11 @@ def test_engine_programs_keep_the_pool_in_place_on_v5e(serving_programs,
         return
     assert pool_value_faults(text, _POOL_LAYERS, _POOL_PAGES, _PAGE, kv_h,
                              ad.head_dim) == []
-    whole = f"bf16[{_POOL_LAYERS},{_POOL_PAGES},{_PAGE},{kv_h},"
+    # a decode step scatters rows into the pool as it is held, a prefill
+    # call whole pages into its page matrices, the kernel's own view
+    whole = f"bf16[{_POOL_LAYERS},{_POOL_PAGES},{_PAGE},{kv_h}," \
+        if program.startswith("decode") or engine._tp > 1 else \
+        f"bf16[{_POOL_LAYERS * _POOL_PAGES},{_PAGE * kv_h},"
     writes = re.findall(rf"= {re.escape(whole)}\S* scatter\(", text)
     assert len(writes) == 2, writes                # K and V, once a layer
     if program.startswith("decode"):
@@ -338,3 +343,135 @@ def test_grouped_expert_matmul_compiles_for_v5e_inside_a_layer_scan(
         arg((L, E, I, H))).compile().as_text()
     assert _mosaic_calls(text) == (1, 1)
     assert expert_value_faults(text, L, E, H, I) == []
+
+
+# -- a model of two kinds of attention layer (the hybrid serving cell) --------
+
+#: MiMo-V2.5 at its published widths as the cell runs it: layer 0 and one
+#: period, 16 of 256 experts held, an eighth of the vocabulary; the cell's
+#: pool (40,960 pages of token capacity), slots and context limit
+_HYBRID = dict(vocab_size=19072, held_experts=(0, 16), max_seq_len=8192)
+_HYBRID_PAGES, _HYBRID_SLOTS = 40960, 256
+
+
+@pytest.mark.parametrize("kv_h, window, sink", [(4, None, False),
+                                                (8, 128, True)],
+                         ids=["full_groups_of_16", "window_sink_groups_of_8"])
+def test_paged_decode_with_k_planes_compiles_for_v5e(one_chip, kv_h, window,
+                                                     sink):
+    """K rows of 192 in two 128-lane planes, V rows of 128, 64 query heads:
+    the Mosaic call alone, both pools through bitcasts (a ``[…, 4, 256]``
+    K pool's ``[pages, 64, 256]`` view was a 2.7 GB copy of it)."""
+    rows, h, page, pages, layers, max_blocks = 256, 64, 16, 4097, 2, 512
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(q, k_pool, v_pool, tables, lengths, logits):
+        flat = lambda a: a.reshape((-1,) + a.shape[2:])
+        return pa.paged_decode_attention(
+            q, flat(k_pool), flat(v_pool), tables, lengths, interpret=False,
+            window=window, sink=logits if sink else None, k_planes=2,
+            plane_stride=layers * pages)
+
+    text = jax.jit(fn).lower(
+        arg((rows, h, 192)), arg((2 * layers, pages, page, kv_h, 128)),
+        arg((layers, pages, page, kv_h, 128)),
+        arg((rows, max_blocks), jnp.int32), arg((rows,), jnp.int32),
+        arg((h,), jnp.float32)).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert "paged_decode_attention" in text
+    made = re.findall(rf"= \w+\[(?:{2 * layers}|{layers}),{pages},\S* "
+                      rf"([\w-]+)\(", text)
+    assert made and set(made) <= {"parameter", "bitcast"}, made
+    flat = re.findall(rf"= \w+\[(?:{2 * layers * pages}|{layers * pages}),"
+                      rf"\S* ([\w-]+)\(", text)
+    assert flat and set(flat) <= {"bitcast"}, flat
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(topo, one_chip):
+    """The hybrid cell's engine over shapes alone and a function that
+    compiles one of its programs for the described chip."""
+    from deepspeed_tpu import models
+    from deepspeed_tpu.inference.v2 import engine_v2 as ev2
+    from deepspeed_tpu.inference.v2.kv_cache import KVCacheConfig
+    from deepspeed_tpu.ops.pallas import moe_grouped_matmul as gm
+
+    model = models.MimoV2Model(models.MimoV2Config(**_HYBRID))
+    cache = KVCacheConfig(num_blocks=_HYBRID_PAGES, block_size=_PAGE,
+                          max_seq_len=_HYBRID["max_seq_len"])
+    placed = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    arg = lambda shape, dt=jnp.int32: placed(jax.ShapeDtypeStruct(shape, dt))
+    mp = pytest.MonkeyPatch()
+    mp.setattr(pa, "reference_off_tpu", lambda interpret: False)
+    mp.setattr(gm, "reference_off_tpu", lambda interpret: False)
+    real_pool = ev2.init_kv_pool
+    mp.setattr(ev2, "init_kv_pool",
+               lambda ad, cc: jax.eval_shape(lambda: real_pool(ad, cc)))
+    shapes = jax.eval_shape(
+        lambda key: jax.tree.map(lambda w: w.astype(model.config.dtype),
+                                 model.init_params(key)),
+        jax.random.PRNGKey(0))
+    engine = ev2.RaggedInferenceEngineV2(model, shapes, cache,
+                                         max_batch_slots=_HYBRID_SLOTS)
+    blocks = cache.max_blocks_per_seq
+    common = (arg((), jnp.float32),
+              placed(jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+
+    @functools.cache
+    def compiled(program):
+        kind, _, n = program.rpartition("_")
+        if kind == "decode_burst":
+            fn = functools.partial(engine._decode_burst_fn, n_steps=int(n))
+            rows = (arg((_HYBRID_SLOTS,)), arg((_HYBRID_SLOTS,)),
+                    arg((_HYBRID_SLOTS, blocks)), arg((_HYBRID_SLOTS,)))
+        else:
+            fn = functools.partial(engine._prefill_batch_fn, kb=int(n))
+            rows = (arg((engine.prefill_batch, engine.chunk)),
+                    arg((engine.prefill_batch, blocks)),
+                    arg((engine.prefill_batch,)),
+                    arg((engine.prefill_batch,)))
+        done = jax.jit(fn, donate_argnums=(1,)).lower(
+            placed(shapes), placed(engine.pool), *rows, *common,
+            arg((rows[0].shape[0],))).compile()
+        return done.as_text(), done.memory_analysis()
+
+    yield engine, compiled
+    mp.undo()
+
+
+@pytest.mark.parametrize("program", ["decode_burst_1", "decode_burst_8",
+                                     "prefill_8", "prefill_64"])
+def test_hybrid_programs_keep_both_pools_in_place_on_v5e(hybrid_programs,
+                                                         program):
+    """The hybrid cell's programs at its shapes (two full layers of 4 KV
+    heads over 40,960 pages, five window layers of 8 over 256 rings of 16
+    pages; K in two planes): each of the four pool arrays is passed on,
+    written in place and read through a bitcast, seven Mosaic attention
+    calls and a pair of grouped expert calls a sparse layer stand in a
+    decode step, nothing copies a layer's experts, and the program plans
+    little beyond its arguments (the parent of the planes planned 2.7 GB:
+    a copy of the full layers' K pool a call)."""
+    engine, compiled = hybrid_programs
+    cc = engine.cache_config
+    assert (cc.ring_blocks, cc.num_rings) == (16, _HYBRID_SLOTS)
+    text, memory = compiled(program)
+    pools = {("full", "k"): (4, _HYBRID_PAGES, 4), ("full", "v"): (2,
+             _HYBRID_PAGES, 4), ("window", "k"): (10, cc.ring_pool_blocks, 8),
+             ("window", "v"): (5, cc.ring_pool_blocks, 8)}
+    for (kind, name), (layers, pages, kv_h) in pools.items():
+        assert engine.pool[kind][name].shape == (layers, pages, _PAGE, kv_h,
+                                                 128)
+        assert pool_value_faults(text, layers, pages, _PAGE, kv_h, 128) \
+            == [], (kind, name)
+    assert memory.temp_size_in_bytes < 0.5e9
+    assert memory.alias_size_in_bytes == sum(
+        2 * np.prod(a.shape) for pool in engine.pool.values()
+        for a in pool.values())
+    assert expert_value_faults(text, 6, 16, 4096, 2048) == []
+    assert _mosaic_calls(text) == (6, 6)
+    paged = len(re.findall(r"paged_decode_attention[\w.]* = ", text))
+    assert paged == (7 if program.startswith("decode") else 0)

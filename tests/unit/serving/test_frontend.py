@@ -162,6 +162,53 @@ def test_preempted_background_resumes_and_completes_exactly():
     assert inter.status == "done"
 
 
+@pytest.mark.parametrize("pumps_before", [3, 4, 5, 6])
+def test_preemption_under_a_decode_call_still_running(pumps_before):
+    """The paged engine leaves a round's decode call running when the pump
+    returns (``step_ahead``), and the victim of a preemption is among its
+    rows: what the call yields for it is dropped when it is committed
+    (the victim is no longer running), the victim decodes that position
+    again when it resumes, and both requests are served the tokens an
+    uncontended run serves."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import LlamaConfig, LlamaModel
+    from deepspeed_tpu.serving import build_serving_frontend
+
+    model = LlamaModel(LlamaConfig.tiny(num_layers=2, max_seq_len=64,
+                                        dtype=jnp.float32))
+    params = model.init_params(jax.random.PRNGKey(0))
+
+    def build():
+        return build_serving_frontend(
+            model, params, replicas=1,
+            cache_config=KVCacheConfig(num_blocks=64, block_size=4,
+                                       max_seq_len=64),
+            max_batch_slots=1, prefill_chunk=8, prefill_batch=1,
+            decode_burst=2, serving_params=ServingParams())
+
+    rng = np.random.RandomState(11)
+    bgp, inp = (rng.randint(1, 512, size=n).tolist() for n in (11, 9))
+    alone = []
+    for prompt, new in ((bgp, 14), (inp, 4)):
+        fe = build()
+        h = fe.submit(prompt, max_new_tokens=new, klass="background")
+        fe.run_until_idle()
+        alone.append(h.result())
+        fe.close()
+    fe = build()
+    bg = fe.submit(bgp, max_new_tokens=14, klass="background")
+    for _ in range(pumps_before):
+        fe.pump()
+    assert fe.router.replicas[0].engine._inflight is not None
+    inter = fe.submit(inp, max_new_tokens=4, klass="interactive")
+    fe.run_until_idle()
+    assert fe.metrics.counters["preemptions"] == 1
+    assert [bg.result(), inter.result()] == alone
+    fe.close()
+
+
 # ---------------------------------------------------------------------------
 # admission control
 # ---------------------------------------------------------------------------
@@ -338,6 +385,31 @@ def test_stream_buffer_really_bounds_unread_tokens():
     # reclaimed by the completion sentinel — and the loss is VISIBLE
     assert h.result() == want[-3:]
     assert h.dropped == 9
+
+
+@pytest.mark.parametrize("held, pushed, kept, dropped", [
+    ([], [1, 2, 3], [1, 2, 3], 0),                 # room for the round
+    ([1, 2, 3], [4, 5], [2, 3, 4, 5], 1),          # the oldest unread goes
+    ([1, 2], [3, 4, 5, 6, 7, 8], [5, 6, 7, 8], 4),  # a round over the buffer
+])
+def test_a_rounds_tokens_are_pushed_and_drained_at_once(held, pushed, kept,
+                                                        dropped):
+    """A stream is handed a round's tokens under one lock (a decode burst
+    gives a stream 8 at once, 256 streams a round) with the bound of the
+    token-at-a-time path, and ``drain`` takes the whole buffer, the
+    completion mark apart."""
+    fe, _ = make_frontend(params=ServingParams(stream_buffer=4))
+    h = fe.submit([5, 6, 7], max_new_tokens=4)
+    for tok in held:
+        h._push(tok)
+    h._push_many(pushed)
+    assert h.dropped == dropped
+    assert h.drain() == (kept, False)
+    assert h.drain() == ([], False)         # empty: no lock taken
+    h._push_many([9])
+    h._finish("done")
+    assert h.drain() == ([9], True)
+    fe.close()
 
 
 def test_serving_metrics_published_to_telemetry():

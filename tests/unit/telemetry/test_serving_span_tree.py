@@ -24,7 +24,11 @@ from deepspeed_tpu.serving import ServingParams, build_serving_frontend
 from deepspeed_tpu.telemetry import tracer as tracer_mod
 from deepspeed_tpu.telemetry.perf import CompileTracker, tracked_jit
 
-#: name -> parent, as ISSUE 24 fixes them
+#: name -> parent, as ISSUE 24 fixes them, but for the decode call: the
+#: front-end drives the engine through ``step_ahead``, which leaves a step's
+#: decode call running and fetches it at the start of the next step (PR 31),
+#: so ``inference/decode_burst`` is the wait for it (its fetch) and the
+#: dispatch lies where it happens
 TREE = {
     "serving/pump": None,
     "serving/admit": "serving/pump",
@@ -35,12 +39,17 @@ TREE = {
     "inference/prefill/dispatch": "inference/prefill",
     "inference/prefill/fetch": "inference/prefill",
     "inference/decode_burst": "inference/step",
-    "inference/decode_burst/dispatch": "inference/decode_burst",
+    "inference/decode_burst/dispatch": "inference/step",
     "inference/decode_burst/fetch": "inference/decode_burst",
     "inference/commit": "inference/step",
     "serving/deliver": "serving/pump",
     "serving/ledger": "serving/pump",
 }
+#: a round with a prefill call AND a decode step dispatches both before it
+#: waits for either: packing and dispatching the decode step is host work
+#: under the prefill's device time, and lies inside its span
+UNDER_PREFILL = {("inference/pack", "inference/prefill"),
+                 ("inference/decode_burst/dispatch", "inference/prefill")}
 
 
 @pytest.fixture(scope="module")
@@ -96,14 +105,16 @@ def named(served, name):
 def test_every_span_of_the_tree_is_there_under_its_parent(served):
     seen = {(e["name"], e["args"].get("parent")) for e in served["events"]
             if "depth" in e["args"]}
-    assert seen == set(TREE.items())
+    assert seen == set(TREE.items()) | UNDER_PREFILL
     depth = {"serving/pump": 0}
     for name, parent in TREE.items():
         if parent is not None:
             depth[name] = depth[parent] + 1
     for e in served["events"]:
         if "depth" in e["args"]:
-            assert e["args"]["depth"] == depth[e["name"]], e["name"]
+            parent = e["args"].get("parent")
+            assert e["args"]["depth"] == (
+                depth[parent] + 1 if parent else 0), e["name"]
     kinds = {e["args"]["kind"] for e in named(served, "inference/pack")}
     assert kinds == {"prefill", "decode"}
 
@@ -126,8 +137,12 @@ def test_children_lie_inside_their_parent_and_sum_to_no_more(served):
 
 def test_prefill_and_decode_spans_read_as_at_the_parent_commit(served):
     """The three accepted metrics read these spans and counters: their
-    count, order, arguments and the token counters are what commit
-    6f933b4 gives for the same three requests."""
+    arguments, the token counters and the served tokens are what commit
+    6f933b4 gives for the same three requests.  Their order is
+    ``step_ahead``'s: the burst in which the second request finishes is
+    committed by the NEXT step, after that round's admissions, so the
+    queued third request is seated a round later and one more burst
+    (of the one request left decoding) runs before its prefill."""
     got = [(e["name"], {k: v for k, v in e["args"].items()
                         if k not in ("depth", "parent")})
            for e in served["events"]
@@ -136,8 +151,8 @@ def test_prefill_and_decode_spans_read_as_at_the_parent_commit(served):
     assert got == [
         (P, {"chunks": 2}), (P, {"chunks": 1}),
         (D, {"burst": 1, "batch": 1}), (D, {"burst": 4, "batch": 2}),
-        (P, {"chunks": 1}), (D, {"burst": 1, "batch": 1}),
-        (P, {"chunks": 1}), (P, {"chunks": 1}),
+        (D, {"burst": 4, "batch": 1}),
+        (P, {"chunks": 1}), (P, {"chunks": 1}), (P, {"chunks": 1}),
         (D, {"burst": 4, "batch": 1}), (D, {"burst": 4, "batch": 1})]
     assert served["counters"]["inference/prefill_tokens"] == 36
     assert served["counters"]["inference/decode_tokens"] == 15
@@ -158,7 +173,7 @@ def test_span_arguments_count_what_the_round_did(served):
     rounds = [e["args"]["round"] for e in named(served, "serving/pump")]
     assert rounds == list(range(rounds[0], rounds[0] + len(rounds)))
     steps = named(served, "inference/step")
-    assert len(steps) == 8
+    assert len(steps) == 10      # the last one commits and finds no work
     assert sum(s["args"]["chunks"] for s in steps) == 6
     assert max(s["args"]["decoding"] for s in steps) == 2
 
