@@ -81,7 +81,7 @@ FIRST_LOSS_BAND = 1.0
 MEMORY_BALANCE_RATIO = 1.25
 
 #: the names the kernels are given at their ``pl.pallas_call(name=...)``
-FLASH_KERNELS = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+FLASH_KERNELS = {"flash_fwd", "flash_bwd"}
 PAGED_KERNEL = "paged_decode_attention"
 
 

@@ -7,11 +7,12 @@ q-loop × online-softmax k-loop kernel that never materializes the
 
 The kernel family (dispatched by :func:`_flash_call` / :func:`_flash_bwd`):
 
-* **resident** (fwd + dq/dkv backward): K/V (and in the dkv pass
-  q/do/lse/Δ) ride VMEM whole; the k-loop walks the contiguous
-  ``lattice.kv_block_bounds`` range, so causal work is the true
-  triangle and windowed work is O(S·window).  Fastest while a head's
-  planes fit the VMEM budget (``lattice.resident_fits``).
+* **resident** (fwd + one-pass backward): K/V (in the backward
+  q/do/lse/Δ and the dq accumulator) ride VMEM whole; the loop walks
+  the contiguous ``lattice.kv_block_bounds`` / ``q_block_bounds`` range,
+  so causal work is the true triangle and windowed work is O(S·window).
+  Taken while a head's planes fit the VMEM budget
+  (``lattice.resident_fits``).
 * **streamed** (fwd + dq/dkv backward): beyond VMEM residency the grid
   grows a live-step dimension and a scalar-prefetched ``index_map``
   DMAs ONLY each step's live block (``lattice.plan_q_live`` /
@@ -84,12 +85,13 @@ def _reference_fwd_with_lse(q, k, v, causal: bool, window=None,
     return jnp.einsum("bhqk,bkhd->bqhd", p, v), lse
 
 
-def _resolve_blocks(block_q, block_k, S, d, backward=False):
-    """0/None → the seq-length table; explicit values are honored (then
-    shrunk to legal divisors).  The backward CAPS explicit sizes at the
-    table's choice — its resident passes hold extra O(S·d) planes, and a
-    512-block at S≥8k pushes scoped VMEM past the limit."""
-    abq, abk = lattice.auto_flash_blocks(S, d, backward=backward)
+def _resolve_blocks(block_q, block_k, S, d, backward=False, itemsize=2):
+    """0/None → :func:`lattice.auto_flash_blocks`; explicit values are
+    honored (then shrunk to legal divisors).  The backward CAPS explicit
+    sizes at the rule's choice: that is the largest tile whose VMEM plan
+    fits the limit its resident passes hand to Mosaic."""
+    abq, abk = lattice.auto_flash_blocks(S, d, backward=backward,
+                                         itemsize=itemsize)
     block_q = min(block_q, abq) if (block_q and backward) else (block_q
                                                                or abq)
     block_k = min(block_k, abk) if (block_k and backward) else (block_k
@@ -347,168 +349,116 @@ def _flash_call(q, k, v, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 
-def _fa_bwd_dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
-                      seg_ref, dq_ref, *, block_q: int, block_k: int,
-                      seq_len: int, causal: bool, scale: float, window,
-                      has_seg: bool = False):
-    """Pallas dq pass: grid (bh, q-block); K/V ride VMEM-resident (as in
-    the forward) and the k-loop walks the lattice's contiguous live range
-    — scores never touch HBM, and causal work is the true triangle."""
-    from jax.experimental import pallas as pl
-
-    qi = pl.program_id(1)
-    nk = seq_len // block_k
-    q = q_ref[0].astype(jnp.float32)                   # [bq, d]
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, :, 0]                             # [bq]
-    delta = delta_ref[0, :, 0]
-    q_seg = (seg_ref[0, 0, pl.ds(qi * block_q, block_q)] if has_seg else None)
-
-    def body(ki, acc):
-        kblk = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        k_seg = (seg_ref[0, 0, pl.ds(ki * block_k, block_k)] if has_seg
-                 else None)
-        keep = lattice.tile_keep(qi, ki, block_q, block_k, causal, window,
-                                 q_seg, k_seg)
-        p = jnp.exp(s - lse[:, None])
-        if keep is not None:
-            p = jnp.where(keep, p, 0.0)
-        dp = jax.lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        return acc + jax.lax.dot_general(
-            ds, kblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    k0, nk_eff = lattice.kv_block_bounds(qi, block_q, block_k, nk, causal,
-                                         window)
-    acc = jax.lax.fori_loop(
-        k0, nk_eff, body, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
-    dq_ref[0] = acc.astype(dq_ref.dtype)
+_NT = (((1,), (1,)), ((), ()))      # a · bᵀ: contract both minor dims
+_NN = (((1,), (0,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))      # aᵀ · b
 
 
-def _fa_bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
-                       seg_ref, dk_ref, dv_ref, *, block_q: int,
-                       block_k: int, seq_len: int, causal: bool,
-                       scale: float, window, has_seg: bool = False):
-    """Pallas dk/dv pass: grid (bh, k-block); Q/do/lse/Δ VMEM-resident,
-    q-loop walks the transposed lattice range.  dv += pᵀ·do,
-    dk += dsᵀ·q·scale, accumulated in registers/VMEM — no segment-sum or
-    HBM score chunks."""
+def _fa_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, seg_ref,
+                   dq_ref, dk_ref, dv_ref, dq_acc, *, block_q: int,
+                   block_k: int, seq_len: int, causal: bool, scale: float,
+                   window, has_seg: bool = False):
+    """The resident backward, one pass: grid (bh, k-block); Q/do/lse/Δ
+    VMEM-resident, the q-loop walks the transposed lattice range, and dq
+    gathers in a float32 ``[S, d]`` VMEM plane over a head's k-blocks —
+    five products a tile where a dq pass beside a dk/dv pass makes seven.
+
+    The score tile is held keys-major, sᵀ = k·qᵀ ``[bk, bq]``: lse and Δ
+    are row vectors that broadcast along sublanes, and dv += pᵀ·do,
+    dk += dsᵀ·q are plain ``[bk, bq] × [bq, d]`` products; dq += ds·k
+    alone turns a (rounded) tile.  The MXU takes q, k, v, do as they
+    arrive and p, ds rounded to that dtype; statistics, ``exp``, the ds
+    arithmetic and the three accumulators are float32."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    nq = seq_len // block_q
-    kblk = k_ref[0].astype(jnp.float32)                # [bk, d]
-    vblk = v_ref[0].astype(jnp.float32)
-    k_seg = (seg_ref[0, 0, pl.ds(ki * block_k, block_k)] if has_seg else None)
+    nq, nk = seq_len // block_q, seq_len // block_k
+    kblk, vblk = k_ref[0], v_ref[0]                    # [bk, d]
+    k_seg = (seg_ref[0, 0, pl.ds(pl.multiple_of(ki * block_k, block_k),
+                                 block_k)] if has_seg else None)
+
+    @pl.when(ki == 0)
+    def _new_head():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def body(qi, carry):
         dk_acc, dv_acc = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), 0]
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), 0]
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        q_seg = (seg_ref[0, 0, pl.ds(qi * block_q, block_q)] if has_seg
-                 else None)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]  # [bq, d]
+        st = jax.lax.dot_general(kblk, q, _NT,
+                                 preferred_element_type=jnp.float32) * scale
         keep = lattice.tile_keep(qi, ki, block_q, block_k, causal, window,
-                                 q_seg, k_seg)
-        p = jnp.exp(s - lse[:, None])
+                                 seg_ref[0, 0, rows] if has_seg else None,
+                                 k_seg, transposed=True)
+        pt = jnp.exp(st - lse_ref[0, :, rows])         # [bk, bq] − [1, bq]
         if keep is not None:
-            p = jnp.where(keep, p, 0.0)
+            pt = jnp.where(keep, pt, 0.0)
         dv_acc = dv_acc + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            pt.astype(do.dtype), do, _NN,
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        dpt = jax.lax.dot_general(vblk, do, _NT,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[0, :, rows])).astype(q.dtype)
         dk_acc = dk_acc + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            dst, q, _NN, preferred_element_type=jnp.float32)
+        dq_acc[rows, :] += jax.lax.dot_general(
+            dst, kblk, _TN, preferred_element_type=jnp.float32)
         return dk_acc, dv_acc
 
     q0, nq_eff = lattice.q_block_bounds(ki, block_q, block_k, nq, causal,
                                         window)
-    d = kblk.shape[-1]
-    dk_acc, dv_acc = jax.lax.fori_loop(
-        q0, nq_eff, body, (jnp.zeros((block_k, d), jnp.float32),
-                           jnp.zeros((block_k, d), jnp.float32)))
-    dk_ref[0] = dk_acc.astype(dk_ref.dtype)
+    zeros = jnp.zeros((block_k, kblk.shape[-1]), jnp.float32)
+    dk_acc, dv_acc = jax.lax.fori_loop(q0, nq_eff, body, (zeros, zeros))
+    dk_ref[0] = (dk_acc * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv_acc.astype(dv_ref.dtype)
+
+    @pl.when(ki == nk - 1)
+    def _head_done():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, do, causal, block_q, block_k,
                       window, interpret: bool = False, segment_ids=None):
-    """Resident kernel backward: dq + dk/dv passes with VMEM-resident
-    scores — measured 4x the jnp chunked scan at B=8/S=2048/h=12/d=64 on
-    v5e (took the 110M-headline attention from 7.5%% to ~30%% component
-    efficiency)."""
+    """Resident kernel backward: one Mosaic call gives dq, dk and dv,
+    scores VMEM-resident, the tile from :func:`lattice.auto_flash_blocks`
+    at the operands' dtype."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     B, S, h, d = q.shape
     block_q, block_k = _resolve_blocks(block_q, block_k, S, d,
-                                       backward=True)
+                                       backward=True,
+                                       itemsize=q.dtype.itemsize)
     qr = q.transpose(0, 2, 1, 3).reshape(B * h, S, d)
     kr = k.transpose(0, 2, 1, 3).reshape(B * h, S, d)
     vr = v.transpose(0, 2, 1, 3).reshape(B * h, S, d)
     dor = do.transpose(0, 2, 1, 3).reshape(B * h, S, d)
-    lse_r = lse.reshape(B * h, S, 1)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                            # [B, S, h]
-    delta_r = delta.transpose(0, 2, 1).reshape(B * h, S, 1)
-    scale = 1.0 / np.sqrt(d)
-    has_seg = segment_ids is not None
+    # a head's statistics as one lane-dense float32 row, [B·h, 1, S]: 32 KiB
+    # at S = 8,192 where [B·h, S, 1] is tiled (8, 128) to 4 MiB, in HBM and
+    # in VMEM
+    lse_r = lse.reshape(B * h, 1, S)
+    delta_r = delta.transpose(0, 2, 1).reshape(B * h, 1, S)
     seg, seg_spec = _seg_operand(segment_ids, S, h)
-
-    dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, seq_len=S, causal=causal,
-                          scale=scale, window=window, has_seg=has_seg),
-        grid=(B * h, S // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, S, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, S, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
-            seg_spec,
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * h, S, d), q.dtype),
-        interpret=interpret,
-        name="flash_bwd_dq",
-        **resident_compiler_params(interpret),
-    )(qr, dor, kr, vr, lse_r, delta_r, seg)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, block_q=block_q,
-                          block_k=block_k, seq_len=S, causal=causal,
-                          scale=scale, window=window, has_seg=has_seg),
+    plane = pl.BlockSpec((1, S, d), lambda bh, ki: (bh, 0, 0))
+    stats = pl.BlockSpec((1, 1, S), lambda bh, ki: (bh, 0, 0))
+    tile_k = pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_fa_bwd_kernel, block_q=block_q, block_k=block_k,
+                          seq_len=S, causal=causal, scale=1.0 / np.sqrt(d),
+                          window=window, has_seg=segment_ids is not None),
         grid=(B * h, S // block_k),
-        in_specs=[
-            pl.BlockSpec((1, S, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, S, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, S, 1), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, S, 1), lambda bh, ki: (bh, 0, 0)),
-            seg_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((B * h, S, d), k.dtype),
+        in_specs=[plane, plane, tile_k, tile_k, stats, stats, seg_spec],
+        out_specs=[plane, tile_k, tile_k],
+        out_shape=[jax.ShapeDtypeStruct((B * h, S, d), q.dtype),
+                   jax.ShapeDtypeStruct((B * h, S, d), k.dtype),
                    jax.ShapeDtypeStruct((B * h, S, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((S, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dkv",
-        **resident_compiler_params(interpret),
+        name="flash_bwd",
+        # dq gathers over a head's k-blocks: that grid axis runs in order
+        **resident_compiler_params(interpret, ("parallel", "arbitrary")),
     )(qr, dor, kr, vr, lse_r, delta_r, seg)
 
     back = lambda a: a.reshape(B, h, S, d).transpose(0, 2, 1, 3)

@@ -22,10 +22,10 @@ Now there is ONE lattice:
   flash forward, both flash backwards, and the block-sparse tile update
   so masking cannot drift between passes.
 
-Block-size selection (:func:`auto_flash_blocks`) is seq-length-aware:
-the 512-everywhere default that made flash merely break even at 8k
-(BENCH_r04) loses VMEM headroom to the resident K/V planes as S grows —
-the table steps tiles down where the measured crossover sits.
+Block-size selection (:func:`auto_flash_blocks`): the forward walks a
+table keyed on the resident planes' elements; the backward takes the
+largest tile whose VMEM plan (:func:`backward_plan_bytes`) fits the limit
+its resident passes hand to Mosaic.
 """
 
 from __future__ import annotations
@@ -37,6 +37,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .select import RESIDENT_VMEM_LIMIT_BYTES
 
 #: PER-PLANE element bound (S·d of K, same for V) for VMEM-resident
 #: kernels; K+V together occupy up to 2x this.  2M elems/plane = 8 MiB
@@ -55,28 +57,43 @@ def resident_fits(S: int, d: int) -> bool:
 # block-size tables
 # ---------------------------------------------------------------------------
 
-#: (min_S·d_elems_exclusive → (block_q, block_k)) forward table,
-#: measured on v5e at d=64/bf16: 512-tiles win on MXU occupancy up to 8k
-#: (·64); past that the fp32 q/score/acc tiles compete with the resident
-#: K/V planes — whose footprint is S·d, which is why the key is ELEMENTS
-#: not raw S (a d=128 model hits the pressure point at half the S) —
-#: and the scheduler stops double-buffering; smaller q tiles restore the
-#: pipeline.  ``auto_flash_blocks`` walks this largest-bound-first.
+#: (min_S·d_elems_exclusive → (block_q, block_k)) forward table: the
+#: q/score/acc tiles share VMEM with the resident K/V planes, whose
+#: footprint is S·d, which is why the key is ELEMENTS not raw S (a d=128
+#: model meets a boundary at half the S).  ``auto_flash_blocks`` walks
+#: this largest-bound-first.
 _FWD_BLOCKS: Tuple[Tuple[int, Tuple[int, int]], ...] = (
     (16384 * 64, (256, 256)),   # S·d > 1M elems
     (8192 * 64, (256, 512)),    # 512k < S·d <= 1M
     (0, (512, 512)),            # S·d <= 512k
 )
 
-#: backward table: the dkv pass holds q/do/lse/Δ resident (O(S·d)) on
-#: top of what the forward holds, so tiles cap earlier — the PR-5-era
-#: guard was exactly ``S·d > 4096·64 → cap 256``, preserved here as the
-#: 262k boundary.
-_BWD_BLOCKS: Tuple[Tuple[int, Tuple[int, int]], ...] = (
-    (8192 * 64, (128, 256)),    # S·d > 512k
-    (4096 * 64, (256, 256)),    # 262k < S·d <= 512k
-    (0, (512, 512)),            # S·d <= 262k
-)
+#: backward tiles (block_q, block_k), largest first; the rule takes the
+#: first whose plan fits.  Nothing larger is a candidate: at
+#: [1, 8192, 32, 128] bf16, window 4,096, on a v5e the backward took
+#: 7.68 ms at 512 x 512, 7.85 at 1024 x 512, 8.17 at 512 x 1024, 8.32 at
+#: 512 x 256, 9.26 at 256 x 512 and 12.2 at 256 x 256 (PR 32, chip runs
+#: of the kernel alone, the device's clock)
+_BWD_TILES: Tuple[Tuple[int, int], ...] = (
+    (512, 512), (512, 256), (256, 512), (256, 256), (128, 256), (128, 128),
+    (64, 64))
+
+#: the streamed backward (S·d past residency; timed in no cell) keeps the
+#: tile it has always run: its calls pass Mosaic no VMEM limit
+_STREAM_BWD_BLOCKS = (128, 256)
+
+
+def backward_plan_bytes(S: int, d: int, itemsize: int, block_q: int,
+                        block_k: int) -> int:
+    """VMEM the resident backward asks of Mosaic at this tile: what the
+    pipeline holds (every operand double-buffered), the float32 dq
+    accumulator, and one tile's float32 score planes and accumulators."""
+    planes = 3 * S * d * itemsize          # q and do in, dq out
+    stats = 2 * 8 * S * 4                  # lse, Δ: [1, S] float32 rows
+    blocked = 4 * block_k * d * itemsize   # k and v in, dk and dv out
+    acc = (S + 2 * block_k) * d * 4
+    scores = 6 * block_q * block_k * 4     # s, keep, p, dp, ds + roundings
+    return 2 * (planes + stats + blocked) + acc + scores
 
 
 def fit_block(b: int, S: int) -> int:
@@ -89,17 +106,28 @@ def fit_block(b: int, S: int) -> int:
     return b
 
 
-def auto_flash_blocks(S: int, d: int, backward: bool = False
-                      ) -> Tuple[int, int]:
-    """VMEM-pressure-aware (block_q, block_k) for the flash kernels,
-    keyed on S·d (the resident planes' footprint); callers pass explicit
-    sizes (or the tuning plane's ``kernels.flash_block_*`` overrides) to
-    bypass the table."""
-    elems = S * max(d, 1)
-    table = _BWD_BLOCKS if backward else _FWD_BLOCKS
-    for min_elems, (bq, bk) in table:
-        if elems > min_elems:  # the (0, ...) row matches any valid S·d
+def auto_flash_blocks(S: int, d: int, backward: bool = False,
+                      itemsize: int = 2) -> Tuple[int, int]:
+    """(block_q, block_k) for the flash kernels from what the call can
+    see: S·d (the resident planes' footprint) and, for the backward, the
+    operands' ``itemsize``.  Callers pass explicit sizes (or the tuning
+    plane's ``kernels.flash_block_*`` overrides) as caps."""
+    if backward:
+        if not resident_fits(S, d):
+            bq, bk = _STREAM_BWD_BLOCKS
             return fit_block(bq, S), fit_block(bk, S)
+        for bq, bk in _BWD_TILES:
+            bq, bk = fit_block(bq, S), fit_block(bk, S)
+            if (backward_plan_bytes(S, d, itemsize, bq, bk)
+                    <= RESIDENT_VMEM_LIMIT_BYTES):
+                return bq, bk
+        raise AssertionError(
+            f"no backward tile fits {RESIDENT_VMEM_LIMIT_BYTES} bytes of "
+            f"VMEM at S={S}, d={d}, itemsize={itemsize}")
+    elems = S * max(d, 1)
+    for min_elems, blocks in _FWD_BLOCKS:
+        if elems > min_elems:  # the (0, ...) row matches any valid S·d
+            return fit_block(blocks[0], S), fit_block(blocks[1], S)
     raise AssertionError(f"block table has no row for S·d = {elems}")
 
 
@@ -156,8 +184,7 @@ def kv_block_bounds(qi, block_q: int, block_k: int, nk: int, causal: bool,
                     window: Optional[int] = None):
     """Traced [k0, nk_eff) k-block loop bounds for one q-block — the
     contiguous-range form of the lattice row (causal/window rows are
-    banded so the range is exact).  Shared by the resident flash forward
-    and its dq backward."""
+    banded so the range is exact).  The resident flash forward's walk."""
     if causal:
         nk_eff = (qi * block_q + block_q + block_k - 1) // block_k
         nk_eff = jnp.minimum(nk_eff, nk)
@@ -176,8 +203,8 @@ def kv_block_bounds(qi, block_q: int, block_k: int, nk: int, causal: bool,
 
 def q_block_bounds(ki, block_q: int, block_k: int, nq: int, causal: bool,
                    window: Optional[int] = None):
-    """Traced [q0, nq_eff) q-block bounds for one k-block (the dkv pass's
-    transposed walk of the same lattice)."""
+    """Traced [q0, nq_eff) q-block bounds for one k-block (the resident
+    backward's transposed walk of the same lattice)."""
     q0 = (ki * block_k) // block_q if causal else 0
     nq_eff = nq
     if window is not None:
@@ -262,19 +289,23 @@ def plan_k_live(S: int, block_q: int, block_k: int, causal: bool,
 
 
 def tile_keep(qi, kj, block_q: int, block_k: int, causal: bool,
-              window: Optional[int] = None, q_seg=None, k_seg=None):
+              window: Optional[int] = None, q_seg=None, k_seg=None,
+              transposed: bool = False):
     """``[bq, bk]`` bool keep mask for tile (qi, kj): causal edge ∩
     window band ∩ segment equality.  ``q_seg [bq]`` / ``k_seg [bk]`` are
     this tile's segment-id slices (packed sequences / padding); None
-    skips the segment term.  Returns None when nothing masks (the caller
-    skips the where())."""
+    skips the segment term.  ``transposed`` gives the same mask as
+    ``[bk, bq]`` (the resident backward holds its score tile keys-major).
+    Returns None when nothing masks (the caller skips the where())."""
     need_pos = causal or window is not None
     keep = None
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    q_axis = 1 if transposed else 0
     if need_pos:
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
+            jnp.int32, shape, q_axis)
         k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+            jnp.int32, shape, 1 - q_axis)
         if causal:
             keep = q_pos >= k_pos
         if window is not None:
@@ -283,6 +314,7 @@ def tile_keep(qi, kj, block_q: int, block_k: int, causal: bool,
                      & (k_pos - q_pos < window))
             keep = reach if keep is None else keep & reach
     if q_seg is not None and k_seg is not None:
-        seg = q_seg[:, None] == k_seg[None, :]
+        seg = (k_seg[:, None] == q_seg[None, :] if transposed
+               else q_seg[:, None] == k_seg[None, :])
         keep = seg if keep is None else keep & seg
     return keep

@@ -20,8 +20,11 @@ from ...utils.logging import warn_once
 #: scoped-VMEM ceiling handed to Mosaic by the kernels that keep whole
 #: per-head planes resident (flash/block-sparse resident passes, the MoE
 #: row gather's output window).  Mosaic's default is 16 MiB; a v5e core
-#: has 128 MiB, and the resident dk/dv pass at S·d = 1M elements (bf16)
-#: needs 20.5 MiB (the compiler's own figure, jax 0.9.0 / libtpu 0.0.34).
+#: has 128 MiB, and the resident flash backward at S·d = 1M elements
+#: (bf16, 512 x 512 tiles) allocates 16.75 MiB (the compiler's own
+#: figure, PR 32: compiled for a described v5e under a 4 MiB limit,
+#: jax 0.9.0 / libtpu 0.0.34; ``lattice.backward_plan_bytes`` counts
+#: 24.5 MiB there).  It is also the budget that rule picks tiles under.
 RESIDENT_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
@@ -40,12 +43,15 @@ def shape_refused(kernel: str, shape: Any, reason: str) -> None:
             f"TPU, not the Pallas kernel — {reason}")
 
 
-def resident_compiler_params(interpret: bool):
+def resident_compiler_params(interpret: bool, dimension_semantics=None):
     """``compiler_params`` for a kernel holding resident planes (empty in
-    the interpreter, which has no VMEM to limit)."""
+    the interpreter, which has no VMEM to limit and walks its grid in
+    order).  ``dimension_semantics`` names the grid axes a kernel carries
+    state across (``"arbitrary"``)."""
     if interpret:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
     return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=RESIDENT_VMEM_LIMIT_BYTES)}
+        vmem_limit_bytes=RESIDENT_VMEM_LIMIT_BYTES,
+        dimension_semantics=dimension_semantics)}

@@ -92,32 +92,63 @@ def test_block_bounds_cover_the_lattice_rows():
             assert not lat[qi, nk_eff:].any()
 
 
+@pytest.mark.parametrize("causal, window, packed", [
+    (True, None, False), (True, 100, False), (False, 100, True),
+    (False, None, True)])
+def test_tile_keep_transposed_is_the_same_mask_keys_major(causal, window,
+                                                          packed):
+    """The backward holds its score tile ``[bk, bq]``: its mask is the
+    forward's, turned."""
+    bq, bk = 64, 128
+    seg = np.asarray(segs(1, 512))[0]
+    for qi, kj in ((0, 0), (3, 1), (5, 2), (7, 3)):
+        q_seg = jnp.asarray(seg[qi * bq:(qi + 1) * bq]) if packed else None
+        k_seg = jnp.asarray(seg[kj * bk:(kj + 1) * bk]) if packed else None
+        plain = lattice.tile_keep(qi, kj, bq, bk, causal, window, q_seg,
+                                  k_seg)
+        turned = lattice.tile_keep(qi, kj, bq, bk, causal, window, q_seg,
+                                   k_seg, transposed=True)
+        assert turned.shape == (bk, bq)
+        np.testing.assert_array_equal(np.asarray(plain).T,
+                                      np.asarray(turned))
+
+
 def test_auto_blocks_step_down_with_seq_length():
     assert lattice.auto_flash_blocks(2048, 64) == (512, 512)
     assert lattice.auto_flash_blocks(32768, 64) == (256, 256)
     bq_s, _ = lattice.auto_flash_blocks(2048, 64)
     bq_l, _ = lattice.auto_flash_blocks(32768, 64)
     assert bq_l <= bq_s
-    # backward caps earlier than forward at matched S
-    fb, _ = lattice.auto_flash_blocks(8192, 64)
-    bb, _ = lattice.auto_flash_blocks(8192, 64, backward=True)
-    assert bb <= fb
+    # the backward's tile never grows with the resident planes
+    tiles = [lattice.auto_flash_blocks(S, 128, backward=True, itemsize=4)
+             for S in (1024, 4096, 16384)]
+    assert all(a[0] * a[1] >= b[0] * b[1] for a, b in zip(tiles, tiles[1:]))
 
 
-def test_auto_blocks_key_on_elements_not_raw_seq_length():
-    """The VMEM pressure point is S·d (the resident planes), so a
-    d=128 model must cap at HALF the S a d=64 model does — the PR-5-era
-    ``S·d > 4096·64 → 256`` backward guard, preserved (review finding:
-    a seq-only table silently dropped it)."""
-    # d=64 at 4096: under the 262k boundary → 512-tiles
-    assert lattice.auto_flash_blocks(4096, 64, backward=True) == (512, 512)
-    # d=128 at 4096: 512k elems → capped, like d=64 at 8192
-    assert lattice.auto_flash_blocks(4096, 128, backward=True) == \
-        lattice.auto_flash_blocks(8192, 64, backward=True)
-    bq, bk = lattice.auto_flash_blocks(4096, 128, backward=True)
-    assert max(bq, bk) <= 256
-    # forward steps down for wide heads too
-    assert lattice.auto_flash_blocks(16384, 128)[0] <= 256
+@pytest.mark.parametrize("S, d, itemsize", [
+    (8192, 128, 2),      # the Mistral training cells
+    (512, 64, 2),        # BERT-large
+    (4096, 64, 4), (16384, 128, 2), (16384, 128, 4), (192, 64, 2)])
+def test_auto_blocks_key_on_elements_not_raw_seq_length(S, d, itemsize):
+    """The backward rule keys on what the resident passes hold: the
+    chosen tile's plan (planes of S·d·itemsize, double-buffered, plus
+    the tile's own float32 planes) fits the limit handed to Mosaic, no
+    larger candidate does, and the tile divides S."""
+    from deepspeed_tpu.ops.pallas.select import RESIDENT_VMEM_LIMIT_BYTES
+
+    bq, bk = lattice.auto_flash_blocks(S, d, backward=True,
+                                       itemsize=itemsize)
+    assert S % bq == 0 and S % bk == 0
+    assert lattice.backward_plan_bytes(S, d, itemsize, bq, bk) \
+        <= RESIDENT_VMEM_LIMIT_BYTES
+    for cq, ck in lattice._BWD_TILES:
+        cq, ck = lattice.fit_block(cq, S), lattice.fit_block(ck, S)
+        if cq * ck > bq * bk:
+            assert lattice.backward_plan_bytes(S, d, itemsize, cq, ck) \
+                > RESIDENT_VMEM_LIMIT_BYTES
+    # wider operands never get a larger tile
+    wide = lattice.auto_flash_blocks(S, d, backward=True, itemsize=4)
+    assert wide[0] * wide[1] <= bq * bk
 
 
 def test_apply_lattice_window_is_token_denominated():
@@ -137,11 +168,17 @@ def test_apply_lattice_window_is_token_denominated():
 
 
 def test_explicit_backward_blocks_capped_at_table():
-    # a 512 explicit block at long S would blow scoped VMEM in the
-    # resident dkv pass — the resolver caps it at the table's choice
-    bq, bk = fa._resolve_blocks(512, 512, 16384, 64, backward=True)
-    abq, abk = lattice.auto_flash_blocks(16384, 64, backward=True)
-    assert bq <= abq and bk <= abk
+    # an explicit block is a cap: the resolver never hands the resident
+    # passes a tile larger than the rule's (whose plan fits the limit),
+    # and honors a smaller one
+    for S, d, itemsize in ((16384, 64, 2), (16384, 128, 4), (8192, 128, 2)):
+        abq, abk = lattice.auto_flash_blocks(S, d, backward=True,
+                                             itemsize=itemsize)
+        bq, bk = fa._resolve_blocks(1024, 1024, S, d, backward=True,
+                                    itemsize=itemsize)
+        assert (bq, bk) == (abq, abk)
+        assert fa._resolve_blocks(128, 256, S, d, backward=True,
+                                  itemsize=itemsize) == (128, 256)
 
 
 # ---------------------------------------------------------------------------
@@ -226,6 +263,50 @@ def test_streamed_bwd_matches_reference(causal, window):
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=2e-3, atol=2e-3)
+
+
+#: dtype, S, d, block_q, block_k, causal, window, segments — what the
+#: rule can pick and what the cells meet: tiles wider than tall and taller
+#: than wide; a window whose edge falls inside a tile (100) and on a
+#: tile's edge (128); S = 384, no multiple of the largest tile; (0, 0)
+#: is the rule's own choice
+_BWD_CASES = [
+    (jnp.float32, 256, 64, 64, 128, True, 100, False),
+    (jnp.float32, 256, 64, 128, 64, True, 128, False),
+    (jnp.float32, 256, 64, 64, 128, False, 100, False),
+    (jnp.float32, 256, 64, 128, 64, False, None, True),
+    (jnp.float32, 384, 64, 128, 64, True, 64, True),
+    (jnp.float32, 384, 128, 0, 0, True, 200, False),
+    (jnp.bfloat16, 256, 128, 64, 128, True, 100, False),
+    (jnp.bfloat16, 256, 128, 128, 64, True, 128, True),
+    (jnp.bfloat16, 256, 64, 128, 64, False, 100, False),
+    (jnp.bfloat16, 384, 64, 128, 128, True, None, False),
+    (jnp.bfloat16, 384, 64, 0, 0, True, 200, True),
+]
+
+
+@pytest.mark.parametrize("dtype, S, d, bq, bk, causal, window, packed",
+                         _BWD_CASES)
+def test_resident_bwd_tiles_match_reference(dtype, S, d, bq, bk, causal,
+                                            window, packed):
+    """The resident backward at its operands' dtype (bf16 products with
+    float32 statistics and accumulators; float32 stays float32) against
+    the float32 reference's vjp of the same inputs."""
+    q, k, v = qkv(B=1, S=S, d=d, dtype=dtype)
+    seg = segs(1, S) if packed else None
+    do = jnp.asarray(np.random.RandomState(7).randn(*q.shape)).astype(dtype)
+    f32 = lambda a: a.astype(jnp.float32)
+    out, lse = fa._reference_fwd_with_lse(f32(q), f32(k), f32(v), causal,
+                                          window, seg)
+    got = fa._flash_bwd_pallas(q, k, v, out.astype(dtype), lse, do, causal,
+                               bq, bk, window, interpret=True,
+                               segment_ids=seg)
+    want = _ref_vjp(f32(q), f32(k), f32(v), f32(do), causal, window, seg)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for g, w in zip(got, want):
+        assert g.dtype == dtype
+        err = np.abs(np.asarray(g, np.float32) - np.asarray(w)).max()
+        assert err <= tol * np.abs(np.asarray(w)).max(), err
 
 
 def test_segment_bwd_matches_reference():

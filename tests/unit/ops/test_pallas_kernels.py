@@ -89,11 +89,10 @@ def test_quantize_zero_rows():
 
 
 def test_flash_backward_kernels_match_reference_all_modes():
-    """The Pallas flash backward pair (_fa_bwd_dq_kernel/_fa_bwd_dkv_kernel,
-    interpret mode here; on-chip via bench --selfcheck) == the reference
-    vjp for causal, non-causal, and windowed attention — the kernels that
-    took the 110M headline from 30.2% to 40.6% MFU must stay testable
-    without a chip."""
+    """The resident Pallas flash backward (``_fa_bwd_kernel``, interpret
+    mode here; on the chip through ``chip_smoke.py``'s selfcheck) == the
+    reference vjp for causal, non-causal, and windowed attention: the
+    kernel must stay testable without a chip."""
     import importlib
 
     import numpy as np

@@ -475,3 +475,58 @@ def test_hybrid_programs_keep_both_pools_in_place_on_v5e(hybrid_programs,
     assert _mosaic_calls(text) == (6, 6)
     paged = len(re.findall(r"paged_decode_attention[\w.]* = ", text))
     assert paged == (7 if program.startswith("decode") else 0)
+
+
+def _plane_relayouts(text: str, B: int, S: int, h: int, d: int):
+    """Names of the compiled program's instructions that make a new
+    ``[.., S, .., d]`` plane of the attention operands by moving data:
+    a ``copy`` or ``transpose``, alone, asynchronous or as a fusion."""
+    shapes = (rf"\w+\[{B},{S},{h},{d}\]", rf"\w+\[{B * h},{S},{d}\]",
+              rf"\w+\[{B},{h},{S},{d}\]")
+    return [name for name, shape, opcode in re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(", text, re.M)
+        if any(re.match(s, shape) for s in shapes)
+        and (opcode in ("copy", "transpose", "copy-done")
+             or opcode == "fusion" and re.match(r"copy|transpose", name))]
+
+
+@pytest.mark.parametrize(
+    "B, S, h, d, causal, window, packed, parent_relayouts", [
+        (1, 8192, 32, 128, True, 4096, False, 15),   # the Mistral cells
+        (32, 512, 16, 64, False, None, True, 9),     # BERT-large, padding
+    ], ids=["mistral7b_row", "bert_large_segments"])
+def test_flash_vjp_compiles_for_v5e(one_chip, B, S, h, d, causal, window,
+                                    packed, parent_relayouts):
+    """``jax.vjp`` of ``flash_attention`` at a training cell's shape:
+    Mosaic takes the backward's plan under ``RESIDENT_VMEM_LIMIT_BYTES``,
+    every backward call is named ``flash_bwd*`` (what
+    ``attention_share.train`` matches), the statistics reach it as
+    lane-dense rows, and no more planes are re-laid out around the calls
+    than in the lowering this backward replaced (PR 32 counted the
+    parent's)."""
+    import importlib
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    x = jax.ShapeDtypeStruct((B, S, h, d), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+
+    def fn(q, k, v, do, segment_ids):
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal, window=window,
+            segment_ids=segment_ids if packed else None, interpret=False),
+            q, k, v)
+        return (out,) + vjp(do)
+
+    text = jax.jit(fn).lower(x, x, x, x, seg).compile().as_text()
+    calls = re.findall(r'^\s*%?([\w.\-]+) = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"', text, re.M)
+    backward = [c for c in calls if "flash_fwd" not in c]
+    assert len(calls) == 2 and len(backward) == 1, calls
+    assert "flash_bwd" in backward[0], calls
+    # lse and Δ: [B·h, 1, S] rows, not [B·h, S, 1] columns on 128 lanes
+    line = next(ln for ln in text.splitlines()
+                if f"%{backward[0]} = " in ln)
+    assert line.count(f"f32[{B * h},1,{S}]") >= 2, line[:400]
+    assert f"f32[{B * h},{S},1]" not in line
+    moved = _plane_relayouts(text, B, S, h, d)
+    assert len(moved) <= parent_relayouts, moved

@@ -464,9 +464,9 @@ def test_every_kernel_of_the_two_main_paths_is_named():
                 kw = {k.arg: k.value for k in node.keywords}
                 assert "name" in kw, f"{f}:{node.lineno}"
                 names.append(kw["name"].value)
-    assert len(names) == len(set(names)) == 7
-    assert {"paged_decode_attention", "flash_fwd", "flash_bwd_dq",
-            "flash_bwd_dkv"} <= set(names)
+    assert len(names) == len(set(names)) == 6
+    assert {"paged_decode_attention", "flash_fwd",
+            "flash_bwd"} <= set(names)
     # the gate looks for the same names in the lowered programs on the chip
     gate = (root.parents[2] / "chip_smoke.py").read_text()
     expected = ast.literal_eval(
