@@ -3,14 +3,16 @@
 Reference: ``deepspeed/inference/v2/model_implementations/`` [K] ships one
 implementation per family (llama, mistral, mixtral, opt, ...) that plugs
 into the shared ragged engine/KV machinery.  The TPU-native equivalent is
-this small hook protocol: the engine owns paging, scheduling and the two
+this small hook protocol: the engine owns paging, scheduling and the
 compiled programs; an adapter owns exactly the architecture deltas —
 embedding (rotary vs learned positions), norm flavor (RMS vs LayerNorm),
 QKV projection (biasless vs biased), and the FFN/residual block.
 
-All hooks operate on FLAT token batches ``[N, ...]`` so the same adapter
-serves both compiled programs (prefill rows are flattened ``[Bp*C]``,
-decode is ``[B]``).  Positions come in as an ``[N]`` int32 vector.
+All hooks operate on FLAT token batches ``[N, ...]`` and are row-wise, so
+the same adapter serves every program: a burst's ``[B]`` decode rows, and
+the step that carries a round's chunks, whose ``[Bp*C]`` flattened chunk
+rows and ``[B]`` decode rows go through each hook together.  Positions come
+in as an ``[N]`` int32 vector.
 
 What ``layers(params)`` returns is the ``xs`` of the engine's layer scan,
 and **a scan slices whatever its ``xs`` hold**: each step gets layer

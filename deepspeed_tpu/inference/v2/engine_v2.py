@@ -6,28 +6,34 @@ cache and Dynamic SplitFuse scheduling (SURVEY §2.5 row "Inference v2").
 
 TPU-first: instead of ragged kernels over dynamic shapes, the engine
 compiles a small number of fixed-shape programs and reuses them for any
-request mix (XLA traces once; raggedness lives in int32 metadata):
+request mix (XLA traces once; raggedness lives in int32 metadata).  A round
+is ONE program call:
 
-* ``prefill_batch`` — ``chunk`` prompt tokens for each of up to
-  ``prefill_batch`` sequences at once, writing KV pages through each row's
-  block table (Dynamic SplitFuse = long prompts become several chunk calls
-  interleaved with decodes; round 3 batches the chunks across sequences).
-* ``decode_burst``  — ``k`` successive decode steps for all
-  ``max_batch_slots`` sequences in ONE device program: sampling happens
+* ``decode_burst`` of ``k`` steps — ``k`` successive decode steps for all
+  ``max_batch_slots`` sequences in one device program: sampling happens
   in-graph (greedy or temperature) and only ``[k, B]`` int32 token ids
   return to the host — no per-token logits round-trip.
   Page tables are fully reserved at admission (prompt + generation budget),
   so a burst never needs host page allocation mid-flight.
+* the ONE-step decode program **carrying the round's prefill chunks** —
+  ``chunk`` prompt tokens for each of up to ``prefill_batch`` sequences ride
+  in the step as rows in front of the decode rows, writing KV pages
+  through each row's block table (Dynamic SplitFuse composes a forward
+  pass from decode tokens plus prompt chunks: long prompts become several
+  chunks, each beside a decode step).  Every layer's weights, every active
+  expert and the head cross HBM once for all the rows; a separate prefill
+  call beside a one-step decode call read them twice (PERF.md §6, PR 37).
+  While nothing decodes yet the decode rows are dead, as idle slots are.
 
 Architecture deltas (norms, positions, FFN, head) live in
 ``adapters.ModelAdapterV2`` — llama/mistral/mixtral AND OPT serve on the
 same engine (reference keeps per-arch model implementations under
 ``inference/v2/model_implementations`` [K]).
 
-Both programs donate the pools (one of K and V for each kind of attention
-layer the adapter states: ``adapters.AttentionKind``) and carry them WHOLE
-through their layer scan, addressed by ``(layer, page)``: a step's rows
-(decode) or pages (prefill) are scattered into a pool in place, and
+Every program donates the pools (one of K and V for each kind of attention
+layer the adapter states: ``adapters.AttentionKind``) and carries them WHOLE
+through its layer scan, addressed by ``(layer, page)``: a step's rows
+(decode) and pages (chunks) are scattered into a pool in place, and
 attention reads pages ``l·N + page`` of its flat view.  No program forms a
 layer's ``pool[l]`` (see ``_layer_step`` for why), so KV updates are
 in-place in HBM and no call moves more of the cache than it reads or
@@ -39,25 +45,23 @@ whose layers are all alike has one kind, no leading layer and a period of
 one.  A kind that recycles its pages (``ring``) has a pool of rings, one a
 live sequence (``KVCacheConfig.ring_blocks``; the scheduler hands them
 out): logical page ``j`` of a sequence is page ``j % ring_blocks`` of its
-ring, so the paged kernel's window walk and the prefill's gather find the
+ring, so the paged kernel's window walk and the chunks' gather find the
 window's keys where the block table of a growing sequence would have put
 them, and the pages behind the window are overwritten.
 
-A round that has both a prefill call and a decode step dispatches BOTH
-before it waits for either, and ``step_ahead`` (the serving front-end's
-entry) returns with the decode call still running: the next call fetches
-and commits it before it plans.  The device goes from one program to the
-next, and runs while the front-end delivers and admits.  The programs take
-their small arguments as NumPy arrays (one transfer inside the call, not
-an upload each) and their sampling keys from a chain split 256 links at a
-time (``_next_key``).
+``step_ahead`` (the serving front-end's entry) returns with the round's
+call still running: the next call fetches it and commits its chunks and
+its tokens before it plans.  The device runs while the front-end delivers
+and admits.  The programs take their small arguments as NumPy arrays (one
+transfer inside the call, not an upload each) and their sampling keys from
+a chain split 256 links at a time (``_next_key``).
 
-Prefill cost is O(pages allocated so far), not O(max_seq_len): each
-chunk call gathers/masks only ``kb`` pages per row, where ``kb`` is the
-smallest power-of-two page bucket covering the batch's deepest
-``start_pos + chunk`` (VERDICT r3 item 6 — the round-2 "O(max_seq_len)
-per chunk" cost note is gone).  Buckets are static shapes, so at most
-``log2(max_blocks/chunk_blocks)+1`` prefill programs ever compile.
+A chunk's cost is O(pages allocated so far), not O(max_seq_len): its rows
+gather/mask only ``kb`` pages each, where ``kb`` is the smallest
+power-of-two page bucket covering the round's deepest ``start_pos +
+chunk`` (VERDICT r3 item 6 — the round-2 "O(max_seq_len) per chunk" cost
+note is gone).  Buckets are static shapes, so at most
+``log2(max_blocks/chunk_blocks)+1`` one-step programs ever compile.
 """
 
 from __future__ import annotations
@@ -77,7 +81,7 @@ from ...telemetry.perf import get_compile_tracker, tracked_jit
 from ...utils.logging import log_dist
 from .adapters import AttentionKind, ModelAdapterV2, make_adapter
 from .kv_cache import KVCacheConfig, init_kv_pool
-from .scheduler import RaggedScheduler, Request
+from .scheduler import RaggedScheduler, Request, RequestState
 
 
 class _null_ctx:
@@ -202,15 +206,11 @@ class RaggedInferenceEngineV2:
         self.chunk = prefill_chunk
         self.prefill_batch = max(1, prefill_batch)
         self.decode_burst = max(1, decode_burst)
-        self._prefill = tracked_jit(self._prefill_batch_fn,
-                                    "inference_v2/prefill",
-                                    tracker=get_compile_tracker(),
-                                    donate_argnums=(1,),
-                                    static_argnames=("kb",))
         self._decode_jits: Dict[int, Callable] = {}
         self._reseed(0)
-        #: the decode call ``step_ahead`` left running: (its requests, its
-        #: steps, its outputs on the device, the EOS id to accept under)
+        #: the call ``step_ahead`` left running: (its chunks, its decoding
+        #: requests, its steps, its outputs on the device, the EOS id to
+        #: accept under)
         self._inflight: Optional[Tuple] = None
         #: MoE serving telemetry (ISSUE 19): when the model routes through
         #: a MOELayer, the decode program additionally returns the gate's
@@ -228,9 +228,10 @@ class RaggedInferenceEngineV2:
         #: host-side rolling per-expert load (fractions, sum≈1) and the
         #: derived max/mean imbalance — the router's placement signal
         self.last_moe_stats: Optional[Dict[str, Any]] = None
-        #: per program ("prefill", "decode"): (entry name, width) of each
-        #: column block of the stats it packs, written as it is traced
-        self._moe_columns: Dict[str, List[Tuple[str, int]]] = {}
+        #: (entry name, width) of each column block of the stats a decode
+        #: program packs (the model's, whatever the program's rows and
+        #: steps), written as one is traced
+        self._moe_columns: List[Tuple[str, int]] = []
         log_dist(f"inference v2: pool={self.cache_config.num_blocks}"
                  f"x{self.cache_config.block_size} tokens, "
                  f"slots={max_batch_slots}, chunk={prefill_chunk}"
@@ -244,8 +245,9 @@ class RaggedInferenceEngineV2:
     def _layer_step(self, params, lp, l, kind, lk, pools, x_flat,
                     positions_flat, write_fn, attend_fn):
         """Shared per-layer skeleton: qkv → KV write → attention →
-        post-attn block.  ``write_fn``/``attend_fn`` differ between the
-        prefill and decode programs.
+        post-attn block.  ``write_fn``/``attend_fn`` are the rows' own:
+        :meth:`_decode_rows`, :meth:`_chunk_rows`, or both
+        (:meth:`_beside`).
 
         ``pools`` holds, for each attention kind, the WHOLE pool ``{"k",
         "v"}: [layers of the kind, N, bs, kv_h, d]``, a carry of the layer
@@ -295,19 +297,34 @@ class RaggedInferenceEngineV2:
             plane = plane.at[(p * layers + l if p else l,) + where].set(part)
         return plane
 
-    @property
-    def _page_matrices(self) -> bool:
-        """How the prefill program addresses a pool: as the paged kernel
-        does, page matrices ``[blocks·N, bs·kv_h, w]`` (a bitcast of the
-        carried buffer), unless the kv-head dim is sharded over ``tensor``,
-        which that view would merge with the tokens: then ``(block,
-        page)`` of the pool as it is held.  With no custom call in the
-        prefill program to hold the layout, XLA:TPU re-lays a carried pool
-        of fewer KV heads than a vreg has sublanes out tokens-minor on the
-        way in and back on the way out (2.5 GB each way of every call at
-        the hybrid cell's size; the TP prefill still does, PERF.md §7);
-        addressed as page matrices there is no such choice to make."""
-        return self._tp == 1
+    def _per_kv_shard(self, fn, in_specs, out_specs):
+        """``fn`` over a pool's planes (``"pool"``), rows of heads
+        (``"heads"``) and replicated arguments (``"all"``), as it runs:
+        as it is on one chip; under tensor-parallel serving on each chip's
+        KV heads and the query heads of their groups (heads are
+        independent), through a ``shard_map`` over the whole mesh.  So the
+        chunk rows address the LOCAL pool as the paged kernel does, page
+        matrices ``[blocks·N, bs·kv_h, w]`` (a bitcast of the carried
+        buffer).  Under GSPMD that view would merge the sharded head dim
+        with the tokens, and the ``(block, page)`` view it forced made
+        XLA:TPU re-lay the carried pool out tokens-minor around every
+        access (a pool of fewer KV heads than a vreg has sublanes: 2.5 GB
+        each way of every call at the hybrid cell's size before PR 31, and
+        inside every layer of the step that carries chunks, where the
+        kernel's custom call pins the other layout)."""
+        if self._tp == 1:
+            return fn
+        from jax.sharding import PartitionSpec as P
+
+        from ...parallel.mesh import AXIS_TENSOR
+        from ...utils.jax_compat import shard_map
+
+        spec = {"pool": P(None, None, None, AXIS_TENSOR, None),
+                "heads": P(None, AXIS_TENSOR, None), "all": P()}
+        return shard_map(
+            fn, mesh=self.mesh, in_specs=jax.tree.map(spec.get, in_specs),
+            out_specs=jax.tree.map(spec.get, out_specs), check_vma=False,
+            axis_names=set(self.mesh.axis_names))
 
     def _ring_pages(self, ring_base, logical):
         """Pages of a recycled pool: ``ring_base [R]`` (a row's ring's
@@ -321,7 +338,7 @@ class RaggedInferenceEngineV2:
 
     def _scan_layers(self, params, pools, x, positions_flat, write_fn,
                      attend_fn):
-        """The layers of both programs: the pattern's leading layers, then
+        """The layers of every program: the pattern's leading layers, then
         a scan over its periods.  Carry: the activations and the pools;
         ``xs``: what the adapter's ``layers(params)`` holds, a period's
         slice a step, and the period's index; ``ys``: the MoE gate's
@@ -369,25 +386,24 @@ class RaggedInferenceEngineV2:
         numerics.scan_collect(stats)  # keep the per-period axis
         return x, pools
 
-    def _prefill_batch_fn(self, params, pool, tokens, tables, start_pos,
-                          last_idx, temperature, key, rings=None, *, kb):
-        """Up to ``Bp`` sequences' chunks at once: ``tokens [Bp, C]`` at
-        positions ``start_pos[r] + [0..C)``; rows beyond the live chunk
-        count carry all-zero tables (page 0 = scratch).  ``kb`` (static)
-        is the page bucket this program attends over — the first ``kb``
-        pages of each row's table cover every key written so far, so the
-        gather/mask is O(allocated), not O(max_seq_len).  ``rings [Bp]``:
-        each row's ring's first page, where a kind recycles (else None);
-        such a kind gathers the window's pages and the chunk's and no
-        bucket.  Returns (sampled token ids ``[Bp]``, pools, the gate's
-        stats packed or None)."""
+    def _chunk_rows(self, tokens, tables, start_pos, rings, kb):
+        """A round's prefill chunks as rows of the one-step program: up to
+        ``Bp`` sequences' chunks, ``tokens [Bp, C]`` at positions
+        ``start_pos[r] + [0..C)``; rows beyond the live chunk count carry
+        all-zero tables (page 0 = scratch).  ``kb`` (static) is the page
+        bucket they attend over — the first ``kb`` pages of each row's
+        table cover every key written so far, so the gather/mask is
+        O(allocated), not O(max_seq_len).  ``rings [Bp]``: each row's
+        ring's first page, where a kind recycles (else None); such a kind
+        gathers the window's pages and the chunk's and no bucket.  Returns
+        (positions ``[Bp·C]``, the rows' part ``(Bp·C, write_fn,
+        attend_fn)``: what :meth:`_layer_step` needs for them,
+        :meth:`_beside`)."""
         ad = self.adapter
         Bp, C = tokens.shape
         bs = self.cache_config.block_size
         mb = int(kb)  # attend over the bucket, not the full table width
         positions = start_pos[:, None] + jnp.arange(C)[None, :]  # [Bp, C]
-        pos_flat = positions.reshape(-1)
-        x = ad.embed(params, tokens.reshape(-1), pos_flat)  # [Bp*C, H]
         page_cursor = start_pos // bs  # chunks & starts are page-aligned
 
         from ...ops.masks import local_attention_mask
@@ -430,29 +446,30 @@ class RaggedInferenceEngineV2:
             """A pool's ``plane`` array ``[blocks, N, bs, kv_h, w]`` as page
             matrices ``[blocks·N, bs·kv_h, w]``: the paged kernel's own
             view, a bitcast, which every pool is scattered into and
-            gathered from (:attr:`_page_matrices`)."""
+            gathered from (:meth:`_per_kv_shard`)."""
             blocks, pages, _, _, w = plane.shape
             return plane.reshape(blocks * pages, -1, w)
 
         def write_fn(pool, kind, l, kk, vv):
             # whole pages, scattered at (l, page) into the carried pool
-            matrices = self._page_matrices
-
-            def written_into(plane, rows):
+            def written_into(plane, rows, l, pages):
                 rows = rows.reshape((Bp * (C // bs), bs) + rows.shape[1:])
-                view = as_pages(plane) if matrices else plane
+                view = as_pages(plane)
                 for p, part in enumerate(self._planes(rows, plane)):
                     block = p * kind.layers + l if p else l
-                    if matrices:
-                        view = view.at[written[kind.name]
-                                       + block * plane.shape[1]].set(
-                            part.reshape((-1,) + view.shape[1:]))
-                    else:
-                        view = view.at[block, written[kind.name]].set(part)
+                    view = view.at[pages + block * plane.shape[1]].set(
+                        part.reshape((-1,) + view.shape[1:]))
                 return view.reshape(plane.shape)
 
-            return {"k": written_into(pool["k"], kk),
-                    "v": written_into(pool["v"], vv)}
+            k, v = self._per_kv_shard(
+                lambda k, v, kk, vv, l, pages: (
+                    written_into(k, kk, l, pages),
+                    written_into(v, vv, l, pages)),
+                ("pool", "pool", "heads", "heads", "all", "all"),
+                ("pool", "pool"))(pool["k"], pool["v"], kk, vv,
+                                  jnp.asarray(l, jnp.int32),
+                                  written[kind.name])
+            return {"k": k, "v": v}
 
         def attend_fn(q, pool, kind, l, sink):
             # gather only the attended pages (a bucket: every key written
@@ -460,55 +477,148 @@ class RaggedInferenceEngineV2:
             # attend chunk-queries over them — O(allocated), not
             # O(max_seq_len).  One gather out of the carried buffer's flat
             # view, never a layer sliced out first
-            keys = attended[kind.name].shape[1] * bs
-
-            def gathered(name, d):
-                plane = pool[name]
-                view = as_pages(plane) if self._page_matrices \
-                    else plane.reshape((-1,) + plane.shape[2:])
-                parts = [view[attended[kind.name]
-                              + (p * kind.layers + l if p else l)
+            def gathered(plane, l, pages, d):
+                view = as_pages(plane)
+                parts = [view[pages + (p * kind.layers + l if p else l)
                               * plane.shape[1]]
                          for p in range(plane.shape[0] // kind.layers)]
                 rows = parts[0] if len(parts) == 1 else jnp.concatenate(
                     parts, axis=-1)[..., :d]
-                return rows.reshape(Bp, keys, kind.kv_heads, d)
+                return rows.reshape(Bp, pages.shape[1] * bs, -1, d)
 
-            kf = gathered("k", kind.k_dim)
-            vf = gathered("v", kind.v_dim)
-            n_rep = ad.num_heads // kind.kv_heads
-            if n_rep > 1:
-                kf = jnp.repeat(kf, n_rep, axis=2)
-                vf = jnp.repeat(vf, n_rep, axis=2)
-            qb = q.reshape(Bp, C, ad.num_heads, kind.k_dim)
-            scale = 1.0 / np.sqrt(kind.k_dim)
-            s = jnp.einsum("bqhd,bkhd->bhqk", qb, kf
-                           ).astype(jnp.float32) * scale
-            s = jnp.where(masks[kind.name], s, -1e30)
-            if sink is None:
-                p = jax.nn.softmax(s, axis=-1).astype(ad.dtype)
-            else:
-                # the sink: one more column, which carries no value
-                beside = jnp.broadcast_to(
-                    sink.astype(jnp.float32)[None, :, None, None],
-                    s.shape[:3] + (1,))
-                p = jax.nn.softmax(jnp.concatenate([s, beside], axis=-1),
-                                   axis=-1)[..., :-1].astype(ad.dtype)
-            attn = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
-            return attn.reshape(Bp * C, ad.num_heads, kind.v_dim)
+            def attended_over(q, k, v, l, pages, mask):
+                kf = gathered(k, l, pages, kind.k_dim)
+                vf = gathered(v, l, pages, kind.v_dim)
+                heads = q.shape[1]
+                n_rep = heads // kf.shape[2]
+                if n_rep > 1:
+                    kf = jnp.repeat(kf, n_rep, axis=2)
+                    vf = jnp.repeat(vf, n_rep, axis=2)
+                qb = q.reshape(Bp, C, heads, kind.k_dim)
+                scale = 1.0 / np.sqrt(kind.k_dim)
+                s = jnp.einsum("bqhd,bkhd->bhqk", qb, kf
+                               ).astype(jnp.float32) * scale
+                s = jnp.where(mask, s, -1e30)
+                if sink is None:
+                    p = jax.nn.softmax(s, axis=-1).astype(ad.dtype)
+                else:
+                    # the sink: one more column, which carries no value
+                    beside = jnp.broadcast_to(
+                        sink.astype(jnp.float32)[None, :, None, None],
+                        s.shape[:3] + (1,))
+                    p = jax.nn.softmax(
+                        jnp.concatenate([s, beside], axis=-1),
+                        axis=-1)[..., :-1].astype(ad.dtype)
+                attn = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
+                return attn.reshape(Bp * C, heads, kind.v_dim)
 
-        x, pool = self._scan_layers(params, pool, x, pos_flat, write_fn,
-                                    attend_fn)
-        x = ad.finalize(params, x).reshape(Bp, C, -1)
-        last_h = jnp.take_along_axis(
-            x, last_idx[:, None, None], axis=1)[:, 0]  # [Bp, H]
-        logits = ad.logits(params, last_h)  # [Bp, V]
-        return (_sample(logits, temperature, key), pool,
-                self._pack_moe_stats("prefill"))
+            if sink is not None and self._tp > 1:
+                # a head's sink would have to be split with the heads
+                raise NotImplementedError(
+                    "a sink under tensor-parallel serving")
+            return self._per_kv_shard(
+                attended_over,
+                ("heads", "pool", "pool", "all", "all", "all"), "heads")(
+                    q, pool["k"], pool["v"], jnp.asarray(l, jnp.int32),
+                    attended[kind.name], masks[kind.name])
+
+        return positions.reshape(-1), (Bp * C, write_fn, attend_fn)
+
+    def _decode_rows(self, tables_of, wp):
+        """The decode rows of a step: row ``r`` writes its K and V at
+        position ``wp[r]`` through its tables (``tables_of``: a kind's
+        name → ``[B, max_blocks]``) and attends over the ``wp[r] + 1`` keys
+        so far through the paged kernel.  Returns the rows' part ``(B,
+        write_fn, attend_fn)``: what :meth:`_layer_step` needs for them
+        (:meth:`_beside`)."""
+        ad = self.adapter
+        bs = self.cache_config.block_size
+        offsets = wp % bs
+        page_ids = {name: table[jnp.arange(wp.shape[0]), wp // bs]
+                    for name, table in tables_of.items()}
+
+        def write_fn(pool, kind, l, kk, vv):
+            # one scatter of [B, kv_h, d] rows at (l, page, offset)
+            where = (page_ids[kind.name], offsets)
+            return {"k": self._scatter(pool["k"], l, where, kk),
+                    "v": self._scatter(pool["v"], l, where, vv)}
+
+        def attend_fn(q, pool, kind, l, sink):
+            # the kernel fetches pages from HBM by page id: it gets
+            # the whole pool's flat view, and the layer's offset is
+            # folded into the tables it prefetches anyway
+            flat = self._flat_pool(pool)
+            pages = pool["k"].shape[1]
+            k_planes = pool["k"].shape[0] // kind.layers
+            if pool["v"].shape[0] != kind.layers:
+                raise NotImplementedError(
+                    f"V rows of {kind.v_dim}: wider than one plane")
+            layer_tables = tables_of[kind.name] + l * pages
+            # what paged_decode_attention will run for these shapes
+            # on this platform, by its own test
+            impl = paged_decode_impl(
+                ad.num_heads // self._tp, kind.kv_heads // self._tp,
+                None, flat["k"].shape[-1], flat["v"].shape[-1])
+            if impl == "reference" and jax.default_backend() == "tpu":
+                from ...telemetry import get_telemetry
+
+                get_telemetry().inc_counter(
+                    "inference/attn/reference_fallbacks",
+                    help="layers traced on a TPU whose paged decode "
+                         "attention runs the jax.numpy reference: "
+                         "the kernel refused their shapes")
+            if self._tp > 1:
+                # the Pallas kernel runs PER TP SHARD via an explicit
+                # shard_map over the kv-head axis (heads independent,
+                # zero cross-rank comm)
+                from ...ops.pallas.paged_attention import (
+                    paged_decode_attention_tp)
+
+                if sink is not None:
+                    raise NotImplementedError(
+                        "a sink under tensor-parallel serving")
+                self.last_attn_path = f"{impl}_tp_shard_map"
+                return paged_decode_attention_tp(
+                    q, flat["k"], flat["v"], layer_tables, wp + 1,
+                    mesh=self.mesh, window=kind.window)
+            self.last_attn_path = impl
+            # plane p of a layer's K lies a whole plane (every layer's
+            # pages) further on than plane p - 1
+            return paged_decode_attention(
+                q, flat["k"], flat["v"], layer_tables, wp + 1,
+                window=kind.window, sink=sink, k_planes=k_planes,
+                plane_stride=kind.layers * pages)
+
+        return wp.shape[0], write_fn, attend_fn
+
+    @staticmethod
+    def _beside(parts):
+        """``parts``: ``(rows, write_fn, attend_fn)`` of each group of a
+        program's rows, in the rows' order → the pair over all of them: a
+        layer's K and V written group by group into the one carried pool,
+        each group's queries attended its own way, the results
+        concatenated.  The groups read nothing of each other's writes
+        (different requests, different pages), so their order is free."""
+        if len(parts) == 1:
+            return parts[0][1:]
+        ends = np.cumsum([rows for rows, _, _ in parts]).tolist()
+        spans = list(zip([0] + ends[:-1], ends))
+
+        def write_fn(pool, kind, l, kk, vv):
+            for (lo, hi), (_, write, _) in zip(spans, parts):
+                pool = write(pool, kind, l, kk[lo:hi], vv[lo:hi])
+            return pool
+
+        def attend_fn(q, pool, kind, l, sink):
+            return jnp.concatenate(
+                [attend(q[lo:hi], pool, kind, l, sink)
+                 for (lo, hi), (_, _, attend) in zip(spans, parts)])
+
+        return write_fn, attend_fn
 
     def _decode_burst_fn(self, params, pool, tokens, kv_lens, tables,
-                         max_pos, temperature, key, rings=None, *,
-                         n_steps: int):
+                         max_pos, temperature, key, rings=None, chunks=None,
+                         *, n_steps: int, kb: Optional[int] = None):
         """``n_steps`` decode iterations entirely on device: each step
         writes KV at ``kv_lens`` through ``tables``, attends via the paged
         kernel, samples the next token in-graph and feeds it back.  Write
@@ -516,93 +626,66 @@ class RaggedInferenceEngineV2:
         the burst only scribbles its own reserved pages; the host discards
         its surplus tokens).  ``rings [B]``: each row's ring's first page,
         where a kind recycles (else None): such a kind's block table is
-        the ring, repeated.  Returns (token ids ``[n_steps, B]``, pools,
-        the gate's stats packed or None)."""
+        the ring, repeated.
+
+        ``chunks`` (the one-step program only): the round's prefill chunks
+        ``(tokens [Bp, C], tables, start_pos, last_idx, rings)``, whose
+        ``Bp·C`` rows ride in the step IN FRONT of the decode rows
+        (:meth:`_chunk_rows` under the page bucket ``kb``): every layer's
+        weights, and the head's, are read once for both, and the chunks'
+        last valid rows are sampled beside the decode rows.
+
+        Returns (token ids ``[n_steps, B]``, pools, the gate's stats
+        packed or None, the chunks' sampled tokens ``[Bp]`` or None)."""
         from ...telemetry import numerics
 
         ad = self.adapter
         B = tokens.shape[0]
-        bs = self.cache_config.block_size
         tables_of = {
             kind.name: self._ring_pages(
                 rings, jnp.broadcast_to(jnp.arange(tables.shape[1])[None, :],
                                         tables.shape))
             if kind.ring else tables for kind in self.kinds.values()}
+        if chunks is not None:
+            if n_steps != 1:
+                raise ValueError("chunks ride in the one-step program")
+            c_tokens, c_tables, c_start, c_last, c_rings = chunks
+            Bp, C = c_tokens.shape
+            c_pos, riding = self._chunk_rows(c_tokens, c_tables, c_start,
+                                             c_rings, kb)
 
         def one_step(carry, key):
             tokens, kv_lens, pool = carry
             step_mark = numerics.scan_mark()
             wp = jnp.minimum(kv_lens, max_pos)  # [B] write positions
-            offsets = wp % bs
-            page_ids = {name: table[jnp.arange(B), wp // bs]
-                        for name, table in tables_of.items()}
-            x = ad.embed(params, tokens, wp)
-
-            def write_fn(pool, kind, l, kk, vv):
-                # one scatter of [B, kv_h, d] rows at (l, page, offset)
-                where = (page_ids[kind.name], offsets)
-                return {"k": self._scatter(pool["k"], l, where, kk),
-                        "v": self._scatter(pool["v"], l, where, vv)}
-
-            def attend_fn(q, pool, kind, l, sink):
-                # the kernel fetches pages from HBM by page id: it gets
-                # the whole pool's flat view, and the layer's offset is
-                # folded into the tables it prefetches anyway
-                flat = self._flat_pool(pool)
-                pages = pool["k"].shape[1]
-                k_planes = pool["k"].shape[0] // kind.layers
-                if pool["v"].shape[0] != kind.layers:
-                    raise NotImplementedError(
-                        f"V rows of {kind.v_dim}: wider than one plane")
-                layer_tables = tables_of[kind.name] + l * pages
-                # what paged_decode_attention will run for these shapes
-                # on this platform, by its own test
-                impl = paged_decode_impl(
-                    ad.num_heads // self._tp, kind.kv_heads // self._tp,
-                    None, flat["k"].shape[-1], flat["v"].shape[-1])
-                if impl == "reference" and jax.default_backend() == "tpu":
-                    from ...telemetry import get_telemetry
-
-                    get_telemetry().inc_counter(
-                        "inference/attn/reference_fallbacks",
-                        help="layers traced on a TPU whose paged decode "
-                             "attention runs the jax.numpy reference: "
-                             "the kernel refused their shapes")
-                if self._tp > 1:
-                    # the Pallas kernel runs PER TP SHARD via an explicit
-                    # shard_map over the kv-head axis (heads independent,
-                    # zero cross-rank comm)
-                    from ...ops.pallas.paged_attention import (
-                        paged_decode_attention_tp)
-
-                    if sink is not None:
-                        raise NotImplementedError(
-                            "a sink under tensor-parallel serving")
-                    self.last_attn_path = f"{impl}_tp_shard_map"
-                    return paged_decode_attention_tp(
-                        q, flat["k"], flat["v"], layer_tables, wp + 1,
-                        mesh=self.mesh, window=kind.window)
-                self.last_attn_path = impl
-                # plane p of a layer's K lies a whole plane (every layer's
-                # pages) further on than plane p - 1
-                return paged_decode_attention(
-                    q, flat["k"], flat["v"], layer_tables, wp + 1,
-                    window=kind.window, sink=sink, k_planes=k_planes,
-                    plane_stride=kind.layers * pages)
-
-            x, pool = self._scan_layers(params, pool, x, wp, write_fn,
-                                        attend_fn)
+            ids, pos = tokens, wp
+            parts = [self._decode_rows(tables_of, wp)]
+            if chunks is not None:
+                ids = jnp.concatenate([c_tokens.reshape(-1), tokens])
+                pos = jnp.concatenate([c_pos, wp])
+                parts.insert(0, riding)
+            x = ad.embed(params, ids, pos)
+            x, pool = self._scan_layers(params, pool, x, pos,
+                                        *self._beside(parts))
+            if chunks is not None:
+                # of a chunk's rows only the last valid one is sampled
+                last = jnp.take_along_axis(
+                    x[:Bp * C].reshape(Bp, C, -1), c_last[:, None, None],
+                    axis=1)[:, 0]
+                x = jnp.concatenate([last, x[Bp * C:]])
             x = ad.finalize(params, x)
-            logits = ad.logits(params, x)  # [B, V]
-            nxt = _sample(logits, temperature, key)
+            sampled = _sample(ad.logits(params, x), temperature, key)
+            nxt = sampled[-B:]
+            firsts = sampled[:-B] if chunks is not None else None
             step_stats = numerics.scan_drain(step_mark)
-            return (nxt, kv_lens + 1, pool), (nxt, step_stats)
+            return (nxt, kv_lens + 1, pool), (nxt, firsts, step_stats)
 
         keys = jax.random.split(key, n_steps)
-        (_, _, pool), (toks, stats) = jax.lax.scan(
+        (_, _, pool), (toks, firsts, stats) = jax.lax.scan(
             one_step, (tokens, kv_lens, pool), keys)
         numerics.scan_collect(stats, combine=True)  # mean over the burst
-        return toks, pool, self._pack_moe_stats("decode")
+        return (toks, pool, self._pack_moe_stats(),
+                None if firsts is None else firsts[0])
 
     def _decode(self, n_steps: int) -> Callable:
         fn = self._decode_jits.get(n_steps)
@@ -612,7 +695,7 @@ class RaggedInferenceEngineV2:
                              "inference_v2/decode_burst",
                              tracker=get_compile_tracker(),
                              static_context={"n_steps": n_steps},
-                             donate_argnums=(1,))
+                             donate_argnums=(1,), static_argnames=("kb",))
             self._decode_jits[n_steps] = fn
         return fn
 
@@ -626,7 +709,7 @@ class RaggedInferenceEngineV2:
 
     # -- MoE serving telemetry -----------------------------------------
 
-    def _pack_moe_stats(self, program: str) -> Optional[jnp.ndarray]:
+    def _pack_moe_stats(self) -> Optional[jnp.ndarray]:
         """Inside a program, after its layer scan: the active collector's
         entries (each with the axis the scan gave it: ``[periods]`` or
         ``[periods, E]``, one entry for each sparse layer of a period) as
@@ -634,7 +717,7 @@ class RaggedInferenceEngineV2:
         fetches the router's stats in one transfer beside the tokens
         (fetched entry by entry they cost the serving round 7.5 ms of
         host, PERF.md PR 27).  Which columns hold what is a fact of the
-        trace, kept for ``program`` in ``_moe_columns``."""
+        trace, kept in ``_moe_columns``."""
         from ...telemetry import numerics
 
         coll = numerics.active()
@@ -654,19 +737,18 @@ class RaggedInferenceEngineV2:
             block = entries[0] if len(entries) == 1 else jnp.stack(
                 [e.reshape(periods, -1) for e in entries], axis=1)
             blocks.append((name, block.reshape(periods * len(entries), -1)))
-        self._moe_columns[program] = [(name, int(b.shape[1]))
-                                      for name, b in blocks]
+        self._moe_columns = [(name, int(b.shape[1])) for name, b in blocks]
         return jnp.concatenate([b for _, b in blocks], axis=1)
 
-    def _ingest_moe_stats(self, packed: np.ndarray, tel: Any, program: str,
+    def _ingest_moe_stats(self, packed: np.ndarray, tel: Any,
                           steps: int = 1) -> None:
-        """Host side of one call's gate stats.  Every call feeds the
-        dropless layer's counters; a decode burst (``steps`` steps, the
-        stats their mean) also sets what the router and autoscaler read:
-        per-expert load gauges and the imbalance/drop scalars.  Telemetry
-        must never kill a serving round: a layout that does not fit the
-        array, or an entry the gate did not report, is skipped."""
-        layout = self._moe_columns.get(program, ())
+        """Host side of one call's gate stats (``steps`` steps, the stats
+        their mean; a step that carries chunks counts their rows too): the
+        dropless layer's counters, and what the router and autoscaler
+        read: per-expert load gauges and the imbalance/drop scalars.
+        Telemetry must never kill a serving round: a layout that does not
+        fit the array, or an entry the gate did not report, is skipped."""
+        layout = self._moe_columns
         if packed.ndim != 2 or packed.shape[1] != sum(w for _, w in layout):
             return
         cols, at = {}, 0
@@ -698,7 +780,7 @@ class RaggedInferenceEngineV2:
                      "the grouped matmul reads), summed over layers and "
                      "steps")
         load = cols.get("moe/load")
-        if program != "decode" or load is None:
+        if load is None:
             return
         load = load.astype(np.float64)                        # [L, E]
         mean = load.mean(axis=1)
@@ -745,18 +827,15 @@ class RaggedInferenceEngineV2:
         split a call is two dispatches a round on the host's critical
         path)."""
         if not self._subkeys:
-            self._refill_keys()
+            self._key, subs = _split_chain(self._key)
+            self._subkeys = list(np.asarray(subs)[::-1])
         return self._subkeys.pop()
-
-    def _refill_keys(self) -> None:
-        self._key, subs = _split_chain(self._key)
-        self._subkeys = list(np.asarray(subs)[::-1]) + self._subkeys
 
     def _reseed(self, seed: int) -> None:
         self._key, self._subkeys = jax.random.PRNGKey(seed), []
 
     def _prefill_bucket(self, chunks) -> int:
-        """Static page-bucket for this prefill call: smallest power-of-two
+        """Static page-bucket for a round's chunks: smallest power-of-two
         multiple of the chunk's page count that covers the deepest row's
         ``start_pos + chunk`` keys.  Bounded program count (log2 buckets),
         O(allocated) gather cost."""
@@ -771,29 +850,28 @@ class RaggedInferenceEngineV2:
     def step(self, temperature: float = 0.0,
              eos_token_id: Optional[int] = None,
              rng: Optional[np.random.Generator] = None) -> int:
-        """One scheduler step, complete when it returns: a batched prefill
-        call and/or a decode burst.  While prefill work exists the burst
-        length is 1 so SplitFuse keeps interleaving chunks with decodes;
-        once all prompts are in, decodes run ``decode_burst`` steps per
-        dispatch.  Returns the number of tokens processed."""
+        """One scheduler step, complete when it returns: ONE program call,
+        a decode step that carries the round's prefill chunks while prefill
+        work exists (so SplitFuse keeps interleaving chunks with decodes),
+        a burst of ``decode_burst`` steps once all prompts are in.
+        Returns the number of tokens processed."""
         del rng  # sampling is in-graph now; kept for API compat
         return self.step_ahead(temperature, eos_token_id) + self.settle()
 
     def step_ahead(self, temperature: float = 0.0,
                    eos_token_id: Optional[int] = None) -> int:
-        """:meth:`step` for a caller that comes back: the step's decode
-        call is left running on the device, and the next ``step_ahead``
-        (or :meth:`settle`) fetches and commits it first.  So whatever the
+        """:meth:`step` for a caller that comes back: the step's call is
+        left running on the device, and the next ``step_ahead`` (or
+        :meth:`settle`) fetches and commits it first.  So whatever the
         caller does between two calls (delivering tokens, admitting) costs
-        the device nothing.  Returns the tokens committed in THIS call: the
-        previous call's decode and this one's prefill.
+        the device nothing.  Returns the tokens committed in THIS call:
+        the previous call's chunks and decode tokens.
 
-        In a round with both, the prefill call and the decode step are
-        dispatched before either is waited for: the decode rows were
-        planned before the prefill and read nothing of its result, so the
-        host packs and dispatches them while the device runs the prefill
-        (that work lies inside the ``inference/prefill`` span), and the
-        device goes from one program to the next."""
+        A round with chunks runs the one-step decode program with the
+        chunks' rows riding in it (:meth:`_decode_burst_fn`), the decode
+        rows dead where nothing decodes yet; a round without runs the
+        burst.  A request's first token is committed a round later, as
+        every decode token is."""
         from ...telemetry import get_telemetry
 
         tel = get_telemetry()
@@ -804,35 +882,17 @@ class RaggedInferenceEngineV2:
             sp.set(chunks=len(chunks), decoding=len(decode))
             if tel.enabled:
                 self._publish_pages_in_use(tel)
-            temp = np.float32(temperature)
-            if len(self._subkeys) < 2:
-                # a round's two keys, fetched while the device is idle
-                self._refill_keys()
-            if chunks:
-                call = self._pack_prefill(tel, chunks, temp)
-                with tel.span("inference/prefill",
-                              args={"chunks": len(chunks)}):
-                    with tel.span("inference/prefill/dispatch"), \
-                            self._collecting_moe():
-                        sampled, self.pool, moe_aux = self._prefill(
-                            *call, kb=self._prefill_bucket(chunks))
-                    if decode:
-                        self._dispatch_decode(tel, chunks, decode, temp,
-                                              eos_token_id)
-                    with tel.span("inference/prefill/fetch"):
-                        sampled, moe_aux = jax.device_get((sampled, moe_aux))
-                n_tokens += self._commit_prefill(tel, chunks, sampled,
-                                                 moe_aux, eos_token_id)
-            elif decode:
-                self._dispatch_decode(tel, chunks, decode, temp,
-                                      eos_token_id)
+            if chunks or decode:
+                self._dispatch(tel, chunks, decode, np.float32(temperature),
+                               eos_token_id)
         return n_tokens
 
     def settle(self) -> int:
-        """Fetch and commit the decode call :meth:`step_ahead` left
-        running, if any; returns the tokens it yielded.  A request that
-        stopped running meanwhile (cancelled, preempted) is passed over:
-        it decodes that position again if it resumes."""
+        """Fetch and commit the call :meth:`step_ahead` left running, if
+        any; returns the tokens it yielded.  A request that stopped
+        prefilling or running meanwhile (cancelled, preempted) is passed
+        over: it prefills that chunk, or decodes that position, again if
+        it resumes."""
         from ...telemetry import get_telemetry
 
         return self._settle(get_telemetry())
@@ -840,20 +900,47 @@ class RaggedInferenceEngineV2:
     def _settle(self, tel: Any) -> int:
         if self._inflight is None:
             return 0
-        decode, burst, toks, moe_aux, eos_token_id = self._inflight
+        chunks, decode, burst, outputs, eos_token_id = self._inflight
         self._inflight = None
         with tel.span("inference/decode_burst",
                       args={"burst": burst, "batch": len(decode)}):
             with tel.span("inference/decode_burst/fetch"):
-                toks, moe_aux = jax.device_get((toks, moe_aux))  # [burst, B]
+                # [burst, B], [Bp] or None, the gate's stats or None
+                toks, firsts, moe_aux = jax.device_get(outputs)
         with tel.span("inference/commit"):
             if moe_aux is not None:
-                self._ingest_moe_stats(moe_aux, tel, "decode", steps=burst)
+                self._ingest_moe_stats(moe_aux, tel, steps=burst)
+            written = self._commit_chunks(tel, chunks, firsts, eos_token_id)
             accepted = self.scheduler.decode_burst_done(decode, toks,
                                                         eos_token_id)
+        if chunks:
+            tel.inc_counter("inference/prefill_tokens", v=written,
+                            help="prompt tokens written through prefill")
+            tel.inc_counter("inference/chunk_tokens_beside_decode",
+                            v=written if accepted else 0,
+                            help="prompt tokens written by a call that "
+                                 "also yielded a decode token: over "
+                                 "prefill_tokens, the share of prefill "
+                                 "work that rode a decode step")
         tel.inc_counter("inference/decode_tokens", v=accepted,
                         help="decode tokens accepted by the scheduler")
-        return accepted
+        return written + accepted
+
+    def _commit_chunks(self, tel: Any, chunks, firsts, eos_token_id) -> int:
+        """The chunks a fetched call wrote, handed to the scheduler (after
+        the fetch: its prefix index sees written pages); returns their
+        prompt tokens.  A chunk whose request is not where the call left
+        it (cancelled or preempted between the rounds) is passed over."""
+        live = [(i, ch) for i, ch in enumerate(chunks)
+                if ch.request.state is RequestState.PREFILL
+                and ch.request.prefilled == ch.start_pos]
+        bs = self.cache_config.block_size
+        self._count_recycled(tel, [ch.start_pos // bs for _, ch in live],
+                             [-(-ch.n_valid // bs) for _, ch in live])
+        for i, ch in live:
+            self.scheduler.chunk_done(
+                ch, int(firsts[i]) if ch.is_last else None, eos_token_id)
+        return sum(ch.n_valid for _, ch in live)
 
     def _publish_pages_in_use(self, tel: Any) -> None:
         sched = self.scheduler
@@ -894,8 +981,9 @@ class RaggedInferenceEngineV2:
                  "later page of the same sequence (logical pages: each is "
                  "one page in every window layer)")
 
-    def _pack_prefill(self, tel: Any, chunks, temp) -> Tuple:
-        """The prefill program's arguments for ``chunks``."""
+    def _pack_chunks(self, tel: Any, chunks) -> Tuple:
+        """``chunks`` as the one-step program takes them
+        (:meth:`_decode_burst_fn`)."""
         with tel.span("inference/pack", args={"kind": "prefill"}):
             Bp, C = self.prefill_batch, self.chunk
             tokens = np.zeros((Bp, C), np.int32)
@@ -910,26 +998,7 @@ class RaggedInferenceEngineV2:
                 last[i] = max(ch.n_valid - 1, 0)
             rings = self._ring_bases(
                 Bp, ((i, ch.request) for i, ch in enumerate(chunks)))
-        return (self.params, self.pool, tokens, tables, start, last, temp,
-                self._next_key(), rings)
-
-    def _commit_prefill(self, tel: Any, chunks, sampled, moe_aux,
-                        eos_token_id) -> int:
-        n_tokens = 0
-        with tel.span("inference/commit"):
-            if moe_aux is not None:
-                self._ingest_moe_stats(moe_aux, tel, "prefill")
-            bs = self.cache_config.block_size
-            self._count_recycled(
-                tel, [ch.start_pos // bs for ch in chunks],
-                [-(-ch.n_valid // bs) for ch in chunks])
-            for i, ch in enumerate(chunks):
-                first = int(sampled[i]) if ch.is_last else None
-                self.scheduler.chunk_done(ch, first, eos_token_id)
-                n_tokens += ch.n_valid
-        tel.inc_counter("inference/prefill_tokens", v=n_tokens,
-                        help="prompt tokens written through prefill")
-        return n_tokens
+        return tokens, tables, start, last, rings
 
     def _count_cache_traffic(self, tel: Any, kv_lens, max_pos, burst) -> None:
         """What the decode steps of a call read of each kind's cache, and
@@ -951,17 +1020,21 @@ class RaggedInferenceEngineV2:
         self._count_recycled(tel, -(-kv_lens // bs),
                              (lengths[-1] - 1) // bs + 1 - -(-kv_lens // bs))
 
-    def _dispatch_decode(self, tel: Any, chunks, decode, temp,
-                         eos_token_id) -> None:
-        """Pack and dispatch the decode call for ``decode``; its outputs
-        stay on the device until :meth:`_settle`."""
+    def _dispatch(self, tel: Any, chunks, decode, temp,
+                  eos_token_id) -> None:
+        """Pack and dispatch the round's one call: ``decode``'s rows and,
+        riding in their step, ``chunks``.  Its outputs stay on the device
+        until :meth:`_settle`."""
+        # exactly TWO decode step counts ever compile (1, which carries
+        # the chunks under their page bucket, and decode_burst):
+        # over-running a request's budget inside a burst is safe (max_pos
+        # clamps writes, the host discards surplus tokens), so the tail
+        # reuses the full-length program
+        burst, riding, bucket = self.decode_burst, None, {}
+        if chunks:
+            burst, riding = 1, self._pack_chunks(tel, chunks)
+            bucket = {"kb": self._prefill_bucket(chunks)}
         with tel.span("inference/pack", args={"kind": "decode"}):
-            # exactly TWO decode program shapes ever compile (1 and
-            # decode_burst): over-running a request's budget inside a
-            # burst is safe (max_pos clamps writes, the host discards
-            # surplus tokens), so the tail reuses the full-length program
-            burst = 1 if (chunks or self.scheduler.prefilling) \
-                else self.decode_burst
             B = self.max_slots
             tokens = np.zeros((B,), np.int32)
             kv_lens = np.zeros((B,), np.int32)
@@ -975,16 +1048,17 @@ class RaggedInferenceEngineV2:
                 max_pos[s] = len(req.prompt) + req.max_new_tokens - 1
                 tables[s] = self.scheduler.table_row(req)
             rings = self._ring_bases(B, ((r.slot, r) for r in decode))
-            if tel.enabled:
+            if tel.enabled and decode:
                 live = [r.slot for r in decode]
                 self._count_cache_traffic(tel, kv_lens[live], max_pos[live],
                                           burst)
         with tel.span("inference/decode_burst/dispatch"), \
                 self._collecting_moe():
-            toks, self.pool, moe_aux = self._decode(burst)(
+            toks, self.pool, moe_aux, firsts = self._decode(burst)(
                 self.params, self.pool, tokens, kv_lens, tables, max_pos,
-                temp, self._next_key(), rings)
-        self._inflight = (decode, burst, toks, moe_aux, eos_token_id)
+                temp, self._next_key(), rings, riding, **bucket)
+        self._inflight = (chunks, decode, burst, (toks, firsts, moe_aux),
+                          eos_token_id)
 
     def generate(self, prompts: List[List[int]], max_new_tokens: int = 32,
                  temperature: float = 0.0, seed: int = 0,

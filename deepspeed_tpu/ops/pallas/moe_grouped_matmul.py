@@ -218,10 +218,14 @@ def _tile_n(K: int, N: int, itemsize: int) -> int:
     return tn
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 6))
 def _grouped_call(kernel, name, x, weights, tile_group, num_tiles,
                   interpret: bool):
     """``weights``: each ``[G, K, N]``; tile m multiplies block
-    ``tile_group[m]`` of the ``G``."""
+    ``tile_group[m]`` of the ``G``.  Jitted on its own so that a program
+    with many sparse layers unrolled, and every further program of the
+    process, traces the kernel once (``paged_attention._paged_kernel_call``
+    says why)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

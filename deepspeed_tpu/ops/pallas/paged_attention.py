@@ -310,13 +310,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     softmax's denominator.  A row of length 0 gives zeros.  The pool is
     passed as it lies in memory: the ``[M, block_size·kv_h, w]`` view the
     kernel reads merges two adjacent dims and moves nothing."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, h, d = q.shape
-    M, block_size, kv_h, w = k_pool.shape
-    N, dv = v_pool.shape[0], v_pool.shape[-1]
-    max_blocks = block_tables.shape[1]
+    h = q.shape[1]
+    kv_h, w, dv = k_pool.shape[2], k_pool.shape[3], v_pool.shape[-1]
     impl = paged_decode_impl(h, kv_h, interpret, w, dv)
     if impl == "reference":
         refusal = _refusal(h, kv_h, w, dv, compiled=not interpret)
@@ -327,7 +322,30 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
         return paged_decode_reference(q, k_pool, v_pool, block_tables,
                                       lengths, window, sink, k_planes,
                                       plane_stride)
+    return _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink,
+                              interpret=bool(interpret), window=window,
+                              k_planes=k_planes, plane_stride=plane_stride)
 
+
+@functools.partial(jax.jit, static_argnames=("interpret", "window",
+                                             "k_planes", "plane_stride"))
+def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
+                       interpret: bool, window, k_planes: int,
+                       plane_stride: int):
+    """The Mosaic call of :func:`paged_decode_attention`, once the path is
+    decided.  A jitted function of its own so that a program which holds
+    the kernel many times (a layer kind's unrolled layers) traces and
+    lowers it once, and every further program of the process (a serving
+    engine compiles one a page bucket) finds the kernel's trace cached:
+    a kernel's body is ~70 ms of Python to trace and lower, and set-up
+    pays it for every instance (PERF.md §6, PR 37)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, h, d = q.shape
+    M, block_size, kv_h, w = k_pool.shape
+    N, dv = v_pool.shape[0], v_pool.shape[-1]
+    max_blocks = block_tables.shape[1]
     dk = k_planes * w
     if dk > d:      # the last plane's lane padding: zeros times zeros
         q = jnp.pad(q, ((0, 0), (0, 0), (0, dk - d)))
@@ -370,7 +388,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, h, dv), q.dtype),
-        interpret=bool(interpret),
+        interpret=interpret,
         name="paged_decode_attention",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, jnp.asarray(head_mask), *sink_arg, k_pool.reshape(M, rows, w),

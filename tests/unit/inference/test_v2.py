@@ -517,9 +517,9 @@ def test_v2_serves_the_dense_forward_tokens(kind, engine_kw, prompt_lens):
 ], ids=["burst4", "burst1"])
 def test_v2_step_ahead_leaves_the_decode_call_running(engine_kw):
     """``step_ahead`` (the front-end's entry) returns with its decode call
-    on the device: its tokens are committed by the next call or by
-    ``settle``, a prefill's first token at once; driven that way the
-    engine serves the tokens ``step`` serves, and counts the same."""
+    on the device: its chunks and its tokens are committed by the next call
+    or by ``settle``; driven that way the engine serves the tokens ``step``
+    serves, and counts the same."""
     model, params = _three_layer_model("gqa_window")
     rng = np.random.RandomState(33)
     prompts = [rng.randint(1, 512, size=n).tolist() for n in (5, 14, 22)]
@@ -556,9 +556,10 @@ def test_v2_settle_commits_what_step_ahead_left():
                                    max_seq_len=64),
         max_batch_slots=2, prefill_chunk=8, decode_burst=4)
     req = eng.put(prompt, 9)
-    eng.step_ahead()                            # prefill: the first token
-    assert len(req.generated) == 1 and eng._inflight is None
-    assert eng.step_ahead() == 0                # a burst of four, running
+    assert eng.step_ahead() == 0                # the chunk's call, running
+    assert not req.generated and eng._inflight is not None
+    # its commit: the prompt's tokens and the first one; a burst, running
+    assert eng.step_ahead() == 6
     assert len(req.generated) == 1 and eng._inflight is not None
     assert eng.settle() == 4 and len(req.generated) == 5
     assert eng.step() == 4                      # ``step`` is both halves
@@ -657,7 +658,7 @@ def test_v2_kv_pages_exported_and_imported_decode_the_same_token():
     src.step()                                  # the source's own next token
     tables = np.zeros((2, dst.cache_config.max_blocks_per_seq), np.int32)
     tables[0, :len(blocks)] = blocks
-    toks, dst.pool, _ = dst._decode(1)(
+    toks, dst.pool, _, _ = dst._decode(1)(
         dst.params, dst.pool, jnp.asarray([req.generated[0], 0], jnp.int32),
         jnp.asarray([len(prompt), 0], jnp.int32), jnp.asarray(tables),
         jnp.asarray([len(prompt) + 2, 0], jnp.int32), jnp.float32(0.0),

@@ -227,8 +227,8 @@ def test_the_engine_serves_the_tokens_of_the_models_own_forward(family):
 def test_the_engine_counts_assignments_and_active_experts(model, params):
     """``inference/moe/assignments`` is rows x k a step of a call and
     ``inference/moe/experts_active`` the non-empty groups summed over
-    layers and steps, from the stats both programs thread out; nothing is
-    dropped."""
+    layers and steps, from the stats every program threads out; nothing
+    is dropped."""
     from deepspeed_tpu import telemetry
 
     tel = telemetry.configure(enabled=True, jsonl=False, prometheus=False)
@@ -238,8 +238,9 @@ def test_the_engine_counts_assignments_and_active_experts(model, params):
                                          max_seq_len=128),
             max_batch_slots=4, prefill_chunk=32, prefill_batch=2,
             decode_burst=4)
-        # one prefill call (2 x 32 rows, padding rows route like any row),
-        # then one four-step burst over the 4 slots
+        # one step that carries the chunks (2 x 32 rows beside the 4 slots'
+        # rows, padding and idle rows route like any row), then one
+        # four-step burst over the 4 slots
         def value(name):
             metric = tel.registry.metrics().get(name)
             return metric.value if metric is not None else 0.0
@@ -249,7 +250,7 @@ def test_the_engine_counts_assignments_and_active_experts(model, params):
         before = {name: value(name) for name in names}
         engine.generate([np.asarray(IDS[0, :20]).tolist()], max_new_tokens=5)
         k, layers, experts = 3, 2, 8
-        assert value(names[0]) - before[names[0]] == 64 * k + 4 * (4 * k)
+        assert value(names[0]) - before[names[0]] == 68 * k + 4 * (4 * k)
         active = value(names[1]) - before[names[1]]
         assert 5 * layers * k <= active <= 5 * layers * experts
         assert float(active).is_integer()
@@ -262,9 +263,8 @@ def test_the_engine_counts_assignments_and_active_experts(model, params):
 
 def test_gate_stats_that_do_not_fit_are_skipped_not_raised(model, params):
     """Telemetry must never kill a serving round: the packed stats are
-    read by the layout their own program recorded; a prefill call feeds
-    the counters and leaves the router's signal (``last_moe_stats``, of
-    the last decode burst) alone."""
+    read by the layout the program recorded as it was traced; stats that
+    do not fit it leave the router's signal (``last_moe_stats``) alone."""
     from deepspeed_tpu import telemetry
 
     engine = engine_v2.build_engine_v2(
@@ -272,18 +272,17 @@ def test_gate_stats_that_do_not_fit_are_skipped_not_raised(model, params):
                                      max_seq_len=128),
         max_batch_slots=4, prefill_chunk=32, prefill_batch=2, decode_burst=4)
     engine.generate([np.asarray(IDS[0, :20]).tolist()], max_new_tokens=5)
-    assert set(engine._moe_columns) == {"prefill", "decode"}
-    width = sum(w for _, w in engine._moe_columns["decode"])
+    assert "moe/load" in dict(engine._moe_columns)
+    width = sum(w for _, w in engine._moe_columns)
     tel = telemetry.configure(enabled=False)
     signal = engine.last_moe_stats
     packed = np.zeros((2, width), np.float32)
-    engine._ingest_moe_stats(packed[:, :-1], tel, "decode")     # too narrow
-    engine._ingest_moe_stats(packed, tel, "no such program")
-    engine._ingest_moe_stats(packed, tel, "prefill")
+    engine._ingest_moe_stats(packed[:, :-1], tel)               # too narrow
+    engine._ingest_moe_stats(packed[0], tel)                    # one row
     assert engine.last_moe_stats is signal
-    engine._moe_columns["decode"] = [
-        (n, w) for n, w in engine._moe_columns["decode"]
+    engine._moe_columns = [
+        (n, w) for n, w in engine._moe_columns
         if n != "moe/drop_rate"] + [("moe/other", 1)]
-    engine._ingest_moe_stats(packed, tel, "decode")              # no entry
+    engine._ingest_moe_stats(packed, tel)                        # no entry
     assert engine.last_moe_stats is not signal
     assert engine.last_moe_stats["drop_rate"] == 0.0

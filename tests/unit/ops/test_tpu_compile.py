@@ -200,59 +200,89 @@ def serving_programs(request, topo, one_chip):
     common = (arg((), jnp.float32),
               placed(jax.eval_shape(lambda: jax.random.PRNGKey(0))))
 
-    @functools.cache                   # two tests read each program's text
+    @functools.cache                   # three tests read a program's text
     def compiled_text(program):
+        """``decode_burst_<n>``: the burst of n steps; ``chunks_<kb>``: the
+        one-step program with a round's chunks riding in it under the page
+        bucket kb (``deepest``: the whole table)."""
         kind, _, n = program.rpartition("_")
+        rows = (arg((_SLOTS,)), arg((_SLOTS,)), arg((_SLOTS, blocks)),
+                arg((_SLOTS,)))
         if kind == "decode_burst":
             fn = functools.partial(engine._decode_burst_fn, n_steps=int(n))
-            rows = (arg((_SLOTS,)), arg((_SLOTS,)), arg((_SLOTS, blocks)),
-                    arg((_SLOTS,)))
+            chunks = None
         else:
-            bucket = blocks if n == "deepest" else int(n)
-            fn = functools.partial(engine._prefill_batch_fn, kb=bucket)
-            rows = (arg((engine.prefill_batch, engine.chunk)),
-                    arg((engine.prefill_batch, blocks)),
-                    arg((engine.prefill_batch,)),
-                    arg((engine.prefill_batch,)))
+            fn = functools.partial(
+                engine._decode_burst_fn, n_steps=1,
+                kb=blocks if n == "deepest" else int(n))
+            chunks = (arg((engine.prefill_batch, engine.chunk)),
+                      arg((engine.prefill_batch, blocks)),
+                      arg((engine.prefill_batch,)),
+                      arg((engine.prefill_batch,)), None)
         return jax.jit(fn, donate_argnums=(1,)).lower(
-            params, pool, *rows, *common).compile().as_text()
+            params, pool, *rows, *common, None, chunks).compile().as_text()
 
     yield engine, compiled_text
     mp.undo()
 
 
-@pytest.mark.parametrize("program", ["decode_burst_1", "decode_burst_8",
-                                     "prefill_8", "prefill_deepest"])
+_PROGRAMS = ["decode_burst_1", "decode_burst_8", "chunks_8",
+             "chunks_deepest"]
+
+
+@pytest.mark.parametrize("program", _PROGRAMS)
 def test_engine_programs_keep_the_pool_in_place_on_v5e(serving_programs,
                                                        program):
     """Both serving cells' programs at the cells' pool shapes: the pool is
-    a carried buffer that is passed on, written by a scatter that aliases
+    a carried buffer that is passed on, written by scatters that alias
     it, and read by the paged kernel through a bitcast.  No instruction
     copies it and none makes a value of one layer's shape (the parent's
     programs sliced a layer out for the kernel and put it back: 62% of the
-    dense cell's device time, PERF.md PR 28)."""
+    dense cell's device time, PERF.md PR 28).  The step that carries a
+    round's chunks writes their pages and the decode rows into the same
+    buffer and holds the paged kernel once, like the plain step; under
+    ``tensor`` each chip does so on its own KV heads (the chunks' pages
+    addressed under GSPMD had the whole pool re-laid out around every
+    access: PERF.md §6, PR 37)."""
     engine, compiled_text = serving_programs
     ad = engine.adapter
     kv_h = ad.kv_heads // engine._tp          # what one chip holds
     text = compiled_text(program)
-    if engine._tp > 1 and program.startswith("prefill"):
-        # it compiles, and that is all that holds: with two kv heads a
-        # chip and no custom call to fix the pool's layout, XLA gives the
-        # carried pool a layout of its own and converts the whole pool on
-        # the way in and out (PERF.md §7, PR 28; no cell serves under TP)
-        return
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert len(re.findall(r"paged_decode_attention[\w.]* = ", text)) == 1
     assert pool_value_faults(text, _POOL_LAYERS, _POOL_PAGES, _PAGE, kv_h,
                              ad.head_dim) == []
-    # a decode step scatters rows into the pool as it is held, a prefill
-    # call whole pages into its page matrices, the kernel's own view
-    whole = f"bf16[{_POOL_LAYERS},{_POOL_PAGES},{_PAGE},{kv_h}," \
-        if program.startswith("decode") or engine._tp > 1 else \
-        f"bf16[{_POOL_LAYERS * _POOL_PAGES},{_PAGE * kv_h},"
-    writes = re.findall(rf"= {re.escape(whole)}\S* scatter\(", text)
-    assert len(writes) == 2, writes                # K and V, once a layer
-    if program.startswith("decode"):
-        assert 'custom_call_target="tpu_custom_call"' in text
-        assert "paged_decode_attention" in text
+    # a decode step's rows are scattered into the pool as it is held, a
+    # chunk's whole pages into its page matrices, the kernel's own view:
+    # K and V, each once a layer
+    as_held = f"bf16[{_POOL_LAYERS},{_POOL_PAGES},{_PAGE},{kv_h},"
+    matrices = f"bf16[{_POOL_LAYERS * _POOL_PAGES},{_PAGE * kv_h},"
+    writes = lambda shape: len(re.findall(
+        rf"= {re.escape(shape)}\S* scatter\(", text))
+    assert writes(as_held) == 2
+    assert writes(matrices) == (2 if program.startswith("chunks") else 0)
+
+
+def _matmul_rows(text):
+    """The leading dim of every matmul's result in a compiled program."""
+    return [int(n) for n in re.findall(
+        r"= \w+\[(\d+),[\d,]+\]\S* convolution\(", text)]
+
+
+def test_a_step_with_chunks_reads_each_weight_once_on_v5e(serving_programs):
+    """The chunks' rows and the decode rows go through a layer's matrices
+    (and the head) TOGETHER: every weight matmul of the step has Bp·C + B
+    rows, none has the chunks' rows or the decode rows alone (the two
+    programs of a round each streamed the model for itself: PERF.md §6,
+    PR 37).  The engine has no prefill program to compile."""
+    engine, compiled_text = serving_programs
+    chunk_rows = engine.prefill_batch * engine.chunk
+    rows = _matmul_rows(compiled_text("chunks_8"))
+    assert rows.count(chunk_rows + _SLOTS) >= 4    # q/k/v/o, MLP or router
+    assert not {chunk_rows, _SLOTS} & set(rows)
+    assert _SLOTS in _matmul_rows(compiled_text("decode_burst_1"))
+    assert not hasattr(engine, "_prefill")
+    assert not hasattr(engine, "_prefill_batch_fn")
 
 
 def expert_value_faults(text, layers, experts, hidden, inner):
@@ -284,8 +314,7 @@ def _mosaic_calls(text):
                            r"moe_grouped_matmul = ", text)))
 
 
-@pytest.mark.parametrize("program", ["decode_burst_1", "decode_burst_8",
-                                     "prefill_8", "prefill_deepest"])
+@pytest.mark.parametrize("program", _PROGRAMS)
 def test_engine_programs_read_the_experts_where_they_lie_on_v5e(
         serving_programs, program):
     """The sparse cell's programs at its widths (64 experts of
@@ -296,7 +325,9 @@ def test_engine_programs_read_the_experts_where_they_lie_on_v5e(
     four programs, a ``dynamic-slice`` and two more values of a layer's
     shape for each of the three leaves (on the chip
     ``dynamic-slice_bitcast_fusion`` x 3, 60% of the cell's device time,
-    PERF.md PR 30).  A dense model's programs hold no expert kernel."""
+    PERF.md PR 30).  The step that carries chunks holds each kernel once:
+    their rows are routed with the decode rows.  A dense model's programs
+    hold no expert kernel."""
     engine, compiled_text = serving_programs
     c = engine.config
     text = compiled_text(program)
@@ -424,19 +455,22 @@ def hybrid_programs(topo, one_chip):
     @functools.cache
     def compiled(program):
         kind, _, n = program.rpartition("_")
+        rows = (arg((_HYBRID_SLOTS,)), arg((_HYBRID_SLOTS,)),
+                arg((_HYBRID_SLOTS, blocks)), arg((_HYBRID_SLOTS,)))
         if kind == "decode_burst":
             fn = functools.partial(engine._decode_burst_fn, n_steps=int(n))
-            rows = (arg((_HYBRID_SLOTS,)), arg((_HYBRID_SLOTS,)),
-                    arg((_HYBRID_SLOTS, blocks)), arg((_HYBRID_SLOTS,)))
-        else:
-            fn = functools.partial(engine._prefill_batch_fn, kb=int(n))
-            rows = (arg((engine.prefill_batch, engine.chunk)),
-                    arg((engine.prefill_batch, blocks)),
-                    arg((engine.prefill_batch,)),
-                    arg((engine.prefill_batch,)))
+            chunks = None
+        else:                    # the one-step program with chunks riding
+            fn = functools.partial(engine._decode_burst_fn, n_steps=1,
+                                   kb=int(n))
+            chunks = (arg((engine.prefill_batch, engine.chunk)),
+                      arg((engine.prefill_batch, blocks)),
+                      arg((engine.prefill_batch,)),
+                      arg((engine.prefill_batch,)),
+                      arg((engine.prefill_batch,)))
         done = jax.jit(fn, donate_argnums=(1,)).lower(
             placed(shapes), placed(engine.pool), *rows, *common,
-            arg((rows[0].shape[0],))).compile()
+            arg((_HYBRID_SLOTS,)), chunks).compile()
         return done.as_text(), done.memory_analysis()
 
     yield engine, compiled
@@ -444,7 +478,7 @@ def hybrid_programs(topo, one_chip):
 
 
 @pytest.mark.parametrize("program", ["decode_burst_1", "decode_burst_8",
-                                     "prefill_8", "prefill_64"])
+                                     "chunks_8", "chunks_64"])
 def test_hybrid_programs_keep_both_pools_in_place_on_v5e(hybrid_programs,
                                                          program):
     """The hybrid cell's programs at its shapes (two full layers of 4 KV
@@ -452,7 +486,8 @@ def test_hybrid_programs_keep_both_pools_in_place_on_v5e(hybrid_programs,
     pages; K in two planes): each of the four pool arrays is passed on,
     written in place and read through a bitcast, seven Mosaic attention
     calls and a pair of grouped expert calls a sparse layer stand in a
-    decode step, nothing copies a layer's experts, and the program plans
+    decode step (the one that carries chunks too: once a layer, not once
+    a group of rows), nothing copies a layer's experts, and the program plans
     little beyond its arguments (the parent of the planes planned 2.7 GB:
     a copy of the full layers' K pool a call)."""
     engine, compiled = hybrid_programs
@@ -473,8 +508,11 @@ def test_hybrid_programs_keep_both_pools_in_place_on_v5e(hybrid_programs,
         for a in pool.values())
     assert expert_value_faults(text, 6, 16, 4096, 2048) == []
     assert _mosaic_calls(text) == (6, 6)
-    paged = len(re.findall(r"paged_decode_attention[\w.]* = ", text))
-    assert paged == (7 if program.startswith("decode") else 0)
+    assert len(re.findall(r"paged_decode_attention[\w.]* = ", text)) == 7
+    if program.startswith("chunks"):
+        # 256 chunk rows and 256 decode rows through each weight together
+        rows = _matmul_rows(text)
+        assert rows.count(512) >= 7 and 256 not in rows
 
 
 def _plane_relayouts(text: str, B: int, S: int, h: int, d: int):

@@ -23,21 +23,21 @@ from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.serving import ServingParams, build_serving_frontend
 from deepspeed_tpu.telemetry import tracer as tracer_mod
 from deepspeed_tpu.telemetry.perf import CompileTracker, tracked_jit
+from deepspeed_tpu.telemetry.perf.compile_tracker import program_name
 
-#: name -> parent, as ISSUE 24 fixes them, but for the decode call: the
+#: name -> parent, as ISSUE 24 fixes them, but for the round's call: the
 #: front-end drives the engine through ``step_ahead``, which leaves a step's
-#: decode call running and fetches it at the start of the next step (PR 31),
-#: so ``inference/decode_burst`` is the wait for it (its fetch) and the
-#: dispatch lies where it happens
+#: ONE call running (the decode step that carries the round's chunks, or a
+#: burst) and fetches it at the start of the next step, so
+#: ``inference/decode_burst`` is the wait for it (its fetch) and the
+#: dispatch lies where it happens; no call of its own prefills, so there is
+#: no ``inference/prefill`` span
 TREE = {
     "serving/pump": None,
     "serving/admit": "serving/pump",
     "inference/step": "serving/pump",
     "inference/plan": "inference/step",
     "inference/pack": "inference/step",
-    "inference/prefill": "inference/step",
-    "inference/prefill/dispatch": "inference/prefill",
-    "inference/prefill/fetch": "inference/prefill",
     "inference/decode_burst": "inference/step",
     "inference/decode_burst/dispatch": "inference/step",
     "inference/decode_burst/fetch": "inference/decode_burst",
@@ -45,11 +45,6 @@ TREE = {
     "serving/deliver": "serving/pump",
     "serving/ledger": "serving/pump",
 }
-#: a round with a prefill call AND a decode step dispatches both before it
-#: waits for either: packing and dispatching the decode step is host work
-#: under the prefill's device time, and lies inside its span
-UNDER_PREFILL = {("inference/pack", "inference/prefill"),
-                 ("inference/decode_burst/dispatch", "inference/prefill")}
 
 
 @pytest.fixture(scope="module")
@@ -70,8 +65,8 @@ def make_frontend(tiny_model):
 
 
 def serve_three(fe):
-    """Three prompts over two slots: batched and single prefill chunks,
-    one-step decodes beside a prefill, full bursts, one request queued."""
+    """Three prompts over two slots: two chunks and one in a step, with and
+    without a row decoding beside them, full bursts, one request queued."""
     rng = np.random.RandomState(7)
     handles = [fe.submit(rng.randint(1, 512, size=n).tolist(),
                          max_new_tokens=6, klass="batch")
@@ -105,7 +100,7 @@ def named(served, name):
 def test_every_span_of_the_tree_is_there_under_its_parent(served):
     seen = {(e["name"], e["args"].get("parent")) for e in served["events"]
             if "depth" in e["args"]}
-    assert seen == set(TREE.items()) | UNDER_PREFILL
+    assert seen == set(TREE.items())
     depth = {"serving/pump": 0}
     for name, parent in TREE.items():
         if parent is not None:
@@ -117,6 +112,60 @@ def test_every_span_of_the_tree_is_there_under_its_parent(served):
                 depth[parent] + 1 if parent else 0), e["name"]
     kinds = {e["args"]["kind"] for e in named(served, "inference/pack")}
     assert kinds == {"prefill", "decode"}
+
+
+def test_a_round_with_chunks_is_one_call_left_running(tiny_model):
+    """A mixed run (four prompts over two slots, answers long enough that
+    later prompts come in beside decoding rows): the children of a step
+    that carries chunks are, in order, the last call's wait and its one
+    commit, the plan, the chunks' pack and the decode rows' pack, and ONE
+    dispatch; and most prompt tokens are written by a call that also
+    yields a decode token."""
+    tel = telemetry.get_telemetry()
+    tel.reset()
+    tel.configure(enabled=True, jsonl=False, prometheus=False)
+    fe = make_frontend(tiny_model)
+    rng = np.random.RandomState(11)
+    handles = [fe.submit(rng.randint(1, 512, size=n).tolist(),
+                         max_new_tokens=new, klass="batch")
+               for n, new in ((6, 30), (20, 12), (22, 9), (15, 5))]
+    fe.run_until_idle()
+    events = tel.tracer.events()
+    counters = {m.name: m.value for m in tel.registry.metrics().values()
+                if m.kind == "counter"}
+    fe.close()
+    tel.reset()
+    assert [len(h.result()) for h in handles] == [30, 12, 9, 5]
+    # events are recorded as they close: a step's children, then the step
+    rounds, children = [], []
+    for e in events:
+        if e["name"] == "inference/step":
+            rounds.append((e["args"], children))
+            children = []
+        elif e["args"].get("parent") == "inference/step":
+            children.append((e["name"], e["args"].get("kind")))
+    carried = [(args, kids) for args, kids in rounds if args["chunks"]]
+    assert len(carried) >= 6
+    was_running = False
+    for args, kids in rounds:
+        want = [("inference/decode_burst", None),
+                ("inference/commit", None)] if was_running else []
+        want.append(("inference/plan", None))
+        was_running = bool(args["chunks"] or args["decoding"])
+        if args["chunks"]:
+            want.append(("inference/pack", "prefill"))
+        if was_running:
+            want += [("inference/pack", "decode"),
+                     ("inference/decode_burst/dispatch", None)]
+        assert kids == want, args
+    beside = counters["inference/chunk_tokens_beside_decode"]
+    assert counters["inference/prefill_tokens"] == 6 + 20 + 22 + 15
+    # all but the first round's chunks (6 + 8 tokens: nothing decodes yet)
+    # and the fourth prompt's, seated after every other request finished
+    alone = [args for args, _ in rounds
+             if args["chunks"] and not args["decoding"]]
+    assert len(alone) == 1 + 2
+    assert beside == counters["inference/prefill_tokens"] - (6 + 8) - 15
 
 
 def test_children_lie_inside_their_parent_and_sum_to_no_more(served):
@@ -136,26 +185,35 @@ def test_children_lie_inside_their_parent_and_sum_to_no_more(served):
 
 
 def test_prefill_and_decode_spans_read_as_at_the_parent_commit(served):
-    """The three accepted metrics read these spans and counters: their
-    arguments, the token counters and the served tokens are what commit
-    6f933b4 gives for the same three requests.  Their order is
+    """The accepted metrics read these spans and counters: the token
+    counters and the served tokens are what commit 6f933b4 gives for the
+    same three requests, and every program call is one
+    ``inference/decode_burst`` span: a step that carries chunks has
+    ``burst`` 1 and counts the rows decoding beside them (none while the
+    first prompts come in, and none beside the third request's three
+    chunks: it was queued until the others had finished).  The order is
     ``step_ahead``'s: the burst in which the second request finishes is
     committed by the NEXT step, after that round's admissions, so the
     queued third request is seated a round later and one more burst
     (of the one request left decoding) runs before its prefill."""
-    got = [(e["name"], {k: v for k, v in e["args"].items()
-                        if k not in ("depth", "parent")})
-           for e in served["events"]
-           if e["name"] in ("inference/prefill", "inference/decode_burst")]
-    P, D = "inference/prefill", "inference/decode_burst"
+    got = [{k: v for k, v in e["args"].items()
+            if k not in ("depth", "parent")}
+           for e in named(served, "inference/decode_burst")]
+    chunks = [s["args"]["chunks"] for s in named(served, "inference/step")]
+    assert chunks == [2, 1, 0, 0, 1, 1, 1, 0, 0, 0]
     assert got == [
-        (P, {"chunks": 2}), (P, {"chunks": 1}),
-        (D, {"burst": 1, "batch": 1}), (D, {"burst": 4, "batch": 2}),
-        (D, {"burst": 4, "batch": 1}),
-        (P, {"chunks": 1}), (P, {"chunks": 1}), (P, {"chunks": 1}),
-        (D, {"burst": 4, "batch": 1}), (D, {"burst": 4, "batch": 1})]
+        {"burst": 1, "batch": 0}, {"burst": 1, "batch": 1},
+        {"burst": 4, "batch": 2}, {"burst": 4, "batch": 1},
+        {"burst": 1, "batch": 0}, {"burst": 1, "batch": 0},
+        {"burst": 1, "batch": 0},
+        {"burst": 4, "batch": 1}, {"burst": 4, "batch": 1}]
+    assert not named(served, "inference/prefill")
+    # one commit a program call
+    assert len(named(served, "inference/commit")) == len(got)
     assert served["counters"]["inference/prefill_tokens"] == 36
     assert served["counters"]["inference/decode_tokens"] == 15
+    # the second prompt's last four tokens rode the first one's decode step
+    assert served["counters"]["inference/chunk_tokens_beside_decode"] == 4
     assert [h.result() for h in served["handles"]] == [
         [308, 305, 456, 28, 393, 183], [26, 26, 26, 26, 26, 310],
         [291, 259, 123, 399, 27, 224]]
@@ -288,12 +346,18 @@ def test_tracked_jit_names_the_module_from_site_and_statics(tracker):
     assert fn.lower(x, x, kb=2).as_text().startswith(
         "module @jit_inference_v2_decode_burst_n_steps8 ")
     np.testing.assert_allclose(fn(x, x + 1, kb=2), 12.0)
-    plain = tracked_jit(_Engine().burst, "inference_v2/prefill",
-                        tracker=trk, static_argnames=("kb", "n_steps"))
-    assert "@jit_inference_v2_prefill " in plain.lower(
+    # a static argument outside the context stays outside the name: the
+    # one-step program carries its chunks' page bucket that way
+    plain = tracked_jit(_Engine().burst, "inference_v2/decode_burst",
+                        tracker=trk, static_context={"n_steps": 1},
+                        static_argnames=("kb", "n_steps"))
+    assert "@jit_inference_v2_decode_burst_n_steps1 " in plain.lower(
         x, x, kb=1, n_steps=1).as_text()
     if trk is not None and trk.enabled:
         assert [e.site for e in trk.events()] == ["inference_v2/decode_burst"]
+    assert "jit_" + program_name("inference_v2/decode_burst",
+                                 {"n_steps": 1}) \
+        == "jit_inference_v2_decode_burst_n_steps1"
 
 
 def test_the_engines_own_programs_carry_their_names(tiny_model):
@@ -306,10 +370,18 @@ def test_the_engines_own_programs_carry_their_names(tiny_model):
                         eng.pool)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     mb = eng.cache_config.max_blocks_per_seq
-    text = eng._decode(4).lower(
-        eng.params, pool, i32(2), i32(2), i32(2, mb), i32(2),
-        jnp.float32(0), jax.random.PRNGKey(0)).as_text()
+    args = (eng.params, pool, i32(2), i32(2), i32(2, mb), i32(2),
+            jnp.float32(0), jax.random.PRNGKey(0))
+    text = eng._decode(4).lower(*args).as_text()
     assert text.startswith("module @jit_inference_v2_decode_burst_n_steps4 ")
+    # the step that carries chunks is the one-step program by name, whatever
+    # its page bucket; the engine compiles nothing else
+    chunks = (i32(2, eng.chunk), i32(2, mb), i32(2), i32(2), None)
+    for kb in (2, 4):
+        text = eng._decode(1).lower(*args, None, chunks, kb=kb).as_text()
+        assert text.startswith(
+            "module @jit_inference_v2_decode_burst_n_steps1 ")
+    assert not hasattr(eng, "_prefill")
 
 
 def test_gauges_are_worked_out_when_the_registry_is_read(tiny_model):
