@@ -52,9 +52,12 @@ them, and the pages behind the window are overwritten.
 ``step_ahead`` (the serving front-end's entry) returns with the round's
 call still running: the next call fetches it and commits its chunks and
 its tokens before it plans.  The device runs while the front-end delivers
-and admits.  The programs take their small arguments as NumPy arrays (one
-transfer inside the call, not an upload each) and their sampling keys from
-a chain split 256 links at a time (``_next_key``).
+and admits.  A call is numbered as it is dispatched (``_Call.call``), and
+with the telemetry hub on its spans in the two rounds carry that number;
+the hub's own accounting for a step runs after the dispatch, under the
+device (``_observe``).  The programs take their small arguments as NumPy
+arrays (one transfer inside the call, not an upload each) and their
+sampling keys from a chain split 256 links at a time (``_next_key``).
 
 A chunk's cost is O(pages allocated so far), not O(max_seq_len): its rows
 gather/mask only ``kb`` pages each, where ``kb`` is the smallest
@@ -69,7 +72,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +94,21 @@ class _null_ctx:
 
     def __exit__(self, *exc):
         return None
+
+
+class _Call(NamedTuple):
+    """One program call, from its dispatch to its commit a round later."""
+    chunks: list                # the prefill chunks riding in it
+    decode: list                # the requests decoding in it
+    steps: int                  # 1 (it may carry chunks) or the burst
+    outputs: tuple              # on the device: (tokens, firsts, gate stats)
+    eos_token_id: Optional[int]     # the EOS id to accept under
+    call: int                   # its number, the engine's count of calls
+    kb: Optional[int]           # the chunks' page bucket
+    kv_lens: np.ndarray         # [B] as packed: the decode rows' lengths
+    max_pos: np.ndarray         # [B] as packed: their last positions
+    #: the dispatch span's start (``perf_counter``; None with the hub off)
+    dispatched: Optional[float]
 
 
 @jax.jit
@@ -208,10 +227,10 @@ class RaggedInferenceEngineV2:
         self.decode_burst = max(1, decode_burst)
         self._decode_jits: Dict[int, Callable] = {}
         self._reseed(0)
-        #: the call ``step_ahead`` left running: (its chunks, its decoding
-        #: requests, its steps, its outputs on the device, the EOS id to
-        #: accept under)
-        self._inflight: Optional[Tuple] = None
+        #: the call ``step_ahead`` left running
+        self._inflight: Optional[_Call] = None
+        #: program calls dispatched so far: the next call's number
+        self._calls = 0
         #: MoE serving telemetry (ISSUE 19): when the model routes through
         #: a MOELayer, the decode program additionally returns the gate's
         #: per-expert load so the router/autoscaler can see hot experts.
@@ -232,6 +251,12 @@ class RaggedInferenceEngineV2:
         #: program packs (the model's, whatever the program's rows and
         #: steps), written as one is traced
         self._moe_columns: List[Tuple[str, int]] = []
+        # the pools' and the router's gauges are worked out when the
+        # registry is read, not in every round; the hub holds the hook
+        # weakly
+        from ...telemetry import get_telemetry
+
+        get_telemetry().add_collect_hook(self._publish_gauges)
         log_dist(f"inference v2: pool={self.cache_config.num_blocks}"
                  f"x{self.cache_config.block_size} tokens, "
                  f"slots={max_batch_slots}, chunk={prefill_chunk}"
@@ -740,59 +765,89 @@ class RaggedInferenceEngineV2:
         self._moe_columns = [(name, int(b.shape[1])) for name, b in blocks]
         return jnp.concatenate([b for _, b in blocks], axis=1)
 
-    def _ingest_moe_stats(self, packed: np.ndarray, tel: Any,
-                          steps: int = 1) -> None:
-        """Host side of one call's gate stats (``steps`` steps, the stats
-        their mean; a step that carries chunks counts their rows too): the
-        dropless layer's counters, and what the router and autoscaler
-        read: per-expert load gauges and the imbalance/drop scalars.
-        Telemetry must never kill a serving round: a layout that does not
-        fit the array, or an entry the gate did not report, is skipped."""
+    def _ingest_moe_stats(self, packed: np.ndarray
+                          ) -> Optional[Dict[str, np.ndarray]]:
+        """Host side of one call's gate stats: what the router and the
+        autoscaler read (``last_moe_stats``: per-expert load, imbalance,
+        drop rate; the gauges of the same names are worked out from it
+        when the registry is read, :meth:`_publish_gauges`).  Returns the
+        stats by entry name for :meth:`_count_moe`.  Telemetry must never
+        kill a serving round: a layout that does not fit the array, or an
+        entry the gate did not report, is skipped."""
         layout = self._moe_columns
         if packed.ndim != 2 or packed.shape[1] != sum(w for _, w in layout):
-            return
+            return None
         cols, at = {}, 0
         for name, width in layout:
             cols[name] = packed[:, at:at + width]
             at += width
-        if tel.enabled and "moe/experts_active" in cols \
-                and "moe/assignments" in cols:
-            # rows x k is the same in every layer; non-empty groups are
-            # not, nor is what lands on a share of the experts
-            computed = float(cols["moe/assignments"].mean()) * steps
-            tel.inc_counter(
-                "inference/moe/assignments", v=computed,
-                help="token-to-expert assignments computed HERE, a layer "
-                     "(the mean over layers) a step of a call: rows x k "
-                     "where every expert is held, the held experts' part "
-                     "of it under expert parallelism")
-            routed = cols.get("moe/assignments_routed")
-            tel.inc_counter(
-                "inference/moe/assignments_routed",
-                v=computed if routed is None
-                else float(routed.mean()) * steps,
-                help="token-to-expert assignments the router made: rows "
-                     "x k, a step of a call, wherever the experts live")
-            tel.inc_counter(
-                "inference/moe/experts_active",
-                v=float(cols["moe/experts_active"].sum()) * steps,
-                help="held experts with at least one row (whose weights "
-                     "the grouped matmul reads), summed over layers and "
-                     "steps")
         load = cols.get("moe/load")
-        if load is None:
+        if load is not None:
+            load = load.astype(np.float64)                    # [L, E]
+            mean = load.mean(axis=1)
+            # max/mean of the hottest layer: 1.0 = a balanced router
+            hottest = np.where(mean > 0, load.max(axis=1)
+                               / np.maximum(mean, 1e-12), 0.0).max()
+            drop = cols.get("moe/drop_rate")
+            self.last_moe_stats = {
+                "load": load.mean(axis=0).tolist(),
+                "imbalance": float(hottest),
+                "drop_rate": float(drop.mean()) if drop is not None else 0.0}
+        return cols
+
+    @staticmethod
+    def _count_moe(tel: Any, cols: Dict[str, np.ndarray], steps: int
+                   ) -> None:
+        """The dropless layer's counters for one call (``steps`` steps,
+        the stats their mean; a step that carries chunks counts their
+        rows too)."""
+        if "moe/experts_active" not in cols or "moe/assignments" not in cols:
             return
-        load = load.astype(np.float64)                        # [L, E]
-        mean = load.mean(axis=1)
-        # max/mean of the hottest layer: 1.0 = a balanced router
-        hottest = np.where(mean > 0, load.max(axis=1)
-                           / np.maximum(mean, 1e-12), 0.0).max()
-        drop = cols.get("moe/drop_rate")
-        stats = {"load": load.mean(axis=0).tolist(),
-                 "imbalance": float(hottest),
-                 "drop_rate": float(drop.mean()) if drop is not None else 0.0}
-        self.last_moe_stats = stats
+        # rows x k is the same in every layer; non-empty groups are
+        # not, nor is what lands on a share of the experts
+        computed = float(cols["moe/assignments"].mean()) * steps
+        tel.inc_counter(
+            "inference/moe/assignments", v=computed,
+            help="token-to-expert assignments computed HERE, a layer "
+                 "(the mean over layers) a step of a call: rows x k "
+                 "where every expert is held, the held experts' part "
+                 "of it under expert parallelism")
+        routed = cols.get("moe/assignments_routed")
+        tel.inc_counter(
+            "inference/moe/assignments_routed",
+            v=computed if routed is None else float(routed.mean()) * steps,
+            help="token-to-expert assignments the router made: rows "
+                 "x k, a step of a call, wherever the experts live")
+        tel.inc_counter(
+            "inference/moe/experts_active",
+            v=float(cols["moe/experts_active"].sum()) * steps,
+            help="held experts with at least one row (whose weights "
+                 "the grouped matmul reads), summed over layers and "
+                 "steps")
+
+    def _publish_gauges(self) -> None:
+        """The registry's collect hook: the gauges of the pools and of
+        the router's last stats, from state the engine keeps anyway.  It
+        runs on the reader's thread, beside a round, and takes no lock:
+        the free list's length, the slots and ``last_moe_stats`` (a dict
+        replaced whole) are safe to read there."""
+        from ...telemetry import get_telemetry
+
+        tel = get_telemetry()
         if not tel.enabled:
+            return
+        sched = self.scheduler
+        tokens = (self.cache_config.num_blocks - 1
+                  - sched.allocator.num_free)
+        for kind in self.kinds.values():
+            tel.set_gauge(
+                f"inference/kv/pages_in_use/{kind.name}",
+                float(sched.ring_pages_in_use() if kind.ring else tokens),
+                help="pages of the kind's pool that live sequences hold "
+                     "(a recycled kind: at most a ring a sequence), each "
+                     "over all the kind's layers")
+        stats = self.last_moe_stats
+        if not stats:
             return
         for e, frac in enumerate(stats["load"]):
             tel.set_gauge(f"inference/moe/expert_load_e{e}", float(frac),
@@ -821,14 +876,16 @@ class RaggedInferenceEngineV2:
             return 0.0
         return float(self.last_moe_stats.get("imbalance", 0.0))
 
-    def _next_key(self) -> np.ndarray:
+    def _next_key(self, tel: Any) -> np.ndarray:
         """The next call's sampling key: ``key, sub = split(key)`` as ever,
         256 links of the chain in one program and one fetch (an eager
         split a call is two dispatches a round on the host's critical
-        path)."""
+        path).  The refill waits for the device, behind the call in
+        flight: it has a span of its own."""
         if not self._subkeys:
-            self._key, subs = _split_chain(self._key)
-            self._subkeys = list(np.asarray(subs)[::-1])
+            with tel.span("inference/keys"):
+                self._key, subs = _split_chain(self._key)
+                self._subkeys = list(np.asarray(subs)[::-1])
         return self._subkeys.pop()
 
     def _reseed(self, seed: int) -> None:
@@ -876,15 +933,15 @@ class RaggedInferenceEngineV2:
 
         tel = get_telemetry()
         with tel.span("inference/step") as sp:
-            n_tokens = self._settle(tel)
+            n_tokens, done = self._settle(tel)
             with tel.span("inference/plan"):
                 chunks, decode = self.scheduler.plan_step()
-            sp.set(chunks=len(chunks), decoding=len(decode))
-            if tel.enabled:
-                self._publish_pages_in_use(tel)
+                sp.set(chunks=len(chunks), decoding=len(decode))
             if chunks or decode:
                 self._dispatch(tel, chunks, decode, np.float32(temperature),
                                eos_token_id)
+            if tel.enabled:
+                self._observe(tel, done)
         return n_tokens
 
     def settle(self) -> int:
@@ -895,25 +952,77 @@ class RaggedInferenceEngineV2:
         it resumes."""
         from ...telemetry import get_telemetry
 
-        return self._settle(get_telemetry())
+        tel = get_telemetry()
+        n_tokens, done = self._settle(tel)
+        if tel.enabled:
+            self._observe(tel, done)
+        return n_tokens
 
-    def _settle(self, tel: Any) -> int:
-        if self._inflight is None:
-            return 0
-        chunks, decode, burst, outputs, eos_token_id = self._inflight
+    def _settle(self, tel: Any) -> Tuple[int, Optional[tuple]]:
+        """→ (the tokens the call in flight yielded, what
+        :meth:`_count_call` counts of it: None with the hub off or
+        nothing in flight).  The spans of the wait and the commit carry
+        the number the call was dispatched under."""
+        c = self._inflight
+        if c is None:
+            return 0, None
         self._inflight = None
+        ident = {"call": c.call}
         with tel.span("inference/decode_burst",
-                      args={"burst": burst, "batch": len(decode)}):
-            with tel.span("inference/decode_burst/fetch"):
+                      args={"burst": c.steps, "batch": len(c.decode),
+                            "call": c.call}):
+            with tel.span("inference/decode_burst/fetch", args=ident) as fetch:
                 # [burst, B], [Bp] or None, the gate's stats or None
-                toks, firsts, moe_aux = jax.device_get(outputs)
-        with tel.span("inference/commit"):
-            if moe_aux is not None:
-                self._ingest_moe_stats(moe_aux, tel, steps=burst)
-            written = self._commit_chunks(tel, chunks, firsts, eos_token_id)
-            accepted = self.scheduler.decode_burst_done(decode, toks,
-                                                        eos_token_id)
-        if chunks:
+                toks, firsts, moe_aux = jax.device_get(c.outputs)
+        with tel.span("inference/commit", args=ident) as commit:
+            moe = None if moe_aux is None else self._ingest_moe_stats(moe_aux)
+            live = self._commit_chunks(c.chunks, firsts, c.eos_token_id)
+            written = sum(ch.n_valid for ch in live)
+            accepted = self.scheduler.decode_burst_done(c.decode, toks,
+                                                        c.eos_token_id)
+        done = None
+        if fetch.end is not None and commit.end is not None:    # hub on
+            done = (c, live, written, accepted, moe,
+                    fetch.end - fetch.start, commit.end)
+        return written + accepted, done
+
+    def _commit_chunks(self, chunks, firsts, eos_token_id) -> list:
+        """The chunks a fetched call wrote, handed to the scheduler (after
+        the fetch: its prefix index sees written pages); returns them.  A
+        chunk whose request is not where the call left it (cancelled or
+        preempted between the rounds) is passed over."""
+        live = [(i, ch) for i, ch in enumerate(chunks)
+                if ch.request.state is RequestState.PREFILL
+                and ch.request.prefilled == ch.start_pos]
+        for i, ch in live:
+            self.scheduler.chunk_done(
+                ch, int(firsts[i]) if ch.is_last else None, eos_token_id)
+        return [ch for _, ch in live]
+
+    def _observe(self, tel: Any, done: Optional[tuple]) -> None:
+        """The hub's own accounting for a step, all of it in one place:
+        after the dispatch, where the device is already running, so that
+        the chain between two calls holds in a traced run what it holds
+        in an untraced one.  ``done``: the call this step committed
+        (:meth:`_settle`); the call it dispatched is ``_inflight``."""
+        sent = self._inflight
+        if done is None and sent is None:
+            return
+        with tel.span("inference/observe"):
+            if sent is not None and sent.decode:
+                live = [r.slot for r in sent.decode]
+                self._count_cache_traffic(tel, sent.kv_lens[live],
+                                          sent.max_pos[live], sent.steps)
+            if done is not None:
+                self._count_call(tel, *done)
+
+    def _count_call(self, tel: Any, c: _Call, live, written: int,
+                    accepted: int, moe, wait_s: float, committed: float
+                    ) -> None:
+        """A committed call's counters, and its record: the ring span
+        ``inference/call`` from the start of its dispatch (a round ago)
+        to the end of its commit."""
+        if c.chunks:
             tel.inc_counter("inference/prefill_tokens", v=written,
                             help="prompt tokens written through prefill")
             tel.inc_counter("inference/chunk_tokens_beside_decode",
@@ -924,35 +1033,35 @@ class RaggedInferenceEngineV2:
                                  "work that rode a decode step")
         tel.inc_counter("inference/decode_tokens", v=accepted,
                         help="decode tokens accepted by the scheduler")
-        return written + accepted
-
-    def _commit_chunks(self, tel: Any, chunks, firsts, eos_token_id) -> int:
-        """The chunks a fetched call wrote, handed to the scheduler (after
-        the fetch: its prefix index sees written pages); returns their
-        prompt tokens.  A chunk whose request is not where the call left
-        it (cancelled or preempted between the rounds) is passed over."""
-        live = [(i, ch) for i, ch in enumerate(chunks)
-                if ch.request.state is RequestState.PREFILL
-                and ch.request.prefilled == ch.start_pos]
         bs = self.cache_config.block_size
-        self._count_recycled(tel, [ch.start_pos // bs for _, ch in live],
-                             [-(-ch.n_valid // bs) for _, ch in live])
-        for i, ch in live:
-            self.scheduler.chunk_done(
-                ch, int(firsts[i]) if ch.is_last else None, eos_token_id)
-        return sum(ch.n_valid for _, ch in live)
-
-    def _publish_pages_in_use(self, tel: Any) -> None:
-        sched = self.scheduler
-        tokens = (self.cache_config.num_blocks - 1
-                  - sched.allocator.num_free)
-        for kind in self.kinds.values():
-            tel.set_gauge(
-                f"inference/kv/pages_in_use/{kind.name}",
-                float(sched.ring_pages_in_use() if kind.ring else tokens),
-                help="pages of the kind's pool that live sequences hold "
-                     "(a recycled kind: at most a ring a sequence), each "
-                     "over all the kind's layers")
+        self._count_recycled(tel, [ch.start_pos // bs for ch in live],
+                             [-(-ch.n_valid // bs) for ch in live])
+        if moe is not None:
+            self._count_moe(tel, moe, c.steps)
+        chunk_rows = self.prefill_batch * self.chunk if c.chunks else 0
+        tel.inc_counter("inference/calls",
+                        help="program calls committed (one a round)")
+        tel.inc_counter("inference/calls_with_chunks",
+                        v=1.0 if c.chunks else 0.0,
+                        help="committed calls that carried prefill chunks")
+        tel.inc_counter("inference/rows_computed",
+                        v=c.steps * self.max_slots + chunk_rows,
+                        help="rows the committed calls computed: every "
+                             "decode slot a step, and every chunk row of "
+                             "a call that carried chunks, live or not")
+        tel.inc_counter("inference/chunk_rows_computed", v=chunk_rows,
+                        help="the chunk rows among rows_computed "
+                             "(prefill_batch x prefill_chunk a call that "
+                             "carried chunks)")
+        tel.inc_counter("inference/rows_live", v=accepted + written,
+                        help="rows of the committed calls whose result "
+                             "was kept: decode tokens accepted and valid "
+                             "prompt tokens committed")
+        if c.dispatched is not None:
+            tel.tracer.add("inference/call", c.dispatched, committed, {
+                "call": c.call, "steps": c.steps,
+                "decode_rows": len(c.decode), "chunk_tokens": written,
+                "accepted": accepted, "kb": c.kb, "wait_s": wait_s})
 
     def _ring_bases(self, rows: int, requests) -> Optional[np.ndarray]:
         """``[rows]``: the first page of each request's ring at its row
@@ -970,7 +1079,7 @@ class RaggedInferenceEngineV2:
         over sequences) were begun by a call: those past a ring's length
         overwrote a page that fell out of the window."""
         ring = self.cache_config.ring_blocks
-        if not (ring and tel.enabled):
+        if not ring:
             return
         first_page, pages = np.asarray(first_page), np.asarray(pages)
         tel.inc_counter(
@@ -981,9 +1090,9 @@ class RaggedInferenceEngineV2:
                  "later page of the same sequence (logical pages: each is "
                  "one page in every window layer)")
 
-    def _pack_chunks(self, tel: Any, chunks) -> Tuple:
+    def _pack_chunks(self, tel: Any, chunks) -> Tuple[Tuple, int]:
         """``chunks`` as the one-step program takes them
-        (:meth:`_decode_burst_fn`)."""
+        (:meth:`_decode_burst_fn`), and their page bucket."""
         with tel.span("inference/pack", args={"kind": "prefill"}):
             Bp, C = self.prefill_batch, self.chunk
             tokens = np.zeros((Bp, C), np.int32)
@@ -998,7 +1107,8 @@ class RaggedInferenceEngineV2:
                 last[i] = max(ch.n_valid - 1, 0)
             rings = self._ring_bases(
                 Bp, ((i, ch.request) for i, ch in enumerate(chunks)))
-        return tokens, tables, start, last, rings
+            kb = self._prefill_bucket(chunks)
+        return (tokens, tables, start, last, rings), kb
 
     def _count_cache_traffic(self, tel: Any, kv_lens, max_pos, burst) -> None:
         """What the decode steps of a call read of each kind's cache, and
@@ -1032,8 +1142,8 @@ class RaggedInferenceEngineV2:
         # reuses the full-length program
         burst, riding, bucket = self.decode_burst, None, {}
         if chunks:
-            burst, riding = 1, self._pack_chunks(tel, chunks)
-            bucket = {"kb": self._prefill_bucket(chunks)}
+            burst = 1
+            riding, bucket["kb"] = self._pack_chunks(tel, chunks)
         with tel.span("inference/pack", args={"kind": "decode"}):
             B = self.max_slots
             tokens = np.zeros((B,), np.int32)
@@ -1048,17 +1158,16 @@ class RaggedInferenceEngineV2:
                 max_pos[s] = len(req.prompt) + req.max_new_tokens - 1
                 tables[s] = self.scheduler.table_row(req)
             rings = self._ring_bases(B, ((r.slot, r) for r in decode))
-            if tel.enabled and decode:
-                live = [r.slot for r in decode]
-                self._count_cache_traffic(tel, kv_lens[live], max_pos[live],
-                                          burst)
-        with tel.span("inference/decode_burst/dispatch"), \
+            self._calls += 1
+        with tel.span("inference/decode_burst/dispatch",
+                      args={"call": self._calls}) as sp, \
                 self._collecting_moe():
             toks, self.pool, moe_aux, firsts = self._decode(burst)(
                 self.params, self.pool, tokens, kv_lens, tables, max_pos,
-                temp, self._next_key(), rings, riding, **bucket)
-        self._inflight = (chunks, decode, burst, (toks, firsts, moe_aux),
-                          eos_token_id)
+                temp, self._next_key(tel), rings, riding, **bucket)
+        self._inflight = _Call(chunks, decode, burst, (toks, firsts, moe_aux),
+                               eos_token_id, self._calls, bucket.get("kb"),
+                               kv_lens, max_pos, sp.start)
 
     def generate(self, prompts: List[List[int]], max_new_tokens: int = 32,
                  temperature: float = 0.0, seed: int = 0,
@@ -1074,11 +1183,6 @@ class RaggedInferenceEngineV2:
             total += self.step(temperature, eos_token_id)
         dt = time.perf_counter() - t0
         self.last_throughput = total / dt if dt > 0 else 0.0
-        from ...telemetry import get_telemetry
-
-        get_telemetry().set_gauge(
-            "inference/tokens_per_sec", self.last_throughput,
-            help="tokens/sec of the last generate() drive")
         return [r.generated for r in reqs]
 
 

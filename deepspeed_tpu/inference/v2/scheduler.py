@@ -102,6 +102,11 @@ class RaggedScheduler:
         self._free_rings: List[int] = list(
             range(cache_config.num_rings - 1, -1, -1)
             if cache_config.ring_blocks else ())
+        # the occupancy gauges are worked out when the registry is read,
+        # not in every plan; the hub holds the hook weakly
+        from ...telemetry import get_telemetry
+
+        get_telemetry().add_collect_hook(self._publish_gauges)
 
     def _make_allocator(self, num_blocks: int) -> BlockAllocator:
         """Subclass hook: the serving scheduler swaps in its refcounted
@@ -216,10 +221,11 @@ class RaggedScheduler:
             self.prefilling.append(req)
 
     def telemetry_gauges(self) -> dict:
-        """Scheduler occupancy numbers, published each ``plan_step``:
-        queue depth, decode-slot occupancy, and KV-pool utilization (the
-        pool is the 'cache' — utilization is pages committed to live
-        sequences over the allocatable pool)."""
+        """Scheduler occupancy numbers, published when the registry is
+        read (:meth:`_publish_gauges`): queue depth, decode-slot
+        occupancy, and KV-pool utilization (the pool is the 'cache' —
+        utilization is pages committed to live sequences over the
+        allocatable pool)."""
         occupied = sum(1 for s in self.slots if s is not None)
         allocatable = self.cache.num_blocks - 1  # page 0 reserved
         return {
@@ -230,16 +236,21 @@ class RaggedScheduler:
                 (allocatable - self.allocator.num_free) / max(allocatable, 1),
         }
 
-    def plan_step(self) -> tuple:
-        """→ (list[PrefillChunk] (≤ ``prefill_batch``, one chunk per
-        distinct prefilling request), decode_requests) for this step."""
-        self._admit()
+    def _publish_gauges(self) -> None:
+        """The registry's collect hook: it runs on the reader's thread,
+        beside a round, and takes no lock; what it reads (queue lengths,
+        the slots, the free list's length) is safe to read there."""
         from ...telemetry import get_telemetry
 
         tel = get_telemetry()
         if tel.enabled:
             for name, v in self.telemetry_gauges().items():
                 tel.set_gauge(name, v)
+
+    def plan_step(self) -> tuple:
+        """→ (list[PrefillChunk] (≤ ``prefill_batch``, one chunk per
+        distinct prefilling request), decode_requests) for this step."""
+        self._admit()
         chunks: List[PrefillChunk] = []
         for req in list(self.prefilling)[:self.prefill_batch]:
             start = req.prefilled

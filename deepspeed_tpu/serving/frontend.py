@@ -296,7 +296,12 @@ class ServingFrontend:
         self.stop()
         from ..telemetry import get_telemetry
 
-        get_telemetry().remove_collect_hook(self._publish_gauges)
+        tel = get_telemetry()
+        if tel.enabled:
+            # no round sets a gauge: the registry keeps this front-end's,
+            # its engines' and their schedulers' last state from here
+            tel.collect()
+        tel.remove_collect_hook(self._publish_gauges)
         for wd in self._watchdogs:
             try:
                 wd.remove_trip_listener(self._on_watchdog_trip)
@@ -531,11 +536,12 @@ class ServingFrontend:
         with self._lock:
             self._round += 1
             with tel.span("serving/pump", args={"round": self._round}):
-                # one health-probe evaluation per replica per round: every
-                # healthy() call below this reuses the memoized verdict
-                for r in self.router.replicas:
-                    r.new_round(self._round)
                 with tel.span("serving/admit") as sp:
+                    # one health-probe evaluation per replica per round:
+                    # every healthy() call below this reuses the memoized
+                    # verdict
+                    for r in self.router.replicas:
+                        r.new_round(self._round)
                     queued = sum(len(q) for q in self._queues.values())
                     seated = 0
                     self._drain_dead()

@@ -357,7 +357,7 @@ class ServingScheduler(RaggedScheduler):
 
     def telemetry_gauges(self) -> dict:
         # extends the base occupancy gauges, so the pool/prefix numbers
-        # publish through the existing plan_step path automatically
+        # publish through the base scheduler's collect hook
         g = super().telemetry_gauges()
         g["serving/kv_pages_cached"] = float(self.allocator.num_cached)
         g["serving/kv_pages_free"] = float(self.allocator.num_free)

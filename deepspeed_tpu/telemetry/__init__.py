@@ -95,7 +95,7 @@ class Telemetry:
         #: run before the registry is read as a whole; kept here and not
         #: on the registry so that ``reset()`` carries them over
         self._collect_hooks: List[Callable[[], Any]] = []
-        self.registry = MetricsRegistry(before_read=self._collect)
+        self.registry = MetricsRegistry(before_read=self.collect)
         self.output_path: Optional[str] = None
         self.chrome_trace = False
         self.prometheus = True
@@ -136,7 +136,7 @@ class Telemetry:
             self.enabled = False
             self.output_path = None
             self.tracer = SpanTracer(self.tracer.max_events)
-            self.registry = MetricsRegistry(before_read=self._collect)
+            self.registry = MetricsRegistry(before_read=self.collect)
 
     # -- collect hooks -----------------------------------------------------
 
@@ -150,14 +150,20 @@ class Telemetry:
         holds for long."""
         ref = weakref.WeakMethod(fn) if inspect.ismethod(fn) else (lambda: fn)
         with self._lock:
-            self._collect_hooks.append(ref)
+            # owners that were collected since: a process that makes many
+            # engines and never reads its registry keeps no list of them
+            self._collect_hooks = [r for r in self._collect_hooks
+                                   if r() is not None] + [ref]
 
     def remove_collect_hook(self, fn: Callable[[], None]) -> None:
         with self._lock:
             self._collect_hooks = [r for r in self._collect_hooks
                                    if r() not in (None, fn)]
 
-    def _collect(self) -> None:
+    def collect(self) -> None:
+        """Run the collect hooks: what a read of the whole registry does
+        first.  A source calls it before it goes away, so that the gauges
+        it feeds keep its last state (a front-end's ``close()``)."""
         with self._lock:
             live = [(r, fn) for r in self._collect_hooks
                     for fn in [r()] if fn is not None]

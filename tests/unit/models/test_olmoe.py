@@ -254,7 +254,9 @@ def test_the_engine_counts_assignments_and_active_experts(model, params):
         active = value(names[1]) - before[names[1]]
         assert 5 * layers * k <= active <= 5 * layers * experts
         assert float(active).is_integer()
-        assert value("inference/moe/drop_rate") == 0.0
+        # a gauge of the router's last stats, worked out on a read
+        assert tel.registry.snapshot()["gauges"][
+            "inference/moe/drop_rate"]["value"] == 0.0
         assert engine.last_moe_stats["drop_rate"] == 0.0
         assert len(engine.last_moe_stats["load"]) == experts
     finally:
@@ -274,15 +276,15 @@ def test_gate_stats_that_do_not_fit_are_skipped_not_raised(model, params):
     engine.generate([np.asarray(IDS[0, :20]).tolist()], max_new_tokens=5)
     assert "moe/load" in dict(engine._moe_columns)
     width = sum(w for _, w in engine._moe_columns)
-    tel = telemetry.configure(enabled=False)
+    telemetry.configure(enabled=False)
     signal = engine.last_moe_stats
     packed = np.zeros((2, width), np.float32)
-    engine._ingest_moe_stats(packed[:, :-1], tel)               # too narrow
-    engine._ingest_moe_stats(packed[0], tel)                    # one row
+    assert engine._ingest_moe_stats(packed[:, :-1]) is None     # too narrow
+    assert engine._ingest_moe_stats(packed[0]) is None          # one row
     assert engine.last_moe_stats is signal
     engine._moe_columns = [
         (n, w) for n, w in engine._moe_columns
         if n != "moe/drop_rate"] + [("moe/other", 1)]
-    engine._ingest_moe_stats(packed, tel)                        # no entry
+    assert "moe/other" in engine._ingest_moe_stats(packed)      # no entry
     assert engine.last_moe_stats is not signal
     assert engine.last_moe_stats["drop_rate"] == 0.0

@@ -18,7 +18,7 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.inference.v2 import KVCacheConfig
+from deepspeed_tpu.inference.v2 import KVCacheConfig, engine_v2
 from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.serving import ServingParams, build_serving_frontend
 from deepspeed_tpu.telemetry import tracer as tracer_mod
@@ -31,7 +31,9 @@ from deepspeed_tpu.telemetry.perf.compile_tracker import program_name
 #: burst) and fetches it at the start of the next step, so
 #: ``inference/decode_burst`` is the wait for it (its fetch) and the
 #: dispatch lies where it happens; no call of its own prefills, so there is
-#: no ``inference/prefill`` span
+#: no ``inference/prefill`` span.  Since ISSUE 38 no stretch of a round lies
+#: outside a leaf: the sampling keys' refill (once in 256 calls, the first
+#: among them) and the hub's own accounting, after the dispatch, have spans
 TREE = {
     "serving/pump": None,
     "serving/admit": "serving/pump",
@@ -40,8 +42,10 @@ TREE = {
     "inference/pack": "inference/step",
     "inference/decode_burst": "inference/step",
     "inference/decode_burst/dispatch": "inference/step",
+    "inference/keys": "inference/decode_burst/dispatch",
     "inference/decode_burst/fetch": "inference/decode_burst",
     "inference/commit": "inference/step",
+    "inference/observe": "inference/step",
     "serving/deliver": "serving/pump",
     "serving/ledger": "serving/pump",
 }
@@ -118,9 +122,9 @@ def test_a_round_with_chunks_is_one_call_left_running(tiny_model):
     """A mixed run (four prompts over two slots, answers long enough that
     later prompts come in beside decoding rows): the children of a step
     that carries chunks are, in order, the last call's wait and its one
-    commit, the plan, the chunks' pack and the decode rows' pack, and ONE
-    dispatch; and most prompt tokens are written by a call that also
-    yields a decode token."""
+    commit, the plan, the chunks' pack and the decode rows' pack, ONE
+    dispatch, and the hub's accounting behind it; and most prompt tokens
+    are written by a call that also yields a decode token."""
     tel = telemetry.get_telemetry()
     tel.reset()
     tel.configure(enabled=True, jsonl=False, prometheus=False)
@@ -157,6 +161,8 @@ def test_a_round_with_chunks_is_one_call_left_running(tiny_model):
         if was_running:
             want += [("inference/pack", "decode"),
                      ("inference/decode_burst/dispatch", None)]
+        if len(want) > 1:       # something was committed or dispatched
+            want.append(("inference/observe", None))
         assert kids == want, args
     beside = counters["inference/chunk_tokens_beside_decode"]
     assert counters["inference/prefill_tokens"] == 6 + 20 + 22 + 15
@@ -197,7 +203,7 @@ def test_prefill_and_decode_spans_read_as_at_the_parent_commit(served):
     queued third request is seated a round later and one more burst
     (of the one request left decoding) runs before its prefill."""
     got = [{k: v for k, v in e["args"].items()
-            if k not in ("depth", "parent")}
+            if k not in ("depth", "parent", "call")}
            for e in named(served, "inference/decode_burst")]
     chunks = [s["args"]["chunks"] for s in named(served, "inference/step")]
     assert chunks == [2, 1, 0, 0, 1, 1, 1, 0, 0, 0]
@@ -281,14 +287,203 @@ def test_hub_off_costs_one_shared_object_and_nothing_else(
             return False
 
     monkeypatch.setattr(tel.tracer, "_lock", NoLock())
+    # the hub's own accounting is not run at all: no call's record, no
+    # NumPy over the packed lengths, none of the router's counters
+    for name in ("_observe", "_count_call", "_count_cache_traffic",
+                 "_count_recycled", "_count_moe"):
+        monkeypatch.setattr(engine_v2.RaggedInferenceEngineV2, name, refuse)
     fe = make_frontend(tiny_model)
     handles = serve_three(fe)
+    eng = fe.router.replicas[0].engine
     fe.close()
     assert all(len(h.result()) == 6 for h in handles)
     monkeypatch.undo()
     assert tel.tracer.events() == []
     assert not any(name.startswith(("serving/", "inference/"))
                    for name in tel.registry.metrics())
+    # what a call costs with the hub off: its number
+    assert eng._calls == 9 and eng._inflight is None
+
+
+def test_a_calls_spans_in_two_rounds_carry_its_number(served):
+    """A round's dispatch and the NEXT round's wait, fetch and commit are
+    one call's: they share ``call``, the engine's count of calls."""
+    by_call = {}
+    for e in served["events"]:
+        if "call" in e["args"] and e["name"] != "inference/call":
+            by_call.setdefault(e["args"]["call"], []).append(e["name"])
+    assert sorted(by_call) == list(range(1, 10))
+    # the ring keeps spans as they close: the dispatch a round before the
+    # fetch, its wait and the commit
+    assert all(names == ["inference/decode_burst/dispatch",
+                         "inference/decode_burst/fetch",
+                         "inference/decode_burst", "inference/commit"]
+               for names in by_call.values())
+    steps = [i for i, e in enumerate(served["events"])
+             if e["name"] == "inference/step"]
+    which = lambda i: sum(s < i for s in steps)     # the step a span lies in
+    for call in by_call:
+        at = {e["name"]: which(i) for i, e in enumerate(served["events"])
+              if e["args"].get("call") == call}
+        assert at["inference/commit"] == at["inference/decode_burst"] \
+            == at["inference/decode_burst/dispatch"] + 1
+        assert at["inference/call"] == at["inference/commit"]
+
+
+def test_every_program_call_has_one_record_of_what_was_committed(served):
+    calls = named(served, "inference/call")
+    waits = named(served, "inference/decode_burst")
+    assert [c["args"]["call"] for c in calls] == list(range(1, 10))
+    # stamped elsewhere: in no thread's tree
+    assert all("depth" not in c["args"] and "parent" not in c["args"]
+               for c in calls)
+    got = [(c["args"]["steps"], c["args"]["decode_rows"]) for c in calls]
+    assert got == [(w["args"]["burst"], w["args"]["batch"]) for w in waits]
+    # what the scheduler committed: the prompts' 36 tokens, chunk by chunk
+    # (5 + 8, 4, then 8 + 8 + 3 of the third prompt, seated late), and the
+    # 15 decode tokens (a request's first token is a chunk's)
+    assert [c["args"]["chunk_tokens"] for c in calls] == [
+        13, 4, 0, 0, 8, 8, 3, 0, 0]
+    assert [c["args"]["accepted"] for c in calls] == [
+        0, 1, 8, 1, 0, 0, 0, 4, 1]
+    assert [c["args"]["kb"] for c in calls] == [
+        2, 4, None, None, 2, 4, 8, None, None]
+    dispatch = {e["args"]["call"]: e
+                for e in named(served, "inference/decode_burst/dispatch")}
+    commit = {e["args"]["call"]: e
+              for e in named(served, "inference/commit")}
+    fetch = {e["args"]["call"]: e
+             for e in named(served, "inference/decode_burst/fetch")}
+    for c in calls:
+        n = c["args"]["call"]
+        # from the dispatch span's start to the commit's end
+        assert c["ts"] == dispatch[n]["ts"]
+        assert c["ts"] + c["dur"] == pytest.approx(
+            commit[n]["ts"] + commit[n]["dur"], abs=0.21)
+        assert c["args"]["wait_s"] == pytest.approx(
+            fetch[n]["dur"] * 1e-6, abs=2e-7)
+
+
+def test_rows_are_counted_where_they_are_decided(served):
+    counters = served["counters"]
+    calls = named(served, "inference/call")
+    slots, chunk_rows = 2, 2 * 8           # B; prefill_batch x prefill_chunk
+    assert counters["inference/calls"] == len(calls) == len(
+        named(served, "inference/decode_burst")) == 9
+    assert counters["inference/calls_with_chunks"] == sum(
+        c["args"]["kb"] is not None for c in calls) == 5
+    assert counters["inference/chunk_rows_computed"] == 5 * chunk_rows
+    assert counters["inference/rows_computed"] == sum(
+        c["args"]["steps"] for c in calls) * slots + 5 * chunk_rows
+    # to the token what the program already counts over the same rounds
+    assert counters["inference/rows_live"] == (
+        counters["inference/decode_tokens"]
+        + counters["inference/prefill_tokens"]) == 15 + 36
+    assert counters["inference/rows_live"] \
+        <= counters["inference/rows_computed"]
+    # one accounting span a step that committed or dispatched a call
+    assert len(named(served, "inference/observe")) == 10
+
+
+def _moe_engine():
+    from deepspeed_tpu.inference.v2 import build_engine_v2
+    from deepspeed_tpu.models import MixtralConfig, MixtralModel
+
+    cfg = MixtralConfig.tiny(num_layers=2, max_seq_len=64,
+                             dtype=jnp.float32, num_experts=4, top_k=2)
+    model = MixtralModel(cfg)
+    return build_engine_v2(
+        model, model.init_params(jax.random.PRNGKey(2)),
+        cache_config=KVCacheConfig(num_blocks=64, block_size=4,
+                                   max_seq_len=64),
+        max_batch_slots=2, prefill_chunk=8)
+
+
+def _expected_gauges(eng):
+    """What a scrape must read, from the state the engine and its
+    scheduler keep anyway."""
+    want = dict(eng.scheduler.telemetry_gauges())
+    want["inference/kv/pages_in_use/kv"] = float(
+        eng.cache_config.num_blocks - 1 - eng.scheduler.allocator.num_free)
+    stats = eng.last_moe_stats
+    if stats:
+        want.update({f"inference/moe/expert_load_e{e}": frac
+                     for e, frac in enumerate(stats["load"])})
+        want["inference/moe/load_imbalance"] = stats["imbalance"]
+        want["inference/moe/drop_rate"] = stats["drop_rate"]
+    return want
+
+
+@pytest.mark.parametrize("family", ["scheduler", "pools", "router"])
+def test_a_rounds_gauges_are_read_from_a_scrape_and_set_by_no_round(
+        tiny_model, family):
+    """``inference/queue_depth`` and its three neighbours, the pools'
+    ``pages_in_use`` and the router's per-expert load were set in every
+    round; now the registry's readers work them out, with the values a
+    round would have set."""
+    tel = telemetry.get_telemetry()
+    tel.configure(enabled=True, jsonl=False, prometheus=False)
+    if family == "router":
+        eng = _moe_engine()
+    else:
+        model, params = tiny_model
+        eng = engine_v2.build_engine_v2(
+            model, params, KVCacheConfig(num_blocks=64, block_size=4,
+                                         max_seq_len=64),
+            max_batch_slots=2, prefill_chunk=8, decode_burst=4)
+    rng = np.random.RandomState(5)
+    for n in (9, 6, 7):                     # two seated, one queued
+        eng.put(rng.randint(1, 512, size=n).tolist(), max_new_tokens=6)
+    for _ in range(3):
+        eng.step_ahead()
+    names = {"scheduler": {"inference/queue_depth", "inference/prefilling",
+                           "inference/batch_occupancy",
+                           "inference/kv_pool_utilization"},
+             "pools": {"inference/kv/pages_in_use/kv"},
+             "router": {f"inference/moe/expert_load_e{e}" for e in range(4)}
+             | {"inference/moe/load_imbalance", "inference/moe/drop_rate"}
+             }[family]
+    gauges = lambda: {n for n, m in tel.registry.metrics().items()
+                      if m.kind == "gauge"}
+    assert not names & gauges()          # no round set them
+    want = _expected_gauges(eng)
+    assert names <= set(want) and want["inference/queue_depth"] == 1.0
+    snap = tel.registry.snapshot()["gauges"]
+    parsed = telemetry.parse_prometheus_text(tel.prometheus_text())
+    for name in names:
+        assert snap[name]["value"] == pytest.approx(want[name], abs=1e-12)
+        assert parsed[telemetry.prom_name(name)] == pytest.approx(
+            want[name], rel=1e-6)
+        assert snap[name]["help"] or family == "scheduler"
+    if family == "pools":
+        assert want["inference/kv/pages_in_use/kv"] > 0
+    if family == "router":
+        assert sum(want[f"inference/moe/expert_load_e{e}"]
+                   for e in range(4)) == pytest.approx(1.0, abs=1e-4)
+    # the next scrape follows the state: everything finished and released
+    while eng.scheduler.has_work:
+        eng.step()
+    after = tel.registry.snapshot()["gauges"]
+    later = _expected_gauges(eng)
+    assert all(after[n]["value"] == pytest.approx(later[n], abs=1e-12)
+               for n in names)
+    if family != "router":
+        assert all(later[n] == 0.0 for n in names)
+
+
+def test_no_gauge_is_set_inside_a_round():
+    """The engine and the v2 scheduler call ``set_gauge`` in their collect
+    hooks and nowhere else: not in ``plan_step``, ``step_ahead``,
+    ``_ingest_moe_stats`` or ``generate``."""
+    root = pathlib.Path(deepspeed_tpu.__file__).parent / "inference" / "v2"
+    for f in ("engine_v2.py", "scheduler.py"):
+        where = set()
+        for fn in ast.walk(ast.parse((root / f).read_text())):
+            if isinstance(fn, ast.FunctionDef):
+                where |= {fn.name for node in ast.walk(fn)
+                          if isinstance(node, ast.Attribute)
+                          and node.attr == "set_gauge"}
+        assert where == {"_publish_gauges"}, f
 
 
 @pytest.mark.filterwarnings("ignore:builtin type event_stats")
@@ -316,7 +511,8 @@ def test_spans_land_in_the_profilers_trace_nested_on_one_thread(
     events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
               for e in line.events]
     names = {n for n, _, _ in events}
-    assert set(TREE) <= names
+    # the keys were refilled before the trace opened: 256 calls' worth
+    assert set(TREE) - {"inference/keys"} <= names
     assert not any(n.startswith("serving/request/") for n in names)
     outer = next(e for e in events if e[0] == "bench/pump")
     pumps = [e for e in events if e[0] == "serving/pump"]
@@ -384,8 +580,17 @@ def test_the_engines_own_programs_carry_their_names(tiny_model):
     assert not hasattr(eng, "_prefill")
 
 
+def live_hooks(tel):
+    """The hub's collect hooks whose owners are alive (an engine of an
+    earlier test, kept by a fixture, may be among them)."""
+    gc.collect()
+    tel.collect()
+    return list(tel._collect_hooks)
+
+
 def test_gauges_are_worked_out_when_the_registry_is_read(tiny_model):
     tel = telemetry.get_telemetry()
+    before = live_hooks(tel)
     tel.configure(enabled=True, jsonl=False, prometheus=False)
     fe = make_frontend(tiny_model)
     serve_three(fe)
@@ -401,13 +606,17 @@ def test_gauges_are_worked_out_when_the_registry_is_read(tiny_model):
     assert parsed["serving_batch_queue_depth"] == 0
     snap = tel.registry.snapshot()["gauges"]
     assert snap["serving/batch_tpot_p50_ms"]["value"] > 0
-    # a closed front-end leaves no hook (and so no reference) behind
+    # a closed front-end leaves no hook behind; its engine's and its
+    # scheduler's are held weakly and go with them
     fe.close()
-    assert tel._collect_hooks == []
+    assert len(live_hooks(tel)) == len(before) + 2
+    del fe
+    assert live_hooks(tel) == before
 
 
 def test_a_failing_collect_hook_does_not_break_the_export():
     tel = telemetry.get_telemetry()
+    before = live_hooks(tel)
     tel.configure(enabled=True, jsonl=False, prometheus=False)
     tel.inc_counter("t/ok")
 
@@ -418,11 +627,12 @@ def test_a_failing_collect_hook_does_not_break_the_export():
     assert "t_ok 1" in tel.prometheus_text()
     tel.remove_collect_hook(bad)
     tel.remove_collect_hook(bad)      # twice is harmless
-    assert tel._collect_hooks == []
+    assert tel._collect_hooks == before
 
 
 def test_a_hook_outlives_the_hubs_reset_and_not_its_owner():
     tel = telemetry.get_telemetry()
+    before = live_hooks(tel)
 
     class Source:
         def publish(self):
@@ -437,7 +647,7 @@ def test_a_hook_outlives_the_hubs_reset_and_not_its_owner():
     gc.collect()
     tel.registry.reset()
     assert "t_derived" not in tel.prometheus_text()
-    assert tel._collect_hooks == []
+    assert tel._collect_hooks == before
 
 
 def test_a_scrape_never_waits_for_the_round(tiny_model, monkeypatch):
