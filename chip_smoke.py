@@ -401,7 +401,8 @@ def run_server(cfg: Any, prompt_lengths: Sequence[int] = PROMPT_LENGTHS,
     B, mb = engine.max_slots, cache.max_blocks_per_seq
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     kernels = kernel_names(decode.lower(
-        engine.params, engine.pool, i32(B), i32(B), i32(B, mb), i32(B),
+        engine.params, engine.pool, i32(B),
+        (i32(B), i32(B + engine.prefill_batch)), i32(B), i32(B, mb), i32(B),
         jax.ShapeDtypeStruct((), jnp.float32), engine._key).as_text())
 
     # logit-level agreement with the plain forward pass, teacher-forced on

@@ -49,15 +49,33 @@ ring, so the paged kernel's window walk and the chunks' gather find the
 window's keys where the block table of a growing sequence would have put
 them, and the pages behind the window are overwritten.
 
-``step_ahead`` (the serving front-end's entry) returns with the round's
-call still running: the next call fetches it and commits its chunks and
-its tokens before it plans.  The device runs while the front-end delivers
-and admits.  A call is numbered as it is dispatched (``_Call.call``), and
-with the telemetry hub on its spans in the two rounds carry that number;
-the hub's own accounting for a step runs after the dispatch, under the
-device (``_observe``).  The programs take their small arguments as NumPy
-arrays (one transfer inside the call, not an upload each) and their
-sampling keys from a chain split 256 links at a time (``_next_key``).
+**A second call in flight.**  ``step_ahead`` (the serving front-end's
+entry) plans, packs and dispatches the round's call FIRST, behind the
+previous round's call, which is still running, and only then fetches and
+commits that one; it returns with the new call in flight.  The device goes
+from one call to the next without waiting for the host: the read-back, the
+commit, the plan, the packs, the dispatch and whatever the front-end does
+between two rounds all run under it.  Two things make that possible.  The
+scheduler plans from what has been DISPATCHED, beside what has been
+committed (``Request.ahead_*``): everything a plan reads is settled once a
+call is out, except an EOS.  And a row whose newest token is still on the
+device takes it there: every program returns its rows' newest tokens as
+one small array, the next call's argument, and a host-packed map says
+which rows read it (``_dispatch``).  The invariant that keeps this safe:
+**a call is committed by what it was packed under** (``_settle``): a row
+whose request ended, was cancelled, preempted or moved between dispatch
+and commit is passed over, as a burst's surplus tokens always were.  And
+it relies on the device running calls **in the order of dispatch**: pages
+a request gives back are handed out at once, while a call in flight may
+still write them, because the call that writes the next owner's keys
+comes later.  ``step`` and ``settle`` leave nothing in flight.
+
+A call is numbered as it is dispatched (``_Call.call``), and with the
+telemetry hub on its spans in the two rounds carry that number; the hub's
+own accounting for a step runs last (``_observe``).  The programs take
+their small arguments as NumPy arrays (one transfer inside the call, not
+an upload each) and their sampling keys, one a call in the order of
+dispatch, from a chain split 256 links at a time (``_next_key``).
 
 A chunk's cost is O(pages allocated so far), not O(max_seq_len): its rows
 gather/mask only ``kb`` pages each, where ``kb`` is the smallest
@@ -72,7 +90,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+from collections import deque
+from typing import (Any, Callable, Deque, Dict, List, NamedTuple, Optional,
                     Tuple)
 
 import jax
@@ -96,14 +115,25 @@ class _null_ctx:
         return None
 
 
+class _Row(NamedTuple):
+    """A decode row as its call was packed."""
+    request: Request
+    slot: int                   # the row, and the column of its tokens
+    position: int               # where its first step writes its key
+
+
 class _Call(NamedTuple):
-    """One program call, from its dispatch to its commit a round later."""
+    """One program call, from its dispatch to its commit a round later.
+    It is committed by what it was PACKED under: a chunk at its start, a
+    decode row at its slot and position, wherever the request is by then
+    (:meth:`RaggedInferenceEngineV2._settle`)."""
     chunks: list                # the prefill chunks riding in it
-    decode: list                # the requests decoding in it
+    decode: List[_Row]          # the rows decoding in it
     steps: int                  # 1 (it may carry chunks) or the burst
     outputs: tuple              # on the device: (tokens, firsts, gate stats)
     eos_token_id: Optional[int]     # the EOS id to accept under
     call: int                   # its number, the engine's count of calls
+    ahead: bool                 # dispatched with the call before uncommitted
     kb: Optional[int]           # the chunks' page bucket
     kv_lens: np.ndarray         # [B] as packed: the decode rows' lengths
     max_pos: np.ndarray         # [B] as packed: their last positions
@@ -227,8 +257,14 @@ class RaggedInferenceEngineV2:
         self.decode_burst = max(1, decode_burst)
         self._decode_jits: Dict[int, Callable] = {}
         self._reseed(0)
-        #: the call ``step_ahead`` left running
-        self._inflight: Optional[_Call] = None
+        #: the calls dispatched and not committed yet, oldest first: one
+        #: between two rounds, a second behind it inside ``step_ahead``
+        self._inflight: Deque[_Call] = deque()
+        #: the last call's ``newest`` (zeros before the first): the next
+        #: call's argument whether it reads it or not, so that every call
+        #: of a program has the one signature
+        self._newest = np.zeros((max_batch_slots + self.prefill_batch,),
+                                np.int32)
         #: program calls dispatched so far: the next call's number
         self._calls = 0
         #: MoE serving telemetry (ISSUE 19): when the model routes through
@@ -641,7 +677,7 @@ class RaggedInferenceEngineV2:
 
         return write_fn, attend_fn
 
-    def _decode_burst_fn(self, params, pool, tokens, kv_lens, tables,
+    def _decode_burst_fn(self, params, pool, tokens, fed, kv_lens, tables,
                          max_pos, temperature, key, rings=None, chunks=None,
                          *, n_steps: int, kb: Optional[int] = None):
         """``n_steps`` decode iterations entirely on device: each step
@@ -653,6 +689,11 @@ class RaggedInferenceEngineV2:
         where a kind recycles (else None): such a kind's block table is
         the ring, repeated.
 
+        ``fed``: ``(source [B], newest [B + Bp])``.  A row's first input
+        token is ``tokens[r]`` where ``source[r] < 0``, else the previous
+        call's ``newest[source[r]]`` (this program's last result, below):
+        a token the host has not fetched yet (:meth:`_dispatch`).
+
         ``chunks`` (the one-step program only): the round's prefill chunks
         ``(tokens [Bp, C], tables, start_pos, last_idx, rings)``, whose
         ``Bp·C`` rows ride in the step IN FRONT of the decode rows
@@ -661,11 +702,16 @@ class RaggedInferenceEngineV2:
         last valid rows are sampled beside the decode rows.
 
         Returns (token ids ``[n_steps, B]``, pools, the gate's stats
-        packed or None, the chunks' sampled tokens ``[Bp]`` or None)."""
+        packed or None, the chunks' sampled tokens ``[Bp]`` or None, the
+        rows' newest tokens ``[B + Bp]``: the last step's, then the chunks'
+        or zeros)."""
         from ...telemetry import numerics
 
         ad = self.adapter
         B = tokens.shape[0]
+        source, newest = fed
+        tokens = jnp.where(source < 0, tokens,
+                           newest[jnp.maximum(source, 0)])
         tables_of = {
             kind.name: self._ring_pages(
                 rings, jnp.broadcast_to(jnp.arange(tables.shape[1])[None, :],
@@ -709,8 +755,11 @@ class RaggedInferenceEngineV2:
         (_, _, pool), (toks, firsts, stats) = jax.lax.scan(
             one_step, (tokens, kv_lens, pool), keys)
         numerics.scan_collect(stats, combine=True)  # mean over the burst
-        return (toks, pool, self._pack_moe_stats(),
-                None if firsts is None else firsts[0])
+        firsts = None if firsts is None else firsts[0]
+        newest = jnp.concatenate([
+            toks[-1], jnp.zeros((self.prefill_batch,), jnp.int32)
+            if firsts is None else firsts])
+        return toks, pool, self._pack_moe_stats(), firsts, newest
 
     def _decode(self, n_steps: int) -> Callable:
         fn = self._decode_jits.get(n_steps)
@@ -910,81 +959,110 @@ class RaggedInferenceEngineV2:
         """One scheduler step, complete when it returns: ONE program call,
         a decode step that carries the round's prefill chunks while prefill
         work exists (so SplitFuse keeps interleaving chunks with decodes),
-        a burst of ``decode_burst`` steps once all prompts are in.
-        Returns the number of tokens processed."""
+        a burst of ``decode_burst`` steps once all prompts are in, fetched
+        and committed (:meth:`step_ahead` + :meth:`settle`: nothing is in
+        flight before or after).  Returns the number of tokens processed."""
         del rng  # sampling is in-graph now; kept for API compat
         return self.step_ahead(temperature, eos_token_id) + self.settle()
 
     def step_ahead(self, temperature: float = 0.0,
                    eos_token_id: Optional[int] = None) -> int:
-        """:meth:`step` for a caller that comes back: the step's call is
-        left running on the device, and the next ``step_ahead`` (or
-        :meth:`settle`) fetches and commits it first.  So whatever the
-        caller does between two calls (delivering tokens, admitting) costs
-        the device nothing.  Returns the tokens committed in THIS call:
-        the previous call's chunks and decode tokens.
+        """:meth:`step` for a caller that comes back: the round's call is
+        planned, packed and dispatched FIRST, behind the previous round's
+        call, which is still running; only then is that one fetched and
+        committed.  The device starts the new call the moment the old one
+        ends, and the host's whole chain between two calls (read-back,
+        commit, plan, packs, dispatch, and whatever the caller does
+        between two rounds) runs under the device.  At most two calls are
+        uncommitted at any time, one when this returns.  Returns the
+        tokens committed in THIS call: the previous call's chunks and
+        decode tokens.
 
-        A round with chunks runs the one-step decode program with the
-        chunks' rows riding in it (:meth:`_decode_burst_fn`), the decode
-        rows dead where nothing decodes yet; a round without runs the
-        burst.  A request's first token is committed a round later, as
-        every decode token is."""
+        The new call is planned from what has been dispatched
+        (``scheduler.plan_step``), and a row whose newest token the
+        previous call has not delivered yet takes it on the device
+        (:meth:`_dispatch`).  A round with chunks runs the one-step decode
+        program with the chunks' rows riding in it
+        (:meth:`_decode_burst_fn`), the decode rows dead where nothing
+        decodes yet; a round without runs the burst.  Where nothing can be
+        planned (every budget ends in the call in flight), that call is
+        committed and the next round plans again."""
         from ...telemetry import get_telemetry
 
         tel = get_telemetry()
         with tel.span("inference/step") as sp:
-            n_tokens, done = self._settle(tel)
             with tel.span("inference/plan"):
                 chunks, decode = self.scheduler.plan_step()
                 sp.set(chunks=len(chunks), decoding=len(decode))
+            sent = None
             if chunks or decode:
-                self._dispatch(tel, chunks, decode, np.float32(temperature),
-                               eos_token_id)
+                sent = self._dispatch(tel, chunks, decode,
+                                      np.float32(temperature), eos_token_id)
+            n_tokens, done = self._settle(tel, keep=0 if sent is None else 1)
             if tel.enabled:
-                self._observe(tel, done)
+                self._observe(tel, sent, done)
         return n_tokens
 
     def settle(self) -> int:
-        """Fetch and commit the call :meth:`step_ahead` left running, if
-        any; returns the tokens it yielded.  A request that stopped
-        prefilling or running meanwhile (cancelled, preempted) is passed
-        over: it prefills that chunk, or decodes that position, again if
-        it resumes."""
+        """Fetch and commit everything in flight, oldest first; returns
+        the tokens it yielded.  What a call computed for a request that is
+        no longer where the call left it (finished, cancelled, preempted,
+        moved to another slot) is passed over (:meth:`_settle`): the
+        request prefills that chunk, or decodes that position, again if it
+        resumes.  After it ``req.generated``, ``req.prefilled`` and the
+        pool are what a loop of :meth:`step` would have left."""
         from ...telemetry import get_telemetry
 
         tel = get_telemetry()
-        n_tokens, done = self._settle(tel)
+        n_tokens, done = self._settle(tel, keep=0)
         if tel.enabled:
-            self._observe(tel, done)
+            self._observe(tel, None, done)
         return n_tokens
 
-    def _settle(self, tel: Any) -> Tuple[int, Optional[tuple]]:
-        """→ (the tokens the call in flight yielded, what
-        :meth:`_count_call` counts of it: None with the hub off or
-        nothing in flight).  The spans of the wait and the commit carry
-        the number the call was dispatched under."""
-        c = self._inflight
-        if c is None:
-            return 0, None
-        self._inflight = None
-        ident = {"call": c.call}
-        with tel.span("inference/decode_burst",
-                      args={"burst": c.steps, "batch": len(c.decode),
-                            "call": c.call}):
-            with tel.span("inference/decode_burst/fetch", args=ident) as fetch:
-                # [burst, B], [Bp] or None, the gate's stats or None
-                toks, firsts, moe_aux = jax.device_get(c.outputs)
-        with tel.span("inference/commit", args=ident) as commit:
-            moe = None if moe_aux is None else self._ingest_moe_stats(moe_aux)
-            live = self._commit_chunks(c.chunks, firsts, c.eos_token_id)
-            written = sum(ch.n_valid for ch in live)
-            accepted = self.scheduler.decode_burst_done(c.decode, toks,
-                                                        c.eos_token_id)
-        done = None
-        if fetch.end is not None and commit.end is not None:    # hub on
-            done = (c, live, written, accepted, moe,
-                    fetch.end - fetch.start, commit.end)
-        return written + accepted, done
+    def _settle(self, tel: Any, keep: int) -> Tuple[int, List[tuple]]:
+        """Fetch and commit the calls in flight, oldest first, all but the
+        ``keep`` newest → (the tokens they yielded, what
+        :meth:`_count_call` counts of each: empty with the hub off).  The
+        spans of the wait and the commit carry the number the call was
+        dispatched under.
+
+        **A call is committed by what it was packed under**: a chunk only
+        if its request is still prefilling at the chunk's start, a decode
+        row only if its request is still running in the row's slot at the
+        row's position.  So the column read is the slot's AS PACKED, a
+        request re-seated in a freed slot never receives its predecessor's
+        token, and one that was cancelled, preempted, moved or ended
+        (an EOS inside the call before) between dispatch and commit keeps
+        nothing of the call: the row is counted as overrun."""
+        n_tokens, done = 0, []
+        while len(self._inflight) > keep:
+            c = self._inflight.popleft()
+            ident = {"call": c.call}
+            with tel.span("inference/decode_burst",
+                          args={"burst": c.steps, "batch": len(c.decode),
+                                "call": c.call}):
+                with tel.span("inference/decode_burst/fetch",
+                              args=ident) as fetch:
+                    # [burst, B], [Bp] or None, the gate's stats or None
+                    toks, firsts, moe_aux = jax.device_get(c.outputs)
+            with tel.span("inference/commit", args=ident) as commit:
+                moe = (None if moe_aux is None
+                       else self._ingest_moe_stats(moe_aux))
+                live = self._commit_chunks(c.chunks, firsts, c.eos_token_id)
+                written = sum(ch.n_valid for ch in live)
+                # length - 1: the position of a request's newest token
+                rows = [row.request for row in c.decode
+                        if row.request.state is RequestState.RUNNING
+                        and row.request.slot == row.slot
+                        and row.request.length - 1 == row.position]
+                accepted = self.scheduler.decode_burst_done(
+                    rows, toks, c.eos_token_id)
+            n_tokens += written + accepted
+            if fetch.end is not None and commit.end is not None:   # hub on
+                done.append((c, live, written, accepted,
+                             len(c.decode) - len(rows), moe,
+                             fetch.end - fetch.start, commit.end))
+        return n_tokens, done
 
     def _commit_chunks(self, chunks, firsts, eos_token_id) -> list:
         """The chunks a fetched call wrote, handed to the scheduler (after
@@ -999,26 +1077,26 @@ class RaggedInferenceEngineV2:
                 ch, int(firsts[i]) if ch.is_last else None, eos_token_id)
         return [ch for _, ch in live]
 
-    def _observe(self, tel: Any, done: Optional[tuple]) -> None:
+    def _observe(self, tel: Any, sent: Optional[_Call],
+                 done: List[tuple]) -> None:
         """The hub's own accounting for a step, all of it in one place:
-        after the dispatch, where the device is already running, so that
-        the chain between two calls holds in a traced run what it holds
-        in an untraced one.  ``done``: the call this step committed
-        (:meth:`_settle`); the call it dispatched is ``_inflight``."""
-        sent = self._inflight
-        if done is None and sent is None:
+        after the dispatch and the commit, so that the host's chain holds
+        in a traced run what it holds in an untraced one.  ``sent``: the
+        call this step dispatched; ``done``: the calls it committed
+        (:meth:`_settle`)."""
+        if sent is None and not done:
             return
         with tel.span("inference/observe"):
             if sent is not None and sent.decode:
-                live = [r.slot for r in sent.decode]
+                live = [row.slot for row in sent.decode]
                 self._count_cache_traffic(tel, sent.kv_lens[live],
                                           sent.max_pos[live], sent.steps)
-            if done is not None:
-                self._count_call(tel, *done)
+            for counted in done:
+                self._count_call(tel, *counted)
 
     def _count_call(self, tel: Any, c: _Call, live, written: int,
-                    accepted: int, moe, wait_s: float, committed: float
-                    ) -> None:
+                    accepted: int, overrun: int, moe, wait_s: float,
+                    committed: float) -> None:
         """A committed call's counters, and its record: the ring span
         ``inference/call`` from the start of its dispatch (a round ago)
         to the end of its commit."""
@@ -1041,6 +1119,17 @@ class RaggedInferenceEngineV2:
         chunk_rows = self.prefill_batch * self.chunk if c.chunks else 0
         tel.inc_counter("inference/calls",
                         help="program calls committed (one a round)")
+        tel.inc_counter("inference/calls_dispatched_ahead",
+                        v=1.0 if c.ahead else 0.0,
+                        help="committed calls that were dispatched while "
+                             "the call before them was still uncommitted: "
+                             "the device went from one to the next without "
+                             "waiting for the host")
+        tel.inc_counter("inference/rows_overrun", v=overrun,
+                        help="decode rows a committed call computed for a "
+                             "request that had finished, been cancelled or "
+                             "moved by then: planned before the call in "
+                             "front of it was committed, and passed over")
         tel.inc_counter("inference/calls_with_chunks",
                         v=1.0 if c.chunks else 0.0,
                         help="committed calls that carried prefill chunks")
@@ -1131,10 +1220,18 @@ class RaggedInferenceEngineV2:
                              (lengths[-1] - 1) // bs + 1 - -(-kv_lens // bs))
 
     def _dispatch(self, tel: Any, chunks, decode, temp,
-                  eos_token_id) -> None:
+                  eos_token_id) -> _Call:
         """Pack and dispatch the round's one call: ``decode``'s rows and,
-        riding in their step, ``chunks``.  Its outputs stay on the device
-        until :meth:`_settle`."""
+        riding in their step, ``chunks``; returns it, in flight.  Its
+        outputs stay on the device until :meth:`_settle`.
+
+        The rows are packed from what is PLANNED for each request (the
+        scheduler's cursors over what has been dispatched).  Where the
+        call in front is not committed yet, a row's newest token is still
+        on the device: the row names where in that call's ``newest`` it
+        lies (``source``: the last step's ``[B]``, then the chunks'
+        ``firsts [Bp]``), and the program takes it from there; every other
+        row carries its token from the host (``source`` -1)."""
         # exactly TWO decode step counts ever compile (1, which carries
         # the chunks under their page bucket, and decode_burst):
         # over-running a request's budget inside a burst is safe (max_pos
@@ -1147,27 +1244,46 @@ class RaggedInferenceEngineV2:
         with tel.span("inference/pack", args={"kind": "decode"}):
             B = self.max_slots
             tokens = np.zeros((B,), np.int32)
+            source = np.full((B,), -1, np.int32)
             kv_lens = np.zeros((B,), np.int32)
             max_pos = np.zeros((B,), np.int32)
             tables = np.zeros((B, self.cache_config.max_blocks_per_seq),
                               np.int32)
+            ahead = bool(self._inflight)
+            # a request with tokens planned ahead has not left its slot
+            # since the call in flight went out: its newest token is that
+            # call's at the same slot, or, where its last chunk rode in
+            # it, the chunk's first token behind the B slots
+            firsts_at = {ch.request.uid: B + i for i, ch in enumerate(
+                self._inflight[-1].chunks) if ch.is_last} if ahead else {}
+            rows = []
             for req in decode:
                 s = req.slot
-                tokens[s] = req.generated[-1]
-                kv_lens[s] = req.prefilled + len(req.generated) - 1
+                if req.ahead_tokens:
+                    source[s] = firsts_at.get(req.uid, s)
+                else:
+                    tokens[s] = req.generated[-1]
+                position = len(req.prompt) + req.planned_tokens - 1
+                kv_lens[s] = position
                 max_pos[s] = len(req.prompt) + req.max_new_tokens - 1
                 tables[s] = self.scheduler.table_row(req)
+                rows.append(_Row(req, s, position))
             rings = self._ring_bases(B, ((r.slot, r) for r in decode))
+            self.scheduler.dispatched(chunks, decode, burst)
             self._calls += 1
         with tel.span("inference/decode_burst/dispatch",
                       args={"call": self._calls}) as sp, \
                 self._collecting_moe():
-            toks, self.pool, moe_aux, firsts = self._decode(burst)(
-                self.params, self.pool, tokens, kv_lens, tables, max_pos,
-                temp, self._next_key(tel), rings, riding, **bucket)
-        self._inflight = _Call(chunks, decode, burst, (toks, firsts, moe_aux),
-                               eos_token_id, self._calls, bucket.get("kb"),
-                               kv_lens, max_pos, sp.start)
+            toks, self.pool, moe_aux, firsts, self._newest = \
+                self._decode(burst)(
+                    self.params, self.pool, tokens, (source, self._newest),
+                    kv_lens, tables, max_pos, temp, self._next_key(tel),
+                    rings, riding, **bucket)
+        call = _Call(chunks, rows, burst, (toks, firsts, moe_aux),
+                     eos_token_id, self._calls, ahead, bucket.get("kb"),
+                     kv_lens, max_pos, sp.start)
+        self._inflight.append(call)
+        return call
 
     def generate(self, prompts: List[List[int]], max_new_tokens: int = 32,
                  temperature: float = 0.0, seed: int = 0,

@@ -52,10 +52,28 @@ class Request:
     #: ``RaggedScheduler.table_row``'s
     table: Optional[tuple] = dataclasses.field(default=None, repr=False,
                                                compare=False)
+    #: PLANNED beside committed (``RaggedScheduler.dispatched``): the prompt
+    #: tokens and the generated tokens of calls that were dispatched for
+    #: this request and are not committed yet.  ``prefilled`` and
+    #: ``generated`` hold only what was fetched; the planner reads the sums
+    #: below.  Both are 0 again when the request leaves its slot.
+    ahead_prefilled: int = 0
+    ahead_tokens: int = 0
 
     @property
     def length(self) -> int:
         return self.prefilled + len(self.generated)
+
+    @property
+    def planned_prefilled(self) -> int:
+        """The prefill cursor once every dispatched call is committed."""
+        return self.prefilled + self.ahead_prefilled
+
+    @property
+    def planned_tokens(self) -> int:
+        """The tokens generated once every dispatched call is committed,
+        unless one of them turns out to be the EOS."""
+        return len(self.generated) + self.ahead_tokens
 
     @property
     def remaining_budget(self) -> int:
@@ -195,12 +213,26 @@ class RaggedScheduler:
 
     def _give_back(self, req: Request) -> None:
         """Undo :meth:`_claim`: pages through ``_release``, the ring to
-        the free rings."""
+        the free rings.  A call still in flight may hold a row of ``req``
+        and write its pages (``max_pos`` keeps it inside them), and they
+        may be handed out at once all the same: the call that writes the
+        next owner's keys is dispatched later, the device runs calls in
+        the order of dispatch, and nobody reads a key before writing it."""
         self._release(req)
         req.blocks = []
         if req.ring >= 0:
             self._free_rings.append(req.ring)
             req.ring = -1
+
+    def _vacate(self, req: Request) -> None:
+        """``req`` leaves its slot, and what was planned for it beyond
+        what is committed is void: the calls in flight pass its rows and
+        chunks over (the engine's ``_settle``), and if it resumes it is
+        planned from what was fetched."""
+        if req.slot >= 0:
+            self.slots[req.slot] = None
+            req.slot = -1
+        req.ahead_prefilled = req.ahead_tokens = 0
 
     def _admit(self) -> None:
         """Move waiting → prefilling while a slot + enough pages exist.
@@ -249,11 +281,21 @@ class RaggedScheduler:
 
     def plan_step(self) -> tuple:
         """→ (list[PrefillChunk] (≤ ``prefill_batch``, one chunk per
-        distinct prefilling request), decode_requests) for this step."""
+        distinct prefilling request), decode_requests) for the next call,
+        planned from what has been DISPATCHED (:meth:`dispatched`), not
+        from what has been committed: a request whose last chunk is in
+        flight decodes, one whose budget ends in a call in flight does
+        not.  Everything read here is settled once a call is dispatched,
+        except an EOS: a row planned for a request that meanwhile ended
+        is passed over when its call is committed."""
         self._admit()
         chunks: List[PrefillChunk] = []
-        for req in list(self.prefilling)[:self.prefill_batch]:
-            start = req.prefilled
+        for req in self.prefilling:
+            if len(chunks) == self.prefill_batch:
+                break
+            start = req.planned_prefilled
+            if start >= len(req.prompt):
+                continue            # its last chunk is in flight
             n_valid = min(self.chunk, len(req.prompt) - start)
             toks = np.zeros((self.chunk,), np.int32)
             toks[:n_valid] = req.prompt[start:start + n_valid]
@@ -262,8 +304,36 @@ class RaggedScheduler:
                                        start_pos=start, n_valid=n_valid,
                                        is_last=is_last))
         decode = [r for r in self.slots
-                  if r is not None and r.state is RequestState.RUNNING]
+                  if r is not None and self._decodes_next(r)]
         return chunks, decode
+
+    @staticmethod
+    def _decodes_next(req: Request) -> bool:
+        """Whether ``req`` has a decode row in the next call: its prompt
+        is in (or will be, once the calls in flight are committed) and its
+        budget does not end in them."""
+        if req.state is RequestState.PREFILL:
+            if req.planned_prefilled < len(req.prompt):
+                return False
+        elif req.state is not RequestState.RUNNING:
+            return False
+        return req.planned_tokens < req.max_new_tokens
+
+    def dispatched(self, chunks: List[PrefillChunk],
+                   decode: List[Request], steps: int) -> None:
+        """A call with this plan's ``chunks`` and ``steps`` decode steps
+        for ``decode`` went out: advance what is PLANNED for each request
+        (the one place that does), so that the next :meth:`plan_step`
+        continues behind it before it is committed.  A driver that commits
+        every plan before it makes the next (the synthetic engine) never
+        calls this: what is planned is then what is committed, and the
+        commits below leave it so."""
+        for ch in chunks:
+            ch.request.ahead_prefilled += ch.n_valid
+            ch.request.ahead_tokens += ch.is_last
+        for req in decode:
+            req.ahead_tokens += min(steps,
+                                    req.max_new_tokens - req.planned_tokens)
 
     # -- state transitions (called by the engine) ----------------------------
 
@@ -271,12 +341,14 @@ class RaggedScheduler:
                    eos_token_id: Optional[int] = None) -> None:
         req = chunk.request
         req.prefilled += chunk.n_valid
+        req.ahead_prefilled = max(req.ahead_prefilled - chunk.n_valid, 0)
         if chunk.is_last:
             assert req.prefilled == len(req.prompt)
             self.prefilling.remove(req)
             req.state = RequestState.RUNNING
             if first_token is not None:
                 req.generated.append(int(first_token))
+                req.ahead_tokens = max(req.ahead_tokens - 1, 0)
                 self._maybe_finish(req, int(first_token), eos_token_id)
 
     def decode_done(self, requests: List[Request], tokens: np.ndarray,
@@ -303,6 +375,7 @@ class RaggedScheduler:
             if req.state is not RequestState.RUNNING:
                 continue
             col = columns[req.slot][:max(req.remaining_budget, 1)]
+            req.ahead_tokens = max(req.ahead_tokens - len(col), 0)
             if eos_token_id is not None and eos_token_id in col:
                 col = col[:col.index(eos_token_id) + 1]
             req.generated.extend(col)
@@ -316,9 +389,7 @@ class RaggedScheduler:
                 or (eos is not None and tok == eos)):
             req.state = RequestState.DONE
             self._give_back(req)
-            if req.slot >= 0:
-                self.slots[req.slot] = None
-                req.slot = -1
+            self._vacate(req)
             from ...telemetry import get_telemetry
 
             get_telemetry().inc_counter(
@@ -337,9 +408,7 @@ class RaggedScheduler:
             self.prefilling.remove(req)
         if req.blocks:
             self._give_back(req)
-        if req.slot >= 0:
-            self.slots[req.slot] = None
-            req.slot = -1
+        self._vacate(req)
         req.state = RequestState.DONE
         from ...telemetry import get_telemetry
 

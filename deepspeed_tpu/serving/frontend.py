@@ -526,10 +526,16 @@ class ServingFrontend:
         """One serving round: drain dead replicas, admit (with
         preemption), step every replica with work, deliver tokens.
         Returns tokens processed — 0 means idle.  The paged engine is
-        stepped through ``step_ahead``: the round's decode call is still
-        on the device when this returns, and what it yields is committed
-        and delivered by the next round (so is the slot of a request it
-        finishes: admission sees it a round later)."""
+        stepped through ``step_ahead``: it dispatches this round's call
+        behind the last round's, which is still running, then fetches and
+        commits that one; the new call is on the device when this returns.
+        So what a round delivers is what the call of the round BEFORE
+        yielded (a token reaches its stream one round after its call
+        ended, a request's slot is free for admission the round after the
+        call that finished it was committed), and whatever is done to a
+        request between two rounds (cancel, preemption) meets one call in
+        flight that may hold a row of it: the engine passes that row over
+        when it commits the call."""
         from ..telemetry import get_telemetry
 
         tel = get_telemetry()
@@ -564,10 +570,12 @@ class ServingFrontend:
                 n = 0
                 for rep in self.router.healthy():
                     if rep.scheduler.has_work:
-                        # an engine that can leave its decode call running
-                        # does: delivery, the clients' reads and the next
-                        # round's admissions then cost the device nothing
-                        # (what it yields is delivered next round)
+                        # an engine that can keep a call queued behind
+                        # the running one does: its own fetch, commit and
+                        # packing, delivery, the clients' reads and the
+                        # next round's admissions then cost the device
+                        # nothing (what a call yields is delivered a round
+                        # after it ended)
                         step = getattr(rep.engine, "step_ahead",
                                        rep.engine.step)
                         n += step(temperature=self.params.temperature,

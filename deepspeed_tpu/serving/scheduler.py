@@ -18,8 +18,10 @@ the deltas are exactly the serving-plane primitives:
   that the trie still indexes enter the allocator's cached tier (LRU
   reclaimed) instead of the free list.
 * **Indexing**: a request's full prompt pages are inserted into the trie
-  the moment its prefill completes (``chunk_done``) — concurrent
-  requests in the same batch can already share them.
+  the moment its prefill is COMMITTED (``chunk_done``) — concurrent
+  requests in the same batch can already share them.  The engine commits
+  a call a round after it dispatched it (``step_ahead``), so a twin
+  admitted in between misses once.
 * **Preemption** (`preempt`/`resume`): a RUNNING request can be bumped
   out of its decode slot; its pages stay referenced, its host state
   (generated tokens, prefill cursor) is untouched, so ``resume`` is just
@@ -208,8 +210,7 @@ class ServingScheduler(RaggedScheduler):
             raise ValueError(
                 f"can only preempt RUNNING/PREFILL requests, uid "
                 f"{req.uid} is {req.state.value}")
-        self.slots[req.slot] = None
-        req.slot = -1
+        self._vacate(req)
         req.state = RequestState.WAITING
 
     def preempt(self, req: Request) -> None:
@@ -244,9 +245,7 @@ class ServingScheduler(RaggedScheduler):
                 f"{req.uid} is {req.state.value}")
         released = len(req.blocks)
         self._give_back(req)
-        if req.slot >= 0:
-            self.slots[req.slot] = None
-            req.slot = -1
+        self._vacate(req)
         req.state = RequestState.DONE
         self.preemptions += 1
         from ..telemetry import get_telemetry
@@ -348,9 +347,7 @@ class ServingScheduler(RaggedScheduler):
         refcounts, slot freed) — the caller re-routes the request."""
         if req.blocks:
             self._give_back(req)
-        if req.slot >= 0:
-            self.slots[req.slot] = None
-            req.slot = -1
+        self._vacate(req)
         req.state = RequestState.DONE
 
     # -- introspection -----------------------------------------------------

@@ -536,9 +536,9 @@ def test_v2_step_ahead_leaves_the_decode_call_running(engine_kw):
         n = eng.step_ahead()
         total += n
         # what this call's decode yields is not on the requests yet
-        lagged += eng._inflight is not None
+        lagged += bool(eng._inflight)
         assert sum(len(r.generated) for r in reqs) - before <= n
-    assert eng._inflight is None and eng.settle() == 0
+    assert not eng._inflight and eng.settle() == 0
     assert lagged >= 3
     for prompt, r in zip(prompts, reqs):
         assert r.generated == _v1_greedy(model, params, prompt, 7)
@@ -557,10 +557,10 @@ def test_v2_settle_commits_what_step_ahead_left():
         max_batch_slots=2, prefill_chunk=8, decode_burst=4)
     req = eng.put(prompt, 9)
     assert eng.step_ahead() == 0                # the chunk's call, running
-    assert not req.generated and eng._inflight is not None
+    assert not req.generated and len(eng._inflight) == 1
     # its commit: the prompt's tokens and the first one; a burst, running
     assert eng.step_ahead() == 6
-    assert len(req.generated) == 1 and eng._inflight is not None
+    assert len(req.generated) == 1 and len(eng._inflight) == 1
     assert eng.settle() == 4 and len(req.generated) == 5
     assert eng.step() == 4                      # ``step`` is both halves
     assert req.generated == _v1_greedy(model, params, prompt, 9)
@@ -658,8 +658,9 @@ def test_v2_kv_pages_exported_and_imported_decode_the_same_token():
     src.step()                                  # the source's own next token
     tables = np.zeros((2, dst.cache_config.max_blocks_per_seq), np.int32)
     tables[0, :len(blocks)] = blocks
-    toks, dst.pool, _, _ = dst._decode(1)(
+    toks, dst.pool, *_ = dst._decode(1)(
         dst.params, dst.pool, jnp.asarray([req.generated[0], 0], jnp.int32),
+        (np.full((2,), -1, np.int32), dst._newest),
         jnp.asarray([len(prompt), 0], jnp.int32), jnp.asarray(tables),
         jnp.asarray([len(prompt) + 2, 0], jnp.int32), jnp.float32(0.0),
         jax.random.PRNGKey(0))

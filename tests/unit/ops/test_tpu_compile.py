@@ -206,8 +206,9 @@ def serving_programs(request, topo, one_chip):
         one-step program with a round's chunks riding in it under the page
         bucket kb (``deepest``: the whole table)."""
         kind, _, n = program.rpartition("_")
-        rows = (arg((_SLOTS,)), arg((_SLOTS,)), arg((_SLOTS, blocks)),
-                arg((_SLOTS,)))
+        rows = (arg((_SLOTS,)),
+                (arg((_SLOTS,)), arg((_SLOTS + engine.prefill_batch,))),
+                arg((_SLOTS,)), arg((_SLOTS, blocks)), arg((_SLOTS,)))
         if kind == "decode_burst":
             fn = functools.partial(engine._decode_burst_fn, n_steps=int(n))
             chunks = None
@@ -455,8 +456,11 @@ def hybrid_programs(topo, one_chip):
     @functools.cache
     def compiled(program):
         kind, _, n = program.rpartition("_")
-        rows = (arg((_HYBRID_SLOTS,)), arg((_HYBRID_SLOTS,)),
-                arg((_HYBRID_SLOTS, blocks)), arg((_HYBRID_SLOTS,)))
+        rows = (arg((_HYBRID_SLOTS,)),
+                (arg((_HYBRID_SLOTS,)),
+                 arg((_HYBRID_SLOTS + engine.prefill_batch,))),
+                arg((_HYBRID_SLOTS,)), arg((_HYBRID_SLOTS, blocks)),
+                arg((_HYBRID_SLOTS,)))
         if kind == "decode_burst":
             fn = functools.partial(engine._decode_burst_fn, n_steps=int(n))
             chunks = None

@@ -201,7 +201,7 @@ def test_preemption_under_a_decode_call_still_running(pumps_before):
     bg = fe.submit(bgp, max_new_tokens=14, klass="background")
     for _ in range(pumps_before):
         fe.pump()
-    assert fe.router.replicas[0].engine._inflight is not None
+    assert fe.router.replicas[0].engine._inflight
     inter = fe.submit(inp, max_new_tokens=4, klass="interactive")
     fe.run_until_idle()
     assert fe.metrics.counters["preemptions"] == 1

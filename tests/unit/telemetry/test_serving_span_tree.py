@@ -26,14 +26,14 @@ from deepspeed_tpu.telemetry.perf import CompileTracker, tracked_jit
 from deepspeed_tpu.telemetry.perf.compile_tracker import program_name
 
 #: name -> parent, as ISSUE 24 fixes them, but for the round's call: the
-#: front-end drives the engine through ``step_ahead``, which leaves a step's
-#: ONE call running (the decode step that carries the round's chunks, or a
-#: burst) and fetches it at the start of the next step, so
-#: ``inference/decode_burst`` is the wait for it (its fetch) and the
-#: dispatch lies where it happens; no call of its own prefills, so there is
-#: no ``inference/prefill`` span.  Since ISSUE 38 no stretch of a round lies
-#: outside a leaf: the sampling keys' refill (once in 256 calls, the first
-#: among them) and the hub's own accounting, after the dispatch, have spans
+#: front-end drives the engine through ``step_ahead``, which dispatches a
+#: step's ONE call (the decode step that carries the round's chunks, or a
+#: burst) behind the previous step's and only then fetches that one (ISSUE
+#: 39), so ``inference/decode_burst`` is the wait for the PREVIOUS call (its
+#: fetch), after this step's dispatch; no call of its own prefills, so there
+#: is no ``inference/prefill`` span.  Since ISSUE 38 no stretch of a round
+#: lies outside a leaf: the sampling keys' refill (once in 256 calls, the
+#: first among them) and the hub's own accounting, last in a step, have spans
 TREE = {
     "serving/pump": None,
     "serving/admit": "serving/pump",
@@ -121,9 +121,9 @@ def test_every_span_of_the_tree_is_there_under_its_parent(served):
 def test_a_round_with_chunks_is_one_call_left_running(tiny_model):
     """A mixed run (four prompts over two slots, answers long enough that
     later prompts come in beside decoding rows): the children of a step
-    that carries chunks are, in order, the last call's wait and its one
-    commit, the plan, the chunks' pack and the decode rows' pack, ONE
-    dispatch, and the hub's accounting behind it; and most prompt tokens
+    that carries chunks are, in order, the plan, the chunks' pack and the
+    decode rows' pack, ONE dispatch, THEN the last call's wait and its one
+    commit, and the hub's accounting behind them; and most prompt tokens
     are written by a call that also yields a decode token."""
     tel = telemetry.get_telemetry()
     tel.reset()
@@ -152,15 +152,16 @@ def test_a_round_with_chunks_is_one_call_left_running(tiny_model):
     assert len(carried) >= 6
     was_running = False
     for args, kids in rounds:
-        want = [("inference/decode_burst", None),
-                ("inference/commit", None)] if was_running else []
-        want.append(("inference/plan", None))
-        was_running = bool(args["chunks"] or args["decoding"])
+        want = [("inference/plan", None)]
         if args["chunks"]:
             want.append(("inference/pack", "prefill"))
-        if was_running:
+        if args["chunks"] or args["decoding"]:
             want += [("inference/pack", "decode"),
                      ("inference/decode_burst/dispatch", None)]
+        if was_running:         # the call the step before left in flight
+            want += [("inference/decode_burst", None),
+                     ("inference/commit", None)]
+        was_running = bool(args["chunks"] or args["decoding"])
         if len(want) > 1:       # something was committed or dispatched
             want.append(("inference/observe", None))
         assert kids == want, args
@@ -302,7 +303,7 @@ def test_hub_off_costs_one_shared_object_and_nothing_else(
     assert not any(name.startswith(("serving/", "inference/"))
                    for name in tel.registry.metrics())
     # what a call costs with the hub off: its number
-    assert eng._calls == 9 and eng._inflight is None
+    assert eng._calls == 9 and not eng._inflight
 
 
 def test_a_calls_spans_in_two_rounds_carry_its_number(served):
@@ -328,6 +329,32 @@ def test_a_calls_spans_in_two_rounds_carry_its_number(served):
         assert at["inference/commit"] == at["inference/decode_burst"] \
             == at["inference/decode_burst/dispatch"] + 1
         assert at["inference/call"] == at["inference/commit"]
+
+
+def test_a_step_in_its_new_order_has_no_hole_and_counts_calls_ahead(served):
+    """Plan, packs and dispatch, then the previous call's wait and commit,
+    then the accounting: a step's children follow each other with nothing
+    between them (the median step spends under a tenth of its time outside
+    every child), every committed call is one ``inference/decode_burst``
+    span, and a call counts as dispatched ahead when the call before it
+    was uncommitted: all nine but the first."""
+    tree = [e for e in served["events"] if "depth" in e["args"]]
+    outside = []
+    for step in named(served, "inference/step"):
+        lo, hi = step["ts"], step["ts"] + step["dur"]
+        kids = sorted((e["ts"], e["ts"] + e["dur"]) for e in tree
+                      if e["args"].get("parent") == "inference/step"
+                      and lo - 0.3 <= e["ts"] and e["ts"] + e["dur"]
+                      <= hi + 0.3)
+        assert all(a[1] <= b[0] + 0.3 for a, b in zip(kids, kids[1:]))
+        outside.append(1.0 - sum(b - a for a, b in kids) / step["dur"])
+    assert sorted(outside)[len(outside) // 2] < 0.1, outside
+    counters = served["counters"]
+    assert counters["inference/calls"] == len(
+        named(served, "inference/decode_burst")) == 9
+    assert counters["inference/calls_dispatched_ahead"] == 8 \
+        <= counters["inference/calls"]
+    assert counters["inference/rows_overrun"] == 0
 
 
 def test_every_program_call_has_one_record_of_what_was_committed(served):
@@ -566,8 +593,9 @@ def test_the_engines_own_programs_carry_their_names(tiny_model):
                         eng.pool)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     mb = eng.cache_config.max_blocks_per_seq
-    args = (eng.params, pool, i32(2), i32(2), i32(2, mb), i32(2),
-            jnp.float32(0), jax.random.PRNGKey(0))
+    args = (eng.params, pool, i32(2), (i32(2), i32(2 + eng.prefill_batch)),
+            i32(2), i32(2, mb), i32(2), jnp.float32(0),
+            jax.random.PRNGKey(0))
     text = eng._decode(4).lower(*args).as_text()
     assert text.startswith("module @jit_inference_v2_decode_burst_n_steps4 ")
     # the step that carries chunks is the one-step program by name, whatever
