@@ -36,7 +36,8 @@ unrolled inside the step.  Most families have one kind and a period of one
 layer, which is what the base class states from ``kv_heads`` /
 ``head_dim`` / ``window``: the dense and OLMoE adapters are that case of
 the same interface, not a second path.  :class:`MimoV2Adapter` is the
-family that mixes full and windowed layers.
+family that mixes full and windowed layers;
+:class:`PanguUltraMoeV2Adapter` the one whose kind is a latent cache.
 """
 
 from __future__ import annotations
@@ -59,6 +60,15 @@ class AttentionKind:
     window: Optional[int] = None    # keys ``i − j < window``; None: all
     sink: bool = False              # a learned logit a head beside the keys
     theta: Optional[float] = None   # rotary base; None: no rotary
+    #: a LATENT cache: the kind's V row is the leading ``v_dim`` numbers
+    #: of its K row (one vector a token that every query head reads, its
+    #: head of the compressed keys and values; the queries come absorbed
+    #: into that space and the output leaves in it).  The kind has no V
+    #: pool, and ``qkv`` returns no V
+    v_in_k: bool = False
+    #: the scores' scale where it is not ``1/sqrt(k_dim)`` of the row as
+    #: cached (an absorbed query's: that of the head it stands for)
+    scale: Optional[float] = None
     #: pages that fell out of the window are recycled: the kind's pool is
     #: rings of ``KVCacheConfig.ring_blocks`` pages, one a sequence, and not
     #: pages of token capacity.  False keeps every key (and the prefix
@@ -166,7 +176,8 @@ class ModelAdapterV2:
             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         """``x [N, H]`` → (q ``[N, h, k_dim]``, k ``[N, kv_h, k_dim]``, v
         ``[N, kv_h, v_dim]``) of a layer of ``kind``, with any rotary
-        encoding already applied."""
+        encoding already applied; v is None where the kind's V lies in
+        its K rows (``AttentionKind.v_in_k``)."""
         raise NotImplementedError
 
     def post_attn(self, lp: Any, x: jnp.ndarray, attn: jnp.ndarray,
@@ -333,9 +344,9 @@ class MimoV2Adapter(ModelAdapterV2):
     whole (as :class:`OlmoeV2Adapter`'s do) and hold this chip's share of
     the experts.  The window kind recycles its pages (``ring``)."""
 
-    def __init__(self, model: Any):
-        super().__init__(model)
-        self.plan = model.plan
+    @property
+    def plan(self) -> Any:
+        return self.model.plan
 
     @property
     def num_layers(self) -> int:
@@ -402,10 +413,58 @@ class MimoV2Adapter(ModelAdapterV2):
                           preferred_element_type=jnp.float32)
 
 
+class PanguUltraMoeV2Adapter(MimoV2Adapter):
+    """openPangu-Ultra-MoE (``models/pangu_ultra_moe.py``): latent
+    attention in the absorbed form.  ONE kind, whose cache row a token is
+    the compressed vector and its rotary part, read by every query head
+    (``kv_heads`` 1) and holding its own value (``v_in_k``); ``qkv``
+    returns queries absorbed into that space and ``post_attn`` takes the
+    attention's output there and brings it back, then the FFN under the
+    model's sandwich norms.  The dense layers lead; the sparse ones are
+    scanned a layer a period, their expert stacks whole (as
+    :class:`OlmoeV2Adapter`'s) and this chip's share."""
+
+    @property
+    def head_dim(self) -> int:
+        return self.config.latent_dim
+
+    @property
+    def kinds(self) -> Tuple[AttentionKind, ...]:
+        from ...models.pangu_ultra_moe import LATENT
+
+        c = self.config
+        return (AttentionKind(
+            LATENT, c.num_layers, 1, c.latent_dim, c.kv_lora_rank,
+            theta=c.rope_theta, v_in_k=True,
+            scale=float(c.qk_head_dim) ** -0.5),)
+
+    @property
+    def pattern(self) -> LayerPattern:
+        c = self.config
+        name = self.kinds[0].name
+        return LayerPattern((name,) * c.first_k_dense, (name,),
+                            c.num_layers - c.first_k_dense)
+
+    def layers(self, params):
+        return self.model.scanned(params)
+
+    def period_layers(self, pp, p):
+        # a period is one layer: its slice, and where its experts lie
+        return [dict(pp, expert_layer=p)]
+
+    def sink(self, lp):
+        return None
+
+    def qkv(self, lp, x, positions, kind):
+        del kind  # one kind
+        return self.model.qkv(lp, x, positions)
+
+
 _REGISTRY = {
     "LlamaModel": LlamaV2Adapter,
     "MimoV2Model": MimoV2Adapter,
     "MixtralModel": LlamaV2Adapter,
     "OlmoeModel": OlmoeV2Adapter,
     "OPTModel": OPTV2Adapter,
+    "PanguUltraMoeModel": PanguUltraMoeV2Adapter,
 }

@@ -31,7 +31,8 @@ same engine (reference keeps per-arch model implementations under
 ``inference/v2/model_implementations`` [K]).
 
 Every program donates the pools (one of K and V for each kind of attention
-layer the adapter states: ``adapters.AttentionKind``) and carries them WHOLE
+layer the adapter states: ``adapters.AttentionKind``; K alone for a latent
+kind, whose one row a token holds its value too) and carries them WHOLE
 through its layer scan, addressed by ``(layer, page)``: a step's rows
 (decode) and pages (chunks) are scattered into a pool in place, and
 attention reads pages ``l·N + page`` of its flat view.  No program forms a
@@ -456,7 +457,9 @@ class RaggedInferenceEngineV2:
         table cover every key written so far, so the gather/mask is
         O(allocated), not O(max_seq_len).  ``rings [Bp]``: each row's
         ring's first page, where a kind recycles (else None); such a kind
-        gathers the window's pages and the chunk's and no bucket.  Returns
+        gathers the window's pages and the chunk's and no bucket.  A latent
+        kind (``v_in_k``) gathers nothing: each of its chunk rows is a row
+        of the paged kernel (:meth:`_paged_attend`).  Returns
         (positions ``[Bp·C]``, the rows' part ``(Bp·C, write_fn,
         attend_fn)``: what :meth:`_layer_step` needs for them,
         :meth:`_beside`)."""
@@ -492,6 +495,8 @@ class RaggedInferenceEngineV2:
             pages = jax.vmap(
                 lambda row, cur: jax.lax.dynamic_slice(
                     row, (cur,), (C // bs,)))(tables, page_cursor)
+            if kind.v_in_k:     # its rows attend through the paged kernel
+                return pages.reshape(-1), None, None
             karange = jnp.arange(mb * bs)
             mask = jax.vmap(lambda p: local_attention_mask(
                 p, karange, causal=True, window=kind.window))(positions)
@@ -522,17 +527,26 @@ class RaggedInferenceEngineV2:
                         part.reshape((-1,) + view.shape[1:]))
                 return view.reshape(plane.shape)
 
-            k, v = self._per_kv_shard(
-                lambda k, v, kk, vv, l, pages: (
-                    written_into(k, kk, l, pages),
-                    written_into(v, vv, l, pages)),
-                ("pool", "pool", "heads", "heads", "all", "all"),
-                ("pool", "pool"))(pool["k"], pool["v"], kk, vv,
-                                  jnp.asarray(l, jnp.int32),
-                                  written[kind.name])
-            return {"k": k, "v": v}
+            # K and V, or K alone where the kind's V lies in its K rows
+            write = self._per_kv_shard(
+                written_into, ("pool", "heads", "all", "all"), "pool")
+            rows = {"k": kk, "v": vv}
+            return {name: write(plane, rows[name], jnp.asarray(l, jnp.int32),
+                                written[kind.name])
+                    for name, plane in pool.items()}
 
         def attend_fn(q, pool, kind, l, sink):
+            if kind.v_in_k:
+                # a latent kind: each chunk row is a row of the paged
+                # kernel, at its own length through its sequence's table,
+                # as a decode row is.  With every query head on the one
+                # cached row the kernel is bound by its products either
+                # way, and gathered here the float32 scores of 128 heads
+                # over the bucket crossed HBM three times: 15 ms of a 43 ms
+                # step against the kernel's 7 (PERF.md §6, PR 40)
+                return self._paged_attend(
+                    q, pool, kind, l, sink, jnp.repeat(tables, C, axis=0),
+                    positions.reshape(-1) + 1)
             # gather only the attended pages (a bucket: every key written
             # so far lives in the first kb pages of each row's table) and
             # attend chunk-queries over them — O(allocated), not
@@ -547,7 +561,7 @@ class RaggedInferenceEngineV2:
                     parts, axis=-1)[..., :d]
                 return rows.reshape(Bp, pages.shape[1] * bs, -1, d)
 
-            def attended_over(q, k, v, l, pages, mask):
+            def attended_over(q, l, pages, mask, k, v):
                 kf = gathered(k, l, pages, kind.k_dim)
                 vf = gathered(v, l, pages, kind.v_dim)
                 heads = q.shape[1]
@@ -556,7 +570,7 @@ class RaggedInferenceEngineV2:
                     kf = jnp.repeat(kf, n_rep, axis=2)
                     vf = jnp.repeat(vf, n_rep, axis=2)
                 qb = q.reshape(Bp, C, heads, kind.k_dim)
-                scale = 1.0 / np.sqrt(kind.k_dim)
+                scale = kind.scale or 1.0 / np.sqrt(kind.k_dim)
                 s = jnp.einsum("bqhd,bkhd->bhqk", qb, kf
                                ).astype(jnp.float32) * scale
                 s = jnp.where(mask, s, -1e30)
@@ -579,11 +593,65 @@ class RaggedInferenceEngineV2:
                     "a sink under tensor-parallel serving")
             return self._per_kv_shard(
                 attended_over,
-                ("heads", "pool", "pool", "all", "all", "all"), "heads")(
-                    q, pool["k"], pool["v"], jnp.asarray(l, jnp.int32),
-                    attended[kind.name], masks[kind.name])
+                ("heads", "all", "all", "all", "pool", "pool"), "heads")(
+                    q, jnp.asarray(l, jnp.int32), attended[kind.name],
+                    masks[kind.name], pool["k"], pool["v"])
 
         return positions.reshape(-1), (Bp * C, write_fn, attend_fn)
+
+    def _paged_attend(self, q, pool, kind, l, sink, tables, lengths):
+        """One-token queries ``q [R, h, k_dim]`` over layer ``l`` of a
+        kind's pool through the paged kernel: row ``r`` attends over its
+        first ``lengths[r]`` keys through ``tables[r]``.  The decode rows
+        of every kind, and the chunk rows of a latent one."""
+        # the kernel fetches pages from HBM by page id: it gets
+        # the whole pool's flat view, and the layer's offset is
+        # folded into the tables it prefetches anyway
+        flat = self._flat_pool(pool)
+        pages = pool["k"].shape[1]
+        k_planes = pool["k"].shape[0] // kind.layers
+        # V: a pool of one plane, or the K row's leading numbers
+        v_in_k = kind.v_dim if kind.v_in_k else 0
+        if not v_in_k and pool["v"].shape[0] != kind.layers:
+            raise NotImplementedError(
+                f"V rows of {kind.v_dim}: wider than one plane")
+        layer_tables = tables + l * pages
+        # what paged_decode_attention will run for these shapes
+        # on this platform, by its own test
+        impl = paged_decode_impl(
+            self.adapter.num_heads // self._tp, kind.kv_heads // self._tp,
+            None, flat["k"].shape[-1],
+            v_in_k or flat["v"].shape[-1])
+        if impl == "reference" and jax.default_backend() == "tpu":
+            from ...telemetry import get_telemetry
+
+            get_telemetry().inc_counter(
+                "inference/attn/reference_fallbacks",
+                help="layers traced on a TPU whose paged decode "
+                     "attention runs the jax.numpy reference: "
+                     "the kernel refused their shapes")
+        if self._tp > 1:
+            # the Pallas kernel runs PER TP SHARD via an explicit
+            # shard_map over the kv-head axis (heads independent,
+            # zero cross-rank comm)
+            from ...ops.pallas.paged_attention import (
+                paged_decode_attention_tp)
+
+            if sink is not None:
+                raise NotImplementedError(
+                    "a sink under tensor-parallel serving")
+            self.last_attn_path = f"{impl}_tp_shard_map"
+            return paged_decode_attention_tp(
+                q, flat["k"], flat["v"], layer_tables, lengths,
+                mesh=self.mesh, window=kind.window)
+        self.last_attn_path = impl
+        # plane p of a layer's K lies a whole plane (every layer's
+        # pages) further on than plane p - 1
+        return paged_decode_attention(
+            q, flat["k"], flat.get("v"), layer_tables, lengths,
+            window=kind.window, sink=sink, k_planes=k_planes,
+            plane_stride=kind.layers * pages, v_in_k=v_in_k,
+            scale=kind.scale)
 
     def _decode_rows(self, tables_of, wp):
         """The decode rows of a step: row ``r`` writes its K and V at
@@ -592,7 +660,6 @@ class RaggedInferenceEngineV2:
         so far through the paged kernel.  Returns the rows' part ``(B,
         write_fn, attend_fn)``: what :meth:`_layer_step` needs for them
         (:meth:`_beside`)."""
-        ad = self.adapter
         bs = self.cache_config.block_size
         offsets = wp % bs
         page_ids = {name: table[jnp.arange(wp.shape[0]), wp // bs]
@@ -601,54 +668,13 @@ class RaggedInferenceEngineV2:
         def write_fn(pool, kind, l, kk, vv):
             # one scatter of [B, kv_h, d] rows at (l, page, offset)
             where = (page_ids[kind.name], offsets)
-            return {"k": self._scatter(pool["k"], l, where, kk),
-                    "v": self._scatter(pool["v"], l, where, vv)}
+            rows = {"k": kk, "v": vv}
+            return {name: self._scatter(plane, l, where, rows[name])
+                    for name, plane in pool.items()}
 
         def attend_fn(q, pool, kind, l, sink):
-            # the kernel fetches pages from HBM by page id: it gets
-            # the whole pool's flat view, and the layer's offset is
-            # folded into the tables it prefetches anyway
-            flat = self._flat_pool(pool)
-            pages = pool["k"].shape[1]
-            k_planes = pool["k"].shape[0] // kind.layers
-            if pool["v"].shape[0] != kind.layers:
-                raise NotImplementedError(
-                    f"V rows of {kind.v_dim}: wider than one plane")
-            layer_tables = tables_of[kind.name] + l * pages
-            # what paged_decode_attention will run for these shapes
-            # on this platform, by its own test
-            impl = paged_decode_impl(
-                ad.num_heads // self._tp, kind.kv_heads // self._tp,
-                None, flat["k"].shape[-1], flat["v"].shape[-1])
-            if impl == "reference" and jax.default_backend() == "tpu":
-                from ...telemetry import get_telemetry
-
-                get_telemetry().inc_counter(
-                    "inference/attn/reference_fallbacks",
-                    help="layers traced on a TPU whose paged decode "
-                         "attention runs the jax.numpy reference: "
-                         "the kernel refused their shapes")
-            if self._tp > 1:
-                # the Pallas kernel runs PER TP SHARD via an explicit
-                # shard_map over the kv-head axis (heads independent,
-                # zero cross-rank comm)
-                from ...ops.pallas.paged_attention import (
-                    paged_decode_attention_tp)
-
-                if sink is not None:
-                    raise NotImplementedError(
-                        "a sink under tensor-parallel serving")
-                self.last_attn_path = f"{impl}_tp_shard_map"
-                return paged_decode_attention_tp(
-                    q, flat["k"], flat["v"], layer_tables, wp + 1,
-                    mesh=self.mesh, window=kind.window)
-            self.last_attn_path = impl
-            # plane p of a layer's K lies a whole plane (every layer's
-            # pages) further on than plane p - 1
-            return paged_decode_attention(
-                q, flat["k"], flat["v"], layer_tables, wp + 1,
-                window=kind.window, sink=sink, k_planes=k_planes,
-                plane_stride=kind.layers * pages)
+            return self._paged_attend(q, pool, kind, l, sink,
+                                      tables_of[kind.name], wp + 1)
 
         return wp.shape[0], write_fn, attend_fn
 
@@ -667,7 +693,8 @@ class RaggedInferenceEngineV2:
 
         def write_fn(pool, kind, l, kk, vv):
             for (lo, hi), (_, write, _) in zip(spans, parts):
-                pool = write(pool, kind, l, kk[lo:hi], vv[lo:hi])
+                pool = write(pool, kind, l, kk[lo:hi],
+                             None if vv is None else vv[lo:hi])
             return pool
 
         def attend_fn(q, pool, kind, l, sink):
@@ -947,6 +974,10 @@ class RaggedInferenceEngineV2:
         O(allocated) gather cost."""
         bs = self.cache_config.block_size
         mb = self.cache_config.max_blocks_per_seq
+        if all(k.ring or k.v_in_k for k in self.kinds.values()):
+            # no kind gathers a bucket (a ring gathers its window, a latent
+            # kind's chunk rows walk their pages in the kernel): one program
+            return mb
         need = max((ch.start_pos + self.chunk) // bs for ch in chunks)
         kb = max(self.chunk // bs, 1)
         while kb < need:
@@ -1087,10 +1118,11 @@ class RaggedInferenceEngineV2:
         if sent is None and not done:
             return
         with tel.span("inference/observe"):
-            if sent is not None and sent.decode:
+            if sent is not None:
                 live = [row.slot for row in sent.decode]
-                self._count_cache_traffic(tel, sent.kv_lens[live],
-                                          sent.max_pos[live], sent.steps)
+                self._count_cache_traffic(
+                    tel, sent.kv_lens[live], sent.max_pos[live], sent.steps,
+                    [ch.start_pos for ch in sent.chunks])
             for counted in done:
                 self._count_call(tel, *counted)
 
@@ -1199,22 +1231,31 @@ class RaggedInferenceEngineV2:
             kb = self._prefill_bucket(chunks)
         return (tokens, tables, start, last, rings), kb
 
-    def _count_cache_traffic(self, tel: Any, kv_lens, max_pos, burst) -> None:
-        """What the decode steps of a call read of each kind's cache, and
-        the ring pages they begin, from the lengths the call packed: a
-        step reads a row's keys so far, the one it writes among them, at
-        most the kind's window."""
+    def _count_cache_traffic(self, tel: Any, kv_lens, max_pos, burst,
+                             chunk_starts=()) -> None:
+        """What the paged kernel reads of each kind's cache in a call, and
+        the ring pages its decode steps begin, from what the call packed:
+        a decode step reads a row's keys so far, the one it writes among
+        them, at most the kind's window; of a latent kind the kernel
+        serves the chunk rows too (``chunk_starts``: the live chunks'
+        first positions), row ``t`` of a chunk its ``start + t + 1``
+        keys."""
         # [burst, rows]: the length a row attends over at each step
         lengths = np.minimum(kv_lens[None, :] + np.arange(burst)[:, None],
                              max_pos[None, :]) + 1
+        C = self.chunk
+        riding = float(sum(C * start + C * (C + 1) // 2
+                           for start in chunk_starts))
         for kind in self.kinds.values():
             tel.inc_counter(
                 f"inference/attn/keys_read_{kind.name}",
-                v=float(np.minimum(lengths, kind.window or lengths).sum()),
-                help="keys a layer of the kind attends over, summed over "
-                     "decoding rows and decode steps (a KV head's; times "
-                     "layers, KV heads and row bytes: what the paged "
-                     "kernel must read)")
+                v=float(np.minimum(lengths, kind.window or lengths).sum())
+                + (riding if kind.v_in_k else 0.0),
+                help="keys a layer of the kind attends over through the "
+                     "paged kernel, summed over decoding rows and decode "
+                     "steps and, of a latent kind, over the chunk rows (a "
+                     "KV head's; times layers, KV heads and row bytes: "
+                     "what the kernel must read)")
         bs = self.cache_config.block_size
         self._count_recycled(tel, -(-kv_lens // bs),
                              (lengths[-1] - 1) // bs + 1 - -(-kv_lens // bs))

@@ -91,7 +91,8 @@ def init_kv_pool(adapter: Any, cache_config: KVCacheConfig
     that a layer's page ``n`` is page ``l·pages + n`` of every plane's
     stretch and one block table serves K's planes and V alike.
     ``pages`` is ``num_blocks`` for a kind that keeps every key and
-    ``ring_pool_blocks`` for one that recycles."""
+    ``ring_pool_blocks`` for one that recycles.  A kind whose V lies in
+    its K rows (``v_in_k``: a latent cache) has ``{"k"}`` alone."""
     pools = {}
     for kind in adapter.kinds:
         pages = (cache_config.ring_pool_blocks if kind.ring
@@ -103,7 +104,9 @@ def init_kv_pool(adapter: Any, cache_config: KVCacheConfig
                               cache_config.block_size, kind.kv_heads, width),
                              adapter.dtype)
 
-        pools[kind.name] = {"k": plane(kind.k_dim), "v": plane(kind.v_dim)}
+        pools[kind.name] = {"k": plane(kind.k_dim)}
+        if not kind.v_in_k:
+            pools[kind.name]["v"] = plane(kind.v_dim)
     return pools
 
 

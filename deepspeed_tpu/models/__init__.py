@@ -14,9 +14,11 @@ from .mimo_v2 import MimoV2Config, MimoV2Model
 from .mixtral import MixtralConfig, MixtralModel
 from .olmoe import OlmoeConfig, OlmoeModel
 from .opt import OPTConfig, OPTModel
+from .pangu_ultra_moe import PanguUltraMoeConfig, PanguUltraMoeModel
 from .resnet import ResNetConfig, ResNetModel
 
 __all__ = ["BertConfig", "BertModel", "LlamaConfig", "LlamaModel",
            "MimoV2Config", "MimoV2Model", "MixtralConfig", "MixtralModel", "OlmoeConfig", "OlmoeModel",
            "OPTConfig", "OPTModel",
+           "PanguUltraMoeConfig", "PanguUltraMoeModel",
            "ResNetConfig", "ResNetModel"]

@@ -58,6 +58,19 @@ size), where every 128-lane view is a bitcast.  The padding is read from
 HBM like the keys (a quarter of a 192-wide K: what the kernel's roofline
 share loses).
 
+``v_in_k`` (a latent cache: ONE row a token that every query head reads,
+whose leading ``v_in_k`` numbers are also its value): there is no V pool,
+no V copy and no V buffer; PV is a dot of the probabilities against each
+of the K buffer's leading planes, the results side by side in the
+accumulator (``v_in_k`` whole planes where the kernel is compiled).  The
+pool comes as ``[planes, pages, rows, 128]`` and ONE strided copy fetches
+all of a page's planes; a step scores ``_LATENT_STEP_TOKENS`` keys.  With
+one KV head under 128 query heads each fetched byte meets ``h`` rows of
+the MXU twice: at 576-wide rows the kernel sits on the chip's ridge and
+not under the cache's stream.  ``scale``: the scores' scale where it is
+not ``1/sqrt(d)`` of the row as cached (an absorbed query is as wide as
+the latent row; the scale is that of the head it stands for).
+
 ``sink`` (``[h]`` float32, one learned logit a query head): a column of
 the softmax that takes mass and carries no value,
 ``p_j = exp(s_j) / (Σ exp(s_j') + exp(sink))``.  In the kernel it is where
@@ -79,14 +92,16 @@ from .select import reference_off_tpu, shape_refused
 
 def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
                            window=None, sink=None, k_planes=1,
-                           plane_stride=0):
+                           plane_stride=0, v_in_k=0, scale=None):
     """Pure-jnp reference.  ``q [B, h, d]``; K pool ``[M, bs, kv_h, w]`` in
     ``k_planes`` planes (plane ``p`` of page ``n`` at ``n +
     p·plane_stride``; ``k_planes·w >= d``: what lies beyond ``d`` is lane
     padding); V pool ``[N, bs, kv_h, dv]``; ``block_tables [B,
     max_blocks]``; ``lengths [B]``; ``window`` = sliding-window reach (only
     the last ``window`` cache entries); ``sink [h]`` = a logit a head
-    beside the keys'."""
+    beside the keys'; ``v_in_k``: V is the K row's leading ``v_in_k``
+    numbers (``v_pool`` None); ``scale``: the scores', where not
+    ``1/sqrt(d)``."""
     B, _, d = q.shape
     _, bs, kv_h, _ = k_pool.shape
     max_blocks = block_tables.shape[1]
@@ -94,13 +109,19 @@ def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
     k = jnp.concatenate([k_pool[block_tables + p * plane_stride]
                          for p in range(k_planes)], axis=-1)
     k = k[..., :d].reshape(B, max_blocks * bs, kv_h, d)
-    v = v_pool[block_tables].reshape(B, max_blocks * bs, kv_h, -1)
+    v = k[..., :v_in_k] if v_in_k else \
+        v_pool[block_tables].reshape(B, max_blocks * bs, kv_h, -1)
     n_rep = q.shape[1] // kv_h
-    if n_rep > 1:
+    row = "bkhd"
+    if kv_h == 1 and n_rep > 1:
+        # every query head reads the one row: none is repeated (128 heads
+        # of 576 numbers a key would be)
+        k, v, row = k[:, :, 0], v[:, :, 0], "bkd"
+    elif n_rep > 1:
         k = jnp.repeat(k, n_rep, axis=2)
         v = jnp.repeat(v, n_rep, axis=2)
-    scale = 1.0 / np.sqrt(d)
-    s = jnp.einsum("bhd,bkhd->bhk", q, k).astype(jnp.float32) * scale
+    scale = scale or 1.0 / np.sqrt(d)
+    s = jnp.einsum(f"bhd,{row}->bhk", q, k).astype(jnp.float32) * scale
     pos = jnp.arange(max_blocks * bs)[None, None, :]
     mask = pos < lengths[:, None, None]
     if window is not None:
@@ -113,11 +134,21 @@ def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
                                   s.shape[:2] + (1,))
         p = jax.nn.softmax(jnp.concatenate([s, beside], axis=-1),
                            axis=-1)[..., :-1].astype(q.dtype)
-    return jnp.einsum("bhk,bkhd->bhd", p, v)
+    return jnp.einsum(f"bhk,{row}->bhd", p, v)
 
 
 #: keys scored per compute step (``P·block_size``)
 _STEP_TOKENS = 256
+#: the same over a latent cache (``v_in_k``): one KV head's 256 keys are a
+#: step of 256 score columns where 8 KV heads' are one of 2,048, and the
+#: step's fixed work (the loop, the waits, the running max and sum, the
+#: rescaling of a ``[h, 512]`` accumulator) is spread over a quarter of the
+#: products.  Measured on the v5e at the serving cell's shapes (PERF.md §6,
+#: PR 40): 35.9% of the kernel's roofline at 256, 43.1% at 512, 45.6% at
+#: 1,024 (and 52.0% with a page's planes in one copy); a row's last step
+#: is half empty on average, which at ~3,100 keys a row costs a sixth at
+#: 1,024 and would cost a third at 2,048
+_LATENT_STEP_TOKENS = 1024
 #: what one step may hold in VMEM: two slots each of K and V pages, the
 #: head mask (double-buffered by the pipeline) and three float32 ``[h, C]``
 #: temporaries of the softmax (Mosaic's default scoped limit is 16 MiB)
@@ -128,27 +159,35 @@ def pages_per_step(block_size: int, kv_h: int, h: int, d: int, itemsize: int,
                    max_blocks: int, v_dim: int | None = None) -> int:
     """``P``: pages fetched and scored per compute step, from the shapes
     alone (``d``: a K row as the pool holds it, all its planes; ``v_dim``:
-    a V row, where it is another width)."""
+    a V row, where it is another width; 0: V lies in the K row and has no
+    buffer, a latent cache)."""
     rows = block_size * kv_h
-    per_page = (2 * rows * (d + (v_dim or d)) * itemsize
+    per_page = (2 * rows * (d + (d if v_dim is None else v_dim)) * itemsize
                 + 5 * h * rows * 4)
-    return max(1, min(_STEP_TOKENS // block_size, max_blocks,
+    step = _LATENT_STEP_TOKENS if v_dim == 0 else _STEP_TOKENS
+    return max(1, min(step // block_size, max_blocks,
                       _VMEM_BUDGET_BYTES // per_page))
 
 
 def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
                   block_size: int, kv_h: int, scale: float, window=None,
                   sink: bool = False, k_planes: int = 1,
-                  plane_stride: int = 0):
+                  plane_stride: int = 0, v_in_k: int = 0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     # with a sink, its [h, 1] logits come before the pools
     sink_ref = rest[0] if sink else None
-    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest[int(sink):]
+    if v_in_k:      # V lies in the K rows: no V pool, no V buffer
+        k_hbm, o_ref, k_buf, sems, slot_ref = rest[int(sink):]
+        v_hbm = v_buf = None
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest[int(sink):]
     b = pl.program_id(0)
     num_rows = pl.num_programs(0)
-    P = v_buf.shape[1]           # the K buffer holds P pages a plane
+    # a latent cache's buffer is [2, planes, P, rows, w]: a page's planes
+    # arrive in one copy
+    P = k_buf.shape[2] if v_in_k else k_buf.shape[1] // k_planes
     h = q_ref.shape[1]
     C = P * block_size * kv_h
 
@@ -162,11 +201,20 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
         return k0, nk - k0
 
     def page_copies(page, slot, j):
-        return tuple(
+        if v_in_k:
+            # the K pool comes as [planes, pages, rows, w]: ONE strided
+            # copy fetches every plane of the page (a copy a plane spent
+            # more of a step starting and awaiting copies: 45.6 → 52.0% of
+            # the roofline, PERF.md §6, PR 40)
+            return (pltpu.make_async_copy(k_hbm.at[:, page],
+                                          k_buf.at[slot, :, j],
+                                          sems.at[0, slot]),)
+        copies = tuple(
             pltpu.make_async_copy(k_hbm.at[page + p * plane_stride],
                                   k_buf.at[slot, p * P + j],
                                   sems.at[0, slot])
-            for p in range(k_planes)) + (
+            for p in range(k_planes))
+        return copies + (
             pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
                                   sems.at[1, slot]),)
 
@@ -202,7 +250,8 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
         # masked columns carry probability 0 into the PV dot, and 0 x what
         # an unwritten VMEM buffer holds may be NaN: after this, a slot only
         # ever holds zeros or pages some row owns
-        v_buf[...] = jnp.zeros_like(v_buf)
+        values = k_buf if v_in_k else v_buf
+        values[...] = jnp.zeros_like(values)
         slot_ref[0] = 0
         start_step(0, k0, n_live, 0, 0)
 
@@ -224,13 +273,19 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
         wait_step(n_live, i, slot)
         w = k_buf.shape[-1]
 
+        def k_plane(p):
+            """Plane ``p`` of the step's pages as ``[C, w]``."""
+            if v_in_k:
+                return k_buf[slot, p].reshape(C, w)
+            kp = k_buf[slot] if k_planes == 1 \
+                else k_buf[slot, p * P:(p + 1) * P]
+            return kp.reshape(C, w)
+
         def plane_scores(p):
             """q's lanes of plane ``p`` against that plane's pages."""
             qp = q if k_planes == 1 else q[:, p * w:(p + 1) * w]
-            kp = k_buf[slot] if k_planes == 1 \
-                else k_buf[slot, p * P:(p + 1) * P]
             return jax.lax.dot_general(
-                qp, kp.reshape(C, w), (((1,), (1,)), ((), ())),
+                qp, k_plane(p), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
         s = plane_scores(0)
@@ -246,9 +301,18 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        v = v_buf[slot].reshape(C, v_buf.shape[-1])
-        pv = jnp.dot(p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
+        if v_in_k:
+            # the row's leading numbers: whole planes, and the leading
+            # lanes of one more where the interpreter runs narrow rows
+            whole, part = divmod(v_in_k, w)
+            values = [k_plane(p) for p in range(whole + bool(part))]
+            if part:
+                values[-1] = values[-1][:, :part]
+        else:
+            values = [v_buf[slot].reshape(C, v_buf.shape[-1])]
+        pv = [jnp.dot(p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32) for v in values]
+        pv = pv[0] if len(pv) == 1 else jnp.concatenate(pv, axis=1)
         return (m_new, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
                 acc * alpha + pv)
 
@@ -268,7 +332,8 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
 def _refusal(h: int, kv_h: int, k_dim: int, v_dim: int,
              compiled: bool) -> str | None:
     """Why the kernel cannot take these shapes, or None.  ``k_dim`` and
-    ``v_dim`` are the POOLS' last dims (a plane's width)."""
+    ``v_dim`` are the POOLS' last dims (a plane's width); where V lies in
+    the K rows, ``v_dim`` is how many of a row's leading numbers it is."""
     if h % kv_h:
         return f"kv heads {kv_h} do not divide query heads {h}"
     if compiled and (k_dim % 128 or v_dim % 128):
@@ -297,7 +362,8 @@ def paged_decode_impl(num_heads: int, kv_heads: int,
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            interpret: bool | None = None, window=None,
                            sink=None, k_planes: int = 1,
-                           plane_stride: int = 0):
+                           plane_stride: int = 0, v_in_k: int = 0,
+                           scale: float | None = None):
     """One-token queries ``q [B, h, d]`` over a shared paged KV pool
     ``k [M, block_size, kv_h, w]``, ``v [N, block_size, kv_h, dv]``
     addressed by ``block_tables [B, max_blocks]`` with true ``lengths
@@ -307,31 +373,39 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     (sliding-window attention) is handled by the kernel's walk: it starts
     at the window's first page, so pages before the window are neither
     fetched nor scored.  ``sink [h]``: a logit a query head in the
-    softmax's denominator.  A row of length 0 gives zeros.  The pool is
+    softmax's denominator.  A row of length 0 gives zeros.  ``v_in_k``:
+    V is the leading ``v_in_k`` numbers of the K row and ``v_pool`` is
+    None (a latent cache) → ``[B, h, v_in_k]``.  ``scale``: the scores',
+    where it is not ``1/sqrt(d)``.  The pool is
     passed as it lies in memory: the ``[M, block_size·kv_h, w]`` view the
     kernel reads merges two adjacent dims and moves nothing."""
     h = q.shape[1]
-    kv_h, w, dv = k_pool.shape[2], k_pool.shape[3], v_pool.shape[-1]
+    kv_h, w = k_pool.shape[2], k_pool.shape[3]
+    dv = v_in_k or v_pool.shape[-1]
     impl = paged_decode_impl(h, kv_h, interpret, w, dv)
     if impl == "reference":
         refusal = _refusal(h, kv_h, w, dv, compiled=not interpret)
         if refusal:
             shape_refused("paged_decode_attention",
                           (tuple(q.shape), tuple(k_pool.shape),
-                           tuple(v_pool.shape)), refusal)
+                           tuple(v_pool.shape) if v_pool is not None
+                           else ("v_in_k", v_in_k)), refusal)
         return paged_decode_reference(q, k_pool, v_pool, block_tables,
                                       lengths, window, sink, k_planes,
-                                      plane_stride)
+                                      plane_stride, v_in_k, scale)
     return _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink,
                               interpret=bool(interpret), window=window,
-                              k_planes=k_planes, plane_stride=plane_stride)
+                              k_planes=k_planes, plane_stride=plane_stride,
+                              v_in_k=v_in_k, scale=scale)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window",
-                                             "k_planes", "plane_stride"))
+                                             "k_planes", "plane_stride",
+                                             "v_in_k", "scale"))
 def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
                        interpret: bool, window, k_planes: int,
-                       plane_stride: int):
+                       plane_stride: int, v_in_k: int = 0,
+                       scale: float | None = None):
     """The Mosaic call of :func:`paged_decode_attention`, once the path is
     decided.  A jitted function of its own so that a program which holds
     the kernel many times (a layer kind's unrolled layers) traces and
@@ -344,13 +418,16 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
 
     B, h, d = q.shape
     M, block_size, kv_h, w = k_pool.shape
-    N, dv = v_pool.shape[0], v_pool.shape[-1]
+    dv = v_in_k or v_pool.shape[-1]
+    if v_in_k and k_planes * (plane_stride or M) != M:
+        raise ValueError(f"a latent pool of {M} pages is not {k_planes} "
+                         f"planes of {plane_stride}")
     max_blocks = block_tables.shape[1]
     dk = k_planes * w
     if dk > d:      # the last plane's lane padding: zeros times zeros
         q = jnp.pad(q, ((0, 0), (0, 0), (0, dk - d)))
     P = pages_per_step(block_size, kv_h, h, dk, k_pool.dtype.itemsize,
-                       max_blocks, dv)
+                       max_blocks, 0 if v_in_k else dv)
     rows = block_size * kv_h
     # query head r reads the columns of kv head r // n_rep
     head_mask = np.where(
@@ -358,15 +435,22 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
         == np.arange(h)[:, None] // (h // kv_h), 0.0, -1e30
     ).astype(np.float32)
     kernel = functools.partial(_paged_kernel, block_size=block_size,
-                               kv_h=kv_h, scale=1.0 / np.sqrt(d),
+                               kv_h=kv_h, scale=scale or 1.0 / np.sqrt(d),
                                window=window, sink=sink is not None,
-                               k_planes=k_planes, plane_stride=plane_stride)
+                               k_planes=k_planes, plane_stride=plane_stride,
+                               v_in_k=v_in_k)
     row = lambda b, lens, table: (b, 0, 0)
     whole = lambda b, lens, table: (0, 0)
     sink_spec, sink_arg = [], []
     if sink is not None:
         sink_spec = [pl.BlockSpec((h, 1), whole)]
         sink_arg = [sink.astype(jnp.float32).reshape(h, 1)]
+    # a V pool of its own, with its buffer; none where V lies in K's rows
+    v_spec, v_scratch, v_arg = [], [], []
+    if not v_in_k:
+        v_spec = [pl.BlockSpec(memory_space=pl.ANY)]
+        v_scratch = [pltpu.VMEM((2, P, rows, dv), v_pool.dtype)]
+        v_arg = [v_pool.reshape(v_pool.shape[0], rows, dv)]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -377,12 +461,13 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
                 pl.BlockSpec((h, P * rows), whole),
                 *sink_spec,
                 pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
+                *v_spec,
             ],
             out_specs=pl.BlockSpec((1, h, dv), row),
             scratch_shapes=[
-                pltpu.VMEM((2, k_planes * P, rows, w), k_pool.dtype),
-                pltpu.VMEM((2, P, rows, dv), v_pool.dtype),
+                pltpu.VMEM((2, k_planes, P, rows, w) if v_in_k
+                           else (2, k_planes * P, rows, w), k_pool.dtype),
+                *v_scratch,
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((1,), jnp.int32),
             ],
@@ -391,8 +476,9 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
         interpret=interpret,
         name="paged_decode_attention",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, jnp.asarray(head_mask), *sink_arg, k_pool.reshape(M, rows, w),
-      v_pool.reshape(N, rows, dv))
+      q, jnp.asarray(head_mask), *sink_arg,
+      k_pool.reshape(k_planes, plane_stride or M, rows, w) if v_in_k
+      else k_pool.reshape(M, rows, w), *v_arg)
 
 
 def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, lengths,

@@ -365,6 +365,42 @@ def check_paged_hybrid(s: KernelShapes, interpret: bool) -> List[Check]:
     return out
 
 
+def check_paged_latent(s: KernelShapes, interpret: bool) -> List[Check]:
+    """Paged decode over a latent cache at the widths of the model that
+    has one: 128 query heads on ONE cached row a token of 512 + 64 numbers
+    in five 128-lane planes, whose leading 512 are also the value, pages
+    of 128 tokens, the scores' scale that of the 192-wide head."""
+    pa = _mod("paged_attention")
+    rng = np.random.RandomState(13)
+    heads, k_dim, v_dim, page, planes = 128, 576, 512, 128, 5
+    max_blocks = max(s.cache_len // page, 6)
+    rows = s.slots
+    pages = rows * max_blocks + 1
+    q = _normal(rng, (rows, heads, k_dim), s.dtype)
+    tables = jnp.asarray(rng.permutation(np.arange(1, pages)).reshape(
+        rows, max_blocks).astype(np.int32))
+    top = max_blocks * page
+    lengths = jnp.asarray(np.resize(np.clip(
+        [page - 1, page, page + 1, top, 3, 2 * page + 5, top // 2, 0],
+        0, top), rows).astype(np.int32))
+    k = _normal(rng, (pages, page, 1, planes * 128), s.dtype)
+    k = k.at[..., k_dim:].set(0)              # the last plane's padding
+    k_pool = jnp.concatenate([k[..., p * 128:(p + 1) * 128]
+                              for p in range(planes)], axis=0)
+    kw = dict(k_planes=planes, plane_stride=pages, v_in_k=v_dim,
+              scale=192 ** -0.5)
+    got = pa.paged_decode_attention(q, k_pool, None, tables, lengths,
+                                    interpret=interpret, **kw)
+    with jax.default_matmul_precision("highest"):
+        want = pa.paged_decode_reference(
+            q.astype(jnp.float32), k_pool.astype(jnp.float32), None, tables,
+            lengths, **kw)
+    live = (lengths > 0)[:, None, None]       # a dead slot gives zeros
+    return [Check("paged_decode(latent k576 v=k[:512], 128 heads on 1)",
+                  float(_rel_err(jnp.where(live, got, 0),
+                                 jnp.where(live, want, 0))), DECODE_TOL)]
+
+
 def check_fused_adam(s: KernelShapes, interpret: bool) -> List[Check]:
     """One-pass Adam and the grad-norm read on one large fp32 leaf.  The
     kernel and the reference run the same fp32 formula; they may differ by
@@ -561,7 +597,8 @@ def check_block_sparse(s: KernelShapes, interpret: bool) -> List[Check]:
 
 
 CHECKS = (check_flash, check_flash_streamed, check_decode, check_paged,
-          check_paged_hybrid, check_fused_adam, check_moe, check_moe_grouped,
+          check_paged_hybrid, check_paged_latent, check_fused_adam, check_moe,
+          check_moe_grouped,
           check_moe_share, check_quantizer, check_block_sparse)
 
 

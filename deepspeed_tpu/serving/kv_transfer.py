@@ -51,7 +51,8 @@ def page_payload(engine: Any, prompt: List[int], blocks: List[int],
     Returns ``{"raw": bytes, "sha256": str, "dtype": str, "shape":
     [...], "synthetic": bool}`` — ``raw`` is K-plane bytes followed by
     V-plane bytes (equal length, concatenated; the receiver splits at
-    the midpoint)."""
+    the midpoint), or the K-plane bytes alone where the pool has no V (a
+    latent cache, whose rows hold their value)."""
     bs = int(engine.cache_config.block_size)
     pool = getattr(engine, "pool", None)
     if pool is None:
@@ -65,11 +66,11 @@ def page_payload(engine: Any, prompt: List[int], blocks: List[int],
                 "dtype": "int32", "shape": [bs], "synthetic": True}
     block = blocks[page_index]
     pool = _token_pool(pool)
-    k = np.asarray(pool["k"][:, block])
-    v = np.asarray(pool["v"][:, block])
-    raw = k.tobytes() + v.tobytes()
-    return {"raw": raw, "sha256": _sha256(raw), "dtype": str(k.dtype),
-            "shape": list(k.shape), "synthetic": False}
+    # K then V, or K alone where the kind's V lies in its K rows
+    planes = [np.asarray(pool[name][:, block]) for name in sorted(pool)]
+    raw = b"".join(p.tobytes() for p in planes)
+    return {"raw": raw, "sha256": _sha256(raw), "dtype": str(planes[0].dtype),
+            "shape": list(planes[0].shape), "synthetic": False}
 
 
 def _token_pool(pools: Dict[str, Any]) -> Dict[str, Any]:
@@ -96,29 +97,29 @@ def inject_pages(engine: Any, blocks: List[int],
         return
     import jax.numpy as jnp
 
+    pool = _token_pool(pool)
+    names = sorted(pool)    # as exported: K then V, or K alone
     ids: List[int] = []
-    ks: List[np.ndarray] = []
-    vs: List[np.ndarray] = []
+    pages: Dict[str, List[np.ndarray]] = {name: [] for name in names}
     for page_index, p in sorted(staged.items()):
         if p.get("synthetic"):
             continue
         raw = p["raw"]
-        half = len(raw) // 2
+        part = len(raw) // len(names)
         dt = np.dtype(p["dtype"])
         shape = tuple(int(s) for s in p["shape"])
         ids.append(blocks[page_index])
-        ks.append(np.frombuffer(raw[:half], dtype=dt).reshape(shape))
-        vs.append(np.frombuffer(raw[half:], dtype=dt).reshape(shape))
+        for i, name in enumerate(names):
+            pages[name].append(np.frombuffer(
+                raw[i * part:(i + 1) * part], dtype=dt).reshape(shape))
     if not ids:
         return
     idx = jnp.asarray(ids)
-    pool = _token_pool(pool)
     # page planes are [L, bs, kh, hd]; stacked on a new axis 1 they
     # line up with pool[:, idx] -> [L, n, bs, kh, hd]
-    pool["k"] = pool["k"].at[:, idx].set(
-        jnp.asarray(np.stack(ks, axis=1)))
-    pool["v"] = pool["v"].at[:, idx].set(
-        jnp.asarray(np.stack(vs, axis=1)))
+    for name in names:
+        pool[name] = pool[name].at[:, idx].set(
+            jnp.asarray(np.stack(pages[name], axis=1)))
 
 
 def push_pages(rpc_fn, rid: str, payloads: Dict[int, Dict[str, Any]],

@@ -72,6 +72,24 @@ def test_streamed_check_refuses_a_resident_shape(shapes):
         selfcheck.check_flash_streamed(resident, interpret=True)
 
 
+def test_the_latent_paged_check_has_teeth(monkeypatch, shapes):
+    """The paged case over a latent cache (128 heads on one row of 576 in
+    five planes, V its leading 512) passes, and a kernel that takes V from
+    the wrong numbers of the row fails it."""
+    pa = importlib.import_module("deepspeed_tpu.ops.pallas.paged_attention")
+    monkeypatch.setattr(selfcheck, "CHECKS", (selfcheck.check_paged_latent,))
+    (check,) = selfcheck.run_checks(shapes, interpret=True)
+    assert "latent" in check.name and check.ok
+    real = pa.paged_decode_attention
+
+    def shifted(q, k_pool, *a, **kw):
+        return real(q, jnp.roll(k_pool, 1, axis=-1), *a, **kw)
+
+    monkeypatch.setattr(pa, "paged_decode_attention", shifted)
+    with pytest.raises(AssertionError, match="selfcheck FAILED.*latent"):
+        selfcheck.run_checks(shapes, interpret=True)
+
+
 def test_the_hybrid_paged_check_has_teeth(monkeypatch, shapes):
     """The paged case at K 192 / V 128 runs both kinds of layer, and a
     kernel that loses the sink fails it."""

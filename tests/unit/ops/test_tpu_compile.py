@@ -519,6 +519,139 @@ def test_hybrid_programs_keep_both_pools_in_place_on_v5e(hybrid_programs,
         assert rows.count(512) >= 7 and 256 not in rows
 
 
+def test_paged_decode_over_a_latent_cache_compiles_for_v5e(one_chip):
+    """128 query heads on ONE cached row of 576 numbers in five 128-lane
+    planes, pages of 128 tokens, V the row's leading 512 (no V pool): the
+    Mosaic call alone, the pool through bitcasts."""
+    rows, h, page, pages, layers, max_blocks = 128, 128, 128, 1025, 2, 128
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(q, k_pool, tables, lengths):
+        return pa.paged_decode_attention(
+            q, k_pool.reshape((-1,) + k_pool.shape[2:]), None, tables,
+            lengths, interpret=False, k_planes=5,
+            plane_stride=layers * pages, v_in_k=512, scale=192 ** -0.5)
+
+    done = jax.jit(fn).lower(
+        arg((rows, h, 576)), arg((5 * layers, pages, page, 1, 128)),
+        arg((rows, max_blocks), jnp.int32), arg((rows,), jnp.int32)).compile()
+    text = done.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert "paged_decode_attention" in text
+    assert done.output_shardings is not None
+    made = re.findall(rf"= \w+\[{5 * layers},{pages},\S* ([\w-]+)\(", text)
+    assert made and set(made) <= {"parameter", "bitcast"}, made
+    # the kernel's own view, [planes, layers·pages, rows, 128]: a bitcast
+    view = re.findall(rf"= \w+\[5,{layers * pages},\S* ([\w-]+)\(", text)
+    assert view and set(view) <= {"bitcast"}, view
+    # a V narrower than whole planes is refused in words, not compiled
+    assert "128 lanes" in pa._refusal(h, 1, 128, 500, compiled=True)
+
+
+#: the latent cell's model at its published widths, cut to a dense and two
+#: sparse layers, with the cell's pages of 128 tokens
+_LATENT = dict(vocab_size=19200, num_layers=3, first_k_dense=1,
+               held_experts=(0, 8), max_seq_len=16384)
+_LATENT_PAGE, _LATENT_PAGES, _LATENT_SLOTS = 128, 6144, 128
+
+
+@pytest.fixture(scope="module")
+def latent_programs(topo, one_chip):
+    """The latent cell's engine over shapes alone and a function that
+    compiles one of its programs for the described chip."""
+    from deepspeed_tpu import models
+    from deepspeed_tpu.inference.v2 import engine_v2 as ev2
+    from deepspeed_tpu.inference.v2.kv_cache import KVCacheConfig
+    from deepspeed_tpu.ops.pallas import moe_grouped_matmul as gm
+
+    model = models.PanguUltraMoeModel(models.PanguUltraMoeConfig(**_LATENT))
+    cache = KVCacheConfig(num_blocks=_LATENT_PAGES, block_size=_LATENT_PAGE,
+                          max_seq_len=_LATENT["max_seq_len"])
+    placed = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    arg = lambda shape, dt=jnp.int32: placed(jax.ShapeDtypeStruct(shape, dt))
+    mp = pytest.MonkeyPatch()
+    mp.setattr(pa, "reference_off_tpu", lambda interpret: False)
+    mp.setattr(gm, "reference_off_tpu", lambda interpret: False)
+    real_pool = ev2.init_kv_pool
+    mp.setattr(ev2, "init_kv_pool",
+               lambda ad, cc: jax.eval_shape(lambda: real_pool(ad, cc)))
+    shapes = jax.eval_shape(
+        lambda key: jax.tree.map(lambda w: w.astype(model.config.dtype),
+                                 model.init_params(key)),
+        jax.random.PRNGKey(0))
+    engine = ev2.RaggedInferenceEngineV2(model, shapes, cache,
+                                         max_batch_slots=_LATENT_SLOTS)
+    blocks = cache.max_blocks_per_seq
+    common = (arg((), jnp.float32),
+              placed(jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+
+    @functools.cache
+    def compiled(program):
+        kind, _, n = program.rpartition("_")
+        rows = (arg((_LATENT_SLOTS,)),
+                (arg((_LATENT_SLOTS,)),
+                 arg((_LATENT_SLOTS + engine.prefill_batch,))),
+                arg((_LATENT_SLOTS,)), arg((_LATENT_SLOTS, blocks)),
+                arg((_LATENT_SLOTS,)))
+        if kind == "decode_burst":
+            fn = functools.partial(engine._decode_burst_fn, n_steps=int(n))
+            chunks = None
+        else:                    # the one-step program with chunks riding
+            fn = functools.partial(engine._decode_burst_fn, n_steps=1,
+                                   kb=int(n))
+            chunks = (arg((engine.prefill_batch, engine.chunk)),
+                      arg((engine.prefill_batch, blocks)),
+                      arg((engine.prefill_batch,)),
+                      arg((engine.prefill_batch,)), None)
+        done = jax.jit(fn, donate_argnums=(1,)).lower(
+            placed(shapes), placed(engine.pool), *rows, *common, None,
+            chunks).compile()
+        return done.as_text(), done.memory_analysis()
+
+    yield engine, compiled
+    mp.undo()
+
+
+@pytest.mark.parametrize("program", ["decode_burst_8", "chunks_128"])
+def test_latent_programs_keep_the_one_pool_in_place_on_v5e(latent_programs,
+                                                           program):
+    """The latent cell's programs at its shapes (a row a token of 576
+    numbers in five planes, 6,144 pages of 128 tokens, no V pool): the
+    pool is passed on, written in place and read through a bitcast; a
+    Mosaic attention call a layer for the decode rows and, in the step
+    that carries chunks, one more for the chunk rows (a row a token: no
+    bucket of keys is gathered, no score matrix of 128 heads over 8,192
+    keys lies in memory); a pair of grouped expert calls in the sparse
+    layer."""
+    engine, compiled = latent_programs
+    assert engine.last_attn_path in (None, "pallas")
+    assert {k: sorted(v) for k, v in engine.pool.items()} == {"latent": ["k"]}
+    plane = engine.pool["latent"]["k"]
+    assert plane.shape == (15, _LATENT_PAGES, _LATENT_PAGE, 1, 128)
+    text, memory = compiled(program)
+    assert engine.last_attn_path == "pallas"
+    assert pool_value_faults(text, 15, _LATENT_PAGES, _LATENT_PAGE, 1,
+                             128) == []
+    assert memory.alias_size_in_bytes == 2 * np.prod(plane.shape)
+    assert memory.temp_size_in_bytes < 1.0e9
+    assert expert_value_faults(text, 2, 8, 7680, 2048) == []
+    assert _mosaic_calls(text) == (1, 1)
+    calls = len(re.findall(r"paged_decode_attention[\w.]* = ", text))
+    assert calls == (4 if program.startswith("chunks") else 2)
+    assert memory.temp_size_in_bytes < 0.7e9
+    if program.startswith("chunks"):
+        # 256 chunk rows and 128 decode rows through each weight together
+        rows = _matmul_rows(text)
+        assert rows.count(384) >= 6 and 256 not in rows
+        # no gathered keys, no scores over a bucket
+        assert not re.search(r"\[2,16384,\d+\]|\[2,\d+,128,16384\]", text)
+        assert engine._prefill_bucket([]) == 128      # one program, no bucket
+
+
 def _plane_relayouts(text: str, B: int, S: int, h: int, d: int):
     """Names of the compiled program's instructions that make a new
     ``[.., S, .., d]`` plane of the attention operands by moving data:
