@@ -368,6 +368,20 @@ class LlamaModel:
     # forward
     # ------------------------------------------------------------------
 
+    def uses_flash_kernels(self) -> bool:
+        """Whether a step of this model holds the Pallas flash kernels:
+        the option sends attention to the flash op AND the op, by its own
+        test (``flash_route``), runs the kernels for the model's shape on
+        this platform.  The engine's memory ledger asks before a step is
+        traced."""
+        c = self.config
+        if c.attn_impl != "flash":
+            return False
+        from ..ops.pallas.flash_attention import flash_route
+
+        return flash_route(c.max_seq_len, c.hd, c.flash_block_q,
+                           c.flash_block_k)[0] == "kernel"
+
     def _constrain(self, x: jnp.ndarray, *spec) -> jnp.ndarray:
         if self.mesh is None:
             return x

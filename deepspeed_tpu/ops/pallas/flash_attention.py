@@ -12,7 +12,11 @@ The kernel family (dispatched by :func:`_flash_call` / :func:`_flash_bwd`):
   the contiguous ``lattice.kv_block_bounds`` / ``q_block_bounds`` range,
   so causal work is the true triangle and windowed work is O(S·window).
   Taken while a head's planes fit the VMEM budget
-  (``lattice.resident_fits``).
+  (``lattice.resident_fits``).  Heads narrower than the 128 lanes share
+  a program side by side (``_heads_per_program``: two at d = 64), read
+  from ``[B, S, h·d]`` as the operands lie, with no transpose on either
+  side; and where one k-block spans the sequence the backward sums Δ in
+  the tile (``delta_in_tile``).  Both from the shape alone.
 * **streamed** (fwd + dq/dkv backward): beyond VMEM residency the grid
   grows a live-step dimension and a scalar-prefetched ``index_map``
   DMAs ONLY each step's live block (``lattice.plan_q_live`` /
@@ -41,8 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import lattice
-from .select import (reference_off_tpu, resident_compiler_params,
-                     shape_refused)
+from .select import (record_route, reference_off_tpu,
+                     resident_compiler_params, shape_refused)
 
 
 def _mask(S, T, causal, window=None):
@@ -118,9 +122,45 @@ def _kernel_refusal(S: int, d: int, block_q: int, block_k: int,
     return None
 
 
-def _seg_operand(segment_ids, S: int, heads: int):
+def _heads_per_program(h: int, d: int) -> int:
+    """Heads a resident program takes: as many as fill the 128 lanes when
+    a head is narrower (two at d = 64), one otherwise.  From the shape
+    alone: at d >= 128 nothing changes."""
+    if d < 128 and 128 % d == 0 and h % (128 // d) == 0:
+        return 128 // d
+    return 1
+
+
+def _planes(heads: int, B: int, S: int, h: int, d: int):
+    """How the resident kernels see ``[B, S, h, d]`` operands, as
+    ``(to_planes, from_planes, index)``.  One head a program: ``[B·h, S,
+    d]`` planes (a transpose each way) at block index ``(g, i, 0)``.
+    Several: the array as it lies, ``[B, S, h·d]`` (a reshape, no copy),
+    a program's heads side by side in one 128-lane block at ``(row, i,
+    group)``: no transpose on either side, lane-dense loads and stores."""
+    if heads == 1:
+        return (lambda a: a.transpose(0, 2, 1, 3).reshape(B * h, S, d),
+                lambda a: a.reshape(B, h, S, d).transpose(0, 2, 1, 3),
+                lambda g, i: (g, i, 0))
+    groups = h // heads
+    return (lambda a: a.reshape(B, S, h * d),
+            lambda a: a.reshape(B, S, h, d),
+            lambda g, i: (g // groups, i, g % groups))
+
+
+def _head_lanes(heads: int, rows: int, width: int):
+    """``[rows, width]`` int32: which of a block's ``heads`` heads each
+    lane belongs to (None for one head: nothing to select)."""
+    if heads == 1:
+        return None
+    return jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1) // (
+        width // heads)
+
+
+def _seg_operand(segment_ids, S: int, programs_a_row: int):
     """(array, BlockSpec) for the resident kernels' segment-id input:
-    ``[B, 1, S]`` blocked one batch row at a time — the middle singleton
+    ``[B, 1, S]`` blocked one batch row at a time (``programs_a_row`` of
+    the grid's leading axis share a row's ids) — the middle singleton
     keeps the block's last two dims equal to the array's, which Mosaic
     demands of any block that is not a multiple of (8, 128) — or a
     ``[1, 1, 1]`` placeholder when the caller packs nothing."""
@@ -130,7 +170,8 @@ def _seg_operand(segment_ids, S: int, heads: int):
         return (jnp.zeros((1, 1, 1), jnp.int32),
                 pl.BlockSpec((1, 1, 1), lambda bh, i: (0, 0, 0)))
     return (segment_ids.astype(jnp.int32)[:, None, :],
-            pl.BlockSpec((1, 1, S), lambda bh, i: (bh // heads, 0, 0)))
+            pl.BlockSpec((1, 1, S),
+                         lambda g, i: (g // programs_a_row, 0, 0)))
 
 
 # ---------------------------------------------------------------------------
@@ -140,51 +181,66 @@ def _seg_operand(segment_ids, S: int, heads: int):
 
 def _fa_kernel(q_ref, k_ref, v_ref, seg_ref, o_ref, lse_ref, *,
                block_q: int, block_k: int, seq_len: int, causal: bool,
-               scale: float, window=None, has_seg: bool = False):
+               scale: float, window=None, has_seg: bool = False,
+               heads: int = 1):
+    """``heads`` > 1: the blocks hold that many heads side by side on the
+    lanes.  Each head in turn takes the block with the other heads' lanes
+    of q zeroed, so q·kᵀ over all lanes is its own score tile and p·v
+    gives its output on its own lanes (the MXU passes of a lone 64-wide
+    head, which fills half the array either way)."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale  # [block_q, d]
+    q_all = q_ref[0].astype(jnp.float32) * scale  # [block_q, heads·d]
     nk = seq_len // block_k
+    lane_head = _head_lanes(heads, block_q, q_all.shape[-1])
 
     m0 = jnp.full((block_q,), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
+    acc0 = jnp.zeros((block_q, q_all.shape[-1]), jnp.float32)
     q_seg = (seg_ref[0, 0, pl.ds(qi * block_q, block_q)] if has_seg else None)
-
-    def body(ki, carry):
-        m, l, acc = carry
-        kblk = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        k_seg = (seg_ref[0, 0, pl.ds(ki * block_k, block_k)] if has_seg
-                 else None)
-        keep = lattice.tile_keep(qi, ki, block_q, block_k, causal, window,
-                                 q_seg, k_seg)
-        if keep is not None:
-            s = jnp.where(keep, s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        if has_seg:
-            # a row fully masked in this tile must not accumulate the
-            # exp(-1e30 − (-1e30)) = 1 garbage a pure -inf carry avoids
-            p = jnp.where(keep, p, 0.0)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
-
     k0, nk_eff = lattice.kv_block_bounds(qi, block_q, block_k, nk, causal,
                                          window)
-    m, l, acc = jax.lax.fori_loop(k0, nk_eff, body, (m0, l0, acc0))
-    l2 = l[:, None]
-    o_ref[0] = jnp.where(l2 > 0, acc / jnp.where(l2 > 0, l2, 1.0),
-                         0.0).astype(o_ref.dtype)
-    lse_ref[0] = jnp.where(l2 > 0, m[:, None] + jnp.log(
-        jnp.where(l2 > 0, l2, 1.0)), 1e30)
+    out, stats = None, []
+    for a in range(heads):
+        q = q_all if heads == 1 else jnp.where(lane_head == a, q_all, 0.0)
+
+        def body(ki, carry, q=q):
+            m, l, acc = carry
+            kblk = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(
+                jnp.float32)
+            vblk = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(
+                jnp.float32)
+            s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            k_seg = (seg_ref[0, 0, pl.ds(ki * block_k, block_k)] if has_seg
+                     else None)
+            keep = lattice.tile_keep(qi, ki, block_q, block_k, causal,
+                                     window, q_seg, k_seg)
+            if keep is not None:
+                s = jnp.where(keep, s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[:, None])
+            if has_seg:
+                # a row fully masked in this tile must not accumulate the
+                # exp(-1e30 − (-1e30)) = 1 garbage a pure -inf carry avoids
+                p = jnp.where(keep, p, 0.0)
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=-1)
+            acc_new = acc * alpha[:, None] + jax.lax.dot_general(
+                p, vblk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l_new, acc_new
+
+        m, l, acc = jax.lax.fori_loop(k0, nk_eff, body, (m0, l0, acc0))
+        l2 = l[:, None]
+        o = jnp.where(l2 > 0, acc / jnp.where(l2 > 0, l2, 1.0), 0.0)
+        out = o if out is None else jnp.where(lane_head == a, o, out)
+        stats.append((m, l2))
+    o_ref[0] = out.astype(o_ref.dtype)
+    for a, (m, l2) in enumerate(stats):
+        lse_ref[a] = jnp.where(l2 > 0, m[:, None] + jnp.log(
+            jnp.where(l2 > 0, l2, 1.0)), 1e30)
 
 
 # ---------------------------------------------------------------------------
@@ -299,47 +355,49 @@ def _flash_call(q, k, v, causal, block_q, block_k, interpret,
                                            segment_ids)
         return (out, lse) if with_lse else out
     block_q, block_k = _resolve_blocks(block_q, block_k, S, d)
-    # [B, S, h, d] -> [B*h, S, d]
-    qr = q.transpose(0, 2, 1, 3).reshape(B * h, S, d)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * h, S, d)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * h, S, d)
+    streamed = (force_stream or not lattice.resident_fits(S, d)) \
+        and not has_seg
+    heads = 1 if streamed else _heads_per_program(h, d)
+    to_planes, from_planes, at = _planes(heads, B, S, h, d)
+    qr, kr, vr = to_planes(q), to_planes(k), to_planes(v)
 
-    if (force_stream or not lattice.resident_fits(S, d)) and not has_seg:
+    if streamed:
         out, lse = _flash_fwd_stream(qr, kr, vr, causal, block_q, block_k,
                                      window, interpret)
-        out = out.reshape(B, h, S, d).transpose(0, 2, 1, 3)
+        out = from_planes(out)
         lse = lse.reshape(B, h, S)
         return (out, lse) if with_lse else out
-    seg, seg_spec = _seg_operand(segment_ids, S, h)
+    seg, seg_spec = _seg_operand(segment_ids, S, h // heads)
+    width = heads * d
 
     kernel = functools.partial(
         _fa_kernel, block_q=block_q, block_k=block_k, seq_len=S,
         causal=causal, scale=1.0 / np.sqrt(d), window=window,
-        has_seg=has_seg)
+        has_seg=has_seg, heads=heads)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * h, S // block_q),
+        grid=(B * h // heads, S // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, S, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, S, d), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, block_q, width), at),
+            pl.BlockSpec((1, S, width), lambda g, qi: at(g, 0)),
+            pl.BlockSpec((1, S, width), lambda g, qi: at(g, 0)),
             seg_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, width), at),
             # lse as [B*h, S, 1]: trailing singleton keeps the block shape
             # legal under the (8, 128) TPU tiling rule for any block_q
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((heads, block_q, 1), lambda g, qi: (g, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * h, S, d), q.dtype),
+            jax.ShapeDtypeStruct(qr.shape, q.dtype),
             jax.ShapeDtypeStruct((B * h, S, 1), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
         **resident_compiler_params(interpret),
     )(qr, kr, vr, seg)
-    out = out.reshape(B, h, S, d).transpose(0, 2, 1, 3)
+    out = from_planes(out)
     lse = lse.reshape(B, h, S)  # drops the singleton
     return (out, lse) if with_lse else out
 
@@ -357,7 +415,8 @@ _TN = (((0,), (0,)), ((), ()))      # aᵀ · b
 def _fa_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, seg_ref,
                    dq_ref, dk_ref, dv_ref, dq_acc, *, block_q: int,
                    block_k: int, seq_len: int, causal: bool, scale: float,
-                   window, has_seg: bool = False):
+                   window, has_seg: bool = False,
+                   delta_in_tile: bool = False, heads: int = 1):
     """The resident backward, one pass: grid (bh, k-block); Q/do/lse/Δ
     VMEM-resident, the q-loop walks the transposed lattice range, and dq
     gathers in a float32 ``[S, d]`` VMEM plane over a head's k-blocks —
@@ -368,12 +427,30 @@ def _fa_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, seg_ref,
     dk += dsᵀ·q are plain ``[bk, bq] × [bq, d]`` products; dq += ds·k
     alone turns a (rounded) tile.  The MXU takes q, k, v, do as they
     arrive and p, ds rounded to that dtype; statistics, ``exp``, the ds
-    arithmetic and the three accumulators are float32."""
+    arithmetic and the three accumulators are float32.
+
+    ``delta_in_tile`` (one k-block spans the sequence, so a tile holds
+    every key of its rows): Δ is the tile's own Σ_k p·dp, in float32 from
+    the p and dp that ds is made of, and ``delta_ref`` is a placeholder.
+    Δ = do·o from the ROUNDED output leaves Σ_k ds ≠ 0 by that rounding,
+    which where keys share a large common part (a deep random-weight
+    encoder) is most of dq's and dk's error: 0.10 against 0.03 relative
+    on BERT-large's ``wq``/``wk`` gradients (my chip runs, PR 41).  With
+    several k-blocks no tile sees a whole row, and Δ stays do·o, with that
+    error where it arises (``test_deep_stack_gradients_by_k_blocks`` holds
+    both; an exact Δ for every tiling needs a float32 output from the
+    forward or a first pass over the row: PERF.md §7).
+
+    ``heads`` > 1 (as in the forward): each head in turn takes the k and
+    v tiles with the other heads' lanes zeroed, so k·qᵀ and v·doᵀ over all
+    lanes are its own tiles and dq lands on its own lanes; dk and dv come
+    out on every lane and are kept on its own."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
     nq, nk = seq_len // block_q, seq_len // block_k
-    kblk, vblk = k_ref[0], v_ref[0]                    # [bk, d]
+    k_all, v_all = k_ref[0], v_ref[0]                  # [bk, heads·d]
+    lane_head = _head_lanes(heads, block_k, k_all.shape[-1])
     k_seg = (seg_ref[0, 0, pl.ds(pl.multiple_of(ki * block_k, block_k),
                                  block_k)] if has_seg else None)
 
@@ -381,36 +458,47 @@ def _fa_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, seg_ref,
     def _new_head():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def body(qi, carry):
-        dk_acc, dv_acc = carry
-        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
-        q, do = q_ref[0, rows, :], do_ref[0, rows, :]  # [bq, d]
-        st = jax.lax.dot_general(kblk, q, _NT,
-                                 preferred_element_type=jnp.float32) * scale
-        keep = lattice.tile_keep(qi, ki, block_q, block_k, causal, window,
-                                 seg_ref[0, 0, rows] if has_seg else None,
-                                 k_seg, transposed=True)
-        pt = jnp.exp(st - lse_ref[0, :, rows])         # [bk, bq] − [1, bq]
-        if keep is not None:
-            pt = jnp.where(keep, pt, 0.0)
-        dv_acc = dv_acc + jax.lax.dot_general(
-            pt.astype(do.dtype), do, _NN,
-            preferred_element_type=jnp.float32)
-        dpt = jax.lax.dot_general(vblk, do, _NT,
-                                  preferred_element_type=jnp.float32)
-        dst = (pt * (dpt - delta_ref[0, :, rows])).astype(q.dtype)
-        dk_acc = dk_acc + jax.lax.dot_general(
-            dst, q, _NN, preferred_element_type=jnp.float32)
-        dq_acc[rows, :] += jax.lax.dot_general(
-            dst, kblk, _TN, preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
-
     q0, nq_eff = lattice.q_block_bounds(ki, block_q, block_k, nq, causal,
                                         window)
-    zeros = jnp.zeros((block_k, kblk.shape[-1]), jnp.float32)
-    dk_acc, dv_acc = jax.lax.fori_loop(q0, nq_eff, body, (zeros, zeros))
-    dk_ref[0] = (dk_acc * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv_acc.astype(dv_ref.dtype)
+    zeros = jnp.zeros((block_k, k_all.shape[-1]), jnp.float32)
+    dk, dv = None, None
+    for a in range(heads):
+        own = None if heads == 1 else lane_head == a
+        kblk = k_all if heads == 1 else jnp.where(own, k_all, 0)
+        vblk = v_all if heads == 1 else jnp.where(own, v_all, 0)
+
+        def body(qi, carry, a=a, kblk=kblk, vblk=vblk):
+            dk_acc, dv_acc = carry
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]  # [bq, heads·d]
+            st = jax.lax.dot_general(
+                kblk, q, _NT, preferred_element_type=jnp.float32) * scale
+            keep = lattice.tile_keep(
+                qi, ki, block_q, block_k, causal, window,
+                seg_ref[0, 0, rows] if has_seg else None, k_seg,
+                transposed=True)
+            pt = jnp.exp(st - lse_ref[a, :, rows])     # [bk, bq] − [1, bq]
+            if keep is not None:
+                pt = jnp.where(keep, pt, 0.0)
+            dv_acc = dv_acc + jax.lax.dot_general(
+                pt.astype(do.dtype), do, _NN,
+                preferred_element_type=jnp.float32)
+            dpt = jax.lax.dot_general(vblk, do, _NT,
+                                      preferred_element_type=jnp.float32)
+            delta = (jnp.sum(pt * dpt, axis=0, keepdims=True)
+                     if delta_in_tile else delta_ref[a, :, rows])  # [1, bq]
+            dst = (pt * (dpt - delta)).astype(q.dtype)
+            dk_acc = dk_acc + jax.lax.dot_general(
+                dst, q, _NN, preferred_element_type=jnp.float32)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                dst, kblk, _TN, preferred_element_type=jnp.float32)
+            return dk_acc, dv_acc
+
+        dk_acc, dv_acc = jax.lax.fori_loop(q0, nq_eff, body, (zeros, zeros))
+        dk = dk_acc if dk is None else jnp.where(own, dk_acc, dk)
+        dv = dv_acc if dv is None else jnp.where(own, dv_acc, dv)
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
 
     @pl.when(ki == nk - 1)
     def _head_done():
@@ -429,40 +517,48 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, block_q, block_k,
     block_q, block_k = _resolve_blocks(block_q, block_k, S, d,
                                        backward=True,
                                        itemsize=q.dtype.itemsize)
-    qr = q.transpose(0, 2, 1, 3).reshape(B * h, S, d)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * h, S, d)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * h, S, d)
-    dor = do.transpose(0, 2, 1, 3).reshape(B * h, S, d)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                            # [B, S, h]
+    heads = _heads_per_program(h, d)
+    to_planes, from_planes, at = _planes(heads, B, S, h, d)
+    qr, kr, vr, dor = to_planes(q), to_planes(k), to_planes(v), to_planes(do)
+    width = heads * d
     # a head's statistics as one lane-dense float32 row, [B·h, 1, S]: 32 KiB
     # at S = 8,192 where [B·h, S, 1] is tiled (8, 128) to 4 MiB, in HBM and
     # in VMEM
     lse_r = lse.reshape(B * h, 1, S)
-    delta_r = delta.transpose(0, 2, 1).reshape(B * h, 1, S)
-    seg, seg_spec = _seg_operand(segment_ids, S, h)
-    plane = pl.BlockSpec((1, S, d), lambda bh, ki: (bh, 0, 0))
-    stats = pl.BlockSpec((1, 1, S), lambda bh, ki: (bh, 0, 0))
-    tile_k = pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0))
+    plane = pl.BlockSpec((1, S, width), lambda g, ki: at(g, 0))
+    stats = pl.BlockSpec((heads, 1, S), lambda g, ki: (g, 0, 0))
+    delta_in_tile = block_k == S
+    if delta_in_tile:
+        # the kernel sums p·dp over the tile's keys, which are all of them
+        delta_r, delta_spec = (jnp.zeros((1, 1, 1), jnp.float32),
+                               pl.BlockSpec((1, 1, 1),
+                                            lambda g, ki: (0, 0, 0)))
+    else:
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)                        # [B, S, h]
+        delta_r, delta_spec = (
+            delta.transpose(0, 2, 1).reshape(B * h, 1, S), stats)
+    seg, seg_spec = _seg_operand(segment_ids, S, h // heads)
+    tile_k = pl.BlockSpec((1, block_k, width), at)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_kernel, block_q=block_q, block_k=block_k,
                           seq_len=S, causal=causal, scale=1.0 / np.sqrt(d),
-                          window=window, has_seg=segment_ids is not None),
-        grid=(B * h, S // block_k),
-        in_specs=[plane, plane, tile_k, tile_k, stats, stats, seg_spec],
+                          window=window, has_seg=segment_ids is not None,
+                          delta_in_tile=delta_in_tile, heads=heads),
+        grid=(B * h // heads, S // block_k),
+        in_specs=[plane, plane, tile_k, tile_k, stats, delta_spec, seg_spec],
         out_specs=[plane, tile_k, tile_k],
-        out_shape=[jax.ShapeDtypeStruct((B * h, S, d), q.dtype),
-                   jax.ShapeDtypeStruct((B * h, S, d), k.dtype),
-                   jax.ShapeDtypeStruct((B * h, S, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((S, d), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct(qr.shape, q.dtype),
+                   jax.ShapeDtypeStruct(qr.shape, k.dtype),
+                   jax.ShapeDtypeStruct(qr.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((S, width), jnp.float32)],
         interpret=interpret,
         name="flash_bwd",
         # dq gathers over a head's k-blocks: that grid axis runs in order
         **resident_compiler_params(interpret, ("parallel", "arbitrary")),
     )(qr, dor, kr, vr, lse_r, delta_r, seg)
 
-    back = lambda a: a.reshape(B, h, S, d).transpose(0, 2, 1, 3)
-    return back(dq), back(dk), back(dv)
+    return from_planes(dq), from_planes(dk), from_planes(dv)
 
 
 # ---------------------------------------------------------------------------
@@ -769,6 +865,23 @@ def _flash_inner_bwd(causal, block_q, block_k, window, impl, res, do):
 _flash.defvjp(_flash_inner_fwd, _flash_inner_bwd)
 
 
+def flash_route(S: int, d: int, block_q: int = 0, block_k: int = 0,
+                has_seg: bool = False, interpret: bool | None = None):
+    """``(impl, refusal)``: what :func:`flash_attention` runs for this
+    shape on this platform, by the test it decides with: ``"kernel"`` (the
+    compiled Pallas kernels), ``"interpret"`` (the kernels in the Pallas
+    interpreter) or ``"reference"`` (``jax.numpy``), and why the kernels
+    refused the shape when they did.  For a caller that has to know before
+    a step is traced (a model's ``uses_flash_kernels``, which the engine's
+    memory ledger asks)."""
+    if reference_off_tpu(interpret):
+        return "reference", None
+    refusal = _kernel_refusal(S, d, block_q, block_k, has_seg)
+    if refusal is not None:
+        return "reference", refusal
+    return ("interpret" if interpret else "kernel"), None
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = 0, block_k: int = 0,
                     window=None, segment_ids=None,
@@ -790,16 +903,11 @@ def flash_attention(q, k, v, causal: bool = True,
     seg = (segment_ids.astype(jnp.int32) if segment_ids is not None
            else jnp.zeros((B, 1), jnp.int32))
     block_q, block_k = int(block_q or 0), int(block_k or 0)
-    if reference_off_tpu(interpret):
-        impl = "reference"
-    else:
-        refusal = _kernel_refusal(S, d, block_q, block_k,
-                                  segment_ids is not None)
-        if refusal is not None:
-            shape_refused("flash_attention", tuple(q.shape), refusal)
-            impl = "reference"
-        else:
-            impl = "interpret" if interpret else "kernel"
+    impl, refusal = flash_route(S, d, block_q, block_k,
+                                segment_ids is not None, interpret)
+    if refusal is not None:
+        shape_refused("flash_attention", tuple(q.shape), refusal)
+    record_route("flash_attention", impl)
     return _flash(q, k, v, seg, causal, block_q, block_k, window, impl)
 
 

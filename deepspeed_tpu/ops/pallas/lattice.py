@@ -61,7 +61,11 @@ def resident_fits(S: int, d: int) -> bool:
 #: q/score/acc tiles share VMEM with the resident K/V planes, whose
 #: footprint is S·d, which is why the key is ELEMENTS not raw S (a d=128
 #: model meets a boundary at half the S).  ``auto_flash_blocks`` walks
-#: this largest-bound-first.
+#: this largest-bound-first.  The last row at [32, 512, 16, 64] bf16, no
+#: mask, on a v5e (PR 41, chip runs of the kernel alone, the device's
+#: clock, a head a program): 0.65 ms at 512 x 512 (one tile a head), 0.98
+#: at 256 x 512, 1.09 at 512 x 256, 1.30 at 256 x 256 and at 128 x 512,
+#: 3.31 at 128 x 128; two heads a program at 512 x 512: 0.53
 _FWD_BLOCKS: Tuple[Tuple[int, Tuple[int, int]], ...] = (
     (16384 * 64, (256, 256)),   # S·d > 1M elems
     (8192 * 64, (256, 512)),    # 512k < S·d <= 1M
@@ -73,7 +77,10 @@ _FWD_BLOCKS: Tuple[Tuple[int, Tuple[int, int]], ...] = (
 #: [1, 8192, 32, 128] bf16, window 4,096, on a v5e the backward took
 #: 7.68 ms at 512 x 512, 7.85 at 1024 x 512, 8.17 at 512 x 1024, 8.32 at
 #: 512 x 256, 9.26 at 256 x 512 and 12.2 at 256 x 256 (PR 32, chip runs
-#: of the kernel alone, the device's clock)
+#: of the kernel alone, the device's clock); at [32, 512, 16, 64] bf16,
+#: no mask, 1.03 ms at 512 x 512, 1.31 at 512 x 256, 1.46 at 256 x 512,
+#: 1.75 at 128 x 512, 2.05 at 256 x 256 and 3.83 at 128 x 128 (PR 41,
+#: the same way)
 _BWD_TILES: Tuple[Tuple[int, int], ...] = (
     (512, 512), (512, 256), (256, 512), (256, 256), (128, 256), (128, 128),
     (64, 64))

@@ -43,6 +43,25 @@ def shape_refused(kernel: str, shape: Any, reason: str) -> None:
             f"TPU, not the Pallas kernel — {reason}")
 
 
+def record_route(op: str, impl: str) -> None:
+    """Count, when a call site is traced, which implementation an entry
+    point chose, under the op's own name: ``ops/<op>/kernel_calls``,
+    ``ops/<op>/interpret_calls`` or ``ops/<op>/reference_calls`` on the
+    telemetry hub (a no-op while the hub is off).  Every caller of the op
+    counts here, a training step as a self-check; a reference call
+    counted on a TPU is a fall-back that :func:`shape_refused` has
+    explained.  The counters make a route visible without reading the
+    compiled program; on the chip the program's device operations are the
+    witness."""
+    from ...telemetry import get_telemetry
+
+    get_telemetry().inc_counter(
+        f"ops/{op}/{impl}_calls",
+        help=f"call sites traced at which {op} runs its {impl} "
+             f"implementation (kernel: compiled Pallas; interpret: the "
+             f"Pallas interpreter; reference: jax.numpy)")
+
+
 def resident_compiler_params(interpret: bool, dimension_semantics=None):
     """``compiler_params`` for a kernel holding resident planes (empty in
     the interpreter, which has no VMEM to limit and walks its grid in

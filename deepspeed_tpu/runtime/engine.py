@@ -732,11 +732,13 @@ class DeepSpeedEngine:
             # named entries under collective_scratch so peak_hbm gating
             # and OOM forensics can point at them
             mc = getattr(self.module, "config", None)
-            if getattr(mc, "attn_impl", "") == "flash":
-                # keyed on the MODEL's route (the signal that decides
-                # whether the kernel actually runs), not the
-                # kernels.flash_attention config knob — the knob only
-                # steers builders that construct the model
+            # keyed on the MODEL's route (the module says whether its
+            # step holds the flash kernels: the signal that decides
+            # whether they actually run), not the kernels.flash_attention
+            # config knob — the knob only steers builders that construct
+            # the model
+            holds_flash = getattr(self.module, "uses_flash_kernels", None)
+            if holds_flash is not None and holds_flash():
                 heads = int(getattr(mc, "num_heads", 0) or 0)
                 max_s = int(getattr(mc, "max_seq_len", 0) or 0)
                 layers = int(getattr(mc, "num_layers", 1) or 1)

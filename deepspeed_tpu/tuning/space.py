@@ -218,7 +218,9 @@ def default_space(max_micro_batch: int = 16,
             "model.attn_impl", ["xla", "flash"],
             description="attention kernel: XLA einsum+softmax vs the "
                         "Pallas flash family (ops/pallas/"
-                        "flash_attention.py dispatch ladder)"))
+                        "flash_attention.py dispatch ladder) for the "
+                        "Llama family; selects nothing for BERT, whose "
+                        "attention always goes through the flash op"))
         space.register(Dimension(
             "model.flash_block_q", [0, 256, 512],
             description="flash q-block (0 = seq-length auto table)",

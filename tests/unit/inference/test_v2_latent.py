@@ -279,6 +279,9 @@ def test_the_kernel_reads_v_from_ks_planes(h, d, dv, bs):
 def test_the_latent_kinds_counter_and_gauge():
     from deepspeed_tpu import telemetry
 
+    # a hub another file of this worker left on has counted this file's
+    # earlier tests: start from an empty registry
+    telemetry.get_telemetry().reset()
     tel = telemetry.configure(enabled=True, jsonl=False, prometheus=False)
     try:
         model = FAMILY.build(TINY)

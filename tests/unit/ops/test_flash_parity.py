@@ -339,37 +339,218 @@ def test_public_vjp_with_segments_on_cpu_path():
 
 
 # ---------------------------------------------------------------------------
+# heads side by side on the lanes (d < 128) and Δ summed in the tile
+# ---------------------------------------------------------------------------
+
+
+def test_heads_per_program_fill_the_lanes_from_the_shape_alone():
+    """Two 64-wide heads (four 32-wide) to a resident program, one from
+    128 lanes up or where the heads do not pair off; the Mistral cells'
+    ``h=32, d=128`` keeps one head a program and the planes it had."""
+    assert fa._heads_per_program(16, 64) == 2       # BERT-large
+    assert fa._heads_per_program(8, 32) == 4
+    assert fa._heads_per_program(3, 64) == 1        # an odd head is left
+    assert fa._heads_per_program(4, 96) == 1        # 96 divides no 128
+    assert fa._heads_per_program(32, 128) == 1      # Mistral-7B
+    assert fa._heads_per_program(8, 256) == 1
+
+
+@pytest.mark.parametrize("B, S, h, d, causal, window, packed", [
+    (2, 256, 4, 64, False, None, True),     # an encoder with padding
+    (2, 256, 4, 64, True, None, False),
+    (1, 256, 8, 32, True, 96, False),       # four heads a program
+    (2, 128, 2, 64, False, None, False),    # one tile: Δ in the tile
+    (1, 256, 3, 64, False, None, True),     # heads that do not pair off
+], ids=["encoder_segments", "causal", "four_heads_window", "one_tile",
+        "odd_heads"])
+def test_heads_side_by_side_match_reference(B, S, h, d, causal, window,
+                                            packed):
+    """Forward and all three gradients of the resident kernels against the
+    reference, with several heads sharing a program's 128 lanes (and one
+    case where they cannot), over 128 x 128 tiles and over one tile."""
+    rng = np.random.RandomState(5)
+    mk = lambda: jnp.asarray(rng.randn(B, S, h, d) * 0.5, jnp.float32)
+    q, k, v, do = mk(), mk(), mk(), mk()
+    seg = (jnp.asarray(np.sort(rng.randint(0, 3, (B, S)), axis=1), jnp.int32)
+           if packed else None)
+    out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal, block_q=128, block_k=128, window=window,
+        segment_ids=seg, interpret=True), q, k, v)
+    ref, ref_vjp = jax.vjp(lambda q, k, v: fa._reference_attention(
+        q, k, v, causal, window, seg), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for got, want in zip(vjp(do), ref_vjp(do)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
 # model routing (BERT padding-as-segments)
 # ---------------------------------------------------------------------------
 
 
-def test_bert_flash_matches_xla_on_real_tokens():
-    """BertConfig(attn_impl='flash') routes encoder attention through the
-    flash family with the padding mask as segment ids; real-token rows
-    must match the XLA path (pad-query rows differ by design and are
-    -100-masked in the loss)."""
-    from deepspeed_tpu.models.bert import BertConfig, BertModel
+def _dense_attention(q, k, v, mesh, causal=False, segment_ids=None):
+    """The attention BERT had beside the flash op until PR 41, written out
+    here: einsum, float32 softmax over every key that is not a pad."""
+    assert not causal
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) \
+        / np.sqrt(q.shape[-1])
+    if segment_ids is not None:
+        s = jnp.where(segment_ids.astype(bool)[:, None, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
+
+@pytest.fixture
+def through_interpreter(monkeypatch):
+    """Every ``flash_attention`` call runs the kernels in the Pallas
+    interpreter, as a test has to ask: the platform here is the CPU."""
+    import functools
+
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+
+
+def _bert_batch(padded: bool, B=2, S=64):
     rng = np.random.RandomState(0)
-    B, S = 2, 64
     ids = jnp.asarray(rng.randint(0, 512, size=(B, S)))
     mask = np.ones((B, S), bool)
-    mask[:, S - 10:] = False  # padded tail
-    mask_j = jnp.asarray(mask)
+    if padded:
+        mask[:, S - 10:] = False  # padded tail
+    labels = np.where(mask & (rng.rand(B, S) < 0.3), np.asarray(ids), -100)
+    batch = {"input_ids": ids, "labels": jnp.asarray(labels)}
+    if padded:
+        batch["attention_mask"] = jnp.asarray(mask)
+    return batch, mask
 
-    cfg_x = BertConfig.tiny(dtype=jnp.float32)
-    model_x = BertModel(cfg_x)
-    params = model_x.init_params(jax.random.PRNGKey(0))
-    logits_x = model_x.forward(params, ids, attention_mask=mask_j)
 
-    import dataclasses
+@pytest.mark.parametrize("impl", ["reference", "interpret"])
+@pytest.mark.parametrize("padded", [True, False], ids=["padded", "no_mask"])
+def test_bert_flash_matches_xla_on_real_tokens(padded, impl, request,
+                                               monkeypatch):
+    """BERT's one attention path (the flash op; padding, when there is
+    any, as segment ids) against the same model over an in-test dense
+    einsum + softmax: logits on real-token rows (pad-query rows differ by
+    design and are -100 in the loss) and the loss's gradients, with the
+    reference the op runs off the TPU and with its kernels interpreted.
+    ``attn_impl`` selects nothing: both values give the same model."""
+    from deepspeed_tpu.models.bert import BertConfig, BertModel
 
-    model_f = BertModel(dataclasses.replace(cfg_x, attn_impl="flash"))
-    logits_f = model_f.forward(params, ids, attention_mask=mask_j)
+    if impl == "interpret":
+        request.getfixturevalue("through_interpreter")
+    batch, mask = _bert_batch(padded)
+    model = BertModel(BertConfig.tiny(dtype=jnp.float32))
+    params = model.init_params(jax.random.PRNGKey(0))
+    run = lambda m: (m.forward(params, batch["input_ids"],
+                               batch.get("attention_mask")),
+                     jax.grad(m.loss)(params, batch))
+    logits, grads = run(model)
+    logits_flash, _ = run(BertModel(BertConfig.tiny(dtype=jnp.float32,
+                                                    attn_impl="flash")))
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(logits_flash))
 
+    monkeypatch.setattr(fa, "flash_attention_spmd", _dense_attention)
+    logits_x, grads_x = run(model)
     np.testing.assert_allclose(
-        np.asarray(logits_f)[mask], np.asarray(logits_x)[mask],
+        np.asarray(logits)[mask], np.asarray(logits_x)[mask],
         rtol=2e-4, atol=2e-4)
+    for (path, g), g_x in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                              jax.tree.leaves(grads_x)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(g_x), rtol=2e-3, atol=2e-5,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_bert_all_ones_mask_gives_the_logits_of_no_mask():
+    """A batch without ``attention_mask`` hands the op no segment ids; the
+    same batch with a mask of ones takes the segment path and gives the
+    same logits."""
+    from deepspeed_tpu.models.bert import BertConfig, BertModel
+
+    batch, _ = _bert_batch(padded=False)
+    model = BertModel(BertConfig.tiny(dtype=jnp.float32))
+    params = model.init_params(jax.random.PRNGKey(0))
+    seen = []
+    real = fa.flash_attention_spmd
+
+    def spy(q, k, v, mesh, causal=True, segment_ids=None, **kw):
+        seen.append(segment_ids is not None)
+        return real(q, k, v, mesh, causal=causal, segment_ids=segment_ids,
+                    **kw)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fa, "flash_attention_spmd", spy)
+        plain = model.forward(params, batch["input_ids"])
+        ones = model.forward(params, batch["input_ids"],
+                             attention_mask=jnp.ones_like(batch["input_ids"]))
+    assert seen == [False, True]          # one trace of the layer each
+    np.testing.assert_allclose(np.asarray(ones), np.asarray(plain),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("k_blocks, limit", [(1, 0.055), (2, 0.13)])
+def test_deep_stack_gradients_by_k_blocks(k_blocks, limit, monkeypatch):
+    """A deep random-weight post-norm encoder in bf16 (24 layers; token
+    representations collapse, so a row's keys share a large common part)
+    against the same weights in float32: the relative error of the
+    ``wq`` / ``wk`` gradients through the interpreted kernels.  With one
+    k-block a tile the backward sums Δ = Σ_k p·dp itself
+    (``delta_in_tile``: 0.033–0.040 here over two seeds, 0.027–0.035 in
+    the BERT-large cell on the chip, whose limit is 0.07); with several it
+    takes Δ = do·o from the bf16-ROUNDED output, and the residue Σ_k ds ≠ 0
+    times the common key shows (0.075–0.099 here).  The second limit is
+    that KNOWN gap (PERF.md §7, PR 41 (6)), held so that it does not grow
+    unseen and so that a repair has a number to tighten."""
+    from deepspeed_tpu.models.bert import BertConfig, BertModel
+
+    S = 128
+    cfg = dict(vocab_size=512, hidden_size=256, intermediate_size=1024,
+               num_layers=24, num_heads=4, max_seq_len=S)
+    batch, _ = _bert_batch(padded=False, S=S)
+    model32 = BertModel(BertConfig(dtype=jnp.float32, **cfg))
+    params = model32.init_params(jax.random.PRNGKey(0))
+    want = jax.jit(jax.grad(model32.loss))(params, batch)["layers"]["attn"]
+
+    real = fa.flash_attention
+    monkeypatch.setattr(fa, "flash_attention", lambda *a, **kw: real(
+        *a, **{**kw, "interpret": True, "block_k": S // k_blocks}))
+    assert fa._resolve_blocks(0, S // k_blocks, S, 64,
+                              backward=True)[1] == S // k_blocks
+    model16 = BertModel(BertConfig(dtype=jnp.bfloat16, **cfg))
+    got = jax.jit(jax.grad(model16.loss))(params, batch)["layers"]["attn"]
+    for name in ("wq", "wk"):
+        g, w = (np.asarray(t[name], np.float32) for t in (got, want))
+        err = np.linalg.norm(g - w) / np.linalg.norm(w)
+        assert err <= limit, (name, err)
+
+
+@pytest.mark.parametrize("impl", ["reference", "interpret"])
+def test_bert_step_counts_the_route_its_attention_took(impl, request):
+    """Tracing a BERT training step counts the implementation the flash op
+    chose, once a call site: off the TPU the reference and no kernel; with
+    the interpreter asked for, that and no reference."""
+    from deepspeed_tpu.models.bert import BertConfig, BertModel
+    from deepspeed_tpu.telemetry import get_telemetry
+
+    if impl == "interpret":
+        request.getfixturevalue("through_interpreter")
+    hub = get_telemetry()
+    hub.reset()
+    hub.configure(enabled=True, jsonl=False, prometheus=False)
+    try:
+        batch, _ = _bert_batch(padded=True)
+        model = BertModel(BertConfig.tiny(dtype=jnp.float32))
+        params = model.init_params(jax.random.PRNGKey(0))
+        jax.jit(jax.value_and_grad(model.loss)).lower(params, batch)
+        counts = {name.rsplit("/", 1)[1]: c["value"] for name, c in
+                  hub.registry.snapshot()["counters"].items()
+                  if name.startswith("ops/flash_attention/")}
+    finally:
+        hub.reset()
+    # the layer scan traces its one call site once
+    assert counts == {f"{impl}_calls": 1.0}
 
 
 # ---------------------------------------------------------------------------

@@ -666,12 +666,14 @@ def _plane_relayouts(text: str, B: int, S: int, h: int, d: int):
 
 
 @pytest.mark.parametrize(
-    "B, S, h, d, causal, window, packed, parent_relayouts", [
-        (1, 8192, 32, 128, True, 4096, False, 15),   # the Mistral cells
-        (32, 512, 16, 64, False, None, True, 9),     # BERT-large, padding
+    "B, S, h, d, causal, window, packed, parent_relayouts, stat_rows", [
+        (1, 8192, 32, 128, True, 4096, False, 15, 2),   # the Mistral cells
+        # BERT-large, padding: one k-block spans the sequence, so Δ is
+        # summed in the tile and only lse comes in (PR 41)
+        (32, 512, 16, 64, False, None, True, 9, 1),
     ], ids=["mistral7b_row", "bert_large_segments"])
 def test_flash_vjp_compiles_for_v5e(one_chip, B, S, h, d, causal, window,
-                                    packed, parent_relayouts):
+                                    packed, parent_relayouts, stat_rows):
     """``jax.vjp`` of ``flash_attention`` at a training cell's shape:
     Mosaic takes the backward's plan under ``RESIDENT_VMEM_LIMIT_BYTES``,
     every backward call is named ``flash_bwd*`` (what
@@ -701,7 +703,70 @@ def test_flash_vjp_compiles_for_v5e(one_chip, B, S, h, d, causal, window,
     # lse and Δ: [B·h, 1, S] rows, not [B·h, S, 1] columns on 128 lanes
     line = next(ln for ln in text.splitlines()
                 if f"%{backward[0]} = " in ln)
-    assert line.count(f"f32[{B * h},1,{S}]") >= 2, line[:400]
+    assert line.count(f"f32[{B * h},1,{S}]") == stat_rows, line[:400]
     assert f"f32[{B * h},{S},1]" not in line
     moved = _plane_relayouts(text, B, S, h, d)
     assert len(moved) <= parent_relayouts, moved
+
+
+def test_mistral_row_resolves_to_the_tiles_it_was_timed_at():
+    """The Mistral training cells' call (``S=8192, d=128``, bf16) keeps the
+    tiles its kernels were timed at (PR 32): whatever row another shape
+    adds to the tables, this one resolves as before and so lowers to the
+    same kernels."""
+    import importlib
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    assert fa._resolve_blocks(0, 0, 8192, 128) == (256, 512)
+    assert fa._resolve_blocks(0, 0, 8192, 128, backward=True,
+                              itemsize=2) == (512, 512)
+    assert fa.flash_route(8192, 128, interpret=False) == ("kernel", None)
+
+
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["no_mask", "attention_mask"])
+def test_bert_step_holds_the_flash_kernels_on_v5e(one_chip, monkeypatch,
+                                                  masked):
+    """``value_and_grad`` of a two-layer BERT-large's loss at the training
+    cell's shape (32 x 512, bf16, remat, ``attn_impl="xla"`` passed as the
+    cell's file passes it): the program holds the flash forward and the
+    one-call backward, no array of the score matrix's shape, no per-head
+    plane, and a segment operand only when the batch carries an
+    ``attention_mask``."""
+    import importlib
+
+    from deepspeed_tpu.models.bert import BertConfig, BertModel
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    # the op asks the platform, which is the CPU here: steer it onto the
+    # path it takes on the chip
+    monkeypatch.setattr(fa, "reference_off_tpu", lambda interpret: False)
+    B, S = 32, 512
+    model = BertModel(BertConfig(num_layers=2, dtype=jnp.bfloat16,
+                                 remat=True, attn_impl="xla"))
+    placed = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    ids = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    batch = {"input_ids": ids, "labels": ids}
+    if masked:
+        batch["attention_mask"] = ids
+    text = jax.jit(jax.value_and_grad(model.loss)).lower(
+        placed(jax.eval_shape(model.init_params, jax.random.PRNGKey(0))),
+        placed(batch)).compile().as_text()
+    calls = {name: line for name, line in re.findall(
+        r'^\s*%?([\w.\-]+) = ([^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*)', text, re.M)}
+    heads = model.config.num_heads
+    # forward, remat's recomputation of it, backward
+    assert sorted(re.sub(r"[.\d]+$", "", c) for c in calls) == [
+        "flash_bwd", "flash_fwd", "flash_fwd"], sorted(calls)
+    assert f"[{B},{heads},{S},{S}]" not in text
+    segments = f"s32[{B},1,{S}]"
+    for name, line in calls.items():
+        assert (segments in line) == masked, (name, line[:400])
+        # two 64-wide heads a program, read from the operands as they lie
+        assert f"bf16[{B},{S},{heads * 64}]" in line, (name, line[:400])
+    # no transpose to or from per-head planes around the calls (the
+    # one-head-a-program lowering made nine at this shape)
+    assert _plane_relayouts(text, B, S, heads, 64) == []

@@ -10,11 +10,22 @@ for kernel scratch and the config-gating fallbacks.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.models import LlamaConfig, LlamaModel
 from deepspeed_tpu.parallel import MeshLayout
 from deepspeed_tpu.utils import groups
+
+
+@pytest.fixture(autouse=True)
+def _hub_left_as_found():
+    """Engines built here with ``telemetry.enabled`` switch the process's
+    hub on; left on, it counts into whatever file this worker runs next."""
+    yield
+    from deepspeed_tpu.telemetry import get_telemetry
+
+    get_telemetry().reset()
 
 
 def make_engine(extra=None, zero=2, clip=1.0, opt="Adam", dp=8,
@@ -134,8 +145,16 @@ def test_overlap_ring_rides_the_comm_verbs():
     assert "ppermute" in ops
 
 
-def test_kernel_scratch_registers_in_memory_ledger():
+def test_kernel_scratch_registers_in_memory_ledger(monkeypatch):
+    import importlib
+
     from deepspeed_tpu.telemetry.memory import get_memory_ledger
+
+    # the flash entry follows the route the op would take for the model's
+    # shape (``LlamaModel.uses_flash_kernels``): say "kernel", as on a TPU
+    monkeypatch.setattr(
+        importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention"),
+        "flash_route", lambda *a, **k: ("kernel", None))
 
     eng = make_engine({"kernels": {"overlap_collectives": True,
                                    "overlap_chunks": 2},
@@ -146,9 +165,9 @@ def test_kernel_scratch_registers_in_memory_ledger():
     keys = [e["key"] for e in led.entries()
             if e["pool"] == "collective_scratch"]
     assert "engine/overlap_ring_staging" in keys
-    # flash scratch keys on the MODEL route (attn_impl), not the config
-    # knob — the knob without routing would attribute bytes that don't
-    # exist
+    # flash scratch keys on the MODEL route (attn_impl and the op's own
+    # choice), not the config knob — the knob without routing would
+    # attribute bytes that don't exist
     assert "engine/flash_softmax_stats" in keys
     get_memory_ledger().reset()  # process-global: scrub the prior
     # engine's entries so the xla build is judged on its own
@@ -160,6 +179,63 @@ def test_kernel_scratch_registers_in_memory_ledger():
                                    or get_memory_ledger()).entries()
                 if e["pool"] == "collective_scratch"]
     assert "engine/flash_softmax_stats" not in xla_keys
+
+
+@pytest.mark.parametrize("family, route, listed", [
+    ("bert", "reference", False), ("bert", "kernel", True),
+    ("llama_flash", "reference", False), ("llama_flash", "kernel", True),
+    ("llama_xla", "kernel", False)])
+def test_flash_scratch_is_keyed_on_the_route_the_module_reports(
+        family, route, listed, monkeypatch):
+    """ONE rule for the ledger's ``engine/flash_softmax_stats`` entry: the
+    module says whether its step holds the flash kernels
+    (``uses_flash_kernels``), from whether its attention calls the flash
+    op (BERT: always, whatever ``attn_impl`` says; Llama: the option) and
+    from the op's own choice for the model's shape (``flash_route``):
+    absent off the TPU, where the reference runs; present where the
+    kernels would.  The engine imports no model class for it."""
+    import importlib
+
+    from deepspeed_tpu.models.bert import BertConfig, BertModel
+    from deepspeed_tpu.telemetry.memory import get_memory_ledger
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    assert fa.flash_route(128, 16)[0] == "reference"   # the CPU here
+    monkeypatch.setattr(fa, "flash_route", lambda *a, **k: (route, None))
+    get_memory_ledger().reset()
+    groups.reset_mesh()
+    mesh = groups.initialize_mesh(MeshLayout.infer(8, dp=8))
+    if family == "bert":
+        model = BertModel(BertConfig.tiny(attn_impl="xla"), mesh=mesh)
+    else:
+        model = LlamaModel(LlamaConfig.tiny(
+            attn_impl=family.split("_")[1]), mesh=mesh)
+    assert model.uses_flash_kernels() == listed
+    eng, *_ = deepspeed_tpu.initialize(
+        model=model, model_parameters=model.init_params(jax.random.PRNGKey(0)),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+                "zero_optimization": {"stage": 2},
+                "telemetry": {"enabled": True, "jsonl": False,
+                              "prometheus": False}}, mesh=mesh)
+    keys = [e["key"] for e in (eng.memory_ledger
+                               or get_memory_ledger()).entries()]
+    assert ("engine/flash_softmax_stats" in keys) == listed
+    get_memory_ledger().reset()
+
+
+def test_engine_imports_no_model_class():
+    """The shared engine asks a module what it does; it tests no model's
+    identity (a lower layer does not know a higher one)."""
+    import ast
+    import inspect
+
+    from deepspeed_tpu.runtime import engine
+
+    tree = ast.parse(inspect.getsource(engine))
+    imported = [(node.module or "") for node in ast.walk(tree)
+                if isinstance(node, ast.ImportFrom)]
+    assert not [m for m in imported if "models" in m.split(".")], imported
 
 
 def test_fused_adam_engine_checkpoint_state_interchanges():
