@@ -99,7 +99,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...ops.pallas.paged_attention import (paged_decode_attention,
+from ...ops.pallas.paged_attention import (pages_per_step,
+                                           paged_decode_attention,
                                            paged_decode_impl)
 from ...telemetry.perf import get_compile_tracker, tracked_jit
 from ...utils.logging import log_dist
@@ -185,6 +186,9 @@ class RaggedInferenceEngineV2:
         #: independent, so no cross-rank communication.
         self.mesh = mesh
         self.last_attn_path = None  # set at trace time by attend_fn
+        #: beside it: the pages a compute step of each kind's paged kernel
+        #: was built with (``pages_per_step``), by the kind's name
+        self.last_attn_pages_per_step: Dict[str, int] = {}
         self._tp = int(mesh.shape.get("tensor", 1)) if mesh is not None else 1
         self.kinds: Dict[str, AttentionKind] = {
             k.name: k for k in self.adapter.kinds}
@@ -630,6 +634,13 @@ class RaggedInferenceEngineV2:
                 help="layers traced on a TPU whose paged decode "
                      "attention runs the jax.numpy reference: "
                      "the kernel refused their shapes")
+        if impl != "reference":
+            self.last_attn_pages_per_step[kind.name] = pages_per_step(
+                flat["k"].shape[1], kind.kv_heads // self._tp,
+                self.adapter.num_heads // self._tp,
+                k_planes * flat["k"].shape[-1], flat["k"].dtype.itemsize,
+                tables.shape[1], 0 if v_in_k else flat["v"].shape[-1],
+                kind.window)
         if self._tp > 1:
             # the Pallas kernel runs PER TP SHARD via an explicit
             # shard_map over the kv-head axis (heads independent,
@@ -922,6 +933,12 @@ class RaggedInferenceEngineV2:
                 help="pages of the kind's pool that live sequences hold "
                      "(a recycled kind: at most a ring a sequence), each "
                      "over all the kind's layers")
+        for name, pages in self.last_attn_pages_per_step.items():
+            tel.set_gauge(
+                f"inference/attn/pages_per_step/{name}", float(pages),
+                help="pages a compute step of the kind's paged decode "
+                     "kernel fetches and scores, as the traced programs "
+                     "were built")
         stats = self.last_moe_stats
         if not stats:
             return

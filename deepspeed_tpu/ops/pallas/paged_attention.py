@@ -13,18 +13,25 @@ inside a step a ``fori_loop`` with a dynamic trip count walks that row's
 LIVE pages only — ``[k0, nk)`` with ``nk = ceil(length / block_size)`` and
 ``k0 = max(length − window, 0) // block_size`` — ``P`` pages a compute
 step.  Page ids come from the scalar-prefetched block table; each live
-page of a step is one ``make_async_copy`` of K and one of V into slot
-``s`` of a ``[2, P, block_size·kv_h, d]`` VMEM buffer, and the next
-step's pages (the next row's first step at a row's end) are in flight in
-slot ``1 − s`` while the current step is scored.  So grid steps plus loop
-iterations are ``Σ_rows max(ceil(live_pages / P), 1)``, whatever the
-table's width; a row's last step fetches only its live pages, and what is
-left in the buffer from earlier steps is masked by position.
+page of a step is one ``make_async_copy`` of K (all its planes) and one
+of V into slot ``s`` of a ``[2, P, block_size·kv_h, d]`` VMEM buffer, and
+the next step's pages (the next row's first step at a row's end) are in
+flight in slot ``1 − s`` while the current step is scored.  So grid steps
+plus loop iterations are ``Σ_rows max(ceil(live_pages / P), 1)``, whatever
+the table's width; a row's last step fetches only its live pages, and what
+is left in the buffer from earlier steps is masked by position.
 
 ``P`` follows from the shapes (:func:`pages_per_step`): as many pages as
-hold ``_STEP_TOKENS`` keys, no more than the table has, and no more than
-fit ``_VMEM_BUDGET_BYTES`` (both buffers of K and V, the head mask and
-the float32 score temporaries).
+make ``_STEP_COLUMNS`` score columns (key rows: ``P·block_size·kv_h``), so
+that a step's fetch and its softmax are of one size whatever the KV heads
+(256 keys of 8 KV heads, 512 of 4), no fewer than ``_STEP_TOKENS`` keys, no
+more than a row can have live (the table's width; under a window,
+``ceil(window / block_size) + 1``: a window layer's every row is then ONE
+step as wide as the window), and no more than fit ``_VMEM_BUDGET_BYTES``
+(both buffers of K and V, the head mask and the float32 score
+temporaries).  A step is scored over all ``P`` pages whatever is live, so
+a larger ``P`` pays in a row's last step what it saves in steps: 512 keys
+measured best for 4 KV heads at ~750 keys a row (PERF.md §6, PR 44).
 
 The arithmetic.  A page is read as the matrix ``[block_size·kv_h, d]`` it
 already is in memory (row ``t·kv_h + g`` is key ``t`` of kv head ``g``),
@@ -46,25 +53,28 @@ narrower than its key head): the V pool's last dim is the accumulator's
 and the output's.  A K row wider than 128 lanes (192) lies in ``k_planes``
 PLANES of 128 lanes, the last padded with zeros
 (``kv_cache.lane_planes``): plane ``p`` of page ``n`` is page ``n +
-p·plane_stride`` of the K pool, fetched by a copy of its own into its
-own stretch of the K buffer, and QKᵀ is the sum over planes of a dot of
-the query's 128 lanes of that plane (the query is padded with zeros to
-match; the scale stays that of the true width).  Why planes and not one
-256-lane row: Mosaic pads an HBM operand's lanes to 128 and refuses the
-page-sized slice a DMA needs of anything else, and the ``[pages,
-block·kv_h, 256]`` view of a ``[…, kv_h = 4, 256]`` pool is, in the chip's
-tiled layout, a COPY of the pool (2.7 GB a call at the serving cell's
-size), where every 128-lane view is a bitcast.  The padding is read from
-HBM like the keys (a quarter of a 192-wide K: what the kernel's roofline
-share loses).
+p·plane_stride`` of the K pool, the planes fill the pool (``k_planes ·
+plane_stride`` pages), and the kernel takes it as ``[planes, plane_stride,
+rows, 128]``: ONE strided copy fetches all of a page's planes into
+``[planes, P, rows, 128]`` of the K buffer (a copy a plane spent more of a
+step starting and awaiting copies: PERF.md §6, PRs 40 and 44), and QKᵀ is
+the sum over planes of a dot of the query's 128 lanes of that plane (the
+query is padded with zeros to match; the scale stays that of the true
+width).  A pool of one plane is taken as ``[pages, rows, 128]``.  Why
+planes and not one 256-lane row: Mosaic pads an HBM operand's lanes to 128
+and refuses the page-sized slice a DMA needs of anything else, and the
+``[pages, block·kv_h, 256]`` view of a ``[…, kv_h = 4, 256]`` pool is, in
+the chip's tiled layout, a COPY of the pool (2.7 GB a call at the serving
+cell's size), where every 128-lane view is a bitcast.  The padding is read
+from HBM like the keys (a quarter of a 192-wide K: what the kernel's
+roofline share loses).
 
 ``v_in_k`` (a latent cache: ONE row a token that every query head reads,
 whose leading ``v_in_k`` numbers are also its value): there is no V pool,
 no V copy and no V buffer; PV is a dot of the probabilities against each
 of the K buffer's leading planes, the results side by side in the
-accumulator (``v_in_k`` whole planes where the kernel is compiled).  The
-pool comes as ``[planes, pages, rows, 128]`` and ONE strided copy fetches
-all of a page's planes; a step scores ``_LATENT_STEP_TOKENS`` keys.  With
+accumulator (``v_in_k`` whole planes where the kernel is compiled).  A
+step scores ``_LATENT_STEP_TOKENS`` keys.  With
 one KV head under 128 query heads each fetched byte meets ``h`` rows of
 the MXU twice: at 576-wide rows the kernel sits on the chip's ridge and
 not under the cache's stream.  ``scale``: the scores' scale where it is
@@ -137,7 +147,15 @@ def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
     return jnp.einsum(f"bhk,{row}->bhd", p, v)
 
 
-#: keys scored per compute step (``P·block_size``)
+#: score columns (key rows: ``P·block_size·kv_h``) per compute step: what
+#: the fetch, the masks and the softmax of a step are sized by.  8 KV
+#: heads' 256 keys, which the dense serving cell runs at 82% of its
+#: roofline; with 4 KV heads and K in two planes a page is 16 KB a copy,
+#: and on the v5e at that cell's shapes (PERF.md §6, PR 44) 256 / 512 /
+#: 768 / 1,024 keys a step made 48.9 / 52.4 / 51.6 / 49.5% of the roofline
+_STEP_COLUMNS = 2048
+#: and no fewer keys than this (``P·block_size``): 16 KV heads keep 256
+#: keys (4,096 columns, 89% of the roofline in its cell)
 _STEP_TOKENS = 256
 #: the same over a latent cache (``v_in_k``): one KV head's 256 keys are a
 #: step of 256 score columns where 8 KV heads' are one of 2,048, and the
@@ -156,23 +174,28 @@ _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
 def pages_per_step(block_size: int, kv_h: int, h: int, d: int, itemsize: int,
-                   max_blocks: int, v_dim: int | None = None) -> int:
+                   max_blocks: int, v_dim: int | None = None,
+                   window: int | None = None) -> int:
     """``P``: pages fetched and scored per compute step, from the shapes
     alone (``d``: a K row as the pool holds it, all its planes; ``v_dim``:
     a V row, where it is another width; 0: V lies in the K row and has no
-    buffer, a latent cache)."""
+    buffer, a latent cache; ``window``: the layer's reach in keys)."""
     rows = block_size * kv_h
     per_page = (2 * rows * (d + (d if v_dim is None else v_dim)) * itemsize
                 + 5 * h * rows * 4)
-    step = _LATENT_STEP_TOKENS if v_dim == 0 else _STEP_TOKENS
-    return max(1, min(step // block_size, max_blocks,
+    keys = _LATENT_STEP_TOKENS if v_dim == 0 \
+        else max(_STEP_TOKENS, _STEP_COLUMNS // kv_h)
+    # a row under a window has live pages [k0, nk): the window's and the
+    # one it begins in the middle of
+    live = max_blocks if window is None \
+        else min(max_blocks, -(-window // block_size) + 1)
+    return max(1, min(keys // block_size, live,
                       _VMEM_BUDGET_BYTES // per_page))
 
 
 def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
                   block_size: int, kv_h: int, scale: float, window=None,
-                  sink: bool = False, k_planes: int = 1,
-                  plane_stride: int = 0, v_in_k: int = 0):
+                  sink: bool = False, k_planes: int = 1, v_in_k: int = 0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -185,9 +208,9 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
         k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest[int(sink):]
     b = pl.program_id(0)
     num_rows = pl.num_programs(0)
-    # a latent cache's buffer is [2, planes, P, rows, w]: a page's planes
-    # arrive in one copy
-    P = k_buf.shape[2] if v_in_k else k_buf.shape[1] // k_planes
+    # K in planes: the buffer is [2, planes, P, rows, w] and a page's
+    # planes arrive in one copy; one plane: [2, P, rows, w]
+    P = k_buf.shape[-3]
     h = q_ref.shape[1]
     C = P * block_size * kv_h
 
@@ -201,22 +224,20 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
         return k0, nk - k0
 
     def page_copies(page, slot, j):
-        if v_in_k:
+        if k_planes > 1:
             # the K pool comes as [planes, pages, rows, w]: ONE strided
             # copy fetches every plane of the page (a copy a plane spent
-            # more of a step starting and awaiting copies: 45.6 → 52.0% of
-            # the roofline, PERF.md §6, PR 40)
-            return (pltpu.make_async_copy(k_hbm.at[:, page],
-                                          k_buf.at[slot, :, j],
-                                          sems.at[0, slot]),)
-        copies = tuple(
-            pltpu.make_async_copy(k_hbm.at[page + p * plane_stride],
-                                  k_buf.at[slot, p * P + j],
-                                  sems.at[0, slot])
-            for p in range(k_planes))
-        return copies + (
-            pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
-                                  sems.at[1, slot]),)
+            # more of a step starting and awaiting copies: PERF.md §6,
+            # PRs 40 and 44)
+            k_copy = pltpu.make_async_copy(
+                k_hbm.at[:, page], k_buf.at[slot, :, j], sems.at[0, slot])
+        else:
+            k_copy = pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[slot, j], sems.at[0, slot])
+        if v_in_k:
+            return (k_copy,)
+        return (k_copy, pltpu.make_async_copy(
+            v_hbm.at[page], v_buf.at[slot, j], sems.at[1, slot]))
 
     def step_pages(n_live, i):
         return jnp.clip(n_live - i * P, 0, P)
@@ -275,10 +296,7 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
 
         def k_plane(p):
             """Plane ``p`` of the step's pages as ``[C, w]``."""
-            if v_in_k:
-                return k_buf[slot, p].reshape(C, w)
-            kp = k_buf[slot] if k_planes == 1 \
-                else k_buf[slot, p * P:(p + 1) * P]
+            kp = k_buf[slot] if k_planes == 1 else k_buf[slot, p]
             return kp.reshape(C, w)
 
         def plane_scores(p):
@@ -419,16 +437,19 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
     B, h, d = q.shape
     M, block_size, kv_h, w = k_pool.shape
     dv = v_in_k or v_pool.shape[-1]
-    if v_in_k and k_planes * (plane_stride or M) != M:
-        raise ValueError(f"a latent pool of {M} pages is not {k_planes} "
+    if k_planes > 1 and k_planes * plane_stride != M:
+        raise ValueError(f"a K pool of {M} pages is not {k_planes} "
                          f"planes of {plane_stride}")
     max_blocks = block_tables.shape[1]
     dk = k_planes * w
     if dk > d:      # the last plane's lane padding: zeros times zeros
         q = jnp.pad(q, ((0, 0), (0, 0), (0, dk - d)))
     P = pages_per_step(block_size, kv_h, h, dk, k_pool.dtype.itemsize,
-                       max_blocks, 0 if v_in_k else dv)
+                       max_blocks, 0 if v_in_k else dv, window)
     rows = block_size * kv_h
+    # K in planes: the pool as [planes, pages, rows, w], so that one
+    # strided copy fetches a page's planes into [planes, P, rows, w]
+    planes = (k_planes,) if k_planes > 1 else ()
     # query head r reads the columns of kv head r // n_rep
     head_mask = np.where(
         np.arange(P * rows)[None, :] % kv_h
@@ -437,8 +458,7 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
     kernel = functools.partial(_paged_kernel, block_size=block_size,
                                kv_h=kv_h, scale=scale or 1.0 / np.sqrt(d),
                                window=window, sink=sink is not None,
-                               k_planes=k_planes, plane_stride=plane_stride,
-                               v_in_k=v_in_k)
+                               k_planes=k_planes, v_in_k=v_in_k)
     row = lambda b, lens, table: (b, 0, 0)
     whole = lambda b, lens, table: (0, 0)
     sink_spec, sink_arg = [], []
@@ -465,8 +485,7 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
             ],
             out_specs=pl.BlockSpec((1, h, dv), row),
             scratch_shapes=[
-                pltpu.VMEM((2, k_planes, P, rows, w) if v_in_k
-                           else (2, k_planes * P, rows, w), k_pool.dtype),
+                pltpu.VMEM((2, *planes, P, rows, w), k_pool.dtype),
                 *v_scratch,
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((1,), jnp.int32),
@@ -477,8 +496,7 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
         name="paged_decode_attention",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, jnp.asarray(head_mask), *sink_arg,
-      k_pool.reshape(k_planes, plane_stride or M, rows, w) if v_in_k
-      else k_pool.reshape(M, rows, w), *v_arg)
+      k_pool.reshape(*planes, M // k_planes, rows, w), *v_arg)
 
 
 def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, lengths,

@@ -341,8 +341,10 @@ def check_paged_hybrid(s: KernelShapes, interpret: bool) -> List[Check]:
     tables = jnp.asarray(rng.permutation(np.arange(1, pages)).reshape(
         rows, max_blocks).astype(np.int32))
     top = max_blocks * s.page
+    # under the window, on it, a window that straddles nine pages, the
+    # whole table, one key past a 512-key step (the full kind's)
     lengths = jnp.asarray(np.resize(np.clip(
-        [window - 1, window, window + 1, top, 3, 2 * window + 5, top // 2,
+        [window - 1, window, window + 4 * s.page + 8, top, 3, 513, top // 2,
          s.page], 1, top), rows).astype(np.int32))
     out = []
     for kv_heads, reach, logits in ((4, None, None), (8, window, sink)):

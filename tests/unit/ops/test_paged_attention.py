@@ -16,10 +16,18 @@ P = 2
 STEP = P * PAGE
 
 
+def _keys_a_step(monkeypatch, keys):
+    """Shrink the rule's two numbers to ``keys`` a step whatever the KV
+    heads (these tests' shapes are tiny beside 2,048 score columns)."""
+    monkeypatch.setattr(pa, "_STEP_TOKENS", keys)
+    monkeypatch.setattr(pa, "_STEP_COLUMNS", keys)
+
+
 @pytest.fixture
 def two_pages_a_step(monkeypatch):
-    monkeypatch.setattr(pa, "_STEP_TOKENS", STEP)
+    _keys_a_step(monkeypatch, STEP)
     assert pa.pages_per_step(PAGE, 1, H, D, 4, MAX_BLOCKS) == P
+    assert pa.pages_per_step(PAGE, 4, H, D, 4, MAX_BLOCKS) == P
 
 
 def _case(n_rep, dtype, lengths, seed=0, dead_slot=True):
@@ -82,7 +90,7 @@ def test_ragged_rows_at_the_derived_step(monkeypatch, pages_a_step):
     row's last step fetches the next row's first), with two pages a step
     and with the step the shapes give (the whole table here)."""
     if pages_a_step:
-        monkeypatch.setattr(pa, "_STEP_TOKENS", pages_a_step * PAGE)
+        _keys_a_step(monkeypatch, pages_a_step * PAGE)
     lengths = list(range(0, MAX_BLOCKS * PAGE + 1, 5))
     q, k_pool, v_pool, tables, lengths = _case(2, jnp.float32, lengths,
                                                seed=1)
@@ -158,19 +166,42 @@ def test_work_does_not_scale_with_the_table():
 
 
 @pytest.mark.parametrize("shape, want", [
-    # Mistral-7B serving: 256 keys a step
+    # Mistral-7B serving: 8 KV heads, 2,048 score columns = 256 keys a step
     ((16, 8, 32, 128, 2, 512), 16),
+    # and under its window of 4,096 (257 live pages at most: no cap)
+    ((16, 8, 32, 128, 2, 512, None, 4096), 16),
     # a table narrower than a step
     ((16, 8, 32, 128, 2, 4), 4),
-    # a TP shard's two kv heads; pages of 64 keys
-    ((16, 2, 8, 128, 2, 512), 16),
+    # OLMoE-1B-7B: 16 KV heads would be 128 keys; the floor of 256 holds
+    ((16, 16, 16, 128, 2, 256), 16),
+    # the hybrid model's FULL layers: 4 KV heads under 64, K held in 256
+    # lanes, V 128: 2,048 columns are 512 keys
+    ((16, 4, 64, 256, 2, 512, 128), 32),
+    # its WINDOW layers: 8 KV heads, window 128: at most 128/16 + 1 live
+    # pages a row, so a step is never built for more
+    ((16, 8, 64, 256, 2, 512, 128, 128), 9),
+    # a window that is no whole number of pages, and one beyond the table
+    ((16, 8, 64, 256, 2, 512, 128, 100), 8),
+    ((16, 8, 64, 256, 2, 6, 128, 128), 6),
+    # a TP shard's two kv heads: 16 while a step was 256 keys whatever the
+    # heads; 2,048 columns of 2 heads are 1,024 keys (no cell runs it)
+    ((16, 2, 8, 128, 2, 512), 64),
+    # pages of 64 keys
     ((64, 8, 32, 128, 2, 128), 4),
     # 32 kv heads of float32: the VMEM budget, not the step, bounds it
     ((16, 32, 32, 128, 4, 512), 6),
     ((512, 8, 32, 128, 2, 16), 1),
-])
+    # the latent cache (V in K's rows, 5 planes, pages of 128): its own
+    # 1,024 keys a step
+    ((128, 1, 128, 640, 2, 128, 0), 8),
+], ids=["mistral", "mistral_window_4096", "narrow_table", "olmoe",
+        "hybrid_full", "hybrid_window_128", "window_100", "window_past_table",
+        "tp_shard", "pages_of_64", "vmem_bound", "one_page", "latent"])
 def test_pages_per_step_follows_the_shapes(shape, want):
     assert pa.pages_per_step(*shape) == want
-    page, kv_h, h, d, itemsize, _ = shape
-    held = want * (4 * page * kv_h * d * itemsize + 5 * h * page * kv_h * 4)
+    page, kv_h, h, d, itemsize, _, v_dim, _ = \
+        shape + (None,) * (8 - len(shape))
+    v_dim = d if v_dim is None else v_dim
+    held = want * (2 * page * kv_h * (d + v_dim) * itemsize
+                   + 5 * h * page * kv_h * 4)
     assert want == 1 or held <= pa._VMEM_BUDGET_BYTES

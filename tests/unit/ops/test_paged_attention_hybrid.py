@@ -11,31 +11,42 @@ from deepspeed_tpu.inference.v2 import kv_cache
 from deepspeed_tpu.ops.pallas import paged_attention as pa
 from deepspeed_tpu.ops.pallas.selfcheck import DECODE_TOL, _rel_err
 
-H, K_DIM, V_DIM, PAGE, MAX_BLOCKS = 64, 192, 128, 16, 12
+#: a table of 640 keys: wider than the 32 pages a step of the full kind
+#: (4 KV heads) holds, so a row can take a second step
+H, K_DIM, V_DIM, PAGE, MAX_BLOCKS = 64, 192, 128, 16, 40
 
 
-def _case(kv_h, lengths, dtype=jnp.float32, seed=0):
+def _case(kv_h, lengths, dtype=jnp.float32, seed=0, max_blocks=MAX_BLOCKS):
     """K rows of 192 held in two planes of 128 lanes
-    (``kv_cache.lane_planes``), plane 1 ``PAGES`` pages after plane 0, V
-    rows of 128, a shuffled table; the padding lanes hold zeros as the
-    engine writes them."""
+    (``kv_cache.lane_planes``), plane 1 a whole plane of pages after
+    plane 0, V rows of 128, a shuffled table; the padding lanes hold zeros
+    as the engine writes them."""
     rng = np.random.RandomState(seed)
     B = len(lengths)
-    pages = PAGES
+    pages = B * max_blocks + 1
     assert kv_cache.lane_planes(K_DIM) == (2, 128)
     k = np.zeros((pages, PAGE, kv_h, 256), np.float32)
     k[..., :K_DIM] = rng.standard_normal((pages, PAGE, kv_h, K_DIM))
     k = np.concatenate([k[..., :128], k[..., 128:]], axis=0)
     v = rng.standard_normal((pages, PAGE, kv_h, V_DIM))
-    tables = rng.permutation(np.arange(1, pages)).reshape(B, MAX_BLOCKS)
+    tables = rng.permutation(np.arange(1, pages)).reshape(B, max_blocks)
     return (jnp.asarray(rng.standard_normal((B, H, K_DIM)), dtype),
             jnp.asarray(k, dtype), jnp.asarray(v, dtype),
             jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
             jnp.asarray(rng.standard_normal(H), jnp.float32))
 
 
-LENGTHS = (0, 1, 16, 127, 128, 129, 192)
+#: nothing, one key, a page, under the window, around it, a window that
+#: straddles 9 pages (200 − 128 = 72: pages 4..12), a 512-key step exactly
+#: and one key past it, and a row of three keys beside one of the whole
+#: table
+LENGTHS = (0, 1, 16, 100, 127, 128, 129, 200, 512, 513, 3, 640)
 PAGES = len(LENGTHS) * MAX_BLOCKS + 1
+
+
+def _pages_a_step(kv_h, window, dtype=jnp.float32, max_blocks=MAX_BLOCKS):
+    return pa.pages_per_step(PAGE, kv_h, H, 256, jnp.dtype(dtype).itemsize,
+                             max_blocks, V_DIM, window)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -47,6 +58,11 @@ def test_kernel_matches_reference(kv_h, window, with_sink, dtype):
     q, k, v, tables, lengths, sink = _case(kv_h, LENGTHS, dtype)
     sink = sink if with_sink else None
     assert k.shape == (2 * PAGES, PAGE, kv_h, 128)
+    # what the walk is built with here: a step of 2,048 score columns
+    # (float32: 30 and 15 pages, the VMEM budget), the window's 9 live
+    # pages at most
+    full = 128 // kv_h if dtype == jnp.bfloat16 else 120 // kv_h
+    assert _pages_a_step(kv_h, window, dtype) == (9 if window else full)
     assert pa.paged_decode_impl(H, kv_h, True, k.shape[-1], V_DIM) \
         == "pallas_interpret"
     got = pa.paged_decode_attention(q, k, v, tables, lengths, interpret=True,
@@ -63,14 +79,40 @@ def test_kernel_matches_reference(kv_h, window, with_sink, dtype):
     assert not np.asarray(got[0], np.float32).any()        # length 0
 
 
+@pytest.mark.parametrize("kv_h, window", [(4, None), (8, 128)],
+                         ids=["full_groups_of_16", "window_groups_of_8"])
+def test_a_table_narrower_than_a_step(kv_h, window):
+    """Six pages a row where the shapes would give a step 32 and 9: the
+    step is the table, and a row's one step fetches its live pages only."""
+    lengths = (0, 1, 50, 95, 96)
+    q, k, v, tables, lengths, sink = _case(kv_h, lengths, max_blocks=6)
+    pages = k.shape[0] // 2
+    assert _pages_a_step(kv_h, window, max_blocks=6) == 6
+    got = pa.paged_decode_attention(q, k, v, tables, lengths, interpret=True,
+                                    window=window, sink=sink, k_planes=2,
+                                    plane_stride=pages)
+    want = pa.paged_decode_reference(q, k, v, tables, lengths, window, sink,
+                                     2, pages)
+    assert float(_rel_err(got[1:], want[1:])) < DECODE_TOL
+    assert not np.asarray(got[0]).any()
+
+
+def test_planes_that_do_not_fill_the_pool_are_refused():
+    q, k, v, tables, lengths, _ = _case(4, (5,), max_blocks=2)
+    with pytest.raises(ValueError, match="planes of 2"):
+        pa.paged_decode_attention(q, k, v, tables, lengths, interpret=True,
+                                  k_planes=2, plane_stride=2)
+
+
 def test_reference_is_the_sum_written_out():
     """One row by hand: scale 1/sqrt(192) of the TRUE width, keys
     ``i − j < window``, the sink in the denominator only."""
     kv_h, window = 4, 128
     q, k, v, tables, lengths, sink = _case(kv_h, (150,) + (0,) * 6)
+    pages = k.shape[0] // 2
     got = np.asarray(pa.paged_decode_reference(q, k, v, tables, lengths,
-                                               window, sink, 2, PAGES))[0]
-    keys = np.concatenate([np.asarray(k)[np.asarray(tables[0]) + p * PAGES]
+                                               window, sink, 2, pages))[0]
+    keys = np.concatenate([np.asarray(k)[np.asarray(tables[0]) + p * pages]
                            for p in (0, 1)], axis=-1).reshape(-1, kv_h, 256)
     vals = np.asarray(v)[np.asarray(tables[0])].reshape(-1, kv_h, V_DIM)
     for head in (0, 17, 63):
@@ -83,7 +125,7 @@ def test_reference_is_the_sum_written_out():
                                    atol=2e-5)
     # and the sink does take mass
     plain = np.asarray(pa.paged_decode_reference(q, k, v, tables, lengths,
-                                                 window, None, 2, PAGES))[0]
+                                                 window, None, 2, pages))[0]
     assert np.abs(plain - got).max() > 1e-3
 
 

@@ -83,6 +83,68 @@ _SERVING_CELLS = {
 _POOL_LAYERS, _POOL_PAGES, _PAGE, _SLOTS = 2, 3200, 16, 32
 
 
+def _kernel_body(fn, *args):
+    """Of the one Pallas call ``fn`` traces: the shapes of the refs its
+    body takes (operands as blocked, the output, the scratch buffers) and
+    how many copies it starts and awaits and how many dots it makes, as
+    written (a loop's body once).  What the Mosaic module is built from:
+    the compiled text holds that module as bytecode."""
+    def calls(jaxpr, name):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == name:
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub, name)
+
+    call, = calls(jax.make_jaxpr(fn)(*args).jaxpr, "pallas_call")
+    body = call.params["jaxpr"]
+    return ([v.aval.shape for v in body.invars],
+            {name: len(list(calls(body, name)))
+             for name in ("dma_start", "dma_wait", "dot_general")})
+
+
+@pytest.mark.parametrize("shape, refs, counts", [
+    # Mistral-7B and OLMoE-1B-7B: one plane, P = 16, [M, rows, 128] pools
+    # and [2, 16, rows, 128] buffers, a K and a V copy a page
+    ("mistral7b", [(3200, 128, 128)] * 2 + [(2, 16, 128, 128)] * 2
+     + [(32, 2048)], {"dma_start": 4, "dma_wait": 2, "dot_general": 2}),
+    ("olmoe", [(3200, 256, 128)] * 2 + [(2, 16, 256, 128)] * 2
+     + [(16, 4096)], {"dma_start": 4, "dma_wait": 2, "dot_general": 2}),
+    # the latent cache: five planes in one copy a page, 1,024 keys a
+    # step, no V pool and no V buffer; a dot a plane and four PV dots
+    ("latent", [(5, 2050, 128, 128), (2, 5, 8, 128, 128), (128, 1024)],
+     {"dma_start": 2, "dma_wait": 1, "dot_general": 9}),
+])
+def test_cells_of_one_plane_and_the_latent_cell_keep_their_kernel(
+        shape, refs, counts):
+    """The dense, OLMoE and latent cells' calls are built as they were
+    before K's planes shared a descriptor and ``P`` followed the KV heads
+    and the window: the same operand views, buffers, head mask, copies
+    and dots (nothing is compiled here: the call's own jaxpr)."""
+    arg = jax.ShapeDtypeStruct
+    if shape == "latent":
+        fn = lambda q, k, t, l: pa.paged_decode_attention(
+            q, k, None, t, l, interpret=False, k_planes=5, plane_stride=2050,
+            v_in_k=512, scale=192 ** -0.5)
+        args = (arg((128, 128, 576), jnp.bfloat16),
+                arg((5 * 2050, 128, 1, 128), jnp.bfloat16),
+                arg((128, 128), jnp.int32), arg((128,), jnp.int32))
+    else:
+        h, kv_h, window = {"mistral7b": (32, 8, 4096),
+                           "olmoe": (16, 16, None)}[shape]
+        fn = lambda q, k, v, t, l: pa.paged_decode_attention(
+            q, k, v, t, l, interpret=False, window=window)
+        pool = arg((3200, 16, kv_h, 128), jnp.bfloat16)
+        args = (arg((32, h, 128), jnp.bfloat16), pool, pool,
+                arg((32, 512), jnp.int32), arg((32,), jnp.int32))
+    got, got_counts = _kernel_body(fn, *args)
+    for ref in set(refs):
+        assert got.count(ref) == refs.count(ref), (ref, got)
+    # lengths, table, q, head mask, pools, output, buffers, semaphores, slot
+    assert len(got) == (9 if shape == "latent" else 11)
+    assert got_counts == counts
+
+
 def _values_made(text):
     """``(name, dims, opcode, operands…)`` of every instruction of a
     compiled program's text whose value is one array (a tuple's shape
@@ -251,6 +313,10 @@ def test_engine_programs_keep_the_pool_in_place_on_v5e(serving_programs,
     text = compiled_text(program)
     assert 'custom_call_target="tpu_custom_call"' in text
     assert len(re.findall(r"paged_decode_attention[\w.]* = ", text)) == 1
+    # what the engine says its kernel was built with: 256 keys a step in
+    # both cells (8 and 16 KV heads); a TP shard's 2 KV heads get 1,024
+    assert list(engine.last_attn_pages_per_step.values()) \
+        == [64 if engine._tp > 1 else 16]
     assert pool_value_faults(text, _POOL_LAYERS, _POOL_PAGES, _PAGE, kv_h,
                              ad.head_dim) == []
     # a decode step's rows are scattered into the pool as it is held, a
@@ -406,18 +472,30 @@ def test_paged_decode_with_k_planes_compiles_for_v5e(one_chip, kv_h, window,
             window=window, sink=logits if sink else None, k_planes=2,
             plane_stride=layers * pages)
 
-    text = jax.jit(fn).lower(
-        arg((rows, h, 192)), arg((2 * layers, pages, page, kv_h, 128)),
-        arg((layers, pages, page, kv_h, 128)),
-        arg((rows, max_blocks), jnp.int32), arg((rows,), jnp.int32),
-        arg((h,), jnp.float32)).compile().as_text()
+    args = (arg((rows, h, 192)), arg((2 * layers, pages, page, kv_h, 128)),
+            arg((layers, pages, page, kv_h, 128)),
+            arg((rows, max_blocks), jnp.int32), arg((rows,), jnp.int32),
+            arg((h,), jnp.float32))
+    # the walk the shapes give: 2,048 score columns of 4 KV heads are 32
+    # pages a step; a window of 128 has 9 live pages at most.  K's planes
+    # of a page arrive in ONE copy: [2 slots, 2 planes, P, 16·kv_h, 128]
+    P = 9 if window else 32
+    refs, counts = _kernel_body(fn, *args)
+    assert (2, 2, P, page * kv_h, 128) in refs
+    assert (2, P, page * kv_h, 128) in refs                   # V's buffer
+    assert (2, layers * pages, page * kv_h, 128) in refs      # the K pool
+    assert (h, P * page * kv_h) in refs                       # the head mask
+    assert counts == {"dma_start": 4, "dma_wait": 2, "dot_general": 3}
+    text = jax.jit(fn).lower(*args).compile().as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
     assert "paged_decode_attention" in text
     made = re.findall(rf"= \w+\[(?:{2 * layers}|{layers}),{pages},\S* "
                       rf"([\w-]+)\(", text)
     assert made and set(made) <= {"parameter", "bitcast"}, made
-    flat = re.findall(rf"= \w+\[(?:{2 * layers * pages}|{layers * pages}),"
-                      rf"\S* ([\w-]+)\(", text)
+    # the kernel's own views, [2 planes, layers·pages, rows, 128] of K and
+    # [layers·pages, rows, 128] of V: bitcasts
+    flat = re.findall(rf"= \w+\[(?:2,)?{layers * pages},\S* ([\w-]+)\(",
+                      text)
     assert flat and set(flat) <= {"bitcast"}, flat
 
 
@@ -506,6 +584,13 @@ def test_hybrid_programs_keep_both_pools_in_place_on_v5e(hybrid_programs,
                                                  128)
         assert pool_value_faults(text, layers, pages, _PAGE, kv_h, 128) \
             == [], (kind, name)
+        if name == "k":
+            # the kernel reads K as [2 planes, a plane's pages, rows, 128]
+            # (one copy fetches both planes of a page): a bitcast
+            view = f"2,{layers // 2 * pages},{_PAGE * kv_h},128"
+            made = [op for _, shape, op, _ in _values_made(text)
+                    if shape == view]
+            assert made and set(made) == {"bitcast"}, (kind, made)
     assert memory.temp_size_in_bytes < 0.5e9
     assert memory.alias_size_in_bytes == sum(
         2 * np.prod(a.shape) for pool in engine.pool.values()
@@ -513,6 +598,7 @@ def test_hybrid_programs_keep_both_pools_in_place_on_v5e(hybrid_programs,
     assert expert_value_faults(text, 6, 16, 4096, 2048) == []
     assert _mosaic_calls(text) == (6, 6)
     assert len(re.findall(r"paged_decode_attention[\w.]* = ", text)) == 7
+    assert engine.last_attn_pages_per_step == {"full": 32, "window": 9}
     if program.startswith("chunks"):
         # 256 chunk rows and 256 decode rows through each weight together
         rows = _matmul_rows(text)
@@ -642,6 +728,7 @@ def test_latent_programs_keep_the_one_pool_in_place_on_v5e(latent_programs,
     assert _mosaic_calls(text) == (1, 1)
     calls = len(re.findall(r"paged_decode_attention[\w.]* = ", text))
     assert calls == (4 if program.startswith("chunks") else 2)
+    assert engine.last_attn_pages_per_step == {"latent": 8}
     assert memory.temp_size_in_bytes < 0.7e9
     if program.startswith("chunks"):
         # 256 chunk rows and 128 decode rows through each weight together

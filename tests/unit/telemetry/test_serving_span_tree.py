@@ -432,6 +432,8 @@ def _expected_gauges(eng):
     want = dict(eng.scheduler.telemetry_gauges())
     want["inference/kv/pages_in_use/kv"] = float(
         eng.cache_config.num_blocks - 1 - eng.scheduler.allocator.num_free)
+    want.update({f"inference/attn/pages_per_step/{kind}": float(pages)
+                 for kind, pages in eng.last_attn_pages_per_step.items()})
     stats = eng.last_moe_stats
     if stats:
         want.update({f"inference/moe/expert_load_e{e}": frac
@@ -441,7 +443,7 @@ def _expected_gauges(eng):
     return want
 
 
-@pytest.mark.parametrize("family", ["scheduler", "pools", "router"])
+@pytest.mark.parametrize("family", ["scheduler", "pools", "router", "kernel"])
 def test_a_rounds_gauges_are_read_from_a_scrape_and_set_by_no_round(
         tiny_model, family):
     """``inference/queue_depth`` and its three neighbours, the pools'
@@ -463,10 +465,17 @@ def test_a_rounds_gauges_are_read_from_a_scrape_and_set_by_no_round(
         eng.put(rng.randint(1, 512, size=n).tolist(), max_new_tokens=6)
     for _ in range(3):
         eng.step_ahead()
+    # off the TPU the reference attends, which walks no pages: nothing is
+    # recorded; a program traced for the chip leaves its kernel's P
+    assert eng.last_attn_path == "reference"
+    assert eng.last_attn_pages_per_step == {}
+    if family == "kernel":
+        eng.last_attn_pages_per_step = {"kv": 16}
     names = {"scheduler": {"inference/queue_depth", "inference/prefilling",
                            "inference/batch_occupancy",
                            "inference/kv_pool_utilization"},
              "pools": {"inference/kv/pages_in_use/kv"},
+             "kernel": {"inference/attn/pages_per_step/kv"},
              "router": {f"inference/moe/expert_load_e{e}" for e in range(4)}
              | {"inference/moe/load_imbalance", "inference/moe/drop_rate"}
              }[family]
@@ -494,7 +503,7 @@ def test_a_rounds_gauges_are_read_from_a_scrape_and_set_by_no_round(
     later = _expected_gauges(eng)
     assert all(after[n]["value"] == pytest.approx(later[n], abs=1e-12)
                for n in names)
-    if family != "router":
+    if family in ("scheduler", "pools"):
         assert all(later[n] == 0.0 for n in names)
 
 
