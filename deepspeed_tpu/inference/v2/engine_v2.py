@@ -101,7 +101,8 @@ import numpy as np
 
 from ...ops.pallas.paged_attention import (pages_per_step,
                                            paged_decode_attention,
-                                           paged_decode_impl)
+                                           paged_decode_impl,
+                                           query_tokens_per_row)
 from ...telemetry.perf import get_compile_tracker, tracked_jit
 from ...utils.logging import log_dist
 from .adapters import AttentionKind, ModelAdapterV2, make_adapter
@@ -187,8 +188,12 @@ class RaggedInferenceEngineV2:
         self.mesh = mesh
         self.last_attn_path = None  # set at trace time by attend_fn
         #: beside it: the pages a compute step of each kind's paged kernel
-        #: was built with (``pages_per_step``), by the kind's name
+        #: was built with (``pages_per_step``), by the kind's name (its
+        #: decode rows' call) and ``<name>/chunk`` (a latent kind's chunk
+        #: rows' call), and the tokens a grid row of that second call
+        #: holds (``query_tokens_per_row``), by the kind's name
         self.last_attn_pages_per_step: Dict[str, int] = {}
+        self.last_attn_query_tokens: Dict[str, int] = {}
         self._tp = int(mesh.shape.get("tensor", 1)) if mesh is not None else 1
         self.kinds: Dict[str, AttentionKind] = {
             k.name: k for k in self.adapter.kinds}
@@ -462,8 +467,8 @@ class RaggedInferenceEngineV2:
         O(allocated), not O(max_seq_len).  ``rings [Bp]``: each row's
         ring's first page, where a kind recycles (else None); such a kind
         gathers the window's pages and the chunk's and no bucket.  A latent
-        kind (``v_in_k``) gathers nothing: each of its chunk rows is a row
-        of the paged kernel (:meth:`_paged_attend`).  Returns
+        kind (``v_in_k``) gathers nothing: its chunk rows are rows of the
+        paged kernel, ``T`` tokens a row (:meth:`_paged_attend`).  Returns
         (positions ``[Bp·C]``, the rows' part ``(Bp·C, write_fn,
         attend_fn)``: what :meth:`_layer_step` needs for them,
         :meth:`_beside`)."""
@@ -541,16 +546,22 @@ class RaggedInferenceEngineV2:
 
         def attend_fn(q, pool, kind, l, sink):
             if kind.v_in_k:
-                # a latent kind: each chunk row is a row of the paged
-                # kernel, at its own length through its sequence's table,
-                # as a decode row is.  With every query head on the one
-                # cached row the kernel is bound by its products either
+                # a latent kind: the chunk's tokens are rows of the paged
+                # kernel through their sequence's table, T consecutive
+                # tokens a row (the row's length is its last token's): they
+                # share each fetched page and the row's last, half-empty
+                # step (PERF.md §6, PR 46).  With every query head on the
+                # one cached row the kernel is bound by its products either
                 # way, and gathered here the float32 scores of 128 heads
                 # over the bucket crossed HBM three times: 15 ms of a 43 ms
                 # step against the kernel's 7 (PERF.md §6, PR 40)
-                return self._paged_attend(
-                    q, pool, kind, l, sink, jnp.repeat(tables, C, axis=0),
-                    positions.reshape(-1) + 1)
+                T = query_tokens_per_row(
+                    C, *self._kernel_shapes(pool, kind, tables.shape[1]))
+                out = self._paged_attend(
+                    q.reshape((Bp * C // T, T) + q.shape[1:]), pool, kind, l,
+                    sink, jnp.repeat(tables, C // T, axis=0),
+                    positions.reshape(-1, T)[:, -1] + 1)
+                return out.reshape((Bp * C,) + out.shape[2:])
             # gather only the attended pages (a bucket: every key written
             # so far lives in the first kb pages of each row's table) and
             # attend chunk-queries over them — O(allocated), not
@@ -603,11 +614,26 @@ class RaggedInferenceEngineV2:
 
         return positions.reshape(-1), (Bp * C, write_fn, attend_fn)
 
+    def _kernel_shapes(self, pool, kind, max_blocks):
+        """What the paged kernel's two rules (``pages_per_step``,
+        ``query_tokens_per_row``) are given for a kind's pool, after their
+        leading argument(s): page size, KV heads and query heads of a TP
+        shard, a K row as held, item size, table width, a V row (0: in
+        the K row), window."""
+        k = pool["k"]       # [layers · planes, N, bs, kv_h, w]
+        return (k.shape[2], kind.kv_heads // self._tp,
+                self.adapter.num_heads // self._tp,
+                k.shape[0] // kind.layers * k.shape[-1], k.dtype.itemsize,
+                max_blocks, 0 if kind.v_in_k else pool["v"].shape[-1],
+                kind.window)
+
     def _paged_attend(self, q, pool, kind, l, sink, tables, lengths):
-        """One-token queries ``q [R, h, k_dim]`` over layer ``l`` of a
-        kind's pool through the paged kernel: row ``r`` attends over its
-        first ``lengths[r]`` keys through ``tables[r]``.  The decode rows
-        of every kind, and the chunk rows of a latent one."""
+        """Queries ``q [R, h, k_dim]``, a token a row, over layer ``l`` of
+        a kind's pool through the paged kernel: row ``r`` attends over its
+        first ``lengths[r]`` keys through ``tables[r]`` (the decode rows of
+        every kind); or ``q [R, T, h, k_dim]``, ``T`` consecutive tokens a
+        row and ``lengths[r]`` the last one's (the chunk rows of a latent
+        kind)."""
         # the kernel fetches pages from HBM by page id: it gets
         # the whole pool's flat view, and the layer's offset is
         # folded into the tables it prefetches anyway
@@ -635,12 +661,12 @@ class RaggedInferenceEngineV2:
                      "attention runs the jax.numpy reference: "
                      "the kernel refused their shapes")
         if impl != "reference":
-            self.last_attn_pages_per_step[kind.name] = pages_per_step(
-                flat["k"].shape[1], kind.kv_heads // self._tp,
-                self.adapter.num_heads // self._tp,
-                k_planes * flat["k"].shape[-1], flat["k"].dtype.itemsize,
-                tables.shape[1], 0 if v_in_k else flat["v"].shape[-1],
-                kind.window)
+            name, tokens = kind.name, 1
+            if q.ndim == 4:     # a chunk's rows: their own step
+                name, tokens = f"{name}/chunk", q.shape[1]
+                self.last_attn_query_tokens[kind.name] = tokens
+            self.last_attn_pages_per_step[name] = pages_per_step(
+                *self._kernel_shapes(pool, kind, tables.shape[1]), tokens)
         if self._tp > 1:
             # the Pallas kernel runs PER TP SHARD via an explicit
             # shard_map over the kv-head axis (heads independent,
@@ -938,7 +964,13 @@ class RaggedInferenceEngineV2:
                 f"inference/attn/pages_per_step/{name}", float(pages),
                 help="pages a compute step of the kind's paged decode "
                      "kernel fetches and scores, as the traced programs "
-                     "were built")
+                     "were built (<kind>/chunk: its chunk rows' call)")
+        for name, tokens in self.last_attn_query_tokens.items():
+            tel.set_gauge(
+                f"inference/attn/query_tokens_per_row/{name}", float(tokens),
+                help="consecutive tokens of a prefill chunk that share a "
+                     "grid row of the kind's paged kernel, as the traced "
+                     "programs were built")
         stats = self.last_moe_stats
         if not stats:
             return

@@ -81,6 +81,18 @@ not under the cache's stream.  ``scale``: the scores' scale where it is
 not ``1/sqrt(d)`` of the row as cached (an absorbed query is as wide as
 the latent row; the scale is that of the head it stands for).
 
+Several query tokens a grid row (``q [R, T, h, d]``: a prefill chunk's
+rows, ``T`` consecutive positions of ONE sequence through ONE table row,
+``lengths[r]`` the LAST token's): the query block is the ``T·h`` rows of
+the row's tokens, token-major, and so are the running max, the sum and the
+accumulator; the walk is the last token's live pages (under a window, from
+the first token's), a superset of every token's; and the column mask
+compares against the query ROW's own length, ``lengths[r] − (T − 1) +
+row // h``.  The row's tokens share each fetched page, the row's fixed work
+and its half-empty last step, and a step holds half the keys
+(:func:`pages_per_step`); ``T`` for a chunk is :func:`query_tokens_per_row`'s.
+``T = 1`` (``q [R, h, d]``) is the same kernel to the instruction.
+
 ``sink`` (``[h]`` float32, one learned logit a query head): a column of
 the softmax that takes mass and carries no value,
 ``p_j = exp(s_j) / (Σ exp(s_j') + exp(sink))``.  In the kernel it is where
@@ -103,7 +115,8 @@ from .select import reference_off_tpu, shape_refused
 def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
                            window=None, sink=None, k_planes=1,
                            plane_stride=0, v_in_k=0, scale=None):
-    """Pure-jnp reference.  ``q [B, h, d]``; K pool ``[M, bs, kv_h, w]`` in
+    """Pure-jnp reference.  ``q [B, h, d]`` (or ``[B, T, h, d]``: ``T``
+    consecutive tokens a row, ``lengths`` the last one's); K pool ``[M, bs, kv_h, w]`` in
     ``k_planes`` planes (plane ``p`` of page ``n`` at ``n +
     p·plane_stride``; ``k_planes·w >= d``: what lies beyond ``d`` is lane
     padding); V pool ``[N, bs, kv_h, dv]``; ``block_tables [B,
@@ -112,6 +125,16 @@ def paged_decode_reference(q, k_pool, v_pool, block_tables, lengths,
     beside the keys'; ``v_in_k``: V is the K row's leading ``v_in_k``
     numbers (``v_pool`` None); ``scale``: the scores', where not
     ``1/sqrt(d)``."""
+    if q.ndim == 4:
+        # token t of a row attends over lengths - (T - 1 - t) keys: a row a
+        # token, through the row's table
+        B, T = q.shape[:2]
+        out = paged_decode_reference(
+            q.reshape((B * T,) + q.shape[2:]), k_pool, v_pool,
+            jnp.repeat(block_tables, T, axis=0),
+            (lengths[:, None] - (T - 1) + jnp.arange(T)[None, :]).reshape(-1),
+            window, sink, k_planes, plane_stride, v_in_k, scale)
+        return out.reshape((B, T) + out.shape[1:])
     B, _, d = q.shape
     _, bs, kv_h, _ = k_pool.shape
     max_blocks = block_tables.shape[1]
@@ -173,29 +196,75 @@ _LATENT_STEP_TOKENS = 1024
 _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
+def _step_pages(block_size: int, kv_h: int, h: int, d: int, itemsize: int,
+                max_blocks: int, v_dim: int | None, window: int | None,
+                q_tokens: int) -> tuple[int, int]:
+    """(the pages a step of ``q_tokens`` tokens a row wants, the pages of
+    it that ``_VMEM_BUDGET_BYTES`` holds), of :func:`pages_per_step`'s
+    arguments."""
+    rows = block_size * kv_h
+    v_dim = d if v_dim is None else v_dim
+    per_page = (2 * rows * (d + v_dim) * itemsize
+                + 5 * q_tokens * h * rows * 4)
+    keys = _LATENT_STEP_TOKENS if v_dim == 0 \
+        else max(_STEP_TOKENS, _STEP_COLUMNS // kv_h)
+    # several tokens a row: half the keys.  A step's fixed work is then
+    # spread over T times the products, so the dead keys of a row's last
+    # step weigh more than the steps: on the v5e, the latent cell's chunk
+    # rows, 512 keys a step were the best of 128 / 256 / 512 / 1,024 at
+    # every T from 2 to 16 (PERF.md §6, PR 46)
+    keys //= min(q_tokens, 2)
+    # a row under a window has live pages [k0, nk): the window's (from the
+    # row's first token's to its last's) and the one it begins in the
+    # middle of
+    live = max_blocks if window is None else min(
+        max_blocks, -(-(window + q_tokens - 1) // block_size) + 1)
+    # the float32 accumulator (a latent row's value is no wider than it)
+    budget = _VMEM_BUDGET_BYTES - q_tokens * h * (v_dim or d) * 4
+    return max(1, min(keys // block_size, live)), budget // per_page
+
+
 def pages_per_step(block_size: int, kv_h: int, h: int, d: int, itemsize: int,
                    max_blocks: int, v_dim: int | None = None,
-                   window: int | None = None) -> int:
+                   window: int | None = None, q_tokens: int = 1) -> int:
     """``P``: pages fetched and scored per compute step, from the shapes
     alone (``d``: a K row as the pool holds it, all its planes; ``v_dim``:
     a V row, where it is another width; 0: V lies in the K row and has no
-    buffer, a latent cache; ``window``: the layer's reach in keys)."""
-    rows = block_size * kv_h
-    per_page = (2 * rows * (d + (d if v_dim is None else v_dim)) * itemsize
-                + 5 * h * rows * 4)
-    keys = _LATENT_STEP_TOKENS if v_dim == 0 \
-        else max(_STEP_TOKENS, _STEP_COLUMNS // kv_h)
-    # a row under a window has live pages [k0, nk): the window's and the
-    # one it begins in the middle of
-    live = max_blocks if window is None \
-        else min(max_blocks, -(-window // block_size) + 1)
-    return max(1, min(keys // block_size, live,
-                      _VMEM_BUDGET_BYTES // per_page))
+    buffer, a latent cache; ``window``: the layer's reach in keys;
+    ``q_tokens``: the tokens a grid row holds, ``T``: the step's score
+    temporaries and its accumulator are ``T·h`` rows)."""
+    want, fit = _step_pages(block_size, kv_h, h, d, itemsize, max_blocks,
+                            v_dim, window, q_tokens)
+    return max(1, min(want, fit))
+
+
+def query_tokens_per_row(chunk: int, block_size: int, kv_h: int, h: int,
+                         d: int, itemsize: int, max_blocks: int,
+                         v_dim: int | None = None,
+                         window: int | None = None) -> int:
+    """``T``: how many of a prefill chunk's ``chunk`` consecutive tokens
+    share a grid row of the kernel (``q [R, T, h, d]``), from the shapes
+    alone (:func:`pages_per_step`'s): the largest divisor of ``chunk``
+    whose step (the ``[T·h, dv]`` float32 accumulator, the score
+    temporaries of ``T·h`` rows and two slots of pages) fits
+    ``_VMEM_BUDGET_BYTES`` at the pages it wants.  A row's tokens share
+    each fetched page, the row's fixed work and its half-empty last step;
+    4 at 128 heads over a 512-wide latent row, where 8 and 16 (with
+    Mosaic's scoped limit raised for them) were within 1.5% (PERF.md §6,
+    PR 46)."""
+    for tokens in range(chunk, 1, -1):
+        if chunk % tokens == 0:
+            want, fit = _step_pages(block_size, kv_h, h, d, itemsize,
+                                    max_blocks, v_dim, window, tokens)
+            if fit >= want:
+                return tokens
+    return 1
 
 
 def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
                   block_size: int, kv_h: int, scale: float, window=None,
-                  sink: bool = False, k_planes: int = 1, v_in_k: int = 0):
+                  sink: bool = False, k_planes: int = 1, v_in_k: int = 0,
+                  q_tokens: int = 1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -211,16 +280,20 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
     # K in planes: the buffer is [2, planes, P, rows, w] and a page's
     # planes arrive in one copy; one plane: [2, P, rows, w]
     P = k_buf.shape[-3]
+    # the query rows of a grid row: its T tokens' heads, token-major
     h = q_ref.shape[1]
+    T = q_tokens
     C = P * block_size * kv_h
 
     def live_pages(row):
-        """(first live page, number of live pages) of ``row``."""
+        """(first live page, number of live pages) of ``row``: from the
+        first token's window to the last token's length."""
         length = len_ref[row]
         nk = jax.lax.div(length + block_size - 1, block_size)
         if window is None:
             return 0, nk
-        k0 = jax.lax.div(jnp.maximum(length - window, 0), block_size)
+        k0 = jax.lax.div(jnp.maximum(length - (window + T - 1), 0),
+                         block_size)
         return k0, nk - k0
 
     def page_copies(page, slot, j):
@@ -279,6 +352,10 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
     slot0 = slot_ref[0]
     q = q_ref[0]                                   # [h, d], cache dtype
     column = jax.lax.broadcasted_iota(jnp.int32, (h, C), 1)
+    # the keys a query row attends over: the grid row's, or with T tokens
+    # a row ``[h, 1]``: row r is token r // heads, and the last has them all
+    own = length if T == 1 else length - (T - 1) + jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0), h // T)
 
     def step(i, carry):
         m_prev, l_prev, acc = carry
@@ -312,9 +389,9 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
         s = s * scale + head_mask_ref[...]
         # column c of this step is position first + c // kv_h
         first = (k0 + i * P) * block_size
-        keep = column < (length - first) * kv_h
+        keep = column < (own - first) * kv_h
         if window is not None:  # sliding window: only the cache tail
-            keep = keep & (column >= (length - window - first) * kv_h)
+            keep = keep & (column >= (own - window - first) * kv_h)
         s = jnp.where(keep, s, -1e30)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -344,7 +421,7 @@ def _paged_kernel(len_ref, table_ref, q_ref, head_mask_ref, *rest,
         start + (jnp.zeros((h, o_ref.shape[2]), jnp.float32),))
     slot_ref[0] = jax.lax.rem(slot0 + steps, 2)
     # a row of length 0 ran one step with every column masked
-    o_ref[0] = jnp.where(length > 0, acc / l, 0.0).astype(o_ref.dtype)
+    o_ref[0] = jnp.where(own > 0, acc / l, 0.0).astype(o_ref.dtype)
 
 
 def _refusal(h: int, kv_h: int, k_dim: int, v_dim: int,
@@ -382,7 +459,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            sink=None, k_planes: int = 1,
                            plane_stride: int = 0, v_in_k: int = 0,
                            scale: float | None = None):
-    """One-token queries ``q [B, h, d]`` over a shared paged KV pool
+    """Queries ``q [B, h, d]``, a token a row (or ``[B, T, h, d]``: ``T``
+    consecutive tokens of one sequence a row, ``lengths`` the LAST one's,
+    token ``t`` attending over ``lengths − (T − 1 − t)`` keys → ``[B, T, h,
+    dv]``) over a shared paged KV pool
     ``k [M, block_size, kv_h, w]``, ``v [N, block_size, kv_h, dv]``
     addressed by ``block_tables [B, max_blocks]`` with true ``lengths
     [B]`` → ``[B, h, dv]``.  A K row of ``d > 128`` lies in ``k_planes``
@@ -397,7 +477,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     where it is not ``1/sqrt(d)``.  The pool is
     passed as it lies in memory: the ``[M, block_size·kv_h, w]`` view the
     kernel reads merges two adjacent dims and moves nothing."""
-    h = q.shape[1]
+    h = q.shape[-2]
     kv_h, w = k_pool.shape[2], k_pool.shape[3]
     dv = v_in_k or v_pool.shape[-1]
     impl = paged_decode_impl(h, kv_h, interpret, w, dv)
@@ -434,7 +514,12 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, h, d = q.shape
+    # T tokens a row: their heads are the grid row's query rows, token-major
+    T = q.shape[1] if q.ndim == 4 else 1
+    B, d = q.shape[0], q.shape[-1]
+    heads = q.shape[-2]
+    h = T * heads
+    q = q.reshape(B, h, d)
     M, block_size, kv_h, w = k_pool.shape
     dv = v_in_k or v_pool.shape[-1]
     if k_planes > 1 and k_planes * plane_stride != M:
@@ -444,8 +529,8 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
     dk = k_planes * w
     if dk > d:      # the last plane's lane padding: zeros times zeros
         q = jnp.pad(q, ((0, 0), (0, 0), (0, dk - d)))
-    P = pages_per_step(block_size, kv_h, h, dk, k_pool.dtype.itemsize,
-                       max_blocks, 0 if v_in_k else dv, window)
+    P = pages_per_step(block_size, kv_h, heads, dk, k_pool.dtype.itemsize,
+                       max_blocks, 0 if v_in_k else dv, window, T)
     rows = block_size * kv_h
     # K in planes: the pool as [planes, pages, rows, w], so that one
     # strided copy fetches a page's planes into [planes, P, rows, w]
@@ -453,25 +538,26 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
     # query head r reads the columns of kv head r // n_rep
     head_mask = np.where(
         np.arange(P * rows)[None, :] % kv_h
-        == np.arange(h)[:, None] // (h // kv_h), 0.0, -1e30
+        == np.arange(h)[:, None] % heads // (heads // kv_h), 0.0, -1e30
     ).astype(np.float32)
     kernel = functools.partial(_paged_kernel, block_size=block_size,
                                kv_h=kv_h, scale=scale or 1.0 / np.sqrt(d),
                                window=window, sink=sink is not None,
-                               k_planes=k_planes, v_in_k=v_in_k)
+                               k_planes=k_planes, v_in_k=v_in_k, q_tokens=T)
     row = lambda b, lens, table: (b, 0, 0)
     whole = lambda b, lens, table: (0, 0)
     sink_spec, sink_arg = [], []
     if sink is not None:
         sink_spec = [pl.BlockSpec((h, 1), whole)]
-        sink_arg = [sink.astype(jnp.float32).reshape(h, 1)]
+        sink = sink.astype(jnp.float32)
+        sink_arg = [(sink if T == 1 else jnp.tile(sink, T)).reshape(h, 1)]
     # a V pool of its own, with its buffer; none where V lies in K's rows
     v_spec, v_scratch, v_arg = [], [], []
     if not v_in_k:
         v_spec = [pl.BlockSpec(memory_space=pl.ANY)]
         v_scratch = [pltpu.VMEM((2, P, rows, dv), v_pool.dtype)]
         v_arg = [v_pool.reshape(v_pool.shape[0], rows, dv)]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -497,6 +583,7 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, lengths, sink, *,
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, jnp.asarray(head_mask), *sink_arg,
       k_pool.reshape(*planes, M // k_planes, rows, w), *v_arg)
+    return out if T == 1 else out.reshape(B, T, heads, dv)
 
 
 def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, lengths,

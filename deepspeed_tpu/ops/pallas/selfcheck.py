@@ -371,36 +371,53 @@ def check_paged_latent(s: KernelShapes, interpret: bool) -> List[Check]:
     """Paged decode over a latent cache at the widths of the model that
     has one: 128 query heads on ONE cached row a token of 512 + 64 numbers
     in five 128-lane planes, whose leading 512 are also the value, pages
-    of 128 tokens, the scores' scale that of the 192-wide head."""
+    of 128 tokens, the scores' scale that of the 192-wide head: a token a
+    row as its decode rows are, and as many tokens a row as the kernel's
+    rule gives a prefill chunk of one page."""
     pa = _mod("paged_attention")
     rng = np.random.RandomState(13)
     heads, k_dim, v_dim, page, planes = 128, 576, 512, 128, 5
     max_blocks = max(s.cache_len // page, 6)
     rows = s.slots
     pages = rows * max_blocks + 1
-    q = _normal(rng, (rows, heads, k_dim), s.dtype)
     tables = jnp.asarray(rng.permutation(np.arange(1, pages)).reshape(
         rows, max_blocks).astype(np.int32))
     top = max_blocks * page
-    lengths = jnp.asarray(np.resize(np.clip(
-        [page - 1, page, page + 1, top, 3, 2 * page + 5, top // 2, 0],
-        0, top), rows).astype(np.int32))
     k = _normal(rng, (pages, page, 1, planes * 128), s.dtype)
     k = k.at[..., k_dim:].set(0)              # the last plane's padding
     k_pool = jnp.concatenate([k[..., p * 128:(p + 1) * 128]
                               for p in range(planes)], axis=0)
     kw = dict(k_planes=planes, plane_stride=pages, v_in_k=v_dim,
               scale=192 ** -0.5)
-    got = pa.paged_decode_attention(q, k_pool, None, tables, lengths,
-                                    interpret=interpret, **kw)
-    with jax.default_matmul_precision("highest"):
-        want = pa.paged_decode_reference(
-            q.astype(jnp.float32), k_pool.astype(jnp.float32), None, tables,
-            lengths, **kw)
-    live = (lengths > 0)[:, None, None]       # a dead slot gives zeros
-    return [Check("paged_decode(latent k576 v=k[:512], 128 heads on 1)",
-                  float(_rel_err(jnp.where(live, got, 0),
-                                 jnp.where(live, want, 0))), DECODE_TOL)]
+
+    def case(what, q_shape, lengths, shortest):
+        q = _normal(rng, q_shape, s.dtype)
+        lengths = jnp.asarray(np.resize(np.clip(lengths, shortest, top),
+                                        rows).astype(np.int32))
+        got = pa.paged_decode_attention(q, k_pool, None, tables, lengths,
+                                        interpret=interpret, **kw)
+        with jax.default_matmul_precision("highest"):
+            want = pa.paged_decode_reference(
+                q.astype(jnp.float32), k_pool.astype(jnp.float32), None,
+                tables, lengths, **kw)
+        # a dead slot gives zeros
+        live = (lengths > 0).reshape((rows,) + (1,) * (q.ndim - 1))
+        return Check(f"paged_decode(latent k576 v=k[:512], {what})",
+                     float(_rel_err(jnp.where(live, got, 0),
+                                    jnp.where(live, want, 0))), DECODE_TOL)
+
+    # a chunk's rows: T consecutive tokens a row, lengths the last one's,
+    # from a sequence's first group to the table's end
+    tokens = pa.query_tokens_per_row(
+        page, page, 1, heads, planes * 128, jnp.dtype(s.dtype).itemsize,
+        max_blocks, 0)
+    return [
+        case("128 heads on 1", (rows, heads, k_dim),
+             [page - 1, page, page + 1, top, 3, 2 * page + 5, top // 2, 0],
+             0),
+        case(f"{tokens} tokens a row", (rows, tokens, heads, k_dim),
+             [tokens, page - 1, page, page + 1, page + tokens - 1, top,
+              2 * page + 5, top // 2], tokens)]
 
 
 def check_fused_adam(s: KernelShapes, interpret: bool) -> List[Check]:

@@ -165,6 +165,98 @@ def test_work_does_not_scale_with_the_table():
     assert grids[8] == grids[512] == (B,)
 
 
+#: several query tokens a grid row, by form: KV heads, window, a sink, K's
+#: planes, V in K's leading numbers (and then the scores' own scale)
+_FORMS = {
+    # one row a token that every head reads: 12 numbers in two planes of
+    # 8 (the last half padding), the leading 8 the value
+    "latent": dict(kv_h=1, d=12, planes=2, v_in_k=8, scale=0.3),
+    "grouped_query": dict(kv_h=2, d=D),
+    "window_and_sink": dict(kv_h=2, d=D, window=11, sink=True),
+}
+
+
+@pytest.mark.parametrize("form", sorted(_FORMS))
+@pytest.mark.parametrize("tokens", [1, 2, 8])
+def test_several_tokens_a_row_attend_as_a_token_a_row(monkeypatch, tokens,
+                                                      form):
+    """``q [R, T, h, d]``: ``T`` consecutive tokens of one sequence a grid
+    row, ``lengths`` the last one's, against the reference applied a token
+    a row.  The first group of a sequence (``lengths`` = ``T``), groups
+    whose tokens cross a page and a step boundary, lengths that end on
+    and just past both, the whole table, and (last) a dead row as the
+    engine leaves one: an all-zero table."""
+    for name in ("_STEP_TOKENS", "_STEP_COLUMNS", "_LATENT_STEP_TOKENS"):
+        monkeypatch.setattr(pa, name, 2 * STEP)
+    f = dict(_FORMS[form])
+    kv_h, d, planes = f.pop("kv_h"), f.pop("d"), f.pop("planes", 1)
+    shapes = (PAGE, kv_h, H, planes * 8 if planes > 1 else d, 4, MAX_BLOCKS,
+              0 if "v_in_k" in f else None, f.get("window"))
+    # two pages a step for several tokens a row (four for one, where no
+    # window has fewer live)
+    assert pa.pages_per_step(*shapes, tokens) == (
+        P if tokens > 1 else 3 if "window" in f else 4)
+    T = tokens
+    lengths = [T, T + 3, PAGE, PAGE + 1, STEP, STEP + 1, STEP + T - 1,
+               2 * STEP + 3, MAX_BLOCKS * PAGE, T]
+    lengths = [max(n, T) for n in lengths]
+    R = len(lengths)
+    rng = np.random.RandomState(T)
+    num_pages = R * MAX_BLOCKS + 1
+    q = jnp.asarray(rng.standard_normal((R, T, H, d)), jnp.float32)
+    k_pool = jnp.asarray(rng.standard_normal(
+        (planes * num_pages, PAGE, kv_h, 8 if planes > 1 else d)),
+        jnp.float32)
+    v_pool = None if "v_in_k" in f else jnp.asarray(
+        rng.standard_normal((num_pages, PAGE, kv_h, D)), jnp.float32)
+    tables = rng.permutation(np.arange(1, num_pages)).reshape(
+        R, MAX_BLOCKS).astype(np.int32)
+    tables[-1] = 0
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+    if f.pop("sink", False):
+        f["sink"] = jnp.asarray(rng.standard_normal((H,)), jnp.float32)
+    if planes > 1:
+        f.update(k_planes=planes, plane_stride=num_pages)
+    if T == 1:      # the one form a row a token has
+        q = q[:, 0]
+    got = pa.paged_decode_attention(q, k_pool, v_pool, tables, lengths,
+                                    interpret=True, **f)
+    assert got.shape == q.shape[:-1] + (f.get("v_in_k", D),)
+    own = (lengths[:, None] - (T - 1) + jnp.arange(T)[None, :]).reshape(-1)
+    want = pa.paged_decode_reference(
+        q.reshape(R * T, H, d), k_pool, v_pool,
+        jnp.repeat(tables, T, axis=0), own, **f)
+    np.testing.assert_allclose(np.asarray(got).reshape(want.shape),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)
+    # and the reference's own form of several tokens a row is that
+    if T > 1:
+        np.testing.assert_array_equal(
+            np.asarray(pa.paged_decode_reference(
+                q, k_pool, v_pool, tables, lengths, **f)).reshape(want.shape),
+            np.asarray(want))
+
+
+def test_a_token_before_its_sequence_gives_zeros():
+    """A row whose ``lengths`` is under ``T``: the tokens that would lie
+    before the sequence's first attend over nothing and give zeros, as a
+    row of length 0 does; the others are not disturbed."""
+    T, lengths = 4, [2, 0]
+    rng = np.random.RandomState(0)
+    q = jnp.asarray(rng.standard_normal((2, T, H, D)), jnp.float32)
+    pool = jnp.asarray(rng.standard_normal((13, PAGE, 2, D)), jnp.float32)
+    tables = jnp.asarray(np.arange(1, 13).reshape(2, 6), jnp.int32)
+    got = pa.paged_decode_attention(q, pool, pool, tables,
+                                    jnp.asarray(lengths, jnp.int32),
+                                    interpret=True)
+    want = pa.paged_decode_reference(q[0, 2:], pool, pool,
+                                     jnp.repeat(tables[:1], 2, axis=0),
+                                     jnp.asarray([1, 2], jnp.int32))
+    np.testing.assert_allclose(np.asarray(got[0, 2:]), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    assert not np.any(np.asarray(got[0, :2])) and not np.any(
+        np.asarray(got[1]))
+
+
 @pytest.mark.parametrize("shape, want", [
     # Mistral-7B serving: 8 KV heads, 2,048 score columns = 256 keys a step
     ((16, 8, 32, 128, 2, 512), 16),
@@ -194,14 +286,55 @@ def test_work_does_not_scale_with_the_table():
     # the latent cache (V in K's rows, 5 planes, pages of 128): its own
     # 1,024 keys a step
     ((128, 1, 128, 640, 2, 128, 0), 8),
+    # its chunk rows, several tokens a grid row: half the keys a step,
+    # and T·h rows of score temporaries and accumulator in the budget:
+    # four tokens hold the four pages, eight hold one
+    ((128, 1, 128, 640, 2, 128, 0, None, 2), 4),
+    ((128, 1, 128, 640, 2, 128, 0, None, 4), 4),
+    ((128, 1, 128, 640, 2, 128, 0, None, 8), 1),
+    # Mistral's shape with eight tokens a row: half of 256 keys; under a
+    # window of 90 keys a token has 7 live pages at most and eight
+    # tokens' windows together 8; the hybrid window layers' 64 heads x 8
+    # tokens are 512 score rows: the budget holds five pages
+    ((16, 8, 32, 128, 2, 512, None, None, 8), 8),
+    ((16, 8, 16, 128, 2, 512, None, 90, 1), 7),
+    ((16, 8, 16, 128, 2, 512, None, 90, 8), 8),
+    ((16, 8, 64, 256, 2, 512, 128, None, 8), 5),
 ], ids=["mistral", "mistral_window_4096", "narrow_table", "olmoe",
         "hybrid_full", "hybrid_window_128", "window_100", "window_past_table",
-        "tp_shard", "pages_of_64", "vmem_bound", "one_page", "latent"])
+        "tp_shard", "pages_of_64", "vmem_bound", "one_page", "latent",
+        "latent_2_tokens", "latent_4_tokens", "latent_8_tokens",
+        "mistral_8_tokens", "window_90_1_token", "window_90_8_tokens",
+        "hybrid_8_tokens"])
 def test_pages_per_step_follows_the_shapes(shape, want):
     assert pa.pages_per_step(*shape) == want
-    page, kv_h, h, d, itemsize, _, v_dim, _ = \
-        shape + (None,) * (8 - len(shape))
+    page, kv_h, h, d, itemsize, _, v_dim, _, tokens = \
+        shape + (None,) * (8 - len(shape)) + (1,) * (len(shape) < 9)
     v_dim = d if v_dim is None else v_dim
     held = want * (2 * page * kv_h * (d + v_dim) * itemsize
-                   + 5 * h * page * kv_h * 4)
+                   + 5 * tokens * h * page * kv_h * 4) \
+        + tokens * h * (v_dim or d) * 4
     assert want == 1 or held <= pa._VMEM_BUDGET_BYTES
+
+
+@pytest.mark.parametrize("chunk, shape, want", [
+    # the latent cell: 128 heads' accumulator and score rows: 4 tokens
+    (128, (128, 1, 128, 640, 2, 128, 0), 4),
+    # a divisor of the chunk, and the whole chunk where everything fits
+    (96, (128, 1, 128, 640, 2, 128, 0), 4),
+    (6, (128, 1, 128, 640, 2, 128, 0), 3),
+    (16, (8, 1, 4, 16, 4, 8, 0), 16),
+    # grouped-query shapes (no caller): Mistral's, the hybrid full layers'
+    (128, (16, 8, 32, 128, 2, 512), 8),
+    (128, (16, 4, 64, 256, 2, 512, 128), 4),
+    # nothing fits beside a page of 512 x 8 KV heads: a token a row
+    (128, (512, 8, 32, 128, 2, 16), 1),
+], ids=["latent", "chunk_of_96", "chunk_of_6", "tiny", "mistral",
+        "hybrid_full", "one_page"])
+def test_query_tokens_per_row_follows_the_shapes(chunk, shape, want):
+    assert pa.query_tokens_per_row(chunk, *shape) == want
+    assert chunk % want == 0
+    # the step it is built with holds the pages it wants
+    full = shape + (None,) * (8 - len(shape))
+    assert want == 1 or pa.pages_per_step(*full, want) \
+        == pa._step_pages(*full, want)[0]

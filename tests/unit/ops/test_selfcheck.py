@@ -74,12 +74,14 @@ def test_streamed_check_refuses_a_resident_shape(shapes):
 
 def test_the_latent_paged_check_has_teeth(monkeypatch, shapes):
     """The paged case over a latent cache (128 heads on one row of 576 in
-    five planes, V its leading 512) passes, and a kernel that takes V from
-    the wrong numbers of the row fails it."""
+    five planes, V its leading 512; a token a row, and a chunk's several
+    tokens a row) passes, and a kernel that takes V from the wrong numbers
+    of the row fails it."""
     pa = importlib.import_module("deepspeed_tpu.ops.pallas.paged_attention")
     monkeypatch.setattr(selfcheck, "CHECKS", (selfcheck.check_paged_latent,))
-    (check,) = selfcheck.run_checks(shapes, interpret=True)
+    check, chunk = selfcheck.run_checks(shapes, interpret=True)
     assert "latent" in check.name and check.ok
+    assert "tokens a row" in chunk.name and chunk.ok
     real = pa.paged_decode_attention
 
     def shifted(q, k_pool, *a, **kw):

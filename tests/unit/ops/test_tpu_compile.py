@@ -145,6 +145,86 @@ def test_cells_of_one_plane_and_the_latent_cell_keep_their_kernel(
     assert got_counts == counts
 
 
+#: the T = 1 calls' Mosaic modules (canonicalised, printed without
+#: locations) as the parent of PR 46 built them: length and the leading 16
+#: hex digits of the text's SHA-256.  A grid row of several query tokens is
+#: the same kernel with ``T`` from the operand's shape, and a row a token
+#: must stay the kernel the four serving cells were measured with
+_T1_MODULES = {
+    "mistral7b": (17209, "1964a50c8e9b802f"),
+    "olmoe": (16348, "ecfc36e9b4b0546f"),
+    "hybrid_full": (17364, "70c8acb562fc898c"),
+    "hybrid_window": (18646, "975a9bec9064a21a"),
+    "latent": (17914, "3782a5e87d6f7e48"),
+}
+#: keyword arguments, q, K pool, V pool, table width, a sink
+_T1_CALLS = {
+    "mistral7b": (dict(window=4096), (32, 32, 128), (3200, 16, 8, 128),
+                  (3200, 16, 8, 128), 512, False),
+    "olmoe": (dict(), (32, 16, 128), (3200, 16, 16, 128),
+              (3200, 16, 16, 128), 256, False),
+    "hybrid_full": (dict(k_planes=2, plane_stride=2 * 40960), (256, 64, 192),
+                    (4 * 40960, 16, 4, 128), (2 * 40960, 16, 4, 128), 512,
+                    False),
+    "hybrid_window": (dict(k_planes=2, plane_stride=5 * 4097, window=128),
+                      (256, 64, 192), (10 * 4097, 16, 8, 128),
+                      (5 * 4097, 16, 8, 128), 512, True),
+    "latent": (dict(k_planes=5, plane_stride=5 * 6144, v_in_k=512,
+                    scale=192 ** -0.5), (128, 128, 576),
+               (25 * 6144, 128, 1, 128), None, 128, False),
+}
+
+
+def _mosaic_module(monkeypatch, capsys, one_chip, kwargs, q, k, v, width,
+                   sink, tokens=None):
+    """The Mosaic module of the one paged call these shapes trace, as
+    ``pallas_call(debug=True)`` prints it while lowering for the chip."""
+    from jax.experimental import pallas as pl
+
+    real = pl.pallas_call
+    monkeypatch.setattr(pl, "pallas_call",
+                        lambda *a, **kw: real(*a, **{**kw, "debug": True}))
+    arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    rows = q[0] if tokens is None else q[0] // tokens
+    q = q if tokens is None else (rows, tokens) + q[1:]
+    jax.clear_caches()      # the call is a jitted function of its own
+    capsys.readouterr()
+    jax.jit(lambda q, k, v, t, l, s: pa.paged_decode_attention(
+        q, k, v, t, l, interpret=False, sink=s, **kwargs)).lower(
+            arg(q), arg(k), None if v is None else arg(v),
+            arg((rows, width), jnp.int32), arg((rows,), jnp.int32),
+            arg(q[-2:-1], jnp.float32) if sink else None)
+    printed = capsys.readouterr().out
+    return printed.split("The Mosaic module for pallas_call", 1)[1].split(
+        "\n", 1)[1]
+
+
+@pytest.mark.parametrize("call", sorted(_T1_CALLS))
+def test_a_row_a_token_lowers_to_the_kernel_the_cells_were_measured_with(
+        monkeypatch, capsys, one_chip, call):
+    import hashlib
+
+    text = _mosaic_module(monkeypatch, capsys, one_chip, *_T1_CALLS[call])
+    assert (len(text), hashlib.sha256(text.encode()).hexdigest()[:16]) \
+        == _T1_MODULES[call]
+
+
+def test_several_tokens_a_row_is_another_module_of_the_same_kernel(
+        monkeypatch, capsys, one_chip):
+    """The latent chunk rows' call at the rule's ``T``: ``T·h`` query rows
+    and their accumulator, the step the rule gives, one name."""
+    shapes = (128, 1, 128, 640, 2, 128, 0)
+    tokens = pa.query_tokens_per_row(128, *shapes)
+    pages = pa.pages_per_step(*shapes, None, tokens)
+    kwargs, q, k, v, width, sink = _T1_CALLS["latent"]
+    text = _mosaic_module(monkeypatch, capsys, one_chip, kwargs,
+                          (256,) + q[1:], k, v, width, sink, tokens=tokens)
+    assert f"memref<1x{tokens * 128}x640xbf16" in text         # the queries
+    assert f"memref<2x5x{pages}x128x128xbf16" in text          # K's slots
+    assert f"vector<{tokens * 128}x512xf32>" in text           # accumulator
+
+
 def _values_made(text):
     """``(name, dims, opcode, operands…)`` of every instruction of a
     compiled program's text whose value is one array (a tuple's shape
@@ -709,10 +789,11 @@ def test_latent_programs_keep_the_one_pool_in_place_on_v5e(latent_programs,
     numbers in five planes, 6,144 pages of 128 tokens, no V pool): the
     pool is passed on, written in place and read through a bitcast; a
     Mosaic attention call a layer for the decode rows and, in the step
-    that carries chunks, one more for the chunk rows (a row a token: no
-    bucket of keys is gathered, no score matrix of 128 heads over 8,192
-    keys lies in memory); a pair of grouped expert calls in the sparse
-    layer."""
+    that carries chunks, one more for the chunk rows (four tokens a grid
+    row, as the kernel's rule gives for these shapes: 64 rows for the 256
+    tokens; no bucket of keys is gathered, no score matrix of 128 heads
+    over 8,192 keys lies in memory); a pair of grouped expert calls in the
+    sparse layer."""
     engine, compiled = latent_programs
     assert engine.last_attn_path in (None, "pallas")
     assert {k: sorted(v) for k, v in engine.pool.items()} == {"latent": ["k"]}
@@ -728,9 +809,25 @@ def test_latent_programs_keep_the_one_pool_in_place_on_v5e(latent_programs,
     assert _mosaic_calls(text) == (1, 1)
     calls = len(re.findall(r"paged_decode_attention[\w.]* = ", text))
     assert calls == (4 if program.startswith("chunks") else 2)
-    assert engine.last_attn_pages_per_step == {"latent": 8}
+    shapes = (_LATENT_PAGE, 1, 128, 640, 2, 128, 0)
+    assert engine.last_attn_pages_per_step["latent"] \
+        == pa.pages_per_step(*shapes) == 8
     assert memory.temp_size_in_bytes < 0.7e9
     if program.startswith("chunks"):
+        # the chunk rows' call: its own step, and the decode rows' entry
+        # not overwritten by it
+        tokens = pa.query_tokens_per_row(engine.chunk, *shapes)
+        assert engine.last_attn_query_tokens == {"latent": tokens} \
+            == {"latent": 4}
+        assert engine.last_attn_pages_per_step == {
+            "latent": 8,
+            "latent/chunk": pa.pages_per_step(*shapes, None, tokens)} \
+            == {"latent": 8, "latent/chunk": 4}
+        grid_rows = engine.prefill_batch * engine.chunk // tokens
+        made = re.findall(r"paged_decode_attention[\w.]* = bf16\[([\d,]+)\]",
+                          text)
+        assert sorted(made) == sorted(
+            [f"{grid_rows},{tokens * 128},512", "128,128,512"] * 2), made
         # 256 chunk rows and 128 decode rows through each weight together
         rows = _matmul_rows(text)
         assert rows.count(384) >= 6 and 256 not in rows

@@ -434,6 +434,8 @@ def _expected_gauges(eng):
         eng.cache_config.num_blocks - 1 - eng.scheduler.allocator.num_free)
     want.update({f"inference/attn/pages_per_step/{kind}": float(pages)
                  for kind, pages in eng.last_attn_pages_per_step.items()})
+    want.update({f"inference/attn/query_tokens_per_row/{kind}": float(tokens)
+                 for kind, tokens in eng.last_attn_query_tokens.items()})
     stats = eng.last_moe_stats
     if stats:
         want.update({f"inference/moe/expert_load_e{e}": frac
@@ -468,14 +470,17 @@ def test_a_rounds_gauges_are_read_from_a_scrape_and_set_by_no_round(
     # off the TPU the reference attends, which walks no pages: nothing is
     # recorded; a program traced for the chip leaves its kernel's P
     assert eng.last_attn_path == "reference"
-    assert eng.last_attn_pages_per_step == {}
-    if family == "kernel":
-        eng.last_attn_pages_per_step = {"kv": 16}
+    assert eng.last_attn_pages_per_step == eng.last_attn_query_tokens == {}
+    if family == "kernel":      # as a latent kind's two calls leave them
+        eng.last_attn_pages_per_step = {"kv": 16, "kv/chunk": 4}
+        eng.last_attn_query_tokens = {"kv": 4}
     names = {"scheduler": {"inference/queue_depth", "inference/prefilling",
                            "inference/batch_occupancy",
                            "inference/kv_pool_utilization"},
              "pools": {"inference/kv/pages_in_use/kv"},
-             "kernel": {"inference/attn/pages_per_step/kv"},
+             "kernel": {"inference/attn/pages_per_step/kv",
+                        "inference/attn/pages_per_step/kv/chunk",
+                        "inference/attn/query_tokens_per_row/kv"},
              "router": {f"inference/moe/expert_load_e{e}" for e in range(4)}
              | {"inference/moe/load_imbalance", "inference/moe/drop_rate"}
              }[family]

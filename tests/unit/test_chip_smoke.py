@@ -96,6 +96,8 @@ def test_kernels_phase_through_the_interpreter(smoke, monkeypatch):
                    "tree_sqsum", "moe_dispatch_mismatched_elements",
                    "moe_combine", "quantizer_codes", "block_sparse_dv"):
         assert family in names, (family, sorted(names))
+    # the latent cache's two calls: a token a row, a chunk's several
+    assert [n for n in names if "latent" in n and "tokens a row" in n], names
 
 
 def test_refuses_to_run_without_a_tpu():
