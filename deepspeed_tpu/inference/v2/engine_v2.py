@@ -30,25 +30,21 @@ Architecture deltas (norms, positions, FFN, head) live in
 same engine (reference keeps per-arch model implementations under
 ``inference/v2/model_implementations`` [K]).
 
-Every program donates the pools (one of K and V for each kind of attention
-layer the adapter states: ``adapters.AttentionKind``; K alone for a latent
-kind, whose one row a token holds its value too) and carries them WHOLE
-through its layer scan, addressed by ``(layer, page)``: a step's rows
-(decode) and pages (chunks) are scattered into a pool in place, and
-attention reads pages ``l·N + page`` of its flat view.  No program forms a
-layer's ``pool[l]`` (see ``_layer_step`` for why), so KV updates are
-in-place in HBM and no call moves more of the cache than it reads or
-writes.
+Every program donates the pools (one for each kind of attention layer the
+adapter states: ``adapters.AttentionKind``) and carries them WHOLE through
+its layer scan, addressed by ``(layer, page)``: a step's rows (decode) and
+pages (chunks) are scattered into a pool in place and attention reads it
+where it lies.  No program forms one layer's slice of a pool (see
+``_layer_step`` for why), so KV updates are in-place in HBM and no call
+moves more of the cache than it reads or writes.  How a row lies in a pool
+(planes, rings, a latent row) and every access to it is
+``kv_cache.KVLayout``'s, one a kind (``self.layouts``); this module holds
+the programs and the round.
 
 The scan runs over the PERIODS of the adapter's layer pattern, a period's
 layers unrolled in the step, after the pattern's leading layers; a model
 whose layers are all alike has one kind, no leading layer and a period of
-one.  A kind that recycles its pages (``ring``) has a pool of rings, one a
-live sequence (``KVCacheConfig.ring_blocks``; the scheduler hands them
-out): logical page ``j`` of a sequence is page ``j % ring_blocks`` of its
-ring, so the paged kernel's window walk and the chunks' gather find the
-window's keys where the block table of a growing sequence would have put
-them, and the pages behind the window are overwritten.
+one.
 
 **A second call in flight.**  ``step_ahead`` (the serving front-end's
 entry) plans, packs and dispatches the round's call FIRST, behind the
@@ -77,18 +73,11 @@ own accounting for a step runs last (``_observe``).  The programs take
 their small arguments as NumPy arrays (one transfer inside the call, not
 an upload each) and their sampling keys, one a call in the order of
 dispatch, from a chain split 256 links at a time (``_next_key``).
-
-A chunk's cost is O(pages allocated so far), not O(max_seq_len): its rows
-gather/mask only ``kb`` pages each, where ``kb`` is the smallest
-power-of-two page bucket covering the round's deepest ``start_pos +
-chunk`` (VERDICT r3 item 6 — the round-2 "O(max_seq_len) per chunk" cost
-note is gone).  Buckets are static shapes, so at most
-``log2(max_blocks/chunk_blocks)+1`` one-step programs ever compile.
 """
 
 from __future__ import annotations
 
-import dataclasses
+import contextlib
 import functools
 import time
 from collections import deque
@@ -98,24 +87,23 @@ from typing import (Any, Callable, Deque, Dict, List, NamedTuple, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ...ops.masks import local_attention_mask
 from ...ops.pallas.paged_attention import (pages_per_step,
                                            paged_decode_attention,
+                                           paged_decode_attention_tp,
                                            paged_decode_impl,
                                            query_tokens_per_row)
+from ...parallel.mesh import AXIS_TENSOR, strip_manual_axes
+from ...telemetry import get_telemetry, numerics
+from ...telemetry.memory import get_memory_ledger
 from ...telemetry.perf import get_compile_tracker, tracked_jit
+from ...utils.jax_compat import shard_map
 from ...utils.logging import log_dist
 from .adapters import AttentionKind, ModelAdapterV2, make_adapter
-from .kv_cache import KVCacheConfig, init_kv_pool
+from .kv_cache import KVCacheConfig, init_kv_pool, kv_layouts
 from .scheduler import RaggedScheduler, Request, RequestState
-
-
-class _null_ctx:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return None
 
 
 class _Row(NamedTuple):
@@ -171,8 +159,7 @@ class RaggedInferenceEngineV2:
                  adapter: Optional[ModelAdapterV2] = None,
                  mesh: Any = None,
                  scheduler_factory: Optional[Callable] = None,
-                 ledger_key: str = "inference_v2/kv_pool",
-                 moe_telemetry: bool = True):
+                 ledger_key: str = "inference_v2/kv_pool"):
         self.model = model
         self.adapter = adapter or make_adapter(model)
         self.config = model.config
@@ -209,26 +196,16 @@ class RaggedInferenceEngineV2:
             # clamps out-of-bounds starts, which would silently retarget a
             # chunk's KV writes onto the sequence's EARLIER pages
             raise ValueError("max_seq_len must be a multiple of prefill_chunk")
-        rings = [k for k in self.kinds.values() if k.ring]
-        if rings:
-            # a ring holds the window's pages and those a prefill chunk
-            # writes before it attends; a decode step needs one page more
-            # than the window's, which a chunk's pages cover
-            bs = self.cache_config.block_size
-            self.cache_config = dataclasses.replace(
-                self.cache_config, num_rings=max_batch_slots,
-                ring_blocks=max(-(-k.window // bs) for k in rings)
-                + max(prefill_chunk // bs, 1))
+        self.cache_config = self.cache_config.with_rings(
+            self.kinds.values(), max_batch_slots, prefill_chunk)
+        #: every access to a kind's pool: no code here indexes a pool array
+        self.layouts = kv_layouts(self.adapter, self.cache_config)
         #: the serving plane swaps in its prefix-sharing scheduler here —
         #: same planner surface, refcounted page reservations
         make_sched = scheduler_factory or RaggedScheduler
         self.scheduler = make_sched(self.cache_config, max_batch_slots,
                                     prefill_chunk, prefill_batch)
         if self._tp > 1:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            from ...parallel.mesh import strip_manual_axes
-
             spec_tree = self.model.param_specs(params)
             self.params = jax.tree.map(
                 lambda p, s: jax.device_put(
@@ -237,19 +214,13 @@ class RaggedInferenceEngineV2:
             # allocate the pool DIRECTLY into its sharding — a serving
             # config sizes the pool near HBM capacity, so transiently
             # materializing it replicated would OOM at startup
-            pool_sharding = NamedSharding(
-                mesh, PartitionSpec(None, None, None, "tensor", None))
             ad, cc = self.adapter, self.cache_config
             self.pool = tracked_jit(
                 lambda: init_kv_pool(ad, cc), "inference_v2/pool_init",
-                tracker=get_compile_tracker(),
-                out_shardings=jax.tree.map(
-                    lambda _: pool_sharding,
-                    jax.eval_shape(lambda: init_kv_pool(ad, cc))))()
+                tracker=get_compile_tracker(), out_shardings=NamedSharding(
+                    mesh, P(None, None, None, AXIS_TENSOR, None)))()
         else:
             self.pool = init_kv_pool(self.adapter, self.cache_config)
-        from ...telemetry.memory import get_memory_ledger
-
         _mem = get_memory_ledger()
         if _mem.enabled:
             # the paged KV pool is the serving plane's dominant HBM
@@ -284,12 +255,9 @@ class RaggedInferenceEngineV2:
         #: stats ride the program's output pytree ([L, E] load fractions
         #: averaged over the burst), so cached calls pay one tiny extra
         #: device→host transfer and zero recompiles.
-        from ...telemetry import numerics
-
         self._moe_coll = (
             numerics.Collector(probes=False, moe=True, tag="serving")
-            if moe_telemetry
-            and getattr(model, "_moe_layer", None) is not None else None)
+            if getattr(model, "_moe_layer", None) is not None else None)
         #: host-side rolling per-expert load (fractions, sum≈1) and the
         #: derived max/mean imbalance — the router's placement signal
         self.last_moe_stats: Optional[Dict[str, Any]] = None
@@ -300,8 +268,6 @@ class RaggedInferenceEngineV2:
         # the pools' and the router's gauges are worked out when the
         # registry is read, not in every round; the hub holds the hook
         # weakly
-        from ...telemetry import get_telemetry
-
         get_telemetry().add_collect_hook(self._publish_gauges)
         log_dist(f"inference v2: pool={self.cache_config.num_blocks}"
                  f"x{self.cache_config.block_size} tokens, "
@@ -320,16 +286,15 @@ class RaggedInferenceEngineV2:
         :meth:`_decode_rows`, :meth:`_chunk_rows`, or both
         (:meth:`_beside`).
 
-        ``pools`` holds, for each attention kind, the WHOLE pool ``{"k",
-        "v"}: [layers of the kind, N, bs, kv_h, d]``, a carry of the layer
-        scan; this layer is of ``kind`` and the ``lk``-th of it: the write
-        scatters rows or pages at ``(lk, page)`` and attention reads pages
-        ``lk·N + page`` of the flat view (:meth:`_flat_pool`).  No layer's
-        ``pool[l]`` is ever formed: a slice of a scanned stack handed to
-        a custom call (the paged kernel) is copied out and the updated
-        layer copied back, which was 62% of the serving cell's device
-        time (PERF.md §6, PRs 25, 27 and 28).  Write, then attend: only
-        the written pool lives on, so the write stays in place."""
+        ``pools`` holds, for each attention kind, the WHOLE pool, a carry
+        of the layer scan; this layer is of ``kind`` and the ``lk``-th of
+        it: the write scatters rows or pages at ``(lk, page)`` and
+        attention reads layer ``lk``'s pages where they lie.  No layer's
+        slice of a pool is ever formed: a slice of a scanned stack handed
+        to a custom call (the paged kernel) is copied out and the updated
+        layer copied back (``kv_cache``'s module docstring).  Write, then
+        attend: only the written pool lives on, so the write stays in
+        place."""
         ad = self.adapter
         q, kk, vv = ad.qkv(lp, x_flat, positions_flat, kind)
         pools = dict(pools, **{kind.name: write_fn(
@@ -338,74 +303,30 @@ class RaggedInferenceEngineV2:
         x_flat = ad.post_attn(lp, x_flat, attn, params, l)
         return x_flat, pools
 
-    @staticmethod
-    def _flat_pool(pool):
-        """A pool as ``[L·N, bs, kv_h, d]``: layer ``l``'s page ``p`` is
-        page ``l·N + p``.  Two adjacent major dims merged: a bitcast."""
-        return {name: a.reshape((-1,) + a.shape[2:])
-                for name, a in pool.items()}
-
-    @staticmethod
-    def _planes(rows, plane):
-        """``rows [..., d]`` as a pool holds them: the planes of ``plane``'s
-        width (``kv_cache.lane_planes``), zeros beyond ``d`` in the last;
-        the rows themselves where one plane holds them."""
-        d, w = rows.shape[-1], plane.shape[-1]
-        if d <= w:
-            return [rows]
-        n = -(-d // w)
-        rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, n * w - d)])
-        return [rows[..., p * w:(p + 1) * w] for p in range(n)]
-
-    @classmethod
-    def _scatter(cls, plane, l, where, rows):
-        """``rows`` written into a pool's ``plane`` array at layer ``l``,
-        a scatter a plane (plane ``p`` of layer ``l`` is block
-        ``p·layers + l``), at ``where`` (index arrays after the layer)."""
-        parts = cls._planes(rows, plane)
-        layers = plane.shape[0] // len(parts)
-        for p, part in enumerate(parts):
-            plane = plane.at[(p * layers + l if p else l,) + where].set(part)
-        return plane
-
     def _per_kv_shard(self, fn, in_specs, out_specs):
-        """``fn`` over a pool's planes (``"pool"``), rows of heads
+        """``fn`` over pool arrays (``"pool"``), rows of heads
         (``"heads"``) and replicated arguments (``"all"``), as it runs:
         as it is on one chip; under tensor-parallel serving on each chip's
         KV heads and the query heads of their groups (heads are
         independent), through a ``shard_map`` over the whole mesh.  So the
-        chunk rows address the LOCAL pool as the paged kernel does, page
-        matrices ``[blocks·N, bs·kv_h, w]`` (a bitcast of the carried
-        buffer).  Under GSPMD that view would merge the sharded head dim
-        with the tokens, and the ``(block, page)`` view it forced made
-        XLA:TPU re-lay the carried pool out tokens-minor around every
-        access (a pool of fewer KV heads than a vreg has sublanes: 2.5 GB
-        each way of every call at the hybrid cell's size before PR 31, and
-        inside every layer of the step that carries chunks, where the
-        kernel's custom call pins the other layout)."""
+        chunk rows address the LOCAL pool as the paged kernel does, through
+        its page matrices (a bitcast of the carried buffer:
+        ``KVLayout.write_pages`` / ``gather_pages``).  Under GSPMD that
+        view would merge the sharded head dim with the tokens, and the
+        ``(block, page)`` view it forced made XLA:TPU re-lay the carried
+        pool out tokens-minor around every access (a pool of fewer KV
+        heads than a vreg has sublanes: 2.5 GB each way of every call at
+        the hybrid cell's size before PR 31, and inside every layer of the
+        step that carries chunks, where the kernel's custom call pins the
+        other layout)."""
         if self._tp == 1:
             return fn
-        from jax.sharding import PartitionSpec as P
-
-        from ...parallel.mesh import AXIS_TENSOR
-        from ...utils.jax_compat import shard_map
-
         spec = {"pool": P(None, None, None, AXIS_TENSOR, None),
                 "heads": P(None, AXIS_TENSOR, None), "all": P()}
         return shard_map(
             fn, mesh=self.mesh, in_specs=jax.tree.map(spec.get, in_specs),
             out_specs=jax.tree.map(spec.get, out_specs), check_vma=False,
             axis_names=set(self.mesh.axis_names))
-
-    def _ring_pages(self, ring_base, logical):
-        """Pages of a recycled pool: ``ring_base [R]`` (a row's ring's
-        first page; 0: the row holds none) and logical page numbers
-        ``[R, n]`` → ``[R, n]``: page ``j % ring_blocks`` of the ring, and
-        the scratch page 0 for a row without one or a page before the
-        sequence's first."""
-        base = ring_base[:, None]
-        return jnp.where((base > 0) & (logical >= 0),
-                         base + logical % self.cache_config.ring_blocks, 0)
 
     def _scan_layers(self, params, pools, x, positions_flat, write_fn,
                      attend_fn):
@@ -420,8 +341,6 @@ class RaggedInferenceEngineV2:
         whole it leaves out of ``layers()`` and reads from ``params``,
         which its hooks are given (as the pools are read at ``lk``
         here)."""
-        from ...telemetry import numerics
-
         ad = self.adapter
         pattern = ad.pattern
         # a layer's index within its kind's pool: the leading layers of
@@ -461,124 +380,65 @@ class RaggedInferenceEngineV2:
         """A round's prefill chunks as rows of the one-step program: up to
         ``Bp`` sequences' chunks, ``tokens [Bp, C]`` at positions
         ``start_pos[r] + [0..C)``; rows beyond the live chunk count carry
-        all-zero tables (page 0 = scratch).  ``kb`` (static) is the page
-        bucket they attend over — the first ``kb`` pages of each row's
-        table cover every key written so far, so the gather/mask is
-        O(allocated), not O(max_seq_len).  ``rings [Bp]``: each row's
-        ring's first page, where a kind recycles (else None); such a kind
-        gathers the window's pages and the chunk's and no bucket.  A latent
-        kind (``v_in_k``) gathers nothing: its chunk rows are rows of the
-        paged kernel, ``T`` tokens a row (:meth:`_paged_attend`).  Returns
-        (positions ``[Bp·C]``, the rows' part ``(Bp·C, write_fn,
-        attend_fn)``: what :meth:`_layer_step` needs for them,
-        :meth:`_beside`)."""
+        all-zero tables (page 0 = scratch).  ``kb`` (static): the page
+        bucket they attend over (:meth:`_prefill_bucket`); ``rings [Bp]``:
+        each row's ring's first page, where a kind recycles (else None).
+        Which pages a kind's rows write and gather, or that they gather
+        none and are rows of the paged kernel, ``T`` tokens a row
+        (:meth:`_paged_attend`), is its layout's.  Returns (positions
+        ``[Bp·C]``, the rows' part ``(Bp·C, write_fn, attend_fn)``: what
+        :meth:`_layer_step` needs for them, :meth:`_beside`)."""
         ad = self.adapter
         Bp, C = tokens.shape
-        bs = self.cache_config.block_size
-        mb = int(kb)  # attend over the bucket, not the full table width
         positions = start_pos[:, None] + jnp.arange(C)[None, :]  # [Bp, C]
-        page_cursor = start_pos // bs  # chunks & starts are page-aligned
+        page_cursor = start_pos // self.cache_config.block_size  # aligned
 
-        from ...ops.masks import local_attention_mask
+        def mask_over(kind, kpos):
+            """``[Bp, 1(head), C, keys]`` over the gathered keys at
+            ``kpos``: every row's the same ``[keys]``, or a row's own
+            ``[Bp, keys]``, negative before its sequence's start."""
+            if kpos is None:
+                return None
+            own = kpos.ndim == 2
 
-        def pages_and_mask(kind):
-            """For a kind: the pages this chunk's rows are written to
-            ``[Bp·C/bs]``, the pages attended over ``[Bp, n]`` and the mask
-            ``[Bp, 1(head), C, n·bs]`` over their keys."""
-            if kind.ring:
-                # the window's pages before the chunk, then the chunk's:
-                # logical numbers, negative before the sequence's start
-                reach = -(-kind.window // bs)
-                logical = (page_cursor[:, None] - reach
-                           + jnp.arange(reach + C // bs)[None, :])
-                kpos = (logical[:, :, None] * bs
-                        + jnp.arange(bs)[None, None, :]).reshape(Bp, -1)
-                mask = jax.vmap(
-                    lambda p, k: local_attention_mask(
-                        p, k, causal=True, window=kind.window) & (k >= 0)[None]
-                )(positions, kpos)
-                return (self._ring_pages(rings, logical[:, reach:]
-                                         ).reshape(-1),
-                        self._ring_pages(rings, logical), mask[:, None])
-            # per-row page slice for this chunk's writes: [Bp, C//bs]
-            pages = jax.vmap(
-                lambda row, cur: jax.lax.dynamic_slice(
-                    row, (cur,), (C // bs,)))(tables, page_cursor)
-            if kind.v_in_k:     # its rows attend through the paged kernel
-                return pages.reshape(-1), None, None
-            karange = jnp.arange(mb * bs)
-            mask = jax.vmap(lambda p: local_attention_mask(
-                p, karange, causal=True, window=kind.window))(positions)
-            return pages.reshape(-1), tables[:, :mb], mask[:, None]
+            def one(p, k):
+                mask = local_attention_mask(p, k, causal=True,
+                                            window=kind.window)
+                return mask & (k >= 0)[None] if own else mask
 
-        plans = {name: pages_and_mask(kind)
-                 for name, kind in self.kinds.items()}
-        written = {name: plan[0] for name, plan in plans.items()}
-        attended = {name: plan[1] for name, plan in plans.items()}
-        masks = {name: plan[2] for name, plan in plans.items()}
+            return jax.vmap(one, in_axes=(0, 0 if own else None))(
+                positions, kpos)[:, None]
 
-        def as_pages(plane):
-            """A pool's ``plane`` array ``[blocks, N, bs, kv_h, w]`` as page
-            matrices ``[blocks·N, bs·kv_h, w]``: the paged kernel's own
-            view, a bitcast, which every pool is scattered into and
-            gathered from (:meth:`_per_kv_shard`)."""
-            blocks, pages, _, _, w = plane.shape
-            return plane.reshape(blocks * pages, -1, w)
+        written, attended, masks = {}, {}, {}
+        for name, layout in self.layouts.items():
+            written[name], attended[name], kpos = layout.chunk_pages(
+                tables, page_cursor, rings, C, int(kb))
+            masks[name] = mask_over(self.kinds[name], kpos)
 
         def write_fn(pool, kind, l, kk, vv):
             # whole pages, scattered at (l, page) into the carried pool
-            def written_into(plane, rows, l, pages):
-                rows = rows.reshape((Bp * (C // bs), bs) + rows.shape[1:])
-                view = as_pages(plane)
-                for p, part in enumerate(self._planes(rows, plane)):
-                    block = p * kind.layers + l if p else l
-                    view = view.at[pages + block * plane.shape[1]].set(
-                        part.reshape((-1,) + view.shape[1:]))
-                return view.reshape(plane.shape)
-
-            # K and V, or K alone where the kind's V lies in its K rows
-            write = self._per_kv_shard(
-                written_into, ("pool", "heads", "all", "all"), "pool")
-            rows = {"k": kk, "v": vv}
-            return {name: write(plane, rows[name], jnp.asarray(l, jnp.int32),
-                                written[kind.name])
-                    for name, plane in pool.items()}
+            return self.layouts[kind.name].write_pages(
+                pool, l, written[kind.name], kk, vv, self._per_kv_shard)
 
         def attend_fn(q, pool, kind, l, sink):
-            if kind.v_in_k:
-                # a latent kind: the chunk's tokens are rows of the paged
-                # kernel through their sequence's table, T consecutive
-                # tokens a row (the row's length is its last token's): they
-                # share each fetched page and the row's last, half-empty
-                # step (PERF.md §6, PR 46).  With every query head on the
-                # one cached row the kernel is bound by its products either
-                # way, and gathered here the float32 scores of 128 heads
-                # over the bucket crossed HBM three times: 15 ms of a 43 ms
-                # step against the kernel's 7 (PERF.md §6, PR 40)
+            layout = self.layouts[kind.name]
+            if layout.chunks_through_kernel:
+                # rows of the paged kernel through their sequence's table,
+                # T consecutive tokens a row (the row's length is its last
+                # token's): they share each fetched page and the row's
+                # last, half-empty step (PERF.md §6, PR 46)
                 T = query_tokens_per_row(
-                    C, *self._kernel_shapes(pool, kind, tables.shape[1]))
+                    C, *layout.kernel_shapes(tables.shape[1], self._tp))
                 out = self._paged_attend(
                     q.reshape((Bp * C // T, T) + q.shape[1:]), pool, kind, l,
                     sink, jnp.repeat(tables, C // T, axis=0),
                     positions.reshape(-1, T)[:, -1] + 1)
                 return out.reshape((Bp * C,) + out.shape[2:])
-            # gather only the attended pages (a bucket: every key written
-            # so far lives in the first kb pages of each row's table) and
-            # attend chunk-queries over them — O(allocated), not
-            # O(max_seq_len).  One gather out of the carried buffer's flat
-            # view, never a layer sliced out first
-            def gathered(plane, l, pages, d):
-                view = as_pages(plane)
-                parts = [view[pages + (p * kind.layers + l if p else l)
-                              * plane.shape[1]]
-                         for p in range(plane.shape[0] // kind.layers)]
-                rows = parts[0] if len(parts) == 1 else jnp.concatenate(
-                    parts, axis=-1)[..., :d]
-                return rows.reshape(Bp, pages.shape[1] * bs, -1, d)
 
-            def attended_over(q, l, pages, mask, k, v):
-                kf = gathered(k, l, pages, kind.k_dim)
-                vf = gathered(v, l, pages, kind.v_dim)
+            # gather only the attended pages and attend chunk-queries over
+            # them — O(allocated), not O(max_seq_len)
+            def attended_over(q, l, pages, mask, pool):
+                kf, vf = layout.gather_pages(pool, l, pages)
                 heads = q.shape[1]
                 n_rep = heads // kf.shape[2]
                 if n_rep > 1:
@@ -607,25 +467,12 @@ class RaggedInferenceEngineV2:
                 raise NotImplementedError(
                     "a sink under tensor-parallel serving")
             return self._per_kv_shard(
-                attended_over,
-                ("heads", "all", "all", "all", "pool", "pool"), "heads")(
-                    q, jnp.asarray(l, jnp.int32), attended[kind.name],
-                    masks[kind.name], pool["k"], pool["v"])
+                attended_over, ("heads", "all", "all", "all",
+                                jax.tree.map(lambda _: "pool", pool)),
+                "heads")(q, jnp.asarray(l, jnp.int32), attended[kind.name],
+                         masks[kind.name], pool)
 
         return positions.reshape(-1), (Bp * C, write_fn, attend_fn)
-
-    def _kernel_shapes(self, pool, kind, max_blocks):
-        """What the paged kernel's two rules (``pages_per_step``,
-        ``query_tokens_per_row``) are given for a kind's pool, after their
-        leading argument(s): page size, KV heads and query heads of a TP
-        shard, a K row as held, item size, table width, a V row (0: in
-        the K row), window."""
-        k = pool["k"]       # [layers · planes, N, bs, kv_h, w]
-        return (k.shape[2], kind.kv_heads // self._tp,
-                self.adapter.num_heads // self._tp,
-                k.shape[0] // kind.layers * k.shape[-1], k.dtype.itemsize,
-                max_blocks, 0 if kind.v_in_k else pool["v"].shape[-1],
-                kind.window)
 
     def _paged_attend(self, q, pool, kind, l, sink, tables, lengths):
         """Queries ``q [R, h, k_dim]``, a token a row, over layer ``l`` of
@@ -633,28 +480,17 @@ class RaggedInferenceEngineV2:
         first ``lengths[r]`` keys through ``tables[r]`` (the decode rows of
         every kind); or ``q [R, T, h, k_dim]``, ``T`` consecutive tokens a
         row and ``lengths[r]`` the last one's (the chunk rows of a latent
-        kind)."""
-        # the kernel fetches pages from HBM by page id: it gets
-        # the whole pool's flat view, and the layer's offset is
-        # folded into the tables it prefetches anyway
-        flat = self._flat_pool(pool)
-        pages = pool["k"].shape[1]
-        k_planes = pool["k"].shape[0] // kind.layers
-        # V: a pool of one plane, or the K row's leading numbers
-        v_in_k = kind.v_dim if kind.v_in_k else 0
-        if not v_in_k and pool["v"].shape[0] != kind.layers:
-            raise NotImplementedError(
-                f"V rows of {kind.v_dim}: wider than one plane")
-        layer_tables = tables + l * pages
+        kind).  The operands are the layout's; the route and its witnesses
+        are decided here."""
+        layout = self.layouts[kind.name]
+        k, v, layer_tables, options, widths = layout.kernel_operands(
+            pool, l, tables)
+        shapes = layout.kernel_shapes(tables.shape[1], self._tp)
+        kv_heads, heads = shapes[1:3]       # of a TP shard
         # what paged_decode_attention will run for these shapes
         # on this platform, by its own test
-        impl = paged_decode_impl(
-            self.adapter.num_heads // self._tp, kind.kv_heads // self._tp,
-            None, flat["k"].shape[-1],
-            v_in_k or flat["v"].shape[-1])
+        impl = paged_decode_impl(heads, kv_heads, None, *widths)
         if impl == "reference" and jax.default_backend() == "tpu":
-            from ...telemetry import get_telemetry
-
             get_telemetry().inc_counter(
                 "inference/attn/reference_fallbacks",
                 help="layers traced on a TPU whose paged decode "
@@ -666,29 +502,19 @@ class RaggedInferenceEngineV2:
                 name, tokens = f"{name}/chunk", q.shape[1]
                 self.last_attn_query_tokens[kind.name] = tokens
             self.last_attn_pages_per_step[name] = pages_per_step(
-                *self._kernel_shapes(pool, kind, tables.shape[1]), tokens)
+                *shapes, tokens)
         if self._tp > 1:
-            # the Pallas kernel runs PER TP SHARD via an explicit
-            # shard_map over the kv-head axis (heads independent,
-            # zero cross-rank comm)
-            from ...ops.pallas.paged_attention import (
-                paged_decode_attention_tp)
-
+            # the kernel runs PER TP SHARD (heads are independent)
             if sink is not None:
                 raise NotImplementedError(
                     "a sink under tensor-parallel serving")
             self.last_attn_path = f"{impl}_tp_shard_map"
             return paged_decode_attention_tp(
-                q, flat["k"], flat["v"], layer_tables, lengths,
-                mesh=self.mesh, window=kind.window)
+                q, k, v, layer_tables, lengths, mesh=self.mesh,
+                window=kind.window)
         self.last_attn_path = impl
-        # plane p of a layer's K lies a whole plane (every layer's
-        # pages) further on than plane p - 1
-        return paged_decode_attention(
-            q, flat["k"], flat.get("v"), layer_tables, lengths,
-            window=kind.window, sink=sink, k_planes=k_planes,
-            plane_stride=kind.layers * pages, v_in_k=v_in_k,
-            scale=kind.scale)
+        return paged_decode_attention(q, k, v, layer_tables, lengths,
+                                      sink=sink, **options)
 
     def _decode_rows(self, tables_of, wp):
         """The decode rows of a step: row ``r`` writes its K and V at
@@ -704,10 +530,8 @@ class RaggedInferenceEngineV2:
 
         def write_fn(pool, kind, l, kk, vv):
             # one scatter of [B, kv_h, d] rows at (l, page, offset)
-            where = (page_ids[kind.name], offsets)
-            rows = {"k": kk, "v": vv}
-            return {name: self._scatter(plane, l, where, rows[name])
-                    for name, plane in pool.items()}
+            return self.layouts[kind.name].write_rows(
+                pool, l, page_ids[kind.name], offsets, kk, vv)
 
         def attend_fn(q, pool, kind, l, sink):
             return self._paged_attend(q, pool, kind, l, sink,
@@ -750,8 +574,7 @@ class RaggedInferenceEngineV2:
         positions clamp at ``max_pos`` (a slot that hit EOS/budget inside
         the burst only scribbles its own reserved pages; the host discards
         its surplus tokens).  ``rings [B]``: each row's ring's first page,
-        where a kind recycles (else None): such a kind's block table is
-        the ring, repeated.
+        where a kind recycles (else None).
 
         ``fed``: ``(source [B], newest [B + Bp])``.  A row's first input
         token is ``tokens[r]`` where ``source[r] < 0``, else the previous
@@ -761,26 +584,20 @@ class RaggedInferenceEngineV2:
         ``chunks`` (the one-step program only): the round's prefill chunks
         ``(tokens [Bp, C], tables, start_pos, last_idx, rings)``, whose
         ``Bp·C`` rows ride in the step IN FRONT of the decode rows
-        (:meth:`_chunk_rows` under the page bucket ``kb``): every layer's
-        weights, and the head's, are read once for both, and the chunks'
-        last valid rows are sampled beside the decode rows.
+        (:meth:`_chunk_rows` under the page bucket ``kb``); their last
+        valid rows are sampled beside the decode rows.
 
         Returns (token ids ``[n_steps, B]``, pools, the gate's stats
         packed or None, the chunks' sampled tokens ``[Bp]`` or None, the
         rows' newest tokens ``[B + Bp]``: the last step's, then the chunks'
         or zeros)."""
-        from ...telemetry import numerics
-
         ad = self.adapter
         B = tokens.shape[0]
         source, newest = fed
         tokens = jnp.where(source < 0, tokens,
                            newest[jnp.maximum(source, 0)])
-        tables_of = {
-            kind.name: self._ring_pages(
-                rings, jnp.broadcast_to(jnp.arange(tables.shape[1])[None, :],
-                                        tables.shape))
-            if kind.ring else tables for kind in self.kinds.values()}
+        tables_of = {name: layout.row_tables(tables, rings)
+                     for name, layout in self.layouts.items()}
         if chunks is not None:
             if n_steps != 1:
                 raise ValueError("chunks ride in the one-step program")
@@ -856,8 +673,6 @@ class RaggedInferenceEngineV2:
         (fetched entry by entry they cost the serving round 7.5 ms of
         host, PERF.md PR 27).  Which columns hold what is a fact of the
         trace, kept in ``_moe_columns``."""
-        from ...telemetry import numerics
-
         coll = numerics.active()
         named = coll.harvest() if coll is not None else None
         if not named:
@@ -944,18 +759,13 @@ class RaggedInferenceEngineV2:
         runs on the reader's thread, beside a round, and takes no lock:
         the free list's length, the slots and ``last_moe_stats`` (a dict
         replaced whole) are safe to read there."""
-        from ...telemetry import get_telemetry
-
         tel = get_telemetry()
         if not tel.enabled:
             return
-        sched = self.scheduler
-        tokens = (self.cache_config.num_blocks - 1
-                  - sched.allocator.num_free)
-        for kind in self.kinds.values():
+        for name, layout in self.layouts.items():
             tel.set_gauge(
-                f"inference/kv/pages_in_use/{kind.name}",
-                float(sched.ring_pages_in_use() if kind.ring else tokens),
+                f"inference/kv/pages_in_use/{name}",
+                float(layout.pages_in_use(self.scheduler)),
                 help="pages of the kind's pool that live sequences hold "
                      "(a recycled kind: at most a ring a sequence), each "
                      "over all the kind's layers")
@@ -989,10 +799,8 @@ class RaggedInferenceEngineV2:
         """Around a program call: the collector only matters at trace time
         (the first call of a shape); cached calls just return the stats
         the traced program already threads out."""
-        from ...telemetry import numerics
-
         return (numerics.collecting(self._moe_coll)
-                if self._moe_coll is not None else _null_ctx())
+                if self._moe_coll is not None else contextlib.nullcontext())
 
     def moe_load_imbalance(self) -> float:
         """Router-facing hot-expert signal: max/mean expert load of the
@@ -1023,10 +831,8 @@ class RaggedInferenceEngineV2:
         O(allocated) gather cost."""
         bs = self.cache_config.block_size
         mb = self.cache_config.max_blocks_per_seq
-        if all(k.ring or k.v_in_k for k in self.kinds.values()):
-            # no kind gathers a bucket (a ring gathers its window, a latent
-            # kind's chunk rows walk their pages in the kernel): one program
-            return mb
+        if not any(lay.gathers_bucket for lay in self.layouts.values()):
+            return mb       # no kind gathers a bucket: one program
         need = max((ch.start_pos + self.chunk) // bs for ch in chunks)
         kb = max(self.chunk // bs, 1)
         while kb < need:
@@ -1034,41 +840,27 @@ class RaggedInferenceEngineV2:
         return min(kb, mb)
 
     def step(self, temperature: float = 0.0,
-             eos_token_id: Optional[int] = None,
-             rng: Optional[np.random.Generator] = None) -> int:
-        """One scheduler step, complete when it returns: ONE program call,
-        a decode step that carries the round's prefill chunks while prefill
-        work exists (so SplitFuse keeps interleaving chunks with decodes),
-        a burst of ``decode_burst`` steps once all prompts are in, fetched
-        and committed (:meth:`step_ahead` + :meth:`settle`: nothing is in
-        flight before or after).  Returns the number of tokens processed."""
-        del rng  # sampling is in-graph now; kept for API compat
+             eos_token_id: Optional[int] = None) -> int:
+        """One scheduler step, complete when it returns: ONE program call
+        (module docstring), fetched and committed (:meth:`step_ahead` +
+        :meth:`settle`: nothing is in flight before or after).  Returns
+        the number of tokens processed."""
         return self.step_ahead(temperature, eos_token_id) + self.settle()
 
     def step_ahead(self, temperature: float = 0.0,
                    eos_token_id: Optional[int] = None) -> int:
         """:meth:`step` for a caller that comes back: the round's call is
         planned, packed and dispatched FIRST, behind the previous round's
-        call, which is still running; only then is that one fetched and
-        committed.  The device starts the new call the moment the old one
-        ends, and the host's whole chain between two calls (read-back,
-        commit, plan, packs, dispatch, and whatever the caller does
-        between two rounds) runs under the device.  At most two calls are
+        call, and only then is that one fetched and committed (module
+        docstring, "A second call in flight").  At most two calls are
         uncommitted at any time, one when this returns.  Returns the
         tokens committed in THIS call: the previous call's chunks and
-        decode tokens.
-
-        The new call is planned from what has been dispatched
-        (``scheduler.plan_step``), and a row whose newest token the
-        previous call has not delivered yet takes it on the device
-        (:meth:`_dispatch`).  A round with chunks runs the one-step decode
+        decode tokens.  A round with chunks runs the one-step decode
         program with the chunks' rows riding in it
         (:meth:`_decode_burst_fn`), the decode rows dead where nothing
         decodes yet; a round without runs the burst.  Where nothing can be
         planned (every budget ends in the call in flight), that call is
         committed and the next round plans again."""
-        from ...telemetry import get_telemetry
-
         tel = get_telemetry()
         with tel.span("inference/step") as sp:
             with tel.span("inference/plan"):
@@ -1086,13 +878,10 @@ class RaggedInferenceEngineV2:
     def settle(self) -> int:
         """Fetch and commit everything in flight, oldest first; returns
         the tokens it yielded.  What a call computed for a request that is
-        no longer where the call left it (finished, cancelled, preempted,
-        moved to another slot) is passed over (:meth:`_settle`): the
-        request prefills that chunk, or decodes that position, again if it
-        resumes.  After it ``req.generated``, ``req.prefilled`` and the
-        pool are what a loop of :meth:`step` would have left."""
-        from ...telemetry import get_telemetry
-
+        no longer where the call left it is passed over (:meth:`_settle`):
+        the request prefills that chunk, or decodes that position, again
+        if it resumes.  After it ``req.generated``, ``req.prefilled`` and
+        the pool are what a loop of :meth:`step` would have left."""
         tel = get_telemetry()
         n_tokens, done = self._settle(tel, keep=0)
         if tel.enabled:
@@ -1104,15 +893,13 @@ class RaggedInferenceEngineV2:
         ``keep`` newest → (the tokens they yielded, what
         :meth:`_count_call` counts of each: empty with the hub off).  The
         spans of the wait and the commit carry the number the call was
-        dispatched under.
-
-        **A call is committed by what it was packed under**: a chunk only
-        if its request is still prefilling at the chunk's start, a decode
-        row only if its request is still running in the row's slot at the
-        row's position.  So the column read is the slot's AS PACKED, a
+        dispatched under.  **A call is committed by what it was packed
+        under** (:class:`_Call`): a chunk only if its request is still
+        prefilling at the chunk's start, a decode row only if its request
+        is still running in the row's slot at the row's position.  So a
         request re-seated in a freed slot never receives its predecessor's
-        token, and one that was cancelled, preempted, moved or ended
-        (an EOS inside the call before) between dispatch and commit keeps
+        token, and one that was cancelled, preempted, moved or ended (an
+        EOS inside the call before) between dispatch and commit keeps
         nothing of the call: the row is counted as overrun."""
         n_tokens, done = 0, []
         while len(self._inflight) > keep:
@@ -1233,29 +1020,15 @@ class RaggedInferenceEngineV2:
                 "decode_rows": len(c.decode), "chunk_tokens": written,
                 "accepted": accepted, "kb": c.kb, "wait_s": wait_s})
 
-    def _ring_bases(self, rows: int, requests) -> Optional[np.ndarray]:
-        """``[rows]``: the first page of each request's ring at its row
-        (``(row, request)`` pairs), 0 elsewhere; None where no kind
-        recycles."""
-        if not self.cache_config.ring_blocks:
-            return None
-        base = np.zeros((rows,), np.int32)
-        for row, req in requests:
-            base[row] = self.cache_config.ring_base(req.ring)
-        return base
-
     def _count_recycled(self, tel: Any, first_page, pages) -> None:
         """``pages`` logical pages a sequence from ``first_page`` on (arrays
         over sequences) were begun by a call: those past a ring's length
         overwrote a page that fell out of the window."""
-        ring = self.cache_config.ring_blocks
-        if not ring:
+        recycled = self.cache_config.pages_recycled(first_page, pages)
+        if recycled is None:
             return
-        first_page, pages = np.asarray(first_page), np.asarray(pages)
         tel.inc_counter(
-            "inference/kv/window_pages_recycled",
-            v=float(np.clip(first_page + pages - np.maximum(first_page, ring),
-                            0, None).sum()),
+            "inference/kv/window_pages_recycled", v=recycled,
             help="pages of the window layers' rings overwritten with a "
                  "later page of the same sequence (logical pages: each is "
                  "one page in every window layer)")
@@ -1275,31 +1048,25 @@ class RaggedInferenceEngineV2:
                 tables[i] = self.scheduler.table_row(ch.request)
                 start[i] = ch.start_pos
                 last[i] = max(ch.n_valid - 1, 0)
-            rings = self._ring_bases(
-                Bp, ((i, ch.request) for i, ch in enumerate(chunks)))
+            rings = self.cache_config.ring_bases(
+                Bp, ((i, ch.request.ring) for i, ch in enumerate(chunks)))
             kb = self._prefill_bucket(chunks)
         return (tokens, tables, start, last, rings), kb
 
     def _count_cache_traffic(self, tel: Any, kv_lens, max_pos, burst,
                              chunk_starts=()) -> None:
-        """What the paged kernel reads of each kind's cache in a call, and
-        the ring pages its decode steps begin, from what the call packed:
-        a decode step reads a row's keys so far, the one it writes among
-        them, at most the kind's window; of a latent kind the kernel
-        serves the chunk rows too (``chunk_starts``: the live chunks'
-        first positions), row ``t`` of a chunk its ``start + t + 1``
-        keys."""
+        """What the paged kernel reads of each kind's cache in a call
+        (``KVLayout.keys_read``; ``chunk_starts``: the live chunks' first
+        positions), and the ring pages its decode steps begin, from what
+        the call packed: a decode step reads a row's keys so far, the one
+        it writes among them."""
         # [burst, rows]: the length a row attends over at each step
         lengths = np.minimum(kv_lens[None, :] + np.arange(burst)[:, None],
                              max_pos[None, :]) + 1
-        C = self.chunk
-        riding = float(sum(C * start + C * (C + 1) // 2
-                           for start in chunk_starts))
-        for kind in self.kinds.values():
+        for name, layout in self.layouts.items():
             tel.inc_counter(
-                f"inference/attn/keys_read_{kind.name}",
-                v=float(np.minimum(lengths, kind.window or lengths).sum())
-                + (riding if kind.v_in_k else 0.0),
+                f"inference/attn/keys_read_{name}",
+                v=layout.keys_read(lengths, self.chunk, chunk_starts),
                 help="keys a layer of the kind attends over through the "
                      "paged kernel, summed over decoding rows and decode "
                      "steps and, of a latent kind, over the chunk rows (a "
@@ -1319,8 +1086,7 @@ class RaggedInferenceEngineV2:
         scheduler's cursors over what has been dispatched).  Where the
         call in front is not committed yet, a row's newest token is still
         on the device: the row names where in that call's ``newest`` it
-        lies (``source``: the last step's ``[B]``, then the chunks'
-        ``firsts [Bp]``), and the program takes it from there; every other
+        lies (``source``; :meth:`_decode_burst_fn`'s ``fed``); every other
         row carries its token from the host (``source`` -1)."""
         # exactly TWO decode step counts ever compile (1, which carries
         # the chunks under their page bucket, and decode_burst):
@@ -1358,7 +1124,8 @@ class RaggedInferenceEngineV2:
                 max_pos[s] = len(req.prompt) + req.max_new_tokens - 1
                 tables[s] = self.scheduler.table_row(req)
                 rows.append(_Row(req, s, position))
-            rings = self._ring_bases(B, ((r.slot, r) for r in decode))
+            rings = self.cache_config.ring_bases(
+                B, ((r.slot, r.ring) for r in decode))
             self.scheduler.dispatched(chunks, decode, burst)
             self._calls += 1
         with tel.span("inference/decode_burst/dispatch",

@@ -20,6 +20,7 @@ from typing import Deque, List, Optional
 
 import numpy as np
 
+from ...telemetry import get_telemetry
 from .kv_cache import BlockAllocator, KVCacheConfig
 
 
@@ -122,8 +123,6 @@ class RaggedScheduler:
             if cache_config.ring_blocks else ())
         # the occupancy gauges are worked out when the registry is read,
         # not in every plan; the hub holds the hook weakly
-        from ...telemetry import get_telemetry
-
         get_telemetry().add_collect_hook(self._publish_gauges)
 
     def _make_allocator(self, num_blocks: int) -> BlockAllocator:
@@ -165,8 +164,6 @@ class RaggedScheduler:
                       max_new_tokens=max_new_tokens)
         self._uid += 1
         self.waiting.append(req)
-        from ...telemetry import get_telemetry
-
         get_telemetry().inc_counter("inference/requests",
                                     help="requests admitted to the queue")
         return req
@@ -272,8 +269,6 @@ class RaggedScheduler:
         """The registry's collect hook: it runs on the reader's thread,
         beside a round, and takes no lock; what it reads (queue lengths,
         the slots, the free list's length) is safe to read there."""
-        from ...telemetry import get_telemetry
-
         tel = get_telemetry()
         if tel.enabled:
             for name, v in self.telemetry_gauges().items():
@@ -351,18 +346,6 @@ class RaggedScheduler:
                 req.ahead_tokens = max(req.ahead_tokens - 1, 0)
                 self._maybe_finish(req, int(first_token), eos_token_id)
 
-    def decode_done(self, requests: List[Request], tokens: np.ndarray,
-                    eos_token_id: Optional[int] = None) -> None:
-        """Single-step acceptance — a burst of 1 (kept for callers that
-        decode one token per dispatch)."""
-        if not requests:
-            return
-        order = {r.slot: i for i, r in enumerate(requests)}
-        row = np.zeros((1, max(order) + 1), tokens.dtype)
-        for req in requests:
-            row[0, req.slot] = tokens[order[req.slot]]
-        self.decode_burst_done(requests, row, eos_token_id)
-
     def decode_burst_done(self, requests: List[Request], tokens: np.ndarray,
                           eos_token_id: Optional[int] = None) -> int:
         """Accept an in-graph burst's ``[n_steps, B]`` token matrix: each
@@ -390,8 +373,6 @@ class RaggedScheduler:
             req.state = RequestState.DONE
             self._give_back(req)
             self._vacate(req)
-            from ...telemetry import get_telemetry
-
             get_telemetry().inc_counter(
                 "inference/requests_done",
                 help="requests finished (EOS or budget)")
@@ -410,8 +391,6 @@ class RaggedScheduler:
             self._give_back(req)
         self._vacate(req)
         req.state = RequestState.DONE
-        from ...telemetry import get_telemetry
-
         get_telemetry().inc_counter(
             "inference/requests_cancelled",
             help="requests aborted before completion")

@@ -9,8 +9,9 @@ received pages.  This module is the wire format and the two pool
 boundaries:
 
 * :func:`page_payload` — one pool page as transportable bytes.  Real
-  engines ship the page's K and V planes across all layers
-  (``pool[kv][:, block]``); the host-only synthetic engine ships a
+  engines ship the page's K and V planes across all layers, as the
+  cache's layout hands them over (``kv_cache.page_arrays``); the
+  host-only synthetic engine ships a
   deterministic token-derived payload so the transfer machinery
   (chunking, checksum gates, rejection) is exercised end-to-end with no
   device.
@@ -64,62 +65,38 @@ def page_payload(engine: Any, prompt: List[int], blocks: List[int],
         raw = arr.tobytes()
         return {"raw": raw + raw, "sha256": _sha256(raw + raw),
                 "dtype": "int32", "shape": [bs], "synthetic": True}
-    block = blocks[page_index]
-    pool = _token_pool(pool)
-    # K then V, or K alone where the kind's V lies in its K rows
-    planes = [np.asarray(pool[name][:, block]) for name in sorted(pool)]
+    from ..inference.v2.kv_cache import page_arrays
+
+    planes = page_arrays(engine.layouts, pool, blocks[page_index])
     raw = b"".join(p.tobytes() for p in planes)
     return {"raw": raw, "sha256": _sha256(raw), "dtype": str(planes[0].dtype),
             "shape": list(planes[0].shape), "synthetic": False}
-
-
-def _token_pool(pools: Dict[str, Any]) -> Dict[str, Any]:
-    """The one pool of a model with one kind of attention layer.  A model
-    that has several (full and window layers) keeps part of a sequence's
-    cache in a ring that no block table names, and is not transferred."""
-    if len(pools) != 1:
-        raise NotImplementedError(
-            f"KV page transfer of a model with {len(pools)} KV pools "
-            f"({sorted(pools)})")
-    return next(iter(pools.values()))
 
 
 def inject_pages(engine: Any, blocks: List[int],
                  staged: Dict[int, Dict[str, Any]]) -> None:
     """Write verified page payloads into ``engine.pool`` at the
     reserved block ids (``staged`` maps page index -> payload dict with
-    ``raw``/``dtype``/``shape``).  One batched scatter per plane — a
-    per-page functional ``.at[].set`` would copy the whole multi-GB
-    pool once per page, under the adopting front-end's lock.  Synthetic
-    payloads are content-free bookkeeping — nothing to write."""
+    ``raw``/``dtype``/``shape``), all in one batched write (it runs under
+    the adopting front-end's lock).  Synthetic payloads are content-free
+    bookkeeping — nothing to write."""
     pool = getattr(engine, "pool", None)
     if pool is None or not staged:
         return
-    import jax.numpy as jnp
+    from ..inference.v2.kv_cache import write_page_arrays
 
-    pool = _token_pool(pool)
-    names = sorted(pool)    # as exported: K then V, or K alone
     ids: List[int] = []
-    pages: Dict[str, List[np.ndarray]] = {name: [] for name in names}
+    pages: List[List[np.ndarray]] = []
     for page_index, p in sorted(staged.items()):
         if p.get("synthetic"):
             continue
-        raw = p["raw"]
-        part = len(raw) // len(names)
-        dt = np.dtype(p["dtype"])
-        shape = tuple(int(s) for s in p["shape"])
+        # equal parts, as exported: K then V, or K alone
+        planes = np.frombuffer(p["raw"], dtype=np.dtype(p["dtype"]))
         ids.append(blocks[page_index])
-        for i, name in enumerate(names):
-            pages[name].append(np.frombuffer(
-                raw[i * part:(i + 1) * part], dtype=dt).reshape(shape))
-    if not ids:
-        return
-    idx = jnp.asarray(ids)
-    # page planes are [L, bs, kh, hd]; stacked on a new axis 1 they
-    # line up with pool[:, idx] -> [L, n, bs, kh, hd]
-    for name in names:
-        pool[name] = pool[name].at[:, idx].set(
-            jnp.asarray(np.stack(pages[name], axis=1)))
+        pages.append(list(planes.reshape(
+            (-1,) + tuple(int(s) for s in p["shape"]))))
+    if ids:
+        write_page_arrays(engine.layouts, pool, ids, pages)
 
 
 def push_pages(rpc_fn, rid: str, payloads: Dict[int, Dict[str, Any]],
