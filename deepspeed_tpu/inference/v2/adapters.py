@@ -8,11 +8,15 @@ compiled programs; an adapter owns exactly the architecture deltas —
 embedding (rotary vs learned positions), norm flavor (RMS vs LayerNorm),
 QKV projection (biasless vs biased), and the FFN/residual block.
 
-All hooks operate on FLAT token batches ``[N, ...]`` and are row-wise, so
-the same adapter serves every program: a burst's ``[B]`` decode rows, and
-the step that carries a round's chunks, whose ``[Bp*C]`` flattened chunk
-rows and ``[B]`` decode rows go through each hook together.  Positions come
-in as an ``[N]`` int32 vector.
+All hooks but one operate on FLAT token batches ``[N, ...]`` and are
+row-wise, so the same adapter serves every program: a burst's ``[B]``
+decode rows, and the step that carries a round's chunks, whose ``[Bp*C]``
+flattened chunk rows and ``[B]`` decode rows go through each hook together.
+Positions come in as an ``[N]`` int32 vector.  The one that is not:
+``mix_chunk`` / ``mix_decode``, the middle of the branch of a model whose
+sequences carry a recurrent STATE beside their keys (below), which is given
+one group of rows at a time with a record of whose they are
+(:class:`StateRows`); its ends, ``mix_in`` / ``mix_out``, are row-wise.
 
 What ``layers(params)`` returns is the ``xs`` of the engine's layer scan,
 and **a scan slices whatever its ``xs`` hold**: each step gets layer
@@ -38,6 +42,22 @@ layer, which is what the base class states from ``kv_heads`` /
 the same interface, not a second path.  :class:`MimoV2Adapter` is the
 family that mixes full and windowed layers;
 :class:`PanguUltraMoeV2Adapter` the one whose kind is a latent cache.
+
+**A second kind of per-sequence state.**  A model whose layers carry a
+recurrent state beside their keys states it as ``state_kinds``
+(:class:`StateKind`: its parts' shapes and types a sequence a layer) and
+gives the branch that reads and moves it in three parts: ``mix_in`` and
+``mix_out`` row-wise over all of a call's rows, and between them a group of
+rows at a time: ``mix_chunk`` for a prefill chunk's rows, given its
+sequences' state as values and handing the new values back, ``mix_decode``
+for decode rows, given besides the pool's array of each part the kind
+states as ``in_place`` (with the layer and the rows' first slot: a kernel's
+operands, as ``KVLayout.kernel_operands`` hands out a kind's pages) and
+handing that array back.  The engine keeps the state in a pool a batch
+slot and writes what comes back where it is kept; which slot is whose is
+the engine's and the layout's.
+:class:`FalconH1V2Adapter` is the family that has one; the others state
+none and their programs hold nothing of it.
 """
 
 from __future__ import annotations
@@ -74,6 +94,35 @@ class AttentionKind:
     #: pages of token capacity.  False keeps every key (and the prefix
     #: cache: PERF.md §7)
     ring: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class StateKind:
+    """A second kind of per-sequence state: what a sequence holds a layer
+    whatever its length (a state-space layer's state, a conv's tail),
+    beside the keys of the same layer.  It lives in a pool indexed by batch
+    slot, not by page (``kv_cache.StateLayout``).  The kind is in every
+    layer of the model."""
+    name: str
+    layers: int
+    #: (part, shape a sequence a layer, type), e.g. ``("ssm", (32, 256,
+    #: 128), float32)``
+    parts: Tuple[Tuple[str, Tuple[int, ...], Any], ...]
+    #: the parts a decode step moves WHERE THEY LIE in the pool (its hook
+    #: gets the pool's array, not the rows' values: ``mix_decode``)
+    in_place: Tuple[str, ...]
+
+
+@dataclasses.dataclass(frozen=True)
+class StateRows:
+    """One group of a call's rows as a branch that is not row-wise needs
+    them: ``tokens`` consecutive rows a sequence, in the sequence's order
+    (1: decode rows; a prefill chunk: its length), of which the first
+    ``valid[r]`` are real (0: the rows are no sequence's).  Whose slot
+    each sequence's state comes from, and that a sequence's first chunk
+    starts from zeros, is the engine's and the layout's."""
+    tokens: int
+    valid: jnp.ndarray              # [R] int32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,6 +194,11 @@ class ModelAdapterV2:
     def pattern(self) -> LayerPattern:
         return LayerPattern((), (self.kinds[0].name,), self.num_layers)
 
+    @property
+    def state_kinds(self) -> Tuple[StateKind, ...]:
+        """The recurrent state a sequence carries beside its keys: none."""
+        return ()
+
     # -- jit-side hooks -----------------------------------------------------
 
     def layers(self, params: Any) -> Any:
@@ -178,6 +232,44 @@ class ModelAdapterV2:
         ``[N, kv_h, v_dim]``) of a layer of ``kind``, with any rotary
         encoding already applied; v is None where the kind's V lies in
         its K rows (``AttentionKind.v_in_k``)."""
+        raise NotImplementedError
+
+    def mix_in(self, lp: Any, x: jnp.ndarray) -> Any:
+        """The layer's branch that is NOT row-wise, where the model states
+        a :class:`StateKind`, in four parts.  This one IS row-wise, over
+        every row of the call at once (so that a weight crosses HBM once
+        for chunk rows and decode rows together): ``x [N, H]`` (the layer's
+        input, as ``qkv`` gets it) → the rows' input to the branch ``[N,
+        …]``."""
+        raise NotImplementedError
+
+    def mix_chunk(self, lp: Any, p: Any, state: Any, rows: StateRows
+                  ) -> Tuple[Any, Any]:
+        """One group of prefill chunks: its rows of :meth:`mix_in`'s result
+        ``p [R·tokens, …]`` and ``state``, the kind's parts for the group's
+        ``R`` sequences ``{part: [R, …]}`` as they come in → (the group's
+        rows ``[R·tokens, …]`` of what :meth:`mix_out` takes, the parts
+        going out, which the engine writes into the pool next)."""
+        raise NotImplementedError
+
+    def mix_decode(self, lp: Any, p: Any, state: Any, held: Any,
+                   rows: StateRows) -> Tuple[Any, Any, Any]:
+        """One group of decode rows, a token a sequence, whose states are
+        one stretch of a layer of the pool
+        (``kv_cache.StateLayout.decode_operands``): ``state {part: [R,
+        …]}``, the values of the parts that are not ``in_place``, and
+        ``held {part: (the pool's array of the part, layer, first slot)}``
+        for those that are, row ``r``'s at ``(layer, first slot + r)`` →
+        (the group's rows of what :meth:`mix_out` takes, ``state`` going
+        out, ``{part: the array}`` with the rows' states moved where they
+        lie)."""
+        raise NotImplementedError
+
+    def mix_out(self, lp: Any, p: Any, y: Any) -> jnp.ndarray:
+        """Row-wise again, over every row of the call: :meth:`mix_in`'s
+        ``p`` and the groups' results ``y`` in the rows' order → what the
+        branch adds to the residual ``[N, H]``, which the engine adds to
+        ``x`` before ``post_attn``."""
         raise NotImplementedError
 
     def post_attn(self, lp: Any, x: jnp.ndarray, attn: jnp.ndarray,
@@ -460,7 +552,54 @@ class PanguUltraMoeV2Adapter(MimoV2Adapter):
         return self.model.qkv(lp, x, positions)
 
 
+class FalconH1V2Adapter(ModelAdapterV2):
+    """Falcon-H1 (``models/falcon_h1.py``): a Mamba-2 mixer beside a
+    grouped-query attention in every layer, both on the same normed input.
+    The attention is the dense kind (rotate-half rotary, no window); the
+    mixer is the branch that is not row-wise (``mix_*``), and what it
+    carries, the state and the conv's tail, is the model's
+    :class:`StateKind`.  The µP multipliers are the model's own."""
+
+    @property
+    def state_kinds(self) -> Tuple[StateKind, ...]:
+        from ...models.falcon_h1 import SSM
+
+        return (StateKind(SSM, self.num_layers, self.model.state_parts(),
+                          in_place=(SSM,)),)
+
+    def embed(self, params, tokens, positions):
+        del positions  # rotary: positions enter at qkv time
+        return self.model.embed(params, tokens)
+
+    def qkv(self, lp, x, positions, kind):
+        del kind  # one kind
+        return self.model.qkv(lp, x, positions)
+
+    def mix_in(self, lp, x):
+        return self.model.mix_in(lp, x)
+
+    def mix_chunk(self, lp, p, state, rows):
+        return self.model.mix_chunk(lp, p, state, rows.tokens, rows.valid)
+
+    def mix_decode(self, lp, p, state, held, rows):
+        return self.model.mix_decode(lp, p, state, held, rows.valid)
+
+    def mix_out(self, lp, p, y):
+        return self.model.mix_out(lp, p, y)
+
+    def post_attn(self, lp, x, attn, params, l):
+        del params, l  # everything is in the layer's slice
+        return self.model.post_attn(lp, x, attn)
+
+    def finalize(self, params, x):
+        return self.model.finalize(params, x)
+
+    def logits(self, params, x):
+        return self.model.logits(params, x)
+
+
 _REGISTRY = {
+    "FalconH1Model": FalconH1V2Adapter,
     "LlamaModel": LlamaV2Adapter,
     "MimoV2Model": MimoV2Adapter,
     "MixtralModel": LlamaV2Adapter,

@@ -41,6 +41,15 @@ moves more of the cache than it reads or writes.  How a row lies in a pool
 ``kv_cache.KVLayout``'s, one a kind (``self.layouts``); this module holds
 the programs and the round.
 
+A model whose sequences carry a recurrent STATE beside their keys (the
+adapter's ``state_kinds``) has a pool a batch slot for it in the same dict
+(``kv_cache.StateLayout``, ``self.state_layouts``), riding the same carry:
+the middle of each layer's branch that is not row-wise (the adapter's
+``mix_chunk`` for chunk rows, ``mix_decode`` for decode rows) is given a
+group of rows with its sequences' state, and what it returns is written
+back in place at ``(layer, slot)`` (``_layer_step``).  A request's slot is its
+batch slot from admission on, so a chunk's state is found as a ring is.
+
 The scan runs over the PERIODS of the adapter's layer pattern, a period's
 layers unrolled in the step, after the pattern's leading layers; a model
 whose layers are all alike has one kind, no leading layer and a period of
@@ -101,8 +110,10 @@ from ...telemetry.memory import get_memory_ledger
 from ...telemetry.perf import get_compile_tracker, tracked_jit
 from ...utils.jax_compat import shard_map
 from ...utils.logging import log_dist
-from .adapters import AttentionKind, ModelAdapterV2, make_adapter
-from .kv_cache import KVCacheConfig, init_kv_pool, kv_layouts
+from .adapters import (AttentionKind, ModelAdapterV2, StateRows,
+                       make_adapter)
+from .kv_cache import (KVCacheConfig, init_kv_pool, kv_layouts,
+                       state_layouts)
 from .scheduler import RaggedScheduler, Request, RequestState
 
 
@@ -196,10 +207,20 @@ class RaggedInferenceEngineV2:
             # clamps out-of-bounds starts, which would silently retarget a
             # chunk's KV writes onto the sequence's EARLIER pages
             raise ValueError("max_seq_len must be a multiple of prefill_chunk")
+        if self._tp > 1 and self.adapter.state_kinds:
+            raise NotImplementedError(
+                f"tensor-parallel serving of a model with recurrent state "
+                f"({[k.name for k in self.adapter.state_kinds]}): its "
+                f"branch is not split over the tensor axis (heads over "
+                f"chips, groups replicated: ROADMAP R7)")
         self.cache_config = self.cache_config.with_rings(
-            self.kinds.values(), max_batch_slots, prefill_chunk)
+            self.kinds.values(), max_batch_slots, prefill_chunk
+        ).with_state(self.adapter.state_kinds, max_batch_slots)
         #: every access to a kind's pool: no code here indexes a pool array
         self.layouts = kv_layouts(self.adapter, self.cache_config)
+        #: the same for the recurrent state a model carries beside its
+        #: keys (empty where it carries none)
+        self.state_layouts = state_layouts(self.adapter, self.cache_config)
         #: the serving plane swaps in its prefix-sharing scheduler here —
         #: same planner surface, refcounted page reservations
         make_sched = scheduler_factory or RaggedScheduler
@@ -280,11 +301,18 @@ class RaggedInferenceEngineV2:
     # ------------------------------------------------------------------
 
     def _layer_step(self, params, lp, l, kind, lk, pools, x_flat,
-                    positions_flat, write_fn, attend_fn):
+                    positions_flat, write_fn, attend_fn, mix_fn=None,
+                    ls=None):
         """Shared per-layer skeleton: qkv → KV write → attention →
         post-attn block.  ``write_fn``/``attend_fn`` are the rows' own:
         :meth:`_decode_rows`, :meth:`_chunk_rows`, or both
-        (:meth:`_beside`).
+        (:meth:`_beside`).  So is ``mix_fn``, where the model carries a
+        recurrent state (else None): the middle of the layer's branch
+        beside attention (the adapter's ``mix_in`` and ``mix_out`` around
+        it are row-wise), on the same input, whose result joins the
+        residual before the post-attn block; the state pools (layer ``ls``
+        of the model) ride ``pools`` and are read and written in place
+        like the others.
 
         ``pools`` holds, for each attention kind, the WHOLE pool, a carry
         of the layer scan; this layer is of ``kind`` and the ``lk``-th of
@@ -300,6 +328,12 @@ class RaggedInferenceEngineV2:
         pools = dict(pools, **{kind.name: write_fn(
             pools[kind.name], kind, lk, kk, vv)})
         attn = attend_fn(q, pools[kind.name], kind, lk, ad.sink(lp))
+        if mix_fn is not None:
+            # row-wise in and out, over all the rows at once; the state is
+            # moved group by group in between
+            p = ad.mix_in(lp, x_flat)
+            y, pools = mix_fn(lp, p, pools, ls)
+            x_flat = x_flat + ad.mix_out(lp, p, y)
         x_flat = ad.post_attn(lp, x_flat, attn, params, l)
         return x_flat, pools
 
@@ -329,7 +363,7 @@ class RaggedInferenceEngineV2:
             axis_names=set(self.mesh.axis_names))
 
     def _scan_layers(self, params, pools, x, positions_flat, write_fn,
-                     attend_fn):
+                     attend_fn, mix_fn=None):
         """The layers of every program: the pattern's leading layers, then
         a scan over its periods.  Carry: the activations and the pools;
         ``xs``: what the adapter's ``layers(params)`` holds, a period's
@@ -349,10 +383,11 @@ class RaggedInferenceEngineV2:
         a_period = {name: pattern.period.count(name) for name in self.kinds}
         with numerics.suppressed():
             seen = dict.fromkeys(self.kinds, 0)
-            for name, lp in zip(pattern.leading, ad.leading_layers(params)):
+            for i, (name, lp) in enumerate(zip(pattern.leading,
+                                               ad.leading_layers(params))):
                 x, pools = self._layer_step(
                     params, lp, None, self.kinds[name], seen[name], pools, x,
-                    positions_flat, write_fn, attend_fn)
+                    positions_flat, write_fn, attend_fn, mix_fn, i)
                 seen[name] += 1
 
         def period(carry, xs):
@@ -366,7 +401,8 @@ class RaggedInferenceEngineV2:
                     params, lp, p * len(pattern.period) + j,
                     self.kinds[name],
                     first[name] + p * a_period[name] + seen[name], pools, x,
-                    positions_flat, write_fn, attend_fn)
+                    positions_flat, write_fn, attend_fn, mix_fn,
+                    len(pattern.leading) + p * len(pattern.period) + j)
                 seen[name] += 1
             return (x, pools), numerics.scan_drain(mark)
 
@@ -376,7 +412,8 @@ class RaggedInferenceEngineV2:
         numerics.scan_collect(stats)  # keep the per-period axis
         return x, pools
 
-    def _chunk_rows(self, tokens, tables, start_pos, rings, kb):
+    def _chunk_rows(self, tokens, tables, start_pos, rings, kb, slots=None,
+                    last_idx=None):
         """A round's prefill chunks as rows of the one-step program: up to
         ``Bp`` sequences' chunks, ``tokens [Bp, C]`` at positions
         ``start_pos[r] + [0..C)``; rows beyond the live chunk count carry
@@ -385,9 +422,13 @@ class RaggedInferenceEngineV2:
         each row's ring's first page, where a kind recycles (else None).
         Which pages a kind's rows write and gather, or that they gather
         none and are rows of the paged kernel, ``T`` tokens a row
-        (:meth:`_paged_attend`), is its layout's.  Returns (positions
-        ``[Bp·C]``, the rows' part ``(Bp·C, write_fn, attend_fn)``: what
-        :meth:`_layer_step` needs for them, :meth:`_beside`)."""
+        (:meth:`_paged_attend`), is its layout's.  ``slots [Bp]``: each
+        row's sequence's state slot where the model carries a state (0: a
+        row that is no sequence's; else None), of whose ``C`` tokens
+        ``last_idx + 1`` are real; a chunk at position 0 starts from
+        zeros.  Returns (positions ``[Bp·C]``, the rows' part ``(Bp·C,
+        write_fn, attend_fn, mix_fn)``: what :meth:`_layer_step` needs for
+        them, :meth:`_beside`)."""
         ad = self.adapter
         Bp, C = tokens.shape
         positions = start_pos[:, None] + jnp.arange(C)[None, :]  # [Bp, C]
@@ -472,7 +513,18 @@ class RaggedInferenceEngineV2:
                 "heads")(q, jnp.asarray(l, jnp.int32), attended[kind.name],
                          masks[kind.name], pool)
 
-        return positions.reshape(-1), (Bp * C, write_fn, attend_fn)
+        mix_fn = None
+        if slots is not None:
+            rows = StateRows(C, jnp.where(slots > 0, last_idx + 1, 0))
+            (name, layout), = self.state_layouts.items()    # one kind
+
+            def mix_fn(lp, p, pools, l):
+                y, state = ad.mix_chunk(lp, p, layout.read_slots(
+                    pools[name], l, slots, start_pos == 0), rows)
+                return y, dict(pools, **{name: layout.write_slots(
+                    pools[name], l, slots, state)})
+
+        return positions.reshape(-1), (Bp * C, write_fn, attend_fn, mix_fn)
 
     def _paged_attend(self, q, pool, kind, l, sink, tables, lengths):
         """Queries ``q [R, h, k_dim]``, a token a row, over layer ``l`` of
@@ -516,12 +568,15 @@ class RaggedInferenceEngineV2:
         return paged_decode_attention(q, k, v, layer_tables, lengths,
                                       sink=sink, **options)
 
-    def _decode_rows(self, tables_of, wp):
+    def _decode_rows(self, tables_of, wp, slots=None):
         """The decode rows of a step: row ``r`` writes its K and V at
         position ``wp[r]`` through its tables (``tables_of``: a kind's
         name → ``[B, max_blocks]``) and attends over the ``wp[r] + 1`` keys
-        so far through the paged kernel.  Returns the rows' part ``(B,
-        write_fn, attend_fn)``: what :meth:`_layer_step` needs for them
+        so far through the paged kernel.  ``slots [B]``: where the model
+        carries a state, row ``r``'s state slot (``r + 1``) if the row is
+        a sequence's and 0 if it is dead (it then moves no state); else
+        None.  Returns the rows' part ``(B, write_fn, attend_fn,
+        mix_fn)``: what :meth:`_layer_step` needs for them
         (:meth:`_beside`)."""
         bs = self.cache_config.block_size
         offsets = wp % bs
@@ -537,23 +592,42 @@ class RaggedInferenceEngineV2:
             return self._paged_attend(q, pool, kind, l, sink,
                                       tables_of[kind.name], wp + 1)
 
-        return wp.shape[0], write_fn, attend_fn
+        mix_fn = None
+        if slots is not None:
+            # the rows are the batch slots in order: their state is one
+            # stretch of the layer, read and written where it lies
+            rows = StateRows(1, (slots > 0).astype(jnp.int32))
+            (name, layout), = self.state_layouts.items()    # one kind
+
+            def mix_fn(lp, p, pools, l):
+                state, held = layout.decode_operands(pools[name], l)
+                y, state, held = self.adapter.mix_decode(lp, p, state, held,
+                                                         rows)
+                return y, dict(pools, **{name: layout.decode_written(
+                    pools[name], l, state, held)})
+
+        return wp.shape[0], write_fn, attend_fn, mix_fn
 
     @staticmethod
     def _beside(parts):
-        """``parts``: ``(rows, write_fn, attend_fn)`` of each group of a
-        program's rows, in the rows' order → the pair over all of them: a
-        layer's K and V written group by group into the one carried pool,
-        each group's queries attended its own way, the results
-        concatenated.  The groups read nothing of each other's writes
-        (different requests, different pages), so their order is free."""
+        """``parts``: ``(rows, write_fn, attend_fn, mix_fn)`` of each group
+        of a program's rows, in the rows' order → the three over all of
+        them: a layer's K and V written group by group into the one
+        carried pool, each group's queries attended its own way, the
+        results concatenated; and, where the model carries a state (else
+        the ``mix_fn``'s are None), each group's branch run on its own
+        rows and state, group by group.  The groups read nothing of each
+        other's writes (different requests, different pages), so their
+        order is free; a slot's state is moved by the one group its
+        request is in, and a dead decode row on a prefilling request's
+        slot leaves it as the chunk before it wrote it."""
         if len(parts) == 1:
             return parts[0][1:]
-        ends = np.cumsum([rows for rows, _, _ in parts]).tolist()
+        ends = np.cumsum([part[0] for part in parts]).tolist()
         spans = list(zip([0] + ends[:-1], ends))
 
         def write_fn(pool, kind, l, kk, vv):
-            for (lo, hi), (_, write, _) in zip(spans, parts):
+            for (lo, hi), (_, write, _, _) in zip(spans, parts):
                 pool = write(pool, kind, l, kk[lo:hi],
                              None if vv is None else vv[lo:hi])
             return pool
@@ -561,20 +635,32 @@ class RaggedInferenceEngineV2:
         def attend_fn(q, pool, kind, l, sink):
             return jnp.concatenate(
                 [attend(q[lo:hi], pool, kind, l, sink)
-                 for (lo, hi), (_, _, attend) in zip(spans, parts)])
+                 for (lo, hi), (_, _, attend, _) in zip(spans, parts)])
 
-        return write_fn, attend_fn
+        def mix_fn(lp, p, pools, l):
+            outs = []
+            for (lo, hi), (_, _, _, mix) in zip(spans, parts):
+                out, pools = mix(lp, p[lo:hi], pools, l)
+                outs.append(out)
+            return jnp.concatenate(outs), pools
+
+        return (write_fn, attend_fn,
+                mix_fn if parts[0][3] is not None else None)
 
     def _decode_burst_fn(self, params, pool, tokens, fed, kv_lens, tables,
                          max_pos, temperature, key, rings=None, chunks=None,
-                         *, n_steps: int, kb: Optional[int] = None):
+                         slots=None, *, n_steps: int,
+                         kb: Optional[int] = None):
         """``n_steps`` decode iterations entirely on device: each step
         writes KV at ``kv_lens`` through ``tables``, attends via the paged
         kernel, samples the next token in-graph and feeds it back.  Write
         positions clamp at ``max_pos`` (a slot that hit EOS/budget inside
         the burst only scribbles its own reserved pages; the host discards
         its surplus tokens).  ``rings [B]``: each row's ring's first page,
-        where a kind recycles (else None).
+        where a kind recycles (else None).  ``slots``: where the model
+        carries a recurrent state (else None), ``(the rows' [B], the
+        chunks' [Bp] or None)``: each row's state slot, 0 for a row that
+        is no sequence's (:meth:`_decode_rows`, :meth:`_chunk_rows`).
 
         ``fed``: ``(source [B], newest [B + Bp])``.  A row's first input
         token is ``tokens[r]`` where ``source[r] < 0``, else the previous
@@ -603,15 +689,17 @@ class RaggedInferenceEngineV2:
                 raise ValueError("chunks ride in the one-step program")
             c_tokens, c_tables, c_start, c_last, c_rings = chunks
             Bp, C = c_tokens.shape
-            c_pos, riding = self._chunk_rows(c_tokens, c_tables, c_start,
-                                             c_rings, kb)
+            c_pos, riding = self._chunk_rows(
+                c_tokens, c_tables, c_start, c_rings, kb,
+                None if slots is None else slots[1], c_last)
 
         def one_step(carry, key):
             tokens, kv_lens, pool = carry
             step_mark = numerics.scan_mark()
             wp = jnp.minimum(kv_lens, max_pos)  # [B] write positions
             ids, pos = tokens, wp
-            parts = [self._decode_rows(tables_of, wp)]
+            parts = [self._decode_rows(
+                tables_of, wp, None if slots is None else slots[0])]
             if chunks is not None:
                 ids = jnp.concatenate([c_tokens.reshape(-1), tokens])
                 pos = jnp.concatenate([c_pos, wp])
@@ -781,6 +869,16 @@ class RaggedInferenceEngineV2:
                 help="consecutive tokens of a prefill chunk that share a "
                      "grid row of the kind's paged kernel, as the traced "
                      "programs were built")
+        for layout in self.state_layouts.values():
+            tel.set_gauge(
+                "inference/ssm/slots_in_use",
+                float(sum(r is not None for r in self.scheduler.slots)),
+                help="batch slots whose recurrent state a live sequence "
+                     "holds")
+            tel.set_gauge(
+                "inference/ssm/state_bytes", float(layout.pool_bytes),
+                help="bytes of the recurrent state's pool: every layer's "
+                     "every slot's, and the scratch slot's")
         stats = self.last_moe_stats
         if not stats:
             return
@@ -959,6 +1057,7 @@ class RaggedInferenceEngineV2:
                 self._count_cache_traffic(
                     tel, sent.kv_lens[live], sent.max_pos[live], sent.steps,
                     [ch.start_pos for ch in sent.chunks])
+                self._count_state_traffic(tel, sent)
             for counted in done:
                 self._count_call(tel, *counted)
 
@@ -1033,9 +1132,10 @@ class RaggedInferenceEngineV2:
                  "later page of the same sequence (logical pages: each is "
                  "one page in every window layer)")
 
-    def _pack_chunks(self, tel: Any, chunks) -> Tuple[Tuple, int]:
+    def _pack_chunks(self, tel: Any, chunks) -> Tuple[Tuple, int, Any]:
         """``chunks`` as the one-step program takes them
-        (:meth:`_decode_burst_fn`), and their page bucket."""
+        (:meth:`_decode_burst_fn`), their page bucket, and their rows'
+        state slots (None where the model carries no state)."""
         with tel.span("inference/pack", args={"kind": "prefill"}):
             Bp, C = self.prefill_batch, self.chunk
             tokens = np.zeros((Bp, C), np.int32)
@@ -1050,8 +1150,10 @@ class RaggedInferenceEngineV2:
                 last[i] = max(ch.n_valid - 1, 0)
             rings = self.cache_config.ring_bases(
                 Bp, ((i, ch.request.ring) for i, ch in enumerate(chunks)))
+            slots = self.cache_config.state_rows(
+                Bp, ((i, ch.request.slot) for i, ch in enumerate(chunks)))
             kb = self._prefill_bucket(chunks)
-        return (tokens, tables, start, last, rings), kb
+        return (tokens, tables, start, last, rings), kb, slots
 
     def _count_cache_traffic(self, tel: Any, kv_lens, max_pos, burst,
                              chunk_starts=()) -> None:
@@ -1076,6 +1178,41 @@ class RaggedInferenceEngineV2:
         self._count_recycled(tel, -(-kv_lens // bs),
                              (lengths[-1] - 1) // bs + 1 - -(-kv_lens // bs))
 
+    def _count_state_traffic(self, tel: Any, sent: _Call) -> None:
+        """What a call moves of the recurrent state, from what it packed:
+        every step reads and writes every batch slot's state in every
+        layer (a dead row's as it was), and a call that carries chunks
+        its chunk rows' slots once more."""
+        for layout in self.state_layouts.values():
+            moved = (sent.steps * self.max_slots
+                     + (self.prefill_batch if sent.chunks else 0)
+                     ) * layout.kind.layers * layout.bytes_per_slot
+            tel.inc_counter(
+                "inference/ssm/state_bytes_read", v=moved,
+                help="bytes of recurrent state the calls dispatched read: "
+                     "every batch slot's a layer a decode step, and a "
+                     "chunk row's slot a layer")
+            tel.inc_counter(
+                "inference/ssm/state_bytes_written", v=moved,
+                help="bytes of recurrent state the calls dispatched wrote "
+                     "back in place (as many as they read)")
+            tel.inc_counter(
+                "inference/ssm/decode_rows", v=sent.steps * len(sent.decode),
+                help="one-token state updates of live sequences: decode "
+                     "rows x steps of the calls dispatched (a layer's; "
+                     "times layers and a slot's bytes twice: what the "
+                     "updates must move)")
+            tel.inc_counter(
+                "inference/ssm/chunk_tokens",
+                v=sum(ch.n_valid for ch in sent.chunks),
+                help="prompt tokens that went through the chunk scan in "
+                     "the calls dispatched (a layer's)")
+            tel.inc_counter(
+                "inference/ssm/chunks_from_zero",
+                v=sum(ch.start_pos == 0 for ch in sent.chunks),
+                help="chunks that began their sequence: their state "
+                     "started from zeros, whatever the slot held")
+
     def _dispatch(self, tel: Any, chunks, decode, temp,
                   eos_token_id) -> _Call:
         """Pack and dispatch the round's one call: ``decode``'s rows and,
@@ -1093,10 +1230,11 @@ class RaggedInferenceEngineV2:
         # over-running a request's budget inside a burst is safe (max_pos
         # clamps writes, the host discards surplus tokens), so the tail
         # reuses the full-length program
-        burst, riding, bucket = self.decode_burst, None, {}
+        burst, riding, riding_slots, bucket = self.decode_burst, None, None, {}
         if chunks:
             burst = 1
-            riding, bucket["kb"] = self._pack_chunks(tel, chunks)
+            riding, bucket["kb"], riding_slots = self._pack_chunks(tel,
+                                                                   chunks)
         with tel.span("inference/pack", args={"kind": "decode"}):
             B = self.max_slots
             tokens = np.zeros((B,), np.int32)
@@ -1126,6 +1264,8 @@ class RaggedInferenceEngineV2:
                 rows.append(_Row(req, s, position))
             rings = self.cache_config.ring_bases(
                 B, ((r.slot, r.ring) for r in decode))
+            slots = self.cache_config.state_rows(
+                B, ((r.slot, r.slot) for r in decode))
             self.scheduler.dispatched(chunks, decode, burst)
             self._calls += 1
         with tel.span("inference/decode_burst/dispatch",
@@ -1135,7 +1275,9 @@ class RaggedInferenceEngineV2:
                 self._decode(burst)(
                     self.params, self.pool, tokens, (source, self._newest),
                     kv_lens, tables, max_pos, temp, self._next_key(tel),
-                    rings, riding, **bucket)
+                    rings, riding,
+                    None if slots is None else (slots, riding_slots),
+                    **bucket)
         call = _Call(chunks, rows, burst, (toks, firsts, moe_aux),
                      eos_token_id, self._calls, ahead, bucket.get("kb"),
                      kv_lens, max_pos, sp.start)

@@ -24,6 +24,16 @@ the only code that indexes a pool array.
 * A LATENT kind (``v_in_k``) has a K pool and no V pool: the one row a token
   holds its value too.
 
+A model whose sequences carry a recurrent STATE beside their keys
+(``adapters.StateKind``: a state-space layer's state and its conv's tail,
+a fixed size a sequence a layer whatever its length) has, for that, a pool
+indexed by BATCH SLOT and not by page: :class:`StateLayout`, the only code
+that indexes it.  Each of the kind's parts is one array ``[layers, 1 +
+slots, …]``; slot 0 is scratch, for rows that are no sequence's, and
+request ``r`` in batch slot ``s`` holds slot ``s + 1`` of every layer from
+admission on.  The state pools lie in the same dict as the KV pools, under
+the kind's name, and ride the same carry.
+
 Inside the engine's programs a pool is a CARRIED BUFFER addressed by
 ``(layer, page)``: it rides the per-layer ``lax.scan`` as a carry beside the
 activations and is written in place.  It is NOT scanned over like the
@@ -58,6 +68,12 @@ class KVCacheConfig:
     #: pages (and page 0), one a sequence from its admission on.
     ring_blocks: int = 0
     num_rings: int = 0
+    #: Set by :meth:`with_state` for a model with a ``StateKind``: the
+    #: batch slots whose recurrent state the state pools hold; a caller
+    #: leaves it 0.  What a scheduler reads to know that a sequence's cache
+    #: is not all in its pages (no shared prefix, no seat given up and
+    #: taken again elsewhere)
+    state_slots: int = 0
 
     @property
     def max_blocks_per_seq(self) -> int:
@@ -80,6 +96,26 @@ class KVCacheConfig:
         return dataclasses.replace(
             self, num_rings=slots,
             ring_blocks=-(-max(windows) // bs) + max(prefill_chunk // bs, 1))
+
+    def with_state(self, state_kinds: Iterable[Any], slots: int
+                   ) -> "KVCacheConfig":
+        """This config with a state slot a batch slot where the model has
+        a ``StateKind``."""
+        if not tuple(state_kinds):
+            return self
+        return dataclasses.replace(self, state_slots=slots)
+
+    def state_rows(self, rows: int, held: Iterable) -> Optional[np.ndarray]:
+        """``[rows]``: the state slot of each row's request (``held``:
+        ``(row, the request's batch slot)`` pairs), 0 (the scratch slot,
+        which is no sequence's) elsewhere; None where the model has no
+        recurrent state."""
+        if not self.state_slots:
+            return None
+        slot = np.zeros((rows,), np.int32)
+        for row, seat in held:
+            slot[row] = 1 + seat if seat >= 0 else 0
+        return slot
 
     def ring_bases(self, rows: int, held: Iterable) -> Optional[np.ndarray]:
         """``[rows]``: the first page of the ring each row's request holds
@@ -340,6 +376,95 @@ class KVLayout:
         return pages.reshape(-1), tables[:, :kb], jnp.arange(kb * bs)
 
 
+@dataclasses.dataclass(frozen=True)
+class StateLayout:
+    """How ONE ``StateKind``'s parts lie in its pool ``{part: [layers, 1 +
+    slots, …]}`` (module docstring), and every access to it.  A call's
+    decode rows ARE the batch slots in order (row ``r`` is slot ``r + 1``),
+    so their state is one contiguous stretch of a layer, read and written
+    where it lies; a chunk's sequence is wherever its request sits."""
+    kind: Any               # adapters.StateKind
+    slots: int              # batch slots (the pool has one more: scratch)
+
+    @property
+    def bytes_per_slot(self) -> int:
+        """What one sequence holds in ONE layer."""
+        return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+                   for _, shape, dtype in self.kind.parts)
+
+    @property
+    def pool_bytes(self) -> int:
+        return self.kind.layers * (1 + self.slots) * self.bytes_per_slot
+
+    def init_pool(self) -> Dict[str, jnp.ndarray]:
+        return {name: jnp.zeros((self.kind.layers, 1 + self.slots) + shape,
+                                dtype)
+                for name, shape, dtype in self.kind.parts}
+
+    def decode_operands(self, pool, l) -> tuple:
+        """Layer ``l``'s state of every batch slot in order, as a decode
+        step's hook takes it (``adapters.ModelAdapterV2.mix_decode``) →
+        (``{part: [slots, …]}``, the rows' values of the parts that are
+        read and written back as values; ``{part: (the pool's array of the
+        part, l, the rows' first slot)}`` for the kind's ``in_place``
+        parts, which the hook moves where they lie: a kernel that reads
+        each sequence's state once and writes it back in place, where in
+        ``jax.numpy`` a layer's 407 MB crossed HBM seven times a step at
+        the serving cell's shapes)."""
+        return ({name: jax.lax.dynamic_index_in_dim(
+                    array, l, 0, keepdims=False)[1:]
+                 for name, array in pool.items()
+                 if name not in self.kind.in_place},
+                {name: (pool[name], l, 1) for name in self.kind.in_place})
+
+    def decode_written(self, pool, l, values, arrays
+                       ) -> Dict[str, jnp.ndarray]:
+        """The pool after a decode step: ``values`` written over their
+        stretch of layer ``l`` (slots 1 …), in place, and ``arrays``, the
+        ``in_place`` parts' as the hook hands them back, in their place."""
+        return {name: arrays[name] if name in arrays
+                else jax.lax.dynamic_update_slice(
+                    array, values[name][None].astype(array.dtype),
+                    (l, 1) + (0,) * (array.ndim - 2))
+                for name, array in pool.items()}
+
+    def read_slots(self, pool, l, slots, fresh) -> Dict[str, jnp.ndarray]:
+        """Layer ``l``'s state at ``slots [R]`` ``{part: [R, …]}``, zeros
+        where ``fresh [R]``: a sequence's first chunk starts from nothing,
+        whatever the slot's last owner left there.  A slice a row: a
+        gather over ``(l, slots)`` is lowered on the chip to a pass over
+        the WHOLE pool (two values of half its size, 1.14 GB each at the
+        serving cell's shapes)."""
+        def rows(array):
+            got = jnp.concatenate([jax.lax.dynamic_slice(
+                array, (l, slots[r]) + (0,) * (array.ndim - 2),
+                (1, 1) + array.shape[2:])[0] for r in range(slots.shape[0])])
+            keep = ~fresh.reshape((-1,) + (1,) * (got.ndim - 1))
+            return jnp.where(keep, got, jnp.zeros_like(got))
+
+        return {name: rows(array) for name, array in pool.items()}
+
+    def write_slots(self, pool, l, slots, state) -> Dict[str, jnp.ndarray]:
+        """``state {part: [R, …]}`` written at ``(l, slots[r])``, in place,
+        a slice a row (rows that are no sequence's land on slot 0)."""
+        def written(array, rows):
+            for r in range(slots.shape[0]):
+                array = jax.lax.dynamic_update_slice(
+                    array, rows[r][None, None].astype(array.dtype),
+                    (l, slots[r]) + (0,) * (array.ndim - 2))
+            return array
+
+        return {name: written(array, state[name])
+                for name, array in pool.items()}
+
+
+def state_layouts(adapter: Any, cache_config: KVCacheConfig
+                  ) -> Dict[str, StateLayout]:
+    """The layout of each of the adapter's state kinds, by its name."""
+    return {kind.name: StateLayout(kind, cache_config.state_slots)
+            for kind in adapter.state_kinds}
+
+
 def kv_layouts(adapter: Any, cache_config: KVCacheConfig
                ) -> Dict[str, KVLayout]:
     """The layout of each of the adapter's attention kinds, by its name."""
@@ -349,9 +474,11 @@ def kv_layouts(adapter: Any, cache_config: KVCacheConfig
 
 def init_kv_pool(adapter: Any, cache_config: KVCacheConfig
                  ) -> Dict[str, Dict[str, jnp.ndarray]]:
-    """Zeroed pools, ``{kind: KVLayout.init_pool()}``."""
-    return {name: layout.init_pool()
-            for name, layout in kv_layouts(adapter, cache_config).items()}
+    """Zeroed pools, ``{kind: its layout's init_pool()}``: the attention
+    kinds' and, in the same dict, the state kinds'."""
+    layouts = dict(kv_layouts(adapter, cache_config),
+                   **state_layouts(adapter, cache_config))
+    return {name: layout.init_pool() for name, layout in layouts.items()}
 
 
 def _transferred(layouts: Dict[str, KVLayout], pools) -> Dict[str, Any]:
@@ -362,6 +489,12 @@ def _transferred(layouts: Dict[str, KVLayout], pools) -> Dict[str, Any]:
         raise NotImplementedError(
             f"KV page transfer of a model with {len(layouts)} KV pools "
             f"({sorted(layouts)})")
+    if set(pools) - set(layouts):
+        raise NotImplementedError(
+            f"KV page transfer of a model with recurrent state "
+            f"({sorted(set(pools) - set(layouts))}): a sequence's state "
+            f"lies in its batch slot, not in its pages, and is not "
+            f"transferred")
     return pools[next(iter(layouts))]
 
 

@@ -9,6 +9,7 @@ that compose with the ZeRO sharding policy.
 """
 
 from .bert import BertConfig, BertModel
+from .falcon_h1 import FalconH1Config, FalconH1Model
 from .llama import LlamaConfig, LlamaModel
 from .mimo_v2 import MimoV2Config, MimoV2Model
 from .mixtral import MixtralConfig, MixtralModel
@@ -17,7 +18,8 @@ from .opt import OPTConfig, OPTModel
 from .pangu_ultra_moe import PanguUltraMoeConfig, PanguUltraMoeModel
 from .resnet import ResNetConfig, ResNetModel
 
-__all__ = ["BertConfig", "BertModel", "LlamaConfig", "LlamaModel",
+__all__ = ["BertConfig", "BertModel", "FalconH1Config", "FalconH1Model",
+           "LlamaConfig", "LlamaModel",
            "MimoV2Config", "MimoV2Model", "MixtralConfig", "MixtralModel", "OlmoeConfig", "OlmoeModel",
            "OPTConfig", "OPTModel",
            "PanguUltraMoeConfig", "PanguUltraMoeModel",

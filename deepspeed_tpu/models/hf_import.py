@@ -1,5 +1,5 @@
 """HF checkpoint import — map Hugging Face weights into the model zoo
-(Llama, Mistral, Mixtral, OPT, BERT).
+(Llama, Mistral, Mixtral, OPT, BERT, Falcon-H1).
 
 Capability anchor: reference users bring HF torch models directly
 (``deepspeed.initialize(model=hf_model)``); this build's engine consumes
@@ -435,3 +435,104 @@ def load_hf_bert(model_name_or_path: str, **config_overrides):
     return _load(model_name_or_path, config_from_hf_bert,
                  params_from_hf_bert_state_dict, model_cls=BertForMaskedLM,
                  **config_overrides)
+
+
+# ---------------------------------------------------------------------------
+# Falcon-H1 — a Mamba-2 mixer beside attention in every layer (layout only:
+# the µP multipliers stay the config's, none is folded into a weight)
+# ---------------------------------------------------------------------------
+
+def config_from_hf_falcon_h1(hf_config: Any, **overrides):
+    from .falcon_h1 import FalconH1Config
+
+    get = _getter(hf_config)
+    for key, want in (("mamba_rms_norm", True), ("mamba_conv_bias", True),
+                      ("mamba_proj_bias", False), ("attention_bias", False),
+                      ("mlp_bias", False), ("projectors_bias", False),
+                      ("tie_word_embeddings", False)):
+        if bool(get(key, want)) != want:
+            raise NotImplementedError(
+                f"this Falcon-H1 implementation has {key}={want}")
+    heads, d_head = int(get("mamba_n_heads")), int(get("mamba_d_head"))
+    if get("mamba_d_ssm") is not None and int(get("mamba_d_ssm")) \
+            != heads * d_head:
+        raise NotImplementedError("mamba_d_ssm is not heads x head size")
+    d = dict(
+        vocab_size=int(get("vocab_size")),
+        hidden_size=int(get("hidden_size")),
+        intermediate_size=int(get("intermediate_size")),
+        num_layers=int(get("num_hidden_layers")),
+        num_heads=int(get("num_attention_heads")),
+        num_kv_heads=int(get("num_key_value_heads")),
+        head_dim=int(get("head_dim") or int(get("hidden_size"))
+                     // int(get("num_attention_heads"))),
+        rope_theta=float(get("rope_theta")),
+        rms_norm_eps=float(get("rms_norm_eps")),
+        mamba_n_heads=heads, mamba_d_head=d_head,
+        mamba_d_state=int(get("mamba_d_state")),
+        mamba_n_groups=int(get("mamba_n_groups")),
+        mamba_d_conv=int(get("mamba_d_conv")),
+        mamba_chunk_size=int(get("mamba_chunk_size")),
+        mamba_norm_before_gate=bool(get("mamba_norm_before_gate")),
+        ssm_multipliers=tuple(float(m) for m in get("ssm_multipliers")),
+        mlp_multipliers=tuple(float(m) for m in get("mlp_multipliers")),
+        max_seq_len=int(get("max_position_embeddings")),
+    )
+    d.update({key: float(get(key)) for key in (
+        "embedding_multiplier", "lm_head_multiplier",
+        "attention_in_multiplier", "attention_out_multiplier",
+        "key_multiplier", "ssm_in_multiplier", "ssm_out_multiplier")})
+    d.update(overrides)
+    return FalconH1Config(**d)
+
+
+def params_from_hf_falcon_h1_state_dict(state_dict: Dict[str, Any],
+                                        config: Any) -> Dict[str, Any]:
+    """``FalconH1ForCausalLM.state_dict()`` → this zoo's stacked tree.
+    HF's ``in_proj`` rows are ``[z | xs | B | C | dt]`` as here; its conv
+    weight ``[conv_dim, 1, K]`` is held ``[K, conv_dim]``."""
+    c = config
+    h, kv, d = c.num_heads, c.num_kv_heads, c.head_dim
+
+    def stack(name, fn=lambda w: w):
+        return jnp.asarray(np.stack([
+            fn(_to_np(state_dict[f"model.layers.{l}.{name}"]))
+            for l in range(c.num_layers)]))
+
+    heads_in = lambda n: (lambda w: w.T.reshape(c.hidden_size, n, d))
+    return {
+        "embed": jnp.asarray(_to_np(state_dict["model.embed_tokens.weight"])),
+        "layers": {
+            "attn_norm": stack("input_layernorm.weight"),
+            "mlp_norm": stack("pre_ff_layernorm.weight"),
+            "attn": {
+                "wq": stack("self_attn.q_proj.weight", heads_in(h)),
+                "wk": stack("self_attn.k_proj.weight", heads_in(kv)),
+                "wv": stack("self_attn.v_proj.weight", heads_in(kv)),
+                "wo": stack("self_attn.o_proj.weight",
+                            lambda w: w.T.reshape(h, d, c.hidden_size))},
+            "ssm": {
+                "in_proj": stack("mamba.in_proj.weight", lambda w: w.T),
+                "conv_w": stack("mamba.conv1d.weight",
+                                lambda w: w[:, 0, :].T),
+                "conv_b": stack("mamba.conv1d.bias"),
+                "dt_bias": stack("mamba.dt_bias"),
+                "A_log": stack("mamba.A_log"),
+                "D": stack("mamba.D"),
+                "norm": stack("mamba.norm.weight"),
+                "out_proj": stack("mamba.out_proj.weight", lambda w: w.T)},
+            "mlp": {
+                "w_gate": stack("feed_forward.gate_proj.weight",
+                                lambda w: w.T),
+                "w_up": stack("feed_forward.up_proj.weight", lambda w: w.T),
+                "w_down": stack("feed_forward.down_proj.weight",
+                                lambda w: w.T)}},
+        "final_norm": jnp.asarray(
+            _to_np(state_dict["model.final_layernorm.weight"])),
+        "lm_head": jnp.asarray(_to_np(state_dict["lm_head.weight"]).T),
+    }
+
+
+def load_hf_falcon_h1(model_name_or_path: str, **config_overrides):
+    return _load(model_name_or_path, config_from_hf_falcon_h1,
+                 params_from_hf_falcon_h1_state_dict, **config_overrides)

@@ -571,6 +571,36 @@ def check_moe_share(s: KernelShapes, interpret: bool) -> List[Check]:
                   float(_rel_err(got, want)), ATTENTION_TOL)]
 
 
+def check_ssm_state_update(s: KernelShapes, interpret: bool) -> List[Check]:
+    """A decode step of the state-space recurrence at a published mixer's
+    widths (32 heads of 128 in 2 groups, state 256), ``slots`` sequences in
+    a pool of three layers, in place: the pool after, and ``y``.  Kernel
+    and reference run the same float32 formula on the VPU; they may differ
+    by FMA contraction and the order of the read-out's sum."""
+    ssu = _mod("ssm_state_update")
+    rng = np.random.RandomState(12)
+    heads, groups, state, head = 32, 2, 256, 128
+    rows = s.slots
+    pool = _normal(rng, (3, rows + 2, heads, state, head), jnp.float32)
+    a = jnp.exp(-jnp.abs(_normal(rng, (rows, heads), jnp.float32)))
+    dx = _normal(rng, (rows, heads, head), jnp.float32, 0.1)
+    b = _normal(rng, (rows, groups, state), s.dtype)
+    c = _normal(rng, (rows, groups, state), s.dtype)
+
+    @jax.jit
+    def errors(pool, a, dx, b, c):
+        want = ssu.ssm_state_update_reference(pool, 1, 1, a, dx, b, c)
+        # the kernel writes the pool it is given: hand it a copy, after
+        # the reference has read the original
+        got = ssu.ssm_state_update(pool + 0.0, 1, 1, a, dx, b, c,
+                                   interpret=interpret)
+        return [_rel_err(g, w) for g, w in zip(got, want)]
+
+    state_err, y_err = errors(pool, a, dx, b, c)
+    return [Check("ssm_state_update_state", float(state_err), 1e-5),
+            Check("ssm_state_update_y", float(y_err), 1e-4)]
+
+
 def check_quantizer(s: KernelShapes, interpret: bool) -> List[Check]:
     qz = _mod("quantizer")
     rng = np.random.RandomState(6)
@@ -618,7 +648,8 @@ def check_block_sparse(s: KernelShapes, interpret: bool) -> List[Check]:
 CHECKS = (check_flash, check_flash_streamed, check_decode, check_paged,
           check_paged_hybrid, check_paged_latent, check_fused_adam, check_moe,
           check_moe_grouped,
-          check_moe_share, check_quantizer, check_block_sparse)
+          check_moe_share, check_ssm_state_update, check_quantizer,
+          check_block_sparse)
 
 
 def run_checks(shapes: KernelShapes, interpret: bool = False

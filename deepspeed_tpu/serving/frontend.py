@@ -840,12 +840,15 @@ class ServingFrontend:
         # under the HBM-headroom floor the victim's pages are RELEASED
         # (cached-free tier), not retained — so preemption can help a
         # page-blocked head too, and HBM actually shrinks
-        release = (self.params.preempt_release_pages
+        pressed = (self.params.preempt_release_pages
                    and self._headroom_degraded())
         for rep in self.router.healthy():
             if rep.scheduler.can_admit(head.prompt, head.max_new_tokens):
                 return False  # admissible without preemption
         for rep in self.router.healthy():
+            # a recurrent state lies in THIS replica's seats: its victim
+            # starts over
+            release = pressed or rep.scheduler.seat_holds_state
             if not release and not rep.scheduler.can_admit(
                     head.prompt, head.max_new_tokens, ignore_slots=True):
                 # the head is page-blocked here, not slot-blocked:

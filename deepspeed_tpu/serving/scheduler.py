@@ -60,6 +60,17 @@ class ServingScheduler(RaggedScheduler):
                       "layers recycle their KV pages, and a shared prefix "
                       "could not give them their last keys back")
             prefix_sharing = False
+        if prefix_sharing and cache_config.state_slots:
+            # a prefix hit skips the prefill of the shared tokens, which
+            # is what builds the state; no snapshot of the state at the
+            # prefix's end exists to start from (ROADMAP R7)
+            from ..utils.logging import warn_once
+
+            warn_once("serving/prefix_cache/state",
+                      "prefix sharing is off for this model: its layers "
+                      "carry a recurrent state, which a shared prefix's "
+                      "pages do not hold")
+            prefix_sharing = False
         self.prefix = PrefixCache(self.allocator, cache_config.block_size,
                                   enabled=prefix_sharing)
         self.preemptions = 0
@@ -199,11 +210,27 @@ class ServingScheduler(RaggedScheduler):
 
     # -- preemptible decode slots ------------------------------------------
 
+    @property
+    def seat_holds_state(self) -> bool:
+        """Whether part of a sequence's cache lies in its batch slot and
+        not in its pages (a recurrent state): a request that gives up its
+        seat can then only start over (:meth:`preempt_release`), and its
+        pages alone are not the sequence (no adoption)."""
+        return bool(self.cache.state_slots)
+
+    def _refuse_if_seat_holds_state(self, what: str) -> None:
+        if self.seat_holds_state:
+            raise NotImplementedError(
+                f"{what} of a model with recurrent state: a sequence's "
+                f"state lies in its batch slot, not in its pages, and no "
+                f"snapshot of it is kept (ROADMAP R7)")
+
     def unseat(self, req: Request) -> None:
         """:meth:`preempt` minus the SLO counters — the disaggregation
         plane's "hold the pages, free the slot" primitive: a prefill
         replica parks a just-prefilled request here while its KV pages
         stream out to a decode replica, then :meth:`cancel`\\ s it."""
+        self._refuse_if_seat_holds_state("preemption that keeps the pages")
         if req.state is RequestState.PREFILL:
             self.prefilling.remove(req)
         elif req.state is not RequestState.RUNNING:
@@ -303,6 +330,7 @@ class ServingScheduler(RaggedScheduler):
         parks WAITING in its slot (inert to the planner) until
         :meth:`adopt_commit` seats it RUNNING."""
         self.validate(prompt, max_new_tokens)
+        self._refuse_if_seat_holds_state("KV adoption")
         if self.cache.ring_blocks:
             raise NotImplementedError(
                 "KV adoption of a model whose window layers recycle their "

@@ -110,3 +110,72 @@ def test_release_preemption_disabled_by_param(monkeypatch):
     degraded["on"] = False
     fe.run_until_idle()
     assert bg.status == "done" and bg.replays == 0
+
+
+def test_only_a_replica_whose_seats_hold_state_starts_its_victim_over(
+        monkeypatch):
+    """A recurrent state lies in the seat, not in the pages, so a victim
+    on such a replica is released and replays.  That is the replica's
+    own: one visited after it, whose seats hold nothing, still keeps its
+    victim's pages and resumes it in place."""
+    engines = [SyntheticEngine(
+        KVCacheConfig(num_blocks=64, block_size=16, max_seq_len=512,
+                      state_slots=slots),
+        max_batch_slots=1, prefill_chunk=16, prefill_batch=1,
+        decode_burst=1) for slots in (1, 0)]
+    fe = ServingFrontend([Replica(e, i) for i, e in enumerate(engines)])
+    monkeypatch.setattr(fe, "_headroom_degraded", lambda: False)
+    first, second = (r.scheduler for r in fe.router.replicas)
+    assert first.seat_holds_state and not second.seat_holds_state
+    # the first replica's one seat goes to a request that is no victim
+    held = fe.submit([3] * 20, max_new_tokens=200)
+    fe.pump()
+    bg = fe.submit([1] * 20, max_new_tokens=64, klass="background")
+    for _ in range(6):
+        fe.pump()
+    assert (held.replica_id, bg.replica_id) == (0, 1)
+    assert bg.status == "running"
+    inter = fe.submit([2] * 20, max_new_tokens=4)
+    fe.pump()
+    assert fe.metrics.counters["preemptions"] == 1
+    assert fe.metrics.counters.get("preempt_pages_released", 0) == 0
+    assert bg.preempted and bg.request is not None and bg.replays == 0
+    fe.run_until_idle()
+    assert inter.status == bg.status == "done" and bg.replays == 0
+
+
+def test_a_victim_that_holds_recurrent_state_starts_over_and_serves_the_same():
+    """Through the front end and a real engine whose layers carry a
+    recurrent state (Falcon-H1 at a tiny size): the bumped background
+    request gives up its seat AND its pages with no HBM pressure, replays
+    from its first token in whatever seat it gets, and its stream is what
+    it is when nothing interrupts it."""
+    import jax
+
+    from deepspeed_tpu import models
+    from deepspeed_tpu.serving import build_serving_frontend
+
+    model = models.FalconH1Model(models.FalconH1Config.tiny())
+    fe = build_serving_frontend(
+        model, model.init_params(jax.random.PRNGKey(3)),
+        cache_config=KVCacheConfig(num_blocks=32, block_size=8,
+                                   max_seq_len=64),
+        max_batch_slots=1, prefill_chunk=16, prefill_batch=1)
+    prompt = [(7 * i + 3) % 256 for i in range(21)]
+    alone = fe.submit(prompt, max_new_tokens=24, klass="background")
+    fe.run_until_idle()
+    want = alone.result(timeout=5)
+    assert len(want) == 24 and fe.metrics.counters.get("preemptions", 0) == 0
+    bg = fe.submit(prompt, max_new_tokens=24, klass="background")
+    for _ in range(40):
+        fe.pump()
+        if bg.delivered:
+            break
+    assert 0 < bg.delivered < 24
+    inter = fe.submit([(5 * i + 1) % 256 for i in range(18)],
+                      max_new_tokens=4)
+    fe.run_until_idle()
+    assert fe.metrics.counters["preemptions"] == 1
+    assert fe.metrics.counters["preempt_pages_released"] > 0
+    assert inter.status == bg.status == "done" and bg.replays == 1
+    assert bg.result(timeout=5) == want
