@@ -33,6 +33,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..parallel.mesh import AXIS_SEQ, AXIS_TENSOR, DP_AXES
+from ..runtime.activation_checkpointing import remat_policy
 from ..telemetry import numerics
 
 P = PartitionSpec
@@ -198,6 +199,20 @@ class BertModel:
         c = self.config
         return flash_route(c.max_seq_len, c.hd)[0] == "kernel"
 
+    def keeps_flash_residuals(self) -> bool:
+        """Whether the flash op names its ``out`` and ``lse`` for the layer
+        scan's remat policy to hold: the op's own rule
+        (``keeps_residuals``) at the shape a device's call has, its heads
+        split over ``tensor``.  The engine's memory ledger asks, beside
+        :meth:`uses_flash_kernels`."""
+        from ..ops.pallas.flash_attention import keeps_residuals
+
+        c = self.config
+        split = (1 if self.mesh is None
+                 else int(self.mesh.shape.get(AXIS_TENSOR, 1)))
+        return keeps_residuals(c.max_seq_len, c.num_heads // split, c.hd,
+                               False, None)
+
     def _constrain(self, x: jnp.ndarray, *spec) -> jnp.ndarray:
         if self.mesh is None:
             return x
@@ -319,10 +334,7 @@ class BertModel:
 
             body = layer
             if c.remat:
-                body = jax.checkpoint(
-                    layer,
-                    policy=jax.checkpoint_policies
-                    .dots_with_no_batch_dims_saveable)
+                body = jax.checkpoint(layer, policy=remat_policy())
             # numerics probes stay OFF through the LTD trunk: the
             # per-layer lax.cond routing would trap their stat tracers
             # inside branch scopes
@@ -337,10 +349,7 @@ class BertModel:
 
             body = layer
             if c.remat:
-                body = jax.checkpoint(
-                    layer,
-                    policy=jax.checkpoint_policies
-                    .dots_with_no_batch_dims_saveable)
+                body = jax.checkpoint(layer, policy=remat_policy())
             x, ys = jax.lax.scan(lambda carry, lp: body(carry, lp), x,
                                  params["layers"])
             numerics.scan_collect(ys)

@@ -19,8 +19,10 @@ TPU-first design, none of which mirrors the reference's torch modules:
 * **Tensor parallelism** — Megatron-style column/row sharding is a
   PartitionSpec on the weights (``tensor`` axis) + the same activation
   constraints; no module surgery (reference: ``module_inject/auto_tp.py``).
-* **Remat** — ``jax.checkpoint`` on the layer body with a dots-saveable
-  policy ≈ reference ``activation_checkpointing`` with partitioned
+* **Remat** — ``jax.checkpoint`` on the layer body under the package's
+  one policy (``activation_checkpointing.remat_policy``: the matrix
+  products' outputs and what an op names, as the flash call its ``out``
+  and ``lse``) ≈ reference ``activation_checkpointing`` with partitioned
   activations for free (saved residuals inherit their shardings).
 """
 
@@ -35,6 +37,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..parallel.mesh import AXIS_PIPE, AXIS_SEQ, AXIS_TENSOR, DP_AXES
+from ..runtime.activation_checkpointing import remat_policy
 from ..telemetry import numerics
 
 P = PartitionSpec
@@ -382,6 +385,21 @@ class LlamaModel:
         return flash_route(c.max_seq_len, c.hd, c.flash_block_q,
                            c.flash_block_k)[0] == "kernel"
 
+    def keeps_flash_residuals(self) -> bool:
+        """Whether the flash op names its ``out`` and ``lse`` for the layer
+        scan's remat policy to hold: the op's own rule
+        (``keeps_residuals``) at the shape a device's call has, its heads
+        split over ``tensor`` and, through Ulysses, ``seq``.  The engine's
+        memory ledger asks, beside :meth:`uses_flash_kernels`."""
+        from ..ops.pallas.flash_attention import keeps_residuals
+
+        c = self.config
+        split = 1 if self.mesh is None else (
+            int(self.mesh.shape.get(AXIS_TENSOR, 1))
+            * int(self.mesh.shape.get(AXIS_SEQ, 1)))
+        return keeps_residuals(c.max_seq_len, c.num_heads // split, c.hd,
+                               True, c.sliding_window)
+
     def _constrain(self, x: jnp.ndarray, *spec) -> jnp.ndarray:
         if self.mesh is None:
             return x
@@ -596,8 +614,7 @@ class LlamaModel:
 
         body = layer
         if c.remat:
-            body = jax.checkpoint(
-                layer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+            body = jax.checkpoint(layer, policy=remat_policy())
 
         pp = (int(self.mesh.shape.get(AXIS_PIPE, 1))
               if self.mesh is not None else 1)

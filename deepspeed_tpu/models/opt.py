@@ -22,6 +22,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..parallel.mesh import AXIS_SEQ, AXIS_TENSOR, DP_AXES
+from ..runtime.activation_checkpointing import remat_policy
 from .bert import _layer_norm
 from .llama import _attention
 
@@ -209,9 +210,7 @@ class OPTModel:
 
         body = layer
         if c.remat:
-            body = jax.checkpoint(
-                layer,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+            body = jax.checkpoint(layer, policy=remat_policy())
         x, _ = jax.lax.scan(lambda carry, lp: body(carry, lp), x,
                             params["layers"])
         return _layer_norm(x, params["final_ln_w"].astype(dt),

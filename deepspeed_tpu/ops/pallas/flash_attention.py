@@ -43,10 +43,17 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from . import lattice
-from .select import (record_route, reference_off_tpu,
+from .select import (record_residuals, record_route, reference_off_tpu,
                      resident_compiler_params, shape_refused)
+
+#: what a call names its two residuals, ``out`` and ``lse``, where
+#: :func:`keeps_residuals` says they are dearer to recompute than to hold:
+#: the names a layer's remat policy keeps (``remat_policy`` in
+#: ``runtime/activation_checkpointing``)
+RESIDUAL_NAMES = ("flash_attention/out", "flash_attention/lse")
 
 
 def _mask(S, T, causal, window=None):
@@ -762,10 +769,10 @@ def _flash_bwd_stream(q, k, v, out, lse, do, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, seg, causal, block_q, block_k, window, impl):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, seg, causal, block_q, block_k, window, impl, keep):
     return _flash_inner_fwd(q, k, v, seg, causal, block_q, block_k,
-                            window, impl)[0]
+                            window, impl, keep)[0]
 
 
 def _segments(seg, q):
@@ -774,7 +781,8 @@ def _segments(seg, q):
     return seg if seg.ndim == 2 and seg.shape[1] == q.shape[1] else None
 
 
-def _flash_inner_fwd(q, k, v, seg, causal, block_q, block_k, window, impl):
+def _flash_inner_fwd(q, k, v, seg, causal, block_q, block_k, window, impl,
+                     keep):
     segment_ids = _segments(seg, q)
     if impl == "reference":
         out, lse = _reference_fwd_with_lse(q, k, v, causal, window,
@@ -783,10 +791,18 @@ def _flash_inner_fwd(q, k, v, seg, causal, block_q, block_k, window, impl):
         out, lse = _flash_call(q, k, v, causal, block_q, block_k,
                                interpret=impl == "interpret", with_lse=True,
                                window=window, segment_ids=segment_ids)
+    if keep:
+        # named as the backward takes them: ``out [B, S, h, d]`` and the
+        # dense ``lse [B, h, S]`` (at [1, 8192, 32, 128]: 64 MiB and
+        # 1 MiB), not the kernel's ``[B·h, S, 1]`` statistics, which pad
+        # to a lane a row (128 MiB).  A name is the identity outside
+        # ``jax.checkpoint`` and lowers to nothing.
+        out = checkpoint_name(out, RESIDUAL_NAMES[0])
+        lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return out, (q, k, v, seg, out, lse)
 
 
-def _flash_inner_bwd(causal, block_q, block_k, window, impl, res, do):
+def _flash_inner_bwd(causal, block_q, block_k, window, impl, keep, res, do):
     """Backward of whatever the forward ran (``impl`` was fixed by
     :func:`flash_attention`): resident Pallas kernels while the planes
     fit VMEM, streamed kernels beyond, a jnp chunked scan for the
@@ -882,6 +898,38 @@ def flash_route(S: int, d: int, block_q: int = 0, block_k: int = 0,
     return ("interpret" if interpret else "kernel"), None
 
 
+def mean_keys_scored(S: int, causal: bool, window=None) -> float:
+    """Keys a query row scores, averaged over the ``S`` rows of the
+    position mask (``ops/masks`` semantics: row ``i`` sees keys ``j <= i``
+    when causal, and ``|i - j| < window``)."""
+    i = np.arange(S, dtype=np.int64)
+    reach = S if window is None else min(int(window), S)
+    behind = np.minimum(i + 1, reach)
+    ahead = 0 if causal else np.minimum(S - 1 - i, reach - 1)
+    return float(np.mean(behind + ahead))
+
+
+def keeps_residuals(S: int, h: int, d: int, causal: bool,
+                    window=None) -> bool:
+    """Whether a call's ``out`` and ``lse`` are worth holding through a
+    rematerialized layer, from the shapes alone.  A layer's remat policy
+    already holds its projections' outputs: a byte of one costs ``K``
+    operations to recompute (2·K a bf16 element, ``K`` the contraction,
+    which for the projections that feed and drain this call is ``h·d``).
+    A byte of ``out`` costs ``2 × keys`` (4·keys·d operations for the
+    2·d bytes of a row's head: the scores and the weighted sum; ``lse``
+    rides along at 1/(64·d) of the bytes).  So the outputs are named for
+    the policy to keep where they are the DEARER of the two to recompute,
+    and strictly: at a tie they cost what the policy's own saves cost, and
+    holding them buys nothing the memory would not buy elsewhere.
+
+    Mistral-7B's row (S 8,192, causal, window 4,096, 32 × 128): 3,072
+    keys a row, 6,144 operations a byte against 4,096: kept, 65 MiB a
+    layer for a second ``flash_fwd`` of 4.1 ms.  BERT-large's (S 512, not
+    causal, 16 × 64): 1,024 against 1,024: recomputed."""
+    return 2 * mean_keys_scored(S, causal, window) > h * d
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = 0, block_k: int = 0,
                     window=None, segment_ids=None,
@@ -908,7 +956,10 @@ def flash_attention(q, k, v, causal: bool = True,
     if refusal is not None:
         shape_refused("flash_attention", tuple(q.shape), refusal)
     record_route("flash_attention", impl)
-    return _flash(q, k, v, seg, causal, block_q, block_k, window, impl)
+    keep = keeps_residuals(S, h, d, causal, window)
+    record_residuals("flash_attention", keep)
+    return _flash(q, k, v, seg, causal, block_q, block_k, window, impl,
+                  keep)
 
 
 def flash_attention_interpret(q, k, v, causal: bool = True,

@@ -62,6 +62,22 @@ def record_route(op: str, impl: str) -> None:
              f"Pallas interpreter; reference: jax.numpy)")
 
 
+def record_residuals(op: str, kept: bool) -> None:
+    """Count, beside :func:`record_route` and as it does, which way an
+    op's rule for its backward's residuals went at a traced call site:
+    ``ops/<op>/residuals_kept`` (named for a remat policy to hold) or
+    ``ops/<op>/residuals_recomputed`` (left to remat, which runs the
+    forward again)."""
+    from ...telemetry import get_telemetry
+
+    way = "kept" if kept else "recomputed"
+    get_telemetry().inc_counter(
+        f"ops/{op}/residuals_{way}",
+        help=f"call sites traced at which {op}'s outputs are {way} for "
+             f"its backward under a layer's remat policy (the op's rule, "
+             f"from the call's shapes)")
+
+
 def resident_compiler_params(interpret: bool, dimension_semantics=None):
     """``compiler_params`` for a kernel holding resident planes (empty in
     the interpreter, which has no VMEM to limit and walks its grid in

@@ -1,3 +1,4 @@
-from .checkpointing import CheckpointConfig, checkpoint, configure
+from .checkpointing import (CheckpointConfig, checkpoint, configure,
+                            remat_policy)
 
-__all__ = ["checkpoint", "configure", "CheckpointConfig"]
+__all__ = ["checkpoint", "configure", "CheckpointConfig", "remat_policy"]

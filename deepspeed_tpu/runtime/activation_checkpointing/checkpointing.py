@@ -65,13 +65,30 @@ def configure(mpu_: Any = None, deepspeed_config: Any = None,
             setattr(_CONFIG, key, val)
 
 
-def _policy():
+def remat_policy():
+    """What a rematerialized layer body holds for its backward, written
+    here once for every model's layer scan and for :func:`checkpoint`: the
+    outputs of its matrix products (``dots_with_no_batch_dims_saveable``)
+    AND the values an op has named as dearer to recompute than those: the
+    flash attention call's ``out`` and ``lse``, where the op's own rule
+    (``flash_attention.keeps_residuals``, from the call's shapes) names
+    them.  An attention call is a Mosaic custom call, not a ``dot_general``,
+    so under the dots policy alone remat runs its forward kernel a second
+    time inside the backward."""
+    from ...ops.pallas.flash_attention import RESIDUAL_NAMES
+
     cp = jax.checkpoint_policies
+    return cp.save_from_both_policies(
+        cp.dots_with_no_batch_dims_saveable,
+        cp.save_only_these_names(*RESIDUAL_NAMES))
+
+
+def _policy():
     if _CONFIG.cpu_checkpointing:
-        return cp.save_and_offload_only_these_names(
+        return jax.checkpoint_policies.save_and_offload_only_these_names(
             names_which_can_be_saved=[], names_which_can_be_offloaded=[],
             offload_src="device", offload_dst="pinned_host")
-    return cp.dots_with_no_batch_dims_saveable
+    return remat_policy()
 
 
 def checkpoint(function: Callable, *args: Any) -> Any:

@@ -746,12 +746,25 @@ class DeepSpeedEngine:
                 if heads and max_s and rows:
                     # fwd lse + bwd delta, fp32 per (row, head, pos); one
                     # layer's planes live at a time under remat
+                    elems = rows * heads * max_s
+                    stats = elems * 4
+                    nbytes, tag = 2 * stats, "lse/delta softmax stats"
+                    kept = getattr(self.module, "keeps_flash_residuals",
+                                   None)
+                    if not getattr(mc, "remat", True):
+                        nbytes *= layers
+                    elif kept is not None and kept():
+                        # the op names its outputs for the remat policy
+                        # (its rule, asked through the module as the route
+                        # is): every layer's out and lse are held from
+                        # the forward pass to that layer's backward
+                        out = elems * int(mc.hd) * np.dtype(
+                            mc.dtype).itemsize
+                        nbytes += layers * (out + stats)
+                        tag += f" + out/lse held by remat x{layers} layers"
                     self.memory_ledger.register(
                         "collective_scratch", "engine/flash_softmax_stats",
-                        2 * rows * heads * max_s * 4 * (1 if getattr(
-                            mc, "remat", True) else layers),
-                        transient=True,
-                        tag="flash attention lse/delta softmax stats")
+                        nbytes, transient=True, tag=f"flash attention {tag}")
             if self.overlap_zero3 and self.policy.stage >= 3:
                 from ..comm.overlap import staging_bytes
 

@@ -549,8 +549,9 @@ def test_bert_step_counts_the_route_its_attention_took(impl, request):
                   if name.startswith("ops/flash_attention/")}
     finally:
         hub.reset()
-    # the layer scan traces its one call site once
-    assert counts == {f"{impl}_calls": 1.0}
+    # the layer scan traces its one call site once; at this shape (64 keys
+    # a row against 8 heads of 16) the op leaves its outputs to remat
+    assert counts == {f"{impl}_calls": 1.0, "residuals_recomputed": 1.0}
 
 
 # ---------------------------------------------------------------------------

@@ -907,6 +907,74 @@ def test_mistral_row_resolves_to_the_tiles_it_was_timed_at():
     assert fa.flash_route(8192, 128, interpret=False) == ("kernel", None)
 
 
+def _computations(text: str):
+    """A compiled program's text cut into its computations, name → body."""
+    parts = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+    return {re.match(r"(?:ENTRY )?%([\w.\-]+)", p).group(1): p
+            for p in parts if re.match(r"(?:ENTRY )?%[\w.\-]+ \(", p)}
+
+
+def test_mistral_step_runs_the_flash_forward_once_a_layer_on_v5e(
+        one_chip, monkeypatch):
+    """``value_and_grad`` of a two-layer Mistral-7B-width loss at the
+    training cells' row (1 x 8,192, bf16, remat, window 4,096): the op
+    names its ``out`` and ``lse`` at this shape and the layer scan's policy
+    holds them, so the program has ONE ``flash_fwd``, in the forward scan's
+    body, and the backward scan's body holds ``flash_bwd`` and no forward
+    (under the dots-only policy remat ran a second one there: 4.1 ms a
+    layer, PR 32's trace).  What the scans stack for it is ``out`` as the
+    kernel wrote it and the DENSE ``lse [B, h, S]``, 64 + 1 MiB a layer:
+    no statistic with a minor axis of 1, which pads to a lane a row
+    (128 MiB a layer)."""
+    import importlib
+
+    from deepspeed_tpu.models import LlamaConfig, LlamaModel
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    # the op asks the platform, which is the CPU here: steer it onto the
+    # path it takes on the chip
+    monkeypatch.setattr(fa, "reference_off_tpu", lambda interpret: False)
+    L, S, h, d = 2, 8192, 32, 128
+    model = LlamaModel(LlamaConfig.mistral_7b(
+        num_layers=L, vocab_size=2048, dtype=jnp.bfloat16,
+        attn_impl="flash", remat=True))
+    assert model.keeps_flash_residuals()
+    placed = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    text = jax.jit(jax.value_and_grad(model.loss)).lower(
+        placed(jax.eval_shape(model.init_params, jax.random.PRNGKey(0))),
+        placed({"input_ids": jax.ShapeDtypeStruct((1, S), jnp.int32)})
+    ).compile().as_text()
+    holders = {
+        name: sorted(re.sub(r"[.\d]+$", "", c) for c in re.findall(
+            r'^\s*%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"',
+            body, re.M))
+        for name, body in _computations(text).items()}
+    holders = {name: calls for name, calls in holders.items() if calls}
+    assert sorted(holders.values()) == [["flash_bwd"], ["flash_fwd"]], holders
+    bodies = set(re.findall(r"\bbody=%?([\w.\-]+)", text))
+    assert set(holders) <= bodies, (holders, bodies)     # two scans' bodies
+
+    forward, = (name for name, calls in holders.items()
+                if calls == ["flash_fwd"])
+    carried = re.search(
+        rf"= \(([^\n]*?)\) while\([^\n]*body=%?{re.escape(forward)}\b", text)
+    stacked = re.findall(rf"(\w+)\[{L},([\d,]+)\]\{{([\d,]+)",
+                         carried.group(1))
+    # the layer's saves, a row of the stack each: q and out, and lse
+    assert sum(t == "bf16" and dims == f"1,{S},{h},{d}"
+               for t, dims, _ in stacked) == 2, stacked
+    statistics = [(dims, order) for t, dims, order in stacked
+                  if t == "f32" and str(S) in dims.split(",")]
+    assert [dims for dims, _ in statistics] == [f"1,{h},{S}"], stacked
+    for dims, order in statistics:
+        # the minor-most axis is the row's S positions, lane-dense
+        minor = ([L] + [int(n) for n in dims.split(",")])[
+            int(order.split(",")[0])]
+        assert minor == S, (dims, order)
+
+
 @pytest.mark.parametrize("masked", [False, True],
                          ids=["no_mask", "attention_mask"])
 def test_bert_step_holds_the_flash_kernels_on_v5e(one_chip, monkeypatch,
