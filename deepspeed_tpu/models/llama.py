@@ -812,9 +812,11 @@ class LlamaModel:
             from ..runtime.sequence_parallel.ulysses_sp import \
                 sequence_tiled_loss
 
-            return sequence_tiled_loss(
-                lambda h: jnp.einsum("bsH,HV->bsV", h, head),
-                hidden, labels, c.loss_tiles)
+            # one partial head gradient a data-parallel replica, added once
+            replicas = 1 if self.mesh is None else int(np.prod(
+                [self.mesh.shape.get(a, 1) for a in DP_AXES]))
+            return sequence_tiled_loss(hidden, head, labels, c.loss_tiles,
+                                       groups=replicas)
         logits = jnp.einsum("bsH,HV->bsV", hidden, head)
         return masked_cross_entropy(logits, labels)
 

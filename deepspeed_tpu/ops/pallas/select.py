@@ -78,6 +78,23 @@ def record_residuals(op: str, kept: bool) -> None:
              f"from the call's shapes)")
 
 
+def record_head_loss(one_pass: bool) -> None:
+    """Count, as :func:`record_residuals` does for an op's residuals, which
+    form of the tiled output-head loss a traced call built
+    (``runtime/sequence_parallel/ulysses_sp.py:sequence_tiled_loss``):
+    ``ops/head_loss/one_pass`` (the gradient computed in the pass that
+    computes the loss: three products over the vocabulary a step) or
+    ``ops/head_loss/recomputed`` (each tile's logits computed again in the
+    backward: four)."""
+    from ...telemetry import get_telemetry
+
+    form = "one_pass" if one_pass else "recomputed"
+    get_telemetry().inc_counter(
+        f"ops/head_loss/{form}",
+        help=f"traced calls of the tiled head loss built in its {form} "
+             f"form (chosen from the head's dtype)")
+
+
 def resident_compiler_params(interpret: bool, dimension_semantics=None):
     """``compiler_params`` for a kernel holding resident planes (empty in
     the interpreter, which has no VMEM to limit and walks its grid in

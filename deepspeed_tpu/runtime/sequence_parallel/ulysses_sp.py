@@ -14,11 +14,13 @@ axis inside ``shard_map`` — an ICI-native collective XLA schedules directly.
 This replaces both the reference's torch-dist all-to-all AND the
 GSPMD-constraint formulation (which trips XLA's "involuntary full
 rematerialization" on the seq↔head reshard); tiled compute is
-``lax.scan`` + ``jax.checkpoint`` over sequence chunks.
+``lax.scan`` + ``jax.checkpoint`` over sequence chunks, and the tiled loss
+a scan with a gradient rule of its own (:func:`sequence_tiled_loss`).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Iterator, Optional
 
 import jax
@@ -27,6 +29,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ...comm.comm import all_to_all_in_graph
+from ...ops.pallas.select import record_head_loss
 from ...parallel.mesh import AXIS_SEQ, AXIS_TENSOR, DP_AXES
 from ...utils import groups as groups_mod
 from ...utils.jax_compat import shard_map as _shard_map
@@ -121,39 +124,143 @@ class TiledMLP:
         return SequenceTiledCompute.apply(mlp_fn, x, tiles, seq_axis=1)
 
 
-def sequence_tiled_loss(logits_fn: Callable[[jnp.ndarray], jnp.ndarray],
-                        hidden: jnp.ndarray, labels: jnp.ndarray,
-                        tiles: int) -> jnp.ndarray:
+def _seq_tiles(x: jnp.ndarray, tiles: int) -> jnp.ndarray:
+    """``[B, S, ...] → [tiles, B, S / tiles, ...]``, a scan's ``xs``."""
+    B, S = x.shape[:2]
+    return jnp.moveaxis(x.reshape((B, tiles, S // tiles) + x.shape[2:]), 1, 0)
+
+
+def _tile_nll(h: jnp.ndarray, head: jnp.ndarray, lab: jnp.ndarray):
+    """One tile's mathematics, written once for every form of the tiled
+    loss: the product with the head, widened to float32, and its
+    log-softmax.  Returns ``(Σ nll over the valid labels, logp, valid,
+    safe labels)``; a caller that wants the sum alone leaves the rest to
+    dead-code elimination."""
+    logits = jnp.einsum("bsH,HV->bsV", h, head).astype(jnp.float32)
+    valid = lab != -100
+    safe = jnp.where(valid, lab, 0)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+    return jnp.sum(jnp.where(valid, nll, 0.0)), logp, valid, safe
+
+
+def _mean_over_tiles(tile_sum, hidden, labels, count, tiles):
+    """``Σ_tiles tile_sum(h, lab) / max(count, 1)``: the scan that holds
+    one tile's logits at a time and nothing of a gradient."""
+    total, _ = jax.lax.scan(
+        lambda acc, xs: (acc + tile_sum(*xs), None), jnp.float32(0.0),
+        (_seq_tiles(hidden, tiles), _seq_tiles(labels, tiles)))
+    return total / jnp.maximum(count, 1)
+
+
+def _recomputing_loss(hidden, head, labels, count, tiles):
+    """The form left to autodiff: each tile under ``jax.checkpoint``, so
+    the backward scan multiplies the tile by the head a second time to get
+    its logits back (four products over the vocabulary a step)."""
+    return _mean_over_tiles(
+        jax.checkpoint(lambda h, lab: _tile_nll(h, head, lab)[0]),
+        hidden, labels, count, tiles)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _one_pass_loss(hidden, head, labels, count, tiles):
+    """The form with a gradient rule of its own.  Called where nothing is
+    differentiated it is the plain tile scan."""
+    return _mean_over_tiles(lambda h, lab: _tile_nll(h, head, lab)[0],
+                            hidden, labels, count, tiles)
+
+
+def _one_pass_fwd(hidden, head, labels, count, tiles):
+    """The loss AND its gradient in one scan over the tiles: a tile's
+    ``dlogits = (softmax − onehot) · valid / max(count, 1)`` is formed in
+    float32 from the logits the loss was just computed from, rounded to
+    the compute dtype where autodiff's cotangent is (the transpose of the
+    widening), and multiplied out at once: ``dh`` into the tile's place of
+    a ``[B, S, H]`` residual, ``dW`` summed over the tiles in float32 and
+    rounded to the head's dtype once, after the last.  The backward then
+    holds no product."""
+    H, V = head.shape
+    dtype = jnp.result_type(hidden.dtype, head.dtype)
+    scale = 1.0 / jnp.maximum(count, 1).astype(jnp.float32)
+
+    def body(carry, xs):
+        total, dW = carry
+        h, lab = xs
+        nll, logp, valid, safe = _tile_nll(h, head, lab)
+        onehot = safe[..., None] == jnp.arange(V, dtype=safe.dtype)
+        dlogits = ((jnp.exp(logp) - onehot)
+                   * (valid * scale)[..., None]).astype(dtype)
+        dh = jnp.einsum("bsV,HV->bsH", dlogits, head)
+        dW = dW + jnp.einsum("bsH,bsV->HV", h, dlogits,
+                             preferred_element_type=jnp.float32)
+        return (total + nll, dW), dh.astype(hidden.dtype)
+
+    (total, dW), dh = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.zeros((H, V), jnp.float32)),
+        (_seq_tiles(hidden, tiles), _seq_tiles(labels, tiles)))
+    dh = jnp.moveaxis(dh, 0, 1).reshape(hidden.shape)
+    return total / jnp.maximum(count, 1), (dh, dW.astype(head.dtype))
+
+
+def _one_pass_bwd(tiles, residuals, g):
+    """``g`` times what the forward left; labels and count get nothing."""
+    return tuple((g * r).astype(r.dtype) for r in residuals) + (None, None)
+
+
+_one_pass_loss.defvjp(_one_pass_fwd, _one_pass_bwd)
+
+
+def sequence_tiled_loss(hidden: jnp.ndarray, head: jnp.ndarray,
+                        labels: jnp.ndarray, tiles: int,
+                        groups: int = 1) -> jnp.ndarray:
     """Tiled final-projection + cross-entropy (never materializes the full
     ``[B, S, V]`` logits — the dominant activation at large vocab).
 
-    Returns (sum_nll, valid_count) reduced over all positions; labels use the
-    HF ``-100`` ignore convention.
+    ``hidden [B, S, H]`` times ``head [H, V]`` (both in the compute dtype),
+    ``tiles`` sequence tiles at a time (one tile where ``S % tiles``);
+    labels use the HF ``-100`` ignore convention.  Returns the MEAN
+    negative log-likelihood over the valid labels, a float32 scalar (0
+    where no label is valid).
+
+    Differentiated, the loss computes its gradient in the pass that
+    computes the loss (:func:`_one_pass_fwd`: three products over the
+    vocabulary a step, the backward none), where a tile left to
+    ``jax.checkpoint`` recomputes its logits in the backward (four).  The
+    form follows the head's dtype, which is all that decides it: bfloat16
+    and float32 take the one-pass form; a float16 head (or any other) keeps
+    the recomputing one, because its loss scale has to reach ``dlogits`` before
+    they are rounded (the scaled cotangent keeps small gradients out of
+    float16's subnormals) and the one-pass form applies the incoming
+    cotangent after the rounding.  Which form a traced call built is
+    counted: ``ops/head_loss/one_pass`` / ``ops/head_loss/recomputed``.
+
+    ``groups`` (the caller's count of data-parallel replicas; ignored where
+    it does not divide ``B``) keeps the head's gradient as one partial sum
+    a group of batch rows until the scan is over.  A contraction over a
+    batch that GSPMD has sharded, inside the scan, is a reduce-scatter of
+    the whole ``[H, V]`` every tile; the groups' axis is sharded as the
+    batch is, so each replica sums its own tiles and the replicas are
+    added once a step.
+
+    Keep ``jax.checkpoint`` off this function's callers: a remat around the
+    loss tail would run the whole one-pass forward, gradient products and
+    all, a second time.
     """
     B, S, H = hidden.shape
     if tiles <= 1 or S % tiles:
         tiles = 1
-    hs = jnp.moveaxis(hidden.reshape(B, tiles, S // tiles, H), 1, 0)
-    ls = jnp.moveaxis(labels.reshape(B, tiles, S // tiles), 1, 0)
-
-    def body(acc, xs):
-        h, lab = xs
-
-        def chunk_nll(h):
-            logits = logits_fn(h).astype(jnp.float32)
-            valid = lab != -100
-            safe = jnp.where(valid, lab, 0)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-            return (jnp.sum(jnp.where(valid, nll, 0.0)),
-                    jnp.sum(valid.astype(jnp.int32)))
-
-        nll_sum, count = jax.checkpoint(chunk_nll)(h)
-        return (acc[0] + nll_sum, acc[1] + count), None
-
-    (total, count), _ = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.int32(0)), (hs, ls))
-    return total / jnp.maximum(count, 1)
+    one_pass = head.dtype in (jnp.bfloat16, jnp.float32)
+    record_head_loss(one_pass)
+    count = jnp.sum((labels != -100).astype(jnp.int32))
+    form = _one_pass_loss if one_pass else _recomputing_loss
+    if groups <= 1 or B % groups:
+        return form(hidden, head, labels, count, tiles)
+    # each group's share of the mean; vmap sums the head's cotangent (it is
+    # not mapped) over the groups' axis, after the scan
+    shares = jax.vmap(lambda h, lab: form(h, head, lab, count, tiles))(
+        hidden.reshape(groups, B // groups, S, H),
+        labels.reshape(groups, B // groups, S))
+    return jnp.sum(shares)
 
 
 # ----------------------------------------------------------------------

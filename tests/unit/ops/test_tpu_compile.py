@@ -975,6 +975,117 @@ def test_mistral_step_runs_the_flash_forward_once_a_layer_on_v5e(
         assert minor == S, (dims, order)
 
 
+def _products_over(text: str, width: int):
+    """Every matrix product (``convolution``) of a compiled program with an
+    operand or a result ``width`` wide, as its ``op_name``."""
+    shape = dict(re.findall(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = \(?\w+\[([\d,]*)\]", text, re.M))
+    found = []
+    for dims, a, b, op in re.findall(
+            r"= \w+\[([\d,]*)\]\S* convolution\(%([\w.\-]+), %([\w.\-]+)\)"
+            r'[^\n]*op_name="([^"]*)"', text):
+        if any(str(width) in d.split(",")
+               for d in (dims, shape.get(a, ""), shape.get(b, ""))):
+            found.append(op)
+    return found
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "data4_zero3"])
+def test_mistral_step_multiplies_by_the_head_three_times_on_v5e(
+        topo, one_chip, monkeypatch, chips):
+    """The loss and gradient of a two-layer Mistral-7B at the training
+    cells' row (8,192 tokens, bf16, remat, ``loss_tiles=8``, the whole
+    32,000-token vocabulary), on one chip and as a ``data=4`` mesh under
+    the ZeRO-3 sharder's specs: THREE products over the vocabulary (the
+    tile's logits, ``dh``, ``dW``), all in the loss tail's one scan and
+    none recomputed (a checkpointed tile made four, 11.4 ms a step, PR 49's
+    trace); no float32 value over the vocabulary larger than one tile's
+    logits but the head's and the embedding's own gradients; and on four chips ONE all-gather of
+    the head (the compiler sinks it into the scan's body, its own choice)
+    and no REDUCTION over ``[.., 32000]`` inside a scan's body (a ``dW``
+    carried through the scan as one array was reduce-scattered every tile:
+    3.2 ms each, eight a step; the replicas' partial sums are added once,
+    after the scan)."""
+    import importlib
+
+    from deepspeed_tpu.models import LlamaConfig, LlamaModel
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "reference_off_tpu", lambda interpret: False)
+    L, S, H, V, tiles = 2, 8192, 4096, 32000, 8
+    config = LlamaConfig.mistral_7b(num_layers=L, dtype=jnp.bfloat16,
+                                    attn_impl="flash", remat=True,
+                                    loss_tiles=tiles)
+    assert (config.hidden_size, config.vocab_size) == (H, V)
+    if chips == 1:
+        model = LlamaModel(config)
+        shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+        params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), shapes)
+        ids = jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=one_chip)
+        constrain = lambda grads: grads
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from deepspeed_tpu.parallel import MeshLayout
+        from deepspeed_tpu.parallel.mesh import DP_AXES, build_mesh
+        from deepspeed_tpu.runtime.zero.config import DeepSpeedZeroConfig
+        from deepspeed_tpu.runtime.zero.sharder import ZeroShardingPolicy
+        from deepspeed_tpu.utils import groups
+
+        layout = MeshLayout.infer(chips)
+        mesh = groups.initialize_mesh(
+            layout, build_mesh(layout, devices=topo.devices))
+        model = LlamaModel(config, mesh=mesh)
+        policy = ZeroShardingPolicy.from_config(
+            mesh, DeepSpeedZeroConfig(stage=3))
+        shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+        base = model.param_specs()
+        params = jax.tree.map(
+            lambda a, sharding: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                     sharding=sharding),
+            shapes, policy.param_shardings(shapes, base))
+        ids = jax.ShapeDtypeStruct(
+            (chips, S), jnp.int32,
+            sharding=NamedSharding(mesh, PartitionSpec(DP_AXES, None)))
+        constrain = lambda grads: policy.apply_grad_constraints(grads, base)
+
+    def step(params, batch):
+        # as the engine's step: the compute copy is cast outside the
+        # gradient, the gradients widened and put where the sharder says
+        compute = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
+        loss, grads = jax.value_and_grad(model.loss)(compute, batch)
+        return loss, constrain(jax.tree.map(
+            lambda g: g.astype(jnp.float32), grads))
+
+    text = jax.jit(step).lower(
+        params, {"input_ids": ids}).compile().as_text()
+
+    products = _products_over(text, V)
+    assert len(products) == 3, products
+    assert not [op for op in products
+                if "rematted_computation" in op or "transpose(" in op], products
+
+    tile = (S // tiles) * V
+    too_large = {dims for dims in re.findall(r"\bf32\[([\d,]+)\]", text)
+                 if str(V) in dims.split(",")
+                 and np.prod([int(n) for n in dims.split(",")]) > tile
+                 and sorted(int(n) for n in dims.split(",")
+                            if n != "1") != [H, V]}   # head and embedding
+    assert not too_large, too_large
+
+    if chips > 1:
+        gathers = re.findall(
+            rf"= bf16\[{H},{V}\]\S* all-gather(?:-start)?\(", text)
+        assert len(gathers) == 1, gathers
+        computations = _computations(text)
+        for body in set(re.findall(r"\bbody=%?([\w.\-]+)", text)):
+            inside = [line[:160] for line in computations[body].splitlines()
+                      if f",{V}]" in line and re.search(
+                          r"all-reduce|reduce-scatter", line)]
+            assert not inside, (body, inside)
+
+
 @pytest.mark.parametrize("masked", [False, True],
                          ids=["no_mask", "attention_mask"])
 def test_bert_step_holds_the_flash_kernels_on_v5e(one_chip, monkeypatch,
