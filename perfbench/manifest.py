@@ -15,6 +15,32 @@ later PR adds a cell or a metric by adding files and entries:
 A configuration of a family that is there adds one file of sizes; a new
 family adds its module beside the others.
 
+What admits a configuration is ``check_configuration`` in
+``tests/perfbench_tests/test_perfbench_manifest.py``, which runs for every
+entry of ``configs``.  It holds the family's ``train_flops_per_token / 6``
+(the weights a trained token of the MODEL passes in the layers that are
+run) between two ends worked out there from the file's published keys, as
+a sum over the layers by what each holds:
+
+    hybrid_override_pattern          one part a layer: M a mixer, * attention,
+                                     E experts, - a dense FFN; as long as
+                                     num_hidden_layers.  No such key:
+                                     attention and an FFN in every layer
+    first_k_dense_replace,           the layers whose FFN is the dense
+    moe_layer_freq                   intermediate_size, not the experts
+    hidden_size H                    attention 2 H^2 .. 5 H^2
+    mamba_num_heads x mamba_head_dim a mixer 2.5 H D .. 4 H D
+    intermediate_size                a dense FFN 2 H I .. 4 H I
+    num_experts_per_tok              routed experts a token, each
+    moe_intermediate_size (else        2 w I .. 4 w I, w = moe_latent_size
+      intermediate_size),              where the file has it (then + 2 H w
+    moe_latent_size                    for the projections), else H
+    n_shared_experts (null = 0),     shared experts of 2 H S .. 4 H S
+    moe_shared_expert_intermediate_size
+    num_experts | num_local_experts  the router, H x the PUBLISHED count
+      | n_routed_experts, published    (high end only)
+    vocab_size                       embedding and head 2 H V (high end only)
+
 Nothing here (or in any module under ``perfbench/``) names a cell, a
 configuration or a metric.
 """

@@ -90,14 +90,28 @@ def result_line(bench: dict, cell: dict, obs: dict, device: dict,
         device["window_s"] = tr.window_s
         line["breakdown"] = {"device_ops": trace_reduce.top_ops(tr),
                              "idle_gaps": trace_reduce.idle_gaps(tr)}
-    # what the driver ignores and a reader of the log wants
-    line["check"] = obs.get("check")
+    # what the driver ignores and a reader of the log wants; last the
+    # check, which holds every number compared and its limit
     line["compiles_in_window"] = obs["compiles_in_window"]
     line["window_s"] = obs["t_close"] - obs["t_open"]
-    for key in ("program_defaults", "losses", "memory", "setup_s"):
+    for key in ("rounds", "program_defaults", "losses", "memory", "setup_s"):
         if key in obs:
             line[key] = obs[key]
+    line["check"] = obs.get("check")
     return line
+
+
+def print_compared(check: dict) -> None:
+    """Each number the check compared beside its limit, as the run's last
+    lines on standard error: read off the runner's ``check``, whose
+    ``tolerance`` is one limit for a serving cell's ``logit_gap`` and one
+    a number for a training cell."""
+    limits = (check or {}).get("tolerance", {})
+    if not isinstance(limits, dict):
+        limits = {"logit_gap": limits}
+    for name, limit in limits.items():
+        print(f"perfbench: {name} {check[name]!r} limit {limit!r}",
+              file=sys.stderr, flush=True)
 
 
 def main() -> int:
@@ -124,8 +138,9 @@ def main() -> int:
     if obs["compiles_in_window"]:
         raise SystemExit(f"perfbench: {obs['compiles_in_window']} program(s) "
                          f"compiled inside the measured window")
-    print(json.dumps(result_line(bench, cell, obs, device, bool(args.trace))),
-          flush=True)
+    line = result_line(bench, cell, obs, device, bool(args.trace))
+    print_compared(line["check"])
+    print(json.dumps(line), flush=True)
     return 0
 
 

@@ -307,6 +307,9 @@ def run(ctx: harness.Context) -> Dict[str, Any]:
     }
     out["work"] = {"tokens": arith.delivered_tokens(
         arith.in_window(session.deliveries, t_open, t_close))}
+    # one program call a round: an untraced run's count of the window's
+    # calls (the program's own counter ``inference/calls`` needs the hub on)
+    out["rounds"] = len(out["pumps"])
     session.close()
     return out
 

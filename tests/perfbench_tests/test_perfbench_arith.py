@@ -33,9 +33,8 @@ def test_span_and_counter_readers():
     obs = {"program_spans": spans, "t_open": 0.0, "t_close": 2.0,
            "program_counters": {"inference/decode_tokens": 270.0}}
     read = lambda name, args: manifest.load_module("readers", name).read(obs, args)
-    assert read("span_share_pct", {"span": "inference/prefill"}) == pytest.approx(2.5)
     assert read("span_ms", {"span": "inference/decode_burst", "per": "burst",
                             "q": 50}) == pytest.approx(110.0)
     assert read("counter_per_span", {"counter": "inference/decode_tokens",
                                      "span": "inference/decode_burst"}) == 135.0
-    assert read("span_share_pct", {"span": "nothing/here"}) is None
+    assert read("span_ms", {"span": "nothing/here", "q": 50}) is None

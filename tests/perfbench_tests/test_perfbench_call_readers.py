@@ -161,9 +161,60 @@ def test_a_new_metric_is_an_entry_and_a_file_that_agree(metric):
     assert entry["source"] == ("device_trace" if metric in GAP + PARTS
                                else "program_counter" if metric in RATIOS
                                else "program_span")
-    # added at the end of the list, in one block
+    assert in_one_block([m["name"] for m in BENCH["per_layer"]])
+
+
+def in_one_block(names):
+    """PR 38's twelve lie in one block, in their order, wherever in the
+    list it is: a later PR appends its entries behind it."""
+    at = names.index(NEW[0])
+    return names[at:at + len(NEW)] == NEW
+
+
+def test_a_later_metric_is_appended_and_the_block_holds():
+    """What any PR that may add a metric does: its entry goes to the end
+    of ``per_layer``.  The block is not held to the end of the list (it
+    was until PR 51, and seven metrics waited without an entry)."""
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-len(NEW):] == NEW
+    assert in_one_block(names + ["a_later_metric.batch"])
+    assert names[-len(NEW):] != NEW       # entries already lie behind it
+    # an entry INSIDE the block, or the block out of order, is refused
+    at = names.index(NEW[0])
+    assert not in_one_block(names[:at + 3] + ["x.batch"] + names[at + 3:])
+    assert not in_one_block(names[:at] + NEW[::-1] + names[at + len(NEW):])
+
+
+AHEAD = "calls_ahead_share.batch"
+
+
+def test_the_share_of_calls_dispatched_ahead_is_an_entry_and_a_file():
+    """PR 39's counter over PR 38's: data only, entered by PR 51 for the
+    five serving cells (every one drives the engine through
+    ``step_ahead``)."""
+    entry = manifest.named(BENCH["per_layer"], AHEAD, "metric")
+    # (that file and entry agree is ``test_metric``'s, for every metric)
+    spec = manifest.load_json("metrics", AHEAD)
+    assert spec["reader"] == "counter_ratio_pct" and spec["args"] == {
+        "part": "inference/calls_dispatched_ahead",
+        "whole": "inference/calls"}
+    assert (entry["layer"], entry["source"], entry["moves"]) == (
+        "v2 engine", "program_counter", "serve_tokens_per_s")
+    served = [w["name"] for w in BENCH["workloads"]
+              if w["name"].startswith("serve-")]
+    assert entry["workloads"] == served and len(served) == 5
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names.index(AHEAD) > names.index(NEW[-1])
+
+
+@pytest.mark.parametrize("counters, want", [
+    ({"inference/calls_dispatched_ahead": 802.0, "inference/calls": 802.0},
+     100.0),
+    ({"inference/calls_dispatched_ahead": 0.0, "inference/calls": 40.0}, 0.0),
+    ({"inference/calls": 40.0}, None),
+    ({"inference/calls_dispatched_ahead": 3.0}, None),
+    ({}, None)])
+def test_calls_ahead_reads_both_counters_or_nothing(counters, want):
+    assert read(AHEAD, {"program_counters": counters}) == want
 
 
 def test_the_parts_name_spans_that_do_not_overlap():

@@ -282,13 +282,11 @@ def test_a_program_without_the_state_gives_nothing_to_read(missing):
 
 
 @pytest.mark.parametrize("metric", NEW)
-def test_a_new_metric_file_is_whole_and_waits_for_its_entry(metric):
-    """The four metrics' files, readers and shapes are here.  Their
-    ``per_layer`` entries are not: ``test_perfbench_call_readers.py`` holds
-    PR 38's twelve to the END of the list, and an entry put before them
-    reads as an edit of what was there (PERF.md §7): where a later
-    ``benchmark`` PR enters them, they list this cell alone and lie in one
-    block."""
+def test_a_new_metric_is_a_file_and_an_entry_that_agree(metric):
+    """The four metrics' files, readers and shapes came with the cell;
+    their ``per_layer`` entries waited until PR 51 unpinned the list
+    (``test_perfbench_call_readers.py`` held PR 38's twelve to its END):
+    they list this cell alone and lie in one block."""
     spec = manifest.load_json("metrics", metric)
     assert spec["name"] == metric and spec["moves"] == "serve_tokens_per_s"
     assert (manifest.BENCH_DIR / "readers" / f"{spec['reader']}.py").is_file()
@@ -304,14 +302,13 @@ def test_a_new_metric_file_is_whole_and_waits_for_its_entry(metric):
         re.compile(spec["args"]["pattern"])
     names = [m["name"] for m in BENCH["per_layer"]]
     listed = [m for m in BENCH["per_layer"] if m["name"] in NEW]
-    for entry in listed:
-        assert entry["workloads"] == [CELL["name"]]
-        assert {k: spec[k] for k in entry if k != "workloads"} == {
-            k: v for k, v in entry.items() if k != "workloads"}
-    assert len(listed) in (0, len(NEW))
-    if listed:
-        at = names.index(NEW[0])
-        assert names[at:at + len(NEW)] == NEW
+    assert all(entry["workloads"] == [CELL["name"]] for entry in listed)
+    entry = manifest.named(listed, metric, "metric")
+    assert {k: spec[k] for k in entry if k != "workloads"} == {
+        k: v for k, v in entry.items() if k != "workloads"}
+    assert len(listed) == len(NEW)
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == NEW
 
 
 def test_the_cell_joins_the_serving_metrics_that_are_not_pinned():
@@ -322,7 +319,8 @@ def test_the_cell_joins_the_serving_metrics_that_are_not_pinned():
         "paged_attn_roofline", "device_idle_share", "peak_hbm_gb",
         "queue_wait_ms_p50", "frontend_host_ms_p50", "engine_host_ms_p50",
         "decode_dispatch_ms_p50", "decode_device_step_ms_p50",
-        "idle_in_pump_share", "chunk_tokens_per_decode_call")}
+        "idle_in_pump_share", "chunk_tokens_per_decode_call",
+        "calls_ahead_share")} | set(NEW)
     assert {m["name"] for m in manifest.cell_metrics(
         BENCH, CELL["name"], "end_to_end")} == {"serve_tokens_per_s",
                                                 "setup_s"}

@@ -56,6 +56,85 @@ def test_cells_are_unique_and_four_chip_cells_are_within_quota():
     assert four <= max(1, len(CELLS) // 4)
 
 
+#: what one character of a published layer pattern says a layer holds
+PATTERN_KEY = "hybrid_override_pattern"
+PATTERN_PARTS = {"M": ("mixer",), "*": ("attention",), "E": ("experts",),
+                 "-": ("ffn",)}
+EXPERT_COUNT_KEYS = ("num_experts", "num_local_experts", "n_routed_experts")
+
+
+def layer_parts(cfg):
+    """For each layer that is run, the parts it holds, from published keys
+    alone.  Where the file has ``hybrid_override_pattern`` a layer holds
+    ONE part, by its character (``M`` a state-space mixer, ``*``
+    attention, ``E`` experts, ``-`` a dense FFN), and the pattern is as
+    long as the layers run: a file cut to fewer layers cuts the pattern
+    with them.  Where it has none, every layer holds attention and an FFN,
+    which is the experts where the file has an expert count and the dense
+    ``intermediate_size`` where it has none, in the ``first_k_dense_replace``
+    leading layers and in the layers ``moe_layer_freq`` marks 0."""
+    layers = cfg["num_hidden_layers"]
+    pattern = cfg.get(PATTERN_KEY)
+    if pattern is not None:
+        if len(pattern) != layers or set(pattern) - set(PATTERN_PARTS):
+            raise ValueError(
+                f"{PATTERN_KEY} {pattern!r} does not give each of the "
+                f"{layers} layers of num_hidden_layers one of "
+                f"{sorted(PATTERN_PARTS)}")
+        return [PATTERN_PARTS[c] for c in pattern]
+    sparse = any(k in cfg for k in EXPERT_COUNT_KEYS)
+    freq = cfg.get("moe_layer_freq")
+    dense = [not sparse or i < cfg.get("first_k_dense_replace", 0)
+             or (isinstance(freq, list) and not freq[i])
+             for i in range(layers)]
+    return [("attention", "ffn" if d else "experts") for d in dense]
+
+
+def token_weight_bracket(cfg):
+    """(low, high): the weights a trained token of the MODEL passes
+    through in the layers that are run (all ``num_experts_per_tok``
+    experts, wherever a deployment holds them: the chip's share of a held
+    subset is smaller and is not what is bracketed), summed over the
+    layers by what each holds (``layer_parts``), from published keys:
+
+    - attention: 2 H^2 .. 5 H^2 (grouped-query to latent projections);
+    - a mixer: 2.5 H D .. 4 H D, D = ``mamba_num_heads`` x
+      ``mamba_head_dim`` (in and out projections of 3 H D and the B, C
+      and step columns on top);
+    - a dense FFN: 2 H I .. 4 H I, I = ``intermediate_size`` (two or three
+      matrices, up to a wider gate);
+    - experts: ``num_experts_per_tok`` routed ones of 2 w I .. 4 w I each,
+      I = ``moe_intermediate_size`` where the file has it, else
+      ``intermediate_size``; w = ``moe_latent_size`` where the file has
+      it, and then the two projections H -> w -> H, 2 H w, in both ends;
+      else w = H.  ``n_shared_experts`` (``null`` reads as 0) shared ones
+      of 2 H S .. 4 H S, S = ``moe_shared_expert_intermediate_size`` where
+      the file has it, else I.  The router, in the high end only: H x the
+      PUBLISHED expert count (``published.n_routed_experts`` where the
+      file holds a reduced share of them);
+    - embedding and head, in the high end only: 2 H V of the file's V."""
+    H, V = cfg["hidden_size"], cfg["vocab_size"]
+    I = cfg.get("moe_intermediate_size", cfg["intermediate_size"])
+    w = cfg.get("moe_latent_size", H)
+    k = cfg.get("num_experts_per_tok", 1)
+    shared = (cfg.get("n_shared_experts") or 0) * H * cfg.get(
+        "moe_shared_expert_intermediate_size", I)
+    published = dict(cfg, **cfg.get("published", {}))
+    router = H * next((published[key] for key in EXPERT_COUNT_KEYS
+                       if key in published), 0)
+    latent = 2 * H * w if "moe_latent_size" in cfg else 0
+    D = cfg.get("mamba_num_heads", 0) * cfg.get("mamba_head_dim", 0)
+    ends = {"attention": (2 * H * H, 5 * H * H),
+            "mixer": (2.5 * H * D, 4 * H * D),
+            "ffn": (2 * H * cfg["intermediate_size"],
+                    4 * H * cfg["intermediate_size"]),
+            "experts": (2 * k * w * I + latent + 2 * shared,
+                        4 * k * w * I + latent + 4 * shared + router)}
+    held = [ends[part] for parts in layer_parts(cfg) for part in parts]
+    return (sum(low for low, _ in held),
+            sum(high for _, high in held) + 2 * H * V)
+
+
 def check_configuration(bench, entry, cfg, family):
     """Everything one configuration is held to: its entry, its file, its
     family's module and the operations it counts for a trained token."""
@@ -79,25 +158,12 @@ def check_configuration(bench, entry, cfg, family):
     assert "deepspeed_tpu" not in reference
     # 6 operations per weight a trained token passes through, and attention
     # on top.  The bracket is worked out here from the published keys, not
-    # by the family's module.  A sparse-expert family's keys: the width of
-    # one expert is ``moe_intermediate_size`` where the config has it
-    # (DeepSeek, Qwen-MoE) and ``intermediate_size`` where it has not
-    # (OLMoE, Mixtral); a token runs ``num_experts_per_tok`` routed experts
-    # and ``n_shared_experts`` shared ones of that width; the router is one
-    # [H, E] matrix a layer, E being ``num_experts`` (OLMoE, Qwen-MoE),
-    # ``num_local_experts`` (Mixtral) or ``n_routed_experts`` (DeepSeek).
-    # A dense family has none of these keys: one FFN, no router.  Leading
-    # dense layers of another width (``first_k_dense_replace``) are not
-    # read: left to the PR that brings such a family.
+    # by the family's module, as a sum over the layers by what each holds
+    # (``layer_parts``, ``token_weight_bracket``: key by key there).  The
+    # low end is not strict: an expert of two matrices is a real expert.
     n = family.train_flops_per_token(cfg, cfg["run"].get("seq", 512)) / 6
-    H, L, V = (cfg[k] for k in ("hidden_size", "num_hidden_layers",
-                                "vocab_size"))
-    I = cfg.get("moe_intermediate_size", cfg["intermediate_size"])
-    m = cfg.get("num_experts_per_tok", 1) + cfg.get("n_shared_experts", 0)
-    R = H * next((cfg[k] for k in ("num_experts", "num_local_experts",
-                                   "n_routed_experts") if k in cfg), 0)
-    assert L * (2 * H * H + 2 * m * H * I) < n \
-        < L * (5 * H * H + 4 * m * H * I + R) + 2 * H * V
+    low, high = token_weight_bracket(cfg)
+    assert low <= n < high, (low, n, high)
 
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
@@ -149,13 +215,7 @@ def test_a_sparse_expert_configuration_is_held_by_its_own_keys(
     """The bracket follows the configuration's sparsity: the operations of
     the eight experts a token runs pass; those of all 64 (what the chip
     stores) and of one (``intermediate_size`` read as a dense FFN) fail."""
-    import importlib.util
-
-    (tmp_path / "olmoe.py").write_text(SPARSE_FAMILY)
-    spec = importlib.util.spec_from_file_location("olmoe_stub",
-                                                  tmp_path / "olmoe.py")
-    family = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(family)
+    family = _stub_family(tmp_path, SPARSE_FAMILY, "olmoe")
     entry = {"name": "olmoe-1b-7b-serve-l8", "source": SPARSE["source"],
              "file": "perfbench/configs/olmoe-1b-7b-serve-l8.json",
              "reduced": ["num_hidden_layers"], "why": "64 experts, 8 a token"}
@@ -173,6 +233,162 @@ def test_a_sparse_expert_configuration_is_held_by_its_own_keys(
              if k not in ("num_experts", "num_experts_per_tok")}
     with pytest.raises(AssertionError):
         check_configuration(bench, entry, dense, family)
+
+
+#: the published keys of a family the benchmark does not hold yet (the
+#: catalog's row of a hybrid of three layer kinds with latent experts),
+#: cut by hand to one period of its pattern and a quarter of its
+#: vocabulary, with a stub family whose count is ``_weights_a_token``:
+#: each layer is ONE of a Mamba-2 mixer, an attention or an expert layer
+#: whose 22 routed experts of two matrices work at a 1,024-wide latent
+LAYER_KINDS = {
+    "hidden_size": 4096, "intermediate_size": 2688, "num_hidden_layers": 11,
+    "hybrid_override_pattern": "MEMEMEM*EME", "num_attention_heads": 32,
+    "num_key_value_heads": 2, "head_dim": 128, "mamba_num_heads": 128,
+    "mamba_head_dim": 64, "n_groups": 8, "ssm_state_size": 128,
+    "moe_intermediate_size": 2688, "moe_latent_size": 1024,
+    "moe_shared_expert_intermediate_size": 5376, "n_routed_experts": 128,
+    "n_shared_experts": 1, "num_experts_per_tok": 22, "vocab_size": 32768,
+    "published": {"num_hidden_layers": 88, "n_routed_experts": 512,
+                  "vocab_size": 131072},
+    "model_type": "layer_kinds_stub", "source": "https://example.org/config",
+    "reduced": {"num_hidden_layers": "88 -> 11", "n_routed_experts": "512 -> "
+                "128 held", "vocab_size": "a quarter",
+                "hybrid_override_pattern": "its first period"},
+    "run": {}}
+LAYER_KINDS_FAMILY = '''
+def build(cfg, mesh=None):
+    raise NotImplementedError("a stub: only its operation count is read")
+
+
+def train_flops_per_token(cfg, seq):
+    return 6.0 * cfg["_weights_a_token"]
+
+
+# -- the plain reference
+def forward(weights, cfg, ids):
+    with jax.default_matmul_precision("highest"):
+        raise NotImplementedError
+
+
+def loss(weights, cfg, batch):
+    raise NotImplementedError
+'''
+
+
+def _layer_kinds_counts(cfg):
+    """By hand, from the keys: a mixer layer, the attention layer, an
+    expert layer outside its routed experts, one routed expert at width
+    ``w``, and the head over the file's vocabulary."""
+    H, D = cfg["hidden_size"], cfg["mamba_num_heads"] * cfg["mamba_head_dim"]
+    mixer = (3 * H * D + 2 * H * cfg["n_groups"] * cfg["ssm_state_size"]
+             + H * cfg["mamba_num_heads"])
+    attention = 2 * H * cfg["head_dim"] * (cfg["num_attention_heads"]
+                                            + cfg["num_key_value_heads"])
+    beside = (2 * H * cfg["moe_shared_expert_intermediate_size"]
+              + 2 * H * cfg["moe_latent_size"]
+              + H * cfg["published"]["n_routed_experts"])
+    expert = lambda w: 2 * w * cfg["moe_intermediate_size"]
+    return mixer, attention, beside, expert, H * cfg["vocab_size"]
+
+
+def _stub_family(tmp_path, text, name):
+    import importlib.util
+
+    (tmp_path / f"{name}.py").write_text(text)
+    spec = importlib.util.spec_from_file_location(name + "_stub",
+                                                  tmp_path / f"{name}.py")
+    family = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(family)
+    return family
+
+
+@pytest.mark.parametrize("case", ["one_part_a_layer", "experts_at_hidden",
+                                  "every_part_in_every_layer",
+                                  "pattern_of_another_length"])
+def test_a_configuration_is_read_by_the_kinds_of_its_layers(case, tmp_path):
+    """The bracket is a sum over the layers by what the published pattern
+    says each holds.  The honest count of one period (1.60 B a token)
+    passes; the routed experts counted at the hidden width in place of
+    the latent one (4 x their term) and every layer counted as holding a
+    mixer, an attention AND experts fail; a pattern that is not as long as
+    the layers run is an error that names the key."""
+    cfg = dict(LAYER_KINDS)
+    mixer, attention, beside, expert, head = _layer_kinds_counts(cfg)
+    k, H, w = cfg["num_experts_per_tok"], cfg["hidden_size"], \
+        cfg["moe_latent_size"]
+    n = {"M": cfg["hybrid_override_pattern"].count("M"),
+         "*": cfg["hybrid_override_pattern"].count("*"),
+         "E": cfg["hybrid_override_pattern"].count("E")}
+    honest = (n["M"] * mixer + n["*"] * attention
+              + n["E"] * (beside + k * expert(w)) + head)
+    assert (mixer, attention) == (109_576_192, 35_651_584)
+    assert expert(w) == 5_505_024 and 1.59e9 < honest < 1.60e9
+    cfg["_weights_a_token"] = {
+        "one_part_a_layer": honest,
+        "pattern_of_another_length": honest,
+        "experts_at_hidden": honest + n["E"] * k * (expert(H) - expert(w)),
+        "every_part_in_every_layer": cfg["num_hidden_layers"] * (
+            mixer + attention + beside + k * expert(w)) + head}[case]
+    family = _stub_family(tmp_path, LAYER_KINDS_FAMILY, "layer_kinds")
+    entry = {"name": "layer-kinds-l11", "source": cfg["source"],
+             "file": "perfbench/configs/layer-kinds-l11.json",
+             "reduced": list(cfg["reduced"]), "why": "one part a layer"}
+    bench = dict(BENCH, workloads=BENCH["workloads"] + [
+        {"name": "serve-layer-kinds", "config": entry["name"]}])
+    if case == "one_part_a_layer":
+        check_configuration(bench, entry, cfg, family)
+        low, high = token_weight_bracket(cfg)
+        # the reading before this one (attention and 23 experts of [H, I]
+        # in every layer) put the low end at 5.94 B: no honest count fits
+        assert low < 1.4e9 and 11 * (2 * H * H + 2 * 23 * H * 2688) > 5.9e9
+    elif case == "pattern_of_another_length":
+        cfg["hybrid_override_pattern"] += "M"
+        with pytest.raises(ValueError, match="hybrid_override_pattern"):
+            check_configuration(bench, entry, cfg, family)
+    else:
+        with pytest.raises(AssertionError):
+            check_configuration(bench, entry, cfg, family)
+
+
+@pytest.mark.parametrize("keys, low, high", [
+    # a dense decoder: attention and one FFN a layer, no router
+    ({"hidden_size": 8, "intermediate_size": 16, "num_hidden_layers": 2,
+      "vocab_size": 10}, 2 * (128 + 256), 2 * (320 + 512) + 160),
+    # ``n_shared_experts: null`` reads as 0, and the router is H x the
+    # PUBLISHED expert count where the file holds a share
+    ({"hidden_size": 8, "intermediate_size": 16, "moe_intermediate_size": 4,
+      "num_hidden_layers": 1, "vocab_size": 10, "n_routed_experts": 2,
+      "n_shared_experts": None, "num_experts_per_tok": 2,
+      "published": {"n_routed_experts": 32}},
+     128 + 2 * 2 * 8 * 4, 320 + 4 * 2 * 8 * 4 + 8 * 32 + 160),
+    # ``first_k_dense_replace`` leading layers take the dense width, and
+    # ``moe_layer_freq`` marks a dense layer with 0
+    ({"hidden_size": 8, "intermediate_size": 16, "moe_intermediate_size": 4,
+      "num_hidden_layers": 2, "vocab_size": 10, "n_routed_experts": 4,
+      "n_shared_experts": 1, "num_experts_per_tok": 2,
+      "first_k_dense_replace": 1},
+     2 * 128 + 256 + (2 * 2 + 2) * 32, 2 * 320 + 512 + (4 * 2 + 4) * 32
+     + 32 + 160),
+    ({"hidden_size": 8, "intermediate_size": 16, "moe_intermediate_size": 4,
+      "num_hidden_layers": 2, "vocab_size": 10, "n_routed_experts": 4,
+      "num_experts_per_tok": 2, "moe_layer_freq": [0, 1]},
+     2 * 128 + 256 + 2 * 2 * 32, 2 * 320 + 512 + 4 * 2 * 32 + 32 + 160),
+    # a pattern: a mixer alone, an attention alone, a dense FFN alone
+    ({"hidden_size": 8, "intermediate_size": 16, "num_hidden_layers": 3,
+      "vocab_size": 10, "hybrid_override_pattern": "M*-",
+      "mamba_num_heads": 4, "mamba_head_dim": 4},
+     2.5 * 8 * 16 + 128 + 256, 4 * 8 * 16 + 320 + 512 + 160)],
+    ids=["dense", "null_shared_and_published_router", "leading_dense_layers",
+         "moe_layer_freq", "pattern_of_single_parts"])
+def test_the_bracket_key_by_key(keys, low, high):
+    assert token_weight_bracket(keys) == (low, high)
+
+
+def test_a_pattern_names_its_key_where_a_character_is_unknown():
+    cfg = dict(LAYER_KINDS, hybrid_override_pattern="MEMEMEM?EME")
+    with pytest.raises(ValueError, match="hybrid_override_pattern"):
+        layer_parts(cfg)
 
 
 def test_configuration_files_are_not_shared():

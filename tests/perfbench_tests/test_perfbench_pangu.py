@@ -246,13 +246,11 @@ def test_a_program_without_the_latent_cache_gives_nothing_to_read(missing):
 
 
 @pytest.mark.parametrize("metric", NEW)
-def test_a_new_metric_file_is_whole_and_waits_for_its_entry(metric):
-    """The two metrics' files, readers and shapes are here.  Their
-    ``per_layer`` entries are not: ``test_perfbench_call_readers.py`` holds
-    PR 38's twelve to the END of the list, and an entry put before them
-    reads as an edit of what was there (PERF.md §7): where a later
-    ``benchmark`` PR enters them, they list this cell alone and lie in one
-    block."""
+def test_a_new_metric_is_a_file_and_an_entry_that_agree(metric):
+    """The two metrics' files, readers and shapes came with the cell;
+    their ``per_layer`` entries waited until PR 51 unpinned the list
+    (``test_perfbench_call_readers.py`` held PR 38's twelve to its END):
+    they list this cell alone and lie in one block."""
     spec = manifest.load_json("metrics", metric)
     assert spec["name"] == metric and spec["moves"] == "serve_tokens_per_s"
     assert (manifest.BENCH_DIR / "readers" / f"{spec['reader']}.py").is_file()
@@ -261,14 +259,13 @@ def test_a_new_metric_file_is_whole_and_waits_for_its_entry(metric):
                              else "v2 engine")
     names = [m["name"] for m in BENCH["per_layer"]]
     listed = [m for m in BENCH["per_layer"] if m["name"] in NEW]
-    for entry in listed:
-        assert entry["workloads"] == [CELL["name"]]
-        assert {k: spec[k] for k in entry if k != "workloads"} == {
-            k: v for k, v in entry.items() if k != "workloads"}
-    assert len(listed) in (0, len(NEW))
-    if listed:
-        at = names.index(NEW[0])
-        assert names[at:at + len(NEW)] == NEW
+    assert all(entry["workloads"] == [CELL["name"]] for entry in listed)
+    entry = manifest.named(listed, metric, "metric")
+    assert {k: spec[k] for k in entry if k != "workloads"} == {
+        k: v for k, v in entry.items() if k != "workloads"}
+    assert len(listed) == len(NEW)
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == NEW
 
 
 def test_the_cell_joins_the_serving_metrics_that_are_not_pinned():
@@ -280,8 +277,10 @@ def test_the_cell_joins_the_serving_metrics_that_are_not_pinned():
             "tokens_per_decode_call.batch",
             "chunk_tokens_per_decode_call.batch",
             "decode_device_step_ms_p50.batch",
-            "idle_in_pump_share.batch"} <= listed
-    # silent since PR 37, another kind of cache, or pinned by PR 38's test
+            "idle_in_pump_share.batch", "calls_ahead_share.batch",
+            *NEW} <= listed
+    # retired by PR 51, not this cell's, another kind of cache, or PR 38's
+    # twelve, which stay the three older cells'
     assert not {"prefill_wall_share.batch", "prefill_device_share.batch",
                 "paged_attn_roofline.batch", "hybrid_attn_roofline.batch",
                 "call_gap_ms_p50.batch", "live_row_share.batch"} & listed

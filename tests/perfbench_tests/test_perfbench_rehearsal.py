@@ -11,6 +11,7 @@ import sys
 import pytest
 
 from perfbench import manifest
+from perfbench import run as bench_run
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 import rehearsal  # noqa: E402
@@ -69,6 +70,39 @@ def test_end_to_end_line(cell, lines):
     for name, metric in line["metrics"].items():
         assert set(metric) == {"value", "unit"} and metric["value"] > 0, name
     json.dumps({k: v for k, v in line.items() if k != "_obs"})
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_line_ends_with_the_check_and_a_serving_line_counts_its_rounds(
+        cell, lines, capsys):
+    """The check, with every number compared and its limit, is the line's
+    last key, and the same numbers are the run's last lines on standard
+    error; a serving line says how many rounds (one program call each) its
+    window held, which an untraced run could not say before PR 51."""
+    line = {k: v for k, v in lines(cell, False).items() if k != "_obs"}
+    assert list(line)[-1] == "check"
+    check = line["check"]
+    limits = check["tolerance"]
+    if not isinstance(limits, dict):
+        limits = {"logit_gap": limits}
+    assert limits and all(check[name] <= limit
+                          for name, limit in limits.items())
+    bench_run.print_compared(check)
+    err = capsys.readouterr().err.strip().splitlines()
+    assert err == [f"perfbench: {name} {check[name]!r} limit {limit!r}"
+                   for name, limit in limits.items()]
+    obs = lines(cell, False)["_obs"]
+    if "deliveries" not in obs:
+        assert "rounds" not in line         # a training cell: whole steps
+        return
+    # the window is the offered one: every delivery in it is counted
+    assert line["window_s"] == obs["t_close"] - obs["t_open"]
+    assert line["rounds"] == len(obs["pumps"]) > 0
+    assert line["rounds"] >= len({d[0] for d in obs["deliveries"]
+                                  if obs["t_open"] < d[0] <= obs["t_close"]})
+    assert line["metrics"]["serve_tokens_per_s"]["value"] == pytest.approx(
+        sum(n for t, _, n in obs["deliveries"]
+            if obs["t_open"] < t <= obs["t_close"]) / line["window_s"])
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -156,20 +156,6 @@ def test_a_programs_step_time_is_its_execution_over_the_steps_it_names():
     assert read({"trace": None}, {"pattern": BURSTS, "q": 50}) is None
 
 
-def test_a_programs_share_is_over_the_busy_time_of_the_chip():
-    # busy 0..40 and 60..80 = 60; prefill programs cover 0..12 and 60..63
-    modules = [_ev(0, 12, "jit_inference_v2_prefill(789)"),
-               _ev(12, 40, "jit_inference_v2_decode_burst_n_steps8(123)"),
-               _ev(60, 63, "jit_inference_v2_prefill(790)")]
-    obs = {"trace": made(modules)}
-    read = reader("module_share_pct").read
-    assert read(obs, {"pattern": r"^jit_inference_v2_prefill\b"}
-                ) == pytest.approx(25.0)
-    assert read(obs, {"pattern": BURSTS}) == pytest.approx(100 * 28 / 60)
-    assert read(obs, {"pattern": "^jit_no_such"}) is None
-    assert read({}, {"pattern": BURSTS}) is None
-
-
 @pytest.fixture(scope="module")
 def recorded() -> tr.Trace:
     text = (DATA / "serve_l2_v5e_decode_step.xspace.txt").read_text()
@@ -177,7 +163,6 @@ def recorded() -> tr.Trace:
 
 
 @pytest.mark.parametrize("metric", ["decode_device_step_ms_p50.batch",
-                                    "prefill_device_share.batch",
                                     "idle_in_pump_share.batch"])
 def test_the_recorded_trace_of_the_parents_program_gives_no_reading(
         recorded, metric):
@@ -193,15 +178,38 @@ def test_the_recorded_trace_of_the_parents_program_gives_no_reading(
 
 
 def test_the_new_metrics_read_the_names_the_program_gives():
-    """The patterns in the metric files against the names ``tracked_jit``
-    gives the serving programs."""
+    """The pattern in the metric file against the names ``tracked_jit``
+    gives the serving programs: the one-step program, which carries a
+    round's prefill chunks since PR 37, counts as one step and the burst
+    as eight."""
     from deepspeed_tpu.telemetry.perf.compile_tracker import program_name
 
     burst = "jit_" + program_name("inference_v2/decode_burst", {"n_steps": 8})
-    prefill = "jit_" + program_name("inference_v2/prefill")
-    modules = [_ev(0, 32, burst + "(1)"), _ev(60, 75, prefill + "(2)")]
-    obs = {"trace": made(modules)}
+    one = "jit_" + program_name("inference_v2/decode_burst", {"n_steps": 1})
     step = manifest.load_json("metrics", "decode_device_step_ms_p50.batch")
-    share = manifest.load_json("metrics", "prefill_device_share.batch")
-    assert reader(step["reader"]).read(obs, step["args"]) == pytest.approx(4e-6)
-    assert reader(share["reader"]).read(obs, share["args"]) == pytest.approx(25.0)
+    read = reader(step["reader"]).read
+    obs = {"trace": made([_ev(0, 32, burst + "(1)")])}
+    assert read(obs, step["args"]) == pytest.approx(4e-6)
+    obs = {"trace": made([_ev(0, 32, burst + "(1)"), _ev(60, 75, one + "(2)"),
+                          _ev(75, 90, one + "(2)")])}
+    assert read(obs, step["args"]) == pytest.approx(15e-6)
+
+
+@pytest.mark.parametrize("metric, reader_name", [
+    ("prefill_wall_share.batch", "span_share_pct"),
+    ("prefill_device_share.batch", "module_share_pct")])
+def test_the_two_prefill_metrics_are_gone(metric, reader_name):
+    """Both read a prefill call of its own, which no round has had since
+    PR 37 (``null`` on every ledger line since): entry, file and reader
+    went with PR 51.  ``chunk_row_share.batch`` and
+    ``calls_with_chunks_share.batch`` say what part of the work is
+    prompts."""
+    bench = manifest.load_benchmark()
+    assert metric not in {m["name"] for m in bench["per_layer"]}
+    assert not (manifest.BENCH_DIR / "metrics" / f"{metric}.json").exists()
+    assert not (manifest.BENCH_DIR / "readers" / f"{reader_name}.py").exists()
+    assert reader_name not in {
+        manifest.load_json("metrics", m["name"])["reader"]
+        for m in bench["per_layer"]}
+    for name in ("chunk_row_share.batch", "calls_with_chunks_share.batch"):
+        assert manifest.named(bench["per_layer"], name, "metric")
