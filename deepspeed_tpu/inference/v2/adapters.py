@@ -34,14 +34,24 @@ flag, and the engine knows nothing of what rides whole.
 model has (:class:`AttentionKind`: KV heads, K and V row widths, window,
 sink, rotary base; each kind has a KV pool of its own shape) and the
 PATTERN its layers follow (:class:`LayerPattern`: leading layers, then
-whole periods of one repeated sequence of kinds).  The engine runs the
-leading layers one by one and scans over the periods, a period's layers
-unrolled inside the step.  Most families have one kind and a period of one
-layer, which is what the base class states from ``kv_heads`` /
-``head_dim`` / ``window``: the dense and OLMoE adapters are that case of
-the same interface, not a second path.  :class:`MimoV2Adapter` is the
-family that mixes full and windowed layers;
-:class:`PanguUltraMoeV2Adapter` the one whose kind is a latent cache.
+whole periods of one repeated sequence).  The engine runs the leading
+layers one by one and scans over the periods, a period's layers unrolled
+inside the step.  Most families have one kind and a period of one layer,
+which is what the base class states from ``kv_heads`` / ``head_dim`` /
+``window``: the dense and OLMoE adapters are that case of the same
+interface, not a second path.  :class:`MimoV2Adapter` is the family that
+mixes full and windowed layers; :class:`PanguUltraMoeV2Adapter` the one
+whose kind is a latent cache.
+
+**A pattern's entry names the layer's PART**, and each part has its hooks:
+an attention kind's name (``qkv``, then the engine's cache write and
+attention, then ``post_attn``: the output projection and whatever the
+family has behind it in the same layer, an FFN in most and nothing in
+some); a state kind's name (``mix_in`` / ``mix_chunk`` or ``mix_decode`` /
+``mix_out``, below: a mixer that is a layer of its own); or :data:`FFN`,
+the FFN alone (``ffn_layer``).  A part's pool has as many layers as the
+pattern has of the part and a layer is handed its place among THOSE:
+:class:`NemotronHV2Adapter` is the family whose layers are one part alone.
 
 **A second kind of per-sequence state.**  A model whose layers carry a
 recurrent state beside their keys states it as ``state_kinds``
@@ -55,9 +65,10 @@ states as ``in_place`` (with the layer and the rows' first slot: a kernel's
 operands, as ``KVLayout.kernel_operands`` hands out a kind's pages) and
 handing that array back.  The engine keeps the state in a pool a batch
 slot and writes what comes back where it is kept; which slot is whose is
-the engine's and the layout's.
-:class:`FalconH1V2Adapter` is the family that has one; the others state
-none and their programs hold nothing of it.
+the engine's and the layout's.  The kind's branch is a layer of its own
+where the pattern names the kind, or runs BESIDE an attention kind's layer
+on the same input (``StateKind.beside``: :class:`FalconH1V2Adapter`); the
+other families state none and their programs hold nothing of it.
 """
 
 from __future__ import annotations
@@ -66,6 +77,9 @@ import dataclasses
 from typing import Any, List, Optional, Tuple
 
 import jax.numpy as jnp
+
+#: a pattern's entry for a layer that is the FFN alone (``ffn_layer``)
+FFN = "ffn"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,17 +114,23 @@ class AttentionKind:
 class StateKind:
     """A second kind of per-sequence state: what a sequence holds a layer
     whatever its length (a state-space layer's state, a conv's tail),
-    beside the keys of the same layer.  It lives in a pool indexed by batch
-    slot, not by page (``kv_cache.StateLayout``).  The kind is in every
-    layer of the model."""
+    beside whatever keys the model caches.  It lives in a pool indexed by batch
+    slot, not by page (``kv_cache.StateLayout``), of ``layers`` layers: the
+    kind's OWN layers, which the pattern names by the kind's name, or the
+    layers of the attention kind it rides beside."""
     name: str
-    layers: int
+    layers: int                     # how many of the model's layers
     #: (part, shape a sequence a layer, type), e.g. ``("ssm", (32, 256,
     #: 128), float32)``
     parts: Tuple[Tuple[str, Tuple[int, ...], Any], ...]
     #: the parts a decode step moves WHERE THEY LIE in the pool (its hook
     #: gets the pool's array, not the rows' values: ``mix_decode``)
     in_place: Tuple[str, ...]
+    #: the attention kind in whose layers the kind's branch runs, on the
+    #: same input and added to the residual before ``post_attn`` (every
+    #: layer of that kind has one); None: its mixer is a layer of its own,
+    #: named in the pattern
+    beside: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,8 +147,9 @@ class StateRows:
 
 @dataclasses.dataclass(frozen=True)
 class LayerPattern:
-    """The model's layers by attention kind: ``leading``, run one by one,
-    then ``periods`` repeats of ``period``, scanned."""
+    """The model's layers by PART (an attention kind's name, a state
+    kind's, or :data:`FFN`): ``leading``, run one by one, then ``periods``
+    repeats of ``period``, scanned."""
     leading: Tuple[str, ...]
     period: Tuple[str, ...]
     periods: int
@@ -279,6 +300,16 @@ class ModelAdapterV2:
         and ``l`` this layer's index among the scanned layers (traced;
         None in a leading layer), for what ``layers()`` left out of
         ``lp``."""
+        raise NotImplementedError
+
+    def ffn_layer(self, lp: Any, x: jnp.ndarray, params: Any,
+                  at: jnp.ndarray) -> jnp.ndarray:
+        """A layer that is the FFN alone (:data:`FFN` in the pattern): ``x
+        [N, H]`` → ``[N, H]``, norm, FFN and residual.  ``at`` is the
+        layer's place among the FFN layers (traced inside the scan), for
+        what ``layers()`` left out of ``lp`` and is read from ``params``.
+        A family whose FFN follows its attention in the same layer has it
+        in ``post_attn`` and states no such layer."""
         raise NotImplementedError
 
     def finalize(self, params: Any, x: jnp.ndarray) -> jnp.ndarray:
@@ -565,7 +596,7 @@ class FalconH1V2Adapter(ModelAdapterV2):
         from ...models.falcon_h1 import SSM
 
         return (StateKind(SSM, self.num_layers, self.model.state_parts(),
-                          in_place=(SSM,)),)
+                          in_place=(SSM,), beside=self.kinds[0].name),)
 
     def embed(self, params, tokens, positions):
         del positions  # rotary: positions enter at qkv time
@@ -598,11 +629,87 @@ class FalconH1V2Adapter(ModelAdapterV2):
         return self.model.logits(params, x)
 
 
+class NemotronHV2Adapter(ModelAdapterV2):
+    """Nemotron-H (``models/nemotron_h.py``): every layer ONE part alone,
+    as the published pattern names it: a Mamba-2 mixer (the model's
+    :class:`StateKind`, a layer of its own), a grouped-query attention
+    with no rotary and nothing behind it, or LatentMoE experts
+    (:data:`FFN`), whose expert stacks stay whole (as
+    :class:`OlmoeV2Adapter`'s) and hold this chip's share.  Each part's
+    weights are a stack of their own, as long as the pattern has layers of
+    the part."""
+
+    @property
+    def kinds(self) -> Tuple[AttentionKind, ...]:
+        from ...models.nemotron_h import KV
+
+        c = self.config
+        return (AttentionKind(KV, c.count("*"), c.num_kv_heads, c.head_dim,
+                              c.head_dim),)      # theta None: no rotary
+
+    @property
+    def state_kinds(self) -> Tuple[StateKind, ...]:
+        from ...models.nemotron_h import SSM
+
+        return (StateKind(SSM, self.config.count("M"),
+                          self.model.state_parts(), in_place=(SSM,)),)
+
+    @property
+    def pattern(self) -> LayerPattern:
+        from ...models.nemotron_h import KV, SSM
+
+        c = self.config
+        part = {"M": SSM, "*": KV, "E": FFN}
+        return LayerPattern((), tuple(part[ch] for ch in c.period),
+                            c.num_layers // len(c.period))
+
+    def layers(self, params):
+        return self.model.scanned(params)
+
+    def period_layers(self, pp, p):
+        return self.model.period_layers(pp, p)
+
+    def embed(self, params, tokens, positions):
+        del positions  # no rotary and no learned positions
+        return self.model.embed(params, tokens)
+
+    def qkv(self, lp, x, positions, kind):
+        del positions, kind  # no rotary; one kind
+        return self.model.qkv(lp, x)
+
+    def post_attn(self, lp, x, attn, params, l):
+        del params, l  # the projection alone: everything is in the slice
+        return self.model.attn_out(lp, x, attn)
+
+    def mix_in(self, lp, x):
+        return self.model.mix_in(lp, x)
+
+    def mix_chunk(self, lp, p, state, rows):
+        return self.model.mix_chunk(lp, p, state, rows.tokens, rows.valid)
+
+    def mix_decode(self, lp, p, state, held, rows):
+        return self.model.mix_decode(lp, p, state, held, rows.valid)
+
+    def mix_out(self, lp, p, y):
+        return self.model.mix_out(lp, p, y)
+
+    def ffn_layer(self, lp, x, params, at):
+        del at  # where the layer's experts lie rides lp (period_layers)
+        return self.model.experts(lp, x, params["moe"])
+
+    def finalize(self, params, x):
+        return self.model.finalize(params, x)
+
+    def logits(self, params, x):
+        return self.model.logits(params, x)
+
+
 _REGISTRY = {
     "FalconH1Model": FalconH1V2Adapter,
     "LlamaModel": LlamaV2Adapter,
     "MimoV2Model": MimoV2Adapter,
     "MixtralModel": LlamaV2Adapter,
+    "NemotronHModel": NemotronHV2Adapter,
     "OlmoeModel": OlmoeV2Adapter,
     "OPTModel": OPTV2Adapter,
     "PanguUltraMoeModel": PanguUltraMoeV2Adapter,

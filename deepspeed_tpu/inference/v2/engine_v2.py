@@ -47,13 +47,17 @@ adapter's ``state_kinds``) has a pool a batch slot for it in the same dict
 the middle of each layer's branch that is not row-wise (the adapter's
 ``mix_chunk`` for chunk rows, ``mix_decode`` for decode rows) is given a
 group of rows with its sequences' state, and what it returns is written
-back in place at ``(layer, slot)`` (``_layer_step``).  A request's slot is its
+back in place at ``(the kind's layer, slot)`` (``_layer_step``).  A request's slot is its
 batch slot from admission on, so a chunk's state is found as a ring is.
 
 The scan runs over the PERIODS of the adapter's layer pattern, a period's
 layers unrolled in the step, after the pattern's leading layers; a model
 whose layers are all alike has one kind, no leading layer and a period of
-one.
+one.  A pattern's entry names the layer's PART: an attention kind (with
+whatever the family has behind it in the layer), a state kind whose mixer
+is a layer of its own, or the FFN alone; each part's pool has as many
+layers as the pattern has of the part, and a layer is handed its place
+among those.
 
 **A second call in flight.**  ``step_ahead`` (the serving front-end's
 entry) plans, packs and dispatches the round's call FIRST, behind the
@@ -221,6 +225,12 @@ class RaggedInferenceEngineV2:
         #: the same for the recurrent state a model carries beside its
         #: keys (empty where it carries none)
         self.state_layouts = state_layouts(self.adapter, self.cache_config)
+        #: the attention kinds beside which a state kind's branch runs in
+        #: the same layer (``StateKind.beside``)
+        self._state_beside = frozenset(
+            k.beside for k in self.adapter.state_kinds if k.beside)
+        #: layers run, by part, as the last program traced counted them
+        self.last_layers_by_part: Dict[str, int] = {}
         #: the serving plane swaps in its prefix-sharing scheduler here —
         #: same planner surface, refcounted page reservations
         make_sched = scheduler_factory or RaggedScheduler
@@ -300,41 +310,57 @@ class RaggedInferenceEngineV2:
     # compiled programs
     # ------------------------------------------------------------------
 
-    def _layer_step(self, params, lp, l, kind, lk, pools, x_flat,
-                    positions_flat, write_fn, attend_fn, mix_fn=None,
-                    ls=None):
-        """Shared per-layer skeleton: qkv → KV write → attention →
-        post-attn block.  ``write_fn``/``attend_fn`` are the rows' own:
+    def _layer_step(self, params, lp, l, part, at, pools, x_flat,
+                    positions_flat, write_fn, attend_fn, mix_fn=None):
+        """One layer: the PART the pattern names for it (``part``, the
+        ``at``-th layer of that part, which is its place in the part's
+        pool), through the adapter's hooks for the part.
+        ``write_fn``/``attend_fn``/``mix_fn`` are the rows' own:
         :meth:`_decode_rows`, :meth:`_chunk_rows`, or both
-        (:meth:`_beside`).  So is ``mix_fn``, where the model carries a
-        recurrent state (else None): the middle of the layer's branch
-        beside attention (the adapter's ``mix_in`` and ``mix_out`` around
-        it are row-wise), on the same input, whose result joins the
-        residual before the post-attn block; the state pools (layer ``ls``
-        of the model) ride ``pools`` and are read and written in place
-        like the others.
+        (:meth:`_beside`).
 
-        ``pools`` holds, for each attention kind, the WHOLE pool, a carry
-        of the layer scan; this layer is of ``kind`` and the ``lk``-th of
-        it: the write scatters rows or pages at ``(lk, page)`` and
-        attention reads layer ``lk``'s pages where they lie.  No layer's
+        * an attention kind: qkv → KV write → attention → ``post_attn``
+          (the output projection and whatever the family has behind it in
+          the same layer: most an FFN, some nothing).  Where a state kind
+          rides BESIDE it (``StateKind.beside``), the state's branch runs
+          on the same input and joins the residual before ``post_attn``;
+          its layer in the state pool is ``at`` too.
+        * a state kind: ``mix_in`` → the groups' state moved → ``mix_out``,
+          added to the residual.  ``mix_in`` and ``mix_out`` are row-wise;
+          the middle is the rows' own (``mix_fn``), on layer ``at`` of the
+          state pool.
+        * ``adapters.FFN``: the adapter's ``ffn_layer``, given ``at``.
+
+        ``pools`` holds, for each kind, the WHOLE pool, a carry of the
+        layer scan: the write scatters rows or pages at ``(at, page)`` and
+        attention reads layer ``at``'s pages where they lie.  No layer's
         slice of a pool is ever formed: a slice of a scanned stack handed
         to a custom call (the paged kernel) is copied out and the updated
         layer copied back (``kv_cache``'s module docstring).  Write, then
         attend: only the written pool lives on, so the write stays in
         place."""
         ad = self.adapter
-        q, kk, vv = ad.qkv(lp, x_flat, positions_flat, kind)
-        pools = dict(pools, **{kind.name: write_fn(
-            pools[kind.name], kind, lk, kk, vv)})
-        attn = attend_fn(q, pools[kind.name], kind, lk, ad.sink(lp))
-        if mix_fn is not None:
+
+        def mixed(x_flat, pools):
             # row-wise in and out, over all the rows at once; the state is
             # moved group by group in between
             p = ad.mix_in(lp, x_flat)
-            y, pools = mix_fn(lp, p, pools, ls)
-            x_flat = x_flat + ad.mix_out(lp, p, y)
-        x_flat = ad.post_attn(lp, x_flat, attn, params, l)
+            y, pools = mix_fn(lp, p, pools, at)
+            return x_flat + ad.mix_out(lp, p, y), pools
+
+        if part in self.kinds:
+            kind = self.kinds[part]
+            q, kk, vv = ad.qkv(lp, x_flat, positions_flat, kind)
+            pools = dict(pools, **{part: write_fn(
+                pools[part], kind, at, kk, vv)})
+            attn = attend_fn(q, pools[part], kind, at, ad.sink(lp))
+            if part in self._state_beside:
+                x_flat, pools = mixed(x_flat, pools)
+            x_flat = ad.post_attn(lp, x_flat, attn, params, l)
+        elif part in self.state_layouts:
+            x_flat, pools = mixed(x_flat, pools)
+        else:
+            x_flat = ad.ffn_layer(lp, x_flat, params, at)
         return x_flat, pools
 
     def _per_kv_shard(self, fn, in_specs, out_specs):
@@ -365,7 +391,9 @@ class RaggedInferenceEngineV2:
     def _scan_layers(self, params, pools, x, positions_flat, write_fn,
                      attend_fn, mix_fn=None):
         """The layers of every program: the pattern's leading layers, then
-        a scan over its periods.  Carry: the activations and the pools;
+        a scan over its periods; each layer is handed its place among the
+        layers of its own part (:meth:`_layer_step`).  Carry: the
+        activations and the pools;
         ``xs``: what the adapter's ``layers(params)`` holds, a period's
         slice a step, and the period's index; ``ys``: the MoE gate's
         stats (``moe_stats`` inside the FFN), which must leave the scan
@@ -377,32 +405,34 @@ class RaggedInferenceEngineV2:
         here)."""
         ad = self.adapter
         pattern = ad.pattern
-        # a layer's index within its kind's pool: the leading layers of
-        # the kind first, then period by period
-        first = {name: pattern.leading.count(name) for name in self.kinds}
-        a_period = {name: pattern.period.count(name) for name in self.kinds}
+        # a layer's place among the layers of its own part (its layer in
+        # the part's pool): the leading layers of the part first, then
+        # period by period
+        parts = set(pattern.leading) | set(pattern.period)
+        first = {name: pattern.leading.count(name) for name in parts}
+        a_period = {name: pattern.period.count(name) for name in parts}
+        self.last_layers_by_part = {
+            name: first[name] + pattern.periods * a_period[name]
+            for name in parts}
         with numerics.suppressed():
-            seen = dict.fromkeys(self.kinds, 0)
-            for i, (name, lp) in enumerate(zip(pattern.leading,
-                                               ad.leading_layers(params))):
+            seen = dict.fromkeys(parts, 0)
+            for name, lp in zip(pattern.leading, ad.leading_layers(params)):
                 x, pools = self._layer_step(
-                    params, lp, None, self.kinds[name], seen[name], pools, x,
-                    positions_flat, write_fn, attend_fn, mix_fn, i)
+                    params, lp, None, name, seen[name], pools, x,
+                    positions_flat, write_fn, attend_fn, mix_fn)
                 seen[name] += 1
 
         def period(carry, xs):
             x, pools = carry
             pp, p = xs
             mark = numerics.scan_mark()
-            seen = dict.fromkeys(self.kinds, 0)
+            seen = dict.fromkeys(parts, 0)
             for j, (name, lp) in enumerate(zip(pattern.period,
                                                ad.period_layers(pp, p))):
                 x, pools = self._layer_step(
-                    params, lp, p * len(pattern.period) + j,
-                    self.kinds[name],
+                    params, lp, p * len(pattern.period) + j, name,
                     first[name] + p * a_period[name] + seen[name], pools, x,
-                    positions_flat, write_fn, attend_fn, mix_fn,
-                    len(pattern.leading) + p * len(pattern.period) + j)
+                    positions_flat, write_fn, attend_fn, mix_fn)
                 seen[name] += 1
             return (x, pools), numerics.scan_drain(mark)
 
@@ -838,8 +868,8 @@ class RaggedInferenceEngineV2:
             "inference/moe/experts_active",
             v=float(cols["moe/experts_active"].sum()) * steps,
             help="held experts with at least one row (whose weights "
-                 "the grouped matmul reads), summed over layers and "
-                 "steps")
+                 "the grouped matmul reads), summed over the layers "
+                 "that have experts and steps")
 
     def _publish_gauges(self) -> None:
         """The registry's collect hook: the gauges of the pools and of
@@ -869,6 +899,12 @@ class RaggedInferenceEngineV2:
                 help="consecutive tokens of a prefill chunk that share a "
                      "grid row of the kind's paged kernel, as the traced "
                      "programs were built")
+        for name, layers in self.last_layers_by_part.items():
+            tel.set_gauge(
+                f"inference/layers/{name}", float(layers),
+                help="layers of the model that are this part (an attention "
+                     "kind, a state kind or the FFN alone), as the traced "
+                     "programs ran them")
         for layout in self.state_layouts.values():
             tel.set_gauge(
                 "inference/ssm/slots_in_use",
@@ -1181,8 +1217,9 @@ class RaggedInferenceEngineV2:
     def _count_state_traffic(self, tel: Any, sent: _Call) -> None:
         """What a call moves of the recurrent state, from what it packed:
         every step reads and writes every batch slot's state in every
-        layer (a dead row's as it was), and a call that carries chunks
-        its chunk rows' slots once more."""
+        layer OF THE KIND (``StateKind.layers``: the layers that have a
+        mixer, not the model's; a dead row's as it was), and a call that
+        carries chunks its chunk rows' slots once more."""
         for layout in self.state_layouts.values():
             moved = (sent.steps * self.max_slots
                      + (self.prefill_batch if sent.chunks else 0)
@@ -1190,8 +1227,8 @@ class RaggedInferenceEngineV2:
             tel.inc_counter(
                 "inference/ssm/state_bytes_read", v=moved,
                 help="bytes of recurrent state the calls dispatched read: "
-                     "every batch slot's a layer a decode step, and a "
-                     "chunk row's slot a layer")
+                     "every batch slot's a layer of the kind a decode "
+                     "step, and a chunk row's slot a layer of the kind")
             tel.inc_counter(
                 "inference/ssm/state_bytes_written", v=moved,
                 help="bytes of recurrent state the calls dispatched wrote "
@@ -1200,8 +1237,8 @@ class RaggedInferenceEngineV2:
                 "inference/ssm/decode_rows", v=sent.steps * len(sent.decode),
                 help="one-token state updates of live sequences: decode "
                      "rows x steps of the calls dispatched (a layer's; "
-                     "times layers and a slot's bytes twice: what the "
-                     "updates must move)")
+                     "times the kind's layers and a slot's bytes twice: "
+                     "what the updates must move)")
             tel.inc_counter(
                 "inference/ssm/chunk_tokens",
                 v=sum(ch.n_valid for ch in sent.chunks),

@@ -13,6 +13,7 @@ from .falcon_h1 import FalconH1Config, FalconH1Model
 from .llama import LlamaConfig, LlamaModel
 from .mimo_v2 import MimoV2Config, MimoV2Model
 from .mixtral import MixtralConfig, MixtralModel
+from .nemotron_h import NemotronHConfig, NemotronHModel
 from .olmoe import OlmoeConfig, OlmoeModel
 from .opt import OPTConfig, OPTModel
 from .pangu_ultra_moe import PanguUltraMoeConfig, PanguUltraMoeModel
@@ -20,7 +21,8 @@ from .resnet import ResNetConfig, ResNetModel
 
 __all__ = ["BertConfig", "BertModel", "FalconH1Config", "FalconH1Model",
            "LlamaConfig", "LlamaModel",
-           "MimoV2Config", "MimoV2Model", "MixtralConfig", "MixtralModel", "OlmoeConfig", "OlmoeModel",
+           "MimoV2Config", "MimoV2Model", "MixtralConfig", "MixtralModel",
+           "NemotronHConfig", "NemotronHModel", "OlmoeConfig", "OlmoeModel",
            "OPTConfig", "OPTModel",
            "PanguUltraMoeConfig", "PanguUltraMoeModel",
            "ResNetConfig", "ResNetModel"]

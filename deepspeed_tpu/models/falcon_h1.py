@@ -38,7 +38,7 @@ size.  Layer ``l``:
   mlp_multipliers[0])) · mlp_multipliers[1]``.
 * Head: final RMSNorm, ``logits = W_head(x) · lm_head_multiplier``, untied.
 
-**Chunk form** (what prefill runs, :meth:`FalconH1Model._scan_chunk`; the
+**Chunk form** (what prefill runs, :func:`mamba2.scan_chunk`; the
 same mathematics): over a block of ``Q`` tokens with carried-in ``S_0`` and
 ``Λ_t = Σ_{s≤t} Δ_s A``::
 
@@ -86,12 +86,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.pallas.ssm_state_update import ssm_state_update
+from . import mamba2
 from .llama import _rms_norm, _rope
-
-#: the name of the per-sequence state's pool
-SSM = "ssm"
-F32 = jnp.float32
+from .mamba2 import F32, SSM    # SSM: the name of the state's pool
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,22 +139,29 @@ class FalconH1Config:
 
     @property
     def d_ssm(self) -> int:
-        return self.mamba_n_heads * self.mamba_d_head
+        return self.mamba.d_ssm
 
     @property
     def bc_dim(self) -> int:
         """``B`` (and ``C``) of one token: every group's."""
-        return self.mamba_n_groups * self.mamba_d_state
+        return self.mamba.bc_dim
 
     @property
     def conv_dim(self) -> int:
         """The channels the conv runs over: ``[xs | B | C]``."""
-        return self.d_ssm + 2 * self.bc_dim
+        return self.mamba.conv_dim
 
     @property
     def proj_dim(self) -> int:
         """``in_proj``'s outputs: ``[z | xs | B | C | dt]``."""
-        return self.d_ssm + self.conv_dim + self.mamba_n_heads
+        return self.mamba.proj_dim
+
+    @property
+    def mamba(self) -> mamba2.Mamba2Dims:
+        """The mixer's sizes, as the shared arithmetic takes them."""
+        return mamba2.Mamba2Dims(self.mamba_n_heads, self.mamba_d_head,
+                                 self.mamba_d_state, self.mamba_n_groups,
+                                 self.mamba_d_conv)
 
     @classmethod
     def tiny(cls, **kw) -> "FalconH1Config":
@@ -255,17 +259,13 @@ class FalconH1Model:
 
     def zero_state(self, rows: int) -> Dict[str, jnp.ndarray]:
         """What ``rows`` sequences hold a layer before their first token."""
-        return {name: jnp.zeros((rows,) + shape, dtype)
-                for name, shape, dtype in self.state_parts()}
+        return self.config.mamba.zero_state(rows, self.config.dtype)
 
     def state_parts(self) -> Tuple[Tuple[str, Tuple[int, ...], Any], ...]:
         """(name, shape, type) of what a sequence holds a layer.  The state
         is float32 whatever the model's type: it is multiplied by a decay
         near 1 once a token, thousands of times over."""
-        c = self.config
-        return ((SSM, (c.mamba_n_heads, c.mamba_d_state, c.mamba_d_head),
-                 F32),
-                ("conv", ((c.mamba_d_conv - 1) * c.conv_dim,), c.dtype))
+        return self.config.mamba.state_parts(self.config.dtype)
 
     # -- the layer's parts ---------------------------------------------------
 
@@ -300,27 +300,10 @@ class FalconH1Model:
     def mix(self, lp: Any, x: jnp.ndarray, state: Dict[str, jnp.ndarray],
             tokens: int, valid: jnp.ndarray
             ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-        """The mixer over ``R`` sequences' rows: ``x [R·tokens, H]``, each
-        sequence's ``tokens`` consecutive rows in order, of which the first
-        ``valid[r]`` are real (the rest padding: they move no state);
-        ``state``: what each sequence holds coming in (``ssm [R, heads,
-        d_state, d_head]``, ``conv [R, (K−1)·conv_dim]``) → (``o_ssm
-        [R·tokens, H]``, the state going out).  One token a sequence is a
-        decode step's update (here on a pool of one layer, the rows its
-        slots); more is a block of the chunk form.  In three parts, for a
-        caller whose rows are several groups and which keeps the state
-        itself: :meth:`mix_in` and :meth:`mix_out` are row-wise (every row
-        of a call through each weight once); :meth:`mix_chunk` or
-        :meth:`mix_decode` between them takes a group."""
-        p = self.mix_in(lp, x)
-        if tokens == 1:
-            y, new, held = self.mix_decode(
-                lp, p, {"conv": state["conv"]},
-                {SSM: (state[SSM][None], 0, 0)}, valid)
-            new = dict(new, **{SSM: held[SSM][0]})
-        else:
-            y, new = self.mix_chunk(lp, p, state, tokens, valid)
-        return self.mix_out(lp, p, y), new
+        """The mixer over ``R`` sequences' rows with their state as values
+        in and out (``mamba2.mix``: ``x [R·tokens, H]`` → ``o_ssm``, the
+        state going out), through this family's four parts."""
+        return mamba2.mix(self, lp, x, state, tokens, valid)
 
     def mix_in(self, lp: Any, x: jnp.ndarray) -> jnp.ndarray:
         """Row-wise: ``x [N, H]`` → ``p [N, proj_dim]``, ``in_proj`` of the
@@ -333,49 +316,6 @@ class FalconH1Model:
             return (u @ lp["ssm"]["in_proj"].astype(dt)) \
                 * jnp.asarray(self._mup(), dt)
 
-    def _conv(self, lp: Any, p: jnp.ndarray, tail: jnp.ndarray, tokens: int,
-              valid: jnp.ndarray):
-        """A group's rows ``p [R·tokens, proj_dim]`` through the conv from
-        the sequences' tails ``[R, (K−1)·conv_dim]`` → (``xs [R, T, G, k,
-        P]``, ``B`` and ``C`` ``[R, T, G, N]``, ``Δ [R, T, G, k]`` float32,
-        0 at a padded position, ``A [G, k]``, the tails going out)."""
-        c = self.config
-        dt = c.dtype
-        m = lp["ssm"]
-        R, T, K = p.shape[0] // tokens, tokens, c.mamba_d_conv
-        heads, P, N, G = (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
-                          c.mamba_n_groups)
-        real = jnp.arange(T)[None, :] < valid[:, None]             # [R, T]
-        p = p.reshape(R, T, c.proj_dim)
-        xbc, dt_raw = (p[..., c.d_ssm:c.d_ssm + c.conv_dim],
-                       p[..., c.d_ssm + c.conv_dim:])
-        with jax.named_scope("ssm/conv"):
-            # the tail's K−1 inputs, then the rows': output t sums inputs
-            # t … t+K−1 of that; the tail going out ends at the last real one
-            seq = jnp.concatenate([tail.astype(dt).reshape(
-                R, K - 1, c.conv_dim), xbc], axis=1)
-            w = m["conv_w"].astype(dt)
-            conv = sum(seq[:, j:j + T] * w[j] for j in range(K)) \
-                + m["conv_b"].astype(dt)
-            conv = jax.nn.silu(conv)
-            out = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
-                s, n, K - 1, 0))(seq, valid)
-        xs = conv[..., :c.d_ssm].reshape(R, T, G, heads // G, P)
-        B = conv[..., c.d_ssm:c.d_ssm + c.bc_dim].reshape(R, T, G, N)
-        C = conv[..., c.d_ssm + c.bc_dim:].reshape(R, T, G, N)
-        delta = jax.nn.softplus(dt_raw.astype(F32) + m["dt_bias"].astype(F32))
-        delta = jnp.where(real[..., None], delta, 0.0
-                          ).reshape(R, T, G, heads // G)
-        A = -jnp.exp(m["A_log"].astype(F32)).reshape(G, heads // G)
-        return xs, B, C, delta, A, out.reshape(tail.shape).astype(tail.dtype)
-
-    def _skip(self, lp: Any, y: jnp.ndarray, xs: jnp.ndarray) -> jnp.ndarray:
-        """``y [R, T, G, k, P]`` float32 with the skip ``D x`` added → ``[R·T,
-        d_ssm]``."""
-        R, T, G, k, _ = xs.shape
-        y = y + lp["ssm"]["D"].astype(F32).reshape(G, k, 1) * xs.astype(F32)
-        return y.reshape(R * T, self.config.d_ssm)
-
     def mix_chunk(self, lp: Any, p: jnp.ndarray,
                   state: Dict[str, jnp.ndarray], tokens: int,
                   valid: jnp.ndarray
@@ -384,14 +324,9 @@ class FalconH1Model:
         (:meth:`mix_in`'s) and their state coming in → (``y [R·tokens,
         d_ssm]`` float32, the state going out): the conv and one block of
         the chunk form."""
-        xs, B, C, delta, A, tail = self._conv(lp, p, state["conv"], tokens,
-                                              valid)
-        R, _, G, k, P = xs.shape
-        S = state[SSM].astype(F32).reshape(R, G, k, B.shape[-1], P)
-        y, S = self._scan_chunk(xs, B, C, delta, A, S)
-        return self._skip(lp, y, xs), {
-            SSM: S.reshape(state[SSM].shape).astype(state[SSM].dtype),
-            "conv": tail}
+        c = self.config
+        return mamba2.chunk(c.mamba, lp["ssm"], p, state, tokens, valid,
+                            c.dtype)
 
     def mix_decode(self, lp: Any, p: jnp.ndarray,
                    state: Dict[str, jnp.ndarray],
@@ -407,17 +342,9 @@ class FalconH1Model:
         float32, the tails going out, the array with the rows' states moved
         one step: ``ssm_state_update``, which reads ``y = S C`` off the new
         values)."""
-        xs, B, C, delta, A, tail = self._conv(lp, p, state["conv"], 1, valid)
-        R, _, G, k, P = xs.shape
-        array, layer, first = held[SSM]
-        with jax.named_scope("ssm/state_update"):
-            d = delta[:, 0].reshape(R, G * k)
-            a = jnp.exp(d * A.reshape(G * k))
-            dx = d[..., None] * xs[:, 0].reshape(R, G * k, P).astype(F32)
-            array, y = ssm_state_update(array, layer, first, a=a, dx=dx,
-                                        b=B[:, 0], c=C[:, 0])
-        return (self._skip(lp, y.reshape(xs.shape), xs), {"conv": tail},
-                {SSM: array})
+        c = self.config
+        return mamba2.decode(c.mamba, lp["ssm"], p, state, held, valid,
+                             c.dtype)
 
     def mix_out(self, lp: Any, p: jnp.ndarray, y: jnp.ndarray
                 ) -> jnp.ndarray:
@@ -427,57 +354,11 @@ class FalconH1Model:
         c = self.config
         dt = c.dtype
         m = lp["ssm"]
-        G = c.mamba_n_groups
-        with jax.named_scope("ssm/gated_norm"):
-            y = y.reshape(-1, G, c.d_ssm // G)
-            gate = jax.nn.silu(p[:, :c.d_ssm].astype(F32)).reshape(y.shape)
-            weight = m["norm"].astype(F32).reshape(G, c.d_ssm // G)
-
-            def normed(v):
-                return v * jax.lax.rsqrt(jnp.mean(
-                    v * v, axis=-1, keepdims=True) + c.rms_norm_eps) * weight
-
-            g = normed(y) * gate if c.mamba_norm_before_gate \
-                else normed(y * gate)
-            g = g.reshape(-1, c.d_ssm).astype(dt)
+        g = mamba2.gated_norm(c.mamba, m, p, y, c.rms_norm_eps,
+                              c.mamba_norm_before_gate, dt)
         with jax.named_scope("ssm/out_proj"):
             return (g @ m["out_proj"].astype(dt)) \
                 * jnp.asarray(c.ssm_out_multiplier, dt)
-
-    @staticmethod
-    def _scan_chunk(xs, B, C, delta, A, S):
-        """One block of ``Q`` tokens a sequence, the chunk form: ``xs [R,
-        Q, G, k, P]``, ``B``/``C`` ``[R, Q, G, N]``, ``delta [R, Q, G, k]``
-        (0 at a padded position), ``A [G, k]``, carried-in ``S [R, G, k,
-        N, P]`` float32 → (``y [R, Q, G, k, P]`` float32 without the skip,
-        the state after the block).  The decays are float32; the products
-        take the inputs' type and sum in float32."""
-        with jax.named_scope("ssm/scan_chunk"):
-            dt = xs.dtype
-            Q = xs.shape[1]
-            lam = jnp.cumsum(delta * A, axis=1)                 # [R, Q, G, k]
-            xd = delta[..., None] * xs.astype(F32)              # Δ_s x_s
-            # within the block: weights exp(Λ_t − Λ_s)(C_t·B_s) for s ≤ t
-            cb = jnp.einsum("rtgn,rsgn->rgts", C, B,
-                            preferred_element_type=F32)
-            lam_h = jnp.moveaxis(lam, 1, -1)                    # [R, G, k, Q]
-            seen = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
-            decay = jnp.exp(jnp.where(
-                seen, lam_h[..., :, None] - lam_h[..., None, :], -jnp.inf))
-            weights = (cb[:, :, None] * decay).astype(dt)       # [R,G,k,t,s]
-            y = jnp.einsum("rgkts,rsgkp->rtgkp", weights, xd.astype(dt),
-                           preferred_element_type=F32)
-            # from the carried-in state: exp(Λ_t)(S_0 C_t)
-            y = y + jnp.exp(lam)[..., None] * jnp.einsum(
-                "rtgn,rgknp->rtgkp", C, S.astype(dt),
-                preferred_element_type=F32)
-            # the state after: exp(Λ_Q) S_0 + Σ_s exp(Λ_Q − Λ_s) Δ_s x_s B_sᵀ
-            last = lam[:, -1]                                   # [R, G, k]
-            to_end = jnp.exp(last[:, None] - lam)               # [R, Q, G, k]
-            S = jnp.exp(last)[..., None, None] * S + jnp.einsum(
-                "rsgkp,rsgn->rgknp", (xd * to_end[..., None]).astype(dt), B,
-                preferred_element_type=F32)
-            return y, S
 
     def post_attn(self, lp: Any, x: jnp.ndarray, attn: jnp.ndarray
                   ) -> jnp.ndarray:
@@ -515,8 +396,6 @@ class FalconH1Model:
         c = self.config
         dt = c.dtype
         B_, S_ = input_ids.shape
-        Q = c.mamba_chunk_size
-        blocks = -(-S_ // Q)
         pos = jnp.tile(jnp.arange(S_), B_)
         seen = jnp.arange(S_)[None, :] <= jnp.arange(S_)[:, None]
         rep = c.num_heads // c.num_kv_heads
@@ -532,20 +411,8 @@ class FalconH1Model:
             attn = jnp.einsum("bgrqk,bkgd->bqgrd", p, v).reshape(
                 B_ * S_, c.num_heads, c.head_dim)
 
-            rows = jnp.pad(x.reshape(B_, S_, -1),
-                           ((0, 0), (0, blocks * Q - S_), (0, 0)))
-
-            def block(state, i):
-                part = jax.lax.dynamic_slice_in_dim(rows, i * Q, Q, 1)
-                out, state = self.mix(
-                    lp, part.reshape(B_ * Q, -1), state, Q,
-                    jnp.full((B_,), jnp.clip(S_ - i * Q, 0, Q)))
-                return state, out.reshape(B_, Q, -1)
-
-            _, outs = jax.lax.scan(block, self.zero_state(B_),
-                                   jnp.arange(blocks))
-            o_ssm = jnp.moveaxis(outs, 0, 1).reshape(B_, blocks * Q, -1)
-            o_ssm = o_ssm[:, :S_].reshape(B_ * S_, -1)
+            o_ssm = mamba2.mix_sequences(self, lp, x, B_, S_,
+                                         c.mamba_chunk_size)
             return self.post_attn(lp, x + o_ssm, attn), None
 
         x = self.embed(params, input_ids.reshape(-1))

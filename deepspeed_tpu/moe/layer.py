@@ -38,8 +38,19 @@ def swiglu_expert_fn(params: Any, x: jnp.ndarray,
     return jnp.einsum("eci,eih->ech", act, params["w_down"].astype(dt))
 
 
+#: an expert's FORM: its leaves, and the grouped call of its up projection
+#: (``ops/pallas/moe_grouped_matmul``); every form ends in the plain
+#: grouped matmul over ``w_down``
+EXPERT_FORMS = {
+    # three matrices: silu(x·w_gate) ⊙ (x·w_up), then w_down
+    "swiglu": (("w_gate", "w_up"), "grouped_swiglu"),
+    # two matrices: relu(x·w_up)², then w_down
+    "relu2": (("w_up",), "grouped_relu2"),
+}
+
+
 class DroplessMoE:
-    """Top-k routed SwiGLU experts with no capacity: every assignment is
+    """Top-k routed experts with no capacity: every assignment is
     computed (``sharded_moe.top_k_routing``), on the sorted layout of
     ``ops/pallas/moe_grouped_matmul`` (tokens gathered by expert, one
     grouped matmul for gate/up and one for down, the weighted sum back).
@@ -60,6 +71,13 @@ class DroplessMoE:
     for the custom call (the v2 engine's ``OlmoeV2Adapter`` keeps them out
     of the scan for that reason); ``wg`` is one layer's either way.
 
+    ``form`` (:data:`EXPERT_FORMS`): gated experts of three matrices (the
+    default) or experts of two with ``relu(·)²`` between.  The call's
+    ``rows [B, S, w]`` are what the experts multiply where that is not what
+    the router reads (a LatentMoE layer routes on ``x [B, S, H]`` and its
+    experts work on a projection of it, ``w`` wide: their leaves are ``[E,
+    w, I]`` / ``[E, I, w]`` and ``y`` is ``[B, S, w]``); None: ``x``.
+
     ``scoring`` and the call's ``choice_bias`` are the router's
     (``top_k_routing``).  ``held=(first, count)``: this chip's share under
     expert parallelism.  The router keeps its width ``num_experts`` and its
@@ -73,7 +91,12 @@ class DroplessMoE:
 
     def __init__(self, num_experts: int, k: int, renormalize: bool = False,
                  mesh: Any = None, scoring: str = "softmax",
-                 held: Optional[Tuple[int, int]] = None):
+                 held: Optional[Tuple[int, int]] = None,
+                 form: str = "swiglu"):
+        if form not in EXPERT_FORMS:
+            raise ValueError(f"form: one of {sorted(EXPERT_FORMS)}, not "
+                             f"{form!r}")
+        self.form = form
         self.num_experts = num_experts
         self.k = k
         self.renormalize = renormalize
@@ -87,7 +110,8 @@ class DroplessMoE:
         self.held = held
 
     def __call__(self, wg: jnp.ndarray, expert_params: Any, x: jnp.ndarray,
-                 layer: Any = None, choice_bias: Optional[jnp.ndarray] = None
+                 layer: Any = None, choice_bias: Optional[jnp.ndarray] = None,
+                 rows: Optional[jnp.ndarray] = None
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
         from ..ops.pallas import moe_grouped_matmul as gm
         from .sharded_moe import top_k_routing
@@ -98,8 +122,10 @@ class DroplessMoE:
             layer = 0
         B, S, H = x.shape
         tokens = x.reshape(B * S, H)
-        expert_idx, weights, meta = top_k_routing(
-            wg, tokens, self.k, self.renormalize, self.scoring, choice_bias)
+        with jax.named_scope("moe/router"):
+            expert_idx, weights, meta = top_k_routing(
+                wg, tokens, self.k, self.renormalize, self.scoring,
+                choice_bias)
         if self.held is None:
             plan = gm.plan_groups(
                 expert_idx, self.num_experts,
@@ -122,14 +148,15 @@ class DroplessMoE:
                 experts_active=jnp.sum(plan.group_sizes > 0
                                        ).astype(jnp.float32))
         sharded = self.mesh is not None and self.mesh.size > 1
-        rows = gm.gather_rows(tokens, plan)
-        act = gm.grouped_swiglu(rows, expert_params["w_gate"],
-                                expert_params["w_up"], layer, plan,
-                                sharded=sharded)
+        fed = tokens if rows is None else rows.reshape(B * S, -1)
+        leaves, up = EXPERT_FORMS[self.form]
+        act = getattr(gm, up)(
+            gm.gather_rows(fed, plan), *(expert_params[n] for n in leaves),
+            layer, plan, sharded=sharded)
         out = gm.grouped_matmul(act, expert_params["w_down"], layer, plan,
                                 sharded=sharded)
         y = gm.combine_rows(out, plan, weights).astype(x.dtype)
-        return y.reshape(B, S, H), meta["l_aux"], meta
+        return y.reshape(B, S, -1), meta["l_aux"], meta
 
 
 class MoE:

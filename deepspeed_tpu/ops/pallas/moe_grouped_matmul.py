@@ -25,7 +25,11 @@ each non-empty expert's weights cross HBM once per call and an empty
 expert's never: at decode widths (a few rows an expert) the kernel is a
 stream of weights, and that is what its roofline counts.
 :func:`grouped_swiglu` fuses the gate and up projections with
-``silu(g)·u``; :func:`grouped_matmul` is the down projection.
+``silu(g)·u``; :func:`grouped_matmul` is the down projection.  An expert of
+TWO matrices with ``relu(·)²`` between them (a LatentMoE expert: its rows
+are the latent's width, not the hidden one) has :func:`grouped_relu2` for
+its up projection, the plain kernel with the activation on the product
+while it is in VMEM, and the same down projection.
 
 **The weights have a layer**: both take the whole stack ``w [L, E, K, N]``
 as the model holds it and ``layer``, an int32 scalar that may be traced
@@ -182,6 +186,11 @@ def grouped_swiglu_reference(x, w_gate, w_up, layer, tile_group, num_tiles):
     return (jax.nn.silu(gate) * up).astype(x.dtype)
 
 
+def grouped_relu2_reference(x, w_up, layer, tile_group, num_tiles):
+    up, = _ragged(x, (w_up,), layer, tile_group, num_tiles)
+    return jnp.square(jax.nn.relu(up)).astype(x.dtype)
+
+
 # -- the kernels -------------------------------------------------------------
 
 def _matmul_kernel(tile_group, num_tiles, x_ref, w_ref, o_ref):
@@ -205,6 +214,16 @@ def _swiglu_kernel(tile_group, num_tiles, x_ref, wg_ref, wu_ref, o_ref):
         gate = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
         up = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
         o_ref[...] = (jax.nn.silu(gate) * up).astype(o_ref.dtype)
+
+
+def _relu2_kernel(tile_group, num_tiles, x_ref, w_ref, o_ref):
+    del tile_group
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) < num_tiles[0])
+    def _():
+        up = jnp.dot(x_ref[...], w_ref[0], preferred_element_type=jnp.float32)
+        o_ref[...] = jnp.square(jnp.maximum(up, 0.0)).astype(o_ref.dtype)
 
 
 def _tile_n(K: int, N: int, itemsize: int) -> int:
@@ -287,6 +306,8 @@ _matmul = _differentiable(_matmul_kernel, "moe_grouped_matmul",
                           grouped_matmul_reference)
 _swiglu = _differentiable(_swiglu_kernel, "moe_grouped_matmul_swiglu",
                           grouped_swiglu_reference)
+_relu2 = _differentiable(_relu2_kernel, "moe_grouped_matmul_relu2",
+                         grouped_relu2_reference)
 
 
 def _runs_reference(kernel: str, x, w, interpret: Optional[bool]) -> bool:
@@ -341,3 +362,17 @@ def grouped_swiglu(x, w_gate, w_up, layer, plan: GroupPlan,
                                         plan.tile_group, plan.num_tiles)
     return _swiglu(bool(interpret), plan.tile_group, plan.num_tiles, layer,
                    x, w_gate, w_up)
+
+
+def grouped_relu2(x, w_up, layer, plan: GroupPlan,
+                  interpret: Optional[bool] = None, sharded: bool = False):
+    """``relu(x·w_up[layer, e])²`` per tile: ``[R, K]`` → ``[R, I]``, the up
+    projection of an expert of two matrices; weights as in
+    :func:`grouped_matmul`."""
+    (w_up,), layer = _in_rows_dtype((w_up,), layer, x.dtype)
+    if sharded or _runs_reference("moe_grouped_matmul_relu2", x, w_up,
+                                  interpret):
+        return grouped_relu2_reference(x, w_up, layer, plan.tile_group,
+                                       plan.num_tiles)
+    return _relu2(bool(interpret), plan.tile_group, plan.num_tiles, layer,
+                  x, w_up)

@@ -571,19 +571,61 @@ def check_moe_share(s: KernelShapes, interpret: bool) -> List[Check]:
                   float(_rel_err(got, want)), ATTENTION_TOL)]
 
 
-def check_ssm_state_update(s: KernelShapes, interpret: bool) -> List[Check]:
-    """A decode step of the state-space recurrence at a published mixer's
-    widths (32 heads of 128 in 2 groups, state 256), ``slots`` sequences in
-    a pool of three layers, in place: the pool after, and ``y``.  Kernel
-    and reference run the same float32 formula on the VPU; they may differ
-    by FMA contraction and the order of the read-out's sum."""
+def check_moe_latent(s: KernelShapes, interpret: bool) -> List[Check]:
+    """Experts of TWO matrices with ``relu(.)²`` between, at a latent's
+    width and not the model's (1,024 wide, inner 2,688: a LatentMoE
+    layer's), over a share of the router's experts (16 held of 512, 22 a
+    token): ``grouped_relu2`` then the plain grouped matmul, against each
+    held expert run over all tokens in float32."""
+    gm = _mod("moe_grouped_matmul")
+    rng = np.random.RandomState(13)
+    T, routed, held, K, W, inner = 256, 512, 16, 22, 1024, 2688
+    score = jax.nn.sigmoid(_normal(rng, (T, routed), jnp.float32))
+    gates, idx = jax.lax.top_k(score, K)
+    local = idx - 64
+    gates = jnp.where((local >= 0) & (local < held), gates, 0.0)
+    u = _normal(rng, (T, W), s.dtype)
+    layer = 1
+    stack_up = _normal(rng, (2, held, W, inner), s.dtype, W ** -0.5)
+    stack_down = _normal(rng, (2, held, inner, W), s.dtype, inner ** -0.5)
+    plan = gm.plan_groups(local, held, gm.tile_rows_for(
+        T * min(K, held), held, s.dtype), share=True)
+    act = gm.grouped_relu2(gm.gather_rows(u, plan), stack_up, layer, plan,
+                           interpret=interpret)
+    got = gm.combine_rows(gm.grouped_matmul(act, stack_down, layer, plan,
+                                            interpret=interpret), plan, gates)
+    ue = u.astype(jnp.float32)
+
+    def one_expert(y, e):
+        out = jnp.square(jax.nn.relu(
+            ue @ stack_up[layer, e].astype(jnp.float32))
+        ) @ stack_down[layer, e].astype(jnp.float32)
+        weight = jnp.sum(jnp.where(local == e, gates, 0.0), axis=1)
+        return y + weight[:, None] * out, None
+
+    with jax.default_matmul_precision("highest"):
+        want, _ = jax.lax.scan(one_expert, jnp.zeros((T, W), jnp.float32),
+                               jnp.arange(held))
+    return [Check("moe_grouped_matmul_relu2(share 16 of 512 at a latent of "
+                  "1024)", float(_rel_err(got, want)), ATTENTION_TOL)]
+
+
+def _ssm_update_checks(name: str, s: KernelShapes, interpret: bool,
+                       heads: int, groups: int, state: int, lanes: int,
+                       decay_a_lane: bool) -> List[Check]:
+    """A decode step of the recurrence on ``slots`` sequences in a pool of
+    three layers ``[…, heads, state, lanes]``, in place, against the
+    reference: the pool after, and ``y``.  Kernel and reference run the
+    same float32 formula on the VPU; they may differ by FMA contraction
+    and the order of the read-out's sum."""
     ssu = _mod("ssm_state_update")
     rng = np.random.RandomState(12)
-    heads, groups, state, head = 32, 2, 256, 128
     rows = s.slots
-    pool = _normal(rng, (3, rows + 2, heads, state, head), jnp.float32)
-    a = jnp.exp(-jnp.abs(_normal(rng, (rows, heads), jnp.float32)))
-    dx = _normal(rng, (rows, heads, head), jnp.float32, 0.1)
+    pool = _normal(rng, (3, rows + 2, heads, state, lanes), jnp.float32)
+    a = jnp.exp(-jnp.abs(_normal(
+        rng, (rows, heads) + ((lanes,) if decay_a_lane else ()),
+        jnp.float32)))
+    dx = _normal(rng, (rows, heads, lanes), jnp.float32, 0.1)
     b = _normal(rng, (rows, groups, state), s.dtype)
     c = _normal(rng, (rows, groups, state), s.dtype)
 
@@ -597,8 +639,24 @@ def check_ssm_state_update(s: KernelShapes, interpret: bool) -> List[Check]:
         return [_rel_err(g, w) for g, w in zip(got, want)]
 
     state_err, y_err = errors(pool, a, dx, b, c)
-    return [Check("ssm_state_update_state", float(state_err), 1e-5),
-            Check("ssm_state_update_y", float(y_err), 1e-4)]
+    return [Check(f"{name}_state", float(state_err), 1e-5),
+            Check(f"{name}_y", float(y_err), 1e-4)]
+
+
+def check_ssm_state_update(s: KernelShapes, interpret: bool) -> List[Check]:
+    """At a published mixer's widths (32 heads of 128 in 2 groups, state
+    256), a head a lane row and the decay a scalar a head."""
+    return _ssm_update_checks("ssm_state_update", s, interpret, 32, 2, 256,
+                              128, False)
+
+
+def check_ssm_state_update_lanes(s: KernelShapes, interpret: bool
+                                 ) -> List[Check]:
+    """Where a lane row holds TWO heads of 64 (128 heads in 8 groups, state
+    128: held ``[64, 128, 128]``) and the decay is a value a lane: the
+    kernel's second form."""
+    return _ssm_update_checks("ssm_state_update_lanes", s, interpret, 64, 8,
+                              128, 128, True)
 
 
 def check_quantizer(s: KernelShapes, interpret: bool) -> List[Check]:
@@ -648,7 +706,8 @@ def check_block_sparse(s: KernelShapes, interpret: bool) -> List[Check]:
 CHECKS = (check_flash, check_flash_streamed, check_decode, check_paged,
           check_paged_hybrid, check_paged_latent, check_fused_adam, check_moe,
           check_moe_grouped,
-          check_moe_share, check_ssm_state_update, check_quantizer,
+          check_moe_share, check_moe_latent, check_ssm_state_update,
+          check_ssm_state_update_lanes, check_quantizer,
           check_block_sparse)
 
 

@@ -29,6 +29,15 @@ and ``B`` and ``C`` come spread over the lanes (``[d_state, d_head]``, 64 KB
 in the activations' type beside 2 MB of state: 6% more traffic), made by
 the caller's XLA program.
 
+**Several heads a lane row.**  A head of fewer than 128 numbers would
+leave its lanes half empty (and an array whose minor dimension is 64 is
+padded to 128 in HBM: twice the pool), so a model of such heads holds
+``pack`` heads of one group side by side on the lanes, ``[heads / pack,
+d_state, pack · d_head]``.  Nothing changes but the decay, which is then a
+value a LANE and no scalar: ``a [R, heads / pack, pack · d_head]``, as
+``dx`` is (``models/mamba2.py`` lays both out).  The operand's rank says
+which; ``a [R, heads]`` is the kernel it was.
+
 ``interpret``: as every entry point here (``select.py``).  Off the TPU the
 ``jax.numpy`` reference runs; the interpreter runs the kernel on the rows'
 stretch cut out of the pool (it does not alias).
@@ -58,7 +67,8 @@ def ssm_state_update_reference(pool, layer, first, a, dx, b, c
     at = (layer, first, 0, 0, 0)
     S = jax.lax.dynamic_slice(pool, at, (1, R) + pool.shape[2:])[0]
     S = S.astype(F32).reshape(R, G, heads // G, N, P)
-    new = a.astype(F32).reshape(R, G, heads // G, 1, 1) * S \
+    # a decay a head, or (several heads a lane row) a lane
+    new = a.astype(F32).reshape(R, G, heads // G, 1, -1) * S \
         + b.astype(F32)[:, :, None, :, None] \
         * dx.astype(F32).reshape(R, G, heads // G, 1, P)
     y = jnp.sum(new * c.astype(F32)[:, :, None, :, None], axis=3)
@@ -68,10 +78,12 @@ def ssm_state_update_reference(pool, layer, first, a, dx, b, c
 
 
 def _update_kernel(layer_ref, slots_ref, a_ref, pool_ref, dx_ref, b_ref,
-                   c_ref, out_ref, y_ref, *, k: int):
+                   c_ref, out_ref, y_ref, *, k: int, lanes: bool):
     """One (sequence, group): ``pool_ref``/``out_ref [1, 1, k, N, P]`` the
     same block of the aliased pool, ``dx_ref``/``y_ref [1, k, P]``,
-    ``b_ref``/``c_ref [1, 1, N, P]``, ``a_ref [R, heads]`` in SMEM."""
+    ``b_ref``/``c_ref [1, 1, N, P]``; ``a_ref [R, heads]`` in SMEM, a
+    scalar a head, or (``lanes``: a lane row holds several heads) ``[1, k,
+    P]`` as ``dx_ref`` is."""
     from jax.experimental import pallas as pl
 
     del layer_ref, slots_ref        # the index maps read them
@@ -79,7 +91,8 @@ def _update_kernel(layer_ref, slots_ref, a_ref, pool_ref, dx_ref, b_ref,
     b = b_ref[0, 0].astype(F32)
     c = c_ref[0, 0].astype(F32)
     for h in range(k):
-        new = a_ref[r, g * k + h] * pool_ref[0, 0, h].astype(F32) \
+        decay = a_ref[0, h:h + 1, :] if lanes else a_ref[r, g * k + h]
+        new = decay * pool_ref[0, 0, h].astype(F32) \
             + b * dx_ref[0, h:h + 1, :]
         out_ref[0, 0, h] = new.astype(out_ref.dtype)
         y_ref[0, h:h + 1, :] = jnp.sum(new * c, axis=0, keepdims=True)
@@ -95,6 +108,11 @@ def _update_pallas(pool, layer, slots, a, dx, b, c, interpret: bool):
     spread = lambda v: jnp.broadcast_to(v[..., None], v.shape + (P,))
     rows = lambda r, g, layer, slots: (r, g, 0)
     block = lambda r, g, layer, slots: (layer[0], slots[r], g, 0, 0)
+    lanes = a.ndim == 3     # a decay a lane: a row a head, as dx
+    a_spec = pl.BlockSpec((1, k, P), rows) if lanes else pl.BlockSpec(
+        (R, heads), lambda r, g, layer, slots: (0, 0),
+        memory_space=pltpu.SMEM)
+    block_spec = pl.BlockSpec((1, 1, k, N, P), block)
     kwargs = {}
     if not interpret:
         kwargs["input_output_aliases"] = {3: 0}     # the pool, in place
@@ -102,25 +120,25 @@ def _update_pallas(pool, layer, slots, a, dx, b, c, interpret: bool):
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
             dimension_semantics=("arbitrary", "arbitrary"))
     return pl.pallas_call(
-        functools.partial(_update_kernel, k=k),
+        functools.partial(_update_kernel, k=k, lanes=lanes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R, G),
             in_specs=[
-                pl.BlockSpec((R, heads), lambda r, g, layer, slots: (0, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1, k, N, P), block),
+                a_spec,
+                block_spec,
                 pl.BlockSpec((1, k, P), rows),
                 pl.BlockSpec((1, 1, N, P),
                              lambda r, g, layer, slots: (r, g, 0, 0)),
                 pl.BlockSpec((1, 1, N, P),
                              lambda r, g, layer, slots: (r, g, 0, 0)),
             ],
-            out_specs=[pl.BlockSpec((1, 1, k, N, P), block),
-                       pl.BlockSpec((1, k, P), rows)]),
+            out_specs=[block_spec, pl.BlockSpec((1, k, P), rows)]),
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct((R, heads, P), F32)],
-        interpret=interpret, name="ssm_state_update", **kwargs,
+        interpret=interpret,
+        name="ssm_state_update_lanes" if lanes else "ssm_state_update",
+        **kwargs,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots.astype(jnp.int32),
       a.astype(F32), pool, dx.astype(F32), spread(b), spread(c))
 
@@ -131,7 +149,9 @@ def ssm_state_update(pool: jnp.ndarray, layer, first, a: jnp.ndarray,
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``pool [layers, slots, heads, d_state, d_head]``: layer ``layer``'s
     slots ``first … first + R`` hold ``R`` sequences' states; ``a [R,
-    heads]`` the step's decay a head (1 for a row that is no sequence's),
+    heads]`` the step's decay a head (1 for a row that is no sequence's; or
+    ``[R, heads, d_head]``, a decay a lane, where a lane row holds several
+    of the model's heads: module docstring),
     ``dx [R, heads, d_head]`` its ``Δ · x`` (0 for such a row), ``b``/``c
     [R, groups, d_state]`` the token's ``B`` and ``C`` → (the pool with
     those states moved one step, in place where the kernel runs; ``y [R,

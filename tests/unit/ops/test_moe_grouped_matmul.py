@@ -192,4 +192,98 @@ def test_every_kernel_is_named_for_the_device_trace():
     assert len(re.findall(r"\bpl\.pallas_call\(", source)) == 1
     assert "interpret=interpret, name=name," in source
     names = re.findall(r'_differentiable\(\w+, "(\w+)"', source)
-    assert sorted(names) == ["moe_grouped_matmul", "moe_grouped_matmul_swiglu"]
+    assert sorted(names) == ["moe_grouped_matmul", "moe_grouped_matmul_relu2",
+                             "moe_grouped_matmul_swiglu"]
+
+
+# -- an expert of two matrices (a LatentMoE expert) ---------------------------
+
+W = 64      # the latent's width: the rows the experts multiply, not H
+
+
+def _latent_weights(seed=11, layers=3):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (T, W)),
+            jax.random.normal(ks[1], (layers, E, W, I)) / np.sqrt(W),
+            jax.random.normal(ks[2], (layers, E, I, W)) / np.sqrt(I),
+            jax.random.uniform(ks[3], (T, K)))
+
+
+def _two_matrix_experts(idx, u, w_up, w_down, gates):
+    y = jnp.zeros((T, W))
+    for e in range(E):
+        out = jnp.square(jax.nn.relu(u @ w_up[e])) @ w_down[e]
+        y += jnp.sum(jnp.where(idx == e, gates, 0.0), axis=1)[:, None] * out
+    return y
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["pallas_interpret", "ragged_dot"])
+def test_the_relu2_kernel_is_one_expert_at_a_time(routing, interpret):
+    """The up projection with ``relu(.)²`` as a grouped kernel (interpreter)
+    and as ``ragged_dot``, then the plain down kernel, against every expert
+    over all tokens; layer 1 of a stack of three, read where it lies."""
+    idx = ROUTINGS[routing]
+    plan = gm.plan_groups(idx, E, gm.tile_rows_for(T * K, E, jnp.float32))
+    u, w_up, w_down, gates = _latent_weights()
+    with jax.default_matmul_precision("highest"):
+        act = jax.jit(lambda l: gm.grouped_relu2(
+            gm.gather_rows(u, plan), w_up, l, plan, interpret=interpret))(
+                jnp.int32(1))
+        # (rows of unused tiles are undefined)
+        assert float(jnp.min(jnp.where(plan.row_valid[:, None], act, 0))) \
+            >= 0.0
+        out = gm.grouped_matmul(act, w_down, 1, plan, interpret=interpret)
+        got = gm.combine_rows(out, plan, gates)
+        want = _two_matrix_experts(idx, u, w_up[1], w_down[1], gates)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+    other = _two_matrix_experts(idx, u, w_up[0], w_down[0], gates)
+    assert float(jnp.max(jnp.abs(got - other))) > 0.1
+
+
+def test_the_relu2_kernels_interpreter_and_ragged_dot_agree_to_the_bit():
+    idx = ROUTINGS["one_heavy_expert"]
+    plan = gm.plan_groups(idx, E, 16)
+    u, w_up, _, _ = _latent_weights(12)
+    rows = gm.gather_rows(u, plan)
+    kernel = gm.grouped_relu2(rows, w_up, 2, plan, interpret=True)
+    plain = gm.grouped_relu2(rows, w_up, 2, plan, interpret=None)
+    used = np.asarray(plan.row_valid)
+    np.testing.assert_allclose(np.asarray(kernel)[used],
+                               np.asarray(plain)[used], rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("held", [None, (2, 4)], ids=["all", "share"])
+def test_the_dropless_layer_takes_the_experts_form_and_their_rows(held):
+    """``form="relu2"`` with ``rows`` apart from what the router reads: the
+    router scores ``x [.., H]``, the experts multiply ``rows [.., w]``, and
+    ``y`` is ``w`` wide; a share computes its experts' part."""
+    from deepspeed_tpu.moe.layer import DroplessMoE
+    from deepspeed_tpu.moe.sharded_moe import top_k_routing
+
+    u, w_up, w_down, _ = _latent_weights(13, layers=1)
+    x = jax.random.normal(jax.random.PRNGKey(14), (T, H))
+    wg = jax.random.normal(jax.random.PRNGKey(15), (H, E)) / np.sqrt(H)
+    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(16), (E,))
+    first, count = held or (0, E)
+    experts = {"w_up": w_up[0, first:first + count],
+               "w_down": w_down[0, first:first + count]}
+    layer = DroplessMoE(E, K, renormalize=True, scoring="sigmoid", held=held,
+                        form="relu2")
+    with jax.default_matmul_precision("highest"):
+        y, _, meta = layer(wg, experts, x[None], choice_bias=bias,
+                           rows=u[None])
+        idx, gates, _ = top_k_routing(wg, x, K, True, "sigmoid", bias)
+        here = (idx >= first) & (idx < first + count)
+        want = _two_matrix_experts(
+            jnp.where(here, idx, -1), u,
+            jnp.zeros_like(w_up[0]).at[first:first + count].set(
+                experts["w_up"]),
+            jnp.zeros_like(w_down[0]).at[first:first + count].set(
+                experts["w_down"]), gates)
+    assert y.shape == (1, T, W)
+    assert float(jnp.max(jnp.abs(y[0] - want))) < 2e-5
+    assert float(meta["assignments"]) == float(jnp.sum(here))
+    with pytest.raises(ValueError, match="form"):
+        DroplessMoE(E, K, form="gelu")

@@ -114,3 +114,14 @@ def test_the_share_check_leaves_most_tiles_empty(monkeypatch, shapes):
     used, tiles = map(int, __import__("re").search(
         r"(\d+) of (\d+) tiles", check.name).groups())
     assert 1 <= used <= tiles // 2
+
+
+@pytest.mark.parametrize("check", ["check_moe_latent",
+                                   "check_ssm_state_update_lanes"])
+def test_the_kernels_second_forms_are_checked_too(monkeypatch, shapes, check):
+    """An expert of two matrices at a latent's width, and a state held two
+    heads a lane row (PR 52): each passes in the interpreter and names the
+    form in what it reports."""
+    monkeypatch.setattr(selfcheck, "CHECKS", (getattr(selfcheck, check),))
+    names = [c.name for c in selfcheck.run_checks(shapes, interpret=True)]
+    assert names and all(("relu2" in n) or ("lanes" in n) for n in names)

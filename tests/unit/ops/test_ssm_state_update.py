@@ -66,3 +66,29 @@ def test_a_row_that_is_no_sequences_moves_nothing(interpret):
     np.testing.assert_array_equal(got[0, 2], pool[0, 2])
     np.testing.assert_array_equal(got[0, 4], pool[0, 4])
     assert float(abs(got[0, 1] - pool[0, 1]).max()) > 0
+
+
+@pytest.mark.parametrize("interpret", [None, True], ids=["reference", "kernel"])
+def test_several_heads_a_lane_row_is_the_same_recurrence(interpret):
+    """Heads of 64 held two a lane row (``[heads / 2, N, 128]``) with the
+    decay a LANE (``a [R, heads / 2, 128]``): the states and ``y`` are
+    those of the one-head-a-row form on the same numbers."""
+    L, slots, R, heads, G, N, P, pack = 2, 5, 3, 8, 2, 16, 64, 2
+    pool, a, dx, b, c = _case(2, L, slots, R, heads, G, N, P)
+    want_pool, want_y = ssm_state_update_reference(pool, 1, 1, a, dx, b, c)
+    # heads 2i and 2i + 1 side by side on the lanes
+    packed = lambda s: s.reshape(s.shape[:-3] + (heads // pack, pack, N, P)
+                                 ).swapaxes(-3, -2).reshape(
+                                     s.shape[:-3] + (heads // pack, N,
+                                                     pack * P))
+    rows = (R, heads // pack, pack * P)
+    got_pool, got_y = jax.jit(lambda *args: ssm_state_update(
+        *args, interpret=interpret))(
+            packed(pool), 1, 1,
+            jnp.broadcast_to(a[..., None], dx.shape).reshape(rows),
+            dx.reshape(rows), b, c)
+    np.testing.assert_allclose(got_pool, packed(want_pool), rtol=1e-6,
+                               atol=1e-6)
+    np.testing.assert_allclose(got_y.reshape(R, heads, P), want_y, rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_array_equal(got_pool[0], packed(pool)[0])

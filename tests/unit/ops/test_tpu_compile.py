@@ -1133,3 +1133,94 @@ def test_bert_step_holds_the_flash_kernels_on_v5e(one_chip, monkeypatch,
     # no transpose to or from per-head planes around the calls (the
     # one-head-a-program lowering made nine at this shape)
     assert _plane_relayouts(text, B, S, heads, 64) == []
+
+
+#: the grouped expert kernels' Mosaic modules (printed without locations) at
+#: the three sparse serving cells' shapes, and the state-space cell's state
+#: update, as the parent of PR 52 built them: length and the leading 16 hex
+#: digits of the text's SHA-256.  PR 52 gave the dropless layer a second
+#: FORM of expert (two matrices, ``moe_grouped_matmul_relu2``) and the
+#: state update a form for several heads a lane row: the cells that were
+#: measured with the gated kernels and the one-head-a-row update keep them
+_GROUPED_MODULES = {
+    "olmoe_chunks": ((4376, "ae8ef3c38f7e3fc6"), (3040, "9af48ac99cbab093")),
+    "olmoe_burst": ((4352, "ffade58a0721036b"), (3026, "6615dec66a9caca7")),
+    "hybrid_chunks": ((4344, "c58c8c454c453a2f"), (3040, "26afbe3f088e8b26")),
+    "hybrid_burst": ((4344, "4747a929b65a8beb"), (3040, "5f0ff67d2eae3a4f")),
+    "latent_chunks": ((4344, "b7d06de36bba9dee"), (3040, "348cd57932617ba8")),
+    "latent_burst": ((4344, "bcf96fd0368bdb10"), (3040, "bb5e2d3c7b86a5a2")),
+}
+#: a call's tokens, k, groups (the experts held), whether they are a share,
+#: layers, H, I
+_GROUPED_CALLS = {
+    "olmoe_chunks": (288, 8, 64, False, 8, 2048, 1024),
+    "olmoe_burst": (32, 8, 64, False, 8, 2048, 1024),
+    "hybrid_chunks": (512, 8, 16, True, 6, 4096, 2048),
+    "hybrid_burst": (256, 8, 16, True, 6, 4096, 2048),
+    "latent_chunks": (384, 8, 8, True, 4, 7680, 2048),
+    "latent_burst": (128, 8, 8, True, 4, 7680, 2048),
+}
+_STATE_UPDATE_MODULE = (30808, "67eabd975b90a8b4")
+
+
+def _module_of(monkeypatch, capsys, fn, *args):
+    """(length, digest) of the Mosaic module of the one Pallas call ``fn``
+    traces, as ``pallas_call(debug=True)`` prints it."""
+    import hashlib
+
+    from jax.experimental import pallas as pl
+
+    real = pl.pallas_call
+    monkeypatch.setattr(pl, "pallas_call",
+                        lambda *a, **kw: real(*a, **{**kw, "debug": True}))
+    jax.clear_caches()      # the call is a jitted function of its own
+    capsys.readouterr()
+    jax.jit(fn).lower(*args)
+    text = capsys.readouterr().out.split(
+        "The Mosaic module for pallas_call", 1)[1].split("\n", 1)[1]
+    return len(text), hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("call", sorted(_GROUPED_CALLS))
+def test_the_gated_expert_kernels_are_the_ones_the_cells_were_measured_with(
+        monkeypatch, capsys, one_chip, call):
+    from deepspeed_tpu.ops.pallas import moe_grouped_matmul as gm
+
+    T, k, E, share, L, H, I = _GROUPED_CALLS[call]
+    tm = gm.tile_rows_for(T * min(k, E), E, jnp.bfloat16)
+    arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+
+    def up(idx, x, w_gate, w_up, layer):
+        plan = gm.plan_groups(idx, E, tm, share=share)
+        return gm.grouped_swiglu(gm.gather_rows(x, plan), w_gate, w_up, layer,
+                                 plan, interpret=False)
+
+    def down(idx, x, w_down, layer):
+        plan = gm.plan_groups(idx, E, tm, share=share)
+        return gm.grouped_matmul(gm.gather_rows(x, plan), w_down, layer,
+                                 plan, interpret=False)
+
+    idx, layer = arg((T, k), jnp.int32), arg((), jnp.int32)
+    got = (_module_of(monkeypatch, capsys, up, idx, arg((T, H)),
+                      arg((L, E, H, I)), arg((L, E, H, I)), layer),
+           _module_of(monkeypatch, capsys, down, idx, arg((T, I)),
+                      arg((L, E, I, H)), layer))
+    assert got == _GROUPED_MODULES[call]
+
+
+def test_a_head_a_lane_row_is_the_update_the_state_space_cell_was_measured_with(
+        monkeypatch, capsys, one_chip):
+    from deepspeed_tpu.ops.pallas import ssm_state_update as ssu
+
+    arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    f32 = jnp.float32
+    got = _module_of(
+        monkeypatch, capsys,
+        lambda pool, layer, a, dx, b, c: ssu.ssm_state_update(
+            pool, layer, 1, a=a, dx=dx, b=b, c=c, interpret=False),
+        arg((6, 97, 32, 256, 128), f32), arg((), jnp.int32),
+        arg((96, 32), f32), arg((96, 32, 128), f32), arg((96, 2, 256)),
+        arg((96, 2, 256)))
+    assert got == _STATE_UPDATE_MODULE
