@@ -196,6 +196,10 @@ class RaggedInferenceEngineV2:
         #: holds (``query_tokens_per_row``), by the kind's name
         self.last_attn_pages_per_step: Dict[str, int] = {}
         self.last_attn_query_tokens: Dict[str, int] = {}
+        #: the rows in a tile of the dropless expert layer's grouped
+        #: matmuls (``moe_grouped_matmul.tile_rows_for``), by program
+        #: (``n_steps<k>``: 1 carries the chunks), set as each is traced
+        self.last_moe_tile_rows: Dict[str, int] = {}
         self._tp = int(mesh.shape.get("tensor", 1)) if mesh is not None else 1
         self.kinds: Dict[str, AttentionKind] = {
             k.name: k for k in self.adapter.kinds}
@@ -758,7 +762,7 @@ class RaggedInferenceEngineV2:
         newest = jnp.concatenate([
             toks[-1], jnp.zeros((self.prefill_batch,), jnp.int32)
             if firsts is None else firsts])
-        return toks, pool, self._pack_moe_stats(), firsts, newest
+        return toks, pool, self._pack_moe_stats(n_steps), firsts, newest
 
     def _decode(self, n_steps: int) -> Callable:
         fn = self._decode_jits.get(n_steps)
@@ -782,15 +786,21 @@ class RaggedInferenceEngineV2:
 
     # -- MoE serving telemetry -----------------------------------------
 
-    def _pack_moe_stats(self) -> Optional[jnp.ndarray]:
-        """Inside a program, after its layer scan: the active collector's
-        entries (each with the axis the scan gave it: ``[periods]`` or
-        ``[periods, E]``, one entry for each sparse layer of a period) as
-        ONE float32 array ``[sparse layers, columns]``, so that the host
-        fetches the router's stats in one transfer beside the tokens
-        (fetched entry by entry they cost the serving round 7.5 ms of
-        host, PERF.md PR 27).  Which columns hold what is a fact of the
-        trace, kept in ``_moe_columns``."""
+    def _pack_moe_stats(self, n_steps: int) -> Optional[jnp.ndarray]:
+        """Inside a program of ``n_steps`` steps, after its layer scan:
+        the active collector's entries (each with the axis the scan gave
+        it: ``[periods]`` or ``[periods, E]``, one entry for each sparse
+        layer of a period) as ONE float32 array ``[sparse layers,
+        columns]``, so that the host fetches the router's stats in one
+        transfer beside the tokens (fetched entry by entry they cost the
+        serving round 7.5 ms of host, PERF.md PR 27).  Which columns hold
+        what is a fact of the trace, kept in ``_moe_columns``; so is the
+        tile the expert layer was just traced with
+        (``last_moe_tile_rows``)."""
+        tile = getattr(getattr(self.model, "_moe_layer", None),
+                       "last_tile_rows", None)
+        if tile is not None:
+            self.last_moe_tile_rows[f"n_steps{n_steps}"] = tile
         coll = numerics.active()
         named = coll.harvest() if coll is not None else None
         if not named:
@@ -870,6 +880,14 @@ class RaggedInferenceEngineV2:
             help="held experts with at least one row (whose weights "
                  "the grouped matmul reads), summed over the layers "
                  "that have experts and steps")
+        if "moe/rows_computed" in cols:
+            tel.inc_counter(
+                "inference/moe/rows_computed",
+                v=float(cols["moe/rows_computed"].mean()) * steps,
+                help="rows of the tiles in use that the grouped matmuls "
+                     "multiply, padding and all, a layer (the mean over "
+                     "layers) a step of a call: inference/moe/assignments "
+                     "over it is how full the tiles are")
 
     def _publish_gauges(self) -> None:
         """The registry's collect hook: the gauges of the pools and of
@@ -899,6 +917,12 @@ class RaggedInferenceEngineV2:
                 help="consecutive tokens of a prefill chunk that share a "
                      "grid row of the kind's paged kernel, as the traced "
                      "programs were built")
+        for name, rows in self.last_moe_tile_rows.items():
+            tel.set_gauge(
+                f"inference/moe/tile_rows/{name}", float(rows),
+                help="rows in a tile of the expert layer's grouped "
+                     "matmuls, as the program (n_steps<k>: 1 carries the "
+                     "chunks) was built: twice the router's mean group")
         for name, layers in self.last_layers_by_part.items():
             tel.set_gauge(
                 f"inference/layers/{name}", float(layers),

@@ -87,7 +87,12 @@ class DroplessMoE:
     result (the shares' parts add up to the whole layer's).  Nothing
     stands in for the other chips or the exchange with them.  ``meta``
     then counts ``assignments`` and ``experts_active`` of the share, and
-    ``assignments_routed`` is the router's rows x k."""
+    ``assignments_routed`` is the router's rows x k.
+
+    ``meta`` also counts ``rows_computed``, the rows of the tiles in use
+    that the grouped matmuls multiply (``assignments`` over it is how full
+    they are); the tile itself is static and stays on the layer,
+    ``last_tile_rows``, as the last call traced was built."""
 
     def __init__(self, num_experts: int, k: int, renormalize: bool = False,
                  mesh: Any = None, scoring: str = "softmax",
@@ -108,6 +113,8 @@ class DroplessMoE:
             raise ValueError(f"held=(first, count)={held} is no share of "
                              f"{num_experts} experts")
         self.held = held
+        #: rows in a tile of the last call traced (``tile_rows_for``)
+        self.last_tile_rows: Optional[int] = None
 
     def __call__(self, wg: jnp.ndarray, expert_params: Any, x: jnp.ndarray,
                  layer: Any = None, choice_bias: Optional[jnp.ndarray] = None,
@@ -126,27 +133,30 @@ class DroplessMoE:
             expert_idx, weights, meta = top_k_routing(
                 wg, tokens, self.k, self.renormalize, self.scoring,
                 choice_bias)
+        # a tile follows the group the ROUTER expects an expert to get,
+        # rows x k / its experts, wherever the experts live: a held
+        # expert's group is no larger for being one of a share
+        self.last_tile_rows = tile_rows = gm.tile_rows_for(
+            B * S * self.k, self.num_experts, x.dtype)
         if self.held is None:
-            plan = gm.plan_groups(
-                expert_idx, self.num_experts,
-                gm.tile_rows_for(B * S * self.k, self.num_experts, x.dtype))
+            plan = gm.plan_groups(expert_idx, self.num_experts, tile_rows)
         else:
             # this chip's share: experts first .. first + count - 1 are
             # groups 0 .. count - 1, the others' assignments get no row
-            # and no weight; the tiles are sized for what can land here
+            # and no weight; what CAN land here sizes the plan's static
+            # count of tiles (``plan_groups``), not the tile
             first, count = self.held
             local = expert_idx - first
             here = (local >= 0) & (local < count)
             weights = jnp.where(here, weights, 0.0)
-            plan = gm.plan_groups(
-                local, count,
-                gm.tile_rows_for(B * S * min(self.k, count), count, x.dtype),
-                share=True)
+            plan = gm.plan_groups(local, count, tile_rows, share=True)
             meta = type(meta)(
                 meta, assignments_routed=meta["assignments"],
                 assignments=jnp.sum(plan.group_sizes).astype(jnp.float32),
                 experts_active=jnp.sum(plan.group_sizes > 0
                                        ).astype(jnp.float32))
+        meta = type(meta)(meta, rows_computed=(
+            plan.num_tiles[0] * tile_rows).astype(jnp.float32))
         sharded = self.mesh is not None and self.mesh.size > 1
         fed = tokens if rows is None else rows.reshape(B * S, -1)
         leaves, up = EXPERT_FORMS[self.form]

@@ -18,6 +18,18 @@ any routing can need.  Nothing is dropped, whatever the routing.  Where
 the ``E`` groups are a share of the router's experts (``share``),
 the assignments to the others get no row and the rest is as before.
 
+Two sizes, two rules.  The TILE (:func:`tile_rows_for`) follows the group
+the router EXPECTS an expert to get, ``T·k`` over the experts it chooses
+among, held here or not: a tile in use costs its rows' traffic (fetched
+once a column slice, written once) at the HBM's rate whether the rows are
+real or padding, and a group's second tile costs a pass of its own with no
+weight fetch to hide under.  The static COUNT of tiles follows what CAN
+land (``plan_groups``); a tile of the unused tail is a grid step that
+fetches and writes nothing, ~0.07 µs on a v5e, but every static row is a
+row of the plan's index arithmetic and of ``gather_rows`` (~0.045 µs)
+whether a tile uses it or not (PERF.md §6, PR 53: the sweep of tiles at
+the sparse serving cells' shapes).
+
 The kernels (``name="moe_grouped_matmul…"`` on the device trace) walk the
 tiles with the expert's weight block chosen by a scalar-prefetched
 ``tile_group``.  Consecutive tiles of one expert keep the block in VMEM, so
@@ -80,7 +92,16 @@ class GroupPlan(NamedTuple):
 
 def tile_rows_for(assignments: int, num_experts: int, dtype) -> int:
     """Rows in a tile: twice the mean group (so most groups are one tile),
-    a power of two between the dtype's sublane tile and the MXU's 128."""
+    a power of two between the dtype's sublane tile and the MXU's 128.
+
+    ``assignments`` over ``num_experts`` are the ROUTER's: its rows x k
+    over the experts it chooses among.  That mean is a held expert's
+    expected group whatever share of the experts the caller holds, so a
+    share is given the same tile as the uncut layer.  What can land on a
+    share, as if every one of a token's choices were local, bounds the
+    static count of tiles (``plan_groups``) and has no say here: a tile
+    sized by it is 128 rows a sixth full, whose padding the kernels fetch
+    and write and whose static rows the plan walks."""
     floor = 32 // jnp.dtype(dtype).itemsize        # 8 for f32, 16 for bf16
     want = max(1, -(-2 * assignments // num_experts))
     return int(min(128, max(floor, 1 << (want - 1).bit_length())))

@@ -529,9 +529,10 @@ def check_moe_share(s: KernelShapes, interpret: bool) -> List[Check]:
     """The grouped expert matmul over a SHARE of the router's experts (16
     held of 256, 8 a token: a decode step's 256 rows put ~8 on each held
     expert and 15 assignments of 16 land elsewhere), so that most of the
-    plan's static tiles are empty; experts of width 2048 over the model's
-    hidden size, or its FFN where that is narrower.  Against each held
-    expert run over all tokens in float32."""
+    plan's static tiles are empty, at the tile the layer gives a share (the
+    router's mean group's: 16 rows here); experts of width 2048 over the
+    model's hidden size, or its FFN where that is narrower.  Against each
+    held expert run over all tokens in float32."""
     gm = _mod("moe_grouped_matmul")
     rng = np.random.RandomState(12)
     T, routed, held, K, H = 256, 256, 16, 8, s.hidden
@@ -548,7 +549,7 @@ def check_moe_share(s: KernelShapes, interpret: bool) -> List[Check]:
                                     H ** -0.5) for _ in range(2))
     stack_down = _normal(rng, (2, held, inner, H), s.dtype, inner ** -0.5)
     plan = gm.plan_groups(local, held, gm.tile_rows_for(
-        T * min(K, held), held, s.dtype), share=True)
+        T * K, routed, s.dtype), share=True)
     act = gm.grouped_swiglu(gm.gather_rows(x, plan), stack_gate, stack_up,
                             layer, plan, interpret=interpret)
     got = gm.combine_rows(gm.grouped_matmul(act, stack_down, layer, plan,
@@ -575,8 +576,9 @@ def check_moe_latent(s: KernelShapes, interpret: bool) -> List[Check]:
     """Experts of TWO matrices with ``relu(.)²`` between, at a latent's
     width and not the model's (1,024 wide, inner 2,688: a LatentMoE
     layer's), over a share of the router's experts (16 held of 512, 22 a
-    token): ``grouped_relu2`` then the plain grouped matmul, against each
-    held expert run over all tokens in float32."""
+    token, at the router's tile: 32 rows for its mean group of 11):
+    ``grouped_relu2`` then the plain grouped matmul, against each held
+    expert run over all tokens in float32."""
     gm = _mod("moe_grouped_matmul")
     rng = np.random.RandomState(13)
     T, routed, held, K, W, inner = 256, 512, 16, 22, 1024, 2688
@@ -589,7 +591,7 @@ def check_moe_latent(s: KernelShapes, interpret: bool) -> List[Check]:
     stack_up = _normal(rng, (2, held, W, inner), s.dtype, W ** -0.5)
     stack_down = _normal(rng, (2, held, inner, W), s.dtype, inner ** -0.5)
     plan = gm.plan_groups(local, held, gm.tile_rows_for(
-        T * min(K, held), held, s.dtype), share=True)
+        T * K, routed, s.dtype), share=True)
     act = gm.grouped_relu2(gm.gather_rows(u, plan), stack_up, layer, plan,
                            interpret=interpret)
     got = gm.combine_rows(gm.grouped_matmul(act, stack_down, layer, plan,

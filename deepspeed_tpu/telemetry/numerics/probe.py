@@ -164,7 +164,8 @@ def moe_stats(meta: Dict[str, Any]) -> None:
     if c is None or not c.want_moe:
         return
     for key in ("load", "entropy", "drop_rate", "overflow_frac",
-                "assignments", "experts_active", "assignments_routed"):
+                "assignments", "experts_active", "assignments_routed",
+                "rows_computed"):
         if key in meta:
             c.add(MOE_PREFIX + key, meta[key])
 

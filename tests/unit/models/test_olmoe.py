@@ -246,7 +246,8 @@ def test_the_engine_counts_assignments_and_active_experts(model, params):
             return metric.value if metric is not None else 0.0
 
         # another test of this process may have counted already
-        names = ("inference/moe/assignments", "inference/moe/experts_active")
+        names = ("inference/moe/assignments", "inference/moe/experts_active",
+                 "inference/moe/rows_computed")
         before = {name: value(name) for name in names}
         engine.generate([np.asarray(IDS[0, :20]).tolist()], max_new_tokens=5)
         k, layers, experts = 3, 2, 8
@@ -254,6 +255,17 @@ def test_the_engine_counts_assignments_and_active_experts(model, params):
         active = value(names[1]) - before[names[1]]
         assert 5 * layers * k <= active <= 5 * layers * experts
         assert float(active).is_integer()
+        # the rows the grouped matmuls multiplied: whole tiles, no fewer
+        # than the assignments, and at most one part-filled tile a group
+        rows = value(names[2]) - before[names[2]]
+        tiles = engine.last_moe_tile_rows
+        assert tiles == {"n_steps1": 64, "n_steps4": 8}
+        assert 68 * k + 4 * (4 * k) <= rows <= (
+            68 * k + experts * 64 + 4 * (4 * k + experts * 8))
+        assert rows * layers % 8 == 0
+        gauges = tel.registry.snapshot()["gauges"]
+        assert {n: gauges[f"inference/moe/tile_rows/{n}"]["value"]
+                for n in tiles} == tiles
         # a gauge of the router's last stats, worked out on a read
         assert tel.registry.snapshot()["gauges"][
             "inference/moe/drop_rate"]["value"] == 0.0

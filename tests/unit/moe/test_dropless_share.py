@@ -159,3 +159,82 @@ def test_a_shares_plan_is_sized_for_what_can_land_on_it(count, k):
 def test_held_must_be_a_share_of_the_routers_experts():
     with pytest.raises(ValueError, match="no share"):
         DroplessMoE(32, 8, held=(28, 8))
+
+
+def _one_expert_at_a_time(m, x, idx, weights, first, count):
+    """Each held expert run over all tokens, weighted by the tokens'
+    weights for it: float32, no plan, no tiles."""
+    y = jnp.zeros_like(x)
+    for e in range(count):
+        out = (jax.nn.silu(x @ m["w_gate"][e]) * (x @ m["w_up"][e])
+               ) @ m["w_down"][e]
+        y = y + jnp.sum(jnp.where(idx == first + e, weights, 0.0),
+                        axis=1)[:, None] * out
+    return y
+
+
+# the tiles a share is given at the serving cells' shapes (64, 32, 16: the
+# router's mean group of 32, 16, 8), each where EVERY choice of every
+# token lands on the held experts, which is what the static tiles are
+# sized for, and where none does
+@pytest.mark.parametrize("lands", ["every_choice", "none"])
+@pytest.mark.parametrize("tokens, tile", [(128, 64), (64, 32), (32, 16)])
+def test_a_share_at_the_routers_tile_computes_whatever_lands(tokens, tile,
+                                                             lands):
+    experts, k, first, count = 16, 4, 4, 8
+    ks = jax.random.split(jax.random.PRNGKey(tile), 6)
+    m = {"wg": jax.random.normal(ks[0], (H, experts)) / np.sqrt(H),
+         "w_gate": jax.random.normal(ks[1], (count, H, I)) / np.sqrt(H),
+         "w_up": jax.random.normal(ks[2], (count, H, I)) / np.sqrt(H),
+         "w_down": jax.random.normal(ks[3], (count, I, H)) / np.sqrt(I)}
+    x = jax.random.normal(ks[4], (tokens, H))
+    # a sigmoid score lies in (0, 1): a bias of 10 decides the choice
+    held = (jnp.arange(experts) >= first) & (jnp.arange(experts)
+                                             < first + count)
+    bias = jnp.where(held, 10.0, 0.0) * (1 if lands == "every_choice" else -1)
+    layer = DroplessMoE(experts, k, renormalize=True, scoring="sigmoid",
+                        held=(first, count))
+    with jax.default_matmul_precision("highest"):
+        y, _, meta = layer(m["wg"], {n: m[n] for n in
+                                     ("w_gate", "w_up", "w_down")},
+                           x[None], choice_bias=bias)
+        idx, weights, _ = top_k_routing(m["wg"], x, k, True, "sigmoid", bias)
+        want = _one_expert_at_a_time(m, x, idx, weights, first, count)
+    landed = tokens * k if lands == "every_choice" else 0
+    assert float(meta["assignments"]) == landed
+    assert float(meta["assignments_routed"]) == tokens * k
+    # the tile is the router's (tokens x k over ITS experts), not the one
+    # of what can land on the share (tokens x k over the 8 held: twice it)
+    assert layer.last_tile_rows == tile == gm.tile_rows_for(
+        tokens * k, experts, x.dtype)
+    np.testing.assert_allclose(np.asarray(y[0]), np.asarray(want), atol=3e-5)
+    assert bool(np.asarray(y).any()) == (landed > 0)
+
+    local = idx - first
+    plan = gm.plan_groups(local, count, tile, share=True)
+    tiles = plan.tile_group.shape[0]
+    assert tiles == tokens * k // tile + count
+    assert 1 <= int(plan.num_tiles[0]) <= tiles
+    assert float(meta["rows_computed"]) == int(plan.num_tiles[0]) * tile
+    assert landed <= float(meta["rows_computed"]) or landed == 0
+    here = np.asarray((local >= 0) & (local < count))
+    assert here.sum() == landed == int(plan.group_sizes.sum())
+    # every assignment that landed has a valid row of its own, in a tile
+    # in use, that holds its token; the others point at row 0
+    dest = np.asarray(plan.dest)
+    assert (dest[~here] == 0).all()
+    assert len(set(dest[here].tolist())) == landed
+    assert (dest[here] < int(plan.num_tiles[0]) * tile).all()
+    assert np.asarray(plan.row_valid)[dest[here]].all()
+    assert (np.asarray(plan.row_token)[dest[here]]
+            == np.broadcast_to(np.arange(tokens)[:, None], here.shape)[here]
+            ).all()
+    # and the kernels themselves (interpreted) on that plan
+    here_w = jnp.where(jnp.asarray(here), weights, 0.0)
+    stack = {n: m[n][None] for n in ("w_gate", "w_up", "w_down")}
+    with jax.default_matmul_precision("highest"):
+        act = gm.grouped_swiglu(gm.gather_rows(x, plan), stack["w_gate"],
+                                stack["w_up"], 0, plan, interpret=True)
+        got = gm.combine_rows(gm.grouped_matmul(
+            act, stack["w_down"], 0, plan, interpret=True), plan, here_w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)

@@ -182,7 +182,16 @@ def test_bfloat16_rows_accumulate_in_float32():
     (32 * 8, 64, jnp.bfloat16, 16),      # a decode step of the 8-of-64 model
     (256 * 8, 64, jnp.bfloat16, 64),     # a prefill call
     (8192 * 2, 8, jnp.bfloat16, 128),    # a training row of an 8x7B
-    (4, 8, jnp.float32, 8)])
+    (4, 8, jnp.float32, 8),
+    # a SHARE of the router's experts is given the router's mean group
+    # too (rows x k over the experts it chooses among), whatever part of
+    # them the chip holds: the serving cells' steps with chunks and bursts
+    (384 * 22, 512, jnp.bfloat16, 64),   # 22 of 512, 128 held: 16.5 a group
+    (128 * 22, 512, jnp.bfloat16, 16),
+    (512 * 8, 256, jnp.bfloat16, 32),    # 8 of 256, 16 held: 16 a group
+    (256 * 8, 256, jnp.bfloat16, 16),
+    (384 * 8, 256, jnp.bfloat16, 32),    # 8 of 256, 8 held: 12 a group
+    (128 * 8, 256, jnp.bfloat16, 16)])
 def test_tile_rows_follow_the_mean_group(assignments, experts, dtype, rows):
     assert gm.tile_rows_for(assignments, experts, dtype) == rows
 

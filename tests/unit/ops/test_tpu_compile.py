@@ -1136,29 +1136,39 @@ def test_bert_step_holds_the_flash_kernels_on_v5e(one_chip, monkeypatch,
 
 
 #: the grouped expert kernels' Mosaic modules (printed without locations) at
-#: the three sparse serving cells' shapes, and the state-space cell's state
-#: update, as the parent of PR 52 built them: length and the leading 16 hex
-#: digits of the text's SHA-256.  PR 52 gave the dropless layer a second
-#: FORM of expert (two matrices, ``moe_grouped_matmul_relu2``) and the
-#: state update a form for several heads a lane row: the cells that were
-#: measured with the gated kernels and the one-head-a-row update keep them
+#: the four sparse serving cells' shapes, and the state-space cell's state
+#: update: length and the leading 16 hex digits of the text's SHA-256.
+#: OLMoE's two and the state update are as the parent of PR 52 built them
+#: (PR 52 gave the dropless layer a second FORM of expert, two matrices,
+#: ``moe_grouped_matmul_relu2``, and the state update a form for several
+#: heads a lane row: the cells that were measured with the gated kernels
+#: and the one-head-a-row update keep them).  The three SHARES' are PR 53's:
+#: their tile is the router's mean group's (``tile_rows_for``), not the one
+#: of what can land on the share, which was 128 rows in all six
 _GROUPED_MODULES = {
     "olmoe_chunks": ((4376, "ae8ef3c38f7e3fc6"), (3040, "9af48ac99cbab093")),
     "olmoe_burst": ((4352, "ffade58a0721036b"), (3026, "6615dec66a9caca7")),
-    "hybrid_chunks": ((4344, "c58c8c454c453a2f"), (3040, "26afbe3f088e8b26")),
-    "hybrid_burst": ((4344, "4747a929b65a8beb"), (3040, "5f0ff67d2eae3a4f")),
-    "latent_chunks": ((4344, "b7d06de36bba9dee"), (3040, "348cd57932617ba8")),
-    "latent_burst": ((4344, "bcf96fd0368bdb10"), (3040, "bb5e2d3c7b86a5a2")),
+    "hybrid_chunks": ((4328, "91ce1efff7b6399f"), (3032, "7c57ada04e1b3faa")),
+    "hybrid_burst": ((4328, "dffce5f8d65d26c9"), (3032, "39faec49b5b50b68")),
+    "latent_chunks": ((4328, "37e7bd9774d204aa"), (3032, "b26e9578274208bd")),
+    "latent_burst": ((4320, "41b3458e1caa12ab"), (3026, "72af2b370eb319b6")),
+    "nemotron_chunks": ((3146, "9023df1befcc7905"),
+                        (3016, "6fadcfa9647e0e34")),
+    "nemotron_burst": ((3146, "50fce319f41eec0e"),
+                       (3016, "01aee7ea28fbdc63")),
 }
-#: a call's tokens, k, groups (the experts held), whether they are a share,
-#: layers, H, I
+#: a call's tokens, k, the router's experts, groups (the experts held),
+#: whether they are a share, layers, the rows' width, I, the experts' form;
+#: their tiles: 128 / 16 (OLMoE), 32 / 16 (hybrid, latent), 64 / 16
 _GROUPED_CALLS = {
-    "olmoe_chunks": (288, 8, 64, False, 8, 2048, 1024),
-    "olmoe_burst": (32, 8, 64, False, 8, 2048, 1024),
-    "hybrid_chunks": (512, 8, 16, True, 6, 4096, 2048),
-    "hybrid_burst": (256, 8, 16, True, 6, 4096, 2048),
-    "latent_chunks": (384, 8, 8, True, 4, 7680, 2048),
-    "latent_burst": (128, 8, 8, True, 4, 7680, 2048),
+    "olmoe_chunks": (288, 8, 64, 64, False, 8, 2048, 1024, "swiglu"),
+    "olmoe_burst": (32, 8, 64, 64, False, 8, 2048, 1024, "swiglu"),
+    "hybrid_chunks": (512, 8, 256, 16, True, 6, 4096, 2048, "swiglu"),
+    "hybrid_burst": (256, 8, 256, 16, True, 6, 4096, 2048, "swiglu"),
+    "latent_chunks": (384, 8, 256, 8, True, 4, 7680, 2048, "swiglu"),
+    "latent_burst": (128, 8, 256, 8, True, 4, 7680, 2048, "swiglu"),
+    "nemotron_chunks": (384, 22, 512, 128, True, 5, 1024, 2688, "relu2"),
+    "nemotron_burst": (128, 22, 512, 128, True, 5, 1024, 2688, "relu2"),
 }
 _STATE_UPDATE_MODULE = (30808, "67eabd975b90a8b4")
 
@@ -1184,17 +1194,20 @@ def _module_of(monkeypatch, capsys, fn, *args):
 @pytest.mark.parametrize("call", sorted(_GROUPED_CALLS))
 def test_the_gated_expert_kernels_are_the_ones_the_cells_were_measured_with(
         monkeypatch, capsys, one_chip, call):
+    from deepspeed_tpu.moe.layer import EXPERT_FORMS
     from deepspeed_tpu.ops.pallas import moe_grouped_matmul as gm
 
-    T, k, E, share, L, H, I = _GROUPED_CALLS[call]
-    tm = gm.tile_rows_for(T * min(k, E), E, jnp.bfloat16)
+    T, k, routed, E, share, L, H, I, form = _GROUPED_CALLS[call]
+    # the tile as ``DroplessMoE`` asks for it: the router's mean group
+    tm = gm.tile_rows_for(T * k, routed, jnp.bfloat16)
     arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
         shape, dt, sharding=one_chip)
+    leaves, up_call = EXPERT_FORMS[form]
 
-    def up(idx, x, w_gate, w_up, layer):
+    def up(idx, x, layer, *weights):
         plan = gm.plan_groups(idx, E, tm, share=share)
-        return gm.grouped_swiglu(gm.gather_rows(x, plan), w_gate, w_up, layer,
-                                 plan, interpret=False)
+        return getattr(gm, up_call)(gm.gather_rows(x, plan), *weights, layer,
+                                    plan, interpret=False)
 
     def down(idx, x, w_down, layer):
         plan = gm.plan_groups(idx, E, tm, share=share)
@@ -1202,8 +1215,8 @@ def test_the_gated_expert_kernels_are_the_ones_the_cells_were_measured_with(
                                  plan, interpret=False)
 
     idx, layer = arg((T, k), jnp.int32), arg((), jnp.int32)
-    got = (_module_of(monkeypatch, capsys, up, idx, arg((T, H)),
-                      arg((L, E, H, I)), arg((L, E, H, I)), layer),
+    got = (_module_of(monkeypatch, capsys, up, idx, arg((T, H)), layer,
+                      *[arg((L, E, H, I))] * len(leaves)),
            _module_of(monkeypatch, capsys, down, idx, arg((T, I)),
                       arg((L, E, I, H)), layer))
     assert got == _GROUPED_MODULES[call]
