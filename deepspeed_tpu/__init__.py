@@ -6,6 +6,13 @@ stages are GSPMD sharding policies, parallelism modes are mesh axes, the hot
 path is one jitted train step.
 """
 
+import time
+
+#: ``perf_counter()`` at this file's first and last line: the telemetry
+#: package, when it is first imported, enters them in the start-up record
+#: as ``startup/package_import`` (no import is added here for it)
+_IMPORT_STAMPS = [time.perf_counter(), None]
+
 from .version import __version__
 from . import comm
 from .parallel import MeshLayout, build_mesh
@@ -20,11 +27,15 @@ def initialize(*args, **kwargs):
     """Public factory — mirrors ``deepspeed.initialize`` [L ACC:2358-2439].
 
     Returns ``(engine, optimizer, dataloader, lr_scheduler)``.  Imported
-    lazily so light uses (comm/mesh only) don't pay engine import cost.
+    lazily so light uses (comm/mesh only) don't pay engine import cost:
+    that import is the start's first phase, so its two stamps are taken
+    here and handed to the start-up record, whose package it loads.
     """
+    entered = time.perf_counter()
     from .runtime.entry import initialize as _initialize
 
-    return _initialize(*args, **kwargs)
+    return _initialize(*args, **kwargs,
+                       _entered=(entered, time.perf_counter()))
 
 
 def init_inference(*args, **kwargs):
@@ -51,3 +62,6 @@ def __getattr__(name):
 
         return _zero
     raise AttributeError(f"module 'deepspeed_tpu' has no attribute {name!r}")
+
+
+_IMPORT_STAMPS[1] = time.perf_counter()

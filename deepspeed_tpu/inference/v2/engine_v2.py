@@ -109,7 +109,7 @@ from ...ops.pallas.paged_attention import (pages_per_step,
                                            paged_decode_impl,
                                            query_tokens_per_row)
 from ...parallel.mesh import AXIS_TENSOR, strip_manual_axes
-from ...telemetry import get_telemetry, numerics
+from ...telemetry import get_telemetry, numerics, startup_span
 from ...telemetry.memory import get_memory_ledger
 from ...telemetry.perf import get_compile_tracker, tracked_jit
 from ...utils.jax_compat import shard_map
@@ -240,22 +240,29 @@ class RaggedInferenceEngineV2:
         make_sched = scheduler_factory or RaggedScheduler
         self.scheduler = make_sched(self.cache_config, max_batch_slots,
                                     prefill_chunk, prefill_batch)
-        if self._tp > 1:
-            spec_tree = self.model.param_specs(params)
-            self.params = jax.tree.map(
-                lambda p, s: jax.device_put(
-                    p, NamedSharding(mesh, strip_manual_axes(*s))),
-                params, spec_tree)
-            # allocate the pool DIRECTLY into its sharding — a serving
-            # config sizes the pool near HBM capacity, so transiently
-            # materializing it replicated would OOM at startup
-            ad, cc = self.adapter, self.cache_config
-            self.pool = tracked_jit(
-                lambda: init_kv_pool(ad, cc), "inference_v2/pool_init",
-                tracker=get_compile_tracker(), out_shardings=NamedSharding(
-                    mesh, P(None, None, None, AXIS_TENSOR, None)))()
-        else:
-            self.pool = init_kv_pool(self.adapter, self.cache_config)
+        # the weights are served as they were handed over (no adapter
+        # stacks, casts or re-lays them); over a tensor axis they are put
+        # in their shards
+        with startup_span("startup/place/weights", {"tensor": self._tp}):
+            if self._tp > 1:
+                spec_tree = self.model.param_specs(params)
+                self.params = jax.tree.map(
+                    lambda p, s: jax.device_put(
+                        p, NamedSharding(mesh, strip_manual_axes(*s))),
+                    params, spec_tree)
+        with startup_span("startup/place/pools", {"tensor": self._tp}):
+            if self._tp > 1:
+                # allocate the pool DIRECTLY into its sharding — a serving
+                # config sizes the pool near HBM capacity, so transiently
+                # materializing it replicated would OOM at startup
+                ad, cc = self.adapter, self.cache_config
+                self.pool = tracked_jit(
+                    lambda: init_kv_pool(ad, cc), "inference_v2/pool_init",
+                    tracker=get_compile_tracker(),
+                    out_shardings=NamedSharding(
+                        mesh, P(None, None, None, AXIS_TENSOR, None)))()
+            else:
+                self.pool = init_kv_pool(self.adapter, self.cache_config)
         _mem = get_memory_ledger()
         if _mem.enabled:
             # the paged KV pool is the serving plane's dominant HBM
@@ -272,6 +279,11 @@ class RaggedInferenceEngineV2:
         self.prefill_batch = max(1, prefill_batch)
         self.decode_burst = max(1, decode_burst)
         self._decode_jits: Dict[int, Callable] = {}
+        with startup_span("startup/engine_v2/programs"):
+            # the two programs of a round, as jitted callables: the step
+            # that carries chunks and the burst.  Nothing is compiled here
+            for n_steps in {1, self.decode_burst}:
+                self._decode(n_steps)
         self._reseed(0)
         #: the calls dispatched and not committed yet, oldest first: one
         #: between two rounds, a second behind it inside ``step_ahead``

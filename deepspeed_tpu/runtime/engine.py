@@ -22,6 +22,7 @@ fusion.
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import time
 import weakref
@@ -46,7 +47,7 @@ from .precision import (DynamicLossScaler, LossScaleState, cast_tree,
                         has_overflow)
 from .zero.sharder import ZeroShardingPolicy
 from ..utils.jax_compat import shard_map as _shard_map
-from ..telemetry import numerics
+from ..telemetry import numerics, startup_span
 
 LossFn = Callable[[Any, Any], jnp.ndarray]  # (params, batch) -> scalar loss
 
@@ -290,8 +291,9 @@ class DeepSpeedEngine:
             self._log_qgz_bytes = _qgz_bytes
 
         # --- optimizer ---------------------------------------------------
-        self.optimizer = optimizer if optimizer is not None else build_optimizer(
-            config, lr=self._schedule)
+        with startup_span("startup/engine/optimizer"):
+            self.optimizer = (optimizer if optimizer is not None
+                              else build_optimizer(config, lr=self._schedule))
         clip = config.gradient_clipping
         self.gradient_clipping = 0.0 if isinstance(clip, str) else float(clip)
 
@@ -563,6 +565,60 @@ class DeepSpeedEngine:
         # recovery is how you prove the failure actually breaks a run.
         self.snapshots = None
         self.resilience = None
+        with startup_span("startup/import",
+                          {"module": "deepspeed_tpu.resilience"}):
+            from .. import resilience  # noqa: F401  (orbax.checkpoint)
+
+        with startup_span("startup/engine/resilience"):
+            self._init_resilience(config)
+        self._train_step_fn = None  # compiled lazily (first call)
+        #: forced-partial-boundary programs, keyed by microbatch count
+        self._partial_step_fns: Dict[int, Any] = {}
+        self._warmup_step_fn = None  # 1-bit warmup variant
+        self._eval_loss_fn = None
+
+        # --- random-LTD (data_efficiency.data_routing) --------------------
+        # keep-count changes along a quantized schedule; each bucket gets
+        # its own compiled step (the model reads ltd_keep at trace time)
+        self._ltd_cfg = None
+        self._ltd_sched = None
+        self._ltd_fns: Dict[int, Any] = {}
+        de = config.data_efficiency
+        routing = (de.data_routing.get("random_ltd", {})
+                   if de.enabled else {})
+        if routing.get("enabled"):
+            ids = tuple(routing.get("random_ltd_layer_id", []))
+            if not hasattr(self.module, "ltd_keep"):
+                logger.warning("random_ltd enabled but the model has no "
+                               "ltd_keep support; ignoring")
+            elif not ids:
+                # explicit beats implicit: without layer ids the model
+                # would silently never drop a token while the engine
+                # compiles a redundant program per keep bucket
+                logger.warning("random_ltd enabled but random_ltd_layer_id "
+                               "is empty; ignoring (list the layers to "
+                               "apply token dropping to)")
+            else:
+                self._ltd_cfg = dict(routing)
+                self.module.ltd_layer_ids = ids
+
+        # --- compat-mode bookkeeping -------------------------------------
+        self._pending_batch: Any = None
+        self._microbatch_buffer: List[Any] = []
+        self._accumulation_boundary_forced: Optional[bool] = None
+        self.global_steps = 0
+        self.micro_steps = 0
+        self.last_metrics: Dict[str, Any] = {}
+        self._last_health_events: List[Any] = []
+        self.timers = SynchronizedWallClockTimer()
+        self.tput_timer = ThroughputTimer(
+            batch_size=int(self.train_batch_size or 1))
+        self.steps_per_print = config.steps_per_print
+        self.monitor = None  # attached by monitor subsystem when configured
+
+    def _init_resilience(self, config: DeepSpeedConfig) -> None:
+        """The resilience plane of the constructor: the fault injector,
+        and with ``resilience.enabled`` the snapshots and the policy."""
         from ..resilience.faults import FaultInjector
 
         self.fault_injector = FaultInjector.from_config(
@@ -614,50 +670,6 @@ class DeepSpeedEngine:
                      f"{rcfg.snapshot_dir} (tiers: memory"
                      + (", disk" if rcfg.disk_tier else "")
                      + (", buddy" if rcfg.buddy_tier else "") + ")")
-        self._train_step_fn = None  # compiled lazily (first call)
-        #: forced-partial-boundary programs, keyed by microbatch count
-        self._partial_step_fns: Dict[int, Any] = {}
-        self._warmup_step_fn = None  # 1-bit warmup variant
-        self._eval_loss_fn = None
-
-        # --- random-LTD (data_efficiency.data_routing) --------------------
-        # keep-count changes along a quantized schedule; each bucket gets
-        # its own compiled step (the model reads ltd_keep at trace time)
-        self._ltd_cfg = None
-        self._ltd_sched = None
-        self._ltd_fns: Dict[int, Any] = {}
-        de = config.data_efficiency
-        routing = (de.data_routing.get("random_ltd", {})
-                   if de.enabled else {})
-        if routing.get("enabled"):
-            ids = tuple(routing.get("random_ltd_layer_id", []))
-            if not hasattr(self.module, "ltd_keep"):
-                logger.warning("random_ltd enabled but the model has no "
-                               "ltd_keep support; ignoring")
-            elif not ids:
-                # explicit beats implicit: without layer ids the model
-                # would silently never drop a token while the engine
-                # compiles a redundant program per keep bucket
-                logger.warning("random_ltd enabled but random_ltd_layer_id "
-                               "is empty; ignoring (list the layers to "
-                               "apply token dropping to)")
-            else:
-                self._ltd_cfg = dict(routing)
-                self.module.ltd_layer_ids = ids
-
-        # --- compat-mode bookkeeping -------------------------------------
-        self._pending_batch: Any = None
-        self._microbatch_buffer: List[Any] = []
-        self._accumulation_boundary_forced: Optional[bool] = None
-        self.global_steps = 0
-        self.micro_steps = 0
-        self.last_metrics: Dict[str, Any] = {}
-        self._last_health_events: List[Any] = []
-        self.timers = SynchronizedWallClockTimer()
-        self.tput_timer = ThroughputTimer(
-            batch_size=int(self.train_batch_size or 1))
-        self.steps_per_print = config.steps_per_print
-        self.monitor = None  # attached by monitor subsystem when configured
 
     # ------------------------------------------------------------------
     # state construction
@@ -700,14 +712,17 @@ class DeepSpeedEngine:
             return TrainState(params=self.infinity.resident, opt_state=(),
                               step=jnp.int32(0), loss_scale=scale_state,
                               skipped_steps=jnp.int32(0))
-        params = jax.tree.map(jnp.asarray, params)
+        # placement is fenced only while the hub is on: the spans then
+        # measure the transfer, else the enqueue (device_put is async), and
+        # say which (``fenced``)
+        placed = {"stage": self.policy.stage,
+                  "fenced": self.telemetry.enabled}
+        with startup_span("startup/place/params", dict(placed, of="host")):
+            params = jax.tree.map(jnp.asarray, params)
         param_shardings = self.policy.param_shardings(params, self.base_specs)
-        with self.telemetry.span("zero/param_placement",
-                                 args={"stage": self.policy.stage}):
+        with startup_span("startup/place/params", placed):
             params = jax.device_put(params, param_shardings)
             if self.telemetry.enabled:
-                # block on the placed tree so the span measures the
-                # transfer, not the enqueue (device_put is async)
                 jax.block_until_ready(params)
         if self.memory_ledger is not None:
             # the ZeRO placement site IS the params allocation: register
@@ -792,14 +807,16 @@ class DeepSpeedEngine:
             # Lion/Adagrad offload stays on the fp32 wire
             wire_bf16 = (self.bf16_enabled and opt_name.lower()
                          in ("adam", "adamw", "cpu_adam"))
-            self.offload_opt = CPUOffloadOptimizer(
-                params,
-                optimizer_name=opt_name,
-                optimizer_params=(dict(opt_cfg.params.model_dump())
-                                  if opt_cfg is not None else {}),
-                schedule=self._schedule,
-                policy=self.policy, base_specs=self.base_specs,
-                wire_bf16=wire_bf16)
+            with startup_span("startup/place/opt_state",
+                              dict(placed, of="host")):
+                self.offload_opt = CPUOffloadOptimizer(
+                    params,
+                    optimizer_name=opt_name,
+                    optimizer_params=(dict(opt_cfg.params.model_dump())
+                                      if opt_cfg is not None else {}),
+                    schedule=self._schedule,
+                    policy=self.policy, base_specs=self.base_specs,
+                    wire_bf16=wire_bf16)
             opt_state = ()
             if wire_bf16:
                 # bf16 wire: the device copy lives in bf16 (fp32 masters are
@@ -809,11 +826,16 @@ class DeepSpeedEngine:
                                    "engine/bf16_wire_cast",
                                    out_shardings=param_shardings)(params)
         else:
-            opt_shapes = jax.eval_shape(self.optimizer.init, params)
+            with startup_span("startup/engine/optimizer",
+                              {"of": "state_shapes"}):
+                opt_shapes = jax.eval_shape(self.optimizer.init, params)
             opt_shardings = self.policy.opt_state_shardings(
                 opt_shapes, tx=self.optimizer, base_specs=self.base_specs)
-            opt_state = self._jit(self.optimizer.init, "engine/opt_init",
-                                  out_shardings=opt_shardings)(params)
+            with startup_span("startup/place/opt_state", placed):
+                opt_state = self._jit(self.optimizer.init, "engine/opt_init",
+                                      out_shardings=opt_shardings)(params)
+                if self.telemetry.enabled:
+                    jax.block_until_ready(opt_state)
             if self.memory_ledger is not None:
                 self.memory_ledger.register_tree(
                     "optimizer", "engine/opt_state", opt_state,
@@ -1596,10 +1618,25 @@ class DeepSpeedEngine:
                             "wire_bf16": wire_bf16},
             in_shardings=(state_shardings, batch_sharding))
 
+    def _first_call(self, site: str):
+        """``startup/first_call`` around a step program's build and first
+        call, where the compile tracker is off (a tracked jit records its
+        own): the compile account then says in it what was traced, lowered
+        and compiled or loaded."""
+        if self.compile_tracker is not None:
+            return contextlib.nullcontext()
+        return startup_span("startup/first_call",
+                            {"site": site, "program": 0})
+
     def _offload_train_step(self, batch) -> Dict[str, Any]:
         if self._train_step_fn is None:
-            self._train_step_fn = self._build_grad_step()
-        grads, metrics, new_scale = self._train_step_fn(self.state, batch)
+            with self._first_call("engine/grad_step"):
+                self._train_step_fn = self._build_grad_step()
+                grads, metrics, new_scale = self._train_step_fn(self.state,
+                                                                batch)
+        else:
+            grads, metrics, new_scale = self._train_step_fn(self.state,
+                                                            batch)
         overflow = bool(metrics["overflow"]) if self.fp16_enabled else False
         st = self.state
         if overflow:
@@ -1675,9 +1712,15 @@ class DeepSpeedEngine:
                 # every cached call after it)
                 with numerics.collecting(coll):
                     self.state, metrics = fn(self.state, batch)
-            else:
-                if self._train_step_fn is None:
+            elif self._train_step_fn is None:
+                # the step program's first call: built, traced, lowered,
+                # compiled or loaded, and run (a tracked jit has that span
+                # of its own)
+                with self._first_call("engine/train_step"):
                     self._train_step_fn = self._build_train_step()
+                    self.state, metrics = self._train_step_fn(self.state,
+                                                              batch)
+            else:
                 self.state, metrics = self._train_step_fn(self.state, batch)
         return metrics
 

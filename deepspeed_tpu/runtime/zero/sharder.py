@@ -215,8 +215,9 @@ class ZeroShardingPolicy:
     def param_shardings(self, params: Any, base_specs: Any = None) -> Any:
         from ...telemetry import get_telemetry
 
-        with get_telemetry().span("zero/param_shardings",
-                                  args={"stage": self.stage}):
+        with get_telemetry().startup_span(
+                "startup/place/shardings",
+                {"stage": self.stage, "of": "params"}):
             return self._map_with_base(
                 lambda p, b: NamedSharding(self.mesh, self.param_spec(p, b)),
                 params, base_specs)
@@ -238,8 +239,9 @@ class ZeroShardingPolicy:
         into the mirrored moments."""
         from ...telemetry import get_telemetry
 
-        with get_telemetry().span("zero/opt_state_shardings",
-                                  args={"stage": self.stage}):
+        with get_telemetry().startup_span(
+                "startup/place/shardings",
+                {"stage": self.stage, "of": "opt_state"}):
             return self._opt_state_shardings(opt_state, tx, base_specs)
 
     def _opt_state_shardings(self, opt_state: Any, tx: Any = None,

@@ -118,27 +118,40 @@ def build_serving_frontend(model: Any, params: Any = None,
                            mesh: Any = None) -> ServingFrontend:
     """N real v2 engine replicas behind one front-end.  Each replica
     owns a full KV pool (HBM cost scales with ``replicas``) and is
-    registered in the memory ledger under ``serving/replica<i>/*``."""
+    registered in the memory ledger under ``serving/replica<i>/*``.  The
+    whole of it is the start-up record's root
+    ``startup/serving_frontend``."""
     import jax
 
-    from ..inference.v2 import build_engine_v2
+    from ..telemetry import startup_span
 
-    if params is None:
-        params = model.init_params(jax.random.PRNGKey(0))
+    with startup_span("startup/serving_frontend",
+                      {"replicas": int(replicas)}):
+        with startup_span("startup/import",
+                          {"module": "deepspeed_tpu.inference.v2"}):
+            from ..inference.v2 import build_engine_v2
 
-    def factory(cc, slots, chunk, pbatch):
-        return ServingScheduler(cc, max_batch_slots=slots,
-                                prefill_chunk=chunk, prefill_batch=pbatch,
-                                prefix_sharing=prefix_sharing,
-                                max_cached_blocks=max_cached_blocks)
+        if params is None:
+            with startup_span("startup/place/weights", {"of": "init"}):
+                params = model.init_params(jax.random.PRNGKey(0))
 
-    reps: List[Replica] = []
-    for i in range(int(replicas)):
-        eng = build_engine_v2(
-            model, params, cache_config=cache_config,
-            max_batch_slots=max_batch_slots, prefill_chunk=prefill_chunk,
-            prefill_batch=prefill_batch, decode_burst=decode_burst,
-            mesh=mesh, scheduler_factory=factory,
-            ledger_key=f"serving/replica{i}/kv_pool")
-        reps.append(Replica(eng, i))
-    return ServingFrontend(reps, params=serving_params)
+        def factory(cc, slots, chunk, pbatch):
+            return ServingScheduler(cc, max_batch_slots=slots,
+                                    prefill_chunk=chunk,
+                                    prefill_batch=pbatch,
+                                    prefix_sharing=prefix_sharing,
+                                    max_cached_blocks=max_cached_blocks)
+
+        reps: List[Replica] = []
+        for i in range(int(replicas)):
+            with startup_span("startup/engine_v2", {"replica": i}):
+                eng = build_engine_v2(
+                    model, params, cache_config=cache_config,
+                    max_batch_slots=max_batch_slots,
+                    prefill_chunk=prefill_chunk,
+                    prefill_batch=prefill_batch, decode_burst=decode_burst,
+                    mesh=mesh, scheduler_factory=factory,
+                    ledger_key=f"serving/replica{i}/kv_pool")
+            reps.append(Replica(eng, i))
+        with startup_span("startup/frontend"):
+            return ServingFrontend(reps, params=serving_params)

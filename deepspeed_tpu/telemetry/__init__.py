@@ -10,6 +10,11 @@ ONE pipeline correlating what used to be fragments (ISSUE 1):
 * :mod:`.step_record` — the per-optimizer-step record the engine emits
   (device-fenced step time, throughput, loss, comm bytes, memory), the
   single source every consumer (bench, autotuner, monitors) reads.
+* the start-up record (``Telemetry.startup``, ``startup_span()``): the
+  same span primitive on a second, small ring that is written whether the
+  hub is on or off, so that every start can say where it went
+  (``startup_report()``: one log line a start, the ``startup`` context of
+  a debug bundle, the gauges ``startup/<phase>_s``).
 
 The module-level hub is a process-global singleton, DISABLED by default:
 ``span()`` returns one shared no-op object and the counter/gauge
@@ -24,6 +29,7 @@ from __future__ import annotations
 
 import inspect
 import os
+import sys
 import threading
 import weakref
 from typing import Any, Callable, Dict, List, Optional
@@ -54,7 +60,8 @@ from .rollup import (MetricsRollup, StepStream, collect_rollup,
                      push_node_telemetry, render_top, rollup_tick)
 from .step_record import (StepRecord, collect_memory_stats,
                           publish_step_record)
-from .tracer import NOOP_SPAN, SpanTracer
+from .tracer import (NOOP_SPAN, STARTUP_ROOTS, SpanTracer, StartupRecord,
+                     startup_phases)
 from .watchdog import (HEARTBEAT_SCHEMA_V, HangWatchdog, WatchdogTimeout,
                        cap_heartbeat_payload, get_watchdog, set_watchdog)
 
@@ -62,6 +69,7 @@ __all__ = [
     "Telemetry", "StepRecord", "MetricsRegistry", "SpanTracer",
     "Counter", "Gauge", "Histogram", "JSONLExporter",
     "configure", "configure_from_config", "get_telemetry", "span",
+    "startup_span",
     "publish_step_record", "collect_memory_stats", "parse_prometheus_text",
     "prom_name", "DEFAULT_BUCKETS",
     "FlightRecorder", "configure_flight_recorder", "get_flight_recorder",
@@ -92,9 +100,12 @@ class Telemetry:
     def __init__(self) -> None:
         self.enabled = False
         self.tracer = SpanTracer()
+        #: the start-up record: written whether the hub is on or off
+        self.startup = StartupRecord(self._startup_closed)
         #: run before the registry is read as a whole; kept here and not
         #: on the registry so that ``reset()`` carries them over
-        self._collect_hooks: List[Callable[[], Any]] = []
+        self._collect_hooks: List[Callable[[], Any]] = [
+            weakref.WeakMethod(self._startup_gauges)]
         self.registry = MetricsRegistry(before_read=self.collect)
         self.output_path: Optional[str] = None
         self.chrome_trace = False
@@ -136,6 +147,7 @@ class Telemetry:
             self.enabled = False
             self.output_path = None
             self.tracer = SpanTracer(self.tracer.max_events)
+            self.startup = StartupRecord(self._startup_closed)
             self.registry = MetricsRegistry(before_read=self.collect)
 
     # -- collect hooks -----------------------------------------------------
@@ -183,6 +195,75 @@ class Telemetry:
         if not self.enabled:
             return NOOP_SPAN
         return self.tracer.span(name, args)
+
+    def startup_span(self, name: str,
+                     args: Optional[Dict[str, Any]] = None):
+        """A span of a START (``startup/...``): the tracer's own span on
+        the start-up record, hub on or off; while the hub is on it lands
+        in the hub's ring too.  For the few dozen phases of a start and a
+        program's first call: a step has none."""
+        return self.startup.span(name, args)
+
+    def _startup_closed(self, name: str, start: float, end: float,
+                        args: Dict[str, Any]) -> None:
+        if self.enabled:
+            self.tracer._record(name, start, end, args)
+        if name in STARTUP_ROOTS and not args.get("depth"):
+            from ..utils.logging import log_dist
+
+            log_dist(self.startup_line())
+
+    def startup_report(self) -> Dict[str, Any]:
+        """The start-up record as a document: its spans, each root by
+        phase (``tracer.startup_phases``) with the programs compiled and
+        loaded under it, and the compile account's totals.  The
+        ``startup`` context of a flight-recorder bundle."""
+        account = get_compile_tracker().account
+        events = self.startup.events()
+        roots = startup_phases(events)
+        for root in roots:
+            made = account.sums(root["start"], root["end"])
+            root["programs_loaded"] = int(made["cache_hits"])
+            root["programs_compiled"] = int(made["programs"]
+                                            - made["cache_hits"])
+        return {"spans": events, "roots": roots,
+                "compile_account": dict(account.totals)}
+
+    def startup_line(self) -> str:
+        """The newest start in one line: ``start-up 31.4 s: import 13.9
+        (<the largest module> 13.7), config 0.1, ... | programs: 0
+        compiled, 1 loaded``."""
+        roots = self.startup_report()["roots"]
+        if not roots:
+            return "start-up: nothing recorded"
+        root = roots[-1]
+        parts = []
+        for phase, seconds in root["phases"].items():
+            part = f"{phase} {seconds:.1f}"
+            if phase == "import" and root["largest_import"]:
+                module, s = root["largest_import"]
+                part += f" ({module} {s:.1f})"
+            parts.append(part)
+        return (f"start-up {root['total_s']:.1f} s ({root['root']}): "
+                + ", ".join(parts)
+                + f" | programs: {root['programs_compiled']} compiled, "
+                  f"{root['programs_loaded']} loaded")
+
+    def _startup_gauges(self) -> None:
+        """Collect hook: ``startup/<phase>_s`` over the record's roots,
+        worked out when the registry is read and never at a start."""
+        if not self.enabled:
+            return
+        total: Dict[str, float] = {}
+        for root in startup_phases(self.startup.events()):
+            for phase, seconds in dict(root["phases"],
+                                       total=root["total_s"]).items():
+                total[phase] = total.get(phase, 0.0) + seconds
+        for phase, seconds in total.items():
+            self.set_gauge(f"startup/{phase}_s", seconds,
+                           help="seconds of the process's starts "
+                                "(startup/initialize, "
+                                "startup/serving_frontend) by phase")
 
     def inc_counter(self, name: str, v: float = 1.0, help: str = "") -> None:
         if not self.enabled:
@@ -237,6 +318,16 @@ class Telemetry:
 
 
 _default = Telemetry()
+# a compile's seconds go to the start-up span it ran under
+get_compile_tracker().account.open_span = \
+    lambda: _default.startup.innermost()
+# the package's own import, stamped by its first and last line (this
+# package is first imported after both, unless the package's import
+# itself reaches here)
+_stamps = getattr(sys.modules.get(__name__.rpartition(".")[0]),
+                  "_IMPORT_STAMPS", None)
+if _stamps and None not in _stamps:
+    _default.startup.add("startup/package_import", *_stamps)
 
 
 def get_telemetry() -> Telemetry:
@@ -263,3 +354,9 @@ def configure_from_config(tcfg: Any) -> Telemetry:
 def span(name: str, args: Optional[Dict[str, Any]] = None):
     """Module-level convenience: ``with telemetry.span("zero/gather"): ...``"""
     return _default.span(name, args)
+
+
+def startup_span(name: str, args: Optional[Dict[str, Any]] = None):
+    """``with telemetry.startup_span("startup/import", {"module": ...}):``
+    (:meth:`Telemetry.startup_span`)."""
+    return _default.startup_span(name, args)

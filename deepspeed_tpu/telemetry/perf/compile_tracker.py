@@ -28,10 +28,16 @@ Anything the AOT path cannot handle (exotic arg types, backend quirks)
 falls back to calling the plain jitted function — the event is still
 recorded (with ``fallback: true`` and combined timing), the program
 just isn't separately lower/compile-split.
+
+:class:`CompileAccount` is the account of the WHOLE process, whatever the
+tracker is set to: what JAX itself reports of every trace, lowering,
+compile and read of the persistent cache (``jax.monitoring``), from the
+moment this module is imported.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import re
@@ -157,6 +163,136 @@ class CompileEvent:
         return dataclasses.asdict(self)
 
 
+
+class CompileAccount:
+    """What JAX reports of making programs ready, for the whole process.
+
+    ONE ``jax.monitoring`` duration listener and one event listener
+    (:meth:`register`), called by JAX on the compiling thread once a
+    trace, a lowering, a compile: a Python call a COMPILE, none a
+    dispatch.  It keeps totals by kind, a bounded list of ``(stamp, kind,
+    value)`` with ``stamp`` the event's END in ``time.perf_counter()``
+    seconds, and adds each value to the arguments of the innermost
+    start-up span open on that thread (``open_span``, set by the hub), so
+    that a ``startup/first_call`` says how long its program was traced,
+    lowered and compiled or loaded, and a phase of a start says what it
+    compiled.  What falls under no span is the caller's.
+
+    Kinds: ``trace_s`` (JAX reports every jitted function traced, the
+    inner ones of a program too: an outer trace is counted less the inner
+    ones that ended inside it, so the seconds add up), ``lower_s`` (jaxpr
+    to MLIR), ``compile_s`` (the backend's compile, which INCLUDES a read
+    of the persistent cache), ``cache_read_s`` and ``cache_saved_s`` (the
+    cache's own: the read alone, and what the compile took when it was
+    stored less the read), and the counts ``cache_hits`` /
+    ``cache_misses`` (a miss is counted when the compiled program is
+    written to the cache)."""
+
+    DURATIONS = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+        "/jax/core/compile/backend_compile_duration": "compile_s",
+        "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+        "/jax/compilation_cache/compile_time_saved_sec": "cache_saved_s",
+    }
+    COUNTS = {
+        "/jax/compilation_cache/cache_hits": "cache_hits",
+        "/jax/compilation_cache/cache_misses": "cache_misses",
+    }
+    #: the kinds a start-up span is told of
+    SPAN_KINDS = frozenset(("trace_s", "lower_s", "compile_s",
+                            "cache_hits", "cache_misses"))
+
+    def __init__(self, max_events: int = 8192):
+        self._lock = threading.Lock()
+        self._tls = threading.local()
+        self.totals: Dict[str, float] = dict.fromkeys(
+            list(self.DURATIONS.values()) + list(self.COUNTS.values()), 0.0)
+        #: ``[stamp, kind, value, thread]``, oldest first; a thread's run of
+        #: traces (one program's) is one entry
+        self._events: "collections.deque[List[Any]]" = collections.deque(
+            maxlen=int(max_events))
+        #: ``() -> the innermost start-up span open on this thread``
+        self.open_span: Optional[Callable[[], Any]] = None
+        self._registered = False
+
+    def register(self) -> "CompileAccount":
+        """Listen to ``jax.monitoring`` (once: JAX has no way to take a
+        listener back)."""
+        import jax
+
+        if not self._registered:
+            self._registered = True
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_duration)
+            jax.monitoring.register_event_listener(self._on_event)
+        return self
+
+    def _on_duration(self, event: str, seconds: float, **_: Any) -> None:
+        kind = self.DURATIONS.get(event)
+        if kind is None:
+            return
+        now = time.perf_counter()
+        if kind == "trace_s":
+            # traces nest: the ones that ended inside this one are in it
+            started = now - seconds
+            traces = getattr(self._tls, "traces", None)
+            if traces is None:
+                traces = self._tls.traces = []
+            while traces and traces[-1][0] >= started:
+                seconds -= traces.pop()[1]
+            traces.append((started, now - started))
+            del traces[:-4096]
+            seconds = max(seconds, 0.0)
+        self._add(now, kind, seconds)
+
+    def _on_event(self, event: str, **_: Any) -> None:
+        kind = self.COUNTS.get(event)
+        if kind is not None:
+            self._add(time.perf_counter(), kind, 1.0)
+
+    def _add(self, stamp: float, kind: str, value: float) -> None:
+        thread = threading.get_ident()
+        with self._lock:
+            self.totals[kind] += value
+            last = self._events[-1] if self._events else None
+            if (kind == "trace_s" and last is not None
+                    and last[1] == kind and last[3] == thread):
+                last[0], last[2] = stamp, last[2] + value
+            else:
+                self._events.append([stamp, kind, value, thread])
+        if kind in self.SPAN_KINDS and self.open_span is not None:
+            span = self.open_span()
+            if span is not None:
+                span._args[kind] = span._args.get(kind, 0.0) + value
+
+    def events(self, since: float = float("-inf"),
+               until: float = float("inf")) -> List[Tuple[float, str, float]]:
+        """``(stamp, kind, value)`` of the events kept that ended in
+        ``(since, until]``."""
+        with self._lock:
+            return [(e[0], e[1], e[2]) for e in self._events
+                    if since < e[0] <= until]
+
+    def sums(self, since: float = float("-inf"),
+             until: float = float("inf")) -> Dict[str, float]:
+        """The kept events of ``(since, until]`` added up by kind, with
+        ``programs``: how many compiles (or reads of the cache) ended
+        there."""
+        out = dict.fromkeys(self.totals, 0.0)
+        out["programs"] = 0.0
+        for _, kind, value in self.events(since, until):
+            out[kind] += value
+            out["programs"] += kind == "compile_s"
+        return out
+
+    def reset(self) -> None:
+        """Test isolation: forget what was counted (the listeners stay)."""
+        with self._lock:
+            self.totals = dict.fromkeys(self.totals, 0.0)
+            self._events.clear()
+
+
 class CompileTracker:
     """Per-site program table + compile-event stream.
 
@@ -182,6 +318,12 @@ class CompileTracker:
         #: ledger harvests ``compiled.cost_analysis()`` here, at compile
         #: time, so the steady state pays nothing
         self._cost_harvesters: List[Callable[[str, int, Any], Any]] = []
+
+    @property
+    def account(self) -> CompileAccount:
+        """The process-wide :class:`CompileAccount`: one, whichever
+        tracker is asked and whether it is enabled or not."""
+        return _account
 
     def configure(self, enabled: Optional[bool] = None,
                   max_events: Optional[int] = None) -> "CompileTracker":
@@ -418,7 +560,18 @@ class TrackedJit:
             if compiled is None:  # this signature runs on the fallback path
                 return self._jitted(*args, **kwargs)
             return compiled(*args, **dynamic)
-        # cache miss: the AOT path, so lower and compile are timed apart
+        # cache miss: this program's first call, a span of the start-up
+        # record from here to the call's return
+        from .. import get_telemetry
+
+        with get_telemetry().startup_span(
+                "startup/first_call",
+                {"site": self.site, "static": self.static_context}) as first:
+            return self._first_call(first, args, kwargs, dynamic, avals,
+                                    sig, key)
+
+    def _first_call(self, first, args, kwargs, dynamic, avals, sig, key):
+        """The AOT path, so lower and compile are timed apart."""
         compiled = None
         try:
             t0 = time.perf_counter()
@@ -441,6 +594,7 @@ class TrackedJit:
             fallback = True
         ev = self.tracker.record(self.site, sig, lower_ms, compile_ms,
                                  fallback=fallback)
+        first.set(program=ev.program)
         if compiled is not None:
             # compile-time cost harvest (anatomy plane): the AOT handle
             # is in hand exactly once, here — cost_analysis() now costs
@@ -488,6 +642,10 @@ def tracked_jit(fn: Callable, site: str,
 
 
 _default = CompileTracker()
+#: registered when this module is first imported, which every engine,
+#: model and serving module of the package does: the account counts from
+#: there
+_account = CompileAccount().register()
 
 
 def get_compile_tracker() -> CompileTracker:

@@ -16,6 +16,11 @@ result is fetched (or blocked on) inside it.  ``add()`` records a span
 whose two ends were stamped elsewhere (a request's phases).  The ring
 can still be exported as Chrome-trace JSON for the cluster timeline
 (``telemetry collect``), shifted by the clock-sync offset.
+
+:class:`StartupRecord` is the same tracer as a second, small ring that is
+written whether the hub is on or off: the few dozen spans of a start
+(``startup/initialize``, ``startup/serving_frontend`` and what lies under
+them, each program's ``startup/first_call``).  A step has none of them.
 """
 
 from __future__ import annotations
@@ -25,7 +30,7 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 _TraceAnnotation = None
 
@@ -171,12 +176,21 @@ class SpanTracer:
         return _Span(self, name, args)
 
     def add(self, name: str, start: float, end: float,
-            args: Optional[Dict[str, Any]] = None) -> None:
+            args: Optional[Dict[str, Any]] = None,
+            parent: Optional[str] = None, depth: Optional[int] = None
+            ) -> None:
         """A span whose ends were stamped elsewhere, in
         ``time.perf_counter()`` seconds (a request's queue wait, from its
         record's own stamps).  It belongs to no thread's stack, so it
-        carries neither depth nor parent, and it goes to the ring only."""
-        self._record(name, start, end, dict(args) if args else {})
+        carries neither depth nor parent unless the caller knows them
+        (an import that ran before the tracer's own module was loaded),
+        and it goes to the ring only."""
+        args = dict(args) if args else {}
+        if depth is not None:
+            args["depth"] = depth
+        if parent is not None:
+            args["parent"] = parent
+        self._record(name, start, end, args)
 
     # ------------------------------------------------------------------
 
@@ -240,3 +254,111 @@ class SpanTracer:
         os.replace(tmp, path)  # atomic: a crashed flush never tears the file
         return path
 
+
+
+#: the spans that are a start of their own: when one closes at depth 0 the
+#: hub says in one line where the start went
+STARTUP_ROOTS = ("startup/initialize", "startup/serving_frontend")
+
+
+class StartupRecord(SpanTracer):
+    """The start-up record: a :class:`SpanTracer` of a few hundred events
+    that is written whether the hub is on or off (``Telemetry.startup``).
+
+    Its spans are the tracer's own ``_Span`` (ring and profiler's trace as
+    any span); each event also carries its two stamps unrounded, ``start``
+    and ``end`` in ``time.perf_counter()`` seconds, so that a reader can
+    hold them against stamps of its own.  ``on_close(name, start, end,
+    args)`` is told of every event: the hub copies it into its own ring
+    while it is on.  The spans open on a thread are kept (innermost last),
+    so that the compile account can add a compile's seconds to the span it
+    ran under (``innermost()``)."""
+
+    def __init__(self, on_close: Callable[[str, float, float, Dict], Any],
+                 max_events: int = 512):
+        super().__init__(max_events)
+        self._on_close = on_close
+
+    def _open(self) -> List[_Span]:
+        spans = getattr(self._tls, "open", None)
+        if spans is None:
+            spans = self._tls.open = []
+        return spans
+
+    def span(self, name: str, args: Optional[Dict[str, Any]] = None
+             ) -> _Span:
+        """A start-up span, to be entered at once (``with``): it counts as
+        open on this thread until it is recorded."""
+        span = _Span(self, name, args)
+        spans = self._open()
+        # one that was made and never entered is dropped here
+        spans[:] = [s for s in spans if s.start is not None] + [span]
+        return span
+
+    def innermost(self) -> Optional[_Span]:
+        """The innermost start-up span open on the calling thread."""
+        for span in reversed(self._open()):
+            if span.start is not None and span.end is None:
+                return span
+        return None
+
+    def _record(self, name: str, start: float, end: float,
+                args: Dict[str, Any]) -> None:
+        spans = self._open()
+        for i in range(len(spans) - 1, -1, -1):
+            if spans[i]._args is args:     # the span that closes, and
+                del spans[i:]              # any inside it that never did
+                break
+        if name == "startup/first_call":
+            args["cache_hit"] = bool(args.get("cache_hits")
+                                     and not args.get("cache_misses"))
+        self._append({
+            "ph": "X", "cat": "startup", "name": name,
+            "pid": os.getpid(), "tid": threading.get_ident(),
+            "ts": round((start - self._t0) * 1e6, 1),
+            "dur": round((end - start) * 1e6, 1),
+            "start": start, "end": end, "args": args})
+        self._on_close(name, start, end, args)
+
+
+def startup_phases(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """Each root of a start-up record by phase: ``{"root", "start",
+    "end", "total_s", "phases": {phase: seconds}, "largest_import":
+    (module, seconds)}``.  A span's SELF time (its own less its
+    children's) goes to the phase its name gives it (``startup/place/*``
+    is ``place``, ``startup/engine/optimizer`` is ``engine``, the root's
+    own is ``other``), so the phases add up to the root.  A span is a
+    child of the innermost span of the same thread that contains it."""
+    out = []
+    nested = [e for e in events if "depth" in e["args"]]
+    for root in nested:
+        if root["name"] not in STARTUP_ROOTS or root["args"]["depth"]:
+            continue
+        inside = sorted(
+            (e for e in nested if e["tid"] == root["tid"]
+             and root["start"] <= e["start"] and e["end"] <= root["end"]),
+            key=lambda e: (e["start"], -e["end"]))
+        self_s = {id(e): e["end"] - e["start"] for e in inside}
+        stack: List[Dict[str, Any]] = []
+        for e in inside:
+            while stack and stack[-1]["end"] < e["end"]:
+                stack.pop()
+            if stack:
+                self_s[id(stack[-1])] -= e["end"] - e["start"]
+            stack.append(e)
+        phases: Dict[str, float] = {}
+        imports: Dict[str, float] = {}
+        for e in inside:
+            phase = "other" if e is root else e["name"].split("/")[1]
+            phases[phase] = phases.get(phase, 0.0) + self_s[id(e)]
+            if e["name"] == "startup/import":
+                module = str(e["args"].get("module"))
+                imports[module] = imports.get(module, 0.0) + self_s[id(e)]
+        out.append({"root": root["name"], "start": root["start"],
+                    "end": root["end"],
+                    "total_s": root["end"] - root["start"],
+                    "phases": phases,
+                    "largest_import": max(imports.items(),
+                                          key=lambda kv: kv[1],
+                                          default=None)})
+    return out
