@@ -113,9 +113,10 @@ class DSElasticAgent:
         self._sleep: Callable[[float], None] = time.sleep
         # prefetch the resilience fault vocabulary OFF the supervision
         # path: the failure branches import it to map NODE_LEAVE_EXIT_
-        # CODE, and a cold import there (orbax + friends, ~2.5s) would
-        # gate the crash->round-bump latency every peer's teardown
-        # clock depends on
+        # CODE, and a cold import there (the resilience package's tree,
+        # tenths of a second; it holds no orbax.checkpoint, which only
+        # what saves loads) would gate the crash->round-bump latency
+        # every peer's teardown clock depends on
         import threading
 
         threading.Thread(

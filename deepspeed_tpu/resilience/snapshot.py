@@ -50,6 +50,7 @@ import numpy as np
 from ..runtime.checkpoint_engine import (CheckpointCorruptionError,
                                          TorchCheckpointEngine,
                                          join_inflight_save,
+                                         orbax_checkpoint,
                                          register_inflight_save,
                                          release_inflight_save,
                                          verify_sidecar_manifest)
@@ -224,6 +225,11 @@ class SnapshotManager:
         self.cfg = cfg
         self.recorder = recorder
         self._clock = clock
+        # a run with snapshots on saves on deadlines (the emergency flush
+        # on the watchdog's thread before its exit action, a SIGTERM's
+        # grace period): the checkpoint library is loaded here, at the
+        # start, and never by the first of those
+        orbax_checkpoint()
         self.snapshot_interval = max(1, int(cfg.snapshot_interval))
         self.snapshot_dir = cfg.snapshot_dir
         self.keep = max(1, int(cfg.keep_snapshots))

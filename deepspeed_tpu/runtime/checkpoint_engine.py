@@ -11,6 +11,19 @@ background thread, which is donation-safe (the next ``train_step`` can
 invalidate the device buffers; the host copy is already taken).  The
 engine classes here supply the reference's lifecycle surface
 (create/save/load/commit/wait) around the two orbax modes.
+
+Where the library is loaded: ``orbax.checkpoint`` costs seconds to import
+(13 s on the chip's host, PERF.md PR 55: its logging pulls
+``google.cloud`` and a walk over the installed distributions), and this
+module is imported by every training start for the names of its
+exceptions.  So nothing here imports it at module level:
+:func:`orbax_checkpoint` imports it where it is first used.  That is the
+first save or load of a run that configured nothing that saves, and the
+START of a run that will save on a deadline: ``SnapshotManager.__init__``
+(``resilience.enabled``) and :class:`DecoupledCheckpointEngine`'s
+constructor (the engine makes its checkpoint engine as it is built) call
+it, so neither an emergency flush on the watchdog's thread nor an async
+save that exists to return at once meets a cold import.
 """
 
 from __future__ import annotations
@@ -19,6 +32,7 @@ import contextlib
 import hashlib
 import json
 import os
+import sys
 import threading
 import time
 import weakref
@@ -26,9 +40,30 @@ from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
 
 from ..utils.logging import log_dist, logger
+
+
+def orbax_checkpoint():
+    """``orbax.checkpoint``, imported by whoever asks first (the module
+    docstring says who that is).  The import that loads it is a span
+    ``startup/import`` with ``module == "orbax.checkpoint"``: on the
+    start-up record where a start is open on this thread (a leaf of its
+    own in ``import_s`` and the start's line), else a span of the hub, so
+    that a first save seconds longer than the second says why."""
+    span = contextlib.nullcontext()
+    if "orbax.checkpoint" not in sys.modules:
+        from ..telemetry import get_telemetry
+
+        tel = get_telemetry()
+        under_start = tel.startup.innermost() is not None
+        span = (tel.startup_span if under_start else tel.span)(
+            "startup/import", {"module": "orbax.checkpoint"})
+    with span:  # a second thread waits here for the first one's import
+        import orbax.checkpoint as ocp
+
+    return ocp
+
 
 #: sidecar integrity manifest written next to every saved checkpoint tree
 SIDECAR_MANIFEST = "ds_manifest.json"
@@ -240,7 +275,7 @@ class TorchCheckpointEngine(CheckpointEngine):
              commit_fn: Optional[Any] = None,
              entry_timeout_s: Optional[float] = None) -> None:
         t0 = time.perf_counter()
-        with ocp.StandardCheckpointer() as saver:
+        with orbax_checkpoint().StandardCheckpointer() as saver:
             with save_entry(path, entry_timeout_s):
                 saver.save(path, state_tree, force=True)
         # integrity sidecar BEFORE the durability marker: a manifest's
@@ -261,7 +296,7 @@ class TorchCheckpointEngine(CheckpointEngine):
         # (verify strict=True); ordinary checkpoint loads must not pay
         # a full re-read of a multi-GB tree
         verify_sidecar_manifest(path)
-        with ocp.StandardCheckpointer() as loader:
+        with orbax_checkpoint().StandardCheckpointer() as loader:
             if target is None:
                 meta = loader.metadata(path).item_metadata.tree
                 target = jax.tree.map(
@@ -344,7 +379,9 @@ class DecoupledCheckpointEngine(CheckpointEngine):
 
     def __init__(self, config_params: Any = None):
         super().__init__(config_params)
+        ocp = orbax_checkpoint()
         self._ckptr = ocp.AsyncCheckpointer(ocp.StandardCheckpointHandler())
+        self._save_args = ocp.args.StandardSave
         self._pending: Optional[str] = None
         self._pending_commit: Optional[Any] = None
 
@@ -353,7 +390,7 @@ class DecoupledCheckpointEngine(CheckpointEngine):
         t0 = time.perf_counter()
         self.wait()
         with save_entry(path):
-            self._ckptr.save(path, args=ocp.args.StandardSave(state_tree),
+            self._ckptr.save(path, args=self._save_args(state_tree),
                              force=True)
         # only the BLOCKING part (join previous + device→host snapshot)
         # counts as checkpoint time; the storage write overlaps training

@@ -12,6 +12,11 @@ SURVEY §5.4's TPU mapping).
 Layout per tag directory:
     state/            orbax sharded pytree (params, opt_state, step, scaler)
     client_state.json user + engine bookkeeping (global_steps, skipped, …)
+
+``orbax.checkpoint`` is not imported with this module: a save or a load
+gets it from ``checkpoint_engine.orbax_checkpoint()``, whose docstring
+says who pays the seconds of its import and when (the first save or load
+of a run that configured nothing that saves; the start of one that did).
 """
 
 from __future__ import annotations
@@ -23,10 +28,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-import orbax.checkpoint as ocp
 
 from ..utils.logging import log_dist, logger
-from .checkpoint_engine import save_entry
+from .checkpoint_engine import (join_inflight_save, orbax_checkpoint,
+                                save_entry)
 
 LATEST_FILE = "latest"
 
@@ -41,16 +46,6 @@ def _side_save(saver, path: str, tree: Any) -> None:
     checkpoint engines' own saves."""
     with save_entry(path):
         saver.save(path, tree, force=True)
-
-
-def _ckpt_engine_for(engine):
-    ceng = getattr(engine, "_ckpt_engine", None)
-    if ceng is None:
-        from .checkpoint_engine import make_checkpoint_engine
-
-        ceng = make_checkpoint_engine(engine.config)
-        engine._ckpt_engine = ceng
-    return ceng
 
 
 def _globalize_tree(tree, mesh):
@@ -97,6 +92,7 @@ def save_checkpoint(engine, save_dir: str, tag: Optional[str] = None,
 
 def _save_checkpoint_impl(engine, save_dir: str, tag: Optional[str],
                           client_state: Optional[Dict[str, Any]]) -> str:
+    ocp = orbax_checkpoint()
     _globalize_state(engine)
     tag = _tag_for(engine, tag)
     ckpt_dir = os.path.abspath(os.path.join(save_dir, tag))
@@ -107,7 +103,7 @@ def _save_checkpoint_impl(engine, save_dir: str, tag: Optional[str],
     # writes behind training; the reference's decoupled engine role).
     # The `latest` durability marker is a commit callback so an async save
     # that dies mid-write never leaves `latest` naming a torn checkpoint.
-    ceng = _ckpt_engine_for(engine)
+    ceng = engine._ckpt_engine
 
     def _write_latest():
         with open(os.path.join(save_dir, LATEST_FILE), "w") as fh:
@@ -219,14 +215,13 @@ def _load_checkpoint_impl(engine, load_dir: str, tag: Optional[str],
     if tag is None:
         logger.warning(f"no checkpoint found under {load_dir}")
         return None, None
+    ocp = orbax_checkpoint()
     ckpt_dir = os.path.abspath(os.path.join(load_dir, tag))
     # join any in-flight async save before reading (it may be this tag)
     # — including one dispatched by a DIFFERENT engine instance (a fresh
     # engine resuming a tag its predecessor is still flushing; waiting
     # only on our own engine leaves that torn-read race to GC timing)
-    _ckpt_engine_for(engine).wait()
-    from .checkpoint_engine import join_inflight_save
-
+    engine._ckpt_engine.wait()
     join_inflight_save(ckpt_dir)
     _globalize_state(engine)  # restore targets must be globally shardable
 

@@ -39,6 +39,7 @@ from ..parallel.mesh import DP_AXES, MeshLayout
 from ..utils import groups as groups_mod
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
+from .checkpoint_engine import make_checkpoint_engine
 from .config import DeepSpeedConfig
 from .lr_schedules import LRScheduler, Schedule, get_lr_schedule
 from .optimizers import build_optimizer
@@ -567,10 +568,18 @@ class DeepSpeedEngine:
         self.resilience = None
         with startup_span("startup/import",
                           {"module": "deepspeed_tpu.resilience"}):
-            from .. import resilience  # noqa: F401  (orbax.checkpoint)
+            # this tree holds no checkpoint library: orbax.checkpoint is
+            # an import leaf of its own, opened only by what will save
+            # (checkpoint_engine.orbax_checkpoint)
+            from .. import resilience  # noqa: F401
 
         with startup_span("startup/engine/resilience"):
             self._init_resilience(config)
+        # the checkpoint engine is made with the engine, not by the first
+        # save: an async one loads orbax.checkpoint in its constructor
+        # (seconds, cold), which a save that exists to return at once
+        # must not meet; a sync one loads nothing until it saves or loads
+        self._ckpt_engine = make_checkpoint_engine(config)
         self._train_step_fn = None  # compiled lazily (first call)
         #: forced-partial-boundary programs, keyed by microbatch count
         self._partial_step_fns: Dict[int, Any] = {}

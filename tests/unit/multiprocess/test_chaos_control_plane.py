@@ -298,12 +298,18 @@ def test_store_death_restart_and_p2p_adoption(tmp_path):
                 collect_rollup(client, list(gang)).prometheus_text())
 
         def _outage_windows_visible():
+            # an outage is counted when it starts, its seconds when the
+            # client reconnects, and both reach the store a beat later:
+            # wait for the CLOSED window (nothing on this process's way
+            # here takes the seconds that would hide the gap)
             parsed = _merged()
             return all(
                 parsed.get(f'train_steps_total{{node="{n}"}}', 0) > 0
                 and parsed.get(
                     f'elasticity_store_outages_total{{node="{n}"}}', 0)
-                >= 1 for n in gang)
+                >= 1 and parsed.get(
+                    f'elasticity_store_degraded_seconds_total'
+                    f'{{node="{n}"}}', 0) > 0 for n in gang)
 
         wait_for(_outage_windows_visible, timeout=90,
                  what="rollup shows every node's step counter AND its "

@@ -3,7 +3,10 @@
 primitive whether the hub is on or off; each program's first call; and the
 compile account of the whole process."""
 
+import ast
+import json
 import logging
+import os
 import subprocess
 import sys
 import threading
@@ -33,10 +36,13 @@ ROOT = __import__("pathlib").Path(__file__).parents[3]
 #: name -> the parents it may lie under, as ISSUE 54 fixes them
 #: (``startup/distributed`` and ``startup/dataloader`` are leaves the
 #: builder added; a second ``startup/engine/optimizer`` is the optimizer
-#: state's shapes)
+#: state's shapes; since PR 55 ``orbax.checkpoint`` is an import leaf of
+#: its own, under the span that first needs it: the snapshots' or an
+#: async checkpoint engine's)
 INITIALIZE = {
     "startup/initialize": {None},
-    "startup/import": {"startup/initialize", "startup/engine"},
+    "startup/import": {"startup/initialize", "startup/engine",
+                       "startup/engine/resilience"},
     "startup/distributed": {"startup/initialize"},
     "startup/config": {"startup/initialize"},
     "startup/mesh": {"startup/initialize"},
@@ -497,6 +503,149 @@ def test_the_package_import_is_stamped_and_loads_nothing_new():
         "print('ok')\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
                          capture_output=True, text=True, timeout=300,
-                         env=dict(__import__("os").environ,
-                                  JAX_PLATFORMS="cpu"))
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+# -- the checkpoint library -------------------------------------------------
+
+#: a process of its own (this one may hold ``orbax.checkpoint`` from any
+#: test before): ``initialize()`` of a tiny model under ``argv[1]``'s
+#: configuration and one ``train_step``, then what the case asks
+A_START = """
+import json, sys, tempfile
+import jax, jax.numpy as jnp, numpy as np
+import deepspeed_tpu
+from deepspeed_tpu.models import LlamaConfig, LlamaModel
+from deepspeed_tpu.parallel import MeshLayout
+from deepspeed_tpu.telemetry import get_telemetry
+from deepspeed_tpu.utils import groups
+
+case, scratch = sys.argv[1], tempfile.mkdtemp()
+config = json.loads(sys.argv[2].replace("SCRATCH", scratch))
+mesh = groups.initialize_mesh(MeshLayout.infer(jax.device_count()))
+model = LlamaModel(LlamaConfig.tiny(num_layers=2, max_seq_len=32,
+                                    dtype=jnp.float32), mesh=mesh)
+engine, *_ = deepspeed_tpu.initialize(
+    model=model, model_parameters=model.init_params(jax.random.PRNGKey(0)),
+    mesh=mesh, config=dict({
+        "train_micro_batch_size_per_gpu": 1,
+        "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+        "zero_optimization": {"stage": 2}}, **config))
+tel = get_telemetry()
+# (the bare namespace ``google.cloud`` is made by a .pth file as the
+# interpreter starts; what orbax's logging loads lies under it)
+heavy = lambda: sorted(m for m in sys.modules
+                       if m.startswith(("orbax", "google.cloud.")))
+named = lambda events: [e for e in events if e["name"] == "startup/import"
+                        and e["args"]["module"] == "orbax.checkpoint"]
+(root,) = [e for e in tel.startup.events()
+           if e["name"] == "startup/initialize"]
+at_return = "orbax.checkpoint" in sys.modules
+batch = {"input_ids": jnp.ones((jax.device_count(), 32), jnp.int32)}
+engine.train_step(batch)
+
+if case in ("nothing_saves", "first_save"):
+    # a run that configured nothing that saves loads no checkpoint library
+    assert not heavy(), heavy()
+    assert not named(tel.startup.events()) and not named(tel.tracer.events())
+if case == "first_save":
+    # the first save pays the load and says so: one span of the hub (this
+    # is no start), inside the save's own, and none for the second save
+    saved = jax.device_get(engine.state.params)
+    engine.save_checkpoint(scratch + "/ckpt")
+    assert "orbax.checkpoint" in sys.modules
+    (span,) = named(tel.tracer.events())
+    assert span["args"]["parent"] == "checkpoint/save"
+    assert not named(tel.startup.events())
+    engine.train_step(batch)
+    engine.save_checkpoint(scratch + "/ckpt")
+    assert len(named(tel.tracer.events())) == 1
+    path, _ = engine.load_checkpoint(scratch + "/ckpt", tag="global_step1")
+    assert path is not None and engine.global_steps == 1
+    for a, b in zip(jax.tree.leaves(saved),
+                    jax.tree.leaves(jax.device_get(engine.state.params))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+if case in ("snapshots", "async_engine"):
+    # a run that will save on a deadline has the library when
+    # initialize() returns: a leaf of its own under the start's root
+    assert at_return
+    (leaf,) = named(tel.startup.events())
+    assert leaf["tid"] == root["tid"] and "depth" in leaf["args"]
+    assert root["start"] <= leaf["start"] and leaf["end"] <= root["end"]
+    assert leaf["args"]["parent"] == sys.argv[3]
+    (start,) = tel.startup_report()["roots"]
+    assert start["largest_import"][0] == "orbax.checkpoint"
+    # and the resilience package's own import stayed light beside it
+    (light,) = [e for e in tel.startup.events()
+                if e["args"].get("module") == "deepspeed_tpu.resilience"]
+    assert light["end"] - light["start"] < leaf["end"] - leaf["start"]
+print("ok")
+"""
+
+
+#: case -> (what it adds to the configuration, the leaf's parent span)
+STARTS = {
+    "nothing_saves": ({}, None),
+    "first_save": ({"telemetry": {"enabled": True, "jsonl": False,
+                                  "prometheus": False}}, None),
+    "snapshots": ({"resilience": {"enabled": True,
+                                  "snapshot_dir": "SCRATCH/snaps"}},
+                  "startup/engine/resilience"),
+    "async_engine": ({"checkpoint": {"checkpoint_engine":
+                                     {"type": "async"}}}, "startup/engine"),
+}
+
+
+@pytest.mark.parametrize("case", STARTS)
+def test_the_checkpoint_library_is_loaded_by_what_saves(case):
+    config, parent = STARTS[case]
+    out = subprocess.run(
+        [sys.executable, "-c", A_START, case, json.dumps(config),
+         str(parent)], cwd=ROOT, capture_output=True, text=True,
+        timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0 and out.stdout.strip().endswith("ok"), \
+        out.stderr[-4000:]
+
+
+def _imported_with_the_module(tree):
+    """``(line, module)`` of every import that runs as the module is
+    imported: not those in a function's body, nor under ``if
+    TYPE_CHECKING:``."""
+    todo = list(tree.body)
+    while todo:
+        node = todo.pop()
+        if isinstance(node, ast.Import):
+            yield from ((node.lineno, a.name) for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            yield node.lineno, node.module or ""
+        elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
+                               ast.Lambda)):
+            continue
+        elif isinstance(node, ast.If) and "TYPE_CHECKING" in ast.unparse(
+                node.test):
+            todo.extend(node.orelse)
+        else:
+            todo.extend(ast.iter_child_nodes(node))
+
+
+def test_no_module_of_the_package_imports_orbax_as_it_is_imported():
+    """The next top-level ``import orbax`` would cost every training start
+    its 13 s again (PERF.md, PR 55) with no other test red: the library
+    comes from ``checkpoint_engine.orbax_checkpoint()``, inside the
+    function that needs it."""
+    found = []
+    for path in sorted((ROOT / "deepspeed_tpu").rglob("*.py")):
+        tree = ast.parse(path.read_text(), filename=str(path))
+        found += [f"{path.relative_to(ROOT)}:{line}: imports {module}"
+                  for line, module in _imported_with_the_module(tree)
+                  if module.split(".")[0] == "orbax"]
+    assert not found, "\n".join(found)
+    # the walk sees what it has to see
+    seen = dict(_imported_with_the_module(ast.parse(
+        "import os\ntry:\n    import orbax.checkpoint as ocp\n"
+        "except ImportError:\n    ocp = None\n"
+        "class A:\n    from orbax import checkpoint\n"
+        "def f():\n    import orbax.checkpoint\n"
+        "if TYPE_CHECKING:\n    import orbax\nelse:\n    import json\n")))
+    assert seen == {1: "os", 3: "orbax.checkpoint", 7: "orbax", 13: "json"}
