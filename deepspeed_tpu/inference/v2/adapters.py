@@ -51,7 +51,9 @@ some); a state kind's name (``mix_in`` / ``mix_chunk`` or ``mix_decode`` /
 ``mix_out``, below: a mixer that is a layer of its own); or :data:`FFN`,
 the FFN alone (``ffn_layer``).  A part's pool has as many layers as the
 pattern has of the part and a layer is handed its place among THOSE:
-:class:`NemotronHV2Adapter` is the family whose layers are one part alone.
+:class:`NemotronHV2Adapter` is the family whose layers are one part alone,
+:class:`SolarOpen2V2Adapter` the one whose published layer is two entries
+(a mixer, then the experts).
 
 **A second kind of per-sequence state.**  A model whose layers carry a
 recurrent state beside their keys states it as ``state_kinds``
@@ -149,7 +151,10 @@ class StateRows:
 class LayerPattern:
     """The model's layers by PART (an attention kind's name, a state
     kind's, or :data:`FFN`): ``leading``, run one by one, then ``periods``
-    repeats of ``period``, scanned."""
+    repeats of ``period``, scanned.  A published layer may be one entry (a
+    family whose FFN rides ``post_attn``, or whose layers are one part
+    alone) or two, a mixer's and then :data:`FFN` (a family that norms each
+    on its own: :class:`SolarOpen2V2Adapter`); the engine counts entries."""
     leading: Tuple[str, ...]
     period: Tuple[str, ...]
     periods: int
@@ -295,8 +300,12 @@ class ModelAdapterV2:
 
     def post_attn(self, lp: Any, x: jnp.ndarray, attn: jnp.ndarray,
                   params: Any, l: jnp.ndarray) -> jnp.ndarray:
-        """Output projection + residual + FFN block: ``x [N, H]``,
-        ``attn [N, h, v_dim]`` → ``[N, H]``.  ``params`` is the whole tree
+        """Output projection + residual + whatever the family has behind
+        the attention in the SAME entry of the pattern (an FFN block in
+        most, nothing where the FFN is an entry of its own): ``x [N, H]``
+        the residual (not the normed input ``qkv`` made of it: a hook that
+        needs that norms ``x`` again), ``attn [N, h, v_dim]`` → ``[N,
+        H]``.  ``params`` is the whole tree
         and ``l`` this layer's index among the scanned layers (traced;
         None in a leading layer), for what ``layers()`` left out of
         ``lp``."""
@@ -704,6 +713,47 @@ class NemotronHV2Adapter(ModelAdapterV2):
         return self.model.logits(params, x)
 
 
+class SolarOpen2V2Adapter(NemotronHV2Adapter):
+    """Solar-Open-2 (``models/solar_open2.py``): a published layer is TWO
+    parts, each under a norm of its own: a token mixer (a gated
+    grouped-query attention with no rotary in the layers ``gqa_layers``
+    names, else a gated delta rule with a decay a key channel: the model's
+    :class:`StateKind`, ``mix_*``) and then the experts (:data:`FFN`), whose
+    stacks stay whole (as :class:`OlmoeV2Adapter`'s) and hold this chip's
+    share.  So the pattern has twice the published layers: ``num_layers``
+    counts published layers, the engine's ``last_layers_by_part`` (gauges
+    ``inference/layers/<part>``) the parts.  The other hooks are
+    :class:`NemotronHV2Adapter`'s, whose layers are parts already;
+    ``post_attn`` (the model's ``attn_out``) applies the attention's output
+    gate, which reads the part's normed input and norms ``x`` again for
+    it."""
+
+    @property
+    def kinds(self) -> Tuple[AttentionKind, ...]:
+        from ...models.solar_open2 import KV
+
+        c = self.config
+        return (AttentionKind(KV, c.mixers.count("*"), c.num_kv_heads,
+                              c.head_dim, c.head_dim),)   # no rotary
+
+    @property
+    def state_kinds(self) -> Tuple[StateKind, ...]:
+        from ...models.solar_open2 import DELTA
+
+        return (StateKind(DELTA, self.config.mixers.count("D"),
+                          self.model.state_parts(), in_place=(DELTA,)),)
+
+    @property
+    def pattern(self) -> LayerPattern:
+        from ...models.solar_open2 import DELTA, KV
+
+        c = self.config
+        mixer = {"*": KV, "D": DELTA}
+        return LayerPattern(
+            (), tuple(part for ch in c.period for part in (mixer[ch], FFN)),
+            c.num_layers // len(c.period))
+
+
 _REGISTRY = {
     "FalconH1Model": FalconH1V2Adapter,
     "LlamaModel": LlamaV2Adapter,
@@ -713,4 +763,5 @@ _REGISTRY = {
     "OlmoeModel": OlmoeV2Adapter,
     "OPTModel": OPTV2Adapter,
     "PanguUltraMoeModel": PanguUltraMoeV2Adapter,
+    "SolarOpen2Model": SolarOpen2V2Adapter,
 }

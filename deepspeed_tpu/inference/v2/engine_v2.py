@@ -686,7 +686,9 @@ class RaggedInferenceEngineV2:
         def mix_fn(lp, p, pools, l):
             outs = []
             for (lo, hi), (_, _, _, mix) in zip(spans, parts):
-                out, pools = mix(lp, p[lo:hi], pools, l)
+                # ``mix_in``'s rows: an array, or a tree of arrays a row
+                out, pools = mix(lp, jax.tree.map(lambda a: a[lo:hi], p),
+                                 pools, l)
                 outs.append(out)
             return jnp.concatenate(outs), pools
 

@@ -18,6 +18,7 @@ from .olmoe import OlmoeConfig, OlmoeModel
 from .opt import OPTConfig, OPTModel
 from .pangu_ultra_moe import PanguUltraMoeConfig, PanguUltraMoeModel
 from .resnet import ResNetConfig, ResNetModel
+from .solar_open2 import SolarOpen2Config, SolarOpen2Model
 
 __all__ = ["BertConfig", "BertModel", "FalconH1Config", "FalconH1Model",
            "LlamaConfig", "LlamaModel",
@@ -25,4 +26,5 @@ __all__ = ["BertConfig", "BertModel", "FalconH1Config", "FalconH1Model",
            "NemotronHConfig", "NemotronHModel", "OlmoeConfig", "OlmoeModel",
            "OPTConfig", "OPTModel",
            "PanguUltraMoeConfig", "PanguUltraMoeModel",
-           "ResNetConfig", "ResNetModel"]
+           "ResNetConfig", "ResNetModel",
+           "SolarOpen2Config", "SolarOpen2Model"]

@@ -269,7 +269,7 @@ def scan_chunk(xs, B, C, delta, A, S):
 
 
 def mix(model: Any, lp: Any, x: jnp.ndarray, state: Dict[str, jnp.ndarray],
-        tokens: int, valid: jnp.ndarray
+        tokens: int, valid: jnp.ndarray, in_place: str = SSM
         ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """A family's mixer (its ``mix_in`` / ``mix_chunk`` or ``mix_decode`` /
     ``mix_out``) over ``R`` sequences' rows with their state as VALUES in
@@ -278,22 +278,23 @@ def mix(model: Any, lp: Any, x: jnp.ndarray, state: Dict[str, jnp.ndarray],
     padding: they move no state); ``state``: what each sequence holds
     coming in → (what the mixer adds to the residual ``[R·tokens, H]``, the
     state going out).  One token a sequence is a decode step's update (here
-    on a pool of one layer, the rows its slots); more is a block of the
-    chunk form.  The serving engine, whose rows are several groups and
-    which keeps the state itself, calls the four parts."""
+    on a pool of one layer, the rows its slots, of the part the family
+    moves ``in_place``); more is a block of the chunk form.  The serving
+    engine, whose rows are several groups and which keeps the state itself,
+    calls the four parts."""
     p = model.mix_in(lp, x)
     if tokens == 1:
         y, new, held = model.mix_decode(
             lp, p, {"conv": state["conv"]},
-            {SSM: (state[SSM][None], 0, 0)}, valid)
-        new = dict(new, **{SSM: held[SSM][0]})
+            {in_place: (state[in_place][None], 0, 0)}, valid)
+        new = dict(new, **{in_place: held[in_place][0]})
     else:
         y, new = model.mix_chunk(lp, p, state, tokens, valid)
     return model.mix_out(lp, p, y), new
 
 
 def mix_sequences(model: Any, lp: Any, x: jnp.ndarray, batch: int, seq: int,
-                  block: int) -> jnp.ndarray:
+                  block: int, in_place: str = SSM) -> jnp.ndarray:
     """:func:`mix` over whole sequences without a cache: ``x [batch·seq,
     H]`` → ``[batch·seq, H]``, in blocks of ``block`` tokens from a zero
     state (the last block padded)."""
@@ -305,7 +306,7 @@ def mix_sequences(model: Any, lp: Any, x: jnp.ndarray, batch: int, seq: int,
         part = jax.lax.dynamic_slice_in_dim(rows, i * block, block, 1)
         out, state = mix(
             model, lp, part.reshape(batch * block, -1), state, block,
-            jnp.full((batch,), jnp.clip(seq - i * block, 0, block)))
+            jnp.full((batch,), jnp.clip(seq - i * block, 0, block)), in_place)
         return state, out.reshape(batch, block, -1)
 
     _, outs = jax.lax.scan(one, model.zero_state(batch), jnp.arange(blocks))

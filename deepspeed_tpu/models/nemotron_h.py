@@ -137,6 +137,22 @@ class NemotronHConfig:
         return cls(**d)
 
 
+def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                     batch: int, seq: int, dt: Any) -> jnp.ndarray:
+    """Whole sequences without a cache: ``q [B·S, h, d]``, ``k`` and ``v
+    [B·S, kv, d]`` (query head ``n`` reads KV head ``n // (h / kv)``) →
+    ``[B·S, h, d]``, a full causal softmax at ``1/√d``, no positions."""
+    heads, d = q.shape[1:]
+    kv = k.shape[1]
+    seen = jnp.arange(seq)[None, :] <= jnp.arange(seq)[:, None]
+    q = q.reshape(batch, seq, kv, heads // kv, d)
+    k, v = (t.reshape(batch, seq, kv, d) for t in (k, v))
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k).astype(F32) / np.sqrt(d)
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1).astype(dt)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", p, v).reshape(
+        batch * seq, heads, d)
+
+
 class NemotronHModel:
     """Weights and their layout, and each part as the serving engine's
     hooks take it (``inference/v2/adapters.NemotronHV2Adapter``): :meth:`qkv`
@@ -394,20 +410,10 @@ class NemotronHModel:
         c = self.config
         dt = c.dtype
         B_, S_ = input_ids.shape
-        seen = jnp.arange(S_)[None, :] <= jnp.arange(S_)[:, None]
-        rep = c.num_heads // c.num_kv_heads
 
         def attention(lp, x):
-            q, k, v = self.qkv(lp, x)
-            q = q.reshape(B_, S_, c.num_kv_heads, rep, c.head_dim)
-            k, v = (t.reshape(B_, S_, c.num_kv_heads, c.head_dim)
-                    for t in (k, v))
-            s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k).astype(F32) \
-                / np.sqrt(c.head_dim)
-            p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1).astype(dt)
-            attn = jnp.einsum("bgrqk,bkgd->bqgrd", p, v).reshape(
-                B_ * S_, c.num_heads, c.head_dim)
-            return self.attn_out(lp, x, attn)
+            return self.attn_out(lp, x, causal_attention(
+                *self.qkv(lp, x), B_, S_, dt))
 
         at = dict.fromkeys(STACKS, 0)
         x = self.embed(params, input_ids.reshape(-1))

@@ -661,6 +661,37 @@ def check_ssm_state_update_lanes(s: KernelShapes, interpret: bool
                               128, 128, True)
 
 
+def check_delta_state_update(s: KernelShapes, interpret: bool) -> List[Check]:
+    """The decode step's update of a gated delta rule's states IN PLACE in
+    their pool, at a published layer's widths (64 heads of 128 keys x 128
+    values, a decay a key channel), against the ``jax.numpy`` reference:
+    the pool after, and ``o``.  ``β`` up to 2 and a decay down to a fifth a
+    token; kernel and reference run the same float32 formula on the VPU
+    (the kernel folds ``β`` into ``k`` and ``v`` first)."""
+    dsu = _mod("delta_state_update")
+    rng = np.random.RandomState(13)
+    rows, heads, d = s.slots, 64, 128
+    pool = _normal(rng, (3, rows + 2, heads, d, d), jnp.float32)
+    a = jnp.exp(-1.6 * jnp.abs(_normal(rng, (rows, heads, d), jnp.float32)))
+    k, q = (_normal(rng, (rows, heads, d), jnp.float32, d ** -0.5)
+            for _ in range(2))
+    beta = 2.0 * jax.nn.sigmoid(_normal(rng, (rows, heads), jnp.float32))
+    v = _normal(rng, (rows, heads, d), jnp.float32)
+
+    @jax.jit
+    def errors(pool, a, k, q, beta, v):
+        want = dsu.delta_state_update_reference(pool, 1, 1, a, k, q, beta, v)
+        # the kernel writes the pool it is given: hand it a copy, after
+        # the reference has read the original
+        got = dsu.delta_state_update(pool + 0.0, 1, 1, a, k, q, beta, v,
+                                     interpret=interpret)
+        return [_rel_err(g, w) for g, w in zip(got, want)]
+
+    state_err, o_err = errors(pool, a, k, q, beta, v)
+    return [Check("delta_state_update_state", float(state_err), 1e-5),
+            Check("delta_state_update_o", float(o_err), 1e-4)]
+
+
 def check_quantizer(s: KernelShapes, interpret: bool) -> List[Check]:
     qz = _mod("quantizer")
     rng = np.random.RandomState(6)
@@ -709,7 +740,8 @@ CHECKS = (check_flash, check_flash_streamed, check_decode, check_paged,
           check_paged_hybrid, check_paged_latent, check_fused_adam, check_moe,
           check_moe_grouped,
           check_moe_share, check_moe_latent, check_ssm_state_update,
-          check_ssm_state_update_lanes, check_quantizer,
+          check_ssm_state_update_lanes, check_delta_state_update,
+          check_quantizer,
           check_block_sparse)
 
 

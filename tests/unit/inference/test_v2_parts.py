@@ -81,7 +81,8 @@ def test_the_adapters_that_were_there_serve_the_parents_logits(family):
 
 
 def test_the_registry_holds_the_six_and_the_family_of_one_part_layers():
-    assert set(adapters._REGISTRY) == set(FAMILIES) | {"NemotronHModel"}
+    assert set(adapters._REGISTRY) == set(FAMILIES) | {"NemotronHModel",
+                                                       "SolarOpen2Model"}
     assert len({adapters._REGISTRY[f] for f in FAMILIES}) == 6
     # the hook of the FFN alone: the families whose FFN follows their
     # attention in the same layer state no such layer and have none
@@ -90,6 +91,25 @@ def test_the_registry_holds_the_six_and_the_family_of_one_part_layers():
         assert adapters.FFN not in ad.pattern.leading + ad.pattern.period
         with pytest.raises(NotImplementedError):
             ad.ffn_layer(None, None, None, None)
+
+
+def test_a_published_layer_may_be_two_entries_of_the_pattern():
+    """Solar-Open-2: a mixer and then the experts, each under its own norm:
+    the pattern has twice the published layers and the state kind's pool
+    the KDA layers alone (PR 57)."""
+    solar = adapters.make_adapter(models.SolarOpen2Model(
+        models.SolarOpen2Config.tiny()))
+    assert solar.num_layers == 8
+    assert solar.pattern == adapters.LayerPattern(
+        (), ("kv", adapters.FFN) + ("delta", adapters.FFN) * 3, 2)
+    kind, = solar.state_kinds
+    assert (kind.name, kind.layers, kind.beside, kind.in_place) == (
+        "delta", 6, None, ("delta",))
+    assert dict((name, shape) for name, shape, _ in kind.parts) == {
+        "delta": (4, 16, 16), "conv": (3, 192)}
+    attention, = solar.kinds
+    assert (attention.layers, attention.theta, attention.kv_heads) == (
+        2, None, 2)
 
 
 def test_a_state_kind_is_a_layer_of_its_own_or_rides_beside_attention():

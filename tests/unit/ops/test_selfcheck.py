@@ -125,3 +125,23 @@ def test_the_kernels_second_forms_are_checked_too(monkeypatch, shapes, check):
     monkeypatch.setattr(selfcheck, "CHECKS", (getattr(selfcheck, check),))
     names = [c.name for c in selfcheck.run_checks(shapes, interpret=True)]
     assert names and all(("relu2" in n) or ("lanes" in n) for n in names)
+
+
+def test_the_delta_rules_update_is_checked_at_a_published_layers_widths(
+        monkeypatch, shapes):
+    """``delta_state_update`` (PR 57) at 64 heads of 128 x 128: the pool
+    after and ``o``, in the interpreter, and a kernel that forgets the
+    decay fails it."""
+    dsu = importlib.import_module(
+        "deepspeed_tpu.ops.pallas.delta_state_update")
+    monkeypatch.setattr(selfcheck, "CHECKS",
+                        (selfcheck.check_delta_state_update,))
+    names = [c.name for c in selfcheck.run_checks(shapes, interpret=True)]
+    assert names == ["delta_state_update_state", "delta_state_update_o"]
+    real = dsu.delta_state_update
+    monkeypatch.setattr(
+        dsu, "delta_state_update",
+        lambda pool, layer, first, a, *rest, **kw: real(
+            pool, layer, first, a * 0 + 1, *rest, **kw))
+    with pytest.raises(AssertionError, match="selfcheck FAILED.*delta_state"):
+        selfcheck.run_checks(shapes, interpret=True)

@@ -1237,3 +1237,27 @@ def test_a_head_a_lane_row_is_the_update_the_state_space_cell_was_measured_with(
         arg((96, 32), f32), arg((96, 32, 128), f32), arg((96, 2, 256)),
         arg((96, 2, 256)))
     assert got == _STATE_UPDATE_MODULE
+
+
+_DELTA_UPDATE_MODULE = (92841, "82c307519e46e67d")
+
+
+def test_the_delta_rule_s_update_is_the_kernel_its_cell_was_measured_with(
+        monkeypatch, capsys, one_chip):
+    """The decode step's update of a gated delta rule's states at the
+    serving cell's shapes (192 rows of 64 heads, 128 keys x 128 values,
+    three layers of 193 slots): a (sequence, 32 heads) block a grid step,
+    the pool aliased in and out."""
+    from deepspeed_tpu.ops.pallas import delta_state_update as dsu
+
+    f32 = jnp.float32
+    arg = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt,
+                                                     sharding=one_chip)
+    got = _module_of(
+        monkeypatch, capsys,
+        lambda pool, layer, a, k, q, beta, v: dsu.delta_state_update(
+            pool, layer, 1, a, k, q, beta, v, interpret=False),
+        arg((3, 193, 64, 128, 128)), arg((), jnp.int32),
+        arg((192, 64, 128)), arg((192, 64, 128)), arg((192, 64, 128)),
+        arg((192, 64)), arg((192, 64, 128)))
+    assert got == _DELTA_UPDATE_MODULE

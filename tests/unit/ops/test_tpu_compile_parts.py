@@ -83,9 +83,10 @@ def program(one_chip):
         mp.undo()
 
 
-def _values_made(text, dtype, shapes):
+def _values_made(text, dtype, shapes, kernel="ssm_state_update"):
     """The instructions that make a value of one of ``shapes`` (dims as
-    text) but pass a buffer on or write into it in place."""
+    text) but pass a buffer on or write into it in place (a call of
+    ``kernel`` does: its result IS its aliased operand)."""
     passes_on = {"parameter", "bitcast", "get-tuple-element"}
     roots, computation = {}, None
     for line in text.splitlines():
@@ -101,7 +102,7 @@ def _values_made(text, dtype, shapes):
             + r"\[([\d,]+)\]\S* ([\w-]+)\((.*)$", text, re.M):
         if shape not in shapes or opcode in passes_on \
                 or opcode == "dynamic-update-slice" \
-                or (opcode == "custom-call" and "ssm_state_update" in name):
+                or (opcode == "custom-call" and kernel in name):
             continue
         called = re.search(r"calls=%?([\w.-]+)", rest)
         if not (opcode == "fusion" and called
