@@ -605,7 +605,7 @@ class FalconH1V2Adapter(ModelAdapterV2):
         from ...models.falcon_h1 import SSM
 
         return (StateKind(SSM, self.num_layers, self.model.state_parts(),
-                          in_place=(SSM,), beside=self.kinds[0].name),)
+                          in_place=(SSM, "conv"), beside=self.kinds[0].name),)
 
     def embed(self, params, tokens, positions):
         del positions  # rotary: positions enter at qkv time
@@ -660,6 +660,10 @@ class NemotronHV2Adapter(ModelAdapterV2):
     def state_kinds(self) -> Tuple[StateKind, ...]:
         from ...models.nemotron_h import SSM
 
+        # the conv's tail stays a VALUE here (PR 58): moved in place, the
+        # conv's output is rounded to the model's type where XLA's fused
+        # chain kept float32, and this family's routers turn that into
+        # swapped experts often enough to thin its check's margin
         return (StateKind(SSM, self.config.count("M"),
                           self.model.state_parts(), in_place=(SSM,)),)
 
@@ -741,7 +745,7 @@ class SolarOpen2V2Adapter(NemotronHV2Adapter):
         from ...models.solar_open2 import DELTA
 
         return (StateKind(DELTA, self.config.mixers.count("D"),
-                          self.model.state_parts(), in_place=(DELTA,)),)
+                          self.model.state_parts(), in_place=(DELTA, "conv")),)
 
     @property
     def pattern(self) -> LayerPattern:

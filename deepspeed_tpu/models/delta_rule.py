@@ -1,7 +1,8 @@
 """A gated delta rule with a decay a KEY CHANNEL (Kimi Delta Attention, KDA:
 arXiv 2510.26692), the recurrence's own arithmetic for the families that
 have one (``models/solar_open2.py``): the conv step over its three
-streams, the chunk form, the decode step's update through
+streams (a decode step's through ``ops/pallas/conv_tail_update``), the
+chunk form, the decode step's update through
 ``ops/pallas/delta_state_update``, the gated norm.  What a family brings is
 ARGUMENTS: the sizes (:class:`DeltaDims`), the leaves ``m`` of one layer's
 recurrence (``conv_w [K, 3 · heads · d]``, ``dt_bias [heads · d]``, ``A_log
@@ -51,7 +52,10 @@ model's type and sum in float32.
 state, float32 whatever its length, key channels on the sublanes and the
 value's numbers on the lanes (what the decode step's kernel moves without
 laying anything out anew), and the conv's tail, the last ``K − 1`` inputs of
-the three streams, time-major ``[K − 1, 3 · heads · d]``.
+the three streams, time-major and flat ``[(K − 1) · 3 · heads · d]`` (as
+``Mamba2Dims`` holds its own: no dimension of three is a tiled one, and a
+decode step's kernel reads a tap as a stretch of lanes).  A decode step
+moves BOTH where they lie.
 """
 
 from __future__ import annotations
@@ -62,6 +66,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.conv_tail_update import conv_tail_update
 from ..ops.pallas.delta_state_update import delta_state_update
 
 #: the name of the per-sequence state's pool, and of its state part
@@ -90,7 +95,7 @@ class DeltaDims:
         is float32 whatever the model's type: it is decayed and corrected
         once a token, thousands of times over."""
         return ((DELTA, (self.heads, self.d_head, self.d_head), F32),
-                ("conv", (self.d_conv - 1, 3 * self.width), dtype))
+                ("conv", ((self.d_conv - 1) * 3 * self.width,), dtype))
 
     def zero_state(self, rows: int, dtype: Any) -> Dict[str, jnp.ndarray]:
         """What ``rows`` sequences hold a layer before their first token."""
@@ -102,26 +107,32 @@ def _l2(x: jnp.ndarray) -> jnp.ndarray:
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
+def _streams(dims: DeltaDims, out: jnp.ndarray):
+    """The conv's output ``[R, T, 3·width]`` → ``q``, ``k`` normalised and
+    ``v``, each ``[R, T, heads, d]`` float32."""
+    q, k, v = (out[..., i * dims.width:(i + 1) * dims.width].astype(
+        F32).reshape(out.shape[:2] + (dims.heads, dims.d_head))
+        for i in range(3))
+    return _l2(q) * dims.d_head ** -0.5, _l2(k), v
+
+
 def conv(dims: DeltaDims, m: Any, qkv: jnp.ndarray, tail: jnp.ndarray,
          tokens: int, valid: jnp.ndarray, dt: Any):
     """A group's rows ``qkv [R·tokens, 3·width]`` through the conv from the
-    sequences' tails ``[R, K−1, 3·width]`` → (``q``, ``k`` normalised and
+    sequences' tails ``[R, (K−1)·3·width]`` → (``q``, ``k`` normalised and
     ``v``, each ``[R, T, heads, d]`` float32, the tails going out)."""
     R, T, K = qkv.shape[0] // tokens, tokens, dims.d_conv
     with jax.named_scope("kda/conv"):
         # the tail's K−1 inputs, then the rows': output t sums inputs
         # t … t+K−1 of that; the tail going out ends at the last real one
-        seq = jnp.concatenate([tail.astype(dt), qkv.reshape(R, T, -1)],
-                              axis=1)
+        seq = jnp.concatenate([tail.astype(dt).reshape(R, K - 1, -1),
+                               qkv.reshape(R, T, -1)], axis=1)
         w = m["conv_w"].astype(dt)
         out = jax.nn.silu(sum(seq[:, j:j + T] * w[j] for j in range(K)))
         left = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
             s, n, K - 1, 0))(seq, valid)
-        q, k, v = (out[..., i * dims.width:(i + 1) * dims.width].astype(
-            F32).reshape(R, T, dims.heads, dims.d_head) for i in range(3))
-        q = _l2(q) * dims.d_head ** -0.5
-        k = _l2(k)
-    return q, k, v, left.astype(tail.dtype)
+        q, k, v = _streams(dims, out)
+    return q, k, v, left.reshape(tail.shape).astype(tail.dtype)
 
 
 def gates(dims: DeltaDims, m: Any, p: Any, tokens: int, valid: jnp.ndarray):
@@ -150,18 +161,22 @@ def chunk(dims: DeltaDims, m: Any, p: Any, state: Dict[str, jnp.ndarray],
             {DELTA: S.astype(state[DELTA].dtype), "conv": tail})
 
 
-def decode(dims: DeltaDims, m: Any, p: Any, state: Dict[str, jnp.ndarray],
-           held: Dict[str, Tuple[jnp.ndarray, Any, Any]], valid: jnp.ndarray,
-           dt: Any) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray],
-                             Dict[str, jnp.ndarray]]:
-    """A decode step's ``R`` rows, a token a sequence: ``state["conv"]`` the
-    conv's tails as values ``[R, K−1, 3·width]``, and the states where they
-    lie, ``held["delta"] = (array [layers, slots, heads, d, d], layer,
-    first slot)``, row ``r``'s at ``(layer, first + r)`` → (``o [R, width]``
-    float32, the tails going out, the array with the rows' states moved one
-    step: ``delta_state_update``, which reads ``o = Sᵀ q`` off the new
-    values)."""
-    q, k, v, tail = conv(dims, m, p["qkv"], state["conv"], 1, valid, dt)
+def decode(dims: DeltaDims, m: Any, p: Any,
+           held: Dict[str, Tuple[jnp.ndarray, Any, Any]], valid: jnp.ndarray
+           ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """A decode step's ``R`` rows, a token a sequence, whose state lies in
+    the pool: ``held[part] = (array [layers, slots, …], layer, first
+    slot)``, row ``r``'s at ``(layer, first + r)``, the conv's tails
+    ``[…, (K−1)·3·width]`` and the states ``[…, heads, d, d]`` → (``o [R,
+    width]`` float32, the two arrays with the rows' parts moved one step
+    where they lie: ``conv_tail_update``, which emits the conv's output,
+    and ``delta_state_update``, which reads ``o = Sᵀ q`` off the new
+    values).  A row with ``valid`` 0 moves neither."""
+    array, layer, first = held["conv"]
+    with jax.named_scope("kda/conv"):
+        tails, out = conv_tail_update(array, layer, first, p["qkv"],
+                                      m["conv_w"], None, valid)
+        q, k, v = _streams(dims, out[:, None])
     g, beta = gates(dims, m, p, 1, valid)
     array, layer, first = held[DELTA]
     with jax.named_scope("kda/state_update"):
@@ -171,7 +186,7 @@ def decode(dims: DeltaDims, m: Any, p: Any, state: Dict[str, jnp.ndarray],
             array, layer, first, a=jnp.exp(g[:, 0]),
             k=jnp.where(live, k[:, 0], 0.0), q=jnp.where(live, q[:, 0], 0.0),
             beta=beta[:, 0], v=v[:, 0])
-    return o.reshape(-1, dims.width), {"conv": tail}, {DELTA: array}
+    return o.reshape(-1, dims.width), {DELTA: array, "conv": tails}
 
 
 def gated_norm(dims: DeltaDims, m: Any, gate: jnp.ndarray, o: jnp.ndarray,

@@ -64,8 +64,9 @@ at the program's edge).  The serving engine keeps
 both in a pool indexed by batch slot (``inference/v2/kv_cache.StateLayout``)
 and hands the mixer a group of rows with their sequences' state: a chunk's
 (:meth:`FalconH1Model.mix_chunk`) as values in and out, a decode step's
-(:meth:`FalconH1Model.mix_decode`) as the pool's array with the layer and
-the rows' first slot, which ``ssm_state_update`` moves where they lie.
+(:meth:`FalconH1Model.mix_decode`) as the pool's arrays with the layer and
+the rows' first slot, which ``ssm_state_update`` and ``conv_tail_update``
+move where they lie.
 
 None of the multipliers is folded into a weight.  Weights are stacked
 ``[L, …]`` for the layer scan: ``layers: {attn_norm, mlp_norm [L, H], attn:
@@ -335,13 +336,12 @@ class FalconH1Model:
                    ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray],
                               Dict[str, jnp.ndarray]]:
         """A decode step's ``R`` rows ``p [R, proj_dim]``, a token a
-        sequence: ``state["conv"]`` the conv's tails as values ``[R,
-        (K−1)·conv_dim]``, and the states where they lie, ``held["ssm"] =
-        (array [layers, slots, heads, d_state, d_head], layer, first
-        slot)``, row ``r``'s at ``(layer, first + r)`` → (``y [R, d_ssm]``
-        float32, the tails going out, the array with the rows' states moved
-        one step: ``ssm_state_update``, which reads ``y = S C`` off the new
-        values)."""
+        sequence, whose state lies in the pool: ``held[part] = (array
+        [layers, slots, …], layer, first slot)``, row ``r``'s at ``(layer,
+        first + r)``, the conv's tails and the states alike (``state``, the
+        parts handed over as values, is empty) → (``y [R, d_ssm]`` float32,
+        no values, the two arrays with the rows' parts moved one step where
+        they lie: ``mamba2.decode``)."""
         c = self.config
         return mamba2.decode(c.mamba, lp["ssm"], p, state, held, valid,
                              c.dtype)

@@ -1,8 +1,9 @@
 """The Mamba-2 mixer's own arithmetic, shared by the families that have one
 (``models/falcon_h1.py``, beside attention in every layer;
-``models/nemotron_h.py``, a layer of its own): the conv step, the chunk
-form, the decode step's update through ``ops/pallas/ssm_state_update``, the
-gated norm.  What differs between families is ARGUMENTS: the sizes
+``models/nemotron_h.py``, a layer of its own): the conv step (a decode
+step's through ``ops/pallas/conv_tail_update``), the chunk form, the decode
+step's update through ``ops/pallas/ssm_state_update``, the gated norm.
+What differs between families is ARGUMENTS: the sizes
 (:class:`Mamba2Dims`), the leaves ``m`` of one layer's mixer (``conv_w [K,
 conv_dim]``, ``conv_b [conv_dim]``, ``dt_bias``, ``A_log``, ``D [heads]``,
 ``norm [d_ssm]``), the model's type, the norm's eps.  The projections
@@ -36,7 +37,9 @@ products take the model's type and sum in float32.
 state, float32 whatever its length, ``d_state`` on the sublanes and the
 head's numbers on the lanes (what the decode step's kernel moves without
 laying anything out anew), and the conv's tail, its last ``K − 1`` inputs,
-time-major and flat ``[(K − 1) · conv_dim]``.  A head of fewer than 128
+time-major and flat ``[(K − 1) · conv_dim]``; a decode step moves the
+state where it lies, and the tail too where the family's kind states it
+``in_place``.  A head of fewer than 128
 numbers shares its lane row with its neighbours (:attr:`Mamba2Dims.pack`
 heads of one group a row: ``[heads / pack, d_state, pack · d_head]``): an
 array whose minor dimension is 64 is padded to 128 lanes in HBM, twice the
@@ -51,6 +54,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.conv_tail_update import conv_tail_update
 from ..ops.pallas.ssm_state_update import ssm_state_update
 
 #: the name of the per-sequence state's pool, and of its state part
@@ -128,6 +132,25 @@ class Mamba2Dims:
         return S.reshape(like.shape).astype(like.dtype)
 
 
+def _split(dims: Mamba2Dims, m: Any, out: jnp.ndarray, dt_raw: jnp.ndarray,
+           valid: jnp.ndarray):
+    """The conv's output ``[R, T, conv_dim]`` and the rows' ``dt [R, T,
+    heads]`` → (``xs [R, T, G, k, P]``, ``B`` and ``C`` ``[R, T, G, N]``,
+    ``Δ [R, T, G, k]`` float32, 0 at a padded position, ``A [G, k]``)."""
+    R, T = out.shape[:2]
+    heads, P, N, G = dims.heads, dims.d_head, dims.d_state, dims.groups
+    d_ssm, bc = dims.d_ssm, dims.bc_dim
+    real = jnp.arange(T)[None, :] < valid[:, None]             # [R, T]
+    xs = out[..., :d_ssm].reshape(R, T, G, heads // G, P)
+    B = out[..., d_ssm:d_ssm + bc].reshape(R, T, G, N)
+    C = out[..., d_ssm + bc:].reshape(R, T, G, N)
+    delta = jax.nn.softplus(dt_raw.astype(F32) + m["dt_bias"].astype(F32))
+    delta = jnp.where(real[..., None], delta, 0.0
+                      ).reshape(R, T, G, heads // G)
+    A = -jnp.exp(m["A_log"].astype(F32)).reshape(G, heads // G)
+    return xs, B, C, delta, A
+
+
 def conv(dims: Mamba2Dims, m: Any, p: jnp.ndarray, tail: jnp.ndarray,
          tokens: int, valid: jnp.ndarray, dt: Any):
     """A group's rows ``p [R·tokens, proj_dim]`` through the conv from the
@@ -135,9 +158,7 @@ def conv(dims: Mamba2Dims, m: Any, p: jnp.ndarray, tail: jnp.ndarray,
     ``B`` and ``C`` ``[R, T, G, N]``, ``Δ [R, T, G, k]`` float32, 0 at a
     padded position, ``A [G, k]``, the tails going out)."""
     R, T, K = p.shape[0] // tokens, tokens, dims.d_conv
-    heads, P, N, G = dims.heads, dims.d_head, dims.d_state, dims.groups
-    d_ssm, bc, conv_dim = dims.d_ssm, dims.bc_dim, dims.conv_dim
-    real = jnp.arange(T)[None, :] < valid[:, None]             # [R, T]
+    d_ssm, conv_dim = dims.d_ssm, dims.conv_dim
     p = p.reshape(R, T, dims.proj_dim)
     xbc, dt_raw = (p[..., d_ssm:d_ssm + conv_dim], p[..., d_ssm + conv_dim:])
     with jax.named_scope("ssm/conv"):
@@ -151,14 +172,8 @@ def conv(dims: Mamba2Dims, m: Any, p: jnp.ndarray, tail: jnp.ndarray,
         out = jax.nn.silu(out)
         left = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
             s, n, K - 1, 0))(seq, valid)
-    xs = out[..., :d_ssm].reshape(R, T, G, heads // G, P)
-    B = out[..., d_ssm:d_ssm + bc].reshape(R, T, G, N)
-    C = out[..., d_ssm + bc:].reshape(R, T, G, N)
-    delta = jax.nn.softplus(dt_raw.astype(F32) + m["dt_bias"].astype(F32))
-    delta = jnp.where(real[..., None], delta, 0.0
-                      ).reshape(R, T, G, heads // G)
-    A = -jnp.exp(m["A_log"].astype(F32)).reshape(G, heads // G)
-    return xs, B, C, delta, A, left.reshape(tail.shape).astype(tail.dtype)
+    return _split(dims, m, out, dt_raw, valid) \
+        + (left.reshape(tail.shape).astype(tail.dtype),)
 
 
 def skip(dims: Mamba2Dims, m: Any, y: jnp.ndarray, xs: jnp.ndarray
@@ -188,14 +203,30 @@ def decode(dims: Mamba2Dims, m: Any, p: jnp.ndarray,
            held: Dict[str, Tuple[jnp.ndarray, Any, Any]], valid: jnp.ndarray,
            dt: Any) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray],
                              Dict[str, jnp.ndarray]]:
-    """A decode step's ``R`` rows ``p [R, proj_dim]``, a token a sequence:
-    ``state["conv"]`` the conv's tails as values ``[R, (K−1)·conv_dim]``,
-    and the states where they lie, ``held["ssm"] = (array [layers, slots,
-    heads/pack, d_state, pack·d_head], layer, first slot)``, row ``r``'s at
-    ``(layer, first + r)`` → (``y [R, d_ssm]`` float32, the tails going out,
-    the array with the rows' states moved one step: ``ssm_state_update``,
-    which reads ``y = S C`` off the new values)."""
-    xs, B, C, delta, A, tail = conv(dims, m, p, state["conv"], 1, valid, dt)
+    """A decode step's ``R`` rows ``p [R, proj_dim]``, a token a sequence,
+    whose state lies in the pool: ``held[part] = (array [layers, slots,
+    …], layer, first slot)``, row ``r``'s at ``(layer, first + r)``, the
+    states ``[…, heads/pack, d_state, pack·d_head]`` and, where the family
+    states it ``in_place``, the conv's tails ``[…, (K−1)·conv_dim]`` (else
+    ``state["conv"]`` holds the rows' tails as values) → (``y [R, d_ssm]``
+    float32, the tails going out if they came as values, the arrays with
+    the rows' parts moved one step where they lie: ``conv_tail_update``,
+    which emits the conv's output, and ``ssm_state_update``, which reads
+    ``y = S C`` off the new values).  A row with ``valid`` 0 moves
+    neither."""
+    d_ssm, conv_dim = dims.d_ssm, dims.conv_dim
+    values, arrays = {}, {}
+    if "conv" in held:
+        array, layer, first = held["conv"]
+        with jax.named_scope("ssm/conv"):
+            arrays["conv"], out = conv_tail_update(
+                array, layer, first, p[:, d_ssm:d_ssm + conv_dim],
+                m["conv_w"], m["conv_b"], valid)
+        xs, B, C, delta, A = _split(dims, m, out[:, None],
+                                    p[:, None, d_ssm + conv_dim:], valid)
+    else:
+        xs, B, C, delta, A, values["conv"] = conv(
+            dims, m, p, state["conv"], 1, valid, dt)
     R, _, G, k, P = xs.shape
     array, layer, first = held[SSM]
     with jax.named_scope("ssm/state_update"):
@@ -209,8 +240,8 @@ def decode(dims: Mamba2Dims, m: Any, p: jnp.ndarray,
             dx = dx.reshape(rows)
         array, y = ssm_state_update(array, layer, first, a=a, dx=dx,
                                     b=B[:, 0], c=C[:, 0])
-    return (skip(dims, m, y.reshape(xs.shape), xs), {"conv": tail},
-            {SSM: array})
+    return (skip(dims, m, y.reshape(xs.shape), xs), values,
+            dict(arrays, **{SSM: array}))
 
 
 def gated_norm(dims: Mamba2Dims, m: Any, p: jnp.ndarray, y: jnp.ndarray,
@@ -269,7 +300,7 @@ def scan_chunk(xs, B, C, delta, A, S):
 
 
 def mix(model: Any, lp: Any, x: jnp.ndarray, state: Dict[str, jnp.ndarray],
-        tokens: int, valid: jnp.ndarray, in_place: str = SSM
+        tokens: int, valid: jnp.ndarray
         ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """A family's mixer (its ``mix_in`` / ``mix_chunk`` or ``mix_decode`` /
     ``mix_out``) over ``R`` sequences' rows with their state as VALUES in
@@ -278,23 +309,22 @@ def mix(model: Any, lp: Any, x: jnp.ndarray, state: Dict[str, jnp.ndarray],
     padding: they move no state); ``state``: what each sequence holds
     coming in → (what the mixer adds to the residual ``[R·tokens, H]``, the
     state going out).  One token a sequence is a decode step's update (here
-    on a pool of one layer, the rows its slots, of the part the family
-    moves ``in_place``); more is a block of the chunk form.  The serving
-    engine, whose rows are several groups and which keeps the state itself,
-    calls the four parts."""
+    each part a pool of one layer, the rows its slots); more is a block of
+    the chunk form.  The serving engine, whose rows are several groups and
+    which keeps the state itself, calls the four parts."""
     p = model.mix_in(lp, x)
     if tokens == 1:
-        y, new, held = model.mix_decode(
-            lp, p, {"conv": state["conv"]},
-            {in_place: (state[in_place][None], 0, 0)}, valid)
-        new = dict(new, **{in_place: held[in_place][0]})
+        y, _, held = model.mix_decode(
+            lp, p, {}, {name: (part[None], 0, 0)
+                        for name, part in state.items()}, valid)
+        new = {name: array[0] for name, array in held.items()}
     else:
         y, new = model.mix_chunk(lp, p, state, tokens, valid)
     return model.mix_out(lp, p, y), new
 
 
 def mix_sequences(model: Any, lp: Any, x: jnp.ndarray, batch: int, seq: int,
-                  block: int, in_place: str = SSM) -> jnp.ndarray:
+                  block: int) -> jnp.ndarray:
     """:func:`mix` over whole sequences without a cache: ``x [batch·seq,
     H]`` → ``[batch·seq, H]``, in blocks of ``block`` tokens from a zero
     state (the last block padded)."""
@@ -306,7 +336,7 @@ def mix_sequences(model: Any, lp: Any, x: jnp.ndarray, batch: int, seq: int,
         part = jax.lax.dynamic_slice_in_dim(rows, i * block, block, 1)
         out, state = mix(
             model, lp, part.reshape(batch * block, -1), state, block,
-            jnp.full((batch,), jnp.clip(seq - i * block, 0, block)), in_place)
+            jnp.full((batch,), jnp.clip(seq - i * block, 0, block)))
         return state, out.reshape(batch, block, -1)
 
     _, outs = jax.lax.scan(one, model.zero_state(batch), jnp.arange(blocks))

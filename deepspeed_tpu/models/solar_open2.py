@@ -327,8 +327,9 @@ class SolarOpen2Model:
         return delta_rule.chunk(c.delta, lp, p, state, tokens, valid, c.dtype)
 
     def mix_decode(self, lp, p, state, held, valid):
-        c = self.config
-        return delta_rule.decode(c.delta, lp, p, state, held, valid, c.dtype)
+        del state                   # both parts are moved where they lie
+        y, arrays = delta_rule.decode(self.config.delta, lp, p, held, valid)
+        return y, {}, arrays
 
     def mix_out(self, lp: Any, p: Any, o: jnp.ndarray) -> jnp.ndarray:
         """Row-wise: the norm a head, the gate, ``out_proj`` → what the
@@ -343,7 +344,7 @@ class SolarOpen2Model:
         """The KDA part over ``R`` sequences' rows with their state as
         values in and out (``mamba2.mix``, the form both recurrences
         share)."""
-        return mamba2.mix(self, lp, x, state, tokens, valid, DELTA)
+        return mamba2.mix(self, lp, x, state, tokens, valid)
 
     # -- the experts ---------------------------------------------------------
 
@@ -415,7 +416,7 @@ class SolarOpen2Model:
                     *self.qkv(lp, x), B_, S_, c.dtype))
             else:
                 x = x + mamba2.mix_sequences(self, lp, x, B_, S_,
-                                             c.chunk_size, DELTA)
+                                             c.chunk_size)
             at[mixer] += 1
             x = self.experts(
                 dict(jax.tree.map(lambda v: v[l], moe), expert_layer=l), x,

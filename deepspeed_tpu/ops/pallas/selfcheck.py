@@ -692,6 +692,38 @@ def check_delta_state_update(s: KernelShapes, interpret: bool) -> List[Check]:
             Check("delta_state_update_o", float(o_err), 1e-4)]
 
 
+def check_conv_tail_update(s: KernelShapes, interpret: bool) -> List[Check]:
+    """The decode step's conv over the rows' held tails IN PLACE in their
+    pool, at the widest published conv (three streams of 8,192 channels,
+    four taps, no bias; a serving step's 192 rows at slots 1 …, a quarter
+    of them dead) against the ``jax.numpy`` reference run in float32: the
+    pool after, which is copies (a dead row's as it lay) and so exact, and
+    the conv's output, which the kernel rounds once to the rows' type.  The
+    interpreter walks ``slots`` rows."""
+    ctu = _mod("conv_tail_update")
+    rng = np.random.RandomState(14)
+    rows, taps, channels = (s.slots if interpret else 192), 4, 3 * 8192
+    pool = _normal(rng, (3, rows + 2, (taps - 1) * channels), s.dtype)
+    x = _normal(rng, (rows, channels), s.dtype)
+    w = _normal(rng, (taps, channels), s.dtype, 0.5)
+    valid = jnp.asarray(rng.rand(rows) < 0.75, jnp.int32)
+
+    @jax.jit
+    def errors(pool, x, w, valid):
+        want = ctu.conv_tail_update_reference(
+            pool.astype(jnp.float32), 1, 1, x.astype(jnp.float32), w, None,
+            valid)
+        # the kernel writes the pool it is given: hand it a copy, after
+        # the reference has read the original
+        got = ctu.conv_tail_update(pool + 0, 1, 1, x, w, None, valid,
+                                   interpret=interpret)
+        return [_rel_err(g, wanted) for g, wanted in zip(got, want)]
+
+    pool_err, out_err = errors(pool, x, w, valid)
+    return [Check("conv_tail_update_pool", float(pool_err), 0.0),
+            Check("conv_tail_update_out", float(out_err), DECODE_TOL)]
+
+
 def check_quantizer(s: KernelShapes, interpret: bool) -> List[Check]:
     qz = _mod("quantizer")
     rng = np.random.RandomState(6)
@@ -741,7 +773,7 @@ CHECKS = (check_flash, check_flash_streamed, check_decode, check_paged,
           check_moe_grouped,
           check_moe_share, check_moe_latent, check_ssm_state_update,
           check_ssm_state_update_lanes, check_delta_state_update,
-          check_quantizer,
+          check_conv_tail_update, check_quantizer,
           check_block_sparse)
 
 

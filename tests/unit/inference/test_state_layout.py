@@ -82,6 +82,43 @@ def test_a_part_in_place_goes_out_as_the_pools_array_and_comes_back_as_one():
     np.testing.assert_array_equal(after["conv"][0], pool["conv"][0])
 
 
+def test_a_kind_whose_every_part_is_in_place_hands_a_decode_step_no_values():
+    """What two of the three families state since PR 58 (the conv's tail
+    moved where it lies, as the state is): the hook gets every part as the pool's
+    array, and every array it hands back is put in its place."""
+    layout = StateLayout(StateKind("ssm", 3, KIND.parts, ("ssm", "conv")), 5)
+    pool = _filled(layout)
+    values, held = layout.decode_operands(pool, 2)
+    assert values == {} and sorted(held) == ["conv", "ssm"]
+    for name, (array, l, first) in held.items():
+        assert array is pool[name] and (l, first) == (2, 1)
+    moved = {name: array + 1 for name, (array, _, _) in held.items()}
+    after = layout.decode_written(pool, 2, {}, moved)
+    assert sorted(after) == ["conv", "ssm"]
+    for name in pool:
+        assert after[name] is moved[name]
+
+
+@pytest.mark.parametrize("make, part, tail_in_place", [
+    (lambda: models.FalconH1Model(models.FalconH1Config.tiny()), "ssm", True),
+    (lambda: models.NemotronHModel(models.NemotronHConfig.tiny()), "ssm",
+     False),
+    (lambda: models.SolarOpen2Model(models.SolarOpen2Config.tiny()),
+     "delta", True)], ids=["falcon-h1", "nemotron-h", "solar-open2"])
+def test_which_families_move_the_tail_where_it_lies(make, part,
+                                                    tail_in_place):
+    """Falcon-H1 and Solar-Open-2 state both parts ``in_place``; Nemotron-H
+    keeps its tail a value (PR 58's one fallback: its check's margin).  In
+    all three the conv's tail lies time-major and FLAT a slot (no dimension
+    of ``K − 1`` for the chip to tile)."""
+    kind, = adapters.make_adapter(make()).state_kinds
+    assert kind.name == part
+    assert kind.in_place == ((part, "conv") if tail_in_place else (part,))
+    shapes = {name: shape for name, shape, _ in kind.parts}
+    assert sorted(shapes) == sorted([part, "conv"])
+    assert len(shapes["conv"]) == 1
+
+
 def test_a_chunks_slot_is_read_where_it_sits_and_from_zeros_where_fresh():
     layout = StateLayout(KIND, 5)
     pool = _filled(layout)
@@ -144,7 +181,7 @@ def test_the_falcon_adapter_states_what_a_sequence_holds_a_layer():
     assert (kind.name, kind.layers) == ("ssm", 72)
     assert kind.parts == (("ssm", (32, 256, 128), jnp.float32),
                           ("conv", (3 * 5120,), jnp.bfloat16))
-    assert kind.in_place == ("ssm",)
+    assert kind.in_place == ("ssm", "conv")
     attention, = adapters.make_adapter(model).kinds
     assert (attention.kv_heads, attention.k_dim, attention.window,
             attention.ring) == (4, 128, None, False)

@@ -104,9 +104,9 @@ def test_a_published_layer_may_be_two_entries_of_the_pattern():
         (), ("kv", adapters.FFN) + ("delta", adapters.FFN) * 3, 2)
     kind, = solar.state_kinds
     assert (kind.name, kind.layers, kind.beside, kind.in_place) == (
-        "delta", 6, None, ("delta",))
+        "delta", 6, None, ("delta", "conv"))
     assert dict((name, shape) for name, shape, _ in kind.parts) == {
-        "delta": (4, 16, 16), "conv": (3, 192)}
+        "delta": (4, 16, 16), "conv": (3 * 192,)}
     attention, = solar.kinds
     assert (attention.layers, attention.theta, attention.kv_heads) == (
         2, None, 2)
@@ -138,6 +138,8 @@ def test_the_layers_run_by_part_are_gauges_set_when_a_program_is_traced():
     from deepspeed_tpu import telemetry
 
     tel = telemetry.get_telemetry()
+    # another file of this worker may have left counters in the registry
+    tel.reset()
     tel.configure(enabled=True, jsonl=False, prometheus=False)
     try:
         model = models.NemotronHModel(models.NemotronHConfig.tiny())

@@ -122,12 +122,11 @@ def test_padded_positions_move_no_state_and_a_decode_step_is_one_token(valid):
         held, outs = dict(state), []
         for t in range(T):
             live = (t < valid).astype(jnp.int32)
-            y, tail, arrays = delta_rule.decode(
-                dims, m, at(p, t), {"conv": held["conv"]},
-                {delta_rule.DELTA: (held[delta_rule.DELTA][None], 0, 0)},
-                live, F32)
-            held = {"conv": tail["conv"],
-                    delta_rule.DELTA: arrays[delta_rule.DELTA][0]}
+            y, arrays = delta_rule.decode(
+                dims, m, at(p, t),
+                {name: (part[None], 0, 0) for name, part in held.items()},
+                live)
+            held = {name: array[0] for name, array in arrays.items()}
             outs.append(y)
     for name in new:
         np.testing.assert_allclose(new[name], held[name], rtol=2e-4,

@@ -3,9 +3,10 @@ AND experts (``models/solar_open2.py``: a gated delta rule or a gated
 attention, then the experts), compiled for a described v5e with no chip
 (``test_tpu_compile_parts.py``'s way): the delta rule's state pool has the
 KDA layers alone and rides the layer scan in place, moved by its own
-kernel; the expert stacks ride whole and are read at their layer by the
-grouped kernels.  No instruction makes a value of a pool's or a stack's
-size."""
+kernel, and so does the conv's tail beside it (PR 58); the expert stacks
+ride whole and are read at their layer by the grouped kernels.  No
+instruction makes a value of a pool's or a stack's size, nor of one layer
+of the tails' pool."""
 
 import functools
 import re
@@ -16,12 +17,17 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu import models
+from deepspeed_tpu.ops.pallas import conv_tail_update as ctu
 from deepspeed_tpu.ops.pallas import delta_state_update as dsu
 from deepspeed_tpu.ops.pallas import moe_grouped_matmul as gm
 from deepspeed_tpu.ops.pallas import paged_attention as pa
 from test_tpu_compile_parts import _values_made, one_chip  # noqa: F401
 
-SLOTS, PAGES, HELD = 16, 64, 8
+#: the serving cell's slots: 193 rows a layer of a state pool.  (Of 17 the
+#: chip's compiler would rather tile the tails' pool by its three LAYERS,
+#: and of 24 it keeps the whole 10 MB pool in VMEM: neither is the cell's
+#: program.)
+SLOTS, PAGES, HELD = 192, 64, 8
 
 
 @pytest.fixture(scope="module")
@@ -42,7 +48,7 @@ def program(one_chip):
         tree)
     arg = lambda shape, dt=jnp.int32: placed(jax.ShapeDtypeStruct(shape, dt))
     mp = pytest.MonkeyPatch()
-    for module in (pa, dsu, gm):
+    for module in (pa, dsu, ctu, gm):
         mp.setattr(module, "reference_off_tpu", lambda interpret: False)
     real_pool = ev2.init_kv_pool
     mp.setattr(ev2, "init_kv_pool",
@@ -81,15 +87,15 @@ def test_the_delta_pool_has_the_kda_layers_and_is_moved_in_place(program):
     state = pools["delta"]["delta"]
     assert state.shape == (3, SLOTS + 1, 64, 128, 128)
     assert state.dtype == jnp.float32
-    assert pools["delta"]["conv"].shape == (3, SLOTS + 1, 3, 24576)
+    assert pools["delta"]["conv"].shape == (3, SLOTS + 1, 3 * 24576)
     assert pools["kv"]["k"].shape == (1, PAGES, 128, 8, 128)
     held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                for pool in pools.values() for a in pool.values())
-    # every pool aliased in and out; the conv's tail [3, 24576] lies in
-    # tiles of four rows on the chip (a third more of its 147 KB a slot)
+    # every pool aliased in and out; the conv's tails lie flat, in tiles
+    # of eight slots by 128 channels (the last tile's seven rows padding)
     tail = pools["delta"]["conv"]
-    assert memory.alias_size_in_bytes == held + int(np.prod(tail.shape)) \
-        * tail.dtype.itemsize // 3
+    assert memory.alias_size_in_bytes == held + (-(SLOTS + 1) % 8) \
+        * 3 * tail.shape[2] * tail.dtype.itemsize
     dims = lambda *shape: ",".join(str(n) for n in shape)
     whole = {dims(*state.shape), dims(*state.shape[1:]),
              dims(SLOTS, *state.shape[2:])}
@@ -100,6 +106,27 @@ def test_the_delta_pool_has_the_kda_layers_and_is_moved_in_place(program):
     assert moved == [dims(*state.shape)] * 3, moved
     assert len(re.findall(r"paged_decode_attention[\w.]* = ", text)) == 1
     assert "ssm_state_update" not in text
+
+
+def test_the_convs_tails_are_moved_where_they_lie_by_a_call_a_layer(program):
+    """PR 58: the decode rows' conv reads a slot's tail once and writes it
+    back shifted in the pool itself; as values the tails of a layer were
+    sliced out, concatenated with the rows, gathered a row and written
+    back (``bf16[SLOTS,4,24576]`` and its neighbours).  This is where a
+    relayout copy of the pool would show."""
+    engine, text, _, _ = program
+    tail = engine.pool["delta"]["conv"]
+    dims = lambda *shape: ",".join(str(n) for n in shape)
+    layer = {dims(*tail.shape), dims(*tail.shape[1:]),
+             dims(SLOTS, tail.shape[2]), dims(SLOTS, 3, 24576),
+             dims(SLOTS, 4, 24576), dims(SLOTS + 1, 3, 24576)}
+    assert _values_made(text, "bf16", layer, "conv_tail_update") == []
+    # a call a KDA layer on the pool itself (its result IS the pool)
+    moved = re.findall(r"conv_tail_update[\w.]* = \(bf16\[([\d,]+)\]", text)
+    assert moved == [dims(*tail.shape)] * 3, moved
+    calls = [line for line in text.splitlines()
+             if re.search(r"conv_tail_update[\w.]* = \(", line)]
+    assert all("output_to_operand_aliasing" in line for line in calls)
 
 
 def test_the_expert_stacks_are_read_where_they_lie(program):

@@ -15,10 +15,15 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu import models
+from deepspeed_tpu.ops.pallas import conv_tail_update as ctu
 from deepspeed_tpu.ops.pallas import paged_attention as pa
 from deepspeed_tpu.ops.pallas import ssm_state_update as ssu
 
-SLOTS, PAGES, LAYERS = 24, 512, 2
+#: the serving cell's slots and layers: 97 rows a layer of a state pool.
+#: (Of 25 rows in two layers the chip's compiler would rather tile the
+#: tails' pool by its LAYERS and re-lay it out around the kernel that moves
+#: it: not the cell's program.)
+SLOTS, PAGES, LAYERS = 96, 512, 6
 
 
 @pytest.fixture(scope="module")
@@ -52,6 +57,7 @@ def program(one_chip):
     mp = pytest.MonkeyPatch()
     mp.setattr(pa, "reference_off_tpu", lambda interpret: False)
     mp.setattr(ssu, "reference_off_tpu", lambda interpret: False)
+    mp.setattr(ctu, "reference_off_tpu", lambda interpret: False)
     real_pool = ev2.init_kv_pool
     mp.setattr(ev2, "init_kv_pool",
                lambda ad, cc: jax.eval_shape(lambda: real_pool(ad, cc)))
@@ -90,7 +96,10 @@ def test_the_state_pool_is_carried_and_written_in_place_on_v5e(program):
     # planned beside them
     held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                for pool in pools.values() for a in pool.values())
-    assert memory.alias_size_in_bytes == held
+    # (the conv's tails lie in tiles of eight slots: seven rows padding)
+    tail = pools["ssm"]["conv"]
+    assert memory.alias_size_in_bytes == held + (-(SLOTS + 1) % 8) \
+        * LAYERS * tail.shape[2] * tail.dtype.itemsize
     assert memory.temp_size_in_bytes < 0.25 * held
     # no instruction makes a value of the pool's size, or of half of it,
     # but the ones that pass the buffer on or write into it in place
@@ -126,3 +135,49 @@ def test_the_state_pool_is_carried_and_written_in_place_on_v5e(program):
     # a paged attention call a layer for the decode rows; the dense kind's
     # chunk rows gather their pages in XLA
     assert len(re.findall(r"paged_decode_attention[\w.]* = ", text)) == 1
+
+
+def test_the_convs_tails_are_moved_where_they_lie_by_the_kernel(program):
+    """PR 58: the decode rows' conv reads a slot's tail once and writes it
+    back shifted in the pool itself (``conv_tail_update``, a call a layer of
+    the scan, aliased); no instruction makes a value of the tails' pool nor
+    of one layer of it.  This is where a relayout copy of the pool would
+    show."""
+    from test_tpu_compile_parts import _values_made
+
+    engine, text, _ = program
+    tail = engine.pool["ssm"]["conv"]
+    assert tail.shape == (LAYERS, SLOTS + 1, 3 * 5120)
+    dims = lambda *shape: ",".join(str(n) for n in shape)
+    layer = {dims(*tail.shape), dims(*tail.shape[1:]),
+             dims(SLOTS, tail.shape[2]), dims(SLOTS, 3, 5120),
+             dims(SLOTS, 4, 5120)}
+    assert _values_made(text, "bf16", layer, "conv_tail_update") == []
+    calls = [line for line in text.splitlines()
+             if re.search(r"conv_tail_update[\w.]* = \(", line)]
+    assert len(calls) == 1 and f"(bf16[{dims(*tail.shape)}]" in calls[0]
+    assert "output_to_operand_aliasing" in calls[0]
+
+
+@pytest.mark.parametrize("shape, dtype", [
+    ((6, 97, 15360), jnp.bfloat16), ((5, 129, 30720), jnp.bfloat16),
+    ((3, 193, 73728), jnp.bfloat16), ((36, 193, 73728), jnp.bfloat16),
+    ((72, 96, 15360), jnp.bfloat16), ((3, 33, 73728), jnp.bfloat16),
+    ((72, 97, 15360), jnp.bfloat16), ((8, 193, 73728), jnp.bfloat16),
+    ((4, 97, 15360), jnp.bfloat16), ((2, 129, 30720), jnp.bfloat16),
+    ((3, 17, 73728), jnp.bfloat16), ((2, 25, 15360), jnp.bfloat16),
+    ((8, 97, 15360), jnp.float32), ((3, 17, 1536), jnp.float32)],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else "")
+def test_the_rule_for_the_tails_pools_order_is_the_compilers(one_chip, shape,
+                                                            dtype):
+    """``conv_tail_update`` takes the pool only where the chip holds it
+    row-major (``rows_on_sublanes``): the three serving cells' pools, and
+    not a pool whose layers pad less than its slots.  The rule is the
+    compiler's own choice for a donated argument of that shape."""
+    compiled = jax.jit(lambda a: a.at[1, 2].add(1), donate_argnums=0).lower(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)).compile()
+    order = re.search(r"entry_computation_layout=\{\(\w+\[[\d,]+\]\{([\d,]+)",
+                      compiled.as_text()).group(1)
+    assert order in ("2,1,0", "2,0,1")
+    assert ctu.rows_on_sublanes(*shape[:2], jnp.dtype(dtype).itemsize) \
+        == (order == "2,1,0")
