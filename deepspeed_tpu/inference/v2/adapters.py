@@ -71,6 +71,19 @@ the engine's and the layout's.  The kind's branch is a layer of its own
 where the pattern names the kind, or runs BESIDE an attention kind's layer
 on the same input (``StateKind.beside``: :class:`FalconH1V2Adapter`); the
 other families state none and their programs hold nothing of it.
+
+**A layer that drafts.**  A model with a multi-token-prediction layer
+states it as ``draft`` (:class:`DraftLayer`: the attention kind whose pool
+holds its keys and its layer there, after the trunk's) and gives three
+hooks: ``draft_in`` (the trunk's output after the final norm and the
+tokens that FOLLOW its rows → the layer's input), ``draft_layer`` (its
+``lp``, a layer of that kind run through ``qkv`` / ``post_attn`` as any
+other) and ``draft_logits``.  The engine then decodes TWO rows a sequence a
+step, the newest token and its draft, emits one token or two and runs the
+layer for the next draft, all in the program (``engine_v2._draft_burst_fn``);
+an engine drafts because its adapter states the layer and for no other
+reason.  :class:`ExaoneMoeV2Adapter` is the family that does; the others
+state none and their programs hold nothing of it.
 """
 
 from __future__ import annotations
@@ -145,6 +158,18 @@ class StateRows:
     starts from zeros, is the engine's and the layout's."""
     tokens: int
     valid: jnp.ndarray              # [R] int32
+
+
+@dataclasses.dataclass(frozen=True)
+class DraftLayer:
+    """A model's multi-token-prediction layer as the engine sees it: one
+    attention layer of ``kind`` BEHIND the trunk, whose keys are layer
+    ``at`` of that kind's pool (the kind's ``layers`` counts it), run on
+    every row a step emits and on a prefill chunk's rows.  ``name`` is the
+    part's in the engine's gauges (``inference/layers/<name>``)."""
+    name: str
+    kind: str
+    at: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,6 +249,11 @@ class ModelAdapterV2:
     def state_kinds(self) -> Tuple[StateKind, ...]:
         """The recurrent state a sequence carries beside its keys: none."""
         return ()
+
+    @property
+    def draft(self) -> Optional[DraftLayer]:
+        """The layer that drafts the token after next: none."""
+        return None
 
     # -- jit-side hooks -----------------------------------------------------
 
@@ -327,6 +357,25 @@ class ModelAdapterV2:
 
     def logits(self, params: Any, x: jnp.ndarray) -> jnp.ndarray:
         """LM head: ``[N, H]`` → fp32 ``[N, V]``."""
+        raise NotImplementedError
+
+    def draft_in(self, params: Any, u: jnp.ndarray, tokens: jnp.ndarray
+                 ) -> jnp.ndarray:
+        """Where the model states a :class:`DraftLayer`, row-wise: ``u [N,
+        H]`` (:meth:`finalize`'s result) and the token that FOLLOWS each
+        row ``[N]`` → the layer's input ``[N, H]``, at the rows' own
+        positions."""
+        raise NotImplementedError
+
+    def draft_layer(self, params: Any) -> Any:
+        """The drafting layer's ``lp``: a layer of ``draft.kind``, run
+        through :meth:`qkv` and :meth:`post_attn` as a leading layer is
+        (``l`` None)."""
+        raise NotImplementedError
+
+    def draft_logits(self, params: Any, y: jnp.ndarray) -> jnp.ndarray:
+        """The drafting layer's output ``[N, H]`` → fp32 ``[N, V]``: the
+        token after the one that follows the row."""
         raise NotImplementedError
 
 
@@ -592,6 +641,48 @@ class PanguUltraMoeV2Adapter(MimoV2Adapter):
         return self.model.qkv(lp, x, positions)
 
 
+class ExaoneMoeV2Adapter(MimoV2Adapter):
+    """K-EXAONE (``models/exaone_moe.py``): window layers (rings, rotary)
+    and full layers (every key, NO rotary: ``theta`` None) of the same
+    head counts, Q and K normed a head inside ``qkv``, no sink; sparse
+    layers with a scaled router and a shared expert behind a leading dense
+    one; and the family's multi-token-prediction layer as the model's
+    :class:`DraftLayer`: a full-attention sparse layer whose keys are one
+    more layer of the full kind's pool."""
+
+    @property
+    def kinds(self) -> Tuple[AttentionKind, ...]:
+        from ...models.exaone_moe import FULL, WINDOW
+
+        c, plan = self.config, self.plan
+        heads = (c.num_kv_heads, c.head_dim, c.head_dim)
+        return (
+            AttentionKind(FULL, plan.count(FULL) + (self.draft is not None),
+                          *heads),
+            AttentionKind(WINDOW, plan.count(WINDOW), *heads,
+                          window=c.sliding_window, theta=c.rope_theta,
+                          ring=True))
+
+    @property
+    def draft(self) -> Optional[DraftLayer]:
+        from ...models.exaone_moe import FULL, MTP
+
+        if not self.config.num_nextn_predict_layers:
+            return None
+        return DraftLayer(MTP, FULL, self.plan.count(FULL))
+
+    def draft_in(self, params, u, tokens):
+        return self.model.draft_in(params, u, tokens)
+
+    def draft_layer(self, params):
+        from ...models.exaone_moe import MTP
+
+        return params[MTP]["layer"]
+
+    def draft_logits(self, params, y):
+        return self.model.draft_logits(params, y)
+
+
 class FalconH1V2Adapter(ModelAdapterV2):
     """Falcon-H1 (``models/falcon_h1.py``): a Mamba-2 mixer beside a
     grouped-query attention in every layer, both on the same normed input.
@@ -759,6 +850,7 @@ class SolarOpen2V2Adapter(NemotronHV2Adapter):
 
 
 _REGISTRY = {
+    "ExaoneMoeModel": ExaoneMoeV2Adapter,
     "FalconH1Model": FalconH1V2Adapter,
     "LlamaModel": LlamaV2Adapter,
     "MimoV2Model": MimoV2Adapter,

@@ -11,8 +11,10 @@ is ONE program call:
 
 * ``decode_burst`` of ``k`` steps — ``k`` successive decode steps for all
   ``max_batch_slots`` sequences in one device program: sampling happens
-  in-graph (greedy or temperature) and only ``[k, B]`` int32 token ids
-  return to the host — no per-token logits round-trip.
+  in-graph (greedy or temperature) and only int32 token ids return to the
+  host — no per-token logits round-trip: ``[k, B]``, a token a sequence a
+  step, or, of a model that drafts (below), ``[k, B, 2]``, a step's one
+  or two.
   Page tables are fully reserved at admission (prompt + generation budget),
   so a burst never needs host page allocation mid-flight.
 * the ONE-step decode program **carrying the round's prefill chunks** —
@@ -68,10 +70,14 @@ commit, the plan, the packs, the dispatch and whatever the front-end does
 between two rounds all run under it.  Two things make that possible.  The
 scheduler plans from what has been DISPATCHED, beside what has been
 committed (``Request.ahead_*``): everything a plan reads is settled once a
-call is out, except an EOS.  And a row whose newest token is still on the
-device takes it there: every program returns its rows' newest tokens as
-one small array, the next call's argument, and a host-packed map says
-which rows read it (``_dispatch``).  The invariant that keeps this safe:
+call is out, except an EOS and, of a model that drafts, how MANY tokens the
+call yields (planned is the least, a token a step).  And a row whose
+newest token is still on the device takes it there: every program returns
+its rows' newest tokens as one small array, the next call's argument, and
+a host-packed map says which rows read it (``_dispatch``); a drafting
+engine's rows take their token, their draft AND their length there
+(``_seq``), always, since the host cannot know a length it has not
+fetched.  The invariant that keeps this safe:
 **a call is committed by what it was packed under** (``_settle``): a row
 whose request ended, was cancelled, preempted or moved between dispatch
 and commit is passed over, as a burst's surplus tokens always were.  And
@@ -79,6 +85,25 @@ it relies on the device running calls **in the order of dispatch**: pages
 a request gives back are handed out at once, while a call in flight may
 still write them, because the call that writes the next owner's keys
 comes later.  ``step`` and ``settle`` leave nothing in flight.
+
+**A step of one token or two.**  Where the adapter states a layer that
+drafts (``adapters.DraftLayer``: a multi-token-prediction layer behind the
+trunk), the two programs are :meth:`_draft_burst_fn`'s: a decode row is TWO
+rows, the sequence's newest token and the draft of the one after it, one
+grid row of two tokens in the paged kernel; the trunk's sample of the first
+is emitted, and the second's too where the first IS the draft (greedy; at
+a temperature no draft is accepted and the stream is one-token
+sampling's); the drafting layer then runs behind the trunk, on the decode
+rows and on a chunk's rows alike, its keys one more layer of its kind's
+pool, and leaves the next draft.  A rejected draft's key is overwritten by
+the next step's first row; a ring is sized for the two rows
+(``KVCacheConfig.with_rings``).  The scheduler commits what a call yielded
+(``decode_burst_done``), a budget may end between a step's two tokens, and
+a call is committed by the LENGTH it found (returned with its tokens).
+Such a model's seat holds part of its sequence (``state_slots``): no shared
+prefix, and a preempted request starts over.  The other adapters state no
+such layer and their programs hold nothing of it
+(``tests/unit/inference/test_v2_programs_unchanged.py``).
 
 A call is numbered as it is dispatched (``_Call.call``), and with the
 telemetry hub on its spans in the two rounds carry that number; the hub's
@@ -136,7 +161,9 @@ class _Call(NamedTuple):
     chunks: list                # the prefill chunks riding in it
     decode: List[_Row]          # the rows decoding in it
     steps: int                  # 1 (it may carry chunks) or the burst
-    outputs: tuple              # on the device: (tokens, firsts, gate stats)
+    #: on the device: (tokens, firsts, gate stats, the rows' lengths as the
+    #: call found them: a drafting engine's, else None)
+    outputs: tuple
     eos_token_id: Optional[int]     # the EOS id to accept under
     call: int                   # its number, the engine's count of calls
     ahead: bool                 # dispatched with the call before uncommitted
@@ -221,9 +248,24 @@ class RaggedInferenceEngineV2:
                 f"({[k.name for k in self.adapter.state_kinds]}): its "
                 f"branch is not split over the tensor axis (heads over "
                 f"chips, groups replicated: ROADMAP R7)")
+        #: the adapter's layer that drafts (``adapters.DraftLayer``), or
+        #: None: the engine decodes two rows a sequence a step because the
+        #: model has such a layer, and for no other reason
+        self.draft = self.adapter.draft
+        if self.draft is not None and (self._tp > 1
+                                       or self.adapter.state_kinds):
+            raise NotImplementedError(
+                "a drafting layer under tensor-parallel serving, or beside "
+                "a recurrent state: neither is built (ROADMAP R8)")
+        # a drafting sequence's newest token, draft and length lie in its
+        # batch slot on the device (``_seq``), as a recurrent state does:
+        # its pages alone are not the sequence
         self.cache_config = self.cache_config.with_rings(
-            self.kinds.values(), max_batch_slots, prefill_chunk
-        ).with_state(self.adapter.state_kinds, max_batch_slots)
+            self.kinds.values(), max_batch_slots, prefill_chunk,
+            row_tokens=1 if self.draft is None else 2
+        ).with_state(self.adapter.state_kinds
+                     or (() if self.draft is None else (self.draft,)),
+                     max_batch_slots)
         #: every access to a kind's pool: no code here indexes a pool array
         self.layouts = kv_layouts(self.adapter, self.cache_config)
         #: the same for the recurrent state a model carries beside its
@@ -293,6 +335,15 @@ class RaggedInferenceEngineV2:
         #: of a program has the one signature
         self._newest = np.zeros((max_batch_slots + self.prefill_batch,),
                                 np.int32)
+        #: a drafting engine's sequences AS THE LAST CALL DISPATCHED LEAVES
+        #: THEM, by batch slot: the position of the newest token, that
+        #: token and the draft of the one after it.  The next call's
+        #: argument and result: the host does not know how far a call in
+        #: flight gets (one token a step or two), so it packs no length; a
+        #: sequence's last prefill chunk seats it here
+        self._seq = None if self.draft is None else {
+            name: np.zeros((max_batch_slots,), np.int32)
+            for name in ("len", "tok", "draft")}
         #: program calls dispatched so far: the next call's number
         self._calls = 0
         #: MoE serving telemetry (ISSUE 19): when the model routes through
@@ -572,14 +623,16 @@ class RaggedInferenceEngineV2:
 
         return positions.reshape(-1), (Bp * C, write_fn, attend_fn, mix_fn)
 
-    def _paged_attend(self, q, pool, kind, l, sink, tables, lengths):
+    def _paged_attend(self, q, pool, kind, l, sink, tables, lengths,
+                      rows="chunk"):
         """Queries ``q [R, h, k_dim]``, a token a row, over layer ``l`` of
         a kind's pool through the paged kernel: row ``r`` attends over its
         first ``lengths[r]`` keys through ``tables[r]`` (the decode rows of
         every kind); or ``q [R, T, h, k_dim]``, ``T`` consecutive tokens a
-        row and ``lengths[r]`` the last one's (the chunk rows of a latent
-        kind).  The operands are the layout's; the route and its witnesses
-        are decided here."""
+        row and ``lengths[r]`` the last one's (``rows``: the ``"chunk"``
+        rows of a latent kind, or a drafting engine's ``"decode"`` rows,
+        the newest token and its draft).  The operands are the layout's;
+        the route and its witnesses are decided here."""
         layout = self.layouts[kind.name]
         k, v, layer_tables, options, widths = layout.kernel_operands(
             pool, l, tables)
@@ -596,9 +649,13 @@ class RaggedInferenceEngineV2:
                      "the kernel refused their shapes")
         if impl != "reference":
             name, tokens = kind.name, 1
-            if q.ndim == 4:     # a chunk's rows: their own step
-                name, tokens = f"{name}/chunk", q.shape[1]
-                self.last_attn_query_tokens[kind.name] = tokens
+            if q.ndim == 4:     # several tokens a row: their own step
+                tokens = q.shape[1]
+                if rows == "chunk":
+                    name = f"{name}/chunk"
+                    self.last_attn_query_tokens[kind.name] = tokens
+                else:
+                    self.last_attn_query_tokens[f"{name}/{rows}"] = tokens
             self.last_attn_pages_per_step[name] = pages_per_step(
                 *shapes, tokens)
         if self._tp > 1:
@@ -654,6 +711,33 @@ class RaggedInferenceEngineV2:
 
         return wp.shape[0], write_fn, attend_fn, mix_fn
 
+    def _draft_rows(self, tables_of, wp):
+        """:meth:`_decode_rows` for a drafting step: ``wp [B, T]``, row
+        ``r``'s ``T`` consecutive tokens (the newest and its draft) write
+        at ``wp[r]`` and attend as ONE row of the paged kernel, ``T``
+        tokens a grid row (``q [B, T, h, d]``: token ``t`` sees ``wp[r, -1]
+        + 1 − (T − 1 − t)`` keys, under a window its own).  The program's
+        rows are ``[B·T]``, a sequence's tokens side by side.  Where
+        ``max_pos`` clamped both onto one position (a row past its
+        budget) the scatter keeps either: nobody reads it."""
+        B, T = wp.shape
+        bs = self.cache_config.block_size
+        flat = wp.reshape(-1)
+        page_ids = {name: table[jnp.repeat(jnp.arange(B), T), flat // bs]
+                    for name, table in tables_of.items()}
+
+        def write_fn(pool, kind, l, kk, vv):
+            return self.layouts[kind.name].write_rows(
+                pool, l, page_ids[kind.name], flat % bs, kk, vv)
+
+        def attend_fn(q, pool, kind, l, sink):
+            out = self._paged_attend(
+                q.reshape((B, T) + q.shape[1:]), pool, kind, l, sink,
+                tables_of[kind.name], wp[:, -1] + 1, rows="decode")
+            return out.reshape((B * T,) + out.shape[2:])
+
+        return B * T, write_fn, attend_fn, None
+
     @staticmethod
     def _beside(parts):
         """``parts``: ``(rows, write_fn, attend_fn, mix_fn)`` of each group
@@ -694,6 +778,16 @@ class RaggedInferenceEngineV2:
 
         return (write_fn, attend_fn,
                 mix_fn if parts[0][3] is not None else None)
+
+    @staticmethod
+    def _last_of_chunks(x, c_last, C):
+        """``x [Bp·C + rows, …]``, the chunks' rows in front: each chunk's
+        row ``c_last`` (its last valid one), then the other rows."""
+        Bp = c_last.shape[0]
+        last = jnp.take_along_axis(
+            x[:Bp * C].reshape(Bp, C, -1), c_last[:, None, None],
+            axis=1)[:, 0]
+        return jnp.concatenate([last, x[Bp * C:]])
 
     def _decode_burst_fn(self, params, pool, tokens, fed, kv_lens, tables,
                          max_pos, temperature, key, rings=None, chunks=None,
@@ -757,10 +851,7 @@ class RaggedInferenceEngineV2:
                                         *self._beside(parts))
             if chunks is not None:
                 # of a chunk's rows only the last valid one is sampled
-                last = jnp.take_along_axis(
-                    x[:Bp * C].reshape(Bp, C, -1), c_last[:, None, None],
-                    axis=1)[:, 0]
-                x = jnp.concatenate([last, x[Bp * C:]])
+                x = self._last_of_chunks(x, c_last, C)
             x = ad.finalize(params, x)
             sampled = _sample(ad.logits(params, x), temperature, key)
             nxt = sampled[-B:]
@@ -778,11 +869,143 @@ class RaggedInferenceEngineV2:
             if firsts is None else firsts])
         return toks, pool, self._pack_moe_stats(n_steps), firsts, newest
 
+    def _draft_burst_fn(self, params, pool, seq, live, tables, max_pos,
+                        temperature, key, rings=None, chunks=None, *,
+                        n_steps: int, kb: Optional[int] = None):
+        """:meth:`_decode_burst_fn` of a model with a drafting layer
+        (``adapters.DraftLayer``): ``n_steps`` steps of TWO rows a
+        sequence, each yielding one token or two.
+
+        ``seq``: ``{"len", "tok", "draft"} [B]``, the sequences as the call
+        before left them, on the device (``self._seq``): the position of
+        each one's newest token, the token, and the draft of the one after
+        it.  ``live [B]``: the slots whose request decodes in this call;
+        the others' rows are dead (position 0 of page 0) and their ``seq``
+        stays.  A step runs the trunk on rows ``(tok, len)`` and ``(draft,
+        len + 1)``, both clamped at ``max_pos``; the first row's sample
+        ``a`` is emitted; if it IS the draft (and the call is greedy: at a
+        temperature above 0 no draft is accepted, so the stream is
+        one-token sampling's, token for token in distribution), the second
+        row's sample ``a'`` is emitted too.  The drafting layer then runs
+        on both rows (input: the row's ``u`` and the token that follows
+        it, ``a`` and ``a'``), writes its keys at the rows' positions in
+        its layer of its kind's pool, and the last emitted row's gives the
+        next draft.  A rejected draft leaves a key at ``len + 1`` in every
+        layer, the drafting layer's too: the next step's first row is
+        written there before anything reads it.
+
+        ``chunks``: as :meth:`_decode_burst_fn`'s, and behind them
+        ``(follow [Bp], seat [Bp])``: the drafting layer runs over a
+        chunk's rows too, row ``i`` reading prompt token ``i + 1``; the
+        chunk's last valid row reads ``follow`` (the next chunk's first
+        token) or, where ``follow < 0`` (the prompt's last chunk), the
+        first token the call samples, and its draft seats the sequence in
+        ``seq[seat]`` (``seat = B``: no sequence's).  So the trunk's ``u``
+        never leaves the call.
+
+        Returns (ids ``[n_steps, B, 2]``, −1 where a step emitted no
+        second token; ``seq["len"]`` as it came in; pools; the gate's
+        stats; the chunks' first tokens ``[Bp]`` or None; ``seq`` going
+        out)."""
+        ad, draft = self.adapter, self.draft
+        B = live.shape[0]
+        tables_of = {name: layout.row_tables(tables, rings)
+                     for name, layout in self.layouts.items()}
+        if chunks is not None:
+            if n_steps != 1:
+                raise ValueError("chunks ride in the one-step program")
+            (c_tokens, c_tables, c_start, c_last, c_rings,
+             c_follow, c_seat) = chunks
+            Bp, C = c_tokens.shape
+            c_pos, riding = self._chunk_rows(c_tokens, c_tables, c_start,
+                                             c_rings, kb)
+
+        def sampled_rows(x):
+            """The rows that go through the head: of a chunk's only its
+            last valid one, then every decode row."""
+            return x if chunks is None else self._last_of_chunks(x, c_last, C)
+
+        def one_step(carry, key):
+            seq, pool = carry
+            step_mark = numerics.scan_mark()
+            wp = jnp.minimum(
+                jnp.where(live, seq["len"], 0)[:, None] + jnp.arange(2),
+                max_pos[:, None])                   # [B, 2]
+            ids = jnp.stack([seq["tok"], seq["draft"]], axis=1).reshape(-1)
+            pos = wp.reshape(-1)
+            parts = [self._draft_rows(tables_of, wp)]
+            if chunks is not None:
+                ids = jnp.concatenate([c_tokens.reshape(-1), ids])
+                pos = jnp.concatenate([c_pos, pos])
+                parts.insert(0, riding)
+            write_fn, attend_fn, _ = self._beside(parts)
+            x = ad.embed(params, ids, pos)
+            x, pool = self._scan_layers(params, pool, x, pos, write_fn,
+                                        attend_fn)
+            u = ad.finalize(params, x)
+            sampled = _sample(ad.logits(params, sampled_rows(u)),
+                              temperature, key)
+            own = sampled[-2 * B:].reshape(B, 2)
+            firsts = sampled[:-2 * B] if chunks is not None else None
+            accept = live & (own[:, 0] == seq["draft"]) & (temperature <= 0)
+            # the drafting layer on every row: the token that follows it
+            follows = own.reshape(-1)
+            if chunks is not None:
+                after = jnp.where(c_follow < 0, firsts, c_follow)
+                shifted = jnp.concatenate(
+                    [c_tokens[:, 1:], jnp.zeros((Bp, 1), jnp.int32)], axis=1)
+                follows = jnp.concatenate([jnp.where(
+                    jnp.arange(C)[None, :] == c_last[:, None],
+                    after[:, None], shifted).reshape(-1), follows])
+            mark = numerics.scan_mark()
+            with jax.named_scope(draft.name):
+                y, pool = self._layer_step(
+                    params, ad.draft_layer(params), None, draft.kind,
+                    draft.at, pool, ad.draft_in(params, u, follows), pos,
+                    write_fn, attend_fn)
+                drafts = jnp.argmax(
+                    ad.draft_logits(params, sampled_rows(y)),
+                    axis=-1).astype(jnp.int32)
+            # its gate's stats: one more sparse layer behind the scan's
+            for name, value in (numerics.scan_drain(mark) or {}).items():
+                numerics.active().add(name.partition(":")[2], value[None])
+            d_own = drafts[-2 * B:].reshape(B, 2)
+            pick = lambda pair: jnp.where(accept, pair[:, 1], pair[:, 0])
+            seq = {"len": seq["len"] + jnp.where(live, 1 + accept, 0),
+                   "tok": jnp.where(live, pick(own), seq["tok"]),
+                   "draft": jnp.where(live, pick(d_own), seq["draft"])}
+            emitted = jnp.stack(
+                [own[:, 0], jnp.where(accept, own[:, 1], -1)], axis=1)
+            seats = (firsts, drafts[:-2 * B]) if chunks is not None else None
+            return (seq, pool), (emitted, seats, numerics.scan_drain(
+                step_mark))
+
+        lens_in = seq["len"]
+        (seq, pool), (toks, seats, stats) = jax.lax.scan(
+            one_step, (seq, pool), jax.random.split(key, n_steps))
+        numerics.scan_collect(stats, combine=True)  # mean over the burst
+        self.last_layers_by_part[draft.name] = 1
+        firsts = None
+        if seats is not None:
+            # a prompt's last chunk seats its sequence: the prompt's length,
+            # the first token and its draft
+            firsts, first_drafts = seats[0][0], seats[1][0]
+            hit = c_seat[:, None] == jnp.arange(B)[None, :]     # [Bp, B]
+            seated = {"len": c_start + c_last + 1, "tok": firsts,
+                      "draft": first_drafts}
+            seq = {name: jnp.where(
+                hit.any(axis=0),
+                jnp.sum(jnp.where(hit, seated[name][:, None], 0), axis=0),
+                seq[name]) for name in seq}
+        return (toks, lens_in, pool, self._pack_moe_stats(n_steps), firsts,
+                seq)
+
     def _decode(self, n_steps: int) -> Callable:
         fn = self._decode_jits.get(n_steps)
         if fn is None:
-            fn = tracked_jit(functools.partial(self._decode_burst_fn,
-                                               n_steps=n_steps),
+            body = (self._decode_burst_fn if self.draft is None
+                    else self._draft_burst_fn)
+            fn = tracked_jit(functools.partial(body, n_steps=n_steps),
                              "inference_v2/decode_burst",
                              tracker=get_compile_tracker(),
                              static_context={"n_steps": n_steps},
@@ -829,9 +1052,16 @@ class RaggedInferenceEngineV2:
         blocks = []
         for name, entries in sorted(by_name.items()):
             periods = entries[0].shape[0]
+            # behind the scan's: a drafting layer's, one row of its own
+            # (where the scan is one period long it stacks like theirs)
+            behind = [e.reshape(e.shape[0], -1) for e in entries
+                      if e.shape[0] != periods]
+            entries = [e for e in entries if e.shape[0] == periods]
             block = entries[0] if len(entries) == 1 else jnp.stack(
                 [e.reshape(periods, -1) for e in entries], axis=1)
-            blocks.append((name, block.reshape(periods * len(entries), -1)))
+            block = block.reshape(periods * len(entries), -1)
+            blocks.append((name, jnp.concatenate([block] + behind)
+                           if behind else block))
         self._moe_columns = [(name, int(b.shape[1])) for name, b in blocks]
         return jnp.concatenate([b for _, b in blocks], axis=1)
 
@@ -1079,28 +1309,46 @@ class RaggedInferenceEngineV2:
             ident = {"call": c.call}
             with tel.span("inference/decode_burst",
                           args={"burst": c.steps, "batch": len(c.decode),
-                                "call": c.call}):
+                                "call": c.call}) as burst:
                 with tel.span("inference/decode_burst/fetch",
                               args=ident) as fetch:
-                    # [burst, B], [Bp] or None, the gate's stats or None
-                    toks, firsts, moe_aux = jax.device_get(c.outputs)
+                    # [burst, B] (a drafting engine: [burst, B, 2], -1
+                    # where a step gave no second token), [Bp] or None, the
+                    # gate's stats or None, the lengths the call found
+                    toks, firsts, moe_aux, lens = jax.device_get(c.outputs)
+                if tel.enabled:
+                    # ``burst`` is the call's STEPS; what its rows yielded
+                    slots = [row.slot for row in c.decode]
+                    burst.set(tokens=int((toks[:, slots] >= 0).sum()))
             with tel.span("inference/commit", args=ident) as commit:
                 moe = (None if moe_aux is None
                        else self._ingest_moe_stats(moe_aux))
                 live = self._commit_chunks(c.chunks, firsts, c.eos_token_id)
                 written = sum(ch.n_valid for ch in live)
-                # length - 1: the position of a request's newest token
+                # length - 1: the position of a request's newest token,
+                # which a drafting call reports as it found it (``lens``)
                 rows = [row.request for row in c.decode
                         if row.request.state is RequestState.RUNNING
                         and row.request.slot == row.slot
-                        and row.request.length - 1 == row.position]
+                        and row.request.length - 1 == (
+                            row.position if lens is None
+                            else lens[row.slot])]
+                # (a request that ends leaves its slot: read them first)
+                kept = None if lens is None else [r.slot for r in rows]
                 accepted = self.scheduler.decode_burst_done(
                     rows, toks, c.eos_token_id)
+                commit.set(tokens=accepted)
             n_tokens += written + accepted
             if fetch.end is not None and commit.end is not None:   # hub on
+                drafted = None
+                if lens is not None:
+                    # of the rows committed: a draft a step, and the steps
+                    # that emitted their second token
+                    drafted = (c.steps * len(kept),
+                               int((toks[:, kept, 1] >= 0).sum()))
                 done.append((c, live, written, accepted,
                              len(c.decode) - len(rows), moe,
-                             fetch.end - fetch.start, commit.end))
+                             fetch.end - fetch.start, commit.end, drafted))
         return n_tokens, done
 
     def _commit_chunks(self, chunks, firsts, eos_token_id) -> list:
@@ -1137,7 +1385,7 @@ class RaggedInferenceEngineV2:
 
     def _count_call(self, tel: Any, c: _Call, live, written: int,
                     accepted: int, overrun: int, moe, wait_s: float,
-                    committed: float) -> None:
+                    committed: float, drafted: Optional[tuple]) -> None:
         """A committed call's counters, and its record: the ring span
         ``inference/call`` from the start of its dispatch (a round ago)
         to the end of its commit."""
@@ -1158,6 +1406,11 @@ class RaggedInferenceEngineV2:
         if moe is not None:
             self._count_moe(tel, moe, c.steps)
         chunk_rows = self.prefill_batch * self.chunk if c.chunks else 0
+        rows_a_slot = 1
+        if drafted is not None:
+            rows_a_slot = 2
+            self._count_drafts(tel, *drafted,
+                               c.steps * 2 * self.max_slots + chunk_rows)
         tel.inc_counter("inference/calls",
                         help="program calls committed (one a round)")
         tel.inc_counter("inference/calls_dispatched_ahead",
@@ -1175,10 +1428,11 @@ class RaggedInferenceEngineV2:
                         v=1.0 if c.chunks else 0.0,
                         help="committed calls that carried prefill chunks")
         tel.inc_counter("inference/rows_computed",
-                        v=c.steps * self.max_slots + chunk_rows,
+                        v=c.steps * rows_a_slot * self.max_slots + chunk_rows,
                         help="rows the committed calls computed: every "
-                             "decode slot a step, and every chunk row of "
-                             "a call that carried chunks, live or not")
+                             "decode slot a step (two rows where the model "
+                             "drafts), and every chunk row of a call that "
+                             "carried chunks, live or not")
         tel.inc_counter("inference/chunk_rows_computed", v=chunk_rows,
                         help="the chunk rows among rows_computed "
                              "(prefill_batch x prefill_chunk a call that "
@@ -1192,6 +1446,30 @@ class RaggedInferenceEngineV2:
                 "call": c.call, "steps": c.steps,
                 "decode_rows": len(c.decode), "chunk_tokens": written,
                 "accepted": accepted, "kb": c.kb, "wait_s": wait_s})
+
+    @staticmethod
+    def _count_drafts(tel: Any, drafted: int, accepted: int,
+                      rows: int) -> None:
+        """A committed call's drafting: ``drafted`` drafts went through the
+        trunk (a row a step, of the rows committed), ``accepted`` of them
+        were the trunk's own next token; the drafting layer ran ``rows``
+        rows."""
+        tel.inc_counter("inference/mtp/drafted", v=drafted,
+                        help="drafts verified by the trunk: one a "
+                             "committed decode row a step")
+        tel.inc_counter("inference/mtp/accepted", v=accepted,
+                        help="drafts that were the trunk's own next token: "
+                             "their step emitted two tokens (whether the "
+                             "budget kept the second or not)")
+        tel.inc_counter("inference/mtp/rows", v=rows,
+                        help="rows the drafting layer ran in the committed "
+                             "calls: two a decode slot a step and every "
+                             "chunk row, live or not")
+        tel.inc_counter("inference/mtp/keys_taken_back",
+                        v=drafted - accepted,
+                        help="rejected drafts: each left a key in every "
+                             "layer's cache, the drafting layer's too, "
+                             "which the next step's first row overwrote")
 
     def _count_recycled(self, tel: Any, first_page, pages) -> None:
         """``pages`` logical pages a sequence from ``first_page`` on (arrays
@@ -1228,6 +1506,22 @@ class RaggedInferenceEngineV2:
                 Bp, ((i, ch.request.slot) for i, ch in enumerate(chunks)))
             kb = self._prefill_bucket(chunks)
         return (tokens, tables, start, last, rings), kb, slots
+
+    def _follow_and_seat(self, chunks) -> Tuple[np.ndarray, np.ndarray]:
+        """What a drafting program takes behind a round's chunks
+        (:meth:`_draft_burst_fn`): the prompt token that FOLLOWS each
+        chunk (−1: the chunk is its prompt's last, and the first token the
+        call samples follows it) and the batch slot a prompt's last chunk
+        seats its sequence in (``max_slots``, which no slot is, for the
+        others and for rows that carry no chunk)."""
+        follow = np.zeros((self.prefill_batch,), np.int32)
+        seat = np.full((self.prefill_batch,), self.max_slots, np.int32)
+        for i, ch in enumerate(chunks):
+            if ch.is_last:
+                follow[i], seat[i] = -1, ch.request.slot
+            else:
+                follow[i] = ch.request.prompt[ch.start_pos + ch.n_valid]
+        return follow, seat
 
     def _count_cache_traffic(self, tel: Any, kv_lens, max_pos, burst,
                              chunk_starts=()) -> None:
@@ -1314,6 +1608,7 @@ class RaggedInferenceEngineV2:
             B = self.max_slots
             tokens = np.zeros((B,), np.int32)
             source = np.full((B,), -1, np.int32)
+            live = np.zeros((B,), bool)     # a drafting engine's rows
             kv_lens = np.zeros((B,), np.int32)
             max_pos = np.zeros((B,), np.int32)
             tables = np.zeros((B, self.cache_config.max_blocks_per_seq),
@@ -1328,7 +1623,12 @@ class RaggedInferenceEngineV2:
             rows = []
             for req in decode:
                 s = req.slot
-                if req.ahead_tokens:
+                if self.draft is not None:
+                    # its token, draft and length are the device's
+                    # (``_seq``); ``position`` below is a lower bound, for
+                    # the cache's counters alone
+                    live[s] = True
+                elif req.ahead_tokens:
                     source[s] = firsts_at.get(req.uid, s)
                 else:
                     tokens[s] = req.generated[-1]
@@ -1346,14 +1646,24 @@ class RaggedInferenceEngineV2:
         with tel.span("inference/decode_burst/dispatch",
                       args={"call": self._calls}) as sp, \
                 self._collecting_moe():
-            toks, self.pool, moe_aux, firsts, self._newest = \
-                self._decode(burst)(
-                    self.params, self.pool, tokens, (source, self._newest),
-                    kv_lens, tables, max_pos, temp, self._next_key(tel),
-                    rings, riding,
-                    None if slots is None else (slots, riding_slots),
-                    **bucket)
-        call = _Call(chunks, rows, burst, (toks, firsts, moe_aux),
+            lens = None
+            if self.draft is not None:
+                if riding is not None:
+                    riding += self._follow_and_seat(chunks)
+                toks, lens, self.pool, moe_aux, firsts, self._seq = \
+                    self._decode(burst)(
+                        self.params, self.pool, self._seq, live, tables,
+                        max_pos, temp, self._next_key(tel), rings, riding,
+                        **bucket)
+            else:
+                toks, self.pool, moe_aux, firsts, self._newest = \
+                    self._decode(burst)(
+                        self.params, self.pool, tokens,
+                        (source, self._newest), kv_lens, tables, max_pos,
+                        temp, self._next_key(tel), rings, riding,
+                        None if slots is None else (slots, riding_slots),
+                        **bucket)
+        call = _Call(chunks, rows, burst, (toks, firsts, moe_aux, lens),
                      eos_token_id, self._calls, ahead, bucket.get("kb"),
                      kv_lens, max_pos, sp.start)
         self._inflight.append(call)

@@ -85,17 +85,30 @@ class KVCacheConfig:
         return 1 + self.num_rings * self.ring_blocks
 
     def with_rings(self, kinds: Iterable[Any], slots: int,
-                   prefill_chunk: int) -> "KVCacheConfig":
+                   prefill_chunk: int, row_tokens: int = 1
+                   ) -> "KVCacheConfig":
         """This config with a ring a batch slot where a kind recycles: the
         widest window's pages and those a prefill chunk writes before it
-        attends (a decode step's one page more is among them)."""
+        attends (a decode step's one page more is among them).
+        ``row_tokens``: the consecutive tokens a decode row writes in a
+        step (2 where the model drafts: the newest token at ``p`` and its
+        draft at ``p + 1``).  Its last token reads the window from ``p +
+        row_tokens − 1`` back while the first one's still lies there, so
+        the ring holds ``window + row_tokens − 1`` keys wherever in a page
+        they begin, ``ceil(that / block) + 1`` pages (the paged kernel's
+        own count of such a row's live pages): no more than a chunk's
+        share gives unless a chunk is a single page.  A rejected draft's
+        key is overwritten by the next step's first row, at the same
+        place: nothing is taken back by hand."""
         windows = [k.window for k in kinds if k.ring]
         if not windows:
             return self
         bs = self.block_size
+        reach = max(windows)
         return dataclasses.replace(
             self, num_rings=slots,
-            ring_blocks=-(-max(windows) // bs) + max(prefill_chunk // bs, 1))
+            ring_blocks=max(-(-reach // bs) + max(prefill_chunk // bs, 1),
+                            -(-(reach + row_tokens - 1) // bs) + 1))
 
     def with_state(self, state_kinds: Iterable[Any], slots: int
                    ) -> "KVCacheConfig":

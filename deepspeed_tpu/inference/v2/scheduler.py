@@ -73,7 +73,11 @@ class Request:
     @property
     def planned_tokens(self) -> int:
         """The tokens generated once every dispatched call is committed,
-        unless one of them turns out to be the EOS."""
+        unless one of them turns out to be the EOS; under a drafting
+        engine AT LEAST these (a step yields one token or two, and a token
+        a step is what is planned: a request's last call may then run past
+        its budget, and its surplus is discarded as a burst's always
+        was)."""
         return len(self.generated) + self.ahead_tokens
 
     @property
@@ -351,14 +355,27 @@ class RaggedScheduler:
         """Accept an in-graph burst's ``[n_steps, B]`` token matrix: each
         request takes its slot's column until it finishes (EOS/budget);
         surplus tokens a done slot generated inside the burst are
-        discarded.  Returns the number of accepted tokens."""
+        discarded.  A drafting engine's is ``[n_steps, B, 2]``, a step's
+        one or two tokens side by side and −1 where it gave no second: a
+        request's column is then what its steps emitted, in order, and a
+        budget may end between a step's two tokens.  What was PLANNED for
+        the call (:meth:`dispatched`) is a token a step, the least it
+        yields.  Returns the number of accepted tokens."""
         accepted = 0
-        columns = np.asarray(tokens).T.tolist()     # [B][n_steps] ints
+        tokens = np.asarray(tokens)
+        steps = tokens.shape[0]
+        if tokens.ndim == 3:
+            columns = [[t for t in col if t >= 0] for col in
+                       tokens.transpose(1, 0, 2).reshape(
+                           tokens.shape[1], -1).tolist()]
+        else:
+            columns = tokens.T.tolist()             # [B][n_steps] ints
         for req in requests:
             if req.state is not RequestState.RUNNING:
                 continue
             col = columns[req.slot][:max(req.remaining_budget, 1)]
-            req.ahead_tokens = max(req.ahead_tokens - len(col), 0)
+            req.ahead_tokens = max(req.ahead_tokens - min(len(col), steps),
+                                   0)
             if eos_token_id is not None and eos_token_id in col:
                 col = col[:col.index(eos_token_id) + 1]
             req.generated.extend(col)

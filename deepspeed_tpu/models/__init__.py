@@ -9,6 +9,7 @@ that compose with the ZeRO sharding policy.
 """
 
 from .bert import BertConfig, BertModel
+from .exaone_moe import ExaoneMoeConfig, ExaoneMoeModel
 from .falcon_h1 import FalconH1Config, FalconH1Model
 from .llama import LlamaConfig, LlamaModel
 from .mimo_v2 import MimoV2Config, MimoV2Model
@@ -20,7 +21,8 @@ from .pangu_ultra_moe import PanguUltraMoeConfig, PanguUltraMoeModel
 from .resnet import ResNetConfig, ResNetModel
 from .solar_open2 import SolarOpen2Config, SolarOpen2Model
 
-__all__ = ["BertConfig", "BertModel", "FalconH1Config", "FalconH1Model",
+__all__ = ["BertConfig", "BertModel", "ExaoneMoeConfig", "ExaoneMoeModel",
+           "FalconH1Config", "FalconH1Model",
            "LlamaConfig", "LlamaModel",
            "MimoV2Config", "MimoV2Model", "MixtralConfig", "MixtralModel",
            "NemotronHConfig", "NemotronHModel", "OlmoeConfig", "OlmoeModel",

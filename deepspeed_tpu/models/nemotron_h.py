@@ -37,8 +37,8 @@ H], w_up [E, held, w, I], w_down [E, held, I, w], shared_up [E, H, S],
 shared_down [E, S, H]}``, ``embed [V, H]``, ``final_norm [H]``, ``lm_head
 [H, V]``.  A layer's place in its stack is its place among the layers of
 its own part, not the model's layer.  The multi-token-prediction module of
-the published model is not built (no scheduler step yields more than one
-token), and there is no trainer path: the model is served.
+the published model is not built (a stack of one-part layers behind the
+trunk: ROADMAP R8 (b)), and there is no trainer path: the model is served.
 """
 
 from __future__ import annotations

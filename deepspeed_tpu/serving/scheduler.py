@@ -63,7 +63,10 @@ class ServingScheduler(RaggedScheduler):
         if prefix_sharing and cache_config.state_slots:
             # a prefix hit skips the prefill of the shared tokens, which
             # is what builds the state; no snapshot of the state at the
-            # prefix's end exists to start from (ROADMAP R7)
+            # prefix's end exists to start from (ROADMAP R7).  (A model
+            # that drafts sets the slots too: a page's last key of its
+            # drafting layer was made with the token that FOLLOWED it in
+            # the sequence that wrote it.)
             from ..utils.logging import warn_once
 
             warn_once("serving/prefix_cache/state",
@@ -213,7 +216,8 @@ class ServingScheduler(RaggedScheduler):
     @property
     def seat_holds_state(self) -> bool:
         """Whether part of a sequence's cache lies in its batch slot and
-        not in its pages (a recurrent state): a request that gives up its
+        not in its pages (a recurrent state; a drafting engine's newest
+        token, draft and length): a request that gives up its
         seat can then only start over (:meth:`preempt_release`), and its
         pages alone are not the sequence (no adoption)."""
         return bool(self.cache.state_slots)
@@ -221,9 +225,9 @@ class ServingScheduler(RaggedScheduler):
     def _refuse_if_seat_holds_state(self, what: str) -> None:
         if self.seat_holds_state:
             raise NotImplementedError(
-                f"{what} of a model with recurrent state: a sequence's "
-                f"state lies in its batch slot, not in its pages, and no "
-                f"snapshot of it is kept (ROADMAP R7)")
+                f"{what} of a model with recurrent state (or one that "
+                f"drafts): part of a sequence lies in its batch slot, not "
+                f"in its pages, and no snapshot of it is kept (ROADMAP R7)")
 
     def unseat(self, req: Request) -> None:
         """:meth:`preempt` minus the SLO counters — the disaggregation

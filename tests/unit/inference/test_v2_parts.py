@@ -81,8 +81,8 @@ def test_the_adapters_that_were_there_serve_the_parents_logits(family):
 
 
 def test_the_registry_holds_the_six_and_the_family_of_one_part_layers():
-    assert set(adapters._REGISTRY) == set(FAMILIES) | {"NemotronHModel",
-                                                       "SolarOpen2Model"}
+    assert set(adapters._REGISTRY) == set(FAMILIES) | {
+        "NemotronHModel", "SolarOpen2Model", "ExaoneMoeModel"}
     assert len({adapters._REGISTRY[f] for f in FAMILIES}) == 6
     # the hook of the FFN alone: the families whose FFN follows their
     # attention in the same layer state no such layer and have none

@@ -218,12 +218,12 @@ def test_prefill_and_decode_spans_read_as_at_the_parent_commit(served):
            for e in named(served, "inference/decode_burst")]
     chunks = [s["args"]["chunks"] for s in named(served, "inference/step")]
     assert chunks == [2, 1, 0, 0, 1, 1, 1, 0, 0, 0]
+    # ``burst`` is the call's STEPS; ``tokens`` (PR 59) what its rows
+    # yielded: a token a row a step where the model does not draft
     assert got == [
-        {"burst": 1, "batch": 0}, {"burst": 1, "batch": 1},
-        {"burst": 4, "batch": 2}, {"burst": 4, "batch": 1},
-        {"burst": 1, "batch": 0}, {"burst": 1, "batch": 0},
-        {"burst": 1, "batch": 0},
-        {"burst": 4, "batch": 1}, {"burst": 4, "batch": 1}]
+        {"burst": burst, "batch": batch, "tokens": burst * batch}
+        for burst, batch in [(1, 0), (1, 1), (4, 2), (4, 1), (1, 0), (1, 0),
+                             (1, 0), (4, 1), (4, 1)]]
     assert not named(served, "inference/prefill")
     # one commit a program call
     assert len(named(served, "inference/commit")) == len(got)
