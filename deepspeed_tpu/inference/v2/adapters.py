@@ -751,12 +751,8 @@ class NemotronHV2Adapter(ModelAdapterV2):
     def state_kinds(self) -> Tuple[StateKind, ...]:
         from ...models.nemotron_h import SSM
 
-        # the conv's tail stays a VALUE here (PR 58): moved in place, the
-        # conv's output is rounded to the model's type where XLA's fused
-        # chain kept float32, and this family's routers turn that into
-        # swapped experts often enough to thin its check's margin
         return (StateKind(SSM, self.config.count("M"),
-                          self.model.state_parts(), in_place=(SSM,)),)
+                          self.model.state_parts(), in_place=(SSM, "conv")),)
 
     @property
     def pattern(self) -> LayerPattern:

@@ -201,8 +201,9 @@ def chunk(dims: Mamba2Dims, m: Any, p: jnp.ndarray,
 def decode(dims: Mamba2Dims, m: Any, p: jnp.ndarray,
            state: Dict[str, jnp.ndarray],
            held: Dict[str, Tuple[jnp.ndarray, Any, Any]], valid: jnp.ndarray,
-           dt: Any) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray],
-                             Dict[str, jnp.ndarray]]:
+           dt: Any, kernel_conv: bool = True
+           ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray],
+                      Dict[str, jnp.ndarray]]:
     """A decode step's ``R`` rows ``p [R, proj_dim]``, a token a sequence,
     whose state lies in the pool: ``held[part] = (array [layers, slots,
     …], layer, first slot)``, row ``r``'s at ``(layer, first + r)``, the
@@ -213,10 +214,12 @@ def decode(dims: Mamba2Dims, m: Any, p: jnp.ndarray,
     the rows' parts moved one step where they lie: ``conv_tail_update``,
     which emits the conv's output, and ``ssm_state_update``, which reads
     ``y = S C`` off the new values).  A row with ``valid`` 0 moves
-    neither."""
+    neither.  A family that passes ``kernel_conv`` False has the kernel
+    move its tails only and hand them back as they lay: the conv is then
+    :func:`conv`, XLA's chain, as where the tail is a value."""
     d_ssm, conv_dim = dims.d_ssm, dims.conv_dim
     values, arrays = {}, {}
-    if "conv" in held:
+    if "conv" in held and kernel_conv:
         array, layer, first = held["conv"]
         with jax.named_scope("ssm/conv"):
             arrays["conv"], out = conv_tail_update(
@@ -224,6 +227,13 @@ def decode(dims: Mamba2Dims, m: Any, p: jnp.ndarray,
                 m["conv_w"], m["conv_b"], valid)
         xs, B, C, delta, A = _split(dims, m, out[:, None],
                                     p[:, None, d_ssm + conv_dim:], valid)
+    elif "conv" in held:
+        array, layer, first = held["conv"]
+        with jax.named_scope("ssm/conv"):
+            arrays["conv"], tail = conv_tail_update(
+                array, layer, first, p[:, d_ssm:d_ssm + conv_dim], None,
+                None, valid)
+        xs, B, C, delta, A, _ = conv(dims, m, p, tail, 1, valid, dt)
     else:
         xs, B, C, delta, A, values["conv"] = conv(
             dims, m, p, state["conv"], 1, valid, dt)

@@ -328,8 +328,15 @@ class NemotronHModel:
         return mamba2.chunk(c.mamba, lp, p, state, tokens, valid, c.dtype)
 
     def mix_decode(self, lp, p, state, held, valid):
+        # the conv stays XLA's chain here and the kernel moves the tails
+        # only (PR 60): with the kernel's own conv, in bfloat16 or float32,
+        # this family's check read a token flipped at a near-tie on one
+        # seed in twelve (0.088 of 0.10); with XLA's chain it reads the
+        # values way's gaps on ten seeds of eleven and never over 0.05 on
+        # nineteen; Falcon-H1's gaps never moved, so it keeps the kernel's
         c = self.config
-        return mamba2.decode(c.mamba, lp, p, state, held, valid, c.dtype)
+        return mamba2.decode(c.mamba, lp, p, state, held, valid, c.dtype,
+                             kernel_conv=False)
 
     def mix_out(self, lp: Any, p: jnp.ndarray, y: jnp.ndarray
                 ) -> jnp.ndarray:

@@ -42,6 +42,17 @@ The kernel multiplies and sums in float32 and rounds once; the
 ``jax.numpy`` reference is the arithmetic of ``models/mamba2.conv`` at one
 token, term for term in the rows' type.
 
+TO MOVE ONLY: a caller that hands NO taps (``w`` None) gets, in place of
+the conv's output, the rows' tails AS THEY LAY before the step, ``[R, (K −
+1) · C]`` in the pool's type: the block the kernel fetched anyway, written
+out beside the shifted one, and no conv is run.  Nemotron-H's mixers take
+that (``models/mamba2.decode`` with ``kernel_conv`` False) and leave the
+conv itself to XLA's fused chain (``models/mamba2.conv``): the step's
+arithmetic stays what it was where the tail was a value, and only the tail's
+movement changes (an XLA read of the pool beside the aliased call would make
+the compiler copy the whole pool, a call).
+Every other caller hands its taps.
+
 ``interpret``: as every entry point here (``select.py``).  Off the TPU the
 ``jax.numpy`` reference runs; the interpreter runs the kernel on the layer
 cut out of the pool (it does not alias).
@@ -71,19 +82,21 @@ VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 def conv_tail_update_reference(pool, layer, first, x, w, b, valid
                                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """:func:`conv_tail_update` in ``jax.numpy``, in ``x``'s type."""
-    (R, C), K, dt = x.shape, w.shape[0], x.dtype
+    (R, C), dt = x.shape, x.dtype
+    K = pool.shape[2] // C + 1
     at = (layer, first, 0)
     held = jax.lax.dynamic_slice(pool, at, (1, R, pool.shape[2]))[0]
     seq = jnp.concatenate([held.astype(dt).reshape(R, K - 1, C),
                            x[:, None]], axis=1)
-    w = w.astype(dt)
-    out = sum(seq[:, j] * w[j] for j in range(K))
-    if b is not None:
-        out = out + b.astype(dt)
+    if w is not None:
+        w = w.astype(dt)
+        out = sum(seq[:, j] * w[j] for j in range(K))
+        if b is not None:
+            out = out + b.astype(dt)
     left = jnp.where((valid > 0)[:, None, None], seq[:, 1:], seq[:, :-1])
     return (jax.lax.dynamic_update_slice(
         pool, left.reshape(1, R, -1).astype(pool.dtype), at),
-        jax.nn.silu(out))
+        held if w is None else jax.nn.silu(out))
 
 
 def rows_on_sublanes(layers: int, slots: int, itemsize: int) -> bool:
@@ -107,15 +120,19 @@ def _chunk(C: int) -> int:
     return next((c for c in range(CHUNK, 0, -LANES) if C % c == 0), C)
 
 
-def _update_kernel(layer_ref, pool_ref, x_ref, taps_ref, valid_ref,
-                   out_pool_ref, out_ref, *, K: int, C: int):
+def _update_kernel(layer_ref, pool_ref, x_ref, *refs, K: int, C: int,
+                   conv: bool):
     """One block of slots: ``pool_ref``/``out_pool_ref [1, rows, (K−1)·C]``
-    the same block of the aliased pool, ``x_ref``/``out_ref [rows, C]``,
-    ``taps_ref [K + 1, C]`` float32 (``w``, then the bias), ``valid_ref
-    [rows, 1]``."""
+    the same block of the aliased pool, ``x_ref [rows, C]``; then ``refs``:
+    with ``conv``, ``taps_ref [K + 1, C]`` float32 (``w``, then the bias),
+    ``valid_ref [rows, 1]``, ``out_pool_ref``, ``out_ref [rows, C]``; to
+    move only, ``valid_ref``, ``out_pool_ref``, ``out_ref [rows,
+    (K−1)·C]``, which takes the block as it was fetched."""
     from jax.experimental import pallas as pl
 
     del layer_ref                   # the index maps read it
+    taps_ref, (valid_ref, out_pool_ref, out_ref) = (
+        (refs[0], refs[1:]) if conv else (None, refs))
     live = valid_ref[...] > 0
     width = _chunk(C)
 
@@ -123,11 +140,15 @@ def _update_kernel(layer_ref, pool_ref, x_ref, taps_ref, valid_ref,
         at = lambda j: pl.ds(pl.multiple_of(j * C + c * width, width), width)
         seq = [pool_ref[0, :, at(j)].astype(F32) for j in range(K - 1)]
         seq.append(x_ref[:, at(0)].astype(F32))
-        acc = taps_ref[K:K + 1, at(0)]
-        for j in range(K):
-            acc = acc + taps_ref[j:j + 1, at(0)] * seq[j]
-        out_ref[:, at(0)] = (acc * jax.nn.sigmoid(acc)).astype(out_ref.dtype)
+        if conv:
+            acc = taps_ref[K:K + 1, at(0)]
+            for j in range(K):
+                acc = acc + taps_ref[j:j + 1, at(0)] * seq[j]
+            out_ref[:, at(0)] = (acc * jax.nn.sigmoid(acc)
+                                 ).astype(out_ref.dtype)
         for j in range(K - 1):
+            if not conv:
+                out_ref[:, at(j)] = seq[j].astype(out_ref.dtype)
             out_pool_ref[0, :, at(j)] = jnp.where(
                 live, seq[j + 1], seq[j]).astype(out_pool_ref.dtype)
         return carry
@@ -137,16 +158,18 @@ def _update_kernel(layer_ref, pool_ref, x_ref, taps_ref, valid_ref,
 
 def _update_pallas(pool, layer, x, w, b, valid, interpret: bool):
     """The kernel over EVERY slot of ``pool``'s layer ``layer``: ``x [slots,
-    C]`` and ``valid [slots]`` int32 a slot."""
+    C]`` and ``valid [slots]`` int32 a slot; ``w`` None: no conv, the second
+    result the slots' tails as they lay."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (S, C), K = x.shape, w.shape[0]
+    S, C = x.shape
+    K = pool.shape[2] // C + 1
     rows = ROWS if S >= ROWS else S
-    taps = jnp.concatenate([
+    taps = [] if w is None else [jnp.concatenate([
         w.astype(x.dtype).astype(F32),
         (jnp.zeros((C,), F32) if b is None
-         else b.astype(x.dtype).astype(F32))[None]])
+         else b.astype(x.dtype).astype(F32))[None]])]
     block = pl.BlockSpec((1, rows, (K - 1) * C),
                          lambda i, layer: (layer[0], i, 0))
     by_rows = lambda width: pl.BlockSpec((rows, width),
@@ -158,25 +181,26 @@ def _update_pallas(pool, layer, x, w, b, valid, interpret: bool):
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
             dimension_semantics=("arbitrary",))
     return pl.pallas_call(
-        functools.partial(_update_kernel, K=K, C=C),
+        functools.partial(_update_kernel, K=K, C=C, conv=w is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(-(-S // rows),),
-            in_specs=[block, by_rows(C),
-                      pl.BlockSpec((K + 1, C), lambda i, layer: (0, 0)),
-                      by_rows(1)],
-            out_specs=[block, by_rows(C)]),
+            in_specs=[block, by_rows(C)]
+            + [pl.BlockSpec((K + 1, C), lambda i, layer: (0, 0))] * len(taps)
+            + [by_rows(1)],
+            out_specs=[block, by_rows(C if taps else (K - 1) * C)]),
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-                   jax.ShapeDtypeStruct((S, C), x.dtype)],
+                   jax.ShapeDtypeStruct((S, C), x.dtype) if taps else
+                   jax.ShapeDtypeStruct((S, (K - 1) * C), pool.dtype)],
         interpret=interpret,
         name="conv_tail_update",
         **kwargs,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), pool, x, taps,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), pool, x, *taps,
       valid[:, None])
 
 
 def conv_tail_update(pool: jnp.ndarray, layer, first, x: jnp.ndarray,
-                     w: jnp.ndarray, b: Optional[jnp.ndarray],
+                     w: Optional[jnp.ndarray], b: Optional[jnp.ndarray],
                      valid: jnp.ndarray, *, interpret: Optional[bool] = None
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``pool [layers, slots, (K − 1) · C]``: layer ``layer``'s slots
@@ -186,7 +210,7 @@ def conv_tail_update(pool: jnp.ndarray, layer, first, x: jnp.ndarray,
     sequence's) → (the pool with those tails shifted one step, ``x`` last,
     in place where the kernel runs and as they lay where ``valid`` is 0;
     ``out [R, C]`` in ``x``'s type, ``silu`` of the conv at the step's
-    token)."""
+    token).  ``w`` None: to move only (the module's text)."""
     if reference_off_tpu(interpret):
         record_route("conv_tail_update", "reference")
         return conv_tail_update_reference(pool, layer, first, x, w, b, valid)
@@ -217,4 +241,4 @@ def conv_tail_update(pool: jnp.ndarray, layer, first, x: jnp.ndarray,
     else:
         record_route("conv_tail_update", "kernel")
         pool, out = _update_pallas(pool, layer, x, w, b, valid, False)
-    return pool, jax.lax.dynamic_slice(out, (first, 0), (R, C))
+    return pool, jax.lax.dynamic_slice(out, (first, 0), (R, out.shape[1]))

@@ -694,34 +694,43 @@ def check_delta_state_update(s: KernelShapes, interpret: bool) -> List[Check]:
 
 def check_conv_tail_update(s: KernelShapes, interpret: bool) -> List[Check]:
     """The decode step's conv over the rows' held tails IN PLACE in their
-    pool, at the widest published conv (three streams of 8,192 channels,
-    four taps, no bias; a serving step's 192 rows at slots 1 …, a quarter
-    of them dead) against the ``jax.numpy`` reference run in float32: the
-    pool after, which is copies (a dead row's as it lay) and so exact, and
-    the conv's output, which the kernel rounds once to the rows' type.  The
+    pool against the ``jax.numpy`` reference run in float32.  Twice: at the
+    widest published conv (three streams of 8,192 channels, four taps, no
+    bias; a serving step's 192 rows at slots 1 …): the pool after, which is
+    copies (a dead row's as it lay) and so exact, and the conv's output,
+    which the kernel rounds once to the rows' type; and as Nemotron-H's
+    mixers call it, with no taps, at their ``[5, 129, 30720]`` tails and 128
+    rows of 10,240 channels: the pool after and the rows' tails handed back
+    as they lay, both copies and exact.  A quarter of the rows dead; the
     interpreter walks ``slots`` rows."""
     ctu = _mod("conv_tail_update")
     rng = np.random.RandomState(14)
-    rows, taps, channels = (s.slots if interpret else 192), 4, 3 * 8192
-    pool = _normal(rng, (3, rows + 2, (taps - 1) * channels), s.dtype)
-    x = _normal(rng, (rows, channels), s.dtype)
-    w = _normal(rng, (taps, channels), s.dtype, 0.5)
-    valid = jnp.asarray(rng.rand(rows) < 0.75, jnp.int32)
+    taps, checks = 4, []
+    for name, layers, rows, spare, channels, conv, tol in (
+            ("conv_tail_update", 3, 192, 2, 3 * 8192, True, DECODE_TOL),
+            ("conv_tail_update_tails", 5, 128, 1, 10240, False, 0.0)):
+        rows = s.slots if interpret else rows
+        pool = _normal(rng, (layers, rows + spare, (taps - 1) * channels),
+                       s.dtype)
+        x = _normal(rng, (rows, channels), s.dtype)
+        w = _normal(rng, (taps, channels), s.dtype, 0.5) if conv else None
+        valid = jnp.asarray(rng.rand(rows) < 0.75, jnp.int32)
 
-    @jax.jit
-    def errors(pool, x, w, valid):
-        want = ctu.conv_tail_update_reference(
-            pool.astype(jnp.float32), 1, 1, x.astype(jnp.float32), w, None,
-            valid)
-        # the kernel writes the pool it is given: hand it a copy, after
-        # the reference has read the original
-        got = ctu.conv_tail_update(pool + 0, 1, 1, x, w, None, valid,
-                                   interpret=interpret)
-        return [_rel_err(g, wanted) for g, wanted in zip(got, want)]
+        @jax.jit
+        def errors(pool, x, w, valid):
+            want = ctu.conv_tail_update_reference(
+                pool.astype(jnp.float32), 1, 1, x.astype(jnp.float32), w,
+                None, valid)
+            # the kernel writes the pool it is given: hand it a copy,
+            # after the reference has read the original
+            got = ctu.conv_tail_update(pool + 0, 1, 1, x, w, None, valid,
+                                       interpret=interpret)
+            return [_rel_err(g, wanted) for g, wanted in zip(got, want)]
 
-    pool_err, out_err = errors(pool, x, w, valid)
-    return [Check("conv_tail_update_pool", float(pool_err), 0.0),
-            Check("conv_tail_update_out", float(out_err), DECODE_TOL)]
+        pool_err, out_err = errors(pool, x, w, valid)
+        checks += [Check(f"{name}_pool", float(pool_err), 0.0),
+                   Check(f"{name}_out", float(out_err), tol)]
+    return checks
 
 
 def check_quantizer(s: KernelShapes, interpret: bool) -> List[Check]:

@@ -83,9 +83,10 @@ def test_a_part_in_place_goes_out_as_the_pools_array_and_comes_back_as_one():
 
 
 def test_a_kind_whose_every_part_is_in_place_hands_a_decode_step_no_values():
-    """What two of the three families state since PR 58 (the conv's tail
-    moved where it lies, as the state is): the hook gets every part as the pool's
-    array, and every array it hands back is put in its place."""
+    """What all three families state (two since PR 58, Nemotron-H since
+    PR 60: the conv's tail moved where it lies, as the state is): the hook
+    gets every part as the pool's array, and every array it hands back is
+    put in its place."""
     layout = StateLayout(StateKind("ssm", 3, KIND.parts, ("ssm", "conv")), 5)
     pool = _filled(layout)
     values, held = layout.decode_operands(pool, 2)
@@ -102,15 +103,15 @@ def test_a_kind_whose_every_part_is_in_place_hands_a_decode_step_no_values():
 @pytest.mark.parametrize("make, part, tail_in_place", [
     (lambda: models.FalconH1Model(models.FalconH1Config.tiny()), "ssm", True),
     (lambda: models.NemotronHModel(models.NemotronHConfig.tiny()), "ssm",
-     False),
+     True),
     (lambda: models.SolarOpen2Model(models.SolarOpen2Config.tiny()),
      "delta", True)], ids=["falcon-h1", "nemotron-h", "solar-open2"])
 def test_which_families_move_the_tail_where_it_lies(make, part,
                                                     tail_in_place):
-    """Falcon-H1 and Solar-Open-2 state both parts ``in_place``; Nemotron-H
-    keeps its tail a value (PR 58's one fallback: its check's margin).  In
-    all three the conv's tail lies time-major and FLAT a slot (no dimension
-    of ``K − 1`` for the chip to tile)."""
+    """All three state both parts ``in_place`` (Nemotron-H since PR 60:
+    the kernel moves its tails and the conv stays XLA's chain, the parent's
+    arithmetic).  In all three the conv's tail lies time-major and FLAT a
+    slot (no dimension of ``K − 1`` for the chip to tile)."""
     kind, = adapters.make_adapter(make()).state_kinds
     assert kind.name == part
     assert kind.in_place == ((part, "conv") if tail_in_place else (part,))

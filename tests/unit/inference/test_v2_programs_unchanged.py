@@ -1,7 +1,8 @@
 """The programs of the families that state no drafting layer hold nothing
 of drafting (PR 59): ``jax.make_jaxpr`` of the decode burst and of the step
-that carries chunks, for one dense family, one that carries a recurrent
-state and one with routed experts (whose gate's stats the program packs), is
+that carries chunks, for one dense family, two that carry a recurrent
+state (a Mamba-2 mixer's and a delta rule's) and one with routed experts
+(whose gate's stats the program packs), is
 the text the PARENT commit's tree gives (``tests/fixtures/serving/
 v2_program_texts.json``: sha256 and length of each, made by running this
 file on that tree: ``python tests/unit/inference/
@@ -28,6 +29,11 @@ FAMILIES = {
     "FalconH1Model": lambda models: models.FalconH1Model(
         models.FalconH1Config.tiny()),
     "OlmoeModel": lambda models: models.OlmoeModel(models.OlmoeConfig.tiny()),
+    # PR 60 changed Nemotron-H's decode step alone (its conv's tail moved
+    # where it lies): Falcon-H1's texts above and the delta rule's, pinned
+    # here in bfloat16, are the parent's
+    "SolarOpen2Model": lambda models: models.SolarOpen2Model(
+        models.SolarOpen2Config.tiny(dtype="bfloat16")),
 }
 PROGRAMS = {"burst": 4, "step_with_chunks": 1}
 

@@ -2,9 +2,11 @@
 over the rows' held tails, in place in the tails' pool.  The kernel in the
 Pallas interpreter against the ``jax.numpy`` reference (which is
 ``models/mamba2.conv`` at one token, term for term), at the three families'
-arguments cut small; what a dead row, a fresh slot and a stretch of slots
-that starts inside a tile of rows must come to; and the rule that says
-where the chip holds the pool row-major."""
+arguments cut small, emitting the conv's output or, for a caller that hands
+no taps, the rows' tails as they lay (Nemotron-H's mixers' way since PR 60);
+what a dead row, a fresh slot and a stretch of slots that starts inside a
+tile of rows must come to; and the rule that says where the chip holds the
+pool row-major."""
 
 import jax
 import jax.numpy as jnp
@@ -33,17 +35,33 @@ def _operands(seed, layers, slots, rows, taps, channels, bias, dtype):
             normal(channels) if bias else None)
 
 
+#: what the second result is: the conv's output, or (no taps handed) the
+#: rows' tails as they lay
+EMITS = ["conv", "tails"]
+
+
+def _taps(emits, w, b):
+    return (w, b) if emits == "conv" else (None, None)
+
+
+@pytest.mark.parametrize("emits", EMITS)
 @pytest.mark.parametrize("dtype", [F32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_the_kernel_is_the_reference_at_each_familys_arguments(family, dtype):
+def test_the_kernel_is_the_reference_at_each_familys_arguments(family, dtype,
+                                                               emits):
     taps, channels, bias = FAMILIES[family]
     pool, x, w, b = _operands(0, 3, 12, 11, taps, channels, bias, dtype)
+    w, b = _taps(emits, w, b)
     valid = jnp.asarray([1, 1, 0, 1, 1, 1, 0, 1, 1, 1, 1])
     want = ctu.conv_tail_update_reference(pool, 1, 1, x, w, b, valid)
     got = ctu.conv_tail_update(pool, 1, 1, x, w, b, valid, interpret=True)
     # the tails are copies: exact in either type
     np.testing.assert_array_equal(got[0], want[0])
     assert got[1].dtype == want[1].dtype == dtype
+    if emits == "tails":
+        # every row's, live or dead, as it lay before the step: to the bit
+        np.testing.assert_array_equal(got[1], want[1])
+        np.testing.assert_array_equal(got[1], pool[1, 1:12])
     # the kernel sums in float32 and rounds once; the reference rounds
     # every product and sum to the rows' type
     tol = 1e-5 if dtype == F32 else 4e-2
@@ -82,15 +100,20 @@ def test_the_reference_is_the_models_conv_at_one_token(interpret):
                                   C.reshape(R, -1)], axis=1))
 
 
+@pytest.mark.parametrize("emits", EMITS)
 @pytest.mark.parametrize("interpret", [None, True],
                          ids=["reference", "interpret"])
-def test_a_row_that_is_no_sequences_leaves_its_tail_as_it_lay(interpret):
+def test_a_row_that_is_no_sequences_leaves_its_tail_as_it_lay(interpret,
+                                                              emits):
     """A dead decode row on a prefilling request's slot must not disturb
     what the chunk wrote (``engine_v2._beside``'s contract)."""
     pool, x, w, b = _operands(2, 2, 21, 19, 4, 128, True, jnp.bfloat16)
+    w, b = _taps(emits, w, b)
     valid = jnp.asarray(np.arange(19) % 3 != 1, jnp.int32)
-    after, _ = ctu.conv_tail_update(pool, 0, 2, x, w, b, valid,
-                                    interpret=interpret)
+    after, out = ctu.conv_tail_update(pool, 0, 2, x, w, b, valid,
+                                      interpret=interpret)
+    if emits == "tails":
+        np.testing.assert_array_equal(out, pool[0, 2:])
     before, after = np.asarray(pool[0, 2:], F32), np.asarray(after[0, 2:],
                                                              F32)
     dead = np.asarray(valid) == 0
@@ -102,18 +125,22 @@ def test_a_row_that_is_no_sequences_leaves_its_tail_as_it_lay(interpret):
                                   np.asarray(x, F32)[~dead])
 
 
+@pytest.mark.parametrize("emits", EMITS)
 @pytest.mark.parametrize("interpret", [None, True],
                          ids=["reference", "interpret"])
-def test_a_fresh_slots_zeros_leave_the_last_tap_alone(interpret):
+def test_a_fresh_slots_zeros_leave_the_last_tap_alone(interpret, emits):
     """A sequence's first token: zeros before it, so the conv is its last
-    tap's product (and the bias)."""
+    tap's product (and the bias), and the tails handed back are zeros."""
     _, x, w, b = _operands(3, 1, 1, 9, 4, 256, True, F32)
     pool = jnp.zeros((2, 10, 3 * 256), F32)
-    after, out = ctu.conv_tail_update(pool, 1, 1, x, w, b,
+    after, out = ctu.conv_tail_update(pool, 1, 1, x, *_taps(emits, w, b),
                                       jnp.ones((9,), jnp.int32),
                                       interpret=interpret)
-    np.testing.assert_allclose(out, jax.nn.silu(w[3] * x + b), rtol=1e-6,
-                               atol=1e-6)
+    if emits == "conv":
+        np.testing.assert_allclose(out, jax.nn.silu(w[3] * x + b),
+                                   rtol=1e-6, atol=1e-6)
+    else:
+        assert out.shape == (9, 3 * 256) and not bool(out.any())
     assert not bool(after[1, 1:, :2 * 256].any())
     np.testing.assert_array_equal(after[1, 1:, 2 * 256:], x)
 
@@ -121,12 +148,15 @@ def test_a_fresh_slots_zeros_leave_the_last_tap_alone(interpret):
 @pytest.mark.parametrize("first, rows, slots", [
     (1, 16, 17), (1, 8, 9), (3, 9, 14), (0, 8, 8), (5, 2, 24), (7, 17, 24),
     (1, 3, 4)])
-def test_rows_whose_slots_start_inside_a_tile_of_rows(first, rows, slots):
+@pytest.mark.parametrize("emits", EMITS)
+def test_rows_whose_slots_start_inside_a_tile_of_rows(first, rows, slots,
+                                                      emits):
     """The kernel walks the layer's slots from 0 in tiles of eight, the
     call's rows laid at their slots' places: whichever stretch they hold,
     they get their own tails, and no other slot is touched."""
     pool, x, w, b = _operands(first, 2, slots, rows, 4, 128, False,
                               jnp.bfloat16)
+    w, b = _taps(emits, w, b)
     valid = jnp.ones((rows,), jnp.int32)
     want = ctu.conv_tail_update_reference(pool, 1, first, x, w, b, valid)
     got = ctu.conv_tail_update(pool, 1, first, x, w, b, valid,
@@ -182,16 +212,20 @@ def test_the_route_is_counted_under_the_ops_name(interpret, route):
         tel.reset()
 
 
-@pytest.mark.parametrize("family", ["mamba2", "delta"])
+@pytest.mark.parametrize("family", ["mamba2", "mamba2-moves-only", "delta"])
 def test_a_familys_decode_step_through_the_kernel_is_its_reference(
         family, monkeypatch):
     """``mamba2.decode`` / ``delta_rule.decode`` with the conv's kernel in
     the interpreter against the same step on the reference: the two arrays
-    going out and the step's output."""
+    going out and the step's output.  The Mamba-2 step as Falcon-H1 runs it
+    and the delta rule hand their taps and take the conv's output; as
+    Nemotron-H runs it (``kernel_conv`` False, PR 60) it hands the kernel
+    none, takes the tails as they lay and leaves the conv to
+    ``mamba2.conv``, the values way's arithmetic."""
     rng = np.random.RandomState(6)
     normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), F32)
     R, valid = 5, jnp.asarray([1, 1, 0, 1, 1])
-    if family == "mamba2":
+    if family != "delta":
         module = mamba2
         dims = mamba2.Mamba2Dims(heads=4, d_head=32, d_state=16, groups=2,
                                  d_conv=4)
@@ -208,15 +242,22 @@ def test_a_familys_decode_step_through_the_kernel_is_its_reference(
              "beta": normal(R, dims.heads)}
     held = {name: (normal(2, R + 1, *shape).astype(dt), 1, 1)
             for name, shape, dt in dims.state_parts(F32)}
-    if family == "mamba2":
-        decode = lambda: module.decode(dims, m, p, {}, held, valid, F32)[::2]
+    if family != "delta":
+        decode = lambda: module.decode(
+            dims, m, p, {}, held, valid, F32,
+            kernel_conv=family == "mamba2")[::2]
     else:
         decode = lambda: module.decode(dims, m, p, held, valid)
     want_y, want = decode()
-    real = ctu.conv_tail_update
-    monkeypatch.setattr(module, "conv_tail_update",
-                        lambda *a: real(*a, interpret=True))
+    real, tapped = ctu.conv_tail_update, []
+
+    def through_the_kernel(*a):
+        tapped.append(a[4] is not None)
+        return real(*a, interpret=True)
+
+    monkeypatch.setattr(module, "conv_tail_update", through_the_kernel)
     got_y, got = decode()
+    assert tapped == [family != "mamba2-moves-only"]
     np.testing.assert_allclose(got_y, want_y, rtol=1e-4, atol=1e-5)
     assert sorted(got) == sorted(want) == sorted(held)
     np.testing.assert_array_equal(got["conv"], want["conv"])
@@ -225,11 +266,16 @@ def test_a_familys_decode_step_through_the_kernel_is_its_reference(
                                    atol=1e-5)
 
 
-def test_the_mixers_decode_takes_the_tail_as_a_value_or_where_it_lies():
-    """``mamba2.decode`` keeps both ways in (Nemotron-H's kind states the
-    state alone ``in_place``): the tail handed over as a value and the tail
-    moved in a pool of one layer give the same step, to the bit on the
-    reference."""
+@pytest.mark.parametrize("kernel_conv", [True, False],
+                         ids=["conv-in-the-op", "op-moves-only"])
+def test_the_mixers_decode_takes_the_tail_as_a_value_or_where_it_lies(
+        kernel_conv):
+    """``mamba2.decode`` keeps the values way in (no adapter hands it a
+    tail as a value since PR 60: ROADMAP D16): the tail handed over as a
+    value and the tail moved in a pool of one layer, with the conv in the
+    op (Falcon-H1's way) or the op handing the tails back as they lay for
+    the same ``mamba2.conv`` (Nemotron-H's), give the same step, to the bit
+    on the reference."""
     rng = np.random.RandomState(7)
     normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), F32)
     dims = mamba2.Mamba2Dims(heads=4, d_head=32, d_state=16, groups=2,
@@ -244,7 +290,8 @@ def test_the_mixers_decode_takes_the_tail_as_a_value_or_where_it_lies():
     pools = {name: (part[None], 0, 0) for name, part in state.items()}
     y_a, values, arrays_a = mamba2.decode(
         dims, m, p, {"conv": state["conv"]}, {"ssm": pools["ssm"]}, valid, dt)
-    y_b, none, arrays_b = mamba2.decode(dims, m, p, {}, pools, valid, dt)
+    y_b, none, arrays_b = mamba2.decode(dims, m, p, {}, pools, valid, dt,
+                                        kernel_conv)
     assert sorted(values) == ["conv"] and none == {}
     assert sorted(arrays_a) == ["ssm"] and sorted(arrays_b) == ["conv", "ssm"]
     np.testing.assert_array_equal(y_a, y_b)

@@ -150,14 +150,18 @@ def test_the_delta_rules_update_is_checked_at_a_published_layers_widths(
 def test_the_convs_step_is_checked_at_the_widest_published_conv(
         monkeypatch, shapes):
     """``conv_tail_update`` (PR 58) at three streams of 8,192 channels and
-    four taps: the pool after (copies: exact) and the conv's output, in
-    the interpreter, and a kernel that moves a dead row's tail fails it."""
+    four taps: the pool after (copies: exact) and the conv's output; and
+    (PR 60) with no taps at Nemotron-H's mixer's channels: the pool after
+    and the tails handed back as they lay, both exact; in the interpreter,
+    and a kernel that moves a dead row's tail fails it."""
     ctu = importlib.import_module(
         "deepspeed_tpu.ops.pallas.conv_tail_update")
     monkeypatch.setattr(selfcheck, "CHECKS",
                         (selfcheck.check_conv_tail_update,))
     names = [c.name for c in selfcheck.run_checks(shapes, interpret=True)]
-    assert names == ["conv_tail_update_pool", "conv_tail_update_out"]
+    assert names == ["conv_tail_update_pool", "conv_tail_update_out",
+                     "conv_tail_update_tails_pool",
+                     "conv_tail_update_tails_out"]
     real = ctu.conv_tail_update
     monkeypatch.setattr(
         ctu, "conv_tail_update",
